@@ -1,0 +1,574 @@
+#!/usr/bin/env python3
+"""The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py             # one chip: trainer, then paged server
+    python chip_smoke.py --chips 4   # four chips: the data-parallel mesh only
+
+Run it through the chip tool; it refuses to start where JAX finds no TPU.
+Everything happens in this one process (a chip belongs to one process).
+One model at its real widths through the normal entry points, lm_base
+(d 768, 12 layers, 12 heads, mlp 3072), weights random from --seed:
+
+one chip
+  train   the cli's parser -> config -> Trainer.fit(): s 2048, b 8, flash
+          attention, rotary positions, bf16, adamw, the synthetic Markov
+          corpus; the loss must be finite and fall, the checkpoint is
+          written, the step program must hold the compiled flash kernels.
+  serve   generate.load_lm(checkpoint) -> PagedEngine -> Scheduler: prompts
+          in two buckets, generations across several 16-token pages. The
+          decode program must hold the compiled paged kernel, which is
+          compared on the live page pool with paged_attention_reference;
+          the tokens are compared with inference.py's generate and, one by
+          one, with the argmax of a float32 forward without a cache. A
+          token that differs is examined against the logit margin.
+four chips (--chips 4)
+  the same training job at the same global batch and seed on one device,
+  on a data=4 mesh, with --fsdp, and with XLA attention on the mesh; loss
+  curves agree within LOSS_TOL and the shards sit on four distinct devices.
+
+Lines before the last are JSON objects of what was observed (smoke
+observations, not benchmarks). The last line is
+{"ok": true, "device": {...}}; any failure exits non-zero without it.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import os
+import sys
+import tempfile
+import time
+
+# stated tolerances, printed with what they judged
+KERNEL_TOL = 2e-2   # paged kernel vs gather reference, bf16 pages:
+#                     max |kernel - reference| <= KERNEL_TOL * max(1, max |reference|)
+TIE_ULPS = 4        # a differing token is a near-tie when the float32
+#                     margin is <= TIE_ULPS bf16 ulps at the top logit
+LOSS_TOL = 1e-2     # max |loss_mesh - loss_one_device| per step, bf16
+#                     (seen on four chips: 1.7e-4 data=4, 1.4e-3 FSDP, 8e-4 XLA)
+SEQ, BATCH = 2048, 8
+PAGE = 16
+
+TRAIN_ARGS = [
+    "--model", "lm_base", "--seq_len", str(SEQ), "--attn_impl", "flash",
+    "--pos_emb", "rope", "--precision", "bf16", "--optimizer", "adamw",
+    "--lr", "3e-4", "--dataset", "synthetic_tokens", "--log_every", "1",
+]
+
+
+class SmokeFailed(Exception):
+    """A phase observed something wrong; the message says what."""
+
+
+def emit(**record) -> None:
+    print(json.dumps(record), flush=True)
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise SmokeFailed(what)
+
+
+class CompileClock:
+    """Seconds JAX spent producing executables (XLA compile, or the
+    persistent cache's retrieval in its place) and the cache's hits and
+    misses, between two take() calls."""
+
+    def __init__(self) -> None:
+        from jax import monitoring
+
+        self.seconds, self.hits, self.misses = 0.0, 0, 0
+        monitoring.register_event_duration_secs_listener(self._duration)
+        monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, secs: float, **_) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += secs
+
+    def _event(self, event: str, **_) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def take(self) -> dict:
+        out = {"compile_seconds": round(self.seconds, 2),
+               "cache_hits": self.hits, "cache_misses": self.misses}
+        self.seconds, self.hits, self.misses = 0.0, 0, 0
+        return out
+
+
+def kernels_in(lowered) -> int:
+    """Mosaic kernels in a lowered program. Interpret mode and the
+    reference paths lower to plain ops and count 0."""
+    return lowered.as_text().count("tpu_custom_call")
+
+
+def peak_gib(device):
+    stats = device.memory_stats() or {}
+    peak = stats.get("peak_bytes_in_use")
+    return None if peak is None else round(peak / 2**30, 2)
+
+
+# ------------------------------------------------------------------ train
+def make_trainer(extra_args, *, seed: int):
+    from ddp_practice_tpu import cli
+    from ddp_practice_tpu.train.loop import Trainer
+
+    argv = TRAIN_ARGS + ["--seed", str(seed)] + list(extra_args)
+    return Trainer(cli.config_from_args(cli.build_parser().parse_args(argv)))
+
+
+def fit_and_read_losses(trainer, metrics_file: str) -> tuple:
+    """(summary, per-step losses, seconds at which each step was logged)."""
+    t0 = time.time()
+    summary = trainer.fit()
+    with open(metrics_file) as f:
+        records = [json.loads(line) for line in f]
+    steps = [r for r in records if r["kind"] == "train"]
+    return (summary, [r["loss"] for r in steps],
+            [r["time"] - t0 for r in steps])
+
+
+def step_kernels(trainer) -> int:
+    """Kernels in the train-step program this Trainer dispatches."""
+    import jax
+    import jax.numpy as jnp
+
+    check(trainer.resident_train_step is not None,
+          "expected the device-resident train step (token corpus in HBM)")
+    rows = jax.ShapeDtypeStruct(
+        (1, trainer.global_batch), jnp.int32, sharding=trainer._grid_sharding
+    )
+    return kernels_in(trainer.resident_train_step.lower(
+        trainer.state, trainer._train_data, rows
+    ))
+
+
+def train_phase(workdir: str, clock: CompileClock, *, seed: int,
+                size_args=("-b", str(BATCH), "-e", "2", "--max_steps", "12"),
+                require_kernels: bool = True) -> str:
+    import jax
+
+    from ddp_practice_tpu import checkpoint
+
+    ckpt = os.path.join(workdir, "ckpt")
+    metrics = os.path.join(workdir, "train_metrics.jsonl")
+    t0 = time.time()
+    trainer = make_trainer(
+        [*size_args, "--data_axis", "1", "--ckpt_dir", ckpt,
+         "--metrics_file", metrics], seed=seed,
+    )
+    n_kernels = step_kernels(trainer)
+    depth = trainer.model.depth
+    if require_kernels:
+        check(n_kernels >= 3,
+              f"train step lowered with {n_kernels} Mosaic kernels: flash "
+              "attention was interpreted or replaced (want fwd, dk/dv, dq)")
+    summary, losses, at = fit_and_read_losses(trainer, metrics)
+    check(len(losses) == summary["steps"] and len(losses) >= 6,
+          f"logged {len(losses)} losses for {summary['steps']} steps")
+    check(all(math.isfinite(x) for x in losses), f"non-finite loss: {losses}")
+    head, tail = sum(losses[:3]) / 3, sum(losses[-3:]) / 3
+    check(tail < head, f"loss did not fall: first3 {head:.4f} last3 {tail:.4f}")
+    check(checkpoint.exists(ckpt), f"no checkpoint under {ckpt}")
+    step_s = sorted(b - a for a, b in zip(at[1:], at[2:]))
+    emit(phase="train", model=trainer.config.model, depth=depth,
+         hidden_dim=trainer.model.hidden_dim, seq_len=trainer.config.seq_len,
+         global_batch=trainer.global_batch, vocab_size=trainer._vocab_size,
+         dataset="synthetic_tokens (Markov, named explicitly)",
+         attn_impl=trainer.config.attn_impl, mesh_devices=trainer.mesh.size,
+         step_program_mosaic_kernels=n_kernels, steps=summary["steps"],
+         first_loss=round(losses[0], 4), last_loss=round(losses[-1], 4),
+         first3_mean=round(head, 4), last3_mean=round(tail, 4),
+         eval_perplexity=round(summary["perplexity"], 3),
+         first_step_logged_after_s=round(at[0], 2),
+         median_step_s_host_clock=round(step_s[len(step_s) // 2], 4),
+         checkpoint_written=True, phase_seconds=round(time.time() - t0, 1),
+         peak_bytes_in_use_gib=peak_gib(jax.devices()[0]), **clock.take())
+    return ckpt
+
+
+# ------------------------------------------------------------------ serve
+def make_requests(vocab_size: int, *, seed: int, lengths, max_new: int):
+    """Prompts cut from the training distribution (the same Markov chain,
+    another stretch of it), so the trained model's next-token choices are
+    not all near-ties."""
+    from ddp_practice_tpu.data.lm_corpus import synthetic_token_corpus
+    from ddp_practice_tpu.serve.scheduler import Request
+
+    corpus = synthetic_token_corpus(
+        8192, vocab_size=vocab_size, seed=seed
+    ).tokens
+    out, at = [], 100
+    for rid, n in enumerate(lengths):
+        out.append(Request(rid=rid, prompt=[int(t) for t in corpus[at:at + n]],
+                           max_new_tokens=max_new, seed=seed))
+        at += n + 37
+    return out
+
+
+def paged_kernel_check(engine, model, *, seed: int,
+                       require_kernels: bool) -> dict:
+    """paged_decode_attention(impl="auto") against the gather reference on
+    the engine's LIVE pool, page tables and lengths, every layer."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ddp_practice_tpu.ops.decode_attention import (
+        paged_attention_reference,
+        paged_decode_attention,
+    )
+
+    heads = model.num_heads
+    table = jnp.asarray(engine._pt)
+    last = jnp.asarray(np.maximum(engine._len - 1, 0))  # newest written row
+    start = jnp.asarray(engine._attn)
+    q = jax.random.normal(
+        jax.random.PRNGKey(seed), (table.shape[0], 1, model.hidden_dim),
+        jnp.float32,
+    ).astype(model.dtype)
+
+    @jax.jit
+    def auto(q, k, v):
+        return paged_decode_attention(q, k, v, table, last, start,
+                                      n_heads=heads, impl="auto")
+
+    @jax.jit
+    def reference(q, k, v):
+        return paged_attention_reference(q, k, v, table, last, start,
+                                         n_heads=heads)
+
+    layer0 = engine._cache["block0"]["attn"]
+    n_kernels = kernels_in(auto.lower(
+        q, layer0["cached_key"], layer0["cached_value"]))
+    if require_kernels:
+        check(n_kernels == 1,
+              f'paged_decode_attention(impl="auto") lowered with {n_kernels} '
+              "Mosaic kernels: it took the reference or interpret mode")
+    active = np.flatnonzero(engine._active)
+    err = ref_max = 0.0
+    for i in range(model.depth):
+        pool = engine._cache[f"block{i}"]["attn"]
+        k, v = pool["cached_key"], pool["cached_value"]
+        got = np.asarray(auto(q, k, v), np.float32)[active]
+        want = np.asarray(reference(q, k, v), np.float32)[active]
+        check(np.isfinite(got).all(), f"paged kernel: non-finite, layer {i}")
+        err = max(err, float(np.abs(got - want).max()))
+        ref_max = max(ref_max, float(np.abs(want).max()))
+    bound = KERNEL_TOL * max(1.0, ref_max)
+    check(err <= bound,
+          f"paged kernel vs reference: max abs err {err:.3e} > {bound:.3e}")
+    return {"paged_kernel_mosaic_kernels": n_kernels,
+            "paged_kernel_max_abs_err": float(f"{err:.3e}"),
+            "paged_kernel_tolerance": float(f"{bound:.3e}"),
+            "paged_kernel_layers": model.depth,
+            "paged_kernel_slots": [int(s) for s in active],
+            "paged_kernel_lengths": [int(engine._len[s]) for s in active]}
+
+
+def float32_logits(model, params, requests, generated) -> dict:
+    """{rid: (len(generated), vocab) float32 logits} from a float32
+    forward with no cache, teacher-forced on the engine's own tokens: row
+    j is what the model, at full precision, thinks of token j given
+    everything before it, so each position is judged alone."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ddp_practice_tpu.config import PrecisionPolicy
+    from ddp_practice_tpu.models import create_model
+
+    ref = create_model(
+        "lm_base", policy=PrecisionPolicy.fp32(), vocab_size=model.vocab_size,
+        max_len=model.max_len, pos_emb="rope", depth=model.depth,
+        hidden_dim=model.hidden_dim, num_heads=model.num_heads,
+        mlp_dim=model.mlp_dim,
+    )
+    params32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+    seqs = [list(r.prompt) + list(generated[r.rid]) for r in requests]
+    width = max(len(s) for s in seqs)
+    # right padding: causal attention never lets a row see it
+    tokens = np.zeros((len(seqs), width), np.int32)
+    for i, s in enumerate(seqs):
+        tokens[i, :len(s)] = s
+    logits = np.asarray(jax.jit(
+        lambda p, t: ref.apply({"params": p}, t)
+    )(params32, jnp.asarray(tokens)), np.float32)
+    return {r.rid: logits[i, len(r.prompt) - 1:
+                          len(r.prompt) - 1 + len(generated[r.rid])]
+            for i, r in enumerate(requests)}
+
+
+def margin(row, token: int) -> tuple:
+    """(float32 argmax, its lead over `token`, the near-tie tolerance:
+    TIE_ULPS bf16 ulps at the top logit's magnitude)."""
+    best = int(row.argmax())
+    top = float(row[best])
+    ulp = 2.0 ** (math.floor(math.log2(max(abs(top), 1e-6))) - 7)
+    return best, round(top - float(row[token]), 5), round(TIE_ULPS * ulp, 5)
+
+
+def serve_phase(ckpt: str, clock: CompileClock, *, seed: int,
+                prompt_lengths=(5, 12, 9, 40, 33, 57), max_new: int = 48,
+                buckets=(16, 64), slot_len: int = 512,
+                require_kernels: bool = True) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ddp_practice_tpu.generate import load_lm
+    from ddp_practice_tpu.inference import make_generate_fn, pad_left_prompts
+    from ddp_practice_tpu.serve.engine import (
+        EngineConfig,
+        PagedEngine,
+        warm_engine,
+    )
+    from ddp_practice_tpu.serve.scheduler import Scheduler
+
+    t0 = time.time()
+    model, params, batch_stats, step = load_lm(ckpt)
+    n_params = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(params))
+    requests = make_requests(model.vocab_size, seed=seed + 1,
+                             lengths=prompt_lengths, max_new=max_new)
+    def bucket_of(r):
+        return min(b for b in buckets if len(r.prompt) <= b)
+
+    used = sorted({bucket_of(r) for r in requests})
+    check(len(used) > 1, f"prompts fall in one bucket only: {used}")
+    # a left-padded prompt fills its bucket; the generation goes on from there
+    pages_crossed = min((bucket_of(r) + max_new) // PAGE
+                        - bucket_of(r) // PAGE for r in requests)
+    check(pages_crossed >= 2, f"generations cross {pages_crossed} pages")
+    engine = PagedEngine(model, params, EngineConfig(
+        max_slots=len(requests), max_len=slot_len, prompt_buckets=buckets,
+        block_size=PAGE, decode_burst=8, temperature=0.0,
+    ), batch_stats=batch_stats)
+    decode_kernels = kernels_in(engine._decode_jit.lower(
+        engine.params, engine._cache, engine._last_logits,
+        jnp.asarray(engine._attn), jnp.asarray(engine._active), engine._keys,
+        jnp.asarray(engine._pt), jnp.asarray(engine._len), None,
+    ))
+    if require_kernels:
+        check(decode_kernels == model.depth,
+              f"decode burst lowered with {decode_kernels} Mosaic kernels, "
+              f"want one paged kernel per layer ({model.depth})")
+    warm_engine(engine)
+    warm = clock.take()
+    warm_seconds = round(time.time() - t0, 1)
+
+    sched = Scheduler(engine)
+    for r in requests:
+        check(sched.submit(r), f"request {r.rid} was not accepted")
+    # a few ticks in, every slot is live and past a page boundary: judge
+    # the kernel on those pages, then let the scheduler drain
+    for _ in range(3):
+        sched.step()
+    check(engine.num_active == len(requests),
+          f"{engine.num_active} of {len(requests)} requests in flight")
+    kernel = paged_kernel_check(engine, model, seed=seed,
+                                require_kernels=require_kernels)
+    t_run = time.time()
+    completions = {c.rid: c for c in sched.run_until_idle()}
+    run_seconds = time.time() - t_run
+    for r in requests:
+        c = completions.get(r.rid)
+        check(c is not None and c.status == "length"
+              and len(c.tokens) == max_new,
+              f"request {r.rid}: {c and (c.status, len(c.tokens))}")
+    generated = {rid: [int(t) for t in c.tokens]
+                 for rid, c in completions.items()}
+
+    # the oracle (ROADMAP D2): inference.py's one-shot generate, flat cache
+    prompts, lens = pad_left_prompts([r.prompt for r in requests])
+    gen = jax.jit(make_generate_fn(
+        model, max_new_tokens=max_new, temperature=0.0,
+        batch_stats=batch_stats,
+    ))
+    oracle = np.asarray(gen(params, prompts, None, lens))[:, prompts.shape[1]:]
+    rows = float32_logits(model, params, requests, generated)
+    # every engine token that is not the float32 argmax, with its margin
+    off_argmax = [
+        {"rid": rid, "index": j, "engine": tok, "float32_argmax": best,
+         "margin": lead, "tolerance": tol}
+        for rid, toks in generated.items() for j, tok in enumerate(toks)
+        for best, lead, tol in [margin(rows[rid][j], tok)] if best != tok
+    ]
+    identical, vs_generate = 0, []
+    for i, r in enumerate(requests):
+        mine = generated[r.rid]
+        first = next((j for j in range(max_new)
+                      if mine[j] != int(oracle[i, j])), None)
+        if first is None:
+            identical += 1
+            continue
+        # up to `first` both saw the same context, so the float32 logits
+        # of that one position judge both choices
+        row, theirs = rows[r.rid][first], int(oracle[i, first])
+        best, lead_mine, tol = margin(row, mine[first])
+        vs_generate.append({
+            "rid": r.rid, "index": first, "float32_argmax": best,
+            "engine": mine[first], "engine_margin": lead_mine,
+            "generate": theirs, "generate_margin": margin(row, theirs)[1],
+            "tolerance": tol,
+        })
+    emit(phase="serve", checkpoint_step=step, params=n_params,
+         requests=len(requests), prompt_lengths=list(prompt_lengths),
+         buckets_used=used, tokens=sum(len(t) for t in generated.values()),
+         page=PAGE, pages_crossed_per_request_min=pages_crossed,
+         decode_program_mosaic_kernels=decode_kernels, **kernel,
+         identical_to_generate=f"{identical}/{len(requests)}",
+         first_differences_vs_generate=vs_generate,
+         tokens_not_float32_argmax=len(off_argmax),
+         largest_margin_off_argmax=max(
+             (d["margin"] for d in off_argmax), default=0.0),
+         tie_tolerance_bf16_ulps=TIE_ULPS,
+         load_and_warmup_seconds=warm_seconds, warmup=warm,
+         drain_seconds_host_clock=round(run_seconds, 2),
+         phase_seconds=round(time.time() - t0, 1),
+         peak_bytes_in_use_gib=peak_gib(jax.devices()[0]), **clock.take())
+    wrong = [d for d in off_argmax if d["margin"] > d["tolerance"]]
+    check(not wrong,
+          f"engine tokens beyond a bf16 near-tie of the float32 argmax: "
+          f"{wrong[:4]}")
+    for d in vs_generate:
+        check(max(d["engine_margin"], d["generate_margin"]) <= d["tolerance"],
+              f"engine and generate() part ways beyond a near-tie: {d}")
+
+
+# ------------------------------------------------------------- four chips
+def mesh_phase(workdir: str, clock: CompileClock, *, seed: int,
+               steps: int = 6, require_kernels: bool = True) -> None:
+    import jax
+    import numpy as np
+
+    n = jax.device_count()
+    runs = {
+        "one_device_flash": ["-b", str(BATCH), "--data_axis", "1"],
+        "data4_flash": ["-b", str(BATCH // n)],
+        "fsdp4_flash": ["-b", str(BATCH // n), "--fsdp"],
+        "data4_xla": ["-b", str(BATCH // n), "--attn_impl", "xla"],
+    }
+    curves = {}
+    for name, extra in runs.items():
+        t0 = time.time()
+        metrics = os.path.join(workdir, f"{name}.jsonl")
+        trainer = make_trainer(
+            [*extra, "-e", "1", "--max_steps", str(steps),
+             "--metrics_file", metrics], seed=seed,
+        )
+        want = 1 if name.startswith("one_device") else n
+        mesh_ids = sorted(d.id for d in trainer.mesh.devices.flat)
+        check(len(set(mesh_ids)) == want,
+              f"{name}: mesh holds devices {mesh_ids}, want {want} distinct")
+        check(trainer.global_batch == BATCH,
+              f"{name}: global batch {trainer.global_batch} != {BATCH}")
+        rows = jax.device_put(
+            np.zeros((1, BATCH), np.int32), trainer._grid_sharding)
+        batch_on = sorted(s.device.id for s in rows.addressable_shards)
+        check(batch_on == mesh_ids
+              and all(s.data.shape == (1, BATCH // want)
+                      for s in rows.addressable_shards),
+              f"{name}: batch rows on devices {batch_on}")
+        leaf = trainer.state.params["block0"]["mlp"]["fc_in"]["kernel"]
+        shard_shapes = sorted({s.data.shape for s in leaf.addressable_shards})
+        param_on = sorted(s.device.id for s in leaf.addressable_shards)
+        check(param_on == mesh_ids, f"{name}: params on devices {param_on}")
+        quarter = math.prod(leaf.shape) // n
+        check([math.prod(s) for s in shard_shapes]
+              == [quarter if "fsdp" in name else math.prod(leaf.shape)],
+              f"{name}: fc_in kernel {leaf.shape} in shards {shard_shapes}")
+        n_kernels = step_kernels(trainer)
+        if require_kernels:
+            check((n_kernels >= 3) == ("flash" in name),
+                  f"{name}: step lowered with {n_kernels} Mosaic kernels")
+        summary, losses, at = fit_and_read_losses(trainer, metrics)
+        check(len(losses) == steps and all(map(math.isfinite, losses)),
+              f"{name}: losses {losses}")
+        curves[name] = losses
+        emit(phase="mesh", run=name, mesh_devices=mesh_ids,
+             per_replica_batch=trainer.config.batch_size,
+             batch_shard=[1, BATCH // want],
+             fc_in_kernel_shards=[list(s) for s in shard_shapes],
+             step_program_mosaic_kernels=n_kernels,
+             losses=[round(x, 4) for x in losses],
+             first_step_logged_after_s=round(at[0], 2),
+             phase_seconds=round(time.time() - t0, 1),
+             peak_bytes_in_use_gib=peak_gib(jax.devices()[0]), **clock.take())
+        del trainer
+    base = np.asarray(curves["one_device_flash"])
+    check(base[-1] < base[0], f"loss did not fall: {base.tolist()}")
+    diffs = {name: float(np.abs(np.asarray(c) - base).max())
+             for name, c in curves.items() if name != "one_device_flash"}
+    emit(phase="mesh", compared_with="one_device_flash",
+         max_abs_loss_diff={k: round(v, 5) for k, v in diffs.items()},
+         tolerance=LOSS_TOL)
+    for name, d in diffs.items():
+        check(d <= LOSS_TOL,
+              f"{name}: loss curve is {d:.4f} from the one-device run "
+              f"(> {LOSS_TOL}): {curves[name]} vs {base.tolist()}")
+
+
+# ------------------------------------------------------------------- main
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, default=1, choices=[1, 4],
+                    help="4 = the data-parallel mesh phase and nothing else")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="weights, data order and prompts")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    dev = jax.devices()[0]
+    n_dev = len(jax.devices())
+    if dev.platform != "tpu":
+        print(f"chip_smoke: no accelerator — jax reports platform "
+              f"{dev.platform!r} ({dev.device_kind}); nothing was run",
+              file=sys.stderr)
+        return 2
+    if n_dev != args.chips:
+        print(f"chip_smoke: --chips {args.chips} but jax reports {n_dev} "
+              f"device(s); nothing was run", file=sys.stderr)
+        return 2
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    from ddp_practice_tpu.data import native_loader
+    from ddp_practice_tpu.utils.backend import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    clock = CompileClock()
+    import flax
+    import jaxlib
+    import optax
+    from importlib import metadata
+
+    emit(phase="start", jax=jax.__version__, jaxlib=jaxlib.__version__,
+         libtpu=metadata.version("libtpu"), flax=flax.__version__,
+         optax=optax.__version__, python=sys.version.split()[0],
+         platform=dev.platform, device_kind=dev.device_kind, devices=n_dev,
+         compile_cache_dir=cache_dir,
+         compile_cache_from_env="JAX_COMPILATION_CACHE_DIR" in os.environ,
+         native_loader="built and loaded" if native_loader.available()
+         else "unavailable: numpy gather", seed=args.seed)
+    try:
+        with tempfile.TemporaryDirectory(prefix="chip_smoke_") as workdir:
+            if args.chips == 4:
+                mesh_phase(workdir, clock, seed=args.seed)
+            else:
+                ckpt = train_phase(workdir, clock, seed=args.seed)
+                serve_phase(ckpt, clock, seed=args.seed)
+    except SmokeFailed as e:
+        print(f"chip_smoke: FAILED — {e}", file=sys.stderr)
+        return 1
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind, "count": n_dev,
+    }}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -164,8 +164,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="use only the first N local devices (0 = all)")
     p.add_argument("--cpu", type=int, default=0, metavar="N",
                    help="force the CPU platform with N virtual devices "
-                        "(sharding dev-runs without TPU hardware; set via "
-                        "jax.config because TPU plugins override env vars)")
+                        "(sharding dev-runs without TPU hardware)")
     p.add_argument("--coordinator", default=None,
                    help="host:port for multi-host rendezvous")
     p.add_argument("--num_processes", type=int, default=None)
@@ -231,8 +230,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "driven by index grids alone; host = stream batches; "
                         "auto = device when single-process and it fits")
     p.add_argument("--compile_cache", default="auto",
-                   help="persistent XLA compilation cache dir (repeat runs "
-                        "skip compile); auto = ~/.cache/ddp_practice_tpu/xla, "
+                   choices=["auto", "off"],
+                   help="persistent XLA compilation cache (repeat runs skip "
+                        "compile): auto = $JAX_COMPILATION_CACHE_DIR when "
+                        "set, else .jax_compile_cache/ in the checkout; "
                         "off = disable")
     p.add_argument("--fused", nargs="?", const="on", default="auto",
                    choices=["auto", "on", "off"],
@@ -356,23 +357,10 @@ def main(argv=None) -> int:
 
         os.environ.setdefault("JAX_NUM_CPU_DEVICES", str(args.devices))
     if args.cpu:
-        import os
-
         import jax
 
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", args.cpu)
-        except AttributeError:
-            # older jax: the option doesn't exist; the XLA flag works as
-            # long as jax hasn't initialized its backends yet (it hasn't —
-            # the train loop import below is the first device touch)
-            flags = os.environ.get("XLA_FLAGS", "")
-            if "xla_force_host_platform_device_count" not in flags:
-                os.environ["XLA_FLAGS"] = (
-                    flags
-                    + f" --xla_force_host_platform_device_count={args.cpu}"
-                ).strip()
+        jax.config.update("jax_num_cpu_devices", args.cpu)
     from ddp_practice_tpu.train.loop import fit  # deferred: jax import cost
 
     t0 = time.time()

@@ -305,10 +305,10 @@ class TrainConfig:
     # differently — tests/test_resident.py pins the bound).
     data_placement: str = "auto"       # auto | host | device
     resident_max_bytes: int = 256 * 1024 * 1024
-    # persistent XLA compilation cache: repeat runs skip compile entirely
-    # (measured on the parity run: ~20-30 s cold -> 6-15 s warm, PARITY.md).
-    # "auto" = ~/.cache/ddp_practice_tpu/xla (or $JAX_COMPILATION_CACHE_DIR
-    # when set); "off" disables; any other value is used as the directory.
+    # persistent XLA compilation cache (utils/backend.py
+    # enable_compile_cache): "auto" = $JAX_COMPILATION_CACHE_DIR when set,
+    # else a fixed git-ignored directory inside the checkout; "off"
+    # disables (tests that count compiles).
     compilation_cache: str = "auto"
     shuffle_eval: bool = False  # the reference baseline shuffles eval; don't (SURVEY §2.5)
 

@@ -1,14 +1,20 @@
 """ctypes binding for the native (C++) batch-assembly backend.
 
-Builds `native/libddp_loader.so` on first use if a compiler is available
-(no pybind11 in this environment; the C ABI + ctypes keeps the binding
-dependency-free). Falls back silently — callers treat None from
-`make_gather` as "use the numpy path", which is bit-identical.
+Builds `native/libddp_loader.<source hash>.so` on first use if a compiler
+is available (no pybind11 in this environment; the C ABI + ctypes keeps
+the binding dependency-free). A library is loaded only when it was built
+from the `dataloader.cpp` and `Makefile` now in `native/` — the hash of
+both is in its filename, so a binary left on disk by another checkout or
+another machine's build is never picked up. Falls back to numpy when it
+cannot build — callers treat None from `make_gather` as "use the numpy
+path", which is bit-identical; `available()` says which one a run got.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,31 +24,45 @@ import numpy as np
 
 from ddp_practice_tpu.data.datasets import Dataset
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
-# ABI-versioned filename (matches native/Makefile TARGET): a stale build
-# from an older ABI simply has a different name and is never picked up —
-# dlopen's per-pathname handle caching makes same-name reloads impossible.
-_SO_NAME = "libddp_loader.v3.so"
+_NATIVE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), "..", "..", "native")
+)
 
 _lib = None
 _lib_lock = threading.Lock()
-_build_attempted = False
 
 
 _ABI_VERSION = 3  # keep in sync with dl_version() in native/dataloader.cpp
 
 
+def _so_name() -> Optional[str]:
+    """`libddp_loader.<hash>.so` for the sources on disk (None: no
+    sources, nothing to build). A hash in the filename, not a stamp
+    beside it: dlopen caches handles by pathname, so a rebuilt library
+    under an old name would never be re-loaded in-process either."""
+    h = hashlib.sha256()
+    try:
+        for name in ("dataloader.cpp", "Makefile"):
+            with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+                h.update(f.read())
+    except OSError:
+        return None
+    return f"libddp_loader.{h.hexdigest()[:12]}.so"
+
+
 def _load_library() -> Optional[ctypes.CDLL]:
-    global _lib, _build_attempted
+    global _lib
     with _lib_lock:
         if _lib is not None:
             return _lib if _lib is not _UNAVAILABLE else None
-        so_path = os.path.abspath(os.path.join(_NATIVE_DIR, _SO_NAME))
-        if not os.path.exists(so_path) and not _build_attempted:
-            _build_attempted = True
-            _try_build()
+        _lib = _UNAVAILABLE  # the negative result is cached too
+        name = _so_name()
+        if name is None:
+            return None
+        so_path = os.path.join(_NATIVE_DIR, name)
         if not os.path.exists(so_path):
-            _lib = _UNAVAILABLE  # cache the negative result
+            _build(name)
+        if not os.path.exists(so_path):
             return None
         lib = ctypes.CDLL(so_path)
         lib.dl_create.restype = ctypes.c_void_p
@@ -57,8 +77,7 @@ def _load_library() -> Optional[ctypes.CDLL]:
         ]
         lib.dl_gather.restype = ctypes.c_int32
         lib.dl_version.restype = ctypes.c_int32
-        if lib.dl_version() != _ABI_VERSION:  # filename/ABI drift guard
-            _lib = _UNAVAILABLE
+        if lib.dl_version() != _ABI_VERSION:  # binding/source drift guard
             return None
         _lib = lib
         return _lib
@@ -67,13 +86,19 @@ def _load_library() -> Optional[ctypes.CDLL]:
 _UNAVAILABLE = object()  # sentinel: library looked for and not usable
 
 
-def _try_build() -> None:
-    makefile = os.path.join(_NATIVE_DIR, "Makefile")
-    if not os.path.exists(makefile):
-        return
+def _build(name: str) -> None:
+    """make TARGET=<name>, after removing libraries of other sources.
+    A failed build (no compiler, read-only tree) leaves nothing behind
+    and the caller uses numpy."""
+    for stale in glob.glob(os.path.join(_NATIVE_DIR, "libddp_loader*.so")):
+        if os.path.basename(stale) != name:  # a concurrent build's result
+            try:
+                os.unlink(stale)
+            except OSError:
+                pass
     try:
         subprocess.run(
-            ["make", "-C", os.path.abspath(_NATIVE_DIR)],
+            ["make", "-C", _NATIVE_DIR, f"TARGET={name}"],
             check=True,
             capture_output=True,
             timeout=120,

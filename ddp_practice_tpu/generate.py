@@ -30,6 +30,7 @@ from ddp_practice_tpu.inference import (
 )
 from ddp_practice_tpu.models import create_model
 from ddp_practice_tpu.train.state import create_state, make_optimizer
+from ddp_practice_tpu.utils.backend import enable_compile_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -122,6 +123,7 @@ def load_lm(ckpt_dir, *, model=None, seq_len=0, kv_cache="policy") -> tuple:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     model, params, batch_stats, step = load_lm(
         args.ckpt_dir, model=args.model, seq_len=args.seq_len,
         kv_cache=args.kv_cache,

@@ -138,13 +138,31 @@ class SelfAttention(nn.Module):
             # sliced path; flash_attention_qkv itself falls back for
             # unpackable head shapes. Falls through to the shared output
             # projection below.
+            # Under a mesh the kernel runs in a shard_map island
+            # (parallel/ring.py kernel_island): the split is taken on
+            # the (3, h, hd) dims, heads over 'tensor', and each device
+            # flattens its OWN heads — sharding the flat 3*h*hd dim
+            # would hand a device q heads without their k and v.
             from ddp_practice_tpu.ops.flash_attention import (
                 flash_attention_qkv,
             )
-
-            out = flash_attention_qkv(
-                qkv.reshape(b, s, 3 * d), self.num_heads, causal=self.causal
+            from ddp_practice_tpu.parallel.ring import (
+                BSHD_SPEC,
+                QKV_SPEC,
+                kernel_island,
             )
+
+            def local_qkv(x):
+                lb, ls, _, lh, lhd = x.shape
+                return flash_attention_qkv(
+                    x.reshape(lb, ls, 3 * lh * lhd), lh, causal=self.causal
+                )
+
+            out = kernel_island(
+                local_qkv,
+                in_specs=(QKV_SPEC,),
+                out_specs=BSHD_SPEC,
+            )(qkv)
             return self._out_proj(out)
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         if self.rope and not decode:
@@ -562,6 +580,19 @@ class EncoderBlock(nn.Module):
             from ddp_practice_tpu.ops.fused_encoder import (
                 fused_encoder_layer,
             )
+            from ddp_practice_tpu.parallel.ring import get_current_mesh
+
+            mesh = get_current_mesh()
+            if mesh is not None and mesh.devices.size > 1:
+                # said here, at trace time, not by the TPU partitioner
+                # ("Mosaic kernels cannot be automatically partitioned")
+                raise ValueError(
+                    f"fused=True on a {mesh.devices.size}-device mesh: the "
+                    "fused encoder-layer kernels are single-chip (they "
+                    "keep a layer's whole weights in one chip's VMEM and "
+                    "have no shard_map island) — use fused='auto'/False "
+                    "there, or a one-device mesh"
+                )
 
             return fused_encoder_layer(
                 x, self.variables["params"],

@@ -13,10 +13,12 @@ ladder. Two execution paths:
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
+
+from ddp_practice_tpu.utils import backend
 
 # Decode query-broadcast tuning, measured on TPU v5e (2026-07-30 profile,
 # BENCHMARKS.md decode section): 8 = the sublane width (smallest MXU row
@@ -65,8 +67,12 @@ def dot_product_attention(
         raise ValueError(f"unknown sp_impl {sp_impl!r} (want 'ring'|'ulysses')")
     if impl == "flash":
         from ddp_practice_tpu.ops.flash_attention import flash_attention
+        from ddp_practice_tpu.parallel.ring import BSHD_SPEC, kernel_island
 
-        return flash_attention(q, k, v, causal=causal)
+        return kernel_island(
+            functools.partial(flash_attention, causal=causal),
+            in_specs=(BSHD_SPEC,) * 3, out_specs=BSHD_SPEC,
+        )(q, k, v)
     return _attention(q, k, v, causal=causal)
 
 
@@ -84,7 +90,7 @@ def attention_with_mask(q, k, v, mask) -> jnp.ndarray:
     if (
         q.shape[1] == 1
         and q.shape[0] <= _Q8_MAX_BATCH
-        and jax.default_backend() != "cpu"
+        and backend.on_tpu()
     ):
         # small-batch single-token decode steps: a 1-row query makes both
         # attention contractions matvecs, which XLA lowers to VPU

@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-from ddp_practice_tpu.ops.pallas_compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 from ddp_practice_tpu.ops.flash_attention import (
     _LANES,
@@ -59,6 +59,7 @@ from ddp_practice_tpu.ops.flash_attention import (
     _heads_per_pack,
     _softmax_accumulate,
 )
+from ddp_practice_tpu.utils import backend
 
 
 def _online_softmax_cell(
@@ -228,8 +229,6 @@ def decode_attention_packed(
     is the bandwidth roofline, so halving cache bytes is the lever the
     round-5 MBU work turned (BENCHMARKS.md decode section).
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     b, sq, hd_total = q.shape
     if sq != 1:
         raise ValueError(
@@ -253,8 +252,8 @@ def decode_attention_packed(
         jnp.asarray(attn_start, jnp.int32)
         if has_start else jnp.zeros((b,), jnp.int32)
     )
-    interpret = jax.default_backend() == "cpu"
-    sem = tpu_compiler_params
+    interpret = not backend.on_tpu()
+    sem = pltpu.CompilerParams
 
     if quant and L > single_block_max:
         # long-cache int8 falls back to a dequantized pass through the
@@ -544,8 +543,6 @@ def paged_decode_attention(
     `_paged_kernel_quant` — cache bytes/token halve while the numerics
     stay pinned to the dequantizing gather reference.
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     b, sq, hd_total = q.shape
     if sq != 1:
         raise ValueError(
@@ -560,7 +557,7 @@ def paged_decode_attention(
     d = hd_total // n_heads
     packable = _heads_per_pack(n_heads, d) is not None and bs % 8 == 0
     if impl == "reference" or (impl == "auto" and (
-            not packable or jax.default_backend() == "cpu")):
+            not packable or not backend.on_tpu())):
         return paged_attention_reference(
             q, k_pages, v_pages, page_table, lengths, attn_start,
             n_heads=n_heads, k_scale=k_scale, v_scale=v_scale,
@@ -612,10 +609,10 @@ def paged_decode_attention(
                 **common,
             ),
             out_shape=jax.ShapeDtypeStruct((b, 1, hd_total), q.dtype),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")
             ),
-            interpret=jax.default_backend() == "cpu",
+            interpret=not backend.on_tpu(),
         )(lens, start, pt, q, k_pages, v_pages, k_scale, v_scale)
     kernel = functools.partial(
         _paged_kernel, sm_scale=sm_scale, block_size=bs,
@@ -629,8 +626,8 @@ def paged_decode_attention(
             **common,
         ),
         out_shape=jax.ShapeDtypeStruct((b, 1, hd_total), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
-        interpret=jax.default_backend() == "cpu",
+        interpret=not backend.on_tpu(),
     )(lens, start, pt, q, k_pages, v_pages)

@@ -69,7 +69,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-from ddp_practice_tpu.ops.pallas_compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
+
+from ddp_practice_tpu.utils import backend
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -235,8 +237,6 @@ def _kv_index_map(causal, block_q, block_k, offset):
 
 def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
     """q/k/v: (bh, seq, d). Returns (out, lse)."""
-    from jax.experimental.pallas import tpu as pltpu
-
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
     block_q, block_k = _check_blocks(seq_q, seq_k, block_q, block_k, causal)
@@ -269,7 +269,7 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -375,8 +375,6 @@ def _dq_kernel(
 def _flash_bwd(q, k, v, do, lse, delta, *, causal, block_q, block_k,
                interpret):
     """Tiled dq/dk/dv. delta = rowsum(do*o) - g_lse, fp32 (bh, seq_q)."""
-    from jax.experimental.pallas import tpu as pltpu
-
     bh, seq_q, d = q.shape
     seq_k = k.shape[1]
     block_q, block_k = _check_blocks(seq_q, seq_k, block_q, block_k, causal)
@@ -417,7 +415,7 @@ def _flash_bwd(q, k, v, do, lse, delta, *, causal, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -442,7 +440,7 @@ def _flash_bwd(q, k, v, do, lse, delta, *, causal, block_q, block_k,
         out_specs=pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -451,7 +449,7 @@ def _flash_bwd(q, k, v, do, lse, delta, *, causal, block_q, block_k,
 
 
 def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
+    return not backend.on_tpu()
 
 
 # --------------------------------------------------------------------- #
@@ -549,8 +547,6 @@ def _flash_fwd_packed(qf, kf, vf, *, n_heads, causal, block_q, block_k,
     2*n_packs), so no slice/relayout ever materializes q, k, v (the
     sliced path cost ~4 ms/step of pure data formatting at lm_base
     shapes — round-4 profile)."""
-    from jax.experimental.pallas import tpu as pltpu
-
     b, seq_q, hd = qf.shape
     if fused_qkv:
         hd //= 3
@@ -599,7 +595,7 @@ def _flash_fwd_packed(qf, kf, vf, *, n_heads, causal, block_q, block_k,
             pltpu.VMEM((hpc, block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, w), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")
         ),
@@ -735,8 +731,6 @@ def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
     block schedule. fused_qkv: as in _flash_fwd_packed (dq/dk/dv still
     come back as three (b, s, h*d) arrays; the caller concatenates once
     for the projection backward)."""
-    from jax.experimental.pallas import tpu as pltpu
-
     b, seq_q, hd = qf.shape
     if fused_qkv:
         hd //= 3
@@ -788,7 +782,7 @@ def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
             pltpu.VMEM((block_k, w), jnp.float32),
             pltpu.VMEM((block_k, w), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")
         ),
@@ -825,7 +819,7 @@ def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
             pltpu.VMEM((block_q, w), jnp.float32),
             pltpu.VMEM((block_q, hpc), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")
         ),

@@ -42,13 +42,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from ddp_practice_tpu.ops.pallas_compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
+
+from ddp_practice_tpu.utils import backend
 
 _LN_EPS = 1e-6  # flax.linen.LayerNorm default
 
 
 def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
+    return not backend.on_tpu()
 
 
 def _layer_norm(xt, scale, bias):
@@ -368,8 +370,6 @@ def _vmem_params(interpret):
         return None
     import os
 
-    from jax.experimental.pallas import tpu as pltpu
-
     raw = os.environ.get("DDP_TPU_FUSED_VMEM_MB", "17")
     try:
         mb = int(raw)
@@ -380,7 +380,7 @@ def _vmem_params(interpret):
             f"DDP_TPU_FUSED_VMEM_MB={raw!r}: want a positive integer "
             "(MB of scoped VMEM to declare for the fused encoder kernels)"
         ) from None
-    return tpu_compiler_params(vmem_limit_bytes=mb * 1024 * 1024)
+    return pltpu.CompilerParams(vmem_limit_bytes=mb * 1024 * 1024)
 
 
 def _fit_tile(n, tile):

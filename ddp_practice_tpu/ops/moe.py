@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ddp_practice_tpu.config import MeshConfig
+from ddp_practice_tpu.utils import backend
 
 
 def _constrain(x, spec):
@@ -400,11 +401,21 @@ def _gmm_tiling(m: int, k: int, n: int):
     (512, 768, 512); (m=32k, k=3072, n=768) 42 TF/s at
     (512, 3072, 768) — both within ~2% of the dense-matmul rate of the
     same FLOPs). Keyed by each CALL's effective dims, so forward and
-    the two backward directions each get their own shape's optimum."""
-    return (
-        _fit_tile(m, 512), min(k, 3072),
-        _fit_tile(n, n if n <= 768 else 512),
-    )
+    the two backward directions each get their own shape's optimum.
+
+    The n tile then halves until the kernel's working set — lhs, rhs and
+    out tiles double-buffered in bf16 plus the fp32 accumulator — fits
+    the 16 MB scoped-VMEM default with room for the compiler's own
+    buffers: jax 0.9's compiler refuses (512, 3072, 768) at 18.58 MB
+    (tests/test_tpu_compile.py; the 42 TF/s figure above predates it and
+    is not re-measured). The full-contraction k tile is what the tuning
+    found to matter, so it is the last to go."""
+    tm, tk = _fit_tile(m, 512), min(k, 3072)
+    tn = _fit_tile(n, n if n <= 768 else 512)
+    while (4 * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn > 14 * 2**20
+           and tn % 256 == 0):
+        tn //= 2
+    return tm, tk, tn
 
 
 def _mb_gmm(lhs, rhs, gs, *, transpose_rhs: bool, interpret: bool):
@@ -860,7 +871,7 @@ class MoEMlp(nn.Module):
 
         w_in, b_in, w_out, b_out = self._expert_params(d, e, f)
         cd = self.dtype
-        interpret = jax.default_backend() == "cpu"
+        interpret = not backend.on_tpu()
         sorted_expert = cf[inv]
         onehot_sorted = jax.nn.one_hot(sorted_expert, e, dtype=cd)
         x_sorted = _dispatch_rows(xf.astype(cd), tok, dest_nk)

@@ -28,12 +28,11 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ddp_practice_tpu.config import MeshConfig
 from ddp_practice_tpu.parallel.ring import get_current_mesh
-from ddp_practice_tpu.parallel.compat import shard_map
 
 
 def pipeline_apply(

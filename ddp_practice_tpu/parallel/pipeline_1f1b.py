@@ -50,12 +50,11 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ddp_practice_tpu.config import MeshConfig
 from ddp_practice_tpu.parallel.ring import get_current_mesh
-from ddp_practice_tpu.parallel.compat import shard_map
 
 
 def _head_cond(head_loss_fn, head_params, y_b, tgt, wgt, aux_shape,

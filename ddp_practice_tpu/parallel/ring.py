@@ -21,11 +21,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax import lax, shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 
 from ddp_practice_tpu.config import MeshConfig
-from ddp_practice_tpu.parallel.compat import shard_map
+from ddp_practice_tpu.utils import backend
 
 _NEG_INF = -1e30
 
@@ -56,9 +56,7 @@ def single_chip_tpu() -> bool:
     this program runs on — the framework's current mesh when set
     (a --devices 1 run on a multi-chip host qualifies), the host
     inventory otherwise."""
-    import jax
-
-    if jax.default_backend() != "tpu":
+    if not backend.on_tpu():
         return False
     mesh = get_current_mesh()
     n_dev = mesh.devices.size if mesh is not None else jax.device_count()
@@ -114,8 +112,9 @@ def ring_attention(
     return fn(q, k, v)
 
 
-def _island_mesh_and_spec(mesh, axis_name: str):
-    """Mesh + (batch, seq, heads, None) spec for an SP shard_map island.
+def _island_context(mesh):
+    """(mesh to open an island on, axes an enclosing shard_map already
+    made manual).
 
     Under an OUTER partial-manual shard_map (the GPipe pipeline is manual
     over 'pipe'/'data'), a nested island must (a) pass the context
@@ -123,23 +122,22 @@ def _island_mesh_and_spec(mesh, axis_name: str):
     and (b) name only still-automatic axes in its specs — the manual ones
     are already local dims here. That is what lets sequence parallelism
     run INSIDE a pipeline stage (sp x pp)."""
-    try:
-        from jax.sharding import AxisType
+    ctx = jax.sharding.get_abstract_mesh()
+    manual = {
+        n for n, t in zip(ctx.axis_names, ctx.axis_types)
+        if t == AxisType.Manual
+    }
+    return (ctx if manual else mesh), manual
 
-        ctx = jax.sharding.get_abstract_mesh()
-        manual = {
-            n for n, t in zip(ctx.axis_names, ctx.axis_types)
-            if t == AxisType.Manual
-        }
-    except Exception:
-        ctx, manual = None, set()
-    if manual:
-        if axis_name in manual:
-            raise ValueError(
-                f"sequence axis {axis_name!r} is already manual in the "
-                "enclosing shard_map — call the local ring directly"
-            )
-        mesh = ctx
+
+def _island_mesh_and_spec(mesh, axis_name: str):
+    """Mesh + (batch, seq, heads, None) spec for an SP shard_map island."""
+    mesh, manual = _island_context(mesh)
+    if axis_name in manual:
+        raise ValueError(
+            f"sequence axis {axis_name!r} is already manual in the "
+            "enclosing shard_map — call the local ring directly"
+        )
     spec = P(
         None if MeshConfig.AXIS_DATA in manual else MeshConfig.AXIS_DATA,
         axis_name,
@@ -147,6 +145,44 @@ def _island_mesh_and_spec(mesh, axis_name: str):
         None,
     )
     return mesh, spec
+
+
+def kernel_island(fn, *, in_specs, out_specs):
+    """`fn` as it must be called under GSPMD `jit` on a mesh: once per
+    device, on that device's own shard, inside a shard_map island.
+
+    A Pallas (Mosaic) kernel is an opaque custom call — the TPU
+    partitioner refuses to split one ("Mosaic kernels cannot be
+    automatically partitioned"), which interpret mode on CPU never shows
+    because there the kernel is ordinary XLA ops. Attention is
+    independent per batch row and per head, so the specs shard batch
+    over 'data' and heads over 'tensor' and nothing is gathered. Axes an
+    enclosing shard_map already made manual are local dims here and are
+    dropped from the specs. With no registered mesh, or one device, `fn`
+    is returned as it is."""
+    mesh = get_current_mesh()
+    if mesh is None or mesh.devices.size == 1:
+        return fn
+    mesh, manual = _island_context(mesh)
+    if manual >= set(mesh.axis_names):
+        return fn  # every axis is already local: nothing left to split
+
+    def local(spec):
+        return P(*(None if a in manual else a for a in spec))
+
+    return shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(local(sp) for sp in in_specs),
+        out_specs=local(out_specs),
+        check_vma=False,
+    )
+
+
+# what a local attention kernel sees under DP x TP (SP aside):
+# (batch, seq, heads, head_dim), and the raw (batch, seq, 3, heads,
+# head_dim) output of the QKV projection
+BSHD_SPEC = P(MeshConfig.AXIS_DATA, None, MeshConfig.AXIS_TENSOR, None)
+QKV_SPEC = P(MeshConfig.AXIS_DATA, None, None, MeshConfig.AXIS_TENSOR, None)
 
 
 def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool,

@@ -23,9 +23,8 @@ from __future__ import annotations
 import functools
 
 import jax
-from jax import lax
+from jax import lax, shard_map
 
-from ddp_practice_tpu.parallel.compat import shard_map
 from ddp_practice_tpu.parallel.ring import (
     _axis_bound,
     _island_mesh_and_spec,

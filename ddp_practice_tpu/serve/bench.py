@@ -4135,6 +4135,17 @@ def _serve_checkpoint(args) -> int:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    import jax
+
+    from ddp_practice_tpu.utils.backend import enable_compile_cache
+
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    # every serving number names the device it came from; stderr under
+    # --json so stdout stays one parseable document
+    print(f"[serve] platform={dev.platform} device_kind={dev.device_kind} "
+          f"devices={jax.device_count()}",
+          file=sys.stderr if args.json else sys.stdout)
     if args.ckpt_dir:
         return _serve_checkpoint(args)
     if args.kv_int8 and not args.shared_prefix:

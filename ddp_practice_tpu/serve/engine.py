@@ -71,6 +71,7 @@ from ddp_practice_tpu.serve.kv_slots import (
     set_cursor,
     write_slot,
 )
+from ddp_practice_tpu.utils import backend
 from ddp_practice_tpu.utils.trace import (
     ENGINE_LANE,
     NULL_SPAN as _NULL,
@@ -213,10 +214,7 @@ def _decode_donate(pool_argnum: int = 1) -> tuple:
     is the whole serving memory, big enough to care (ROADMAP
     engine-level item). Gated off on CPU, where donation is
     unimplemented and every dispatch would warn."""
-    return (pool_argnum,) if jax.default_backend() == "tpu" else ()
-
-
-_CPU_DISPATCH_BARRIER = None
+    return (pool_argnum,) if backend.on_tpu() else ()
 
 
 def _await_dispatch(*state) -> None:
@@ -236,10 +234,7 @@ def _await_dispatch(*state) -> None:
     stream-ordered per core, so the barrier would only break dispatch
     pipelining — skip it.
     """
-    global _CPU_DISPATCH_BARRIER
-    if _CPU_DISPATCH_BARRIER is None:
-        _CPU_DISPATCH_BARRIER = jax.default_backend() == "cpu"
-    if _CPU_DISPATCH_BARRIER:
+    if not backend.on_tpu():
         jax.block_until_ready(state)
 
 

@@ -75,17 +75,21 @@ from ddp_practice_tpu.utils.backoff import backoff_delay
 # the belt under the supervisor's own bookkeeping. tests/conftest.py's
 # session fixture asserts this drains; atexit is the suspenders.
 _CHILDREN: Dict[int, subprocess.Popen] = {}
+# pid -> the jax platform the child was pinned to (the chip lease check)
+_CHILD_PLATFORM: Dict[int, str] = {}
 _CHILDREN_LOCK = threading.Lock()
 
 
-def _register_child(proc: subprocess.Popen) -> None:
+def _register_child(proc: subprocess.Popen, platform: str) -> None:
     with _CHILDREN_LOCK:
         _CHILDREN[proc.pid] = proc
+        _CHILD_PLATFORM[proc.pid] = platform
 
 
 def _unregister_child(pid: int) -> None:
     with _CHILDREN_LOCK:
         _CHILDREN.pop(pid, None)
+        _CHILD_PLATFORM.pop(pid, None)
 
 
 def live_worker_pids() -> List[int]:
@@ -121,6 +125,44 @@ def reap_all() -> List[int]:
 atexit.register(reap_all)
 
 
+# ------------------------------------------------------------- chip lease
+def worker_platform(spec: WorkerSpec) -> str:
+    """The jax platform a worker for `spec` will be pinned to: the
+    spec's own, else $JAX_PLATFORMS (both are "asked for by name"),
+    else whatever this host hands a JAX process — which initialises
+    this launcher's backend, so on a chip machine an unnamed platform
+    resolves to the chip AND finds the launcher holding it."""
+    name = spec.platform or os.environ.get("JAX_PLATFORMS", "")
+    name = name.split(",")[0].strip().lower()
+    if name:
+        return name
+    import jax
+
+    return jax.default_backend()
+
+
+def _chip_holder() -> Optional[str]:
+    """Who already holds this host's accelerator, or None. A chip
+    belongs to one process at a time: a launcher that has initialised
+    a non-CPU jax backend holds every local chip, and so does each
+    live worker pinned to one (no per-chip visibility split exists
+    here — ROADMAP S7's in-process replicas are the on-chip design)."""
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        import jax
+
+        if jax.default_backend() != "cpu":
+            return (f"this launcher process (pid {os.getpid()}, jax "
+                    f"backend {jax.default_backend()!r} initialised)")
+    with _CHILDREN_LOCK:
+        held = [(pid, plat) for pid, plat in _CHILD_PLATFORM.items()
+                if plat != "cpu" and _CHILDREN[pid].poll() is None]
+    if held:
+        return f"worker pid {held[0][0]} (platform {held[0][1]!r})"
+    return None
+
+
 # ---------------------------------------------------------------- spawning
 class SpawnedWorker:
     """One live worker process attempt: Popen + ready info + RPC client."""
@@ -132,6 +174,7 @@ class SpawnedWorker:
         self.pid = proc.pid
         self.rpc_port = ready["rpc_port"]
         self.telemetry_port = ready["telemetry_port"]
+        self.platform = ready["platform"]
         self.client = client
         self.log_path = log_path
         self._spec_path = spec_path
@@ -168,7 +211,25 @@ def spawn_worker(spec: WorkerSpec, *, log_dir: Optional[str] = None,
     """Spawn one worker process and block until it is READY and
     answering pings (raises RuntimeError with the log tail otherwise).
     stdout/stderr go to a LOG FILE, not a pipe — a chatty worker can
-    never deadlock against a parent that stopped reading."""
+    never deadlock against a parent that stopped reading.
+
+    The child is PINNED to one jax platform (`worker_platform`) through
+    its environment, so it can never fall back to another device on its
+    own; a worker that needs a chip somebody already holds is refused
+    here, before it boots, instead of quietly serving from the CPU
+    beside a launcher on the TPU."""
+    platform = worker_platform(spec)
+    if platform != "cpu":
+        holder = _chip_holder()
+        if holder is not None:
+            raise RuntimeError(
+                f"worker {spec.replica} needs the {platform!r} "
+                f"accelerator, but a chip belongs to one process at a "
+                f"time and {holder} holds it. Run the fleet on the CPU "
+                f"by name (JAX_PLATFORMS=cpu, or WorkerSpec.platform="
+                f"'cpu'), or use in-process replicas (--replicas) on "
+                f"the chip."
+            )
     log_dir = log_dir or tempfile.mkdtemp(prefix="ddp_worker_")
     os.makedirs(log_dir, exist_ok=True)
     fd, spec_path = tempfile.mkstemp(
@@ -185,10 +246,11 @@ def spawn_worker(spec: WorkerSpec, *, log_dir: Optional[str] = None,
             [sys.executable, "-m", "ddp_practice_tpu.serve.worker",
              "--spec", "@" + spec_path],
             stdout=log_fh, stderr=subprocess.STDOUT,
+            env={**os.environ, "JAX_PLATFORMS": platform},
         )
     finally:
         log_fh.close()  # the child holds its own descriptor
-    _register_child(proc)
+    _register_child(proc, platform)
     ready = None
     deadline = time.monotonic() + ready_timeout_s
     while time.monotonic() < deadline:
@@ -205,6 +267,11 @@ def spawn_worker(spec: WorkerSpec, *, log_dir: Optional[str] = None,
         if proc.poll() is not None:
             break
         time.sleep(0.1)
+    why = "never became ready"
+    if ready is not None and ready["platform"] != platform:
+        why = (f"came up on platform {ready['platform']!r}, not the "
+               f"{platform!r} it was pinned to")
+        ready = None
     if ready is None:
         rc = proc.poll()
         tail = ""
@@ -224,7 +291,7 @@ def spawn_worker(spec: WorkerSpec, *, log_dir: Optional[str] = None,
             pass
         _unregister_child(proc.pid)
         raise RuntimeError(
-            f"worker {spec.replica} never became ready "
+            f"worker {spec.replica} {why} "
             f"(rc={rc}); log tail:\n{tail}"
         )
     client = RpcClient("127.0.0.1", ready["rpc_port"],

@@ -64,7 +64,10 @@ class WorkerSpec:
     rpc_port: int = 0           # 0 = ephemeral, reported in READY
     telemetry_port: int = 0
     warmup: bool = True
-    platform: str = "cpu"       # jax platform pin ("" = leave alone)
+    # jax platform pin; "" = $JAX_PLATFORMS, else the host's default
+    # (serve/supervisor.py worker_platform resolves and pins it — a
+    # worker never lands on a device nobody named)
+    platform: str = ""
     # fleet tracing: record this replica's prefill/decode_burst/queued/
     # request spans (utils/trace.py) and stream them back to the router
     # as batched `trace` push frames, where the TraceCollector merges
@@ -808,11 +811,16 @@ class WorkerServer:
         self.telemetry.close()
 
     def ready_line(self) -> str:
+        import jax
+
+        dev = jax.devices()[0]
         return READY_PREFIX + json.dumps({
             "pid": os.getpid(),
             "replica": self.spec.replica,
             "rpc_port": self.rpc.port,
             "telemetry_port": self.telemetry.port,
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
         })
 
 
@@ -826,10 +834,16 @@ def main(argv=None) -> int:
         with open(text[1:]) as f:
             text = f.read()
     spec = WorkerSpec.from_json(text)
+    import jax
+
+    from ddp_practice_tpu.utils.backend import enable_compile_cache
+
     if spec.platform:
-        # pin the platform BEFORE jax initializes a backend (the heavy
-        # imports all hide inside WorkerServer)
-        os.environ.setdefault("JAX_PLATFORMS", spec.platform)
+        # pin the platform BEFORE jax initializes a backend. Through
+        # the config, not the environment: the package import above
+        # already loaded jax, which reads $JAX_PLATFORMS only once.
+        jax.config.update("jax_platforms", spec.platform)
+    enable_compile_cache()
     server = WorkerServer(spec)
     # graceful SIGTERM: finish in-flight work, refuse new submits, exit
     # 0 once idle (handler only sets flags — never runs mid-burst)

@@ -30,6 +30,7 @@ from ddp_practice_tpu.parallel.mesh import batch_sharding, build_mesh, shard_sta
 from ddp_practice_tpu.parallel.ring import set_current_mesh
 from ddp_practice_tpu.parallel.sharding_rules import param_sharding_rules
 from ddp_practice_tpu.train.state import create_state, make_optimizer
+from ddp_practice_tpu.utils import backend
 from ddp_practice_tpu.utils.logging import get_logger, main_process_only
 from ddp_practice_tpu.utils.profiling import profile_region, step_annotation
 from ddp_practice_tpu.utils.timing import Timer
@@ -52,33 +53,10 @@ info0 = main_process_only(log.info)
 warn0 = main_process_only(log.warning)
 
 
-def _enable_compilation_cache(setting: str) -> None:
-    """Point XLA's persistent compilation cache somewhere durable so repeat
-    runs skip compile (the dominant cost of short runs: the parity
-    experiment drops 28.5 s -> 10.0 s warm, PARITY.md). The reference has
-    no equivalent — CUDA kernels arrive precompiled; XLA programs are
-    compiled per (program, shapes) and this cache is the TPU-native answer.
-    Idempotent; respects an explicit $JAX_COMPILATION_CACHE_DIR."""
-    if setting == "off":
-        return
-    import os
-
-    path = setting
-    if setting == "auto":
-        path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
-            os.path.expanduser("~"), ".cache", "ddp_practice_tpu", "xla"
-        )
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-    except (OSError, AttributeError) as e:  # unwritable dir: run uncached
-        log.warning("compilation cache disabled: %s", e)
-
-
 class Trainer:
     def __init__(self, config: TrainConfig):
         self.config = config
-        _enable_compilation_cache(config.compilation_cache)
+        backend.enable_compile_cache(config.compilation_cache)
         dist.initialize(
             config.coordinator_address, config.num_processes, config.process_id
         )
@@ -505,7 +483,7 @@ class Trainer:
         # (device threads join different run_ids). On the CPU dev platform,
         # serialize step dispatch; on TPU, keep async dispatch (collectives
         # ride ICI and overlap is the point).
-        self._serialize_steps = jax.default_backend() == "cpu"
+        self._serialize_steps = not backend.on_tpu()
         self._watchdog = None
         self._pending_save = None  # in-flight async checkpoint write
         self._metrics_fh = None

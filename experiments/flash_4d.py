@@ -10,7 +10,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-from ddp_practice_tpu.ops.pallas_compat import tpu_compiler_params
 from jax.experimental.pallas import tpu as pltpu
 
 sys.path.insert(0, "/root/repo")
@@ -73,7 +72,7 @@ def fwd4d(q, k, v, *, causal=True, block_q=512, block_k=1024):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")
         ),

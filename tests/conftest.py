@@ -35,13 +35,7 @@ import jax  # noqa: E402
 # conftest ran), so also override through the config system — effective any
 # time before backend initialization.
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # this jax build predates the jax_num_cpu_devices option — the
-    # XLA_FLAGS belt above is the only device-count lever, and it works
-    # as long as no plugin imported jax before this conftest ran
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
 
 import pytest  # noqa: E402
 

@@ -1,0 +1,191 @@
+"""No chip, no number: the entry points that measure or prove something
+about the TPU refuse to run where JAX finds none, and the helpers that
+place the compile cache and the worker processes say where they put them.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import pytest
+
+from ddp_practice_tpu.utils import backend
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(*argv):
+    return subprocess.run(
+        [sys.executable, *argv], cwd=ROOT, capture_output=True, text=True,
+        timeout=120, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+
+
+@pytest.mark.parametrize("args", [(), ("--chips", "4")], ids=["one", "four"])
+def test_chip_smoke_refuses_to_run_without_a_chip(args):
+    """Exit code non-zero, no result line, no phase (a phase prints a
+    JSON line and takes far longer than this test's timeout allows)."""
+    r = _run("chip_smoke.py", *args)
+    assert r.returncode != 0
+    assert r.stdout == ""
+    assert "no accelerator" in r.stderr and "nothing was run" in r.stderr
+
+
+def test_bench_refuses_to_run_without_a_chip():
+    r = _run("bench.py", "--models", "lm_decode")
+    assert r.returncode != 0
+    assert r.stdout == ""
+    assert "no TPU" in r.stderr
+
+
+class _FakeChip:
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+
+
+def test_bench_exits_nonzero_when_a_requested_model_fails(
+        monkeypatch, capsys):
+    """The line and the (partial) suite are still written, but a model
+    that failed fails the run — `n_errors` is not a field to overlook."""
+    import bench
+    from ddp_practice_tpu import benchmarks
+
+    def decode(name, *, batch_size, **kw):
+        if batch_size == 1:
+            raise RuntimeError("boom")
+        return {"model": name, "mode": "decode", "batch_size": batch_size,
+                "precision": "bf16", "n_chips": 1,
+                "device_kind": _FakeChip.device_kind,
+                "tokens_per_sec_per_chip": 1.0}
+
+    written = []
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeChip()])
+    monkeypatch.setattr(benchmarks, "bench_lm_decode", decode)
+    monkeypatch.setattr(bench, "_write_suite",
+                        lambda suite, partial: written.append(partial))
+    monkeypatch.setattr(backend, "enable_compile_cache", lambda *a: None)
+    assert bench.main(["--models", "lm_decode,lm_decode_bs1"]) == 1
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert line["n_errors"] == 1 and line["value"] == 1.0
+    assert written == [True]  # never over the recorded full suite
+    assert bench.main(["--models", "lm_decode"]) == 0
+
+
+def test_benchmarks_raise_on_an_unknown_device(monkeypatch):
+    from ddp_practice_tpu import benchmarks
+
+    with pytest.raises(RuntimeError, match="measure the TPU"):
+        benchmarks._chip()  # the CPU test backend
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeChip()])
+    assert benchmarks._chip() == ("TPU v5 lite", 197e12, 819e9)
+    monkeypatch.setattr(_FakeChip, "device_kind", "TPU v9 imaginary")
+    with pytest.raises(RuntimeError, match="peak tables"):
+        benchmarks._chip()
+
+
+@pytest.fixture
+def cache_config():
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_obeys_the_environment(monkeypatch, tmp_path,
+                                             cache_config):
+    """JAX_COMPILATION_CACHE_DIR set: that directory, and the code sets
+    no other. (JAX reads the variable itself, at import.)"""
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert backend.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == was
+
+
+def test_compile_cache_default_is_fixed_inside_the_checkout(monkeypatch,
+                                                            cache_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(ROOT, ".jax_compile_cache")
+    assert backend.enable_compile_cache() == want
+    assert backend.enable_compile_cache("auto") == want  # never a new name
+    assert jax.config.jax_compilation_cache_dir == want
+    ignored = subprocess.run(
+        ["git", "check-ignore", "-q", want], cwd=ROOT
+    ).returncode
+    assert ignored in (0, 128)  # 128: not a git checkout (the chip copy)
+
+
+def test_compile_cache_off_and_no_free_form_path(cache_config):
+    was = jax.config.jax_compilation_cache_dir
+    assert backend.enable_compile_cache("off") is None
+    with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+        backend.enable_compile_cache("/some/where")
+    assert jax.config.jax_compilation_cache_dir == was
+
+
+def test_one_call_site_sets_the_cache_directory():
+    hits = subprocess.run(
+        ["grep", "-rn", "--include=*.py", "jax_compilation_cache_dir",
+         "ddp_practice_tpu", "bench.py", "chip_smoke.py",
+         "__graft_entry__.py", "tools", "experiments"],
+        cwd=ROOT, capture_output=True, text=True,
+    ).stdout.splitlines()
+    assert len(hits) == 1 and "utils/backend.py" in hits[0], hits
+
+
+# ------------------------------------------------- the smoke's own phases
+TINY_TRAIN_ARGS = [
+    "--model", "lm_tiny", "--seq_len", "128", "--attn_impl", "flash",
+    "--pos_emb", "rope", "--precision", "bf16", "--optimizer", "adamw",
+    "--lr", "1e-3", "--dataset", "synthetic_tokens", "--log_every", "1",
+    "--synthetic_size", "40000", "--compile_cache", "off",
+]
+
+
+@pytest.fixture
+def smoke(monkeypatch):
+    """chip_smoke's phases at a tiny size, for the CPU: what they check
+    about the device (compiled kernels) is switched off, what they check
+    about the program (loss falls, checkpoint loads, tokens agree with
+    generate() and the float32 forward, shards sit where they should)
+    stays."""
+    sys.path.insert(0, ROOT)
+    import chip_smoke
+
+    monkeypatch.setattr(chip_smoke, "TRAIN_ARGS", TINY_TRAIN_ARGS)
+    return chip_smoke
+
+
+def _json_lines(capsys) -> list:
+    return [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith("{")]
+
+
+@pytest.mark.slow
+def test_one_chip_phases_rehearse_on_cpu(smoke, tmp_path, capsys):
+    clock = smoke.CompileClock()
+    ckpt = smoke.train_phase(
+        str(tmp_path), clock, seed=0, require_kernels=False,
+        size_args=("-b", "8", "-e", "2", "--max_steps", "12"),
+    )
+    smoke.serve_phase(
+        ckpt, clock, seed=0, prompt_lengths=(3, 5, 12, 9), max_new=48,
+        buckets=(8, 16), slot_len=128, require_kernels=False,
+    )
+    train, serve = _json_lines(capsys)
+    assert train["last3_mean"] < train["first3_mean"]
+    assert serve["tokens"] == 4 * 48 and serve["buckets_used"] == [8, 16]
+    with pytest.raises(smoke.SmokeFailed, match="Mosaic kernels"):
+        smoke.train_phase(str(tmp_path / "again"), clock, seed=0,
+                          size_args=("-b", "8", "-e", "1"))
+
+
+@pytest.mark.slow
+def test_mesh_phase_rehearses_on_virtual_devices(smoke, devices, tmp_path,
+                                                 capsys):
+    smoke.mesh_phase(str(tmp_path), smoke.CompileClock(), seed=0,
+                     require_kernels=False)
+    lines = _json_lines(capsys)
+    assert [ln["mesh_devices"] for ln in lines[:4]] == [
+        [0], list(range(8)), list(range(8)), list(range(8))]
+    assert max(lines[-1]["max_abs_loss_diff"].values()) <= smoke.LOSS_TOL

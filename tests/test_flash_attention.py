@@ -245,3 +245,46 @@ def test_fused_qkv_unpackable_falls_back():
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )
+
+
+@pytest.mark.parametrize("rope", [False, True])
+def test_flash_island_matches_unsharded(devices, rope):
+    """Under a registered mesh the flash call runs in a shard_map island
+    (parallel/ring.py kernel_island) — batch over 'data', heads over
+    'tensor' — because the TPU partitioner refuses a bare Mosaic kernel
+    (tests/test_tpu_compile.py). Here, on virtual devices: the island's
+    split is the same function. rope=False is the packed-QKV call site
+    in models/vit.py, whose split must be taken on the (3, h, hd) dims
+    (a split of the flat 3*h*hd dim would part q heads from their k, v);
+    rope=True is ops.attention's."""
+    from ddp_practice_tpu.config import MeshConfig
+    from ddp_practice_tpu.models.vit import SelfAttention
+    from ddp_practice_tpu.parallel.mesh import build_mesh
+    from ddp_practice_tpu.parallel.ring import set_current_mesh
+
+    attn = SelfAttention(num_heads=4, attn_impl="flash", causal=True,
+                         rope=rope)
+    x = jnp.asarray(
+        np.random.default_rng(5).normal(size=(4, 128, 256)), jnp.float32
+    )
+    variables = attn.init(jax.random.PRNGKey(0), x)
+
+    def loss(variables, x):
+        return jnp.sum(jnp.square(attn.apply(variables, x)))
+
+    want_y = attn.apply(variables, x)
+    want_g = jax.grad(loss)(variables, x)
+    set_current_mesh(
+        build_mesh(MeshConfig(data=2, tensor=2), devices=devices[:4])
+    )
+    got_y = jax.jit(attn.apply)(variables, x)
+    got_g = jax.jit(jax.grad(loss))(variables, x)
+    np.testing.assert_allclose(
+        np.asarray(got_y), np.asarray(want_y), rtol=2e-5, atol=2e-5
+    )
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4
+        ),
+        got_g, want_g,
+    )

@@ -43,22 +43,6 @@ def _models():
     return piped, seq
 
 
-def _partial_manual(fn, *args, **kwargs):
-    """Run a PARTIAL-manual shard_map composition (the pipeline island
-    manual over 'pipe'/'data' while GSPMD partitions the stage body over
-    the remaining axes). This image's old XLA cannot compile that —
-    "PartitionId instruction is not supported for SPMD partitioning"
-    (ROADMAP standing debt) — which is an environment limit, not a code
-    bug: skip on exactly that error, fail on anything else."""
-    try:
-        return fn(*args, **kwargs)
-    except Exception as e:
-        if "PartitionId" in str(e):
-            pytest.skip("old XLA: PartitionId unsupported under "
-                        "partial-manual SPMD partitioning")
-        raise
-
-
 @pytest.mark.fast
 def test_pipeline_forward_matches_sequential(pipe_mesh):
     piped, seq = _models()
@@ -137,7 +121,7 @@ def test_pipeline_composes_sequence_parallelism(devices, sp_impl):
         x = _images()
         variables = seq.init(jax.random.PRNGKey(0), x)
         want = seq.apply(variables, x)
-        got = _partial_manual(piped.apply, variables, x)
+        got = piped.apply(variables, x)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
         )
@@ -182,7 +166,7 @@ def test_pipeline_composes_tensor_parallelism_forward(tp_pipe_mesh):
     assert shard_shape[0] == qkv.shape[0] // 2  # pipe (stage dim)
     assert shard_shape[3] == qkv.shape[3] // 2  # tensor (heads dim)
 
-    got = _partial_manual(piped.apply, {"params": sharded_params}, x)
+    got = piped.apply({"params": sharded_params}, x)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5
     )
@@ -222,7 +206,7 @@ def test_pipeline_tensor_parallel_train_step(tp_pipe_mesh):
         "weight": jnp.ones((8,), jnp.float32),
     }
     before = np.asarray(jax.tree.leaves(state.params)[0])
-    state, metrics = _partial_manual(step, state, batch)
+    state, metrics = step(state, batch)
     assert np.isfinite(float(metrics["loss"]))
     after = np.asarray(jax.tree.leaves(state.params)[0])
     assert not np.allclose(before, after)

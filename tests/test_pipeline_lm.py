@@ -30,21 +30,6 @@ def _tokens(b=8, s=16, seed=0):
     return jnp.asarray(rng.integers(0, VOCAB, (b, s)), jnp.int32)
 
 
-def _partial_manual(fn, *args, **kwargs):
-    """Same contract as tests/test_pipeline.py: this image's old XLA
-    cannot compile a partial-manual shard_map (pipeline island x
-    GSPMD-automatic 'tensor' axis) — "PartitionId instruction is not
-    supported for SPMD partitioning" (ROADMAP standing debt). Skip on
-    exactly that environment limit, fail on anything else."""
-    try:
-        return fn(*args, **kwargs)
-    except Exception as e:
-        if "PartitionId" in str(e):
-            pytest.skip("old XLA: PartitionId unsupported under "
-                        "partial-manual SPMD partitioning")
-        raise
-
-
 @pytest.fixture()
 def pipe_mesh(devices):
     mesh = build_mesh(MeshConfig(data=2, pipe=4))
@@ -153,7 +138,7 @@ def test_1f1b_sharded_train_step(devices):
         batch = {"tokens": _tokens(B, S, seed=6)}
         before = np.asarray(jax.device_get(
             jax.tree.leaves(state.params)[0]))
-        state, metrics = _partial_manual(step, state, batch)
+        state, metrics = step(state, batch)
         assert np.isfinite(float(metrics["loss"]))
         after = np.asarray(jax.device_get(jax.tree.leaves(state.params)[0]))
         assert not np.allclose(before, after)
@@ -237,7 +222,7 @@ def test_pipelined_lm_sharded_train_step(devices):
         batch = {"tokens": _tokens(B, S, seed=3)}
         before = np.asarray(jax.device_get(
             jax.tree.leaves(state.params)[0]))
-        state, metrics = _partial_manual(step, state, batch)
+        state, metrics = step(state, batch)
         assert np.isfinite(float(metrics["loss"]))
         after = np.asarray(jax.device_get(jax.tree.leaves(state.params)[0]))
         assert not np.allclose(before, after)

@@ -1,0 +1,467 @@
+"""The main path's programs, compiled for a described TPU v5e — no chip.
+
+Interpret mode on CPU turns a Pallas kernel into ordinary XLA ops, so it
+never meets the TPU compiler: tilings it refuses, VMEM it does not have,
+or the partitioner's "Mosaic kernels cannot be automatically
+partitioned" all pass every CPU test and die on the first chip call.
+The TPU compiler is installed here and compiles for a chip that is
+DESCRIBED (`topologies.get_topology_desc`) and not attached, so each
+case lowers one program at real widths, steers the `backend.on_tpu()`
+gates to their compiled branch (in the test, not through an option of
+the program), and asserts the kernel is in the executable
+(`tpu_custom_call`). Nothing runs: a pass says the first chip call
+should not die in the compiler, never that results or times are right
+(chip_smoke.py says that, on the chip).
+
+Kernel cases are tier-1 (about a second each). Whole-step programs take
+10-20 s each and are `slow`.
+"""
+
+import functools
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+# libtpu guards the chip with /tmp/libtpu_lockfile, one process at a time.
+# No chip is touched here, and test processes run side by side.
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from ddp_practice_tpu.config import MeshConfig, PrecisionPolicy, TrainConfig
+from ddp_practice_tpu.models import create_model
+from ddp_practice_tpu.parallel.mesh import build_mesh, shard_state
+from ddp_practice_tpu.parallel.ring import set_current_mesh
+from ddp_practice_tpu.utils import backend
+
+# lm_base widths, the shape of the smoke and of bench.py's lm_long/lm_decode
+B, S, H, D = 8, 2048, 12, 64
+HD = H * D
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu here: nothing to ask
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+
+
+@pytest.fixture(autouse=True)
+def as_on_chip(monkeypatch):
+    """Take every gate's compiled branch, with the persistent cache off:
+    a compile for a described chip is written to the cache but cannot be
+    read back without one, and the next run would warn and recompile."""
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(device, tree):
+    """The tree's shapes, placed on a described device."""
+    one = SingleDeviceSharding(device)
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree
+    )
+
+
+def _compile(fn, *args, device, min_kernels=1):
+    """Lower `fn` for `device` from shapes alone and return the HLO text
+    (raises what the chip's compiler would raise)."""
+    text = jax.jit(fn).lower(*_on(device, args)).compile().as_text()
+    assert text.count("tpu_custom_call") >= min_kernels, (
+        "the program compiled without its Pallas kernel: it was "
+        "interpreted or replaced by the reference"
+    )
+    return text
+
+
+def _sds(shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# --------------------------------------------------------------- kernels
+def _flash(causal=True, grad=False, h=H, d=D):
+    from ddp_practice_tpu.ops.flash_attention import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=causal)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    x = _sds((B, S, h, d))
+    return (jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd), (x, x, x)
+
+
+def _flash_qkv():
+    from ddp_practice_tpu.ops.flash_attention import flash_attention_qkv
+
+    def loss(qkv):
+        return flash_attention_qkv(qkv, H, causal=True).astype(
+            jnp.float32).sum()
+
+    return jax.grad(loss), (_sds((B, S, 3 * HD)),)
+
+
+def _decode_packed(L, int8=False):
+    from ddp_practice_tpu.ops.decode_attention import decode_attention_packed
+
+    def step(q, k, v, cur, start, *scales):
+        ks, vs = scales if scales else (None, None)
+        return decode_attention_packed(
+            q, k, v, cur, start, n_heads=H, k_scale=ks, v_scale=vs
+        )
+
+    kv = _sds((B, L, HD), jnp.int8 if int8 else BF16)
+    args = (_sds((B, 1, HD)), kv, kv, _sds((), jnp.int32),
+            _sds((B,), jnp.int32))
+    if int8:
+        sc = _sds((B, H, L), jnp.float32)
+        args += (sc, sc)
+    return step, args
+
+
+def _paged(block, int8=False, with_start=True, slots=8, blocks_per_slot=32):
+    from ddp_practice_tpu.ops.decode_attention import paged_decode_attention
+
+    def step(q, k, v, table, lengths, start, *scales):
+        ks, vs = scales if scales else (None, None)
+        return paged_decode_attention(
+            q, k, v, table, lengths, start if with_start else None,
+            n_heads=H, k_scale=ks, v_scale=vs, impl="auto",
+        )
+
+    nb = 1 + slots * blocks_per_slot
+    pool = _sds((nb, block, HD), jnp.int8 if int8 else BF16)
+    args = (_sds((slots, 1, HD)), pool, pool,
+            _sds((slots, blocks_per_slot), jnp.int32),
+            _sds((slots,), jnp.int32), _sds((slots,), jnp.int32))
+    if int8:
+        sc = _sds((nb, H, block), jnp.float32)
+        args += (sc, sc)
+    return step, args
+
+
+def _fused_encoder(causal):
+    """vit_tiny's layer (d 192, 3 heads, s 64) and lm_tiny_fused's
+    (d 256, 4 heads, s 256, causal), forward and backward kernels."""
+    from ddp_practice_tpu.models.vit import EncoderBlock
+    from ddp_practice_tpu.ops.fused_encoder import fused_encoder_layer
+
+    imgs, s, d, heads, mlp = (
+        (256, 256, 256, 4, 1024) if causal else (1024, 64, 192, 3, 768)
+    )
+    block = EncoderBlock(heads, mlp)
+    params = jax.eval_shape(
+        lambda: block.init(jax.random.PRNGKey(0), jnp.zeros((1, s, d)))
+    )["params"]
+
+    def loss(x, p):
+        return fused_encoder_layer(
+            x, p, num_heads=heads, causal=causal
+        ).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1)), (_sds((imgs, s, d)), params)
+
+
+def _moe_sorted():
+    """ops/moe.py impl="sorted": megablox gmm forward, gmm + tgmm
+    backward, at lm_moe's dims (d 768, mlp 3072, 8 experts, top-2) over
+    one batch of 8 x 2048 tokens. The row count is part of the case:
+    the tiling jax 0.9 refused at 8 rows (18.58 MB of scoped VMEM)
+    compiles at 2."""
+    from ddp_practice_tpu.ops.moe import MoEMlp
+
+    moe = MoEMlp(num_experts=8, top_k=2, mlp_dim=3072, impl="sorted",
+                 dtype=BF16)
+    x = _sds((B, S, 768))
+    variables = jax.eval_shape(
+        lambda: moe.init(jax.random.PRNGKey(0), jnp.zeros((1, 16, 768), BF16))
+    )
+
+    def loss(x, variables):
+        y, _ = moe.apply(variables, x, mutable=["intermediates",
+                                                "batch_stats"])
+        return y.astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1)), (x, variables)
+
+
+KERNELS = {
+    "flash_fwd": functools.partial(_flash, grad=False),
+    "flash_fwd_bwd": functools.partial(_flash, grad=True),
+    "flash_qkv_fwd_bwd": _flash_qkv,
+    # h 5 x d 48 does not pack into 128 lanes: the folded (b*h, s, d) path
+    "flash_folded_fwd_bwd": functools.partial(_flash, grad=True, h=5, d=48),
+    "decode_single_block_L640": functools.partial(_decode_packed, 640),
+    "decode_multi_block_L2048": functools.partial(_decode_packed, 2048),
+    "decode_int8_L1024": functools.partial(_decode_packed, 1024, int8=True),
+    "decode_int8_L2048": functools.partial(_decode_packed, 2048, int8=True),
+    "paged_b16": functools.partial(_paged, 16),
+    "paged_b16_no_start": functools.partial(_paged, 16, with_start=False),
+    "paged_int8_b16": functools.partial(_paged, 16, int8=True),
+    "paged_int8_b32": functools.partial(_paged, 32, int8=True),
+    "fused_encoder_vit_tiny": functools.partial(_fused_encoder, False),
+    "fused_encoder_lm_tiny_causal": functools.partial(_fused_encoder, True),
+    "moe_sorted_gmm_tgmm": _moe_sorted,
+}
+
+
+# the MoE case spends 12 s in the sort around its kernels: `slow` by
+# pytest.ini's own rule
+@pytest.mark.parametrize("name", [
+    pytest.param(n, marks=pytest.mark.slow) if n.startswith("moe") else n
+    for n in sorted(KERNELS)
+])
+def test_kernel_compiles_for_v5e(topo, name):
+    fn, args = KERNELS[name]()
+    _compile(fn, *args, device=topo.devices[0])
+
+
+def test_flash_compiles_sharded_over_four_devices(topo):
+    """The refusal the first rehearsal found, now a guard: a flash call
+    directly under GSPMD `jit` with its batch sharded over data=4 is
+    "Mosaic kernels cannot be automatically partitioned". Through
+    ops.attention the kernel runs in a shard_map island, so each device
+    gets its own 2 of the 8 rows and nothing is gathered."""
+    from ddp_practice_tpu.ops.attention import dot_product_attention
+
+    mesh = build_mesh(MeshConfig(), devices=topo.devices)
+    assert mesh.devices.size == 4
+    set_current_mesh(mesh)
+    sharded = NamedSharding(mesh, P(MeshConfig.AXIS_DATA))
+    x = jax.ShapeDtypeStruct((B, S, H, D), BF16, sharding=sharded)
+
+    def loss(q, k, v):
+        return dot_product_attention(
+            q, k, v, causal=True, impl="flash"
+        ).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 3, len(calls)  # fwd, dk/dv, dq
+    per_device = f"bf16[{B // 4},{S},{HD}]"
+    assert all(per_device in ln for ln in calls), calls[0][:300]
+    assert "all-gather" not in text
+
+
+def test_forced_fused_refuses_a_mesh_at_trace_time(topo):
+    from ddp_practice_tpu.models.vit import EncoderBlock
+
+    block = EncoderBlock(3, 768, fused=True)
+    x = jnp.zeros((8, 64, 192))
+    variables = block.init(jax.random.PRNGKey(0), x)
+    set_current_mesh(build_mesh(MeshConfig(), devices=topo.devices))
+    with pytest.raises(ValueError, match="4-device mesh"):
+        jax.eval_shape(block.apply, variables, x)
+
+
+# ------------------------------------------------------------ whole steps
+def _abstract_trainer(topo, *, model, mesh_cfg, model_kwargs, sample,
+                      fsdp=False):
+    """What Trainer.__init__ builds, from shapes alone on described
+    devices: (mesh, model, tx, abstract state, state shardings)."""
+    from ddp_practice_tpu.parallel.fsdp import fsdp_rules
+    from ddp_practice_tpu.parallel.sharding_rules import param_sharding_rules
+    from ddp_practice_tpu.train.state import create_state, make_optimizer
+
+    mesh = build_mesh(mesh_cfg, devices=topo.devices)
+    set_current_mesh(mesh)
+    net = create_model(model, policy=PrecisionPolicy.bf16(), **model_kwargs)
+    tx = make_optimizer(
+        TrainConfig(model=model, optimizer="adamw", learning_rate=3e-4), 14
+    )
+    abstract = jax.eval_shape(
+        lambda r: create_state(net, tx, rng=r, sample_input=sample),
+        jax.random.PRNGKey(0),
+    )
+    rules = param_sharding_rules(model)
+    if fsdp:
+        rules = fsdp_rules(mesh.shape[MeshConfig.AXIS_DATA], rules)
+    return mesh, net, tx, abstract, shard_state(abstract, mesh, rules)
+
+
+def _collectives(text):
+    return {
+        k: len(re.findall(rf"\b{k}(?:-start)?\(", text))
+        for k in ("all-reduce", "all-gather", "reduce-scatter")
+    }
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("layout", ["data4", "data2_tensor2", "fsdp4"])
+@pytest.mark.parametrize("pos_emb", ["rope", "learned"])
+def test_lm_base_flash_step_compiles_on_the_mesh(topo, layout, pos_emb):
+    """chip_smoke.py --chips 4's program: lm_base (depth cut to 2 for
+    compile time), flash, global batch 8 at s 2048 through the resident
+    train step Trainer uses, on the described 2x2. rope takes the sliced
+    flash path, learned positions the packed-QKV one."""
+    from ddp_practice_tpu.train.steps import make_resident_lm_train_step
+
+    mesh_cfg = (MeshConfig(data=2, tensor=2) if layout == "data2_tensor2"
+                else MeshConfig())
+    mesh, net, tx, abstract, shardings = _abstract_trainer(
+        topo, model="lm_base", mesh_cfg=mesh_cfg, fsdp=layout == "fsdp4",
+        model_kwargs=dict(vocab_size=64, max_len=S, attn_impl="flash",
+                          pos_emb=pos_emb, depth=2),
+        sample=jnp.zeros((B, S), jnp.int32),
+    )
+    step = make_resident_lm_train_step(
+        net, tx, window=S + 1, seed=0, mesh=mesh, state_shardings=shardings
+    )
+    text = step.lower(
+        abstract, {"tokens": _sds((262272,), jnp.int32)},
+        _sds((1, B), jnp.int32),
+    ).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(calls) == 6, len(calls)  # (fwd, dk/dv, dq) x 2 layers
+    dp, tp = mesh.shape["data"], mesh.shape["tensor"]
+    width = (3 * HD if pos_emb == "learned" else HD) // tp
+    per_device = f"bf16[{B // dp},{S},{width}]"
+    assert all(per_device in ln for ln in calls), calls[0][:300]
+    colls = _collectives(text)
+    assert colls["all-reduce"] >= 1, colls  # the gradient all-reduce
+    if layout == "data4":
+        assert colls["all-gather"] == 0, colls  # nothing is gathered
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name", ["vit_tiny", "lm_tiny_fused"])
+def test_fused_train_step_compiles_on_one_chip(topo, name):
+    """The WHOLE jitted step (optimizer, steps_per_call scan, donation)
+    around the fused encoder kernels, at bench.py's shapes: the 17 MB
+    scoped-VMEM window of ops/fused_encoder.py was found on an older
+    compiler inside a real step, where the lone kernel fit and the step
+    did not. vit_tiny takes the kernels by default (fused="auto" on a
+    one-device TPU mesh)."""
+    from ddp_practice_tpu.train import steps
+
+    one = MeshConfig(data=1)
+    if name == "vit_tiny":
+        b, k = 1024, 32
+        mesh, net, tx, abstract, shardings = _abstract_trainer(
+            topo, model="vit_tiny", mesh_cfg=one,
+            model_kwargs=dict(num_classes=10, axis_name=None),
+            sample=jnp.zeros((b, 32, 32, 3), jnp.float32),
+        )
+        factory, batch = steps.make_chunked_train_step, {
+            "image": _sds((k, b, 32, 32, 3), jnp.float32),
+            "label": _sds((k, b), jnp.int32),
+            "weight": _sds((k, b), jnp.float32),
+        }
+        kernels = 2 * 12  # fwd + bwd per layer
+    else:
+        b, k, s = 256, 16, 256
+        mesh, net, tx, abstract, shardings = _abstract_trainer(
+            topo, model="lm_tiny", mesh_cfg=one,
+            model_kwargs=dict(vocab_size=64, max_len=s, num_heads=4,
+                              fused=True),
+            sample=jnp.zeros((b, s), jnp.int32),
+        )
+        factory, batch = steps.make_chunked_lm_train_step, {
+            "tokens": _sds((k, b, s + 1), jnp.int32),
+        }
+        kernels = 2 * net.depth
+    from ddp_practice_tpu.parallel.mesh import batch_sharding
+
+    step = factory(
+        net, tx, num_steps=k, mesh=mesh, state_shardings=shardings,
+        batch_shardings=batch_sharding(mesh),
+    )
+    text = step.lower(abstract, batch).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+
+
+@pytest.mark.slow
+def test_paged_engine_programs_compile_with_donation(topo):
+    """PagedEngine's prefill, decode-burst and copy-on-write programs at
+    lm_base widths, as chip_smoke.py builds them. Donation is on only on
+    TPU (serve/engine.py _decode_donate), so no CPU test ever lowered
+    it; the decode burst must hold the compiled paged kernel, once per
+    layer."""
+    from ddp_practice_tpu.serve.engine import EngineConfig, PagedEngine
+
+    model = create_model(
+        "lm_base", policy=PrecisionPolicy.bf16(), vocab_size=64,
+        max_len=S, pos_emb="rope",
+    )
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))
+    )["params"]
+    # the engine allocates its pool where jax.devices() says (the CPU);
+    # only its traced programs are lowered for the described chip
+    engine = PagedEngine(model, params, EngineConfig(
+        max_slots=8, max_len=512, prompt_buckets=(32, 128), block_size=16,
+        decode_burst=8,
+    ))
+    slots, mb = 8, engine.max_blocks_per_slot
+    i32 = jnp.int32
+    logits = _sds((slots, model.vocab_size), model.dtype)
+    on_chip = functools.partial(_on, topo.devices[0])
+    for w in engine.buckets:
+        engine._prefill_jit.lower(*on_chip((
+            params, engine._cache, logits, _sds((1, w), i32), _sds((), i32),
+            _sds((-(-w // 16),), i32), _sds((), i32),
+        ))).compile()
+    # the engine's own jits of the two pool-rewriting programs: built
+    # with donate_argnums because on_tpu() said so
+    decode = engine._decode_jit.lower(*on_chip((
+        params, engine._cache, logits, _sds((slots,), i32),
+        _sds((slots,), jnp.bool_), _sds((slots, 2), jnp.uint32),
+        _sds((slots, mb), i32), _sds((slots,), i32))), None,
+    ).compile()
+    assert decode.as_text().count(
+        'custom_call_target="tpu_custom_call"') == model.depth
+    cow = engine._cow_jit.lower(*on_chip((
+        engine._cache, _sds((), i32), _sds((), i32)))).compile()
+    for compiled in (decode, cow):
+        # donation took: the pool's bytes are aliased, not copied
+        assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
+@pytest.mark.slow
+def test_paged_prefill_compiles_at_s2048(topo):
+    """models/vit.py's paged PREFILL (s > 1: scatter through the page
+    table, gather_pages, dense masked attention) at a 2048-token chunk —
+    the span a 16k-token prompt's last chunk attends is what bounds it,
+    here 4096 positions per slot."""
+    from ddp_practice_tpu.inference import decode_apply
+    from ddp_practice_tpu.serve.kv_pages import make_paged_cache
+
+    model = create_model(
+        "lm_base", policy=PrecisionPolicy.bf16(), vocab_size=64,
+        max_len=S, pos_emb="rope", depth=2,
+    )
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))
+    )["params"]
+    pool = jax.eval_shape(lambda: make_paged_cache(model, 1 + 256, 16))
+
+    def prefill(params, pool, tokens, table, pos0):
+        return decode_apply(model, params, pool, tokens,
+                            page_table=table, kv_lengths=pos0)
+
+    _compile(
+        prefill, params, pool, _sds((1, S), jnp.int32),
+        _sds((1, 256), jnp.int32), _sds((1,), jnp.int32),
+        device=topo.devices[0], min_kernels=0,
+    )

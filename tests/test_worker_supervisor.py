@@ -636,3 +636,38 @@ def test_fleet_targets_reports_draining_and_kv():
     sup.shrink(0)
     t = fleet_targets(sup, [h])
     assert t[0]["draining"] is True
+
+
+# ------------------------------------------------------------ chip lease
+def test_worker_platform_is_named_or_resolved(monkeypatch):
+    """The launcher pins every child to ONE platform: the spec's, else
+    $JAX_PLATFORMS (conftest exports cpu, so the fleet tests stay on the
+    CPU by name), else what this host gives a JAX process."""
+    from ddp_practice_tpu.serve import supervisor
+
+    assert supervisor.worker_platform(WorkerSpec()) == "cpu"
+    assert supervisor.worker_platform(WorkerSpec(platform="tpu")) == "tpu"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert supervisor.worker_platform(WorkerSpec()) == "cpu"  # this host
+    # a CPU launcher holds no chip
+    assert supervisor._chip_holder() is None
+
+
+def test_spawn_refuses_a_chip_somebody_holds(monkeypatch):
+    """A chip belongs to one process: a worker that needs the
+    accelerator while the launcher (or another worker) holds it is
+    refused before it boots — it must never come up on the CPU beside a
+    launcher on the TPU and be measured against it."""
+    import subprocess
+
+    from ddp_practice_tpu.serve import supervisor
+
+    def no_popen(*a, **kw):
+        raise AssertionError("the worker process was started")
+
+    monkeypatch.setattr(subprocess, "Popen", no_popen)
+    monkeypatch.setattr(
+        supervisor, "_chip_holder", lambda: "this launcher process (pid 1)"
+    )
+    with pytest.raises(RuntimeError, match="one process at a time"):
+        supervisor.spawn_worker(WorkerSpec(platform="tpu"))
