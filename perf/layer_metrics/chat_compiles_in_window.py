@@ -1,0 +1,14 @@
+"""Executables JAX built (compiled, or loaded from the persistent cache) inside
+the measured window; 0 is expected.
+"""
+
+from perf.lib import readers
+
+UNIT = "count"
+LAYER = "entry"
+SOURCE = "program_counter"
+MOVES = "ttft_p95_ms"
+
+
+def read(obs: dict):
+    return readers.compiles_in_window(obs)
