@@ -250,6 +250,7 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
                            seq_k - seq_q if causal else 0)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -394,6 +395,7 @@ def _flash_bwd(q, k, v, do, lse, delta, *, causal, block_q, block_k,
     )
     dk, dv = pl.pallas_call(
         dkdv,
+        name="flash_bwd_dkv",
         grid=(bh, seq_k // block_k, seq_q // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, d), qo_map),
@@ -428,6 +430,7 @@ def _flash_bwd(q, k, v, do, lse, delta, *, causal, block_q, block_k,
     kv_map = _kv_index_map(causal, block_q, block_k, offset)
     dq = pl.pallas_call(
         dqk,
+        name="flash_bwd_dq",
         grid=(bh, seq_q // block_q, seq_k // block_k),
         in_specs=[
             pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -575,6 +578,7 @@ def _flash_fwd_packed(qf, kf, vf, *, n_heads, causal, block_q, block_k,
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd_packed",
         grid=(b, n_packs, seq_q // block_q, seq_k // block_k),
         in_specs=[
             pl.BlockSpec((None, block_q, w), lambda b_, g, i, j: (b_, i, g)),
@@ -759,6 +763,7 @@ def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
     )
     dk, dv = pl.pallas_call(
         dkdv,
+        name="flash_bwd_dkv_packed",
         grid=(b, n_packs, seq_k // block_k, seq_q // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, w), qo_map),
@@ -802,6 +807,7 @@ def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
     )
     dq = pl.pallas_call(
         dqk,
+        name="flash_bwd_dq_packed",
         grid=(b, n_packs, seq_q // block_q, seq_k // block_k),
         in_specs=[
             pl.BlockSpec((None, block_q, w), lambda b_, g, i, j: (b_, i, g)),

@@ -283,11 +283,24 @@ class _EngineBase:
     token-granular `step()` veneer over `step_burst`, the
     two-jitted-programs observable (`self._prefill_jit` /
     `self._decode_jit` set by each subclass __init__), and the optional
-    tracer (`set_tracer`): per-dispatch prefill / decode-burst lane
-    spans plus `jax.profiler.TraceAnnotation` regions NAMED with the
-    dispatch's trace-ids, so a device trace (utils/profiling.py ->
-    utils/xprof.py) lines up with the host spans. tracer=None (default)
-    keeps the dispatch path annotation-free."""
+    tracer (`set_tracer`): per-dispatch `prefill` / `decode_burst` /
+    `verify` lane spans, each split into what the host prepares
+    (`prefill_host`, `burst_plan`), the jitted call (`prefill_dispatch`,
+    `burst_dispatch`) and the wait for the device (`burst_readback`).
+    `set_tracer` also hands the recorder `jax.profiler.TraceAnnotation`
+    (TraceRecorder.set_annotate), so while a profiler session is open
+    every one of these spans is mirrored on the profiler's host line
+    under a FIXED name (`serve:prefill`, `serve:burst_dispatch`, ...;
+    request ids are span attrs, never names). tracer=None (default)
+    keeps the dispatch path free of spans and annotations alike.
+
+    The jitted methods' names are a contract too: a device trace shows
+    each program as `jit_<method name>` ("XLA Modules" line), and the
+    benchmark's readers find the prefill and decode programs by the
+    substrings `prefill_admit`, `prefix_prefill`, `decode_burst` and
+    `verify` (PERF.md §3; pinned by tests/test_span_tree.py). Rename
+    `_prefill_admit` / `_prefix_prefill` / `_decode_burst` / `_verify`
+    only together with those readers."""
 
     # set by each subclass __init__ via set_tracer defaults
     tracer = None
@@ -307,11 +320,37 @@ class _EngineBase:
         trace.label_replica)."""
         self.tracer = tracer
         self.replica = replica
+        if tracer is not None:
+            tracer.set_annotate(jax.profiler.TraceAnnotation, "serve")
 
-    def _dispatch_ids(self) -> list:
-        """Active slots' trace-ids in slot order (decode annotation)."""
-        return [self._slot_trace.get(s, f"slot{s}")
-                for s in np.flatnonzero(self._active)]
+    def _span(self, name: str, tid: int = ENGINE_LANE, **attrs):
+        """One of this engine's lane spans (callers have tested
+        `tracer.enabled`). Engine-lane spans name no request, so under
+        head sampling they ride only while a SAMPLED request is in
+        flight (`sampled_only`) — otherwise an idle 1%-sampled fleet
+        would still record every burst and the plane would never
+        shrink; a slot-lane span carries its request's trace_id and
+        follows that request's own verdict."""
+        return self.tracer.span(name, pid=self.replica, tid=tid,
+                                sampled_only="trace_id" not in attrs,
+                                **attrs)
+
+    def _prefill_spans(self, name: str, slot: int, trace_id: str,
+                       **attrs) -> tuple:
+        """(`name`, its `prefill_host` half, its `prefill_dispatch`
+        half) on the slot's lane, all under the request's trace_id."""
+        lane = SLOT_LANE_BASE + slot
+        return (self._span(name, lane, trace_id=trace_id, slot=slot,
+                           **attrs),
+                self._span("prefill_host", lane, trace_id=trace_id),
+                self._span("prefill_dispatch", lane, trace_id=trace_id))
+
+    def _burst_spans(self, name: str, **attrs) -> tuple:
+        """(`name`, its `burst_dispatch` half, its `burst_readback`
+        half) on the engine lane."""
+        return (self._span(name, active=int(np.count_nonzero(self._active)),
+                           **attrs),
+                self._span("burst_dispatch"), self._span("burst_readback"))
 
     def bucket_for(self, prompt_len: int) -> int:
         """Smallest bucket width holding `prompt_len` (raises if none)."""
@@ -597,28 +636,28 @@ class SlotEngine(_EngineBase):
             raise
         start = self.cursor - w
         assert start >= 0, (self.cursor, w)  # cursor >= base >= every bucket
-        padded = np.full((1, w), self.config.pad_id, np.int32)
-        padded[0, w - p:] = np.asarray(prompt, np.int32)
         tr = self.tracer
         if tr is not None and tr.enabled:
             tid = trace_id or f"slot{slot}"
             self._slot_trace[slot] = tid
-            span = tr.span("prefill", trace_id=tid, pid=self.replica,
-                           tid=SLOT_LANE_BASE + slot, bucket=w,
-                           prompt_len=p, slot=slot)
-            ann = jax.profiler.TraceAnnotation(f"serve:prefill:{tid}")
+            span, host, disp = self._prefill_spans(
+                "prefill", slot, tid, bucket=w, prompt_len=p)
         else:
-            span = ann = _NULL
-        with span, ann:
-            (self._cache, self._last_logits,
-             self._attn_starts) = self._prefill_jit(
-                self.params, self._cache, self._last_logits,
-                self._attn_starts,
-                jnp.asarray(padded), jnp.int32(start),
-                jnp.int32(self.cursor - p), jnp.int32(slot),
-            )
-            _await_dispatch(self._cache, self._last_logits,
-                            self._attn_starts)
+            span = host = disp = _NULL
+        with span:
+            with host:
+                padded = np.full((1, w), self.config.pad_id, np.int32)
+                padded[0, w - p:] = np.asarray(prompt, np.int32)
+                args = (jnp.asarray(padded), jnp.int32(start),
+                        jnp.int32(self.cursor - p), jnp.int32(slot))
+            with disp:
+                (self._cache, self._last_logits,
+                 self._attn_starts) = self._prefill_jit(
+                    self.params, self._cache, self._last_logits,
+                    self._attn_starts, *args,
+                )
+                _await_dispatch(self._cache, self._last_logits,
+                                self._attn_starts)
         # keyed by the REQUEST's seed alone (not the slot), so a
         # request's sampled tokens are independent of where admission
         # happened to place it — batch composition stays invisible
@@ -641,31 +680,24 @@ class SlotEngine(_EngineBase):
             )
         tr = self.tracer
         if tr is not None and tr.enabled:
-            ids = self._dispatch_ids()
-            # sampled_only: the burst span names no trace_id (it is a
-            # shared engine-lane record), so under head sampling it is
-            # kept only while some SAMPLED request is in flight —
-            # otherwise an idle 1%-sampled fleet would still record a
-            # span per burst and the plane would never shrink
-            span = tr.span("decode_burst", pid=self.replica,
-                           tid=ENGINE_LANE, burst=k, active=len(ids),
-                           cursor=self.cursor, sampled_only=True)
-            ann = jax.profiler.TraceAnnotation(
-                "serve:decode[" + ",".join(ids) + "]"
-            )
+            span, disp, read = self._burst_spans(
+                "decode_burst", burst=k, cursor=self.cursor)
         else:
-            span = ann = _NULL
-        with span, ann:
-            (self._cache, self._last_logits, toks,
-             self._keys, finite) = self._decode_jit(
-                self.params, self._cache, self._last_logits,
-                self._attn_starts,
-                jnp.asarray(self._active), self._keys,
-                self._sampling_args(),
-            )
-            _await_dispatch(self._cache, self._last_logits, self._keys)
+            span = disp = read = _NULL
+        with span:
+            with disp:
+                (self._cache, self._last_logits, toks,
+                 self._keys, finite) = self._decode_jit(
+                    self.params, self._cache, self._last_logits,
+                    self._attn_starts,
+                    jnp.asarray(self._active), self._keys,
+                    self._sampling_args(),
+                )
+                _await_dispatch(self._cache, self._last_logits,
+                                self._keys)
             self.cursor += k
-            toks, finite = jax.device_get((toks, finite))
+            with read:  # the host waits for the device here
+                toks, finite = jax.device_get((toks, finite))
         self.burst_seq += 1
         self.last_burst_active = int(np.count_nonzero(self._active))
         # (K, max_slots) bool: False rows mark slots whose token this
@@ -1405,27 +1437,28 @@ class PagedEngine(_EngineBase):
         if tr is not None and tr.enabled:
             tid = trace_id or f"slot{slot}"
             self._slot_trace[slot] = tid
-            span = tr.span("prefill", trace_id=tid, pid=self.replica,
-                           tid=SLOT_LANE_BASE + slot, bucket=w,
-                           prompt_len=p, slot=slot, blocks=n_table,
-                           prefix_hit=matched)
-            ann = jax.profiler.TraceAnnotation(f"serve:prefill:{tid}")
+            span, host, disp = self._prefill_spans(
+                "prefill", slot, tid, bucket=w, prompt_len=p,
+                blocks=n_table, prefix_hit=matched)
         else:
-            span = ann = _NULL
+            span = host = disp = _NULL
         if self.radix is None:
             # plain path, unchanged since PR 3: LEFT-padded scratch
             # prefill + block scatter
             self._len[slot] = w
             self._attn[slot] = w - p
-            padded = np.full((1, w), self.config.pad_id, np.int32)
-            padded[0, w - p:] = np.asarray(prompt, np.int32)
-            with span, ann:
-                self._cache, self._last_logits = self._prefill_jit(
-                    self.params, self._cache, self._last_logits,
-                    jnp.asarray(padded), jnp.int32(w - p),
-                    jnp.asarray(ids, jnp.int32), jnp.int32(slot),
-                )
-                _await_dispatch(self._cache, self._last_logits)
+            with span:
+                with host:
+                    padded = np.full((1, w), self.config.pad_id, np.int32)
+                    padded[0, w - p:] = np.asarray(prompt, np.int32)
+                    args = (jnp.asarray(padded), jnp.int32(w - p),
+                            jnp.asarray(ids, jnp.int32), jnp.int32(slot))
+                with disp:
+                    self._cache, self._last_logits = self._prefill_jit(
+                        self.params, self._cache, self._last_logits,
+                        *args,
+                    )
+                    _await_dispatch(self._cache, self._last_logits)
         else:
             # prefix path: canonical positions, RIGHT-padded suffix
             # appended at `matched` through the page table; the hit's
@@ -1433,17 +1466,20 @@ class PagedEngine(_EngineBase):
             sl = p - matched
             self._len[slot] = matched + sl
             self._attn[slot] = 0
-            padded = np.full((1, w), self.config.pad_id, np.int32)
-            padded[0, :sl] = np.asarray(prompt[matched:], np.int32)
-            with span, ann:
-                self._cache, self._last_logits = self._prefix_jit(
-                    self.params, self._cache, self._last_logits,
-                    jnp.asarray(padded), jnp.int32(matched),
-                    jnp.int32(sl),
-                    jnp.asarray(self._pt[slot:slot + 1]),
-                    jnp.int32(slot),
-                )
-                _await_dispatch(self._cache, self._last_logits)
+            with span:
+                with host:
+                    padded = np.full((1, w), self.config.pad_id, np.int32)
+                    padded[0, :sl] = np.asarray(prompt[matched:], np.int32)
+                    args = (jnp.asarray(padded), jnp.int32(matched),
+                            jnp.int32(sl),
+                            jnp.asarray(self._pt[slot:slot + 1]),
+                            jnp.int32(slot))
+                with disp:
+                    self._cache, self._last_logits = self._prefix_jit(
+                        self.params, self._cache, self._last_logits,
+                        *args,
+                    )
+                    _await_dispatch(self._cache, self._last_logits)
             # publish this prompt's own full blocks for future hits
             # (already-cached chunks keep their existing node)
             n_full = p // bs
@@ -1509,26 +1545,28 @@ class PagedEngine(_EngineBase):
             ids = self._acquire_decode(grow, protect=slot)
             self._pt[slot, self._nblk[slot]:need] = ids
             self._nblk[slot] = need
-        padded = np.full((1, w), self.config.pad_id, np.int32)
-        padded[0, :take] = np.asarray(prompt[done:done + take], np.int32)
         tr = self.tracer
         if tr is not None and tr.enabled:
-            tid = self._slot_trace.get(slot, f"slot{slot}")
-            span = tr.span("prefill_chunk", trace_id=tid,
-                           pid=self.replica, tid=SLOT_LANE_BASE + slot,
-                           bucket=w, pos0=done, take=take, slot=slot)
-            ann = jax.profiler.TraceAnnotation(
-                f"serve:prefill_chunk:{tid}"
-            )
+            span, host, disp = self._prefill_spans(
+                "prefill_chunk", slot,
+                self._slot_trace.get(slot, f"slot{slot}"),
+                bucket=w, pos0=done, take=take)
         else:
-            span = ann = _NULL
-        with span, ann:
-            self._cache, self._last_logits = self._prefix_jit(
-                self.params, self._cache, self._last_logits,
-                jnp.asarray(padded), jnp.int32(done), jnp.int32(take),
-                jnp.asarray(self._pt[slot:slot + 1]), jnp.int32(slot),
-            )
-            _await_dispatch(self._cache, self._last_logits)
+            span = host = disp = _NULL
+        with span:
+            with host:
+                padded = np.full((1, w), self.config.pad_id, np.int32)
+                padded[0, :take] = np.asarray(prompt[done:done + take],
+                                              np.int32)
+                args = (jnp.asarray(padded), jnp.int32(done),
+                        jnp.int32(take),
+                        jnp.asarray(self._pt[slot:slot + 1]),
+                        jnp.int32(slot))
+            with disp:
+                self._cache, self._last_logits = self._prefix_jit(
+                    self.params, self._cache, self._last_logits, *args,
+                )
+                _await_dispatch(self._cache, self._last_logits)
         done += take
         st["done"] = done
         self._len[slot] = done
@@ -1692,35 +1730,31 @@ class PagedEngine(_EngineBase):
         preempted slots drop out of this burst (their rows are pads) and
         surface via `take_preempted()`."""
         k = self.config.decode_burst
-        grown = self._grow_tables(k)
-        splits = self._cow_split(k)
         tr = self.tracer
-        if tr is not None and tr.enabled:
-            ids = self._dispatch_ids()
-            # sampled_only: same head-sampling gate as SlotEngine's
-            # burst span — no trace_id, so it rides only while a
-            # sampled request is flowing
-            span = tr.span("decode_burst", pid=self.replica,
-                           tid=ENGINE_LANE, burst=k, active=len(ids),
-                           blocks_grown=grown, cow_splits=splits,
-                           blocks_free=self.blocks.num_free,
-                           sampled_only=True)
-            ann = jax.profiler.TraceAnnotation(
-                "serve:decode[" + ",".join(ids) + "]"
-            )
+        traced = tr is not None and tr.enabled
+        with self._span("burst_plan") if traced else _NULL:
+            grown = self._grow_tables(k)
+            splits = self._cow_split(k)
+        if traced:
+            span, disp, read = self._burst_spans(
+                "decode_burst", burst=k, blocks_grown=grown,
+                cow_splits=splits, blocks_free=self.blocks.num_free)
         else:
-            span = ann = _NULL
-        with span, ann:
-            (self._cache, self._last_logits, toks,
-             self._keys, finite) = self._decode_jit(
-                self.params, self._cache, self._last_logits,
-                jnp.asarray(self._attn), jnp.asarray(self._active),
-                self._keys, jnp.asarray(self._pt), jnp.asarray(self._len),
-                self._sampling_args(),
-            )
-            _await_dispatch(self._cache, self._last_logits, self._keys)
+            span = disp = read = _NULL
+        with span:
+            with disp:
+                (self._cache, self._last_logits, toks,
+                 self._keys, finite) = self._decode_jit(
+                    self.params, self._cache, self._last_logits,
+                    jnp.asarray(self._attn), jnp.asarray(self._active),
+                    self._keys, jnp.asarray(self._pt),
+                    jnp.asarray(self._len), self._sampling_args(),
+                )
+                _await_dispatch(self._cache, self._last_logits,
+                                self._keys)
             self._len[self._active] += k
-            toks, finite = jax.device_get((toks, finite))
+            with read:  # the host waits for the device here
+                toks, finite = jax.device_get((toks, finite))
         self.burst_seq += 1
         self.last_burst_active = int(np.count_nonzero(self._active))
         self.last_finite = np.asarray(finite)
@@ -1772,33 +1806,31 @@ class PagedEngine(_EngineBase):
             raise RuntimeError("step_verify needs spec_decode=True")
         k = int(drafts.shape[1])
         nblk_before = self._nblk.copy()
-        grown = self._grow_tables(k + 1)
-        splits = self._cow_split(k + 1)
         tr = self.tracer
-        if tr is not None and tr.enabled:
-            ids = self._dispatch_ids()
-            span = tr.span("verify", pid=self.replica,
-                           tid=ENGINE_LANE, k=k, active=len(ids),
-                           drafted=int(draft_lens.sum()),
-                           blocks_grown=grown, cow_splits=splits,
-                           sampled_only=True)
-            ann = jax.profiler.TraceAnnotation(
-                "serve:verify[" + ",".join(ids) + "]"
-            )
+        traced = tr is not None and tr.enabled
+        with self._span("burst_plan") if traced else _NULL:
+            grown = self._grow_tables(k + 1)
+            splits = self._cow_split(k + 1)
+        if traced:
+            span, disp, read = self._burst_spans(
+                "verify", k=k, drafted=int(draft_lens.sum()),
+                blocks_grown=grown, cow_splits=splits)
         else:
-            span = ann = _NULL
-        with span, ann:
-            (self._cache, self._last_logits, toks,
-             accepted, finite) = self._verify_jit(
-                self.params, self._cache, self._last_logits,
-                jnp.asarray(self._attn), jnp.asarray(self._active),
-                jnp.asarray(drafts), jnp.asarray(draft_lens),
-                jnp.asarray(self._pt), jnp.asarray(self._len),
-            )
-            _await_dispatch(self._cache, self._last_logits)
-            toks, accepted, finite = jax.device_get(
-                (toks, accepted, finite)
-            )
+            span = disp = read = _NULL
+        with span:
+            with disp:
+                (self._cache, self._last_logits, toks,
+                 accepted, finite) = self._verify_jit(
+                    self.params, self._cache, self._last_logits,
+                    jnp.asarray(self._attn), jnp.asarray(self._active),
+                    jnp.asarray(drafts), jnp.asarray(draft_lens),
+                    jnp.asarray(self._pt), jnp.asarray(self._len),
+                )
+                _await_dispatch(self._cache, self._last_logits)
+            with read:  # the host waits for the device here
+                toks, accepted, finite = jax.device_get(
+                    (toks, accepted, finite)
+                )
         accepted = np.asarray(accepted)
         counts = np.where(self._active, accepted + 1, 0).astype(np.int64)
         self._len[self._active] += counts[self._active].astype(np.int32)

@@ -44,17 +44,34 @@ prefill/decode-burst lane spans), and every Completion carries a flight
 record — queue_s / prefill_s / decode_s / stall_s — computed from the
 admission timestamps whether or not a tracer is attached. `tracer=None`
 (the default) costs one `is not None` test per lifecycle edge.
+
+With a tracer attached every `step()` is also one `tick` span on the
+engine lane whose children name the tick's phases, in order and without
+overlap — `expire`, `admit` (gate, allocator, radix and every prefill of
+the tick), the engine's `burst_plan` and `decode_burst` / `verify`
+(themselves split into `burst_dispatch` and `burst_readback`), `deliver`
+(preemption drain, the row loop, `_finish`, chunk emission, metrics) —
+and a tick longer than 8x the rolling median of the last 64 records a
+`slow_tick` instant with the phase durations, the serving twin of the
+Trainer's `step_anomaly`.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import statistics
 import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence
 
 from ddp_practice_tpu.serve.engine import SlotEngine
-from ddp_practice_tpu.utils.trace import ENGINE_LANE
+from ddp_practice_tpu.utils.trace import ENGINE_LANE, NULL_SPAN
+
+# slow_tick: a tick this many times the rolling median of the last
+# SLOW_TICK_WINDOW ticks (at least SLOW_TICK_MIN_HISTORY of them seen)
+SLOW_TICK_FACTOR = 8.0
+SLOW_TICK_WINDOW = 64
+SLOW_TICK_MIN_HISTORY = 8
 
 
 class MonotonicClock:
@@ -318,6 +335,9 @@ class Scheduler:
         # counters (prefill at admit, decode at finish). None (the
         # default) leaves every code path byte-identical to FIFO.
         self.vtc = vtc
+        # durations of the last ticks, for the slow_tick verdict; only
+        # fed while a tracer is attached
+        self._tick_history: Deque[float] = deque(maxlen=SLOW_TICK_WINDOW)
 
     # ------------------------------------------------------------ intake
     def submit(self, req: Request) -> bool:
@@ -826,116 +846,184 @@ class Scheduler:
         """One tick: expire -> admit -> prefill chunks -> decode ->
         release. Returns the completions finalized during this tick.
         May raise faults.ReplicaCrashed when a chaos plan kills this
-        replica."""
+        replica. With a tracer attached the tick is one `tick` span
+        whose children are its phases (`_traced_tick`); with none, the
+        one test of the tracer is all the tick pays for it."""
         if self.fault_hook is not None:
             self.fault_hook.on_tick(self)
         before = len(self.completions)
+        tr = self.tracer
+        if tr is not None and tr.enabled:
+            self._traced_tick(tr, before)
+            return self.completions[before:]
         self._expire_queue()
         self._admit()
         self._prefill_pump()
         if any(not st.prefilling for st in self.running.values()):
-            eng = self.engine
-            counts = None
-            drafted = None
-            if self._spec_k:
-                drafts, draft_lens, any_drafted = eng.propose_drafts()
-                if any_drafted:
-                    drafted = (drafts, draft_lens)
-            if drafted is None:
-                # no slot has a proposal this tick (or speculation is
-                # off): plain burst — greedy-identical to a verify of
-                # empty drafts, minus the wasted window forward
-                burst = eng.step_burst()      # (K, max_slots)
-                finite = eng.last_finite      # (K, max_slots)
-            else:
-                # verify dispatch: rows are the accepted run + one
-                # correction token; row r of a slot is real iff
-                # r < counts[slot]
-                burst, counts, finite = eng.step_verify(*drafted)
-            # block-aware preemption: slots the engine evicted BEFORE
-            # this dispatch produced no tokens this burst — requeue
-            # their requests (front) before mapping token rows
-            self._drain_preempted()
-            if counts is not None:
-                # accept accounting BEFORE the row loop, so a request
-                # finishing mid-run still books its last dispatch.
-                # Every slot still running was active at dispatch, so
-                # counts >= 1 (accepted = counts - 1).
-                for slot, st in self.running.items():
-                    if st.prefilling:
-                        continue  # inactive at dispatch: counts[slot]=0
-                    stats = self._spec_stats.setdefault(
-                        st.req.rid, [0, 0])
-                    stats[0] += int(drafted[1][slot])
-                    stats[1] += int(counts[slot]) - 1
-            eos = self.engine.config.eos_id
-            for k, row in enumerate(burst):
-                if not self.running:
-                    break  # the rest of the burst is free-slot padding
-                if counts is not None and all(
-                        k >= int(counts[s]) for s in self.running):
-                    break  # every remaining run ended before this row
-                self.clock.tick()
-                now = self.clock.now()
-                for slot, st in list(self.running.items()):
-                    if st.prefilling:
-                        continue  # inactive at dispatch: rows are pads
-                    if counts is not None and k >= int(counts[slot]):
-                        continue  # this slot's verified run was shorter
-                    if not finite[k, slot]:
-                        # this row's token was sampled from non-finite
-                        # logits: poison ONE request, not the batch — the
-                        # tokens produced so far are valid (finite when
-                        # sampled), so a router can resume from them
-                        del self.running[slot]
-                        self.engine.release(slot)
-                        self._finish(
-                            st.req, st.tokens, "error",
-                            st.first_token_time,
-                            admitted=(st.admit_t0, st.admit_t1),
-                            chunked=st.chunk_base + st.emitted,
-                        )
-                        continue
-                    tok = int(row[slot])
-                    st.tokens.append(tok)
-                    if st.first_token_time is None:
-                        st.first_token_time = now
-                    done_status = None
-                    if eos is not None and tok == eos:
-                        done_status = "eos"
-                    elif len(st.tokens) >= st.req.max_new_tokens:
-                        done_status = "length"
-                    elif (st.req.deadline is not None
-                          and now > st.req.deadline):
-                        done_status = "timeout"
-                    if done_status:
-                        # released mid-burst: later rows of this burst
-                        # no longer map to this request (its surplus
-                        # tokens are discarded with it)
-                        del self.running[slot]
-                        self.engine.release(slot)
-                        self._finish(
-                            st.req, st.tokens, done_status,
-                            st.first_token_time,
-                            admitted=(st.admit_t0, st.admit_t1),
-                            chunked=st.chunk_base + st.emitted,
-                        )
-            if self.stream:
-                # one TokenChunk per still-running request per burst:
-                # the tokens this tick produced, stamped with their
-                # rid-global offsets. Finished requests already left
-                # through their final chunk in _finish.
-                for st in self.running.values():
-                    if len(st.tokens) > st.emitted:
-                        self._emit_chunk(
-                            st.req.rid, st.req.trace_id,
-                            st.chunk_base + st.emitted,
-                            st.tokens[st.emitted:],
-                        )
-                        st.emitted = len(st.tokens)
+            self._deliver(*self._dispatch(NULL_SPAN))
         if self.metrics:
             self.metrics.on_tick(self)
         return self.completions[before:]
+
+    def _traced_tick(self, tr, before: int) -> None:
+        """`step()`'s body under a tracer: the same calls in the same
+        order, each phase under its span (module doc)."""
+        def span(name, **attrs):
+            return tr.span(name, pid=self.replica, tid=ENGINE_LANE,
+                           sampled_only=True, **attrs)
+
+        admits = self._admit_counter
+        with span("tick", queue=len(self.queue),
+                  running=len(self.running)) as tick:
+            with span("expire"):
+                self._expire_queue()
+            with span("admit"):
+                self._admit()
+                self._prefill_pump()
+            decoding = any(not st.prefilling
+                           for st in self.running.values())
+            if decoding:
+                dispatched = self._dispatch(span("burst_plan"))
+            with span("deliver"):
+                if decoding:
+                    self._deliver(*dispatched)
+                if self.metrics:
+                    self.metrics.on_tick(self)
+            tick.attrs["admitted"] = self._admit_counter - admits
+            tick.attrs["delivered"] = len(self.completions) - before
+        self._judge_tick(tr, tick, decoding)
+
+    def _dispatch(self, plan_span) -> tuple:
+        """The tick's one decode dispatch: a verify of the drafter's
+        proposals when any slot has one, a plain burst otherwise.
+        Returns (token rows, counts or None, finite flags, drafted or
+        None). The engine records `burst_plan` and `decode_burst` /
+        `verify` itself; drafting is the scheduler's share of the
+        plan, timed under `plan_span`."""
+        eng = self.engine
+        drafted = None
+        if self._spec_k:
+            with plan_span:
+                drafts, draft_lens, any_drafted = eng.propose_drafts()
+            if any_drafted:
+                drafted = (drafts, draft_lens)
+        if drafted is None:
+            # no slot has a proposal this tick (or speculation is
+            # off): plain burst — greedy-identical to a verify of
+            # empty drafts, minus the wasted window forward
+            burst = eng.step_burst()      # (K, max_slots)
+            return burst, None, eng.last_finite, None
+        # verify dispatch: rows are the accepted run + one correction
+        # token; row r of a slot is real iff r < counts[slot]
+        burst, counts, finite = eng.step_verify(*drafted)
+        return burst, counts, finite, drafted
+
+    def _deliver(self, burst, counts, finite, drafted) -> None:
+        """Hand one dispatch's token rows to their requests: requeue
+        what the engine preempted, book the accepts, finish what ended,
+        emit the stream chunks."""
+        # block-aware preemption: slots the engine evicted BEFORE
+        # this dispatch produced no tokens this burst — requeue
+        # their requests (front) before mapping token rows
+        self._drain_preempted()
+        if counts is not None:
+            # accept accounting BEFORE the row loop, so a request
+            # finishing mid-run still books its last dispatch.
+            # Every slot still running was active at dispatch, so
+            # counts >= 1 (accepted = counts - 1).
+            for slot, st in self.running.items():
+                if st.prefilling:
+                    continue  # inactive at dispatch: counts[slot]=0
+                stats = self._spec_stats.setdefault(
+                    st.req.rid, [0, 0])
+                stats[0] += int(drafted[1][slot])
+                stats[1] += int(counts[slot]) - 1
+        eos = self.engine.config.eos_id
+        for k, row in enumerate(burst):
+            if not self.running:
+                break  # the rest of the burst is free-slot padding
+            if counts is not None and all(
+                    k >= int(counts[s]) for s in self.running):
+                break  # every remaining run ended before this row
+            self.clock.tick()
+            now = self.clock.now()
+            for slot, st in list(self.running.items()):
+                if st.prefilling:
+                    continue  # inactive at dispatch: rows are pads
+                if counts is not None and k >= int(counts[slot]):
+                    continue  # this slot's verified run was shorter
+                if not finite[k, slot]:
+                    # this row's token was sampled from non-finite
+                    # logits: poison ONE request, not the batch — the
+                    # tokens produced so far are valid (finite when
+                    # sampled), so a router can resume from them
+                    del self.running[slot]
+                    self.engine.release(slot)
+                    self._finish(
+                        st.req, st.tokens, "error",
+                        st.first_token_time,
+                        admitted=(st.admit_t0, st.admit_t1),
+                        chunked=st.chunk_base + st.emitted,
+                    )
+                    continue
+                tok = int(row[slot])
+                st.tokens.append(tok)
+                if st.first_token_time is None:
+                    st.first_token_time = now
+                done_status = None
+                if eos is not None and tok == eos:
+                    done_status = "eos"
+                elif len(st.tokens) >= st.req.max_new_tokens:
+                    done_status = "length"
+                elif (st.req.deadline is not None
+                      and now > st.req.deadline):
+                    done_status = "timeout"
+                if done_status:
+                    # released mid-burst: later rows of this burst
+                    # no longer map to this request (its surplus
+                    # tokens are discarded with it)
+                    del self.running[slot]
+                    self.engine.release(slot)
+                    self._finish(
+                        st.req, st.tokens, done_status,
+                        st.first_token_time,
+                        admitted=(st.admit_t0, st.admit_t1),
+                        chunked=st.chunk_base + st.emitted,
+                    )
+        if self.stream:
+            # one TokenChunk per still-running request per burst:
+            # the tokens this tick produced, stamped with their
+            # rid-global offsets. Finished requests already left
+            # through their final chunk in _finish.
+            for st in self.running.values():
+                if len(st.tokens) > st.emitted:
+                    self._emit_chunk(
+                        st.req.rid, st.req.trace_id,
+                        st.chunk_base + st.emitted,
+                        st.tokens[st.emitted:],
+                    )
+                    st.emitted = len(st.tokens)
+
+    def _judge_tick(self, tr, tick, decoding: bool) -> None:
+        """The slow_tick verdict (tracer attached only): a tick longer
+        than SLOW_TICK_FACTOR x the rolling median of the last
+        SLOW_TICK_WINDOW that decoded records one instant whose attrs
+        are the seconds of every span the tick caused, by name — so a
+        stall names the phase that held it. Only ticks that decoded
+        feed the history: an idle tick takes microseconds and would
+        make every working one look slow."""
+        dur = tick.t1 - tick.t0
+        hist = self._tick_history
+        if len(hist) >= SLOW_TICK_MIN_HISTORY:
+            median = statistics.median(hist)
+            if median > 0 and dur > SLOW_TICK_FACTOR * median:
+                phases = {name + "_s": round(secs, 6)
+                          for name, secs in (tick.caused or {}).items()}
+                tr.instant("slow_tick", pid=self.replica,
+                           tid=ENGINE_LANE, median_s=round(median, 6),
+                           tick_s=round(dur, 6), **phases)
+        if decoding:
+            hist.append(dur)
 
     # ------------------------------------------------- fleet operations
     def shed_queued(self, predicate) -> List[Request]:

@@ -54,7 +54,11 @@ warn0 = main_process_only(log.warning)
 
 
 class Trainer:
-    def __init__(self, config: TrainConfig):
+    def __init__(self, config: TrainConfig, tracer=None):
+        """`tracer`: a utils/trace.py TraceRecorder to record the
+        step-phase spans into (a caller that drives `train_epoch()`
+        itself hands one in and reads it back through `self.tracer`);
+        None leaves it to `trace_out` / `telemetry_out`."""
         self.config = config
         backend.enable_compile_cache(config.compilation_cache)
         dist.initialize(
@@ -462,22 +466,30 @@ class Trainer:
         self._train_images = 0
         self._train_seconds = 0.0
         self.eval_perplexity = None  # set by _evaluate_lm
-        # host-side step-phase tracing (utils/trace.py): data / dispatch /
-        # block / checkpoint spans into the same recorder family the
-        # serving stack uses, written as Chrome trace JSON at fit end.
-        # Device-side profiles (profile_dir) line up with these by wall
-        # clock; process 0 only, None = zero overhead.
-        self._tracer = None
-        # a recorder exists for EITHER consumer: --trace_out wants the
-        # exit-time dump, --telemetry_out wants the live stream (the
-        # exporter attaches as sink below)
-        if (config.trace_out or config.telemetry_out) \
+        # host-side step-phase tracing (utils/trace.py): one
+        # `train_epoch` span per epoch whose children are epoch_open /
+        # data / dispatch / after_group / block (and checkpoint), into
+        # the same recorder family the serving stack uses; `save_trace`
+        # writes it as Chrome trace JSON (fit() does at its end). The
+        # recorder mirrors every span into the profiler as
+        # `train:<name>` (set_annotate), so a device profile carries
+        # them on its own clock. None = zero overhead. `self.tracer` is
+        # the public name; the attribute stays, the benchmark's train
+        # driver reads it.
+        self._tracer = tracer
+        # without one handed in, a recorder exists for EITHER consumer:
+        # --trace_out wants the exit-time dump, --telemetry_out wants
+        # the live stream (the exporter attaches as sink below);
+        # process 0 only
+        if tracer is None and (config.trace_out or config.telemetry_out) \
                 and dist.process_index() == 0:
             from ddp_practice_tpu.utils.trace import TraceRecorder
 
             self._tracer = TraceRecorder()
+        if self._tracer is not None:
             self._tracer.set_process_name(0, "train")
             self._tracer.set_thread_name(0, 0, "steps")
+            self._tracer.set_annotate(jax.profiler.TraceAnnotation, "train")
         # XLA:CPU's in-process collective rendezvous can deadlock when more
         # than one execution of a collective-bearing program is in flight
         # (device threads join different run_ids). On the CPU dev platform,
@@ -641,9 +653,14 @@ class Trainer:
             )
             self._slo.evaluate(now)
 
+    @property
+    def tracer(self):
+        """The TraceRecorder the step-phase spans go to, or None."""
+        return self._tracer
+
     def _tspan(self, name: str, **attrs):
-        """A step-phase span on the train lane, or a no-op without
-        --trace-out (one attribute test on the hot path)."""
+        """A step-phase span on the train lane, or a no-op without a
+        tracer (one attribute test on the hot path)."""
         if self._tracer is None:
             return _NULL_SPAN
         return self._tracer.span(name, pid=0, tid=0, **attrs)
@@ -660,7 +677,10 @@ class Trainer:
                     return
             yield item
 
-    def _save_trace(self) -> None:
+    def save_trace(self) -> None:
+        """Write the recorder to `config.trace_out` (Chrome trace JSON).
+        fit() calls it at its end; a caller of `train_epoch()` alone
+        calls it itself."""
         if self._tracer is None or not self.config.trace_out:
             return  # stream-only runs (--telemetry_out) have no dump
         try:
@@ -937,18 +957,20 @@ class Trainer:
         With profile_dir, the trace covers the whole first epoch (the first
         group includes compile; use bench.py for steady-state traces)."""
         cfg = self.config
-        self.train_loader.set_epoch(epoch)
-        idx, _ = self.train_loader.epoch_plan()
-        if cfg.max_steps_per_epoch:
-            idx = idx[: cfg.max_steps_per_epoch]
-        total = len(idx)
-        g = self._resident_group(total)
+        with self._tspan("epoch_open"):
+            self.train_loader.set_epoch(epoch)
+            idx, _ = self.train_loader.epoch_plan()
+            if cfg.max_steps_per_epoch:
+                idx = idx[: cfg.max_steps_per_epoch]
+            total = len(idx)
+            g = self._resident_group(total)
+            self._pending.clear()
+            timer = Timer()
+            # host-side global step base for trace labels (resume-aware);
+            # the state is quiescent at epoch start so this readback is
+            # free
+            step_base = int(self.state.step)
         final_metrics = None
-        self._pending.clear()
-        timer = Timer()
-        # host-side global step base for trace labels (resume-aware); the
-        # state is quiescent at epoch start so this readback is free
-        step_base = int(self.state.step)
         steps_done = 0
         profiling = False
         if cfg.profile_dir and epoch == 0:
@@ -971,7 +993,9 @@ class Trainer:
                 prev = steps_done
                 steps_done += inc
                 final_metrics = metrics
-                self._after_train_group(epoch, prev, steps_done, metrics)
+                with self._tspan("after_group", step=step_base + steps_done):
+                    self._after_train_group(epoch, prev, steps_done,
+                                            metrics)
             self._close_train_epoch(final_metrics)
         finally:
             if profiling:
@@ -1034,21 +1058,35 @@ class Trainer:
         )
 
     def train_epoch(self, epoch: int) -> dict:
-        if self.resident_train_step is not None:
-            return self._train_epoch_resident(epoch)
+        """One epoch. With a tracer it is one `train_epoch` span whose
+        children are `epoch_open` (loader plan, step readback), then per
+        dispatch group `data`, `dispatch` and `after_group` (telemetry,
+        watchdog, log readback — its `block` child is the readback),
+        then the closing `block` fence: host time outside every child
+        is the span's self time."""
+        with self._tspan("train_epoch", epoch=epoch):
+            if self.resident_train_step is not None:
+                return self._train_epoch_resident(epoch)
+            return self._train_epoch_host(epoch)
+
+    def _train_epoch_host(self, epoch: int) -> dict:
+        """One epoch fed from the host loader through the prefetcher."""
         cfg = self.config
-        self.train_loader.set_epoch(epoch)  # ≡ sampler.set_epoch (ddp_main.py:160)
-        k = max(1, cfg.steps_per_call if self.chunk_step is not None else 1)
-        items = self._tagged_batches(self.train_loader, k)
-        batches = self._traced_batches(items)
+        with self._tspan("epoch_open"):
+            self.train_loader.set_epoch(epoch)  # ≡ sampler.set_epoch (ddp_main.py:160)
+            k = max(1, cfg.steps_per_call
+                    if self.chunk_step is not None else 1)
+            items = self._tagged_batches(self.train_loader, k)
+            batches = self._traced_batches(items)
+            self._pending.clear()
+            timer = Timer()
+            # host-side global step base for trace labels (resume-aware);
+            # the state is quiescent at epoch start, and a host counter —
+            # unlike int(self.state.step) per group — never blocks on
+            # in-flight steps
+            step_base = int(self.state.step)
         final_metrics = None
-        self._pending.clear()
-        timer = Timer()
         images_this_epoch = 0
-        # host-side global step base for trace labels (resume-aware); the
-        # state is quiescent at epoch start, and a host counter — unlike
-        # int(self.state.step) per group — never blocks on in-flight steps
-        step_base = int(self.state.step)
         # profile a steady-state window (post-compile) of the first epoch,
         # shrunk to fit short (smoke) epochs
         profile_window = None
@@ -1105,7 +1143,9 @@ class Trainer:
                 steps_done += inc
                 images_this_epoch += self.global_batch * inc
                 final_metrics = metrics
-                self._after_train_group(epoch, prev, steps_done, metrics)
+                with self._tspan("after_group", step=step_base + steps_done):
+                    self._after_train_group(epoch, prev, steps_done,
+                                            metrics)
             self._close_train_epoch(final_metrics)
         finally:
             items.close()  # stop the prefetch producer thread promptly
@@ -1311,7 +1351,7 @@ class Trainer:
                 self._metrics_fh = None
             # written in the finally so a crashed run still leaves its
             # partial timeline — a flight recorder's whole point
-            self._save_trace()
+            self.save_trace()
             if self._tele_server is not None:
                 self._tele_server.close()
                 self._tele_server = None

@@ -50,10 +50,23 @@ Trace-id propagation is the router's failover contract: a re-admitted
 request's sub-Request carries the ORIGINAL trace_id, so a crash-migrated
 request's spans on the survivor join the same async track as its spans
 on the dead replica — one request, one timeline (pinned in
-tests/test_trace.py). The engines also name their
-`jax.profiler.TraceAnnotation` regions with the dispatch's trace-ids, so
-a device timeline captured by utils/profiling.py lines up with the host
-spans by name (utils/xprof.py reads the device side back).
+tests/test_trace.py).
+
+Every lane span also records the span that CAUSED it (`parent`: the
+`link` id of the innermost span open on the recording thread when it
+began, on whatever lane either sits; a span draws its `link` when it
+BEGINS, its `seq` when it is RECORDED, so `seq` stays the ring's order
+and the OTLP drain's high-water mark), so a span's self time is its
+duration minus its children's — from linkage, not guessed from overlap.
+And with
+`set_annotate(jax.profiler.TraceAnnotation, "serve")` (the engines and
+the Trainer install it; this module never imports jax) every lane span
+is mirrored, for its lifetime, as a profiler annotation named
+`<prefix>:<span name>` — a closed set of names (`serve:tick`,
+`serve:decode_burst`, `train:dispatch`, ...; request ids live in span
+attrs, never in names). While a profiler session is open each program
+span is therefore also an event on the profiler's host line, on the
+device trace's own clock.
 """
 
 from __future__ import annotations
@@ -204,9 +217,10 @@ class _TailStage:
 
 class _Rec:
     __slots__ = ("kind", "name", "t0", "t1", "pid", "tid", "trace_id",
-                 "attrs", "seq")
+                 "attrs", "seq", "link", "parent")
 
-    def __init__(self, kind, name, t0, t1, pid, tid, trace_id, attrs, seq):
+    def __init__(self, kind, name, t0, t1, pid, tid, trace_id, attrs, seq,
+                 link=None, parent=None):
         self.kind = kind
         self.name = name
         self.t0 = t0
@@ -215,14 +229,26 @@ class _Rec:
         self.tid = tid
         self.trace_id = trace_id
         self.attrs = attrs
+        # drawn when the record is made: the ring's own order
         self.seq = seq
+        # lane spans only: this span's link id (drawn when it BEGAN) and
+        # the link id of the span that caused it
+        self.link = link
+        self.parent = parent
 
 
 class _Span:
-    """Context manager for one lane span; created only when enabled."""
+    """Context manager for one lane span; created only when enabled.
+
+    Its `link` id is drawn when it BEGINS, so the spans it causes can
+    name it as their parent before it is recorded (a parent is recorded
+    after its children: records are appended as spans end, and draw
+    their `seq` then). `attrs` may be filled in until it ends; `t0`/`t1`
+    stay readable afterwards, and `caused` holds the seconds of every
+    span it caused, by name (None if it caused none)."""
 
     __slots__ = ("rec", "name", "trace_id", "pid", "tid", "attrs", "t0",
-                 "sampled_only")
+                 "t1", "link", "parent", "caused", "sampled_only", "_ann")
 
     def __init__(self, rec, name, trace_id, pid, tid, attrs,
                  sampled_only=False):
@@ -233,16 +259,43 @@ class _Span:
         self.tid = tid
         self.attrs = attrs
         self.sampled_only = sampled_only
+        self.caused = None
 
     def __enter__(self):
-        self.t0 = self.rec._now()
+        rec = self.rec
+        stack = rec._open_spans()
+        self.parent = stack[-1] if stack else None
+        self.link = next(rec._link)
+        stack.append(self)
+        # the mirror annotation begins when it is built: build it right
+        # beside the clock read, in the order the benchmark's own marker
+        # uses (annotation, then clock), so the two clocks are compared
+        # at one instant
+        self._ann = rec._annotation(self.name)
+        self.t0 = rec._now()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        self.rec.record_span(
-            self.name, self.t0, self.rec._now(), trace_id=self.trace_id,
-            pid=self.pid, tid=self.tid, attrs=self.attrs,
-            sampled_only=self.sampled_only,
+        rec = self.rec
+        self.t1 = rec._now()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        rec._open_spans().pop()
+        parent = self.parent
+        if parent is not None:
+            # hand the parent this span's seconds and what it caused
+            total = parent.caused
+            if total is None:
+                total = parent.caused = {}
+            total[self.name] = (total.get(self.name, 0.0)
+                                + self.t1 - self.t0)
+            if self.caused:
+                for name, secs in self.caused.items():
+                    total[name] = total.get(name, 0.0) + secs
+        rec._record_span(
+            self.name, self.t0, self.t1, self.trace_id, self.pid,
+            self.tid, self.attrs or None, self.sampled_only, self.link,
+            None if parent is None else parent.link,
         )
         return False
 
@@ -300,8 +353,47 @@ class TraceRecorder:
         # span drained after its root shipped still parents onto it.
         self._otlp_drained = -1
         self._otlp_roots: Dict[str, str] = {}
+        # per-thread stack of the lane spans open there (what a new
+        # span's `parent` is read from) and the link ids they draw
+        self._tls = threading.local()
+        self._link = itertools.count()
+        # profiler mirror (set_annotate): None = spans stay host-only
+        self._annotate = None
+        self._ann_prefix = ""
+        self._ann_names: Dict[str, str] = {}
         if sink is not None:
             self.set_sink(sink)
+
+    def set_annotate(self, fn, prefix: str) -> None:
+        """Mirror every lane span into a profiler: `fn(name)` must
+        return a context manager (the program passes
+        `jax.profiler.TraceAnnotation`) and is entered for the span's
+        lifetime under the name `<prefix>:<span name>`. With a profiler
+        session open, each program span is then also an event on the
+        profiler's host line, on the device trace's clock; with none,
+        the annotation is a no-op of the profiler's. `fn=None` turns
+        the mirror off."""
+        self._annotate = fn
+        self._ann_prefix = prefix
+        self._ann_names = {}
+
+    def _annotation(self, name: str):
+        fn = self._annotate
+        if fn is None:
+            return None
+        full = self._ann_names.get(name)
+        if full is None:
+            full = self._ann_names[name] = f"{self._ann_prefix}:{name}"
+        ann = fn(full)
+        ann.__enter__()
+        return ann
+
+    def _open_spans(self) -> list:
+        try:
+            return self._tls.stack
+        except AttributeError:
+            self._tls.stack = []
+            return self._tls.stack
 
     def set_sink(self, sink) -> None:
         """Attach a streaming consumer: `sink(record_dict)` per span/
@@ -578,12 +670,19 @@ class TraceRecorder:
                     trace_id: Optional[str] = None, pid: int = 0,
                     tid: int = 0, attrs: Optional[dict] = None,
                     sampled_only: bool = False) -> None:
-        """Explicit-timestamp lane span (for intervals the caller timed)."""
+        """Explicit-timestamp lane span (for intervals the caller timed).
+        It hangs under whatever span is open on this thread now."""
         if not self.enabled:
             return
-        rec = _Rec(
-            _DUR, name, t0, t1, pid, tid, trace_id, attrs, next(self._seq)
-        )
+        stack = self._open_spans()
+        self._record_span(name, t0, t1, trace_id, pid, tid, attrs,
+                          sampled_only, next(self._link),
+                          stack[-1].link if stack else None)
+
+    def _record_span(self, name, t0, t1, trace_id, pid, tid, attrs,
+                     sampled_only, link, parent) -> None:
+        rec = _Rec(_DUR, name, t0, t1, pid, tid, trace_id, attrs,
+                   next(self._seq), link, parent)
         if self.sampler is not None and not self._admit(rec, sampled_only):
             return
         self._append(rec)
@@ -704,6 +803,11 @@ class TraceRecorder:
             args = dict(r.attrs) if r.attrs else {}
             if r.trace_id is not None:
                 args["trace_id"] = r.trace_id
+            if ph == "B":
+                # linkage for self time: a child names its parent's link
+                args["link"] = r.link
+                if r.parent is not None:
+                    args["parent"] = r.parent
             if args:
                 ev["args"] = args
             if ph == "b":
@@ -721,11 +825,13 @@ class TraceRecorder:
 
         def sweep(recs, b_ph, e_ph):
             """Emit properly nested begin/end pairs for one lane: sort by
-            (start, -end, seq), close every span that ends at-or-before
-            the next span's start, drain at the end. Genuinely crossing
-            intervals come out ts-disordered — the validator flags them
-            rather than this export papering over them."""
-            recs.sort(key=lambda r: (r.t0, -r.t1, r.seq))
+            (start, -end, order begun), close every span that ends
+            at-or-before the next span's start, drain at the end.
+            Genuinely crossing intervals come out ts-disordered — the
+            validator flags them rather than this export papering over
+            them."""
+            recs.sort(key=lambda r: (
+                r.t0, -r.t1, r.seq if r.link is None else r.link))
             stack = []
             for r in recs:
                 while stack and stack[-1].t1 <= r.t0:

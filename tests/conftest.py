@@ -85,6 +85,13 @@ def t1_duration_ledger():
 
 
 @pytest.fixture(scope="session")
+def t1_session_wall_s():
+    """() -> seconds this pytest process has run: what a `timeout`
+    around the run cuts, however many workers share the tests."""
+    return lambda: time.monotonic() - _T1_START
+
+
+@pytest.fixture(scope="session")
 def devices():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 virtual devices, got {len(devs)}"

@@ -1,0 +1,454 @@
+"""The span tree (ISSUE 24): one `tick` per Scheduler.step() and one
+`train_epoch` per Trainer.train_epoch(), children linked by `parent`,
+mirrored into the profiler under fixed names — and nothing at all without
+a tracer. Plus the names a device trace is read by: the jitted serving
+programs and the flash kernels.
+"""
+
+import json
+import os
+import re
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+from tools.check_traces import validate  # noqa: E402
+
+from ddp_practice_tpu.utils.trace import (  # noqa: E402
+    ENGINE_LANE,
+    SLOT_LANE_BASE,
+    TraceRecorder,
+)
+
+VOCAB = 32
+# what a tick may hold directly, in the order it must appear
+TICK_CHILDREN = ["expire", "admit", "burst_plan", "decode_burst", "deliver"]
+SERVE_NAMES = {"tick", "expire", "admit", "prefill", "prefill_host",
+               "prefill_dispatch", "burst_plan", "decode_burst",
+               "burst_dispatch", "burst_readback", "deliver"}
+
+
+@pytest.fixture(scope="module")
+def lm():
+    import jax
+    import jax.numpy as jnp
+
+    from ddp_practice_tpu.models import create_model
+
+    model = create_model(
+        "lm_tiny", vocab_size=VOCAB, max_len=96, hidden_dim=32,
+        depth=1, num_heads=2, mlp_dim=64, pos_emb="rope",
+    )
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    return model, params
+
+
+def make_engine(lm, kind: str):
+    from ddp_practice_tpu.serve.engine import (
+        EngineConfig,
+        PagedEngine,
+        SlotEngine,
+    )
+
+    model, params = lm
+    if kind == "paged":
+        return PagedEngine(model, params, EngineConfig(
+            max_slots=2, prompt_buckets=(4, 8), eos_id=None, block_size=4,
+            decode_burst=2))
+    return SlotEngine(model, params, EngineConfig(
+        max_slots=2, prompt_buckets=(4, 8), eos_id=None, decode_burst=2))
+
+
+def serve(lm, kind: str, traced: bool = True, *, requests: int = 4,
+          max_new: int = 4, **recorder_kw):
+    """Drive a toy scheduler to idle under a FakeClock, which is the
+    recorder's clock too. Returns (scheduler, recorder or None, ticks)."""
+    from ddp_practice_tpu.serve.scheduler import (
+        FakeClock,
+        Request,
+        Scheduler,
+    )
+
+    clock = FakeClock(step_s=0.01)
+    rec = TraceRecorder(clock=clock, **recorder_kw) if traced else None
+    engine = make_engine(lm, kind)
+    engine.set_tracer(rec, 0)
+    sched = Scheduler(engine, clock=clock, tracer=rec, replica=0)
+    for rid in range(requests):
+        sched.submit(Request(rid=rid, prompt=[1, 2, 3],
+                             max_new_tokens=max_new))
+    n = 0
+    while not sched.idle:
+        sched.step()
+        n += 1
+    assert all(c.status == "length" for c in sched.completions)
+    return sched, rec, n
+
+
+def lane_spans(rec: TraceRecorder) -> list:
+    """Lane spans in the order they BEGAN (a span's `link` is drawn
+    then; its `seq` when it is recorded, i.e. as it ends)."""
+    return sorted((r for r in rec._records if r.kind == 0),
+                  key=lambda r: r.link)
+
+
+# ------------------------------------------------------------ serve ticks
+@pytest.mark.parametrize("kind", ["paged", "slot"])
+def test_every_tick_is_one_span_with_its_phases_as_children(lm, kind):
+    sched, rec, n_ticks = serve(lm, kind)
+    spans = lane_spans(rec)
+    by_link = {r.link: r for r in spans}
+    ticks = [r for r in spans if r.name == "tick"]
+    assert len(ticks) == n_ticks and all(r.parent is None for r in ticks)
+    assert all(r.tid == ENGINE_LANE for r in ticks)
+    assert {r.name for r in spans} <= SERVE_NAMES
+    # everything but a tick names the span that caused it
+    assert all(r.parent in by_link for r in spans if r.name != "tick")
+    admitted = delivered = 0
+    for tick in ticks:
+        kids = [r for r in spans if r.parent == tick.link]
+        names = [r.name for r in kids]
+        # its phases, in order, each at most once
+        assert names == [n for n in TICK_CHILDREN if n in names], names
+        assert names[:2] == ["expire", "admit"] and names[-1] == "deliver"
+        # nested in the tick, one after the other, no more than it
+        for a, b in zip(kids, kids[1:]):
+            assert a.t1 <= b.t0
+        assert all(tick.t0 <= r.t0 and r.t1 <= tick.t1 for r in kids)
+        assert sum(r.t1 - r.t0 for r in kids) <= (tick.t1 - tick.t0) + 1e-9
+        assert {"queue", "running", "admitted", "delivered"} <= set(
+            tick.attrs)
+        admitted += tick.attrs["admitted"]
+        delivered += tick.attrs["delivered"]
+    assert admitted == 4 and delivered == len(sched.completions) == 4
+    # a prefill sits on its slot's lane and still names the `admit` on
+    # the engine lane that caused it; the engine's own halves hang under
+    # the dispatch they split
+    prefills = [r for r in spans if r.name == "prefill"]
+    assert len(prefills) == 4
+    for r in prefills:
+        assert r.tid >= SLOT_LANE_BASE
+        assert by_link[r.parent].name == "admit"
+        halves = [c.name for c in spans if c.parent == r.link]
+        assert halves == ["prefill_host", "prefill_dispatch"]
+    for r in spans:
+        if r.name in ("burst_dispatch", "burst_readback"):
+            assert by_link[r.parent].name == "decode_burst"
+    if kind == "paged":
+        assert any(r.name == "burst_plan" for r in spans)
+    # the export carries the linkage and stays validator-clean
+    trace = rec.to_chrome_trace()
+    assert validate(trace) == []
+    begins = {e["args"]["link"]: e for e in trace["traceEvents"]
+              if e["ph"] == "B"}
+    for ev in begins.values():
+        if ev["name"] == "tick":
+            assert "parent" not in ev["args"]
+        else:
+            assert ev["args"]["parent"] in begins
+
+
+def test_self_time_comes_from_linkage(lm):
+    """A tick's self time is its duration minus its children's: under the
+    FakeClock every clock step is taken by `deliver` (one a token row), so
+    the tick itself and every other phase own none."""
+    _, rec, _ = serve(lm, "paged")
+    spans = lane_spans(rec)
+    for tick in (r for r in spans if r.name == "tick"):
+        kids = [r for r in spans if r.parent == tick.link]
+        own = (tick.t1 - tick.t0) - sum(r.t1 - r.t0 for r in kids)
+        assert own == pytest.approx(0.0, abs=1e-9)
+        deliver = kids[-1]
+        assert deliver.t1 - deliver.t0 == pytest.approx(tick.t1 - tick.t0)
+
+
+def test_the_ring_keeps_record_order_and_the_drain_loses_no_parent(lm):
+    """A parent is recorded after its children, so its `seq` (drawn as it
+    is recorded) is the higher one: the ring stays in seq order, which is
+    what `drain_otlp`'s high-water mark counts on. A push that lands
+    between `prefill_host` ending and `prefill` ending must still ship
+    the request's `prefill` in the next batch."""
+    _, rec, _ = serve(lm, "paged")
+    seqs = [r.seq for r in rec._records]
+    assert seqs == sorted(seqs)
+
+    def drained(r):
+        batch = r.drain_otlp()
+        return batch and [s["name"] for s in
+                          batch["resourceSpans"][0]["scopeSpans"][0]["spans"]]
+
+    r = TraceRecorder()
+    with r.span("prefill", trace_id="r1", tid=SLOT_LANE_BASE):
+        with r.span("prefill_host", trace_id="r1", tid=SLOT_LANE_BASE):
+            pass
+        assert drained(r) == ["prefill_host"]       # the push, mid-prefill
+    assert drained(r) == ["prefill"]
+    assert drained(r) is None
+
+
+def test_validator_flags_a_child_outside_its_parent():
+    def ev(ph, name, ts, tid, **args):
+        return {"ph": ph, "name": name, "ts": ts, "pid": 0, "tid": tid,
+                **({"args": args} if args else {})}
+
+    def trace(child_end):
+        return {"traceEvents": [
+            {"ph": "M", "name": "process_name", "pid": 0, "tid": 0,
+             "args": {"name": "replica0"}},
+            ev("B", "admit", 10.0, 0, link=1), ev("E", "admit", 20.0, 0),
+            ev("B", "prefill", 12.0, 1, link=2, parent=1),
+            ev("E", "prefill", child_end, 1),
+            # its parent was sampled out of the file: no error
+            ev("B", "prefill", 30.0, 1, link=4, parent=3),
+            ev("E", "prefill", 31.0, 1)]}
+
+    assert validate(trace(19.0)) == []
+    errors = validate(trace(21.0))
+    assert len(errors) == 1 and "outside its parent 'admit'" in errors[0]
+
+
+class CountingAnnotation:
+    """Stands where `jax.profiler.TraceAnnotation` stands."""
+
+    names: list = []
+
+    def __init__(self, name):
+        CountingAnnotation.names.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    import jax
+
+    CountingAnnotation.names = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", CountingAnnotation)
+    return CountingAnnotation.names
+
+
+@pytest.mark.parametrize("kind", ["paged", "slot"])
+def test_no_tracer_no_record_and_no_annotation(lm, kind, annotations,
+                                               monkeypatch):
+    from ddp_practice_tpu.serve import engine, scheduler
+
+    def built(*args, **kw):
+        raise AssertionError("a span or its attrs built without a tracer")
+
+    # without a tracer the tick takes the plain path and the engine
+    # builds no span, so no attr is computed for one either
+    monkeypatch.setattr(scheduler.Scheduler, "_traced_tick", built)
+    for maker in ("_span", "_prefill_spans", "_burst_spans"):
+        monkeypatch.setattr(engine._EngineBase, maker, built)
+    sched, _, _ = serve(lm, kind, traced=False)
+    assert annotations == []
+    assert sched.engine.tracer is None and not sched.engine._slot_trace
+    assert not sched._tick_history
+
+
+def test_a_disabled_tracer_costs_what_none_costs(lm, annotations):
+    _, rec, _ = serve(lm, "paged", enabled=False)
+    assert annotations == [] and len(rec) == 0
+
+
+@pytest.mark.parametrize("kind", ["paged", "slot"])
+def test_spans_are_mirrored_under_a_closed_set_of_names(lm, kind,
+                                                        annotations):
+    _, rec, _ = serve(lm, kind)
+    spans = lane_spans(rec)
+    # one annotation a lane span, named by the span and by nothing else
+    assert sorted(annotations) == sorted(
+        "serve:" + r.name for r in spans)
+    assert set(annotations) <= {"serve:" + n for n in SERVE_NAMES}
+    assert not any(re.search(r"r\d|\[|,", n) for n in annotations)
+    # the request ids are where they belong
+    assert {r.trace_id for r in spans if r.name == "prefill"} == {
+        f"r{i}" for i in range(4)}
+
+
+def test_engine_source_builds_no_annotation_itself():
+    import ddp_practice_tpu.serve.engine as engine
+
+    src = open(engine.__file__).read()
+    assert "TraceAnnotation(" not in src
+    assert "_dispatch_ids" not in src
+
+
+# -------------------------------------------------------------- slow_tick
+def slow_ticks(rec: TraceRecorder) -> list:
+    return [r for r in rec._records if r.kind == 2 and r.name == "slow_tick"]
+
+
+def test_slow_tick_fires_on_a_tick_ten_times_the_median(lm, monkeypatch):
+    from ddp_practice_tpu.serve.scheduler import Scheduler
+
+    real_expire, stalled = Scheduler._expire_queue, []
+
+    def expire(self):
+        # once, twelve decoding ticks in: hold the `expire` phase for
+        # ten normal ticks (a tick is 2 token rows = 0.02 s)
+        if len(self._tick_history) == 12 and not stalled:
+            stalled.append(True)
+            self.clock.advance(0.2)
+        real_expire(self)
+
+    monkeypatch.setattr(Scheduler, "_expire_queue", expire)
+    _, rec, _ = serve(lm, "paged", requests=2, max_new=40)
+    hits = slow_ticks(rec)
+    assert len(hits) == 1
+    attrs = hits[0].attrs
+    assert attrs["median_s"] == pytest.approx(0.02)
+    assert attrs["tick_s"] == pytest.approx(0.22)
+    # the phase that held it is named, and the others are beside it
+    assert attrs["expire_s"] == pytest.approx(0.2)
+    assert attrs["deliver_s"] == pytest.approx(0.02)
+    assert {"admit_s", "decode_burst_s", "burst_dispatch_s",
+            "burst_readback_s", "burst_plan_s"} <= set(attrs)
+
+
+def test_slow_tick_stays_quiet_on_a_flat_history(lm):
+    sched, rec, n = serve(lm, "paged", requests=2, max_new=40)
+    assert n >= 20 and len(sched._tick_history) >= 20
+    assert slow_ticks(rec) == []
+
+
+# ---------------------------------------------------------------- trainer
+def train_config(**kw):
+    from ddp_practice_tpu.config import MeshConfig, TrainConfig
+
+    cfg = dict(dataset="synthetic", epochs=1, batch_size=4,
+               optimizer="adam", learning_rate=1e-3, log_every_steps=2,
+               max_steps_per_epoch=4, mesh=MeshConfig(data=-1))
+    cfg.update(kw)
+    return TrainConfig(**cfg)
+
+
+@pytest.mark.parametrize("placement", ["device", "host"])
+def test_train_epoch_alone_yields_the_tree(devices, placement, annotations):
+    from ddp_practice_tpu.train.loop import Trainer
+
+    rec = TraceRecorder()
+    trainer = Trainer(train_config(data_placement=placement), tracer=rec)
+    assert trainer.tracer is trainer._tracer is rec
+    assert (trainer.resident_train_step is not None) == (
+        placement == "device")
+    trainer.train_epoch(0)          # fit() is never called
+    spans = lane_spans(rec)
+    by_link = {r.link: r for r in spans}
+    roots = [r for r in spans if r.parent is None]
+    assert [r.name for r in roots] == ["train_epoch"]
+    root = roots[0]
+    assert root.attrs == {"epoch": 0}
+    kids = [r for r in spans if r.parent == root.link]
+    names = [r.name for r in kids]
+    assert names[0] == "epoch_open" and names[-1] == "block"
+    assert names.count("dispatch") == names.count("after_group") >= 2
+    assert set(names) == {"epoch_open", "data", "dispatch", "after_group",
+                          "block"}
+    for a, b in zip(kids, kids[1:]):
+        assert a.t1 <= b.t0
+    assert all(root.t0 <= r.t0 and r.t1 <= root.t1 for r in kids)
+    # the log readback stays a `block`, now under the after_group it
+    # belongs to
+    inner = [r for r in spans if r.name == "block"
+             and by_link[r.parent].name == "after_group"]
+    assert len(inner) == 2          # steps 2 and 4 of 4, log every 2
+    assert validate(rec.to_chrome_trace()) == []
+    assert set(annotations) == {"train:" + n for n in set(names)
+                                | {"train_epoch"}}
+
+
+def test_save_trace_is_public_and_needs_no_fit(devices, tmp_path):
+    from ddp_practice_tpu.train.loop import Trainer
+
+    out = tmp_path / "host_spans.json"
+    trainer = Trainer(train_config(trace_out=str(out)))
+    assert trainer.tracer is not None       # trace_out still makes one
+    trainer.train_epoch(0)
+    trainer.save_trace()
+    names = {e["name"] for e in json.loads(out.read_text())["traceEvents"]
+             if e["ph"] == "B"}
+    assert {"train_epoch", "epoch_open", "after_group", "dispatch"} <= names
+
+
+def test_trainer_without_a_tracer_has_none(devices, annotations):
+    from ddp_practice_tpu.train.loop import Trainer
+
+    trainer = Trainer(train_config())
+    assert trainer.tracer is None
+    trainer.train_epoch(0)
+    assert annotations == []
+
+
+# ------------------------------------------------- names a trace is read by
+@pytest.mark.parametrize("attr,needle", [
+    ("_prefill_jit", "prefill_admit"),
+    ("_prefix_jit", "prefix_prefill"),
+    ("_decode_jit", "decode_burst"),
+    ("_verify_jit", "verify"),
+])
+def test_paged_programs_keep_the_names_the_readers_match(lm, attr, needle):
+    """A device trace shows a program as `jit_<function name>`;
+    `perf/lib/readers.py` finds the prefill and decode programs by these
+    substrings (PERF.md §3)."""
+    fn = getattr(make_engine(lm, "paged"), attr)
+    assert needle in fn.__name__
+
+
+@pytest.mark.parametrize("attr,needle", [
+    ("_prefill_jit", "prefill_admit"),
+    ("_decode_jit", "decode_burst"),
+])
+def test_slot_programs_keep_the_names_the_readers_match(lm, attr, needle):
+    assert needle in getattr(make_engine(lm, "slot"), attr).__name__
+
+
+def pallas_names(jaxpr) -> list:
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                out.extend(pallas_names(inner))
+    return out
+
+
+@pytest.mark.parametrize("heads,variant", [(2, "_packed"), (3, "")])
+def test_every_flash_kernel_carries_a_flash_name(heads, variant):
+    """`name=` is what the compiled custom call is named by (and so the
+    op on a device trace's "XLA Ops" line, under shard_map too:
+    tests/test_tpu_compile.py compiles it): forward and both backward
+    kernels, packed (heads pair up in 128 lanes) and folded."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddp_practice_tpu.ops.flash_attention import flash_attention
+
+    x = jnp.zeros((1, 256, heads, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    names = pallas_names(
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x).jaxpr)
+    assert sorted(names) == sorted(
+        n + variant for n in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"))
+
+
+def test_no_pallas_call_in_flash_attention_goes_unnamed():
+    import ddp_practice_tpu.ops.flash_attention as fa
+
+    src = open(fa.__file__).read()
+    calls = src.count("pl.pallas_call(")
+    assert calls == 6 == len(re.findall(r'name="flash_(fwd|bwd_)', src))

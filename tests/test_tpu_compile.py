@@ -256,6 +256,14 @@ def test_flash_compiles_sharded_over_four_devices(topo):
     calls = [ln for ln in text.splitlines()
              if "custom-call(" in ln and "tpu_custom_call" in ln]
     assert len(calls) == 3, len(calls)  # fwd, dk/dv, dq
+    # the compiled custom calls are named by the kernels' `name=`, not by
+    # the island around them ("shard_map"): a device trace's "XLA Ops"
+    # line shows these names, and perf/layer_metrics/train_flash_dev_pct
+    # reads them
+    named = sorted(ln.split("=")[0].strip().lstrip("%").split(".")[0]
+                   for ln in calls)
+    assert named == ["flash_bwd_dkv_packed", "flash_bwd_dq_packed",
+                     "flash_fwd_packed"], named
     per_device = f"bf16[{B // 4},{S},{HD}]"
     assert all(per_device in ln for ln in calls), calls[0][:300]
     assert "all-gather" not in text

@@ -29,7 +29,24 @@ TAIL_ALLOWANCE_S = 45.0
 MIN_REPORTS = 100
 
 
-def test_t1_suite_fits_the_timeout(request, t1_duration_ledger):
+# a ledger that holds fewer tests than the session collected is a
+# SHARE of the suite: a pytest-xdist worker collects every test and runs
+# (and keeps the durations of) its own files only, timed beside the
+# other workers (the same test read 15.5 s alone and 20.4-25.3 s beside
+# five). There the sum says nothing and the clock says it all: the
+# workers run side by side, so the wall time this process has run is
+# what the timeout cuts. A single duration there is judged against a
+# line doubled once more for the load.
+SHARED_LOAD_MARGIN = 2.0
+
+
+def _is_a_share(request, ledger) -> bool:
+    # the two sentinels themselves are collected and not yet reported
+    return len(ledger) + 2 < request.session.testscollected
+
+
+def test_t1_suite_fits_the_timeout(request, t1_duration_ledger,
+                                   t1_session_wall_s):
     markexpr = getattr(request.config.option, "markexpr", "") or ""
     if "not slow" not in markexpr.replace("(", "").replace(")", ""):
         pytest.skip("budget sentinel audits only the tier-1 "
@@ -38,6 +55,8 @@ def test_t1_suite_fits_the_timeout(request, t1_duration_ledger):
         pytest.skip(f"partial run ({len(t1_duration_ledger)} reports "
                     f"< {MIN_REPORTS}) — not the tier-1 population")
     total = sum(t1_duration_ledger.values())
+    if _is_a_share(request, t1_duration_ledger):
+        total = t1_session_wall_s()
     projected = total * OVERHEAD_FACTOR + TAIL_ALLOWANCE_S
     slowest = sorted(t1_duration_ledger.items(),
                      key=lambda kv: -kv[1])[:10]
@@ -90,6 +109,8 @@ def test_t1_no_unmarked_slow_tests(request, t1_duration_ledger):
     })
     assert not errors, "\n".join(errors)
     hard_line = SLOW_MARK_S * NOISE_MARGIN
+    if _is_a_share(request, t1_duration_ledger):
+        hard_line *= SHARED_LOAD_MARGIN
     hard = [w for w in warnings
             if ledger.get(w.split(" took", 1)[0], 0.0) > hard_line]
     for w in warnings:

@@ -39,7 +39,12 @@ Checks (each one a real corruption mode of the exporter):
   violation means the exporter emitted crossing (non-nested) intervals;
 - **matched async b/e** per (pid, id): b before e, same name, ts
   ordered, nothing left open;
-- only known phases (B E b e i M X C) appear.
+- only known phases (B E b e i M X C) appear;
+- **parent linkage**: a lane span that names a `parent` (args.parent =
+  the args.link of the span that caused it, on whatever lane) must lie
+  inside that parent's interval — self time is duration minus
+  children, which only holds if children are inside. A parent that is
+  not in the file (sampled out, evicted from the ring) is no error.
 
 FLEET mode (``--fleet [--skew-s S]``): the extra contracts of a MERGED
 cross-process timeline (utils/trace.py TraceCollector):
@@ -89,6 +94,7 @@ def validate(trace) -> List[str]:
     lane_stacks = defaultdict(list)     # (pid, tid) -> [(name, ts)]
     lane_last_ts = {}                   # (pid, tid) -> last B/E ts seen
     async_open = defaultdict(list)      # (pid, id) -> [(name, ts)]
+    linked = {}                         # link -> (name, t0, t1, parent)
     for i, ev in enumerate(events):
         where = f"event {i}"
         if not isinstance(ev, dict):
@@ -130,12 +136,15 @@ def validate(trace) -> List[str]:
                 )
             lane_last_ts[lane] = ts
             if ph == "B":
-                lane_stacks[lane].append((name, ts))
+                lane_stacks[lane].append((name, ts, ev.get("args") or {}))
             else:
                 if not lane_stacks[lane]:
                     errors.append(f"{where}: E with no open B on {lane}")
                 else:
-                    open_name, open_ts = lane_stacks[lane].pop()
+                    open_name, open_ts, args = lane_stacks[lane].pop()
+                    if "link" in args:
+                        linked[args["link"]] = (open_name, open_ts, ts,
+                                               args.get("parent"))
                     if open_name != name:
                         errors.append(
                             f"{where}: E closes {open_name!r} "
@@ -171,6 +180,14 @@ def validate(trace) -> List[str]:
                             f"{where}: async span for id {aid!r} ends "
                             f"before it starts ({open_ts} -> {ts})"
                         )
+    for link, (name, t0, t1, parent) in linked.items():
+        if parent in linked:
+            p_name, p0, p1, _ = linked[parent]
+            if t0 < p0 or t1 > p1:
+                errors.append(
+                    f"span {name!r} (link {link}, {t0}..{t1}) lies outside "
+                    f"its parent {p_name!r} (link {parent}, {p0}..{p1})"
+                )
     for lane, stack in lane_stacks.items():
         if stack:
             errors.append(
