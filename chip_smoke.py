@@ -17,7 +17,9 @@ one chip
   serve   generate.load_lm(checkpoint) -> PagedEngine -> Scheduler: prompts
           in two buckets, generations across several 16-token pages. The
           decode program must hold the compiled paged kernel, which is
-          compared on the live page pool with paged_attention_reference;
+          compared on the live page pool with paged_attention_reference,
+          and again at the benchmark's serving shapes on ragged,
+          left-padded slots (within one bf16 ulp);
           the tokens are compared with inference.py's generate and, one by
           one, with the argmax of a float32 forward without a cache. A
           token that differs is examined against the logit margin.
@@ -210,6 +212,11 @@ def make_requests(vocab_size: int, *, seed: int, lengths, max_new: int):
     return out
 
 
+def bf16_ulp(x: float) -> float:
+    """Spacing of bfloat16 numbers at magnitude `x`."""
+    return 2.0 ** (math.floor(math.log2(max(abs(x), 1e-6))) - 7)
+
+
 def paged_kernel_check(engine, model, *, seed: int,
                        require_kernels: bool) -> dict:
     """paged_decode_attention(impl="auto") against the gather reference on
@@ -270,6 +277,73 @@ def paged_kernel_check(engine, model, *, seed: int,
             "paged_kernel_lengths": [int(engine._len[s]) for s in active]}
 
 
+def paged_walk_check(*, seed: int, slots: int = 64, columns: int = 66,
+                     pool: int = 4352, heads: int = 12, head_dim: int = 64,
+                     impl: str = "auto", require_kernels: bool = True) -> dict:
+    """The paged decode kernel at the benchmark's serving shapes (PERF.md
+    section 4: 64 slots, 66 table columns, a 4,352-page pool, 12 x 64,
+    bf16) on ragged, left-padded slots: lengths and `attn_start` drawn
+    from the seed over the whole table, with the walk's edges among them
+    (one live token, a page's and a 128-token chunk's first and last
+    row, the table's last row, a retired slot on page 0). Every slot is
+    compared with the gather reference over its live positions; both
+    round to bf16, so they may part by one bf16 ulp of the slot's
+    largest output and no more."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ddp_practice_tpu.ops.decode_attention import (
+        paged_attention_reference,
+        paged_decode_attention,
+    )
+
+    rng = np.random.default_rng(seed)
+    span = columns * PAGE
+    last = rng.integers(0, span, slots)
+    edges = [0, PAGE - 1, PAGE, 127, 128, 129, span - 1, span - PAGE]
+    last[:len(edges)] = np.minimum(edges, span - 1)[:slots]
+    start = (last * rng.uniform(0, 1, slots) ** 2).astype(np.int64)
+    start[:3] = last[:3]                      # one live token
+    table = rng.permutation(np.arange(1, pool))[:slots * columns].reshape(
+        slots, columns)
+    table[-1], last[-1], start[-1] = 0, 5, 0  # retired: page 0, pinned
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    width = heads * head_dim
+    q = jax.random.normal(kq, (slots, 1, width), jnp.float32).astype(
+        jnp.bfloat16)
+    k = jax.random.normal(kk, (pool, PAGE, width), jnp.float32).astype(
+        jnp.bfloat16)
+    v = jax.random.normal(kv, (pool, PAGE, width), jnp.float32).astype(
+        jnp.bfloat16)
+    args = (q, k, v, jnp.asarray(table, jnp.int32),
+            jnp.asarray(last, jnp.int32), jnp.asarray(start, jnp.int32))
+
+    kernel = jax.jit(lambda *a: paged_decode_attention(
+        *a, n_heads=heads, impl=impl))
+    n_kernels = kernels_in(kernel.lower(*args))
+    if require_kernels:
+        check(n_kernels == 1,
+              f"the paged decode lowered with {n_kernels} Mosaic kernels")
+    got = np.asarray(kernel(*args), np.float32)[:, 0]
+    want = np.asarray(jax.jit(lambda *a: paged_attention_reference(
+        *a, n_heads=heads))(*args), np.float32)[:, 0]
+    check(np.isfinite(got).all(), "paged walk: non-finite output")
+    ulps = np.abs(got - want).max(axis=1) / np.asarray(
+        [bf16_ulp(x) for x in np.abs(want).max(axis=1)])
+    worst = int(ulps.argmax())
+    check(ulps[worst] <= 1.0,
+          f"paged walk vs reference: slot {worst} (positions "
+          f"{int(start[worst])}..{int(last[worst])}) is {ulps[worst]:.2f} "
+          f"bf16 ulps of its largest output away")
+    return {"paged_walk_mosaic_kernels": n_kernels,
+            "paged_walk_slots": slots, "paged_walk_table_columns": columns,
+            "paged_walk_pool_pages": pool,
+            "paged_walk_live_tokens": int((last - start + 1).sum()),
+            "paged_walk_worst_bf16_ulps": round(float(ulps[worst]), 3),
+            "paged_walk_slots_off_by_an_ulp": int((ulps > 0).sum())}
+
+
 def float32_logits(model, params, requests, generated) -> dict:
     """{rid: (len(generated), vocab) float32 logits} from a float32
     forward with no cache, teacher-forced on the engine's own tokens: row
@@ -308,8 +382,8 @@ def margin(row, token: int) -> tuple:
     TIE_ULPS bf16 ulps at the top logit's magnitude)."""
     best = int(row.argmax())
     top = float(row[best])
-    ulp = 2.0 ** (math.floor(math.log2(max(abs(top), 1e-6))) - 7)
-    return best, round(top - float(row[token]), 5), round(TIE_ULPS * ulp, 5)
+    return (best, round(top - float(row[token]), 5),
+            round(TIE_ULPS * bf16_ulp(top), 5))
 
 
 def serve_phase(ckpt: str, clock: CompileClock, *, seed: int,
@@ -371,6 +445,8 @@ def serve_phase(ckpt: str, clock: CompileClock, *, seed: int,
           f"{engine.num_active} of {len(requests)} requests in flight")
     kernel = paged_kernel_check(engine, model, seed=seed,
                                 require_kernels=require_kernels)
+    if require_kernels:   # the CPU rehearses it apart, at a toy size
+        kernel.update(paged_walk_check(seed=seed))
     t_run = time.time()
     completions = {c.rid: c for c in sched.run_until_idle()}
     run_seconds = time.time() - t_run

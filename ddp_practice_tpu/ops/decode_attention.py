@@ -37,6 +37,19 @@ index map pins their DMA to block 0 (Pallas elides DMAs whose block
 index is unchanged), so a step at position p reads O(p) cache bytes, not
 O(L) — the einsum path always paid O(L).
 
+The PAGED kernel (second half of this file; serve/kv_pages.py's pool) is
+built the other way round, because a skipped grid cell is not free: on
+the chip a dead cell costs 0.13 us and a live 16-token page with its
+twelve-head loop 0.71 us (PERF.md section 6, PR 25). Its grid runs over
+SLOTS only. One cell walks one slot's live pages, from the page of
+`attn_start` to the page of `len` and nowhere else, eight 16-token pages
+(128 tokens) at a time: the pools stay in HBM and each page comes by its
+own async copy into a two-deep VMEM buffer, the next chunk (or the next
+slot's first) in flight while this one is computed. The heads are not
+looped over: the query becomes 16 block-diagonal rows, so a chunk is two
+matmuls (`_paged_walk_kernel`). The int8 pool keeps the older
+one-page-a-cell walk (`_paged_kernel_quant`).
+
 The reference has no decode path at all (its model is a CNN classifier);
 this backs the generation stack (inference.py), whose API the LM family
 needs for parity with torch generation loops.
@@ -62,19 +75,18 @@ from ddp_practice_tpu.ops.flash_attention import (
 from ddp_practice_tpu.utils import backend
 
 
-def _online_softmax_cell(
-    cur, start, j, n_j,
-    q_ref, k_ref, v_ref, o_ref,
+def _kernel(
+    cur_ref, start_ref,              # scalar prefetch (SMEM)
+    q_ref, k_ref, v_ref, o_ref,      # blocks
     m_scr, l_scr, acc_scr,
-    *, sm_scale, block, n_heads, d,
+    *, sm_scale, block_l, n_heads, d, has_start,
 ):
-    """One grid cell of the multi-block online-softmax decode walk,
-    shared by the flat (`_kernel`) and paged (`_paged_kernel`) kernels —
-    the only thing that differs between them is where `cur` comes from
-    (pool-global scalar vs per-slot length) and how the kv tile was
-    addressed (contiguous vs page table), both settled by the caller.
-    `cur`/`start` are this cell's cursor scalars (start None = no
-    left-padding mask); key positions are `j * block + offset`."""
+    """One grid cell (batch row, L-block) of the multi-block
+    online-softmax walk over the flat cache; key positions are
+    `j * block_l + offset`."""
+    b_idx = pl.program_id(0)
+    j = pl.program_id(1)
+    cur = cur_ref[0]
 
     @pl.when(j == 0)
     def _init():
@@ -82,43 +94,27 @@ def _online_softmax_cell(
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    @pl.when(j * block <= cur)
+    @pl.when(j * block_l <= cur)
     def _compute():
-        k_pos = j * block + jax.lax.broadcasted_iota(
-            jnp.int32, (8, block), 1
+        k_pos = j * block_l + jax.lax.broadcasted_iota(
+            jnp.int32, (8, block_l), 1
         )
         valid = k_pos <= cur
-        if start is not None:
-            valid &= k_pos >= start
+        if has_start:
+            valid &= k_pos >= start_ref[b_idx]
         penalty = jnp.where(valid, 0.0, _NEG_INF)
         for hh in range(n_heads):
             lo, hi = hh * d, (hh + 1) * d
             qs = (q_ref[:, lo:hi] * sm_scale).astype(q_ref.dtype)  # (1, d)
             q8 = jnp.broadcast_to(qs, (8, d))
-            s = _dot_tb(q8, k_ref[:, lo:hi]) + penalty    # (8, block) f32
+            s = _dot_tb(q8, k_ref[:, lo:hi]) + penalty  # (8, block_l) f32
             m_scr[hh], l_scr[hh], acc_scr[:, lo:hi] = _softmax_accumulate(
                 s, v_ref[:, lo:hi], m_scr[hh], l_scr[hh], acc_scr[:, lo:hi]
             )
 
-    @pl.when(j == n_j - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
         o_ref[:] = acc_scr[:1].astype(o_ref.dtype)
-
-
-def _kernel(
-    cur_ref, start_ref,              # scalar prefetch (SMEM)
-    q_ref, k_ref, v_ref, o_ref,      # blocks
-    m_scr, l_scr, acc_scr,
-    *, sm_scale, block_l, n_heads, d, has_start,
-):
-    b_idx = pl.program_id(0)
-    j = pl.program_id(1)
-    _online_softmax_cell(
-        cur_ref[0], start_ref[b_idx] if has_start else None,
-        j, pl.num_programs(1),
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-        sm_scale=sm_scale, block=block_l, n_heads=n_heads, d=d,
-    )
 
 
 def _kernel_single(
@@ -366,32 +362,140 @@ def decode_attention_packed(
 # slot-local — position p of slot b lives in pool block
 # `page_table[b, p // block_size]` at row `p % block_size` — so there is
 # no shared cursor and a step's attention span is the slot's own
-# occupied pages, not a pool-global [0, max_len).
+# occupied pages, not a pool-global [0, max_len). The work is O(live
+# tokens): neither the pool's size nor the table's width is paid for.
 
 
-def _paged_kernel(
+# Tokens a chunk of the page walk aims at: the contraction depth of the
+# p @ V product on a 128 x 128 MXU, and eight 16-token pages.
+_CHUNK_TOKENS = 128
+# VMEM the walk's four chunk buffers (K and V, two deep) may take: half of
+# the 16 MiB a kernel gets by default.
+_CHUNK_VMEM_BYTES = 8 << 20
+
+
+def _pages_per_chunk(block_size: int, hd_total: int, dtype) -> int:
+    """Pages P the walk fetches and computes at a time: enough for 128
+    tokens (128 <= P * block_size < 256 whatever the page size, one page
+    when a page is longer), halved while the four (P * block_size,
+    h*hd) buffers would pass the VMEM budget (very wide models)."""
+    p = -(-_CHUNK_TOKENS // block_size)
+    row = hd_total * jnp.dtype(dtype).itemsize
+    while p > 1 and 4 * p * block_size * row > _CHUNK_VMEM_BYTES:
+        p //= 2
+    return p
+
+
+def _paged_walk_kernel(
     len_ref, start_ref, pt_ref,          # scalar prefetch (SMEM)
-    q_ref, k_ref, v_ref, o_ref,          # blocks
-    m_scr, l_scr, acc_scr,
-    *, sm_scale, block_size, n_heads, d, has_start,
+    q_ref, k_hbm, v_hbm, o_ref,          # q/out blocks; the pools, in HBM
+    k_buf, v_buf, sem, first_buf,        # scratch
+    *, sm_scale, block_size, pages, d, rows,
 ):
-    """Grid (batch, blocks-per-slot); the kv tile of cell (b, j) is pool
-    block `pt_ref[b, j]` — the page-table indirection happens in the
-    BlockSpec index map, so the body is `_online_softmax_cell` with a
-    per-SLOT cursor (`len_ref[b]`) instead of the pool-global scalar.
-    Blocks past the slot's length are skipped: `@pl.when` gates the
-    compute and the index map pins their DMA to the slot's block 0
-    (unchanged index -> Pallas elides the copy), so a slot with `p`
-    occupied positions pays O(p) cache reads however large the pool or
-    the per-slot capacity."""
-    b_idx = pl.program_id(0)
-    j = pl.program_id(1)
-    _online_softmax_cell(
-        len_ref[b_idx], start_ref[b_idx] if has_start else None,
-        j, pl.num_programs(1),
-        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-        sm_scale=sm_scale, block=block_size, n_heads=n_heads, d=d,
-    )
+    """Grid (slots,): one cell walks ONE slot's live pages, columns
+    `attn_start // block_size` to `len // block_size` of its page-table
+    row and no others, `pages` at a time. The pools stay in HBM; a
+    chunk's pages come by one async copy each into a (pages *
+    block_size, h*hd) VMEM buffer, K and V, two buffers deep: chunk c+1
+    is in flight while chunk c is computed, and a slot's last chunk
+    starts the next slot's first, so only the call's first chunk is
+    waited for with nothing to do (`first_buf` carries the buffer
+    parity from cell to cell, which is why the grid is "arbitrary").
+
+    The tile body is the online softmax of the flat kernel with the
+    heads batched: the query is laid out as `rows` block-diagonal rows
+    (row h holds head h's d lanes, zeros elsewhere), so ONE q @ K^T
+    over all h*hd lanes gives every head's scores (the zeros add
+    nothing: bf16 products, float32 sums, as a per-head dot) and ONE
+    p @ V gives (rows, h*hd), of which row h's own d lanes are head h's
+    output. Two matmuls a chunk where a head loop makes 2 * h, each on
+    an (8, d) query tile: that loop, not the bytes, set the old
+    kernel's time (PERF.md section 6, PR 25).
+
+    Pages of a chunk past the slot's last are not fetched; their rows
+    keep what an earlier chunk left (zeros at first), which is finite,
+    so the mask's -1e30 turns them into exact zeros."""
+    b = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    mb = pt_ref.shape[1]
+    bs = block_size
+    tile = pages * bs
+
+    def span(slot):
+        """First and last live page-table column of `slot`."""
+        last = jnp.minimum(len_ref[slot] // bs, mb - 1)
+        return jnp.clip(start_ref[slot] // bs, 0, last), last
+
+    def copies(slot, first, last, c, buf, go):
+        """Start (`go`) or wait for chunk c of `slot` in buffer `buf`."""
+        for i in range(pages):
+            col = first + c * pages + i
+
+            @pl.when(col <= last)
+            def _(i=i, col=col):
+                page = pt_ref[slot, col]
+                rows_i = pl.ds(i * bs, bs)
+                for hbm, dst, which in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                    dma = pltpu.make_async_copy(
+                        hbm.at[page], dst.at[buf, rows_i], sem.at[which, buf]
+                    )
+                    if go:
+                        dma.start()
+                    else:
+                        dma.wait()
+
+    first, last = span(b)
+    n_chunks = (last - first) // pages + 1
+
+    @pl.when(b == 0)
+    def _open():
+        k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        first_buf[0] = 0
+        copies(b, first, last, 0, 0, True)
+
+    buf0 = first_buf[0]
+    nxt_slot = jnp.minimum(b + 1, n_slots - 1)
+    nxt_first, nxt_last = span(nxt_slot)
+
+    hd_total = q_ref.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, hd_total), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, hd_total), 0)
+    own = (lane >= row * d) & (lane < (row + 1) * d)    # row h: head h's lanes
+    qs = (q_ref[...] * sm_scale).astype(q_ref.dtype)    # (1, h*hd)
+    q_bd = jnp.where(
+        own, jnp.broadcast_to(qs.astype(jnp.float32), own.shape), 0.0
+    ).astype(q_ref.dtype)
+    # a retired slot's pinned length may lie past its table
+    cur, start = jnp.minimum(len_ref[b], mb * bs - 1), start_ref[b]
+    offs = jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 1)
+
+    def chunk(c, state):
+        buf = (buf0 + c) % 2
+        ends = c + 1 == n_chunks
+
+        @pl.when(jnp.logical_not(ends) | (b + 1 < n_slots))
+        def _prefetch():
+            copies(jnp.where(ends, nxt_slot, b),
+                   jnp.where(ends, nxt_first, first),
+                   jnp.where(ends, nxt_last, last),
+                   jnp.where(ends, 0, c + 1), 1 - buf, True)
+
+        copies(b, first, last, c, buf, False)
+        k_pos = (first + c * pages) * bs + offs
+        penalty = jnp.where((k_pos <= cur) & (k_pos >= start), 0.0, _NEG_INF)
+        s = _dot_tb(q_bd, k_buf[buf]) + penalty          # (rows, tile) f32
+        return _softmax_accumulate(s, v_buf[buf], *state)
+
+    _, _, acc = lax.fori_loop(0, n_chunks, chunk, (
+        jnp.full((rows, _LANES), -jnp.inf, jnp.float32),
+        jnp.zeros((rows, _LANES), jnp.float32),
+        jnp.zeros((rows, hd_total), jnp.float32),
+    ))
+    first_buf[0] = (buf0 + n_chunks) % 2
+    o_ref[...] = jnp.sum(
+        jnp.where(own, acc, 0.0), axis=0, keepdims=True
+    ).astype(o_ref.dtype)
 
 
 def gather_pages(pages: jnp.ndarray, page_table: jnp.ndarray,
@@ -436,8 +540,8 @@ def paged_attention_reference(
     The span is the PER-SLOT capacity (sized to the request's own
     context budget), not the pool — the slot engine's cost driver was
     the pool-global [0, max_len) scan, which this path already removes.
-    It is also the correctness oracle for `_paged_kernel` (and its int8
-    variant) and the serving path on backends without the kernel (CPU
+    It is also the correctness oracle for `_paged_walk_kernel` (and the
+    int8 kernel) and the serving path on backends without the kernel (CPU
     tests; unpackable head shapes). An int8 pool dequantizes through
     its scale pages during the gather."""
     from ddp_practice_tpu.ops.attention import attention_with_mask
@@ -466,9 +570,15 @@ def _paged_kernel_quant(
     m_scr, l_scr, acc_scr,
     *, sm_scale, block_size, n_heads, d, has_start, compute_dtype,
 ):
-    """`_paged_kernel` over an INT8 block pool with per-block scale
-    pages: the (h, block_size) scale tiles ride the SAME page-table
-    index map as the K/V tiles they dequantize, the K scale multiplies
+    """The int8 block pool's kernel, one page a grid cell: grid (slots,
+    table columns), the kv tile of cell (b, j) is pool block
+    `pt_ref[b, j]` through the BlockSpec index map, and the per-block
+    (h, block_size) scale tiles ride the SAME map as the K/V tiles they
+    dequantize. Cells past the slot's length are gated off and their
+    DMA pinned to column 0 (unchanged index -> no copy), but each is
+    still a grid step: the bf16 pool left this walk for
+    `_paged_walk_kernel`, and this one follows when its scale pages can
+    ride a chunk's copies. The K scale multiplies
     the score row after the q.k dot and the V scale folds into the
     probability row before the p.v dot (`_softmax_accumulate(vs_row=)`) —
     no dequantized tile ever materializes, so HBM still streams
@@ -529,6 +639,13 @@ def paged_decode_attention(
     """One paged decode step; returns (b, 1, h*hd). See the module-level
     paged section for the layout.
 
+    The kernel is `_paged_walk_kernel`: one grid cell a slot, the
+    slot's live pages fetched `_pages_per_chunk` at a time by
+    double-buffered async copies, whatever the table's width. The
+    traced device op is ONE, named `paged_decode` (`paged_decode_int8`
+    for the int8 pool): perf/lib/readers.py sums the ops whose name
+    holds "paged_decode".
+
     impl: "auto" runs the Pallas kernel on TPU when the heads pack into
     128-lane tiles and the gather reference otherwise (on CPU the
     reference IS the fast path — interpret-mode pays python emulation
@@ -569,31 +686,22 @@ def paged_decode_attention(
         )
     sm_scale = 1.0 / (d ** 0.5)
     has_start = attn_start is not None
-    mb = page_table.shape[1]
     lens = jnp.asarray(lengths, jnp.int32)
     start = (
         jnp.asarray(attn_start, jnp.int32)
         if has_start else jnp.zeros((b,), jnp.int32)
     )
     pt = jnp.asarray(page_table, jnp.int32)
-
-    def kv_map(b_, j, len_ref, start_ref, pt_ref):
-        j_sel = lax.select(j * bs <= len_ref[b_], j, 0)
-        return (pt_ref[b_, j_sel], 0, 0)
-
-    common = dict(
-        grid=(b, mb),
-        out_specs=pl.BlockSpec((None, 1, hd_total),
-                               lambda b_, j, *_: (b_, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((n_heads, 8, _LANES), jnp.float32),
-            pltpu.VMEM((n_heads, 8, _LANES), jnp.float32),
-            pltpu.VMEM((8, hd_total), jnp.float32),
-        ],
-    )
-    q_spec = pl.BlockSpec((None, 1, hd_total), lambda b_, j, *_: (b_, 0, 0))
-    kv_spec = pl.BlockSpec((None, bs, hd_total), kv_map)
+    q_spec = pl.BlockSpec((None, 1, hd_total), lambda b_, *_: (b_, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((b, 1, hd_total), q.dtype)
+    interpret = not backend.on_tpu()
     if quant:
+        # the int8 pool keeps the one-page-a-cell walk (_paged_kernel_quant)
+        def kv_map(b_, j, len_ref, start_ref, pt_ref):
+            j_sel = lax.select(j * bs <= len_ref[b_], j, 0)
+            return (pt_ref[b_, j_sel], 0, 0)
+
+        kv_spec = pl.BlockSpec((None, bs, hd_total), kv_map)
         scale_spec = pl.BlockSpec((None, n_heads, bs), kv_map)
         kernel = functools.partial(
             _paged_kernel_quant, sm_scale=sm_scale, block_size=bs,
@@ -604,30 +712,48 @@ def paged_decode_attention(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
+                grid=(b, page_table.shape[1]),
                 in_specs=[q_spec, kv_spec, kv_spec,
                           scale_spec, scale_spec],
-                **common,
+                out_specs=q_spec,
+                scratch_shapes=[
+                    pltpu.VMEM((n_heads, 8, _LANES), jnp.float32),
+                    pltpu.VMEM((n_heads, 8, _LANES), jnp.float32),
+                    pltpu.VMEM((8, hd_total), jnp.float32),
+                ],
             ),
-            out_shape=jax.ShapeDtypeStruct((b, 1, hd_total), q.dtype),
+            out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")
             ),
-            interpret=not backend.on_tpu(),
+            interpret=interpret,
+            name="paged_decode_int8",
         )(lens, start, pt, q, k_pages, v_pages, k_scale, v_scale)
+    pages = _pages_per_chunk(bs, hd_total, k_pages.dtype)
     kernel = functools.partial(
-        _paged_kernel, sm_scale=sm_scale, block_size=bs,
-        n_heads=n_heads, d=d, has_start=has_start,
+        _paged_walk_kernel, sm_scale=sm_scale, block_size=bs, pages=pages,
+        d=d, rows=-(-n_heads // 16) * 16,   # whole bf16 sublane tiles
     )
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    chunk_buf = pltpu.VMEM((2, pages * bs, hd_total), k_pages.dtype)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            in_specs=[q_spec, kv_spec, kv_spec],
-            **common,
+            grid=(b,),
+            in_specs=[q_spec, pool_spec, pool_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                chunk_buf, chunk_buf,
+                pltpu.SemaphoreType.DMA((2, 2)),   # (K | V, buffer)
+                pltpu.SMEM((1,), jnp.int32),
+            ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, 1, hd_total), q.dtype),
+        out_shape=out_shape,
+        # cells run in order: each starts the next one's first chunk
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            dimension_semantics=("arbitrary",)
         ),
-        interpret=not backend.on_tpu(),
+        interpret=interpret,
+        name="paged_decode",
     )(lens, start, pt, q, k_pages, v_pages)

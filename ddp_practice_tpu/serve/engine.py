@@ -1736,9 +1736,16 @@ class PagedEngine(_EngineBase):
             grown = self._grow_tables(k)
             splits = self._cow_split(k)
         if traced:
+            # how far the kernel's walk is from O(table): step j of the
+            # burst attends pages attn // bs .. (len + j) // bs of a slot
+            act, bs = self._active, self.config.block_size
+            last_page = (self._len[act][:, None] + np.arange(k)) // bs
             span, disp, read = self._burst_spans(
                 "decode_burst", burst=k, blocks_grown=grown,
-                cow_splits=splits, blocks_free=self.blocks.num_free)
+                cow_splits=splits, blocks_free=self.blocks.num_free,
+                pages_walked=int(
+                    (last_page - self._attn[act][:, None] // bs + 1).sum()),
+                pages_held=int(self._nblk[act].sum()) * k)
         else:
             span = disp = read = _NULL
         with span:

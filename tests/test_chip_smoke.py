@@ -161,6 +161,18 @@ def _json_lines(capsys) -> list:
             if ln.startswith("{")]
 
 
+def test_paged_walk_check_rehearses_on_cpu(smoke):
+    """The smoke's ragged, left-padded walk check at a toy size, the
+    kernel interpreted: its lengths hold the edges it names and a sound
+    kernel passes its one-ulp bound."""
+    seen = smoke.paged_walk_check(
+        seed=3, slots=10, columns=10, pool=128, heads=2, impl="kernel",
+        require_kernels=False)
+    assert seen["paged_walk_mosaic_kernels"] == 0       # interpreted
+    assert seen["paged_walk_worst_bf16_ulps"] <= 1.0
+    assert seen["paged_walk_live_tokens"] > 10
+
+
 @pytest.mark.slow
 def test_one_chip_phases_rehearse_on_cpu(smoke, tmp_path, capsys):
     clock = smoke.CompileClock()

@@ -261,3 +261,173 @@ def test_paged_single_token_contract():
     out = paged_decode_attention(q_small, kp_small, kp_small, pt, lengths,
                                  n_heads=4)  # auto -> reference
     assert out.shape == (B, 1, 64)
+
+
+# ------------------------------------------------------- the page walk
+# ISSUE 25: one grid cell a slot walks the slot's live pages `P` at a
+# time by manual double-buffered DMA. Cases of ONE test; page 16 means
+# P = 8 (128-token chunks), so column 8 opens a slot's second chunk.
+LAST = 66 * 16 - 1   # last position of the last page of a 66-column table
+
+
+def _random_table(rng, b, mb, nb):
+    return rng.integers(1, nb, size=(b, mb))
+
+
+def _retired_table(rng, b, mb, nb):
+    """Slot 1 retired: its whole row names page 0 (the garbage page);
+    its length stays pinned, once inside the table and once past it."""
+    pt = _random_table(rng, b, mb, nb)
+    pt[1] = 0
+    pt[3] = 0
+    return pt
+
+
+def _shared_prefix_table(rng, b, mb, nb):
+    """Slots 0 and 1 share their first 9 pages (a chunk and a page)."""
+    pt = _random_table(rng, b, mb, nb)
+    pt[1, :9] = pt[0, :9]
+    return pt
+
+
+def _descending_table(rng, b, mb, nb):
+    """Every row's pages lie in the pool in the reverse of their order."""
+    return np.stack([np.arange(nb - 1 - i * mb, nb - 1 - (i + 1) * mb, -1)
+                     for i in range(b)])
+
+
+WALK_CASES = {
+    # name: (block_size, table columns, lengths, attn_start, table, dtype)
+    "lengths_at_page_and_chunk_edges": (
+        16, 66, [0, 1, 15, 16, 17, 127, 128, 129, LAST], None,
+        _random_table, jnp.float32),
+    "table_shorter_than_a_chunk": (
+        16, 3, [0, 20, 47], [0, 3, 17], _random_table, jnp.float32),
+    "attn_start_zero_midpage_later_chunk": (
+        16, 66, [40, 200, 700, LAST, 130], [0, 21, 300, 1040, 129],
+        _random_table, jnp.float32),
+    "retired_slot_beside_active": (
+        16, 66, [77, 5, 300, 66 * 16 + 5, 129], [0, 0, 140, 0, 16],
+        _retired_table, jnp.float32),
+    "shared_prefix_pages": (
+        16, 66, [150, 190, 9], [0, 0, 0], _shared_prefix_table, jnp.float32),
+    "page_ids_descending": (
+        16, 66, [LAST, 500, 127], [3, 130, 0], _descending_table,
+        jnp.float32),
+    "page_8_chunks_of_16_pages": (
+        8, 21, [0, 127, 128, 167], [0, 5, 126, 129], _random_table,
+        jnp.float32),
+    "page_32_chunks_of_4_pages": (
+        32, 7, [31, 128, 223, 100], [0, 33, 129, 99], _random_table,
+        jnp.float32),
+    "bf16_pool": (
+        16, 66, [250, 1000, 15, 129], [6, 517, 0, 0], _random_table,
+        jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_paged_walk_matches_reference(case):
+    from ddp_practice_tpu.ops.decode_attention import (
+        paged_attention_reference,
+        paged_decode_attention,
+    )
+
+    bs, mb, lengths, start, table, dtype = WALK_CASES[case]
+    b = len(lengths)
+    nb = 1 + b * mb
+    rng = np.random.default_rng(sorted(WALK_CASES).index(case))
+    q = jnp.asarray(rng.normal(size=(b, 1, H * HD)), dtype)
+    kp = jnp.asarray(rng.normal(size=(nb, bs, H * HD)), dtype)
+    vp = jnp.asarray(rng.normal(size=(nb, bs, H * HD)), dtype)
+    pt = jnp.asarray(table(rng, b, mb, nb), jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    start = None if start is None else jnp.asarray(start, jnp.int32)
+    ref = paged_attention_reference(q, kp, vp, pt, lengths, start, n_heads=H)
+    got = paged_decode_attention(q, kp, vp, pt, lengths, start, n_heads=H,
+                                 impl="kernel")
+    # bf16: an ulp of outputs that reach 2 (chip_smoke.py's bound)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=tol, rtol=tol)
+
+
+def test_paged_walk_skips_what_it_may_and_nothing_else():
+    """Pages under `attn_start` and past `len` are never read (NaNs
+    there change nothing); every page between them is (a NaN in any
+    shows)."""
+    from ddp_practice_tpu.ops.decode_attention import paged_decode_attention
+
+    bs, mb, b = 16, 66, 2
+    nb = 1 + b * mb
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.normal(size=(b, 1, H * HD)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(nb, bs, H * HD)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(nb, bs, H * HD)), jnp.float32)
+    pt = np.arange(1, nb).reshape(b, mb)
+    lengths = jnp.asarray([700, 40], jnp.int32)     # pages 0..43, 0..2
+    start = jnp.asarray([300, 17], jnp.int32)       # from page 18, 1
+
+    @jax.jit
+    def kernel(k, v):
+        return paged_decode_attention(
+            q, k, v, jnp.asarray(pt, jnp.int32), lengths, start, n_heads=H,
+            impl="kernel")
+
+    def run(k, v):
+        return np.asarray(kernel(k, v))
+
+    want = run(kp, vp)
+    dead = np.concatenate([pt[0, :18], pt[0, 44:], pt[1, :1], pt[1, 3:], [0]])
+    np.testing.assert_array_equal(
+        run(kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan)), want)
+    for page in (pt[0, 18], pt[0, 25], pt[0, 26], pt[0, 43], pt[1, 1],
+                 pt[1, 2]):
+        slot = 0 if page in pt[0] else 1
+        assert np.isnan(run(kp, vp.at[page].set(jnp.nan))[slot]).all(), page
+
+
+@pytest.mark.parametrize("block_size,width,dtype,pages", [
+    (16, 768, jnp.bfloat16, 8),     # the flood cell: 128 tokens, 4 x 196 KB
+    (8, 768, jnp.bfloat16, 16),
+    (32, 768, jnp.bfloat16, 4),
+    (24, 256, jnp.float32, 6),      # 144 tokens: never under 128
+    (128, 1024, jnp.bfloat16, 1),
+    (256, 1024, jnp.bfloat16, 1),   # a page longer than a chunk: one a time
+    (16, 16384, jnp.bfloat16, 4),   # too wide for 4 x 128 rows in 8 MiB
+])
+def test_pages_per_chunk_follows_the_shapes(block_size, width, dtype, pages):
+    from ddp_practice_tpu.ops.decode_attention import (
+        _CHUNK_VMEM_BYTES,
+        _pages_per_chunk,
+    )
+
+    got = _pages_per_chunk(block_size, width, dtype)
+    assert got == pages
+    buffers = 4 * got * block_size * width * jnp.dtype(dtype).itemsize
+    assert got == 1 or buffers <= _CHUNK_VMEM_BYTES
+
+
+@pytest.mark.parametrize("case", ["attn_start_zero_midpage_later_chunk",
+                                  "retired_slot_beside_active"])
+def test_paged_walk_waits_for_what_it_reads(case, monkeypatch):
+    """The same cases under the TPU interpreter, which lands a DMA's
+    bytes only when it is WAITED for and watches for races: a chunk
+    read before its wait, or a buffer refilled while it is read, shows
+    here and nowhere else on the CPU (plain interpret mode copies at
+    `start`)."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    pallas_call = pl.pallas_call
+
+    def on_wait(*args, interpret, **kw):
+        assert interpret is True
+        return pallas_call(*args, **kw, interpret=pltpu.InterpretParams(
+            detect_races=True, dma_execution_mode="on_wait"))
+
+    monkeypatch.setattr(pl, "pallas_call", on_wait)
+    test_paged_walk_matches_reference(case)
+    assert not interpret_pallas_call.races.races_found

@@ -151,6 +151,55 @@ def test_every_tick_is_one_span_with_its_phases_as_children(lm, kind):
             assert ev["args"]["parent"] in begins
 
 
+def test_decode_burst_says_how_far_the_walk_is_from_the_table(lm):
+    """ISSUE 25: under the tracer a `decode_burst` span counts the pages
+    the paged kernel walks (`attn // bs` to `(len + j) // bs` of every
+    active slot, every step j of the burst) beside the pages the slots
+    hold; a left-padded prompt's leading pages are held and not walked."""
+    import numpy as np
+
+    from ddp_practice_tpu.serve.engine import EngineConfig, PagedEngine
+    from ddp_practice_tpu.serve.scheduler import (
+        FakeClock,
+        Request,
+        Scheduler,
+    )
+
+    model, params = lm
+    bs, k = 4, 2
+    engine = PagedEngine(model, params, EngineConfig(
+        max_slots=2, prompt_buckets=(8,), eos_id=None, block_size=bs,
+        decode_burst=k))
+    clock = FakeClock(step_s=0.01)
+    rec = TraceRecorder(clock=clock)
+    engine.set_tracer(rec, 0)
+    sched = Scheduler(engine, clock=clock, tracer=rec, replica=0)
+    # 2 tokens in a bucket of 8: attn_start 6, so page 0 is never walked
+    for rid, prompt in enumerate([[1, 2], [1, 2, 3, 4, 5, 6, 7]]):
+        sched.submit(Request(rid=rid, prompt=prompt, max_new_tokens=6))
+    step_burst, before = engine.step_burst, []
+
+    def spy():
+        before.append((engine._active.copy(), engine._len.copy(),
+                       engine._attn.copy()))
+        out = step_burst()
+        before[-1] += (engine._nblk.copy(),)
+        return out
+
+    engine.step_burst = spy
+    while not sched.idle:
+        sched.step()
+    bursts = [r for r in lane_spans(rec) if r.name == "decode_burst"]
+    assert len(bursts) == len(before) >= 3
+    for span, (active, length, attn, nblk) in zip(bursts, before):
+        walked = sum((int(length[s]) + j) // bs - int(attn[s]) // bs + 1
+                     for s in np.flatnonzero(active) for j in range(k))
+        assert span.attrs["pages_walked"] == walked > 0
+        assert span.attrs["pages_held"] == k * int(nblk[active].sum())
+        assert type(span.attrs["pages_walked"]) is int
+    assert bursts[0].attrs["pages_walked"] < bursts[0].attrs["pages_held"]
+
+
 def test_self_time_comes_from_linkage(lm):
     """A tick's self time is its duration minus its children's: under the
     FakeClock every clock step is taken by `deliver` (one a token row), so
@@ -421,6 +470,36 @@ def pallas_names(jaxpr) -> list:
             if hasattr(inner, "eqns"):
                 out.extend(pallas_names(inner))
     return out
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_the_paged_decode_kernel_is_one_op_named_paged_decode(pool):
+    """`perf/lib/readers.py paged_decode_roofline_pct` sums every traced
+    op whose name holds "paged_decode": the kernel is ONE pallas_call a
+    call (no gather beside it) and carries the name itself, whatever
+    module scope it is traced under (tests/test_tpu_compile.py reads the
+    compiled custom call's name)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddp_practice_tpu.ops.decode_attention import paged_decode_attention
+
+    slots, mb, bs, heads, d = 2, 3, 16, 2, 64
+    dtype = jnp.bfloat16 if pool == "bf16" else jnp.int8
+    pages = jnp.zeros((1 + slots * mb, bs, heads * d), dtype)
+    scale = (None if pool == "bf16"
+             else jnp.ones((pages.shape[0], heads, bs), jnp.float32))
+
+    def step(q, k, v, table, lengths, start):
+        return paged_decode_attention(
+            q, k, v, table, lengths, start, n_heads=heads, k_scale=scale,
+            v_scale=scale, impl="kernel")
+
+    names = pallas_names(jax.make_jaxpr(step)(
+        jnp.zeros((slots, 1, heads * d), jnp.bfloat16), pages, pages,
+        jnp.zeros((slots, mb), jnp.int32), jnp.zeros((slots,), jnp.int32),
+        jnp.zeros((slots,), jnp.int32)).jaxpr)
+    assert len(names) == 1 and "paged_decode" in names[0], names
 
 
 @pytest.mark.parametrize("heads,variant", [(2, "_packed"), (3, "")])

@@ -135,7 +135,8 @@ def _decode_packed(L, int8=False):
     return step, args
 
 
-def _paged(block, int8=False, with_start=True, slots=8, blocks_per_slot=32):
+def _paged(block, int8=False, with_start=True, slots=8, blocks_per_slot=32,
+           pool=None):
     from ddp_practice_tpu.ops.decode_attention import paged_decode_attention
 
     def step(q, k, v, table, lengths, start, *scales):
@@ -145,7 +146,7 @@ def _paged(block, int8=False, with_start=True, slots=8, blocks_per_slot=32):
             n_heads=H, k_scale=ks, v_scale=vs, impl="auto",
         )
 
-    nb = 1 + slots * blocks_per_slot
+    nb = pool or 1 + slots * blocks_per_slot
     pool = _sds((nb, block, HD), jnp.int8 if int8 else BF16)
     args = (_sds((slots, 1, HD)), pool, pool,
             _sds((slots, blocks_per_slot), jnp.int32),
@@ -213,6 +214,11 @@ KERNELS = {
     "decode_int8_L2048": functools.partial(_decode_packed, 2048, int8=True),
     "paged_b16": functools.partial(_paged, 16),
     "paged_b16_no_start": functools.partial(_paged, 16, with_start=False),
+    # the flood cell's engine (PERF.md section 4): 64 slots, 66 table
+    # columns (not a multiple of the walk's 8 pages), a 4,352-page pool
+    "paged_b16_flood": functools.partial(
+        _paged, 16, slots=64, blocks_per_slot=66, pool=4352),
+    "paged_b32": functools.partial(_paged, 32),
     "paged_int8_b16": functools.partial(_paged, 16, int8=True),
     "paged_int8_b32": functools.partial(_paged, 32, int8=True),
     "fused_encoder_vit_tiny": functools.partial(_fused_encoder, False),
@@ -229,7 +235,15 @@ KERNELS = {
 ])
 def test_kernel_compiles_for_v5e(topo, name):
     fn, args = KERNELS[name]()
-    _compile(fn, *args, device=topo.devices[0])
+    text = _compile(fn, *args, device=topo.devices[0])
+    if name.startswith("paged"):
+        # ONE device op a call, named by the kernel's `name=`:
+        # perf/lib/readers.py sums every traced op whose name holds
+        # "paged_decode" (a gather beside the compute would be a second)
+        calls = [ln.split("=")[0].strip().lstrip("%") for ln in
+                 text.splitlines() if "custom-call(" in ln
+                 and "tpu_custom_call" in ln]
+        assert len(calls) == 1 and "paged_decode" in calls[0], calls
 
 
 def test_flash_compiles_sharded_over_four_devices(topo):
