@@ -19,6 +19,7 @@ from ddp_practice_tpu.models.pipeline_lm import PipelinedLM
 from ddp_practice_tpu.models.pipeline_vit import PipelinedViT
 from ddp_practice_tpu.models.vit_moe import ViTMoE
 from ddp_practice_tpu.models.lm import LMBase, LMTiny, TransformerLM
+from ddp_practice_tpu.models.hybrid_lm import HybridLM
 
 _REGISTRY = {}
 # registry names whose module exposes the tri-state `fused` field
@@ -199,6 +200,19 @@ def _lm_pipe(*, num_classes, policy, axis_name, **kw):
     )
 
 
+@register("nemotron_h")
+def _nemotron_h(*, num_classes, policy, axis_name, **kw):
+    # Mamba-2 + LatentMoE + grouped-query attention by a pattern string;
+    # LM registry convention (vocab_size is the explicit kwarg). The
+    # defaults are test-sized: the published widths come as options
+    # (perf/families/nemotron_h.py model_options)
+    return HybridLM(
+        dtype=policy.compute_dtype,
+        param_dtype=policy.param_dtype,
+        **kw,
+    )
+
+
 __all__ = [
     "create_model",
     "ConvNet",
@@ -212,6 +226,7 @@ __all__ = [
     "PipelinedViT",
     "ViTMoE",
     "TransformerLM",
+    "HybridLM",
     "LMTiny",
     "LMBase",
 ]

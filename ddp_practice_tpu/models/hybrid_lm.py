@@ -1,0 +1,219 @@
+"""A decoder of mixed layers: Mamba-2 mixers, latent expert layers and
+grouped-query attention, one mixer a layer, chosen by a pattern string.
+
+    x = x + mixer_i(RMSNorm(x))        i over `pattern`
+    'M'  Mamba-2 mixer                 (ops/ssm.py)
+    'E'  LatentMoE, a chip's share of the experts held  (ops/moe.py)
+    '*'  causal attention, `kv_heads` <= `num_heads`, no positional
+         embedding: the recurrent layers carry position
+    logits = RMSNorm(x) W_head         untied, over `vocab_size` rows
+
+The Nemotron-H layout (`create_model("nemotron_h", ...)`); the widths are
+options, so the tests run it small and the benchmark at the published
+sizes (perf/configs/nemotron3_super_ep4.json).
+
+Decode mode keeps TWO kinds of cache in the "cache" collection: attention
+layers the K/V leaves `SelfAttention` declares (flat, or pages under
+`PagedEngine`), Mamba layers a fixed-size state a sequence, `ssm_state`
+(b, heads, head_dim, state) in float32 and `conv_state`, the conv's last
+`conv_kernel - 1` inputs. A call with one token a sequence advances the
+state by the recurrence; a call with more runs the chunked scan FROM the
+stored state (zeros for a fresh sequence) and leaves the final state.
+Left padding (`attn_start`) moves neither: a padded position has dt = 0
+and a zero conv input. Pages cannot re-derive a state, so what needs a
+sequence's past at an arbitrary position (a paged call of several tokens:
+prefix reuse, chunked prefill, speculative verify) is refused here and at
+engine construction.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ddp_practice_tpu.models.vit import SelfAttention
+from ddp_practice_tpu.ops import ssm
+from ddp_practice_tpu.ops.moe import LatentMoE
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    # > 1: the last dim is normalised in that many equal groups
+    groups: int = 1
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           self.param_dtype)
+        xf = x.astype(jnp.float32)
+        shaped = xf.reshape(*x.shape[:-1], self.groups, -1)
+        shaped = shaped * jax.lax.rsqrt(
+            jnp.mean(jnp.square(shaped), axis=-1, keepdims=True) + self.eps)
+        return (shaped.reshape(x.shape) * scale.astype(jnp.float32)
+                ).astype(self.dtype)
+
+
+class Mamba2Mixer(nn.Module):
+    num_heads: int
+    head_dim: int
+    state_size: int
+    groups: int
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    norm_eps: float = 1e-5
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, *, decode: bool = False, attn_start=None,
+                 paged: bool = False):
+        b, s, d = x.shape
+        h, p, n, g = (self.num_heads, self.head_dim, self.state_size,
+                      self.groups)
+        inner, gn = h * p, g * n
+        conv_dim = inner + 2 * gn
+        vec = lambda name, shape: self.param(
+            name, nn.initializers.normal(0.02), shape, self.param_dtype)
+        proj = nn.Dense(2 * inner + 2 * gn + h, use_bias=False,
+                        dtype=self.dtype, param_dtype=self.param_dtype,
+                        name="in_proj")(x)
+        z, xbc, dt = jnp.split(proj, [inner, inner + conv_dim], axis=-1)
+        conv_w = vec("conv_kernel", (self.conv_kernel, conv_dim))
+        conv_b = vec("conv_bias", (conv_dim,))
+        a = -jnp.exp(vec("A_log", (h,)).astype(jnp.float32))
+        d_skip = vec("D", (h,))
+        dt = jax.nn.softplus(
+            dt.astype(jnp.float32) + vec("dt_bias", (h,)).astype(jnp.float32))
+        if attn_start is not None and s > 1:
+            # a call of several tokens is a padded row from its position 0
+            # (the engines' prefill); a single token is always real
+            real = jnp.arange(s)[None, :] >= attn_start[:, None]   # (b, s)
+            dt = jnp.where(real[..., None], dt, 0.0)
+            xbc = jnp.where(real[..., None], xbc, 0)
+        state0 = jnp.zeros((b, h, p, n), jnp.float32)
+        tail0 = jnp.zeros((b, self.conv_kernel - 1, conv_dim), self.dtype)
+        if decode:
+            if paged and s > 1:
+                raise ValueError(
+                    "a recurrent layer cannot take several tokens at slot-"
+                    "local positions through pages: its state holds only "
+                    "the sequence's end (prefix reuse, chunked prefill and "
+                    "speculative verify need state snapshots)")
+            ssm_state = self.variable(
+                "cache", "ssm_state", lambda: state0)
+            conv_state = self.variable(
+                "cache", "conv_state", lambda: tail0)
+            if not self.is_initializing():
+                state0, tail0 = ssm_state.value, conv_state.value
+        xbc, tail = ssm.causal_conv(xbc, tail0, conv_w, conv_b)
+        xbc = nn.silu(xbc)
+        xs, bm, cm = jnp.split(xbc, [inner, inner + gn], axis=-1)
+        xs = xs.reshape(b, s, h, p)
+        bm, cm = bm.reshape(b, s, g, n), cm.reshape(b, s, g, n)
+        if decode and s == 1 and not self.is_initializing():
+            y, state = ssm.ssm_step(xs[:, 0], dt[:, 0], a, bm[:, 0],
+                                    cm[:, 0], d_skip, state0)
+            y = y[:, None]
+        else:
+            y, state = ssm.ssm_scan(xs, dt, a, bm, cm, d_skip, state0,
+                                    chunk=self.chunk_size)
+        if decode and not self.is_initializing():
+            ssm_state.value = state
+            conv_state.value = tail.astype(conv_state.value.dtype)
+        y = y.reshape(b, s, inner).astype(self.dtype) * nn.silu(z)
+        y = RMSNorm(self.norm_eps, self.dtype, self.param_dtype,
+                    groups=g, name="norm")(y)
+        return nn.Dense(d, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype, name="out_proj")(y)
+
+
+class HybridLM(nn.Module):
+    pattern: str = "MEM*EME"
+    vocab_size: int = 256
+    hidden_dim: int = 64
+    max_len: int = 262144
+    # 'M'
+    mamba_heads: int = 8
+    mamba_head_dim: int = 8
+    ssm_state: int = 16
+    ssm_groups: int = 2
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    # '*'
+    num_heads: int = 4
+    kv_heads: int = 2
+    head_dim: int = 16
+    # 'E'
+    num_experts: int = 16
+    top_k: int = 3
+    latent_dim: int = 32
+    expert_dim: int = 48
+    shared_dim: int = 96
+    experts_held: int = 4
+    expert_offset: int = 0
+    routed_scaling: float = 1.0
+    norm_eps: float = 1e-5
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    # what the serving engines ask a model: how positions enter (here the
+    # recurrent layers carry them) and whether a sequence has state that
+    # pages cannot hold
+    pos_emb: str = "none"
+    recurrent: bool = True
+    axis_name: Optional[str] = None  # registry uniformity
+
+    @nn.compact
+    def __call__(self, tokens, *, train: bool = False, decode: bool = False,
+                 attn_start=None, page_table=None, kv_lengths=None):
+        """tokens (batch, seq) int32 -> logits (batch, seq, vocab_size) in
+        the compute dtype. `decode`, `attn_start`, `page_table` and
+        `kv_lengths` as in models/lm.py TransformerLM."""
+        del train
+        if set(self.pattern) - set("ME*") or not self.pattern:
+            raise ValueError(
+                f"pattern {self.pattern!r}: want a string of 'M', 'E', '*'")
+        if self.hidden_dim != self.num_heads * self.head_dim:
+            raise ValueError(
+                "SelfAttention takes its head size from the width: "
+                f"hidden_dim {self.hidden_dim} != num_heads "
+                f"{self.num_heads} x head_dim {self.head_dim}")
+        if (page_table is not None or attn_start is not None) and not decode:
+            raise ValueError("page_table / attn_start are decode features")
+        if tokens.shape[1] > self.max_len:
+            raise ValueError(
+                f"sequence {tokens.shape[1]} exceeds max_len {self.max_len}")
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        x = nn.Embed(self.vocab_size, self.hidden_dim, name="tok_embed",
+                     **kw)(tokens)
+        for i, kind in enumerate(self.pattern):
+            y = RMSNorm(self.norm_eps, name=f"norm{i}", **kw)(x)
+            if kind == "M":
+                y = Mamba2Mixer(
+                    self.mamba_heads, self.mamba_head_dim, self.ssm_state,
+                    self.ssm_groups, self.conv_kernel, self.chunk_size,
+                    self.norm_eps, name=f"mamba{i}", **kw,
+                )(y, decode=decode, attn_start=attn_start,
+                  paged=page_table is not None)
+            elif kind == "E":
+                y = LatentMoE(
+                    self.num_experts, self.top_k, self.latent_dim,
+                    self.expert_dim, self.shared_dim, self.experts_held,
+                    self.expert_offset, self.routed_scaling,
+                    name=f"moe{i}", **kw,
+                )(y, decode=decode)
+            else:
+                y = SelfAttention(
+                    self.num_heads, causal=True, rope=False,
+                    kv_heads=self.kv_heads, use_bias=False,
+                    name=f"attn{i}", **kw,
+                )(y, decode=decode, attn_start=attn_start,
+                  page_table=page_table, kv_lengths=kv_lengths)
+            x = x + y
+        x = RMSNorm(self.norm_eps, name="norm_f", **kw)(x)
+        return nn.Dense(self.vocab_size, use_bias=False, name="lm_head",
+                        **kw)(x)

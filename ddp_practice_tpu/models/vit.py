@@ -15,6 +15,7 @@ ring path); compute dtype policy-driven (bf16), logits fp32.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import flax.linen as nn
@@ -111,6 +112,39 @@ class SelfAttention(nn.Module):
     # Writes round to this dtype; attention math runs at the q/k
     # promotion (int8 dequantizes inside the packed kernel).
     kv_cache_dtype: object = None  # None | jnp.dtype | "int8"
+    # grouped-query attention: `kv_heads` key/value heads serve
+    # num_heads query heads (query head i reads KV head i // group).
+    # None = num_heads (the fused three-way `qkv` projection); fewer
+    # splits the projection into `q` and `kv`, and every cache leaf is
+    # kv_heads * head_dim wide.
+    kv_heads: Optional[int] = None
+    use_bias: bool = True
+
+    def _project(self, x, head_dim: int):
+        """(q, k, v, fused): q (b, s, h, hd), k and v (b, s, kv_heads,
+        hd); `fused` is the raw (b, s, 3, h, hd) projection when there is
+        one (the packed flash kernels window it)."""
+        dense = functools.partial(
+            nn.DenseGeneral, dtype=self.dtype, param_dtype=self.param_dtype,
+            use_bias=self.use_bias)
+        kvh = self.kv_heads or self.num_heads
+        if kvh == self.num_heads:
+            qkv = dense((3, self.num_heads, head_dim), name="qkv")(x)
+            return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], qkv
+        if self.num_heads % kvh:
+            raise ValueError(
+                f"kv_heads {kvh} must divide num_heads {self.num_heads}")
+        q = dense((self.num_heads, head_dim), name="q")(x)
+        kv = dense((2, kvh, head_dim), name="kv")(x)
+        return q, kv[:, :, 0], kv[:, :, 1], None
+
+    def _widen_kv(self, k, v):
+        """K and V repeated to one head a query head (the dense attention
+        paths; the paged walk kernel reads the grouped cache as it is)."""
+        group = self.num_heads // k.shape[2]
+        if group == 1:
+            return k, v
+        return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
 
     @nn.compact
     def __call__(self, x, *, decode: bool = False, attn_start=None,
@@ -118,17 +152,13 @@ class SelfAttention(nn.Module):
         b, s, d = x.shape
         assert d % self.num_heads == 0, (d, self.num_heads)
         head_dim = d // self.num_heads
-        qkv = nn.DenseGeneral(
-            (3, self.num_heads, head_dim),
-            dtype=self.dtype,
-            param_dtype=self.param_dtype,
-            name="qkv",
-        )(x)
+        q, k, v, qkv = self._project(x, head_dim)
         if (
             self.attn_impl == "flash"
             and not decode
             and not self.rope
             and self.seq_axis is None
+            and qkv is not None
         ):
             # hand the raw projection output to the packed kernels: the
             # (3, h, hd) feature flatten IS the [q|k|v] column layout they
@@ -164,7 +194,6 @@ class SelfAttention(nn.Module):
                 out_specs=BSHD_SPEC,
             )(qkv)
             return self._out_proj(out)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         if self.rope and not decode:
             # global positions: under GSPMD jit the sequence dim is sharded
             # by annotation, not split — s IS the global length (the SP
@@ -238,7 +267,8 @@ class SelfAttention(nn.Module):
                     (b_, h_, s_), jnp.float32,
                 )
             if self.is_initializing():
-                out = dot_product_attention(q, k, v, causal=True, impl="xla")
+                out = dot_product_attention(
+                    q, *self._widen_kv(k, v), causal=True, impl="xla")
             else:
                 from jax import lax
 
@@ -294,7 +324,8 @@ class SelfAttention(nn.Module):
                 cached_key.value = kc
                 cached_value.value = vc
                 cache_index.value = cur + s
-                if s == 1 and _heads_per_pack(h_, hd_) is not None:
+                if (s == 1 and h_ == self.num_heads
+                        and _heads_per_pack(h_, hd_) is not None):
                     # token step: packed kernel on the flat cache —
                     # no reshape, O(cur) cache reads (int8: scales ride
                     # as separate small operands)
@@ -330,10 +361,12 @@ class SelfAttention(nn.Module):
                             >= attn_start[:, None, None]
                         )
                         mask = mask[:, None]  # (b, 1, sq, sk)
-                    out = attention_with_mask(q, k4, v4, mask)
+                    out = attention_with_mask(
+                        q, *self._widen_kv(k4, v4), mask)
         else:
             out = dot_product_attention(
-                q, k, v, causal=self.causal, seq_axis=self.seq_axis,
+                q, *self._widen_kv(k, v), causal=self.causal,
+                seq_axis=self.seq_axis,
                 sp_impl=self.sp_impl, impl=self.attn_impl,
             )
         return self._out_proj(out)
@@ -383,12 +416,7 @@ class SelfAttention(nn.Module):
             raise ValueError(
                 "paged decode needs kv_lengths (per-slot write positions)"
             )
-        if not self.rope:
-            raise ValueError(
-                "paged decode needs rope=True — slot-local positions "
-                "require relative position encoding"
-            )
-        b_, s_, h_, hd_ = k.shape
+        b_, s_, h_, hd_ = k.shape   # h_: KV heads (the pool's width)
         if self.is_initializing():
             raise ValueError(
                 "paged cache pools are allocated by serve/kv_pages.py "
@@ -428,8 +456,12 @@ class SelfAttention(nn.Module):
         pos0 = jnp.asarray(kv_lengths, jnp.int32)
         # (b, s) slot-local positions of the incoming tokens
         positions = pos0[:, None] + jnp.arange(s_, dtype=jnp.int32)[None, :]
-        q = apply_rope(q, positions)
-        k = apply_rope(k, positions)
+        if self.rope:
+            # no rotation at all is as slot-local as a rotary one (a model
+            # whose other layers carry position); a learned absolute table
+            # is refused where it lives (models/lm.py)
+            q = apply_rope(q, positions)
+            k = apply_rope(k, positions)
         if quant:
             def _quantize(x4):
                 # per-(batch, token, head) symmetric int8, same recipe
@@ -472,9 +504,10 @@ class SelfAttention(nn.Module):
         if s_ == 1:
             out = paged_decode_attention(
                 q.reshape(b_, 1, -1), kc, vc, page_table, pos0, attn_start,
-                n_heads=h_, k_scale=ks_pool, v_scale=vs_pool,
+                n_heads=self.num_heads, n_kv_heads=h_,
+                k_scale=ks_pool, v_scale=vs_pool,
             )
-            return out.reshape(b_, 1, h_, hd_)
+            return out.reshape(b_, 1, self.num_heads, hd_)
         # paged prefill: gather the slot's span once (dequantizing int8
         # pools through their scale pages) and mask causally per query
         # row in slot-local coordinates
@@ -486,10 +519,11 @@ class SelfAttention(nn.Module):
         if attn_start is not None:
             valid &= kpos[None, None, :] >= attn_start[:, None, None]
         cd = pool_dtype if not quant else q.dtype
+        k4, v4 = self._widen_kv(k4, v4)
         out = attention_with_mask(
             q.astype(cd), k4.astype(cd), v4.astype(cd), valid[:, None]
         )
-        return out.reshape(b_, s_, h_, hd_).astype(q.dtype)
+        return out.reshape(b_, s_, self.num_heads, hd_).astype(q.dtype)
 
     def _out_proj(self, out):
         """Shared output projection over (b, s, h, hd) attention output —
@@ -501,6 +535,7 @@ class SelfAttention(nn.Module):
             axis=(-2, -1),
             dtype=self.dtype,
             param_dtype=self.param_dtype,
+            use_bias=self.use_bias,
             name="out",
         )(out)
 

@@ -390,7 +390,7 @@ def _paged_walk_kernel(
     len_ref, start_ref, pt_ref,          # scalar prefetch (SMEM)
     q_ref, k_hbm, v_hbm, o_ref,          # q/out blocks; the pools, in HBM
     k_buf, v_buf, sem, first_buf,        # scratch
-    *, sm_scale, block_size, pages, d, rows,
+    *, sm_scale, block_size, pages, d, rows, kv_heads=None,
 ):
     """Grid (slots,): one cell walks ONE slot's live pages, columns
     `attn_start // block_size` to `len // block_size` of its page-table
@@ -411,6 +411,12 @@ def _paged_walk_kernel(
     output. Two matmuls a chunk where a head loop makes 2 * h, each on
     an (8, d) query tile: that loop, not the bytes, set the old
     kernel's time (PERF.md section 6, PR 25).
+
+    Grouped queries (`kv_heads` set: fewer KV heads than query heads) need
+    no block diagonal: q and the output come as (heads, d) blocks, and the
+    `rows` query heads that share KV head j are the rows of ONE matmul
+    against that head's own d lanes of the chunk, so a chunk is two
+    matmuls a KV head and K and V are read once for the whole group.
 
     Pages of a chunk past the slot's last are not fetched; their rows
     keep what an earlier chunk left (zeros at first), which is finite,
@@ -458,19 +464,26 @@ def _paged_walk_kernel(
     nxt_slot = jnp.minimum(b + 1, n_slots - 1)
     nxt_first, nxt_last = span(nxt_slot)
 
-    hd_total = q_ref.shape[-1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, hd_total), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (rows, hd_total), 0)
-    own = (lane >= row * d) & (lane < (row + 1) * d)    # row h: head h's lanes
-    qs = (q_ref[...] * sm_scale).astype(q_ref.dtype)    # (1, h*hd)
-    q_bd = jnp.where(
-        own, jnp.broadcast_to(qs.astype(jnp.float32), own.shape), 0.0
-    ).astype(q_ref.dtype)
+    grouped = kv_heads is not None
     # a retired slot's pinned length may lie past its table
     cur, start = jnp.minimum(len_ref[b], mb * bs - 1), start_ref[b]
     offs = jax.lax.broadcasted_iota(jnp.int32, (rows, tile), 1)
+    if grouped:
+        qs = (q_ref[...] * sm_scale).astype(q_ref.dtype)    # (h, d)
+        lanes = [(j * rows, j * d) for j in range(kv_heads)]
+        width = d
+    else:
+        hd_total = q_ref.shape[-1]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, hd_total), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, hd_total), 0)
+        own = (lane >= row * d) & (lane < (row + 1) * d)  # row h: head h
+        qs = (q_ref[...] * sm_scale).astype(q_ref.dtype)    # (1, h*hd)
+        q_bd = jnp.where(
+            own, jnp.broadcast_to(qs.astype(jnp.float32), own.shape), 0.0
+        ).astype(q_ref.dtype)
+        lanes, width = [None], hd_total
 
-    def chunk(c, state):
+    def chunk(c, states):
         buf = (buf0 + c) % 2
         ends = c + 1 == n_chunks
 
@@ -484,18 +497,30 @@ def _paged_walk_kernel(
         copies(b, first, last, c, buf, False)
         k_pos = (first + c * pages) * bs + offs
         penalty = jnp.where((k_pos <= cur) & (k_pos >= start), 0.0, _NEG_INF)
-        s = _dot_tb(q_bd, k_buf[buf]) + penalty          # (rows, tile) f32
-        return _softmax_accumulate(s, v_buf[buf], *state)
+        if not grouped:
+            s = _dot_tb(q_bd, k_buf[buf]) + penalty      # (rows, tile) f32
+            return (_softmax_accumulate(s, v_buf[buf], *states[0]),)
+        out = []
+        for (r0, l0), state in zip(lanes, states):
+            s = _dot_tb(qs[r0:r0 + rows],
+                        k_buf[buf, :, l0:l0 + d]) + penalty
+            out.append(_softmax_accumulate(
+                s, v_buf[buf, :, l0:l0 + d], *state))
+        return tuple(out)
 
-    _, _, acc = lax.fori_loop(0, n_chunks, chunk, (
+    states = lax.fori_loop(0, n_chunks, chunk, tuple((
         jnp.full((rows, _LANES), -jnp.inf, jnp.float32),
         jnp.zeros((rows, _LANES), jnp.float32),
-        jnp.zeros((rows, hd_total), jnp.float32),
-    ))
+        jnp.zeros((rows, width), jnp.float32),
+    ) for _ in lanes))
     first_buf[0] = (buf0 + n_chunks) % 2
-    o_ref[...] = jnp.sum(
-        jnp.where(own, acc, 0.0), axis=0, keepdims=True
-    ).astype(o_ref.dtype)
+    if grouped:
+        for (r0, _), (_, _, acc) in zip(lanes, states):
+            o_ref[r0:r0 + rows, :] = acc.astype(o_ref.dtype)
+    else:
+        o_ref[...] = jnp.sum(
+            jnp.where(own, states[0][2], 0.0), axis=0, keepdims=True
+        ).astype(o_ref.dtype)
 
 
 def gather_pages(pages: jnp.ndarray, page_table: jnp.ndarray,
@@ -531,6 +556,7 @@ def paged_attention_reference(
     attn_start=None,          # optional (b,) int32 slot-local first key
     *,
     n_heads: int,
+    n_kv_heads=None,          # KV heads in the pool (None = n_heads)
     k_scale=None,             # (num_blocks, h, block_size) f32 — int8
     v_scale=None,             # pool per-block dequant scale pages
 ) -> jnp.ndarray:
@@ -547,11 +573,15 @@ def paged_attention_reference(
     from ddp_practice_tpu.ops.attention import attention_with_mask
 
     b = q.shape[0]
-    hh = k_pages.shape[2]
+    kvh = n_kv_heads or n_heads
+    hh = q.shape[2]
     d = hh // n_heads
     span = page_table.shape[1] * k_pages.shape[1]
-    k = gather_pages(k_pages, page_table, n_heads, k_scale)
-    v = gather_pages(v_pages, page_table, n_heads, v_scale)
+    k = gather_pages(k_pages, page_table, kvh, k_scale)
+    v = gather_pages(v_pages, page_table, kvh, v_scale)
+    if kvh != n_heads:   # query head i reads KV head i // group
+        k = jnp.repeat(k, n_heads // kvh, axis=2)
+        v = jnp.repeat(v, n_heads // kvh, axis=2)
     pos = jnp.arange(span, dtype=jnp.int32)[None, :]
     valid = pos <= lengths[:, None]
     if attn_start is not None:
@@ -632,6 +662,7 @@ def paged_decode_attention(
     attn_start=None,
     *,
     n_heads: int,
+    n_kv_heads=None,
     k_scale=None,
     v_scale=None,
     impl: str = "auto",
@@ -672,12 +703,21 @@ def paged_decode_attention(
         raise ValueError("int8 page pool needs BOTH k_scale and v_scale")
     bs = k_pages.shape[1]
     d = hd_total // n_heads
-    packable = _heads_per_pack(n_heads, d) is not None and bs % 8 == 0
+    kvh = n_kv_heads or n_heads
+    group = n_heads // kvh
+    if group == 1:
+        packable = _heads_per_pack(n_heads, d) is not None and bs % 8 == 0
+    else:
+        # the group's query heads are the sublane rows of a matmul and a
+        # KV head's lanes a whole-tile slice of the chunk; no int8 pool
+        packable = (d % _LANES == 0 and group % 8 == 0 and bs % 8 == 0
+                    and not quant)
     if impl == "reference" or (impl == "auto" and (
             not packable or not backend.on_tpu())):
         return paged_attention_reference(
             q, k_pages, v_pages, page_table, lengths, attn_start,
-            n_heads=n_heads, k_scale=k_scale, v_scale=v_scale,
+            n_heads=n_heads, n_kv_heads=kvh, k_scale=k_scale,
+            v_scale=v_scale,
         )
     if not packable:
         raise ValueError(
@@ -729,14 +769,27 @@ def paged_decode_attention(
             interpret=interpret,
             name="paged_decode_int8",
         )(lens, start, pt, q, k_pages, v_pages, k_scale, v_scale)
-    pages = _pages_per_chunk(bs, hd_total, k_pages.dtype)
-    kernel = functools.partial(
-        _paged_walk_kernel, sm_scale=sm_scale, block_size=bs, pages=pages,
-        d=d, rows=-(-n_heads // 16) * 16,   # whole bf16 sublane tiles
-    )
+    kv_total = kvh * d
+    pages = _pages_per_chunk(bs, kv_total, k_pages.dtype)
+    if group > 1:
+        # q and out as (heads, d) blocks: a free reshape out here, and in
+        # the kernel a KV head's query heads are then whole rows
+        q = q.reshape(b, n_heads, d)
+        q_spec = pl.BlockSpec((None, n_heads, d), lambda b_, *_: (b_, 0, 0))
+        out_shape = jax.ShapeDtypeStruct((b, n_heads, d), q.dtype)
+        kernel = functools.partial(
+            _paged_walk_kernel, sm_scale=sm_scale, block_size=bs,
+            pages=pages, d=d, rows=group, kv_heads=kvh,
+        )
+    else:
+        kernel = functools.partial(
+            _paged_walk_kernel, sm_scale=sm_scale, block_size=bs,
+            pages=pages, d=d,
+            rows=-(-n_heads // 16) * 16,   # whole bf16 sublane tiles
+        )
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
-    chunk_buf = pltpu.VMEM((2, pages * bs, hd_total), k_pages.dtype)
-    return pl.pallas_call(
+    chunk_buf = pltpu.VMEM((2, pages * bs, kv_total), k_pages.dtype)
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -757,3 +810,4 @@ def paged_decode_attention(
         interpret=interpret,
         name="paged_decode",
     )(lens, start, pt, q, k_pages, v_pages)
+    return out.reshape(b, 1, hd_total)

@@ -29,6 +29,8 @@ from typing import Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ddp_practice_tpu.config import MeshConfig
 from ddp_practice_tpu.utils import backend
@@ -1004,3 +1006,248 @@ class MoEMlp(nn.Module):
         out = _constrain(out, (ax, MeshConfig.AXIS_DATA, None, None))
         y = jnp.einsum("gtec,egcd->gtd", combine.astype(cdtype), out)
         return self._ungroup(y, g0, t0, n_sub).astype(x.dtype)
+
+
+# ------------------------------------------------- a chip's share of experts
+# Token-choice experts of which THIS chip holds a contiguous range (one of
+# the chips that share each layer in an expert-parallel deployment). The
+# router scores and picks over ALL experts; only the picks that land in the
+# held range are computed and summed here, and what the absent experts
+# would have added is left out (their chips add it; on one chip the layer
+# runs without that exchange). The dropless sorted path above, with a held
+# range: the picks sort by held expert (`_assignment_permutation`, absent
+# picks last), each expert's rows are laid out as whole row tiles, and ONE
+# kernel, `moe_gmm`, walks the tiles with the tile's expert's weights.
+
+
+def route_sigmoid_topk(scores_logits, select_bias, *, k: int,
+                       scaling: float):
+    """Sigmoid router with a selection-only bias. scores_logits (N, E)
+    float32. The k picks are the top of `sigmoid + bias`; the weights are
+    the picks' own sigmoids (no bias), normalised over the picks, times
+    `scaling`. Returns (choices (N, k) int32, weights (N, k) float32)."""
+    s = jax.nn.sigmoid(scores_logits.astype(jnp.float32))
+    _, choices = jax.lax.top_k(s + select_bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(s, choices, axis=-1)
+    w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-20)
+    return choices.astype(jnp.int32), w * scaling
+
+
+def held_tile_layout(choices, *, offset: int, held: int, tile: int):
+    """Where each pick's row goes. choices (N, k) over ALL the experts the
+    router scores; experts `offset` .. `offset + held` are here.
+
+    The held picks' rows form a padded buffer of `n_tiles * tile` rows in
+    which expert e's rows start at a tile boundary (so one row tile has
+    one expert); `n_tiles = ceil(N k / tile) + held` covers any routing.
+    Returns a dict: `row_token` (rows,) the token whose latent a row
+    holds, `row_valid` (rows,), `tile_expert` (n_tiles,) local expert of
+    each tile (idle tiles repeat the last used one: no new weights are
+    fetched for them), `tiles_used` (1,), `pick_row` (N, k) the row of a
+    held pick (0 otherwise), `pick_held` (N, k) bool, `counts` (held,)."""
+    n, k = choices.shape
+    local = choices - offset
+    is_held = (local >= 0) & (local < held)
+    local = jnp.where(is_held, local, held).reshape(n * k)
+    counts, dest, inv = _assignment_permutation(local, held + 1)
+    counts = counts[:held]
+    first = jnp.cumsum(counts) - counts           # sorted row of rank 0
+    tiles = -(-counts // tile)
+    tile_end = jnp.cumsum(tiles)
+    tile_first = tile_end - tiles
+    used = tile_end[-1]
+    n_tiles = -(-(n * k) // tile) + held
+    t = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32),
+                    jnp.maximum(used - 1, 0))
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, t, side="right"), held - 1
+    ).astype(jnp.int32)
+    rows = jnp.arange(n_tiles * tile, dtype=jnp.int32)
+    row_e = tile_expert[rows // tile]
+    rank = rows - tile_first[row_e] * tile
+    row_valid = (rows // tile < used) & (rank < counts[row_e])
+    src = inv[jnp.where(row_valid, first[row_e] + rank, 0)]
+    held_local = jnp.minimum(local, held - 1)
+    pick_row = tile_first[held_local] * tile + dest - first[held_local]
+    return {
+        "row_token": src // k, "row_valid": row_valid,
+        "tile_expert": tile_expert,
+        "tiles_used": used.astype(jnp.int32)[None],
+        "pick_row": jnp.where(is_held.reshape(-1), pick_row, 0
+                              ).reshape(n, k),
+        "pick_held": is_held, "counts": counts,
+    }
+
+
+def _expert_mlp_kernel(te_ref, used_ref, x_ref, w1_ref, w2_ref, o_ref):
+    """One row tile through its expert: relu(x W1)^2 W2. Tiles past the
+    used ones write zeros and fetch nothing new."""
+    del te_ref
+    t = pl.program_id(0)
+
+    @pl.when(t < used_ref[0])
+    def _():
+        h = jnp.dot(x_ref[...], w1_ref[...],
+                    preferred_element_type=jnp.float32)
+        h = jnp.square(jnp.maximum(h, 0.0)).astype(x_ref.dtype)
+        o_ref[...] = jnp.dot(
+            h, w2_ref[...], preferred_element_type=jnp.float32
+        ).astype(o_ref.dtype)
+
+    @pl.when(t >= used_ref[0])
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+def expert_mlp_tiles(x_rows, w1, w2, tile_expert, tiles_used, *, tile: int):
+    """relu(x W1_e)^2 W2_e for every row tile, e the tile's expert.
+    x_rows (n_tiles * tile, d); w1 (held, d, f); w2 (held, f, d): the
+    kernel on the TPU, a gather + einsum elsewhere."""
+    if not backend.on_tpu():
+        with jax.named_scope("moe_gmm"):
+            return expert_mlp_tiles_reference(
+                x_rows, w1, w2, tile_expert, tiles_used, tile=tile)
+    return expert_mlp_tiles_kernel(
+        x_rows, w1, w2, tile_expert, tiles_used, tile=tile)
+
+
+def expert_mlp_tiles_reference(x_rows, w1, w2, tile_expert, tiles_used, *,
+                               tile: int):
+    rows, d = x_rows.shape
+    n_tiles = rows // tile
+    xt = x_rows.reshape(n_tiles, tile, d)
+    h = jnp.einsum("tmd,tdf->tmf", xt, w1[tile_expert],
+                   preferred_element_type=jnp.float32)
+    h = jnp.square(jnp.maximum(h, 0.0)).astype(x_rows.dtype)
+    out = jnp.einsum("tmf,tfd->tmd", h, w2[tile_expert],
+                     preferred_element_type=jnp.float32)
+    live = jnp.arange(n_tiles)[:, None, None] < tiles_used[0]
+    return jnp.where(live, out, 0.0).astype(x_rows.dtype).reshape(rows, d)
+
+
+def expert_mlp_tiles_kernel(x_rows, w1, w2, tile_expert, tiles_used, *,
+                            tile: int):
+    """ONE device op named `moe_gmm` (interpret mode off the TPU, where
+    only the tests call it): grid over the tiles, the weights' block index
+    is the tile's expert, so a run of tiles of one expert fetches its two
+    matrices once and every held expert that has a row is streamed exactly
+    once."""
+    rows, d = x_rows.shape
+    n_tiles = rows // tile
+    f = w1.shape[2]
+    itemsize = jnp.dtype(w1.dtype).itemsize
+    # both matrices of an expert, two deep, and the row tiles
+    vmem = 4 * d * f * itemsize + 8 * tile * max(d, f) * 4 + (4 << 20)
+    return pl.pallas_call(
+        _expert_mlp_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec((tile, d), lambda t, te, used: (t, 0)),
+                pl.BlockSpec((None, d, f),
+                             lambda t, te, used: (te[t], 0, 0)),
+                pl.BlockSpec((None, f, d),
+                             lambda t, te, used: (te[t], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((tile, d), lambda t, te, used: (t, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, d), x_rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(vmem)),
+        interpret=not backend.on_tpu(),
+        name="moe_gmm",
+    )(tile_expert, tiles_used, x_rows, w1, w2)
+
+
+def _row_tile(rows_per_expert: float) -> int:
+    """Rows a tile holds: the power of two from 16 (a bf16 sublane tile)
+    to 128 (the MXU's height) that covers what an expert expects."""
+    tile = 16
+    while tile < 128 and tile < rows_per_expert:
+        tile *= 2
+    return tile
+
+
+class LatentMoE(nn.Module):
+    """Experts in a latent space (LatentMoE), a chip's share of them held.
+
+        s = sigmoid(x W_r)                      float32, all `num_experts`
+        picks = top_k(s + selection bias);  w_e = s_e / sum_picks s * scaling
+        u = x W_down                            d -> latent
+        routed = (sum_{picks held here} w_e relu(u W1_e)^2 W2_e) W_up
+        out = routed + relu(x V1)^2 V2          the shared expert, whole
+
+    `experts_held` experts from `expert_offset` are here; `W_down`, `W_up`,
+    the router and the shared expert are whole on every chip. In decode
+    mode the layer also counts, into the cache collection's `moe_stats`
+    (rows that landed on held experts, held experts touched, most rows on
+    one expert; summed over the calls since the engine last zeroed it),
+    what `PagedEngine` hands its tracer."""
+
+    num_experts: int
+    top_k: int
+    latent_dim: int
+    expert_dim: int
+    shared_dim: int
+    experts_held: int
+    expert_offset: int = 0
+    routed_scaling: float = 1.0
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, *, decode: bool = False):
+        lead, d = x.shape[:-1], x.shape[-1]
+        cd = self.dtype
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=cd, param_dtype=self.param_dtype)
+        xf = x.reshape(-1, d).astype(cd)
+        n, k, held = xf.shape[0], self.top_k, self.experts_held
+        if not 0 <= self.expert_offset <= self.num_experts - held:
+            raise ValueError(
+                f"held experts [{self.expert_offset}, "
+                f"{self.expert_offset + held}) lie outside the "
+                f"{self.num_experts} the router scores")
+        bias = self.param("e_score_correction_bias", nn.initializers.zeros,
+                          (self.num_experts,), self.param_dtype)
+        u = dense(self.latent_dim, name="down")(xf)
+        with jax.named_scope("moe_route"):
+            logits = nn.Dense(
+                self.num_experts, use_bias=False, dtype=jnp.float32,
+                param_dtype=self.param_dtype, name="router",
+            )(xf.astype(jnp.float32))
+            choices, weights = route_sigmoid_topk(
+                logits, bias, k=k, scaling=self.routed_scaling)
+            tile = _row_tile(n * k / self.num_experts)
+            lay = held_tile_layout(choices, offset=self.expert_offset,
+                                   held=held, tile=tile)
+            rows = jnp.where(lay["row_valid"][:, None],
+                             u[lay["row_token"]], 0).astype(cd)
+        w1 = self.param("expert_w1", nn.initializers.normal(0.02),
+                        (held, self.latent_dim, self.expert_dim),
+                        self.param_dtype)
+        w2 = self.param("expert_w2", nn.initializers.normal(0.02),
+                        (held, self.expert_dim, self.latent_dim),
+                        self.param_dtype)
+        out = expert_mlp_tiles(
+            rows, w1.astype(cd), w2.astype(cd), lay["tile_expert"],
+            lay["tiles_used"], tile=tile)
+        with jax.named_scope("moe_combine"):
+            gate = jnp.where(lay["pick_held"], weights, 0.0)
+            picked = out[lay["pick_row"]].astype(jnp.float32)  # (n, k, lat)
+            routed = jnp.einsum("nk,nkd->nd", gate, picked).astype(cd)
+        y = dense(d, name="up")(routed)
+        shared = dense(self.shared_dim, name="shared_in")(xf)
+        shared = jnp.square(nn.relu(shared))
+        y = y + dense(d, name="shared_out")(shared)
+        if decode:
+            stats = self.variable("cache", "moe_stats", jnp.zeros, (3,),
+                                  jnp.int32)
+            if not self.is_initializing():
+                c, old = lay["counts"], stats.value
+                stats.value = jnp.stack([
+                    old[0] + c.sum(), old[1] + (c > 0).sum(),
+                    jnp.maximum(old[2], c.max())]).astype(jnp.int32)
+        return y.reshape(*lead, d).astype(x.dtype)

@@ -61,6 +61,7 @@ from ddp_practice_tpu.serve.kv_pages import (
     BlockAllocator,
     RadixPrefixCache,
     copy_block,
+    leaf_kind,
     make_paged_cache,
     rewind_block_tail,
     scatter_prompt_blocks,
@@ -274,6 +275,9 @@ def warm_engine(engine, widths=None) -> None:
         engine.spec_drafted_tokens = 0
         engine.spec_accepted_tokens = 0
         engine.spec_dispatches = 0
+    if getattr(engine, "moe_rows_routed", 0):
+        # warmup picks belong to no request either
+        engine.moe_rows_held = engine.moe_rows_routed = 0
     engine.reset_epoch()
 
 
@@ -313,6 +317,10 @@ class _EngineBase:
     # this request" (preempted / queued) when attributing a resume gap.
     burst_seq = 0
     last_burst_active = 0
+    # (picks that landed on held experts, held experts with a row summed
+    # over expert layers and steps, most rows one expert took) of the last
+    # decode burst; None for a model without held experts (PagedEngine)
+    last_burst_experts = None
 
     def set_tracer(self, tracer, replica: int = 0) -> None:
         """Attach a utils/trace.py TraceRecorder; `replica` is this
@@ -454,6 +462,11 @@ class SlotEngine(_EngineBase):
                 "left-aligns prompts at arbitrary cache offsets, which "
                 "only relative positions survive (models/lm.py attn_start)"
             )
+        if getattr(model, "recurrent", False):
+            raise ValueError(
+                "SlotEngine serves no model with recurrent state: its "
+                "pool is positions under one cursor — use PagedEngine, "
+                "which keeps a state a slot beside the pages")
         if not config.prompt_buckets:
             raise ValueError("prompt_buckets must be non-empty")
         if config.spec_decode:
@@ -791,12 +804,34 @@ class PagedEngine(_EngineBase):
     def __init__(self, model, params, config: EngineConfig = EngineConfig(),
                  *, batch_stats: Any = None,
                  draft_source: Optional[DraftSource] = None) -> None:
-        if getattr(model, "pos_emb", None) != "rope":
+        # a model with recurrent state (models/hybrid_lm.py): its layers
+        # carry position, so attention may have no positional embedding
+        # at all, and beside the pages every slot owns a fixed-size state
+        self._recurrent = bool(getattr(model, "recurrent", False))
+        pos_ok = ("rope", "none") if self._recurrent else ("rope",)
+        if getattr(model, "pos_emb", None) not in pos_ok:
             raise ValueError(
                 "PagedEngine needs pos_emb='rope' — slots decode at "
                 "slot-local positions, which only relative positions "
-                "survive (models/lm.py)"
+                "survive (models/lm.py); pos_emb='none' is admitted "
+                "where recurrent layers carry position"
             )
+        if self._recurrent:
+            # pages cannot re-derive a state: each of these needs a
+            # sequence's state at a position that is not its end (ROADMAP
+            # M6: snapshots at block boundaries)
+            for option, why in (
+                ("prefix_cache", "a shared prefix's pages come without "
+                 "the state at the prefix's end"),
+                ("prefill_chunk", "chunks append through the page table "
+                 "(needs prefix_cache)"),
+                ("spec_decode", "a rejected draft cannot be rolled back "
+                 "out of the state"),
+            ):
+                if getattr(config, option):
+                    raise ValueError(
+                        f"{option} is refused for a model with recurrent "
+                        f"state: {why}")
         if not config.prompt_buckets:
             raise ValueError("prompt_buckets must be non-empty")
         if config.decode_burst < 1:
@@ -867,7 +902,19 @@ class PagedEngine(_EngineBase):
         # cache): the scheduler reads this right after admit() to book
         # prefix_hit_tokens into the request's flight record
         self.last_prefix_hit: Optional[int] = None
-        self._cache = make_paged_cache(model, num_blocks, bs)
+        self._cache = make_paged_cache(model, num_blocks, bs, max_slots=s)
+        flat = jax.tree_util.tree_flatten_with_path(self._cache)[0]
+        # bytes of the per-slot state pool (gauge `ssm_state_bytes`), and
+        # the expert layers' picks a decode step routes: slots x top-k x
+        # layers (every row of the batch is computed, retired slots' too)
+        self.ssm_state_bytes = int(sum(
+            a.nbytes for path, a in flat if leaf_kind(path) == "state"))
+        self._moe_layers = sum(
+            1 for path, _ in flat if leaf_kind(path) == "stats")
+        self._picks_a_step = (
+            s * int(getattr(model, "top_k", 0)) * self._moe_layers)
+        self.moe_rows_held = 0       # cumulative (metrics export)
+        self.moe_rows_routed = 0
         self._last_logits = jnp.zeros((s, model.vocab_size), model.dtype)
         self._keys = jnp.zeros((s, 2), jnp.uint32)
         self._active = np.zeros((s,), bool)
@@ -917,7 +964,12 @@ class PagedEngine(_EngineBase):
         self.spec_drafted_tokens = 0
         self.spec_accepted_tokens = 0
         self.spec_dispatches = 0
-        self._prefill_jit = jax.jit(self._prefill_admit)
+        # the state pool is donated to the prefill too: it is gigabytes
+        # (every slot's float32 state), and admission rewrites one row
+        self._prefill_jit = jax.jit(
+            self._prefill_admit,
+            donate_argnums=_decode_donate() if self._recurrent else (),
+        )
         self._decode_jit = jax.jit(
             self._decode_burst, donate_argnums=_decode_donate()
         )
@@ -952,7 +1004,7 @@ class PagedEngine(_EngineBase):
             attn_start=attn_start[None], batch_stats=self.batch_stats,
         )
         pool = scatter_prompt_blocks(
-            pool, scratch, block_ids, w, self.config.block_size
+            pool, scratch, block_ids, w, self.config.block_size, slot
         )
         last_logits = lax.dynamic_update_slice(
             last_logits, logits[:, -1].astype(last_logits.dtype), (slot, 0)
@@ -1014,11 +1066,23 @@ class PagedEngine(_EngineBase):
             lengths = lengths + active.astype(lengths.dtype)
             return (pool, logits[:, -1], keys, lengths), (toks, finite)
 
+        # expert layers count into their `moe_stats` leaf: zeroed here so
+        # that after the scan it holds this burst's own sums
+        pool = jax.tree_util.tree_map_with_path(
+            lambda path, a: jnp.zeros_like(a)
+            if leaf_kind(path) == "stats" else a, pool)
         (pool, last_logits, keys, _), (toks, finite) = lax.scan(
             body, (pool, last_logits, keys, lengths), None,
             length=self.config.decode_burst,
         )
-        return pool, last_logits, toks, keys, finite
+        stats = [a for path, a
+                 in jax.tree_util.tree_flatten_with_path(pool)[0]
+                 if leaf_kind(path) == "stats"]
+        if stats:   # (rows on held experts, experts touched, most rows)
+            stats = jnp.stack(stats)
+            stats = jnp.stack([stats[:, 0].sum(), stats[:, 1].sum(),
+                               stats[:, 2].max()])
+        return pool, last_logits, toks, keys, finite, stats
 
     def _verify(self, params, pool, last_logits, attn_starts, active,
                 drafts, draft_lens, page_table, lengths):
@@ -1608,6 +1672,11 @@ class PagedEngine(_EngineBase):
         the per-request knob the front door's n>1 sampling rides. A
         slot with no recorded seed path (direct `_keys` manipulation in
         tests) falls back to folding the parent's current key."""
+        if self._recurrent:
+            raise ValueError(
+                "fork is refused for a model with recurrent state: the "
+                "child would share the parent's pages but needs a state "
+                "of its own (ROADMAP M6: state snapshots)")
         if not self._active[slot]:
             raise ValueError(f"slot {slot} is not active")
         child = self.allocator.alloc()
@@ -1751,7 +1820,7 @@ class PagedEngine(_EngineBase):
         with span:
             with disp:
                 (self._cache, self._last_logits, toks,
-                 self._keys, finite) = self._decode_jit(
+                 self._keys, finite, stats) = self._decode_jit(
                     self.params, self._cache, self._last_logits,
                     jnp.asarray(self._attn), jnp.asarray(self._active),
                     self._keys, jnp.asarray(self._pt),
@@ -1761,7 +1830,21 @@ class PagedEngine(_EngineBase):
                                 self._keys)
             self._len[self._active] += k
             with read:  # the host waits for the device here
-                toks, finite = jax.device_get((toks, finite))
+                toks, finite, stats = jax.device_get(
+                    (toks, finite, stats))
+            if self._moe_layers:
+                # what the burst's expert layers saw, from the program:
+                # picks that landed on held experts, held experts with a
+                # row (summed over layers and steps) and the most rows
+                # one expert took in a step
+                rows, touched, most = (int(v) for v in stats)
+                self.last_burst_experts = (rows, touched, most)
+                self.moe_rows_held += rows
+                self.moe_rows_routed += k * self._picks_a_step
+                if traced and getattr(span, "attrs", None) is not None:
+                    span.attrs.update(expert_rows=rows,
+                                      experts_touched=touched,
+                                      expert_rows_max=most)
         self.burst_seq += 1
         self.last_burst_active = int(np.count_nonzero(self._active))
         self.last_finite = np.asarray(finite)

@@ -426,25 +426,51 @@ class RadixPrefixCache:
         return self.evict(self._nodes)
 
 
-def make_paged_cache(model, num_blocks: int, block_size: int) -> Any:
-    """Block-pool cache collection for `model` (decode mode).
+# Cache leaves that are NOT pages, by the variable's name in the model's
+# "cache" collection: a recurrent layer's fixed-size state a sequence
+# (models/hybrid_lm.py Mamba2Mixer) is pooled a SLOT, and an expert
+# layer's counters (ops/moe.py LatentMoE `moe_stats`) are one small
+# vector a layer.
+STATE_LEAVES = ("ssm_state", "conv_state")
+STATS_LEAF = "moe_stats"
+
+
+def leaf_kind(path) -> str:
+    """"state" | "stats" | "pages": which pool a cache leaf belongs to
+    (scalars, the flat layout's cursors, are told by their rank)."""
+    name = str(getattr(path[-1], "key", path[-1]))
+    if name in STATE_LEAVES:
+        return "state"
+    return "stats" if name == STATS_LEAF else "pages"
+
+
+def make_paged_cache(model, num_blocks: int, block_size: int,
+                     max_slots: int = 1) -> Any:
+    """Block-pool cache collection for `model` (decode mode): a cache
+    spec a layer, by what the layer declares.
 
     Mirrors the tree structure of `inference.make_cache` — same variable
     names per attention block, so `decode_apply` threads it unchanged —
-    but every K/V leaf is `(num_blocks, block_size, h*hd)` instead of
-    `(batch, max_len, h*hd)`. An int8 cache model's per-(head, position)
-    scale leaves pool the same way: `(1, h, block_size)` becomes
+    but every K/V leaf is `(num_blocks, block_size, kv_heads*hd)` instead
+    of `(batch, max_len, kv_heads*hd)`. An int8 cache model's per-(head,
+    position) scale leaves pool the same way: `(1, h, block_size)` becomes
     `(num_blocks, h, block_size)` — per-block scale pages riding the
-    same page table as the K/V they dequantize. Scalar leaves (the flat
-    layout's write cursors) stay for tree parity; the paged path never
-    advances them.
+    same page table as the K/V they dequantize. A recurrent layer's
+    leaves (`STATE_LEAVES`) are a per-SLOT state pool, `(max_slots, ...)`:
+    admission overwrites a slot's row from the prefill's final state,
+    decode updates every row in place, and release leaves the row for
+    the next owner to overwrite. Scalar leaves (the flat layout's write
+    cursors) and an expert layer's counters stay as they are.
     """
     shapes = jax.eval_shape(lambda: make_cache(model, 1, block_size))
-    return jax.tree.map(
-        lambda a: jnp.zeros(a.shape, a.dtype) if a.ndim == 0
-        else jnp.zeros((num_blocks,) + a.shape[1:], a.dtype),
-        shapes,
-    )
+
+    def per_leaf(path, a):
+        if a.ndim == 0 or leaf_kind(path) == "stats":
+            return jnp.zeros(a.shape, a.dtype)
+        lead = max_slots if leaf_kind(path) == "state" else num_blocks
+        return jnp.zeros((lead,) + a.shape[1:], a.dtype)
+
+    return jax.tree_util.tree_map_with_path(per_leaf, shapes)
 
 
 def _is_scale_leaf(path) -> bool:
@@ -458,7 +484,7 @@ def _is_scale_leaf(path) -> bool:
 
 
 def scatter_prompt_blocks(pool: Any, scratch: Any, block_ids,
-                          width: int, block_size: int) -> Any:
+                          width: int, block_size: int, slot=None) -> Any:
     """Scatter a batch-1 contiguous scratch cache into pool blocks.
 
     `scratch` holds a freshly prefilled prompt at positions `[0, width)`
@@ -470,13 +496,20 @@ def scatter_prompt_blocks(pool: Any, scratch: Any, block_ids,
     and stays invisible, because attention is masked to the slot's own
     positions. int8 scale leaves ((1, h, width) -> (nb, h, block_size))
     chunk along their position axis (2) the same way. Scalar leaves
-    keep the POOL's value (no global clock).
+    keep the POOL's value (no global clock), as do an expert layer's
+    counters. A recurrent layer's state leaves are not pages: the
+    scratch's batch-1 final state overwrites row `slot` of the state
+    pool.
     """
     n_chunks = -(-width // block_size)
 
     def per_leaf(path, p, s):
-        if p.ndim == 0:
+        kind = leaf_kind(path)
+        if p.ndim == 0 or kind == "stats":
             return p
+        if kind == "state":
+            return lax.dynamic_update_slice(
+                p, s.astype(p.dtype), (slot,) + (0,) * (p.ndim - 1))
         pos_axis = 2 if _is_scale_leaf(path) else 1
         for i in range(n_chunks):
             lo = i * block_size
@@ -528,8 +561,8 @@ def copy_block(pool: Any, src, dst) -> Any:
     split). Copying from/into the garbage block is a caller bug; the
     engine asserts it host-side before dispatch."""
 
-    def per_leaf(p):
-        if p.ndim == 0:
+    def per_leaf(path, p):
+        if p.ndim == 0 or leaf_kind(path) != "pages":
             return p
         row = lax.dynamic_slice(
             p, (src,) + (0,) * (p.ndim - 1), (1,) + p.shape[1:]
@@ -538,4 +571,4 @@ def copy_block(pool: Any, src, dst) -> Any:
             p, row, (dst,) + (0,) * (p.ndim - 1)
         )
 
-    return jax.tree.map(per_leaf, pool)
+    return jax.tree_util.tree_map_with_path(per_leaf, pool)

@@ -62,6 +62,14 @@ class ServeMetrics:
         self.spec_drafted = r.counter("spec_drafted_tokens_total")
         self.spec_accepted = r.counter("spec_accepted_tokens_total")
         self._last_drafted = self._last_accepted = 0
+        # a chip's share of experts and recurrent state (PagedEngine over
+        # a models/hybrid_lm.py model): picks the decode steps routed and
+        # those that landed on experts held here, as deltas of the
+        # engine's cumulative fields; bytes of the per-slot state pool
+        self.moe_rows_held = r.counter("moe_rows_held_total")
+        self.moe_rows_routed = r.counter("moe_rows_routed_total")
+        self.ssm_state_bytes = r.gauge("ssm_state_bytes")
+        self._last_held = self._last_routed = 0
         self.tokens_total = r.counter("serve_tokens_total")
         self.submitted = r.counter("serve_requests_submitted")
 
@@ -101,6 +109,12 @@ class ServeMetrics:
                 )
                 self._last_hit = radix.hit_tokens
                 self._last_miss = radix.miss_tokens
+        held = getattr(eng, "moe_rows_held", 0)
+        routed = getattr(eng, "moe_rows_routed", 0)
+        self.moe_rows_held.inc(held - self._last_held)
+        self.moe_rows_routed.inc(routed - self._last_routed)
+        self._last_held, self._last_routed = held, routed
+        self.ssm_state_bytes.set(getattr(eng, "ssm_state_bytes", 0))
         drafted = getattr(eng, "spec_drafted_tokens", 0)
         accepted = getattr(eng, "spec_accepted_tokens", 0)
         self.spec_drafted.inc(drafted - self._last_drafted)

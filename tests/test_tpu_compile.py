@@ -202,7 +202,59 @@ def _moe_sorted():
     return jax.grad(loss, argnums=(0, 1)), (x, variables)
 
 
+def _paged_grouped(slots=128, blocks_per_slot=114):
+    """The hybrid cell's attention (perf/configs/nemotron3_super_ep4.json):
+    32 query heads over 2 KV heads of 128, pages 256 lanes wide; the 16
+    query heads of a KV head are one matmul's rows."""
+    from ddp_practice_tpu.ops.decode_attention import paged_decode_attention
+
+    def step(q, k, v, table, lengths, start):
+        return paged_decode_attention(
+            q, k, v, table, lengths, start, n_heads=32, n_kv_heads=2)
+
+    pool = _sds((1 + slots * blocks_per_slot, 16, 2 * 128))
+    return step, (_sds((slots, 1, 32 * 128)), pool, pool,
+                  _sds((slots, blocks_per_slot), jnp.int32),
+                  _sds((slots,), jnp.int32), _sds((slots,), jnp.int32))
+
+
+def _ssm_step(slots=128):
+    """ops/ssm.py's one-token recurrence at the hybrid cell's widths: 128
+    heads of 64 over a state of 128 in 8 groups, every slot's float32
+    state rewritten in place."""
+    from ddp_practice_tpu.ops.ssm import ssm_step
+
+    f32 = jnp.float32
+    return ssm_step, (
+        _sds((slots, 128, 64)), _sds((slots, 128), f32), _sds((128,), f32),
+        _sds((slots, 8, 128)), _sds((slots, 8, 128)), _sds((128,)),
+        _sds((slots, 128, 64, 128), f32))
+
+
+def _moe_gmm(rows_a_tile):
+    """ops/moe.py's expert kernel at the hybrid cell's widths: 128 held
+    experts of 1024 -> 2688 -> 1024, both matrices of an expert (11 MB)
+    in VMEM two deep; a decode step's tiles of 16 rows and a 768-token
+    prompt's of 64."""
+    from ddp_practice_tpu.ops.moe import expert_mlp_tiles
+
+    picks = {16: 128 * 22, 64: 768 * 22}[rows_a_tile]
+    tiles = -(-picks // rows_a_tile) + 128
+
+    def mlp(rows, w1, w2, tile_expert, used):
+        return expert_mlp_tiles(rows, w1, w2, tile_expert, used,
+                                tile=rows_a_tile)
+
+    return mlp, (_sds((tiles * rows_a_tile, 1024)),
+                 _sds((128, 1024, 2688)), _sds((128, 2688, 1024)),
+                 _sds((tiles,), jnp.int32), _sds((1,), jnp.int32))
+
+
 KERNELS = {
+    "hybrid_paged_grouped_32q_2kv": _paged_grouped,
+    "hybrid_ssm_step": _ssm_step,
+    "hybrid_moe_gmm_decode_tiles": functools.partial(_moe_gmm, 16),
+    "hybrid_moe_gmm_prompt_tiles": functools.partial(_moe_gmm, 64),
     "flash_fwd": functools.partial(_flash, grad=False),
     "flash_fwd_bwd": functools.partial(_flash, grad=True),
     "flash_qkv_fwd_bwd": _flash_qkv,
@@ -244,6 +296,15 @@ def test_kernel_compiles_for_v5e(topo, name):
                  text.splitlines() if "custom-call(" in ln
                  and "tpu_custom_call" in ln]
         assert len(calls) == 1 and "paged_decode" in calls[0], calls
+    if name.startswith("hybrid"):
+        # each is ONE device op under the name the benchmark's readers
+        # look for (perf/layer_metrics/flood_ssm_*, flood_moe_*)
+        want = {"hybrid_paged": "paged_decode", "hybrid_ssm_s": "ssm_step",
+                "hybrid_moe_g": "moe_gmm"}[name[:12]]
+        calls = [ln.split("=")[0].strip().lstrip("%") for ln in
+                 text.splitlines() if "custom-call(" in ln
+                 and "tpu_custom_call" in ln]
+        assert len(calls) == 1 and want in calls[0], calls
 
 
 def test_flash_compiles_sharded_over_four_devices(topo):
