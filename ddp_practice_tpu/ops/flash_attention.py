@@ -17,8 +17,12 @@ k-block) grid step:
     @when(kj == last): out = acc, lse = m + log l
 
 so the (seq x seq) score matrix never materializes in HBM — O(seq) memory,
-one pass over K/V. Causal masking skips whole k-blocks above the diagonal
-(@when(visible) gates the FLOPs).
+one pass over K/V. Causal masking is taken twice: whole k-blocks above the
+diagonal are skipped at the grid (@when(visible) gates the FLOPs), and
+inside a visible cell only the 256-wide score sub-tiles that hold an
+unmasked element are computed (_live_subtiles, _cell_strips): at seq 2048
+with blocks (512, 1024) a head runs 9.0 squares of 512x512 where the whole
+cells ran 12 and 8.0 hold work (`causal_tile_counts`).
 
 Performance structure (the round-4 restructure; measured on TPU v5e —
 see BENCHMARKS.md kernel table):
@@ -35,10 +39,17 @@ see BENCHMARKS.md kernel table):
     d=64 the scale 1/8 is exact in bf16) so no (block_q, block_k) scale
     pass runs;
   * p / ds are cast to bf16 before their MXU consumers (FlashAttention-2
-    staging); softmax statistics stay fp32.
+    staging); softmax statistics stay fp32;
+  * a cell's work is ONE straight-line body of static shapes (a branch
+    for each place the diagonal can cross a cell), never a loop over
+    sub-tiles: Mosaic overlaps MXU and VPU work inside a basic block only.
 With head_dim 64 the MXU contraction/output width caps useful utilization
-at 50% of peak; the restructured forward reaches ~49% of bf16 peak on the
-executed-dot basis at lm_base shapes — at the structural ceiling.
+at 50% of peak. Where the kernels stand at lm_base shapes (b 8, h 12,
+s 2048, causal; PERF.md section 5, PR 27): forward 1.03 ms, dq 1.22,
+dk/dv 1.50 a layer = 25% / 32% / 35% of bf16 peak on the dots that hold
+work, 28% / 35% / 39% on the sub-tiles executed. The backward is within a
+quarter of the cap; the forward is held by the VPU's softmax chain
+(rowmax, exp, rowsum, rescale), not by the MXU.
 
 Backward is tiled the same way (FlashAttention-2 scheme), recomputing
 p = exp(s - lse) blockwise from the saved logsumexp:
@@ -67,6 +78,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -133,17 +145,88 @@ def _softmax_accumulate(s, v_tile, m_prev, l_prev, acc_prev, *,
     return m_next, l_next, acc_next
 
 
-def _causal_penalty(qi, kj, block_q, block_k, offset):
-    """Additive mask for one (q-block, k-block) tile: 0 where query i may
-    attend key j (j <= i + offset, offset = seq_k - seq_q), -inf-like
-    otherwise. Added to s (cheaper than select on Mosaic)."""
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-    return jnp.where(q_pos + offset >= k_pos, 0.0, _NEG_INF)
+def _tile_penalty(shift, rows, cols):
+    """Additive mask for a (rows, cols) score tile: 0 where query a may
+    attend key b (b <= a + shift; shift = first query's position + offset
+    - first key's position, offset = seq_k - seq_q), -inf-like otherwise.
+    Added to s (cheaper than select on Mosaic)."""
+    rel = (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+           - jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
+    return jnp.where(rel + shift >= 0, 0.0, _NEG_INF)
+
+
+def _mask_tail(s, pen):
+    """The score tile with the causal penalty `pen` on its last columns
+    (none, some, or all of them: only the sub-tiles the diagonal crosses
+    pay for the mask)."""
+    if pen is None:
+        return s
+    w = s.shape[1] - pen.shape[1]
+    if w == 0:
+        return s + pen
+    return jnp.concatenate([s[:, :w], s[:, w:] + pen], axis=1)
+
+
+def _cell_strips(body, qi, kj, *, block_q, block_k, causal, seq_q, seq_k):
+    """Run `body` over what a grid cell has to compute (nothing, where a
+    causal cell lies above the diagonal: no branch below fits it).
+
+    body(strips) takes a list of (rows, n_keys, pen): the static slice
+    `rows` of the q block against the K/V block's first n_keys keys, with
+    `pen` (None, or a (rows, n_masked) penalty) for the last of them.
+    Non-causal: one strip, the cell whole. Causal: the strips of sub_q
+    query rows, each against the key sub-tiles that _live_subtiles calls
+    live. Which those are depends on the program ids, but a shape allows
+    only a few answers (_causal_plan lists them; at seq 2048 with blocks
+    (512, 1024): the diagonal in the block's first half, in its second,
+    or below the block), and each is compiled as its own branch: ONE
+    straight-line body of static shapes for the whole cell. Mosaic
+    overlaps MXU and VPU work inside a basic block, not across the steps
+    of a loop: looping over single sub-tiles ran the kernels 2-4x slower
+    than the whole cell, branching a strip at a time left them where
+    they were (PERF.md section 6, PR 27)."""
+    if not causal:
+        return body([(slice(None), block_k, None)])
+    (sub_q, sub_k), cases, _ = _causal_plan(seq_q, seq_k, block_q, block_k)
+    shift = qi * block_q + (seq_k - seq_q) - kj * block_k
+    starts = range(0, block_q, sub_q)
+    live = [_live_subtiles(shift + r, sub_q, sub_k, block_k) for r in starts]
+    for case in cases:
+        hit = [(n_full == a) & (n_live == b)
+               for (n_full, n_live), (a, b) in zip(live, case)]
+
+        @pl.when(functools.reduce(jnp.logical_and, hit))
+        def _case(case=case):
+            strips = []
+            for r, (a, b) in zip(starts, case):
+                rows, n_keys = slice(r, r + sub_q), b * sub_k
+                if a < b:  # the diagonal crosses key sub-tiles [a, b)
+                    strips.append((rows, n_keys, _tile_penalty(
+                        shift + r - a * sub_k, sub_q, (b - a) * sub_k)))
+                elif b and strips and strips[-1][1:] == (n_keys, None):
+                    # bare strips of one width run as one (a cell below
+                    # the diagonal: whole, as before)
+                    strips[-1] = (slice(strips[-1][0].start, rows.stop),
+                                  n_keys, None)
+                elif b:
+                    strips.append((rows, n_keys, None))
+            body(strips)
+
+
+def _fwd_cell(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, heads, sm_scale,
+              strips):
+    """The forward over one grid cell, as _cell_strips' body: a strip's
+    live scores are ONE tile, folded into the strip's running state by one
+    _softmax_accumulate (one rescale a strip and cell, as the whole cell
+    paid before). `heads`: (lanes, index) of each head in the refs."""
+    for rows, n_keys, pen in strips:
+        for lanes, hh in heads:
+            q = (q_ref[rows, lanes] * sm_scale).astype(q_ref.dtype)
+            s = _mask_tail(_dot_tb(q, k_ref[:n_keys, lanes]), pen)
+            (m_ref[hh, rows], l_ref[hh, rows],
+             acc_ref[rows, lanes]) = _softmax_accumulate(
+                s, v_ref[:n_keys, lanes], m_ref[hh, rows], l_ref[hh, rows],
+                acc_ref[rows, lanes])
 
 
 def _fwd_kernel(
@@ -158,8 +241,6 @@ def _fwd_kernel(
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     n_k = pl.num_programs(2)
-    offset = seq_k - seq_q if causal else 0
-    d = v_ref.shape[-1]
 
     @pl.when(kj == 0)
     def _init():
@@ -167,28 +248,18 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    # a k-block fully above the diagonal contributes nothing: skip its FLOPs
-    visible = (
-        (qi * block_q + block_q - 1 + offset) >= (kj * block_k)
-        if causal else (kj >= 0)
-    )
-
-    @pl.when(visible)
-    def _compute():
-        q = (q_ref[:] * sm_scale).astype(q_ref.dtype)  # (bq, d), cheap
-        s = _dot_tb(q, k_ref[:])                       # (bq, bk) fp32
-        if causal:
-            s = s + _causal_penalty(qi, kj, block_q, block_k, offset)
-        m_scr[:], l_scr[:], acc_scr[:] = _softmax_accumulate(
-            s, v_ref[:], m_scr[:], l_scr[:], acc_scr[:]
-        )
+    _cell_strips(
+        functools.partial(_fwd_cell, q_ref, k_ref, v_ref, m_scr, l_scr,
+                          acc_scr, [(slice(None), 0)], sm_scale),
+        qi, kj, block_q=block_q, block_k=block_k, causal=causal,
+        seq_q=seq_q, seq_k=seq_k)
 
     @pl.when(kj == n_k - 1)
     def _finalize():
         o_ref[:] = acc_scr[:].astype(o_ref.dtype)
-        l_col = l_scr[:, :1]
+        l_col = l_scr[0][:, :1]
         l_safe = jnp.maximum(l_col, 1e-30)
-        lse_ref[:] = m_scr[:, :1] + jnp.log(l_safe)
+        lse_ref[:] = m_scr[0][:, :1] + jnp.log(l_safe)
 
 
 def _fit_block(seq, block):
@@ -218,6 +289,78 @@ def _block_visible(block_q, block_k, offset):
     return lambda i, j: (i * block_q + block_q - 1 + offset) >= (j * block_k)
 
 
+# Edge of the score sub-tiles a causal grid cell is worked in: inside a
+# visible (block_q, block_k) cell only the sub-tiles holding an unmasked
+# element are computed (_live_subtiles). Chosen on the chip, not an
+# argument: at the LM cells' shape 256 ran the three kernels in 3.75 ms a
+# layer, 128 in 4.25, 512 in 3.94, the whole cell in 4.72 (PERF.md §6,
+# PR 27).
+_SUB = 256
+
+
+def _sub_tiles(block_q, block_k):
+    """(sub_q, sub_k): the sub-tile of a causal grid cell. A block that
+    _SUB does not divide into lane-aligned pieces (192, 320: no multiple
+    of 128) stays whole."""
+
+    def sub(block):
+        fit = _fit_block(block, _SUB)
+        return block if fit % _LANES else fit
+
+    return sub(block_q), sub(block_k)
+
+
+def _live_subtiles(shift, sub_q, sub_k, block_k, xp=jnp):
+    """THE causal schedule inside a grid cell: of the block_k // sub_k key
+    sub-tiles of a K/V block, which does a strip of sub_q query rows
+    compute, and on which does it add the mask's penalty? `shift` = (the
+    strip's first query row) + offset - (the block's first key), offset =
+    seq_k - seq_q: query a of the strip sees key b of the block iff
+    b <= a + shift. Returns (n_full, n_live): sub-tiles [0, n_full) hold
+    no masked element, [n_full, n_live) are crossed by the diagonal,
+    [n_live, ...) are all mask and are not computed. Every causal kernel,
+    folded and packed, reads this one function (through _cell_strips),
+    on traced scalars; `causal_tile_counts` and the tests read it on
+    numpy arrays."""
+    n_full = xp.clip(shift + 1, 0, block_k) // sub_k
+    n_live = (xp.clip(shift + sub_q, 0, block_k) + sub_k - 1) // sub_k
+    return n_full, n_live
+
+
+def _causal_plan(seq_q, seq_k, block_q, block_k):
+    """_live_subtiles over a whole causal head, in numpy: the sub-tile
+    shape; the answers the shape can produce, as the sorted set of a
+    visible cell's ((n_full, n_live) of each of its strips) — _cell_strips
+    compiles one branch an answer; and the number of sub-tiles computed."""
+    sub_q, sub_k = _sub_tiles(block_q, block_k)
+    at = lambda n, step: np.arange(0, n, step)
+    shift = (at(seq_q, sub_q)[:, None] + (seq_k - seq_q)
+             - at(seq_k, block_k)[None, :])
+    n_full, n_live = _live_subtiles(shift, sub_q, sub_k, block_k, xp=np)
+    # (q block, strip of the block, k block, which count)
+    cells = np.stack([n_full, n_live], axis=-1).reshape(
+        seq_q // block_q, block_q // sub_q, seq_k // block_k, 2)
+    cases = sorted({
+        tuple((int(a), int(b)) for a, b in cells[i, :, j])
+        for i in range(cells.shape[0]) for j in range(cells.shape[2])
+        if cells[i, :, j, 1].any()
+    })
+    return (sub_q, sub_k), cases, int(n_live.sum())
+
+
+def causal_tile_counts(seq_q, seq_k, block_q=512, block_k=1024):
+    """(executed, useful) score sub-tiles of one causal head: how many
+    (sub_q, sub_k) sub-tiles the kernels compute under _live_subtiles,
+    and how many the unmasked scores alone would fill. The schedule is
+    static, so this is a number of the shape, not of a run (PERF.md §3)."""
+    block_q, block_k = _check_blocks(seq_q, seq_k, block_q, block_k, True)
+    (sub_q, sub_k), _, executed = _causal_plan(
+        seq_q, seq_k, block_q, block_k)
+    unmasked = int(np.clip(np.arange(seq_q) + seq_k - seq_q + 1, 0,
+                           seq_k).sum())
+    return executed, unmasked / (sub_q * sub_k)
+
+
 def _redirect(causal, vis, i, j, idx):
     """Prefetch-redirect for swept block indices: a block belonging to a
     cell the kernel will skip (fully above the diagonal) redirects its
@@ -235,6 +378,17 @@ def _kv_index_map(causal, block_q, block_k, offset):
     return lambda b, i, j: (b, _redirect(causal, vis, i, j, j), 0)
 
 
+# The pallas_call wrappers below are jitted (their keywords static): a
+# model calls them once a layer with the same shapes, and as ONE jitted
+# function the kernels are traced and lowered once a program instead of
+# once a layer. The causal kernels hold a body for each place the diagonal
+# can cross a cell; lowered twelve times over they added 26 s to the LM
+# training cells' `setup_s` (PERF.md section 6, PR 27).
+_FOLDED_STATICS = ("causal", "block_q", "block_k", "interpret")
+_PACKED_STATICS = _FOLDED_STATICS + ("n_heads", "fused_qkv")
+
+
+@functools.partial(jax.jit, static_argnames=_FOLDED_STATICS)
 def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
     """q/k/v: (bh, seq, d). Returns (out, lse)."""
     bh, seq_q, d = q.shape
@@ -266,8 +420,8 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, seq_q, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((1, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((1, block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -278,17 +432,48 @@ def _flash_fwd(q, k, v, *, causal, block_q, block_k, interpret):
     return out, lse[..., 0]
 
 
-def _bwd_probs(q_scaled, k_ref, lse_ref, qi, kj, block_q, block_k, causal,
-               offset):
-    """Recompute the (block_q, block_k) probability tile from the saved
-    logsumexp: p = exp(qs@k^T - lse). lse arrives as a (block_q, 1) column;
-    it is broadcast once to the 128-lane replicated form and lane-widened
-    from there (never a width-1 broadcast at block_k width)."""
-    s = _dot_tb(q_scaled, k_ref[:])
-    if causal:
-        s = s + _causal_penalty(qi, kj, block_q, block_k, offset)
-    lse128 = jnp.broadcast_to(lse_ref[:], (block_q, _LANES))
-    return jnp.exp(s - _widen(lse128, block_k))
+def _stat(x, width):
+    """A per-row statistic (lse, delta) held as a (rows, 1) column:
+    broadcast once to the 128-lane replicated form and lane-widened from
+    there (never a width-1 broadcast at score-tile width)."""
+    return _widen(jnp.broadcast_to(x, (x.shape[0], _LANES)), width)
+
+
+def _bwd_tiles(q_ref, do_ref, lse_ref, delta, k_ref, v_ref, heads, sm_scale,
+               strips):
+    """What both backward kernels compute of a grid cell, as a generator
+    over (strip, head): (rows, keys, lanes), the scaled q tile, do, and
+    the probability and ds tiles over the strip's live keys, recomputed
+    from the saved logsumexp: p = exp(qs@k^T - lse), ds = p * (do@v^T -
+    delta). `delta(rows, lanes, hh)` gives the head's (rows, 1) column."""
+    for rows, n_keys, pen in strips:
+        keys = slice(0, n_keys)
+        for lanes, hh in heads:
+            qs = (q_ref[rows, lanes] * sm_scale).astype(q_ref.dtype)
+            do = do_ref[rows, lanes]
+            k = k_ref[keys, lanes]
+            s = _mask_tail(_dot_tb(qs, k), pen)
+            p = jnp.exp(s - _stat(lse_ref[rows, hh:hh + 1], n_keys))
+            dp = _dot_tb(do, v_ref[keys, lanes])            # do @ v^T
+            ds = p * (dp - _stat(delta(rows, lanes, hh), n_keys))
+            yield (rows, keys, lanes), qs, do, k, p, ds.astype(qs.dtype)
+
+
+def _dkdv_cell(dk_scr, dv_scr, *args):
+    """dk/dv of one grid cell, as _cell_strips' body."""
+    for (_, keys, lanes), qs, do, _, p, ds in _bwd_tiles(*args):
+        dv_scr[keys, lanes] = dv_scr[keys, lanes] + _dot_ta(
+            p.astype(do.dtype), do)                         # p^T @ do
+        dk_scr[keys, lanes] = dk_scr[keys, lanes] + _dot_ta(ds, qs)  # ds^T @ qs
+
+
+def _dq_cell(dq_scr, *args):
+    """dq of one grid cell, as _cell_strips' body."""
+    for (rows, _, lanes), _, _, k, _, ds in _bwd_tiles(*args):
+        dq_scr[rows, lanes] = dq_scr[rows, lanes] + lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),                # ds @ k
+            preferred_element_type=jnp.float32,
+        )
 
 
 def _dkdv_kernel(
@@ -303,30 +488,18 @@ def _dkdv_kernel(
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     n_q = pl.num_programs(2)
-    offset = seq_k - seq_q if causal else 0
 
     @pl.when(qi == 0)
     def _init():
         dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    visible = (
-        (qi * block_q + block_q - 1 + offset) >= (ki * block_k)
-        if causal else (qi >= 0)
-    )
-
-    @pl.when(visible)
-    def _compute():
-        qs = (q_ref[:] * sm_scale).astype(q_ref.dtype)
-        do = do_ref[:]
-        p = _bwd_probs(qs, k_ref, lse_ref, qi, ki, block_q, block_k,
-                       causal, offset)
-        p_lo = p.astype(do.dtype)
-        dv_scr[:] = dv_scr[:] + _dot_ta(p_lo, do)       # p^T @ do
-        dp = _dot_tb(do, v_ref[:])                      # do @ v^T
-        delta128 = jnp.broadcast_to(delta_ref[:], (block_q, _LANES))
-        ds = p * (dp - _widen(delta128, block_k))
-        dk_scr[:] = dk_scr[:] + _dot_ta(ds.astype(qs.dtype), qs)  # ds^T @ qs
+    _cell_strips(
+        functools.partial(_dkdv_cell, dk_scr, dv_scr, q_ref, do_ref,
+                          lse_ref, lambda rows, lanes, hh: delta_ref[rows],
+                          k_ref, v_ref, [(slice(None), 0)], sm_scale),
+        qi, ki, block_q=block_q, block_k=block_k, causal=causal,
+        seq_q=seq_q, seq_k=seq_k)
 
     @pl.when(qi == n_q - 1)
     def _finalize():
@@ -343,36 +516,24 @@ def _dq_kernel(
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     n_k = pl.num_programs(2)
-    offset = seq_k - seq_q if causal else 0
 
     @pl.when(kj == 0)
     def _init():
         dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    visible = (
-        (qi * block_q + block_q - 1 + offset) >= (kj * block_k)
-        if causal else (kj >= 0)
-    )
-
-    @pl.when(visible)
-    def _compute():
-        qs = (q_ref[:] * sm_scale).astype(q_ref.dtype)
-        do = do_ref[:]
-        p = _bwd_probs(qs, k_ref, lse_ref, qi, kj, block_q, block_k,
-                       causal, offset)
-        dp = _dot_tb(do, v_ref[:])
-        delta128 = jnp.broadcast_to(delta_ref[:], (block_q, _LANES))
-        ds = (p * (dp - _widen(delta128, block_k))).astype(q_ref.dtype)
-        dq_scr[:] = dq_scr[:] + lax.dot_general(        # ds @ k
-            ds, k_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    _cell_strips(
+        functools.partial(_dq_cell, dq_scr, q_ref, do_ref, lse_ref,
+                          lambda rows, lanes, hh: delta_ref[rows],
+                          k_ref, v_ref, [(slice(None), 0)], sm_scale),
+        qi, kj, block_q=block_q, block_k=block_k, causal=causal,
+        seq_q=seq_q, seq_k=seq_k)
 
     @pl.when(kj == n_k - 1)
     def _finalize():
         dq_ref[:] = (dq_scr[:] * sm_scale).astype(dq_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=_FOLDED_STATICS)
 def _flash_bwd(q, k, v, do, lse, delta, *, causal, block_q, block_k,
                interpret):
     """Tiled dq/dk/dv. delta = rowsum(do*o) - g_lse, fp32 (bh, seq_q)."""
@@ -486,6 +647,11 @@ def _heads_per_pack(h: int, d: int):
     return hpc if h % hpc == 0 else None
 
 
+def _packed_heads(hpc, d):
+    """(lanes, index) of the hpc heads of a 128-wide column pack."""
+    return [(slice(hh * d, (hh + 1) * d), hh) for hh in range(hpc)]
+
+
 def _fwd_kernel_packed(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     *, sm_scale, block_q, block_k, causal, seq_q, seq_k, hpc, d,
@@ -498,7 +664,6 @@ def _fwd_kernel_packed(
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     n_k = pl.num_programs(3)
-    offset = seq_k - seq_q if causal else 0
 
     @pl.when(kj == 0)
     def _init():
@@ -506,30 +671,11 @@ def _fwd_kernel_packed(
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    visible = (
-        (qi * block_q + block_q - 1 + offset) >= (kj * block_k)
-        if causal else (kj >= 0)
-    )
-
-    # NOTE: a diagonal/interior split (interior cells skipping the iota/
-    # where penalty) measured NEUTRAL on v5e (1.33 vs 1.30 ms at lm_base
-    # shapes — the duplicated body costs what the skipped pass saves), so
-    # the penalty runs on every visited cell, like the bundled jax kernel.
-    @pl.when(visible)
-    def _compute():
-        penalty = (
-            _causal_penalty(qi, kj, block_q, block_k, offset)
-            if causal else None
-        )
-        for hh in range(hpc):
-            lo, hi = hh * d, (hh + 1) * d
-            q = (q_ref[:, lo:hi] * sm_scale).astype(q_ref.dtype)
-            s = _dot_tb(q, k_ref[:, lo:hi])
-            if causal:
-                s = s + penalty
-            m_scr[hh], l_scr[hh], acc_scr[:, lo:hi] = _softmax_accumulate(
-                s, v_ref[:, lo:hi], m_scr[hh], l_scr[hh], acc_scr[:, lo:hi]
-            )
+    _cell_strips(
+        functools.partial(_fwd_cell, q_ref, k_ref, v_ref, m_scr, l_scr,
+                          acc_scr, _packed_heads(hpc, d), sm_scale),
+        qi, kj, block_q=block_q, block_k=block_k, causal=causal,
+        seq_q=seq_q, seq_k=seq_k)
 
     @pl.when(kj == n_k - 1)
     def _finalize():
@@ -539,6 +685,7 @@ def _fwd_kernel_packed(
             lse_ref[:, hh:hh + 1] = m_scr[hh][:, :1] + jnp.log(l_safe)
 
 
+@functools.partial(jax.jit, static_argnames=_PACKED_STATICS)
 def _flash_fwd_packed(qf, kf, vf, *, n_heads, causal, block_q, block_k,
                       interpret, fused_qkv=False):
     """qf/kf/vf: flat (b, s, h*d). Returns (out_flat, lse_packed) where
@@ -616,51 +763,29 @@ def _dkdv_kernel_packed(
     ki = pl.program_id(2)
     qi = pl.program_id(3)
     n_q = pl.num_programs(3)
-    offset = seq_k - seq_q if causal else 0
 
     @pl.when(qi == 0)
     def _init():
         dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    visible = (
-        (qi * block_q + block_q - 1 + offset) >= (ki * block_k)
-        if causal else (qi >= 0)
-    )
-
-    @pl.when(visible)
-    def _compute():
-        penalty = (
-            _causal_penalty(qi, ki, block_q, block_k, offset)
-            if causal else None
+    def delta(rows, lanes, hh):
+        # delta = rowsum(do * o) for this head, recomputed in-register
+        # (a VPU mult+rowsum, noise next to the dots) — a separate
+        # XLA/Pallas delta pass costs more in relayouts/grid overhead
+        # than it saves (measured round 4)
+        return jnp.sum(
+            do_ref[rows, lanes].astype(jnp.float32)
+            * out_ref[rows, lanes].astype(jnp.float32),
+            axis=-1, keepdims=True,
         )
-        for hh in range(hpc):
-            lo, hi = hh * d, (hh + 1) * d
-            qs = (q_ref[:, lo:hi] * sm_scale).astype(q_ref.dtype)
-            do = do_ref[:, lo:hi]
-            s = _dot_tb(qs, k_ref[:, lo:hi])
-            if causal:
-                s = s + penalty
-            lse128 = jnp.broadcast_to(lse_ref[:, hh:hh + 1],
-                                      (block_q, _LANES))
-            p = jnp.exp(s - _widen(lse128, block_k))
-            p_lo = p.astype(do.dtype)
-            dv_scr[:, lo:hi] = dv_scr[:, lo:hi] + _dot_ta(p_lo, do)
-            dp = _dot_tb(do, v_ref[:, lo:hi])
-            # delta = rowsum(do * o) for this head, recomputed in-register
-            # (a VPU mult+rowsum, noise next to the dots) — a separate
-            # XLA/Pallas delta pass costs more in relayouts/grid overhead
-            # than it saves (measured round 4)
-            delta = jnp.sum(
-                do.astype(jnp.float32) * out_ref[:, lo:hi].astype(
-                    jnp.float32),
-                axis=-1, keepdims=True,
-            )
-            delta128 = jnp.broadcast_to(delta, (block_q, _LANES))
-            ds = p * (dp - _widen(delta128, block_k))
-            dk_scr[:, lo:hi] = dk_scr[:, lo:hi] + _dot_ta(
-                ds.astype(qs.dtype), qs
-            )
+
+    _cell_strips(
+        functools.partial(_dkdv_cell, dk_scr, dv_scr, q_ref, do_ref,
+                          lse_ref, delta, k_ref, v_ref,
+                          _packed_heads(hpc, d), sm_scale),
+        qi, ki, block_q=block_q, block_k=block_k, causal=causal,
+        seq_q=seq_q, seq_k=seq_k)
 
     @pl.when(qi == n_q - 1)
     def _finalize():
@@ -676,7 +801,6 @@ def _dq_kernel_packed(
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     n_k = pl.num_programs(3)
-    offset = seq_k - seq_q if causal else 0
 
     @pl.when(kj == 0)
     def _init():
@@ -692,41 +816,20 @@ def _dq_kernel_packed(
                 prod[:, hh * d:(hh + 1) * d], axis=-1, keepdims=True
             )
 
-    visible = (
-        (qi * block_q + block_q - 1 + offset) >= (kj * block_k)
-        if causal else (kj >= 0)
-    )
-
-    @pl.when(visible)
-    def _compute():
-        penalty = (
-            _causal_penalty(qi, kj, block_q, block_k, offset)
-            if causal else None
-        )
-        for hh in range(hpc):
-            lo, hi = hh * d, (hh + 1) * d
-            qs = (q_ref[:, lo:hi] * sm_scale).astype(q_ref.dtype)
-            do = do_ref[:, lo:hi]
-            s = _dot_tb(qs, k_ref[:, lo:hi])
-            if causal:
-                s = s + penalty
-            lse128 = jnp.broadcast_to(lse_ref[:, hh:hh + 1],
-                                      (block_q, _LANES))
-            p = jnp.exp(s - _widen(lse128, block_k))
-            dp = _dot_tb(do, v_ref[:, lo:hi])
-            delta128 = jnp.broadcast_to(delta_scr[:, hh:hh + 1],
-                                        (block_q, _LANES))
-            ds = (p * (dp - _widen(delta128, block_k))).astype(q_ref.dtype)
-            dq_scr[:, lo:hi] = dq_scr[:, lo:hi] + lax.dot_general(
-                ds, k_ref[:, lo:hi], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+    _cell_strips(
+        functools.partial(
+            _dq_cell, dq_scr, q_ref, do_ref, lse_ref,
+            lambda rows, lanes, hh: delta_scr[rows, hh:hh + 1],
+            k_ref, v_ref, _packed_heads(hpc, d), sm_scale),
+        qi, kj, block_q=block_q, block_k=block_k, causal=causal,
+        seq_q=seq_q, seq_k=seq_k)
 
     @pl.when(kj == n_k - 1)
     def _finalize():
         dq_ref[:] = (dq_scr[:] * sm_scale).astype(dq_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=_PACKED_STATICS)
 def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
                       block_q, block_k, interpret, fused_qkv=False):
     """Packed grads. lse_pk: (b, n_packs, seq_q, hpc) fp32; out is the
@@ -1010,9 +1113,12 @@ def flash_attention(
 ) -> jnp.ndarray:
     """Fused multi-head attention; layout-matches ops.attention._attention.
 
-    Default blocks (512, 1024) are the measured sweet spot on TPU v5e for
-    lm_base shapes (head_dim 64). Blocks clamp to the sequence length, so
-    short-seq callers (ViT at s=64) are unaffected.
+    Default blocks (512, 1024): on TPU v5e at lm_base shapes (head_dim
+    64) every smaller grid cell lost more to per-cell overhead than its
+    mask savings gave (BENCHMARKS.md, round 4); the causal saving inside
+    a cell is taken by 256-wide sub-tiles instead (_SUB; PERF.md section
+    6, PR 27). Blocks clamp to the sequence length, so short-seq callers
+    (ViT at s=64) are unaffected.
 
     When head_dim packs into 128 lanes (d <= 128 dividing 128, head count
     a multiple of the pack; or d a multiple of 128) the packed-layout

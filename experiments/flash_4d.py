@@ -108,7 +108,8 @@ def _fwd_kernel_ids(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         q = (q_ref[:] * sm_scale).astype(q_ref.dtype)
         s = fa._dot_tb(q, k_ref[:])
         if causal:
-            s = s + fa._causal_penalty(qi, kj, block_q, block_k, offset)
+            s = s + fa._tile_penalty(
+                qi * block_q + offset - kj * block_k, block_q, block_k)
         m_prev = m_scr[:]
         l_prev = l_scr[:]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])

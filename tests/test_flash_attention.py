@@ -288,3 +288,154 @@ def test_flash_island_matches_unsharded(devices, rope):
         ),
         got_g, want_g,
     )
+
+
+# ---------------------------------------------------------------------
+# The causal sub-tile schedule (PR 27): inside a visible grid cell only
+# the score sub-tiles holding an unmasked element are computed.
+# ---------------------------------------------------------------------
+
+def _tile_classes(seq_q, seq_k, block_q, block_k):
+    """What _live_subtiles says of every sub-tile of a causal head, strip
+    by strip and cell by cell as the kernels ask it, beside what the mask
+    itself says: two int maps over the (seq_q // sub_q, seq_k // sub_k)
+    sub-tiles, 0 = all mask (skipped), 1 = crossed by the diagonal
+    (computed with the penalty), 2 = no masked element (computed bare)."""
+    from ddp_practice_tpu.ops.flash_attention import (
+        _check_blocks, _live_subtiles, _sub_tiles)
+
+    block_q, block_k = _check_blocks(seq_q, seq_k, block_q, block_k, True)
+    sub_q, sub_k = _sub_tiles(block_q, block_k)
+    offset = seq_k - seq_q
+    nq, nk = seq_q // sub_q, seq_k // sub_k
+    by_schedule = np.zeros((nq, nk), int)
+    for qi in range(seq_q // block_q):
+        for kj in range(seq_k // block_k):
+            shift = qi * block_q + offset - kj * block_k
+            c0 = kj * block_k // sub_k
+            for r in range(block_q // sub_q):
+                n_full, n_live = _live_subtiles(
+                    shift + r * sub_q, sub_q, sub_k, block_k, xp=np)
+                row = qi * block_q // sub_q + r
+                by_schedule[row, c0:c0 + n_live] = 1
+                by_schedule[row, c0:c0 + n_full] = 2
+    unmasked = (np.arange(seq_k)[None, :]
+                <= np.arange(seq_q)[:, None] + offset)
+    tiles = unmasked.reshape(nq, sub_q, nk, sub_k)
+    by_mask = tiles.any(axis=(1, 3)).astype(int) + tiles.all(axis=(1, 3))
+    return by_schedule, by_mask, (sub_q, sub_k)
+
+
+@pytest.mark.parametrize("seq_q,seq_k,block_q,block_k", [
+    (2048, 2048, 512, 1024),   # the LM training cells
+    (2048, 2048, 1024, 512),
+    (2048, 2048, 256, 256),
+    (4096, 4096, 512, 1024),
+    (1024, 2048, 512, 1024),   # seq_q < seq_k: bottom-right alignment
+    (512, 640, 512, 1024),     # offset 128: no multiple of the 256 rows
+    (384, 1536, 512, 1024),    # blocks 384 and 512
+    (256, 2048, 512, 1024),
+    (768, 768, 512, 512),
+    (192, 192, 512, 1024),     # no lane-aligned sub-tile: the cell whole
+    (320, 640, 512, 1024),
+    (128, 128, 512, 1024),
+])
+def test_causal_schedule_covers_the_mask_and_nothing_else(
+        seq_q, seq_k, block_q, block_k):
+    """Every unmasked (i, j) lies in a computed sub-tile, no computed
+    sub-tile is wholly masked, the penalty goes exactly where the
+    diagonal crosses, and causal_tile_counts counts that same schedule."""
+    from ddp_practice_tpu.ops.flash_attention import causal_tile_counts
+
+    by_schedule, by_mask, (sub_q, sub_k) = _tile_classes(
+        seq_q, seq_k, block_q, block_k)
+    np.testing.assert_array_equal(by_schedule, by_mask)
+    executed, useful = causal_tile_counts(seq_q, seq_k, block_q, block_k)
+    assert executed == (by_mask > 0).sum()
+    offset = seq_k - seq_q
+    unmasked = np.clip(np.arange(seq_q) + offset + 1, 0, seq_k).sum()
+    assert useful == pytest.approx(unmasked / (sub_q * sub_k))
+    assert useful <= executed
+
+
+def test_causal_tile_counts_at_the_training_cells_shape():
+    """lm_2k_b8: the whole-cell schedule ran 12 squares of 512x512 a head
+    where 8 hold work (1.50x); sub-tiles must leave at most 1.13x."""
+    from ddp_practice_tpu.ops.flash_attention import (
+        _cell_strips, _sub_tiles, causal_tile_counts)
+
+    executed, useful = causal_tile_counts(2048, 2048, 512, 1024)
+    assert executed <= 1.13 * useful
+    sub_q, sub_k = _sub_tiles(512, 1024)
+    squares = executed * sub_q * sub_k / 512 ** 2
+    assert 8.0 < squares <= 9.0
+    # a non-causal cell is one strip: every row against every key, bare
+    seen = []
+    _cell_strips(seen.append, 0, 0, block_q=512, block_k=1024, causal=False,
+                 seq_q=2048, seq_k=2048)
+    assert seen == [[(slice(None), 1024, None)]]
+
+
+_CAUSAL_SHAPES = {
+    # block_k > block_q at two q blocks: wholly masked sub-tiles, diagonal
+    # sub-tiles, bare ones, and masked trailing columns of the K/V block
+    "trailing": (1024, 1024, 512, 1024),
+    # cells of one sub-tile each, every loop at most one step long
+    "single": (768, 768, 512, 512),
+    # seq_q < seq_k: bottom-right alignment, offset a whole K/V sub-tile
+    "cross": (512, 1024, 256, 512),
+    # offset 128 under 256-row strips: two crossed sub-tiles a strip
+    "cross_odd": (512, 640, 512, 1024),
+    # blocks of 384 fit 128-wide sub-tiles
+    "fit_128": (384, 384, 512, 1024),
+    # 192 and 320 hold no lane-aligned sub-tile: the cell stays whole
+    "whole_192": (192, 192, 512, 1024),
+    "whole_320": (320, 320, 512, 1024),
+}
+
+
+@pytest.mark.parametrize("path,shape", [
+    (path, shape) for shape, (sq, sk, _, _) in _CAUSAL_SHAPES.items()
+    for path in ("packed", "fused_qkv", "folded")
+    if path != "fused_qkv" or sq == sk       # fused IS self-attention
+] + [("packed_d128", "trailing")])           # one head a 128-lane pack
+def test_causal_subtiles_match_dense(path, shape):
+    """Causal output and all three gradients against dense attention, on
+    every kernel family, over shapes whose cells hold skipped, crossed
+    and bare sub-tiles (asserted below, so a change of _SUB that empties
+    a case fails here and not silently)."""
+    from ddp_practice_tpu.ops.flash_attention import flash_attention_qkv
+
+    seq_q, seq_k, block_q, block_k = _CAUSAL_SHAPES[shape]
+    _, by_mask, _ = _tile_classes(seq_q, seq_k, block_q, block_k)
+    if shape == "trailing":
+        assert {0, 1, 2} <= set(by_mask.ravel())
+    if shape.startswith("whole"):
+        assert by_mask.shape == (1, 1)
+    h = 3 if path == "folded" else 2         # 3 heads of 64 do not pack
+    b, d = 1, 128 if path == "packed_d128" else 64
+    rng = np.random.default_rng(31)
+    mk = lambda s: jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
+    q, k, v, w = mk(seq_q), mk(seq_k), mk(seq_k), mk(seq_q)
+
+    if path == "fused_qkv":
+        def flash(q, k, v):
+            qkv = jnp.concatenate(
+                [x.reshape(b, seq_q, h * d) for x in (q, k, v)], axis=-1)
+            return flash_attention_qkv(qkv, h, causal=True, block_q=block_q,
+                                       block_k=block_k)
+    else:
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal=True, block_q=block_q,
+                                   block_k=block_k)
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)),
+        np.asarray(_attention(q, k, v, causal=True)), rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda *a: (flash(*a) * w).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    want = jax.grad(lambda *a: (_attention(*a, causal=True) * w).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b_), rtol=2e-4, atol=2e-4)
