@@ -15,56 +15,14 @@ Examples:
   python -m ddp_practice_tpu.cli --precision bf16     # the "AMP" variant
   python -m ddp_practice_tpu.cli --model vit_tiny --dataset cifar10 \\
       --tensor 2 --optimizer adamw --lr 1e-3
-  python -m ddp_practice_tpu.cli serve                # continuous-batching
-                                                      # serve bench (serve/)
-  python -m ddp_practice_tpu.cli serve --replicas 2 \\
-      --fault-plan '{"faults": [{"kind": "crash", "tick": 40}]}'
-                                       # fault-tolerant router fleet:
-                                       # goodput under injected faults
-  python -m ddp_practice_tpu.cli serve --procs 2  # CROSS-PROCESS fleet:
-                                       # real worker OS processes behind
-                                       # the RPC seam (serve/worker.py,
-                                       # supervised + federated telemetry)
-  python -m ddp_practice_tpu.cli serve --procs 2 --rate 100 \\
-      --fault-plan '{"faults": [{"kind": "kill", "at_s": 1.0}]}'
-                                       # chaos with teeth: SIGKILL a live
-                                       # worker mid-decode, goodput +
-                                       # zero-lost measured for real
-  python -m ddp_practice_tpu.cli serve --procs 2 --trace-out fleet.json
-                                       # FLEET tracing: worker spans
-                                       # stream back + merge into ONE
-                                       # clock-aligned timeline; validate
-                                       # with check_traces.py --fleet
-  python -m ddp_practice_tpu.cli serve --procs 2 \\
-      --otlp-endpoint http://collector:4318/v1/traces
-                                       # LIVE egress: kept spans batch-
-                                       # POST to an OTLP/HTTP collector
-                                       # as they land (bounded queue,
-                                       # retry backoff, dead-endpoint
-                                       # breaker; at-least-once with
-                                       # batch-id dedup)
-  python -m ddp_practice_tpu.cli serve --procs 2 --rate 100 \\
-      --adaptive-sampling --trace-budget-sps 150
-                                       # adaptive head rate: a feedback
-                                       # loop steers kept-spans/s to the
-                                       # budget through a 4x load step,
-                                       # pushing rate changes live over
-                                       # the rpc trace op
-  python -m ddp_practice_tpu.cli serve --procs 2 \\
-      --trace-tenant-rates '{"acme": 1.0, "free-tier": 0.01}'
-                                       # per-tenant head rates: tenants
-                                       # keep their own sampling floor;
-                                       # tail keeps (faults, failovers)
-                                       # stay tenant-blind
-  python -m ddp_practice_tpu.cli serve --procs 3 --autoscale --rate 25
-                                       # ELASTIC fleet vs the peak-
-                                       # provisioned fixed arm through a
-                                       # 4x arrival step: trip-fast scale
-                                       # up from a pre-warmed standby
-                                       # (ms, not ~15 s), resolve-slow
-                                       # drain back down; gates goodput/
-                                       # worker-second, reaction time,
-                                       # zero lost, oscillation bound
+  python -m ddp_practice_tpu.cli serve --ckpt_dir ck --prompt "ab"
+                                       # serve prompts from a trained LM
+                                       # checkpoint through the
+                                       # continuous-batching engine
+                                       # (serve/cli.py owns the flags)
+
+Measuring is not this module's job: `python3 perf/run.py --workload
+<cell>` is the benchmark (BENCHMARK.json) and PERF.md holds its results.
 """
 
 from __future__ import annotations
@@ -346,9 +304,9 @@ def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
     if argv and argv[0] == "serve":
         # inference subcommand: the training flags below don't apply, so
-        # dispatch before the trainer parser sees the argv (serve/bench.py
+        # dispatch before the trainer parser sees the argv (serve/cli.py
         # owns the serve flag surface)
-        from ddp_practice_tpu.serve.bench import main as serve_main
+        from ddp_practice_tpu.serve.cli import main as serve_main
 
         return serve_main(argv[1:])
     args = build_parser().parse_args(argv)

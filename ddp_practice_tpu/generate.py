@@ -59,7 +59,7 @@ def build_parser() -> argparse.ArgumentParser:
 def load_lm(ckpt_dir, *, model=None, seq_len=0, kv_cache="policy") -> tuple:
     """(model, params, batch_stats, step) rebuilt from the checkpoint
     manifest + leaves — shared by this CLI and the serving entry point
-    (serve/bench.py), which is why it takes plain kwargs rather than the
+    (serve/cli.py), which is why it takes plain kwargs rather than the
     parsed argparse namespace."""
     manifest = ckpt.latest_manifest(ckpt_dir)
     if manifest is None:

@@ -15,8 +15,8 @@ ViT param names (`parallel/sharding_rules.py`) apply unchanged. The
 embedding table and the (untied) output projection both shard over
 'tensor' by name. Logits are fp32 (softmax stability under bf16 compute).
 
-Wired surfaces: `bench.py --models lm_long` (tokens/sec + MFU at long
-sequence on the real chip), `__graft_entry__.dryrun_multichip` (dp x sp
+Wired surfaces: `perf/run.py --workload gpt2s_train_2k` (MFU at seq 2048
+on the real chip), `__graft_entry__.dryrun_multichip` (dp x sp
 causal ring + flash case), `train/steps.py make_lm_train_step` (next-token
 loss), `tests/test_lm.py`.
 """
@@ -288,7 +288,7 @@ def LMTiny(**kw):
 
 def LMBase(**kw):
     """Bench-sized decoder (d=768, L=12, GPT-2-small shape) for the
-    long-context throughput/MFU measurements (bench.py lm_long)."""
+    long-context throughput/MFU measurements (perf/ gpt2s_* cells)."""
     kw.setdefault("hidden_dim", 768)
     kw.setdefault("depth", 12)
     kw.setdefault("num_heads", 12)

@@ -23,8 +23,7 @@ Layers (each its own module, composable and separately testable):
   per-request deadlines, EOS/length release, injectable clock
   (FakeClock for deterministic CPU tests) and fault hook;
 - faults.py    — seeded, JSON-serializable FaultPlan (crash / latency /
-  nan_logits / admit_fail) driving deterministic chaos tests and
-  goodput-under-faults benches;
+  nan_logits / admit_fail) driving deterministic chaos tests;
 - health.py    — per-replica HEALTHY/DEGRADED/DEAD state machine with a
   consecutive-failure circuit breaker and backoff half-open probes;
 - slo.py       — declarative SLO targets (TTFT/TPOT p99, error rate,
@@ -65,10 +64,8 @@ Layers (each its own module, composable and separately testable):
   router all take an optional TraceRecorder (`--trace-out` exports
   Chrome trace JSON; tools/check_traces.py validates it), and every
   Completion carries a queue/prefill/decode/stall flight record;
-- bench.py     — serve_bench: one Poisson trace through the continuous
-  engine, the static-batch baseline, and (--replicas) the router fleet
-  with optional --fault-plan goodput runs (BENCHMARKS.md records the
-  curves); also the `cli.py serve` entry point;
+- cli.py       — the `cli.py serve` entry point: serve --prompt strings
+  from a trained LM checkpoint (measurement is perf/run.py, PERF.md);
 - frontdoor.py — the HTTP/SSE wire surface over Router.stream: POST
   /v1/generate streams the typed tokens/resumed/end events as SSE
   frames (sse.py codec, shared by server and client), per-tenant
@@ -83,10 +80,12 @@ Layers (each its own module, composable and separately testable):
   and Jain's fairness index; slo.py's TenantSLORegistry gives each
   tenant its own error budget so a hostile tenant's burn pages as ITS
   alert and scopes the brown-out to ITS work;
-- workload.py  — deterministic multi-tenant workload plans (the QoS
-  lab): per-tenant Poisson/bursty/diurnal arrivals, heavy-tailed
-  lengths, multi-turn sessions, a hostile marker — JSON-serializable
-  and byte-replayable, judged offline by tools/check_qos.py.
+- workload.py  — deterministic synthetic traffic: the single-tenant
+  Poisson trace builders the router tests replay, and multi-tenant
+  workload plans (per-tenant Poisson/bursty/diurnal arrivals,
+  heavy-tailed lengths, multi-turn sessions, a hostile marker) —
+  JSON-serializable and byte-replayable, judged offline by
+  tools/check_qos.py.
 """
 
 from ddp_practice_tpu.serve.admission import (

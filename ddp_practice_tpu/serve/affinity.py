@@ -31,9 +31,8 @@ cache-aware scheduling) with the vLLM paged block as the unit of reuse:
   the changed replica), and clean fallback to the least-loaded order
   when digests are absent or cold.
 * `LeastLoadedPolicy` — the PR-2 order behind the same seam: HEALTHY
-  before DEGRADED, then least-loaded, then stable id. The control arm
-  of `fleet_bench --cache-aware`, and the Router default when
-  `RouterConfig.cache_aware` is off.
+  before DEGRADED, then least-loaded, then stable id. The Router
+  default when `RouterConfig.cache_aware` is off.
 
 Everything here is host-pure (no jax), deterministic, and wire-safe:
 digests are plain ints/lists in JSON frames.

@@ -3,9 +3,9 @@
 The slot pool (kv_slots.py) shares ONE write cursor: every decode step
 consumes a position for all slots, the pool drains in
 `max_len - max_bucket` steps between epoch rewinds, and decode attention
-scans the whole `[0, max_len)` span every step — BENCHMARKS.md measured
-the span cost directly (halving max_len moved continuous/static
-throughput 0.54x -> ~1.0x). This module replaces positions-as-a-global-
+scans the whole `[0, max_len)` span every step, whatever the slots
+hold: the flat cache's cost follows its allocated span, not the live
+context. This module replaces positions-as-a-global-
 resource with vLLM-style paging:
 
 - the flax "cache" collection of a decode-mode model is allocated as a

@@ -204,7 +204,7 @@ class TokenStream:
         # replica tokens suppressed by the dedup cursor (failover
         # re-decode of the salvaged prefix lands here — EXPECTED under
         # chaos; consumer-visible duplicates are structurally impossible
-        # and re-checked from the event log by bench/check_stream)
+        # and re-checked from the event log by tools/check_stream.py)
         self.suppressed = 0
         self.gaps = 0
         self.resume_gap_s = 0.0

@@ -169,8 +169,8 @@ class _TraceBuffer:
 
 
 def build_model(model_kw: dict):
-    """The bench's tiny-LM recipe (serve/bench.py), spec-driven: same
-    kwargs + PRNGKey(0) init in every process -> replicated params."""
+    """The fleet's tiny-LM recipe, spec-driven: same kwargs +
+    PRNGKey(0) init in every process -> replicated params."""
     import jax
     import jax.numpy as jnp
 

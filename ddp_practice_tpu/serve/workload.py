@@ -1,7 +1,8 @@
-"""Deterministic multi-tenant workload plans for the QoS lab.
+"""Deterministic synthetic traffic: trace builders and workload plans.
 
-The single-tenant trace builders in serve/bench.py (Poisson arrivals,
-uniform lengths) can show throughput but cannot show FAIRNESS: every
+The single-tenant trace builders at the end of this module (build_trace,
+build_shared_prefix_trace: Poisson arrivals, uniform lengths) are what
+the router and affinity tests replay; they cannot show FAIRNESS: every
 interesting QoS failure needs at least two tenants with different
 shapes — a hostile tenant flooding at several times its share while a
 compliant tenant trickles, bursts landing on a diurnal trough, long
@@ -9,9 +10,9 @@ heavy-tailed prompts starving short interactive ones. This module makes
 that mix a first-class, REPLAYABLE input, the same way serve/faults.py
 made failures one: a WorkloadPlan is a list of TenantSpecs serialized
 as JSON, and ``build(vocab=..., seed=...)`` expands it into the same
-arrival-sorted trace-dict list the bench harness already replays —
-identical every time for a given (plan, vocab, seed), so the fair and
-FIFO arms of a bench see byte-identical offered load.
+arrival-sorted trace-dict list those builders return — identical every
+time for a given (plan, vocab, seed), so two arms of a comparison see
+byte-identical offered load.
 
 Per-tenant knobs (each one a real traffic shape):
 
@@ -33,9 +34,8 @@ Per-tenant knobs (each one a real traffic shape):
 - ``hostile`` — marks the tenant whose traffic is the attack in an
   isolation experiment. The flag changes NOTHING about generation
   (hostility is just a rate several times the fair share — set
-  ``rate_rps`` accordingly); it tells consumers (the qos bench arm,
-  tools/check_qos.py) which tenant's SLO alert SHOULD trip and whose
-  must not.
+  ``rate_rps`` accordingly); it tells consumers (tools/check_qos.py)
+  which tenant's SLO alert SHOULD trip and whose must not.
 
 Trace rows carry ``tenant`` and ``priority``, which Request already
 threads through every seam (admission -> scheduler -> SLO attribution),
@@ -278,3 +278,69 @@ def _row(spec: TenantSpec, at: float, prompt: list, rng) -> dict:
         "tenant": spec.name,
         "priority": spec.priority,
     }
+
+
+def build_trace(
+    *,
+    n_requests: int,
+    rate_hz: float,
+    vocab: int,
+    prompt_len_range=(2, 16),
+    max_new_range=(4, 32),
+    seed: int = 0,
+) -> list:
+    """Poisson arrivals with mixed prompt lengths and token budgets."""
+    rng = np.random.default_rng(seed)
+    gaps = rng.exponential(1.0 / rate_hz, n_requests)
+    arrivals = np.cumsum(gaps)
+    trace = []
+    for i in range(n_requests):
+        plen = int(rng.integers(prompt_len_range[0], prompt_len_range[1] + 1))
+        trace.append({
+            "rid": i,
+            "arrival": float(arrivals[i]),
+            "prompt": rng.integers(0, vocab, plen).tolist(),
+            "max_new_tokens": int(
+                rng.integers(max_new_range[0], max_new_range[1] + 1)
+            ),
+        })
+    return trace
+
+
+def build_shared_prefix_trace(
+    *,
+    n_requests: int,
+    rate_hz: float,
+    vocab: int,
+    k_prefixes: int = 2,
+    prefix_len: int = 48,
+    tail_range=(1, 8),
+    max_new_range=(8, 24),
+    seed: int = 0,
+) -> list:
+    """K seeded system prompts x many continuations — the PR-6 prefix
+    workload: every request is one of `k_prefixes` fixed prefixes plus a
+    short unique tail, arriving Poisson. Deterministic per seed (same
+    trace replays through the plain and prefix-sharing engines)."""
+    rng = np.random.default_rng(seed)
+    prefixes = [
+        rng.integers(0, vocab, prefix_len).tolist()
+        for _ in range(k_prefixes)
+    ]
+    gaps = rng.exponential(1.0 / rate_hz, n_requests)
+    arrivals = np.cumsum(gaps)
+    trace = []
+    for i in range(n_requests):
+        pre = prefixes[int(rng.integers(0, k_prefixes))]
+        tail = rng.integers(
+            0, vocab, int(rng.integers(tail_range[0], tail_range[1] + 1))
+        ).tolist()
+        trace.append({
+            "rid": i,
+            "arrival": float(arrivals[i]),
+            "prompt": list(pre) + tail,
+            "max_new_tokens": int(
+                rng.integers(max_new_range[0], max_new_range[1] + 1)
+            ),
+        })
+    return trace

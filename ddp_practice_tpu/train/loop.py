@@ -955,7 +955,7 @@ class Trainer:
         measured, tests/test_resident.py).
 
         With profile_dir, the trace covers the whole first epoch (the first
-        group includes compile; use bench.py for steady-state traces)."""
+        group includes compile; perf/run.py --trace 1 for steady state)."""
         cfg = self.config
         with self._tspan("epoch_open"):
             self.train_loader.set_epoch(epoch)

@@ -36,7 +36,7 @@ def enable_compile_cache(setting: str = "auto") -> Optional[str]:
     in use, or None when it is off or could not be made.
 
     Called first by every entry point (cli train/serve, serve/worker.py,
-    bench.py, generate.py, chip_smoke.py). Where
+    generate.py, chip_smoke.py). Where
     `JAX_COMPILATION_CACHE_DIR` is set JAX already points there and this
     sets no other directory; otherwise the cache lives in
     `COMPILE_CACHE_DIR`. "off" is for tests that count compiles.

@@ -23,7 +23,7 @@ def main():
     from ddp_practice_tpu.train.steps import _lm_train_step_fn
     from ddp_practice_tpu.utils.xprof import op_summary
 
-    # the bench.py lm_moe entry's exact dims
+    # lm_moe at lm_base dims (the 2026-07 suite's entry)
     seq_len, vocab, bsz, K = 2048, 32768, 8, 4
     model_kwargs = dict(
         hidden_dim=768, depth=12, num_heads=12, mlp_dim=3072,

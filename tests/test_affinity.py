@@ -460,7 +460,7 @@ def test_sigkill_affinity_preferred_worker_failover_and_invalidate():
 
     import numpy as np
 
-    from ddp_practice_tpu.serve.bench import build_shared_prefix_trace
+    from ddp_practice_tpu.serve.workload import build_shared_prefix_trace
     from ddp_practice_tpu.serve.engine import EngineConfig, PagedEngine
     from ddp_practice_tpu.serve.router import RouterConfig
     from ddp_practice_tpu.serve.scheduler import Request, Scheduler

@@ -33,58 +33,6 @@ def test_chip_smoke_refuses_to_run_without_a_chip(args):
     assert "no accelerator" in r.stderr and "nothing was run" in r.stderr
 
 
-def test_bench_refuses_to_run_without_a_chip():
-    r = _run("bench.py", "--models", "lm_decode")
-    assert r.returncode != 0
-    assert r.stdout == ""
-    assert "no TPU" in r.stderr
-
-
-class _FakeChip:
-    platform = "tpu"
-    device_kind = "TPU v5 lite"
-
-
-def test_bench_exits_nonzero_when_a_requested_model_fails(
-        monkeypatch, capsys):
-    """The line and the (partial) suite are still written, but a model
-    that failed fails the run — `n_errors` is not a field to overlook."""
-    import bench
-    from ddp_practice_tpu import benchmarks
-
-    def decode(name, *, batch_size, **kw):
-        if batch_size == 1:
-            raise RuntimeError("boom")
-        return {"model": name, "mode": "decode", "batch_size": batch_size,
-                "precision": "bf16", "n_chips": 1,
-                "device_kind": _FakeChip.device_kind,
-                "tokens_per_sec_per_chip": 1.0}
-
-    written = []
-    monkeypatch.setattr(jax, "devices", lambda: [_FakeChip()])
-    monkeypatch.setattr(benchmarks, "bench_lm_decode", decode)
-    monkeypatch.setattr(bench, "_write_suite",
-                        lambda suite, partial: written.append(partial))
-    monkeypatch.setattr(backend, "enable_compile_cache", lambda *a: None)
-    assert bench.main(["--models", "lm_decode,lm_decode_bs1"]) == 1
-    line = json.loads(capsys.readouterr().out.splitlines()[-1])
-    assert line["n_errors"] == 1 and line["value"] == 1.0
-    assert written == [True]  # never over the recorded full suite
-    assert bench.main(["--models", "lm_decode"]) == 0
-
-
-def test_benchmarks_raise_on_an_unknown_device(monkeypatch):
-    from ddp_practice_tpu import benchmarks
-
-    with pytest.raises(RuntimeError, match="measure the TPU"):
-        benchmarks._chip()  # the CPU test backend
-    monkeypatch.setattr(jax, "devices", lambda: [_FakeChip()])
-    assert benchmarks._chip() == ("TPU v5 lite", 197e12, 819e9)
-    monkeypatch.setattr(_FakeChip, "device_kind", "TPU v9 imaginary")
-    with pytest.raises(RuntimeError, match="peak tables"):
-        benchmarks._chip()
-
-
 @pytest.fixture
 def cache_config():
     was = jax.config.jax_compilation_cache_dir
@@ -126,7 +74,7 @@ def test_compile_cache_off_and_no_free_form_path(cache_config):
 def test_one_call_site_sets_the_cache_directory():
     hits = subprocess.run(
         ["grep", "-rn", "--include=*.py", "jax_compilation_cache_dir",
-         "ddp_practice_tpu", "bench.py", "chip_smoke.py",
+         "ddp_practice_tpu", "chip_smoke.py",
          "__graft_entry__.py", "tools", "experiments"],
         cwd=ROOT, capture_output=True, text=True,
     ).stdout.splitlines()

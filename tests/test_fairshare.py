@@ -6,11 +6,12 @@ cost metering + fleet federation, the per-tenant SLO registry's
 isolation/overflow semantics, and the tenant-scoped brown-out shed
 seam (in-process predicate + remote name-list wire form).
 
-Everything here is host-pure — fake engines, fake completions, fake
-RPC clients; no jax compile. The live end-to-end story (fair vs FIFO
-under a hostile flood, SIGKILL mid-flood) is pinned by the qos bench
-arm + tools/check_qos.py over its checked-in artifacts
-(tests/test_tools_artifacts.py)."""
+Everything but the last test is host-pure — fake engines, fake
+completions, fake RPC clients; no jax compile. The last one replays a
+hostile flood through one real engine, FIFO then fair: the same tokens
+a request, nothing lost, the compliant tenant served sooner. The
+SIGKILL-mid-flood story is judged by tools/check_qos.py over its
+checked-in artifacts (tests/test_tools_artifacts.py)."""
 
 import json
 import re
@@ -595,3 +596,45 @@ def test_slo_registry_tenant_gauges_respect_label_guard():
     finally:
         set_label_limit(old)
         reset_label_guard()
+
+
+# ------------------------------------- fair vs FIFO through a real engine
+def test_fair_head_reorders_who_runs_never_what_they_decode(devices):
+    """A hostile tenant's six requests are queued ahead of a compliant
+    tenant's two, one slot. Weighted-fair admission moves the compliant
+    tenant forward; every request still ends once, with the tokens FIFO
+    gave it (greedy: scheduling picks WHO decodes next, never WHAT)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddp_practice_tpu.models import create_model
+    from ddp_practice_tpu.serve import EngineConfig, SlotEngine
+
+    model = create_model(
+        "lm_tiny", vocab_size=32, max_len=96, hidden_dim=64, depth=2,
+        num_heads=4, mlp_dim=128, pos_emb="rope",
+    )
+    params = model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+    engine = SlotEngine(model, params, EngineConfig(
+        max_slots=1, max_len=96, prompt_buckets=(8,), temperature=0.0))
+    flood = [("bulk", [1 + i, 2, 3]) for i in range(6)] \
+        + [("acme", [9, 8 + i]) for i in range(2)]
+
+    def replay(vtc):
+        sched = Scheduler(engine, clock=FakeClock(step_s=0.01),
+                          max_queue=16, vtc=vtc)
+        for rid, (tenant, prompt) in enumerate(flood):
+            sched.submit(Request(rid=rid, prompt=prompt, max_new_tokens=5,
+                                 tenant=tenant))
+        return sched.run_until_idle()
+
+    fifo, fair = replay(None), replay(VirtualTokenCounter())
+    for comps in (fifo, fair):
+        assert sorted(c.rid for c in comps) == list(range(8))  # none lost
+        assert all(c.status == "length" for c in comps)
+    assert {c.rid: c.tokens for c in fair} == {c.rid: c.tokens for c in fifo}
+    first_acme = [[c.tenant for c in comps].index("acme")
+                  for comps in (fifo, fair)]
+    assert first_acme[0] == 6 and first_acme[1] < 3

@@ -10,11 +10,11 @@ Pinned in three tiers, cheapest first:
   /healthz run against a real socket but a router that never has to
   exist — the door turns these away before the engine is touched, so
   the test should not pay for an engine either.
-- `net` + `slow` e2e: a real router behind the door. Greedy tokens over
+- `net` e2e: a real router behind the door. Greedy tokens over
   the wire are bit-identical to `router.stream()` in-process, frame ids
   are contiguous with exactly one terminal, drain finishes in-flight
-  streams while refusing new ones, and a deliberately throttled reader
-  (tiny buffers at every layer) is SHED with a typed `slow_consumer`
+  streams while refusing new ones, and (`slow`) a deliberately throttled
+  reader (tiny buffers at every layer) is SHED with a typed `slow_consumer`
   terminal while its request decodes to completion anyway.
 """
 
@@ -254,7 +254,7 @@ def test_healthz_and_drain_refusal(stub_door):
     assert fd.drain(timeout_s=5)   # nothing in flight: immediate
 
 
-# ----------------------------------------------------- socket e2e, slow
+# ----------------------------------------------------------- socket e2e
 @pytest.fixture(scope="module")
 def lm():
     import jax
@@ -273,7 +273,6 @@ def lm():
 
 
 @pytest.mark.net
-@pytest.mark.slow
 def test_wire_identity_contiguity_and_drain(lm, devices):
     """One router, both sides: greedy reference tokens via
     `router.stream()` in-process, then the SAME router behind the door

@@ -227,8 +227,8 @@ def test_router_balances_over_training(devices):
         # drops for the balancers to fix (the default cf=2.0 gives this
         # small config so much slack that drops are 0 from step one and
         # the trajectory would assert nothing); the absolute <5% warm
-        # claim is recorded by the cf=2.0 bench entry (BENCHMARKS.json
-        # lm_moe: drop 0.0087 after 40 warm steps on this corpus)
+        # claim at cf=2.0 is a 2026-07 chip record (BENCHMARKS.md, MoE
+        # router balance: drop under 1% after 40 warm steps)
         capacity_factor=1.0,
     )
     tx = make_optimizer(

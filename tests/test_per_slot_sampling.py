@@ -4,8 +4,8 @@ The contract: ONE jitted decode program serves a batch mixing greedy
 and sampled rows with arbitrary per-request (temperature, top_k,
 top_p), bit-identical to the per-request `sample_logits` path, and
 never recompiles when the params change — they are traced (b,) arrays,
-not compile-time constants. The kernel-level pins are jit-free and run
-in tier-1; everything that compiles an engine is `slow`.
+not compile-time constants. The kernel-level pins are jit-free; the
+engine-level identity and zero-recompile pin runs in tier-1 too.
 """
 
 import jax
@@ -117,7 +117,6 @@ def _run_slot(eng, prompt, n=10, seed=7, sampling=None):
     return out
 
 
-@pytest.mark.slow
 def test_per_slot_stream_identical_to_config_baked_engine(lm, devices,
                                                           compile_guard):
     """A slot sampled at (t, k, p) in the per-slot engine emits the

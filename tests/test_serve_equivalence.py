@@ -20,11 +20,6 @@ from ddp_practice_tpu.models import create_model
 from ddp_practice_tpu.serve import EngineConfig, PagedEngine, SlotEngine
 from ddp_practice_tpu.serve.scheduler import FakeClock, Request, Scheduler
 
-# every test here compiles BOTH the one-shot scan and the serve programs
-# (~15-25 s each on the CI CPU) — full-suite tier only, per the tier-1
-# 870 s budget (pytest.ini)
-pytestmark = pytest.mark.slow
-
 VOCAB = 32
 
 

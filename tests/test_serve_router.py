@@ -33,7 +33,7 @@ from ddp_practice_tpu.serve import (
     SlotEngine,
     make_router,
 )
-from ddp_practice_tpu.serve.bench import build_trace
+from ddp_practice_tpu.serve.workload import build_trace
 
 VOCAB = 32
 
@@ -190,6 +190,49 @@ def test_nan_and_admit_faults_are_retried_to_identical_tokens(devices, lm):
     for c in comps:
         assert c.status == "length"
         assert c.tokens == want[c.rid], f"rid {c.rid} diverged"
+
+
+def test_faulted_fleet_loses_nothing_and_matches_a_clean_rerun(devices, lm):
+    """The router's count gates in one tier-1 run (the two slow tests
+    above hold them against a third, fault-free engine): an admission
+    failure, a latency spike and a NaN-poisoned slot on replica 1, then
+    replica 0 dead for good mid-decode. Every request ends `length`, the
+    survivor compiles nothing new, and each one's tokens, migrated and
+    retried ones included, are what the survivor gives the same prompt
+    when nothing goes wrong."""
+    model, params = lm
+    trace = _trace(8)
+    plan = FaultPlan([
+        FaultSpec(kind="admit_fail", tick=1, replica=1),
+        FaultSpec(kind="latency", tick=2, replica=1, delay_s=0.05),
+        FaultSpec(kind="nan_logits", tick=4, replica=1, slot=0),
+        FaultSpec(kind="crash", tick=6, replica=0),
+    ])
+    router = make_router(
+        model, params, 2, ENGINE_CFG, clock=FakeClock(step_s=0.01),
+        max_queue=64,
+        config=RouterConfig(max_retries=3, retry_base_s=0.01,
+                            retry_jitter=0.0, trip_after=10),
+        fault_plan=plan,
+    )
+    router.warmup()
+    warm = router.compile_stats()
+    faulted = {c.rid: c for c in _drive(router, trace)}
+    assert sorted(faulted) == [t["rid"] for t in trace]  # one end each
+    assert all(c.status == "length" for c in faulted.values())
+    assert router.metrics.failovers.value >= 1
+    assert router.metrics.retries.value >= 1
+    assert router.states() == {0: "dead", 1: "healthy"}
+    # the plan is spent and replica 0 stays down: the same prompts again
+    # run start to finish on the survivor
+    for t in trace:
+        router.submit(Request(rid=100 + t["rid"], prompt=t["prompt"],
+                              max_new_tokens=t["max_new_tokens"]))
+    router.run_until_idle()
+    clean = {c.rid - 100: c for c in router.completions if c.rid >= 100}
+    for rid, c in faulted.items():
+        assert c.tokens == clean[rid].tokens, f"rid {rid} diverged"
+    assert router.compile_stats()[1] == warm[1]
 
 
 def test_brownout_sheds_low_priority_and_caps_budget(devices, lm):

@@ -186,8 +186,8 @@ def test_flight_stats_surface_spec_accept_rate():
 
 
 # ====================================================== engine-level pins
-# everything below compiles real jitted programs (~15-25 s each on the
-# CI CPU) — full-suite tier only, per the tier-1 870 s budget
+# everything below compiles real jitted programs; the identity and
+# zero-recompile pin is tier-1, the rest full-suite only
 slow = pytest.mark.slow
 
 
@@ -258,7 +258,6 @@ def _warm(eng):
     return eng
 
 
-@slow
 def test_spec_token_identity_and_zero_recompiles(devices, lm,
                                                  compile_guard):
     """THE tentpole pin: the spec-enabled paged engine is greedy

@@ -263,7 +263,7 @@ def test_check_fleet_cache_verdict_as_library():
 
 def test_artifacts_validate_as_library_too():
     """Belt to the CLI suspenders: the library entry points the tests
-    and the serve bench use agree with the CLIs."""
+    use agree with the CLIs."""
     from tools.check_slo import load_events, slo_report
     from tools.check_traces import parse_stream_text, validate
 
@@ -291,12 +291,6 @@ def test_artifacts_validate_as_library_too():
 # worker-streamed spans under pid=worker-N lanes, clock_offset skew
 # model stamped by the collector
 FLEET_TRACE = os.path.join(ROOT, "tests", "data", "fleet_trace.json")
-# bench-regression ledger pair: baseline == the repo's own
-# BENCH_serve.json at the time the ledger was cut; _bad is the same
-# file with a 1.5x-regressed seam latency ratio and 2 lost requests
-BENCH_BASELINE = os.path.join(ROOT, "tests", "data",
-                              "bench_baseline.json")
-BENCH_BAD = os.path.join(ROOT, "tests", "data", "bench_current_bad.json")
 
 
 def test_check_traces_fleet_mode_exit_codes_both_ways(tmp_path):
@@ -343,109 +337,6 @@ def test_fleet_trace_artifact_contracts():
                 or x.get("id") == tid}
         linked = linked or ({0, 1} <= pids)
     assert linked
-
-
-def test_check_bench_exit_codes_both_ways(tmp_path):
-    # the repo's OWN bench json vs the checked-in baseline: the ledger
-    # that keeps fleet-overhead/goodput numbers honest across PRs
-    r = _run("tools/check_bench.py", "BENCH_serve.json",
-             "--baseline", BENCH_BASELINE)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "BENCH OK" in r.stdout
-    # the regressed current fails, and names the regressed keys
-    r = _run("tools/check_bench.py", BENCH_BAD,
-             "--baseline", BENCH_BASELINE)
-    assert r.returncode == 1
-    assert "REGRESSION" in r.stdout
-    assert "latency_ratio_p50" in r.stdout
-    assert "lost" in r.stdout
-    # the ISSUE-12 observability gates regress in the same ledger: a
-    # blown push overhead and a controller that missed its ±20% budget
-    assert "otlp_push_overhead_100rps.mean_ratio" in r.stdout
-    assert "adaptive_sampling_100rps.within_budget" in r.stdout
-    # the ISSUE-13 spec-decode gates regress in the same ledger: an
-    # evaporated TPOT win and one divergent stream — token identity
-    # is an absolute contract (baseline 1.0, tol 0), so the planted
-    # 31/32 identity must fail, not drift
-    assert "spec_decode_8rps.tpot_ratio" in r.stdout
-    assert "spec_decode_8rps.token_identity" in r.stdout
-    # the ISSUE-14 elastic gates regress in the same ledger: the
-    # goodput-per-worker edge evaporated, two requests lost across a
-    # scale event, a reaction outside the evaluation window, a thrash
-    # past the hold bound, and a 16s "warm" promotion — the absolute
-    # seconds bound (baseline 0 -> limit = tol) must catch it
-    assert "autoscale_burst_100rps.goodput_per_worker_ratio" in r.stdout
-    assert "autoscale_burst_100rps.lost" in r.stdout
-    assert "autoscale_burst_100rps.reaction_within_window" in r.stdout
-    assert "autoscale_burst_100rps.oscillation_ok" in r.stdout
-    assert "autoscale_burst_100rps.promote_join_s" in r.stdout
-    # the ISSUE-15 cache-routing gates regress in the same ledger: the
-    # affinity edge evaporated (hit-rate AND goodput ratios below the
-    # band), two requests lost, and one stream diverged from the
-    # least-loaded arm — identity is an absolute contract (baseline
-    # 1.0, tol 0), so the planted 0.958 must fail, not drift
-    assert "cache_routing_100rps.hit_rate_ratio" in r.stdout
-    assert "cache_routing_100rps.goodput_ratio" in r.stdout
-    assert "cache_routing_100rps.lost" in r.stdout
-    assert "cache_routing_100rps.token_identity" in r.stdout
-    # the ISSUE-19 tenant-QoS gates regress in the same ledger: a
-    # FIFO-grade fairness index, an isolation ratio past the 0.7x
-    # acceptance bound (gated as the 0/1 isolation_ok verdict), a
-    # silent hostile alert next to a paging compliant tenant, a
-    # diverged stream in each arm, and lost work under SIGKILL — the
-    # 0/1 contracts are absolute, so every planted value must fail
-    assert "qos_mixed_tenants_100rps.isolation_ok" in r.stdout
-    assert "qos_mixed_tenants_100rps.fairness_index" in r.stdout
-    assert "qos_mixed_tenants_100rps.hostile_alert_tripped" in r.stdout
-    assert "qos_mixed_tenants_100rps.compliant_clean" in r.stdout
-    assert "qos_mixed_tenants_100rps.token_identity" in r.stdout
-    assert "qos_mixed_tenants_100rps.sigkill.check_qos_ok" in r.stdout
-    assert "qos_mixed_tenants_100rps.sigkill.trace_ok" in r.stdout
-    assert "qos_mixed_tenants_100rps.sigkill_lost" in r.stdout
-    # unreadable input is exit 2, not a fake verdict
-    garbage = tmp_path / "garbage.json"
-    garbage.write_text("{broken")
-    assert _run("tools/check_bench.py", str(garbage)).returncode == 2
-    assert _run("tools/check_bench.py",
-                str(tmp_path / "missing.json")).returncode == 2
-    # a custom gate map overrides the defaults (and --json round-trips)
-    gates = tmp_path / "gates.json"
-    gates.write_text(json.dumps({
-        "fleet_x2_sigkill_100rps.fleet.lost":
-            {"direction": "lower", "tol": 0.0},
-    }))
-    r = _run("tools/check_bench.py", BENCH_BAD, "--baseline",
-             BENCH_BASELINE, "--gates", str(gates), "--json")
-    assert r.returncode == 1
-    rep = json.loads(r.stdout)
-    assert [row["status"] for row in rep["rows"]] == ["regression"]
-
-
-def test_check_bench_as_library():
-    from tools.check_bench import bench_verdict, dig
-
-    cur = json.load(open(os.path.join(ROOT, "BENCH_serve.json")))
-    base = json.load(open(BENCH_BASELINE))
-    ok, rows = bench_verdict(cur, base)
-    assert ok, [r for r in rows
-                if r["status"] not in ("ok", "skipped", "new")]
-    # a key absent from BOTH sides is SKIPPED; one measured in current
-    # with no baseline history is NEW (passes with a note — landing a
-    # new bench entry must not require hand-editing old baselines);
-    # one that vanished from current is a miss
-    ok, rows = bench_verdict(
-        cur, base, {"nonexistent.key": {"direction": "lower",
-                                        "tol": 0.1}})
-    assert ok and rows[0]["status"] == "skipped"
-    ok, rows = bench_verdict(
-        {"brand": {"new_metric": 1.23}}, base,
-        {"brand.new_metric": {"direction": "lower", "tol": 0.1}})
-    assert ok and rows[0]["status"] == "new" and "note" in rows[0]
-    ok, rows = bench_verdict(
-        {}, base, {"fleet_x2_overhead_8rps.latency_ratio_p50":
-                   {"direction": "lower", "tol": 0.1}})
-    assert not ok and rows[0]["status"] == "missing"
-    assert dig({"a": {"b": 3}}, "a.b") == 3
 
 
 def test_check_stream_exit_codes_both_ways(tmp_path):
@@ -673,8 +564,7 @@ QOS_FLEET_BAD = os.path.join(ROOT, "tests", "data",
                              "fleet_healthz_qos_bad.json")
 # the failure budget the artifact run was recorded against: 5x the
 # steady-state 0.5s TTFT target, because a mid-run worker SIGKILL
-# makes the steady-state budget unmeetable by ANY scheduler (see
-# serve/bench.py qos_bench)
+# makes the steady-state budget unmeetable by ANY scheduler
 QOS_SLO = json.dumps({"ttft_p99_s": 2.5, "fast_window_s": 0.5,
                       "slow_window_s": 1.0})
 

@@ -38,7 +38,7 @@ from ddp_practice_tpu.parallel.mesh import build_mesh, shard_state
 from ddp_practice_tpu.parallel.ring import set_current_mesh
 from ddp_practice_tpu.utils import backend
 
-# lm_base widths, the shape of the smoke and of bench.py's lm_long/lm_decode
+# lm_base widths, the shape of the smoke and of perf/'s gpt2s_train_2k cells
 B, S, H, D = 8, 2048, 12, 64
 HD = H * D
 BF16 = jnp.bfloat16
@@ -429,7 +429,7 @@ def test_lm_base_flash_step_compiles_on_the_mesh(topo, layout, pos_emb):
 @pytest.mark.parametrize("name", ["vit_tiny", "lm_tiny_fused"])
 def test_fused_train_step_compiles_on_one_chip(topo, name):
     """The WHOLE jitted step (optimizer, steps_per_call scan, donation)
-    around the fused encoder kernels, at bench.py's shapes: the 17 MB
+    around the fused encoder kernels, at the models' own shapes: the 17 MB
     scoped-VMEM window of ops/fused_encoder.py was found on an older
     compiler inside a real step, where the lone kernel fit and the step
     did not. vit_tiny takes the kernels by default (fused="auto" on a

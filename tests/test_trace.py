@@ -486,37 +486,6 @@ def test_crash_migrated_request_keeps_trace_id_on_survivor(lm):
     # tests/test_serve_router.py; here the TRACE is the contract
 
 
-@pytest.mark.chaos
-@pytest.mark.slow
-def test_serve_bench_chaos_trace_out_end_to_end(tmp_path):
-    """The CLI acceptance path (cli.py serve --replicas 2 --fault-plan
-    ... --trace-out): real-clock bench, injected crash, trace written to
-    disk, validator-clean, phase breakdown in the report."""
-    from ddp_practice_tpu.serve.bench import serve_bench
-    from ddp_practice_tpu.serve.faults import FaultPlan, FaultSpec
-
-    out = tmp_path / "t.json"
-    report = serve_bench(
-        n_requests=12, rate_hz=200.0, max_slots=4, max_new_range=(2, 12),
-        replicas=2, decode_burst=2,
-        fault_plan=FaultPlan([FaultSpec(kind="crash", tick=3,
-                                        replica=0, down_s=0.05)]),
-        trace_out=str(out),
-    )
-    assert report["trace_out"] == str(out)
-    trace = json.loads(out.read_text())
-    assert validate(trace) == []
-    router = report["router"]
-    # the phase breakdown rides the report next to ttft/tpot
-    for row in (report["continuous"], router):
-        assert set(row["phases"]) == {"queue_s", "prefill_s",
-                                      "decode_s", "stall_s"}
-        assert row["phases"]["decode_s"]["p99"] > 0
-    # the trace covers the ROUTER run: replica pids + router lane exist
-    pids = {e["pid"] for e in trace["traceEvents"]}
-    assert {0, 1, ROUTER_PID} <= pids
-
-
 @pytest.mark.slow
 def test_train_trace_out_records_step_phases(tmp_path):
     """`cli.py ... --trace-out`: the training driver's host-side phases

@@ -1,15 +1,20 @@
-"""Workload lab (serve/workload.py): spec validation, deterministic
-plan expansion, the add-a-tenant prefix-stability contract, arrival
-shaping (bursty/diurnal via Lewis thinning), multi-turn session prompt
-growth, and the JSON round-trip the bench/CLI seam rides. All host
-math — no engines, no clocks."""
+"""Synthetic traffic (serve/workload.py): the single-tenant trace
+builders, spec validation, deterministic plan expansion, the
+add-a-tenant prefix-stability contract, arrival shaping (bursty/diurnal
+via Lewis thinning), multi-turn session prompt growth, and the JSON
+round-trip. All host math — no engines, no clocks."""
 
 import json
 import math
 
 import pytest
 
-from ddp_practice_tpu.serve.workload import TenantSpec, WorkloadPlan
+from ddp_practice_tpu.serve.workload import (
+    TenantSpec,
+    WorkloadPlan,
+    build_shared_prefix_trace,
+    build_trace,
+)
 
 VOCAB = 32
 
@@ -190,3 +195,33 @@ def test_plan_from_json_path_and_error_shapes(tmp_path):
         WorkloadPlan.from_json("no/such/plan.json")
     with pytest.raises(TypeError):  # unknown keys are typos, not config
         WorkloadPlan.from_json('[{"name": "a", "rps": 3}]')
+
+
+# ------------------------------------------- single-tenant trace builders
+@pytest.mark.parametrize("build, kw, prompt_range", [
+    (build_trace,
+     dict(prompt_len_range=(2, 16), max_new_range=(4, 32)), (2, 16)),
+    (build_shared_prefix_trace,
+     dict(k_prefixes=3, prefix_len=12, tail_range=(1, 8),
+          max_new_range=(4, 32)), (13, 20)),
+], ids=["poisson", "shared-prefix"])
+def test_trace_builders_are_seeded_and_in_range(build, kw, prompt_range):
+    """What the router and affinity tests lean on: the same seed gives
+    the same trace (two arms replay identical load), another seed another
+    one, lengths and budgets stay inside their ranges, arrivals rise."""
+    def make(seed):
+        return build(n_requests=40, rate_hz=20.0, vocab=VOCAB, seed=seed,
+                     **kw)
+
+    trace = make(5)
+    assert trace == make(5) and trace != make(6)
+    assert [r["rid"] for r in trace] == list(range(40))
+    arrivals = [r["arrival"] for r in trace]
+    assert arrivals == sorted(arrivals) and arrivals[0] > 0.0
+    for r in trace:
+        assert prompt_range[0] <= len(r["prompt"]) <= prompt_range[1]
+        assert all(0 <= t < VOCAB for t in r["prompt"])
+        assert 4 <= r["max_new_tokens"] <= 32
+    if build is build_shared_prefix_trace:
+        # every prompt opens with one of the k system prompts
+        assert len({tuple(r["prompt"][:12]) for r in trace}) == 3

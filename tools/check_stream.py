@@ -30,7 +30,7 @@ serving, ``final`` marks the terminal). Per trace_id the checks are:
 exit 0 = every stream holds the contract; 1 = at least one violation;
 2 = input unreadable/malformed — a broken audit must be
 distinguishable from a broken stream (same convention as
-tools/check_bench.py / check_slo.py).
+tools/check_slo.py).
 
 ``--sse`` audits the OTHER side of the wire: a JSONL capture of SSE
 frames as a socket consumer actually parsed them (one line per frame:
