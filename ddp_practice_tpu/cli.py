@@ -94,8 +94,13 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--expert", type=int, default=1, help="expert-parallel degree")
     p.add_argument("--sp_impl", default="ring", choices=["ring", "ulysses"],
                    help="sequence-parallel attention scheme")
-    p.add_argument("--attn_impl", default="xla", choices=["xla", "flash"],
-                   help="local attention kernel (flash = Pallas tiled)")
+    p.add_argument("--attn_impl", default="auto",
+                   choices=["auto", "xla", "flash"],
+                   help="local attention kernel: xla = compiler-fused, "
+                        "flash = the Pallas streaming kernels (long "
+                        "sequences), auto = from the shape: the Pallas "
+                        "whole-sequence kernels for short sequences on a "
+                        "TPU, xla otherwise")
     p.add_argument("--microbatches", type=int, default=4,
                    help="GPipe microbatches per step (pipe > 1)")
     p.add_argument("--pipe_schedule", default="gpipe",

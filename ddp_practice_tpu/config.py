@@ -187,9 +187,13 @@ class TrainConfig:
     fsdp: bool = False
     # sequence-parallel attention scheme when mesh.seq > 1
     sp_impl: str = "ring"              # ring | ulysses
-    # local attention kernel: "xla" (compiler-fused) | "flash" (Pallas tiled
-    # kernel, ops/flash_attention.py) — composes with ring/ulysses
-    attn_impl: str = "xla"
+    # local attention kernel: "xla" (compiler-fused) | "flash" (the Pallas
+    # streaming kernels, ops/flash_attention.py) — both compose with
+    # ring/ulysses — | "auto": each attention layer picks from its shape
+    # at trace time (models/vit.py SelfAttention.resolve_attn_impl): the
+    # whole-sequence Pallas kernels for short unsharded sequences on a
+    # TPU (ViT's 196 patches), "xla" everywhere else
+    attn_impl: str = "auto"
     # GPipe microbatches per step when mesh.pipe > 1
     num_microbatches: int = 4
     # pipeline schedule: "gpipe" (autodiff-of-scan; activation memory grows

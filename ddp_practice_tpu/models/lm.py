@@ -42,7 +42,7 @@ class TransformerLM(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
     seq_axis: Optional[str] = None  # mesh axis for sequence parallelism
     sp_impl: str = "ring"
-    attn_impl: str = "xla"
+    attn_impl: str = "auto"         # models/vit.py SelfAttention.attn_impl
     # KV-cache storage dtype for decode: None (= compute dtype), a
     # jnp.dtype, or the string "int8" (quantized cache + scales); see
     # models/vit.py SelfAttention.kv_cache_dtype

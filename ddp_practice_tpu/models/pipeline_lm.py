@@ -116,7 +116,7 @@ class PipelinedLM:
         pos_emb: str = "learned",
         seq_axis: Optional[str] = None,
         sp_impl: str = "ring",
-        attn_impl: str = "xla",
+        attn_impl: str = "auto",
         schedule: str = "gpipe",
         # interleaved schedule only: layer chunks per device (virtual
         # pipeline stages, Megatron-style — parallel/interleave.py)

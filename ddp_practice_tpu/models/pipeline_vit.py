@@ -51,7 +51,7 @@ class PipelinedViT:
         remat: bool = True,
         seq_axis: Optional[str] = None,
         sp_impl: str = "ring",
-        attn_impl: str = "xla",
+        attn_impl: str = "auto",
         axis_name: Optional[str] = None,
     ):
         if depth % max(num_stages, 1) != 0:

@@ -15,11 +15,15 @@ ring path); compute dtype policy-driven (bf16), logits fp32.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional
 
 import flax.linen as nn
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ddp_practice_tpu.config import MeshConfig
 
 from ddp_practice_tpu.ops.attention import dot_product_attention
 from ddp_practice_tpu.ops.rope import apply_rope
@@ -92,13 +96,36 @@ class MlpBlock(nn.Module):
         return x
 
 
+# What SelfAttention resolved `attn_impl` to in the calls traced while
+# `resolved_attn_impls()` is open (None: nobody is asking).
+_RESOLVED: Optional[set] = None
+
+
+@contextlib.contextmanager
+def resolved_attn_impls():
+    """Collect, into the set this yields, what every SelfAttention traced
+    inside resolved its `attn_impl` to outside decode ("xla", "flash" or
+    "flash_short"). The choice is static, made once a trace from the
+    shape, so whoever traces a model once (the Trainer's abstract init)
+    knows which attention its programs run."""
+    global _RESOLVED
+    was, _RESOLVED = _RESOLVED, set()
+    try:
+        yield _RESOLVED
+    finally:
+        _RESOLVED = was
+
+
 class SelfAttention(nn.Module):
     num_heads: int
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
     seq_axis: Optional[str] = None  # mesh axis for sequence parallelism
     sp_impl: str = "ring"           # "ring" | "ulysses"
-    attn_impl: str = "xla"          # "xla" | "flash" (Pallas kernel)
+    # the attention core outside decode: "xla" (ops/attention.py
+    # _attention), "flash" (the streaming Pallas kernels), or "auto":
+    # chosen at trace time from the shape (resolve_attn_impl below)
+    attn_impl: str = "auto"
     causal: bool = False            # decoder (LM) blocks mask the future
     rope: bool = False              # rotary Q/K (ops/rope.py) vs none here
     # decode-mode KV-cache storage dtype. None = the compute dtype (bf16
@@ -138,6 +165,24 @@ class SelfAttention(nn.Module):
         kv = dense((2, kvh, head_dim), name="kv")(x)
         return q, kv[:, :, 0], kv[:, :, 1], None
 
+    def _project_flat(self, x):
+        """The fused `qkv` projection once more (same parameters, after
+        `_project` made them), as ONE matmul onto a flat (b, s, 3*h*hd)
+        output: the layout the short kernels window. Through DenseGeneral
+        the output is (b, s, 3, h, hd), which XLA lays out batch-minor
+        when s is not a multiple of 8 (ViT's 196) and then relays out
+        around the kernels, 115 MB a copy at ViT-B/16's shape; its result
+        is dead code here. No mesh axis may split the heads: merging a
+        sharded (3, h, hd) into one dim would gather them."""
+        from flax.linen.dtypes import promote_dtype
+
+        p = self.variables["params"]["qkv"]
+        kernel = p["kernel"].reshape(x.shape[-1], -1)
+        bias = p["bias"].reshape(-1) if self.use_bias else None
+        x, kernel, bias = promote_dtype(x, kernel, bias, dtype=self.dtype)
+        y = x @ kernel
+        return y if bias is None else y + bias
+
     def _widen_kv(self, k, v):
         """K and V repeated to one head a query head (the dense attention
         paths; the paged walk kernel reads the grouped cache as it is)."""
@@ -146,6 +191,48 @@ class SelfAttention(nn.Module):
             return k, v
         return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
 
+    @staticmethod
+    def _tensor_parallel() -> int:
+        """Devices the registered mesh splits the heads over."""
+        from ddp_practice_tpu.parallel.ring import get_current_mesh
+
+        mesh = get_current_mesh()
+        return 1 if mesh is None else mesh.shape.get(
+            MeshConfig.AXIS_TENSOR, 1)
+
+    def resolve_attn_impl(self, seq: int, head_dim: int, *,
+                          decode: bool = False) -> str:
+        """What `attn_impl` means for a call of this shape: "xla", "flash",
+        or "flash_short" (ops/flash_attention.py's whole-sequence kernels).
+        "xla" and "flash" are taken at their word. "auto" takes the short
+        kernels where they run and the chip says they win: no decode, no
+        rope, no sequence parallelism, the fused `qkv` projection (no
+        grouped K/V heads), heads that pack into 128
+        lanes on every device of the mesh, a sequence inside
+        [SHORT_SEQ_MIN, SHORT_SEQ_MAX], compiled TPU execution (off the TPU
+        a kernel nobody asked for would be interpreted); `_attention`
+        everywhere else. No model is named: the shape decides."""
+        if self.attn_impl != "auto":
+            return self.attn_impl
+        if (decode or self.rope or self.seq_axis is not None
+                or (self.kv_heads or self.num_heads) != self.num_heads):
+            return "xla"
+        from ddp_practice_tpu.utils import backend
+
+        if not backend.on_tpu():
+            return "xla"
+        from ddp_practice_tpu.ops.flash_attention import (
+            SHORT_SEQ_MIN,
+            short_seq_supported,
+        )
+
+        tensor = self._tensor_parallel()
+        if (self.num_heads % tensor or seq < SHORT_SEQ_MIN
+                or not short_seq_supported(
+                    seq, self.num_heads // tensor, head_dim)):
+            return "xla"
+        return "flash_short"
+
     @nn.compact
     def __call__(self, x, *, decode: bool = False, attn_start=None,
                  page_table=None, kv_lengths=None):
@@ -153,8 +240,11 @@ class SelfAttention(nn.Module):
         assert d % self.num_heads == 0, (d, self.num_heads)
         head_dim = d // self.num_heads
         q, k, v, qkv = self._project(x, head_dim)
+        impl = self.resolve_attn_impl(s, head_dim, decode=decode)
+        if _RESOLVED is not None and not decode:
+            _RESOLVED.add(impl)
         if (
-            self.attn_impl == "flash"
+            impl in ("flash", "flash_short")
             and not decode
             and not self.rope
             and self.seq_axis is None
@@ -173,18 +263,30 @@ class SelfAttention(nn.Module):
             # the (3, h, hd) dims, heads over 'tensor', and each device
             # flattens its OWN heads — sharding the flat 3*h*hd dim
             # would hand a device q heads without their k and v.
-            from ddp_practice_tpu.ops.flash_attention import (
-                flash_attention_qkv,
-            )
+            from ddp_practice_tpu.ops import flash_attention as fa
             from ddp_practice_tpu.parallel.ring import (
                 BSHD_SPEC,
                 QKV_SPEC,
                 kernel_island,
             )
 
+            if impl == "flash_short" and self._tensor_parallel() == 1:
+                # whole heads on every device: the flat projection, its
+                # batch split over 'data' alone
+                out = kernel_island(
+                    functools.partial(fa.flash_short_qkv,
+                                      n_heads=self.num_heads,
+                                      causal=self.causal),
+                    in_specs=(P(MeshConfig.AXIS_DATA),),
+                    out_specs=BSHD_SPEC,
+                )(self._project_flat(x))
+                return self._out_proj(out)
+            kernel = (fa.flash_short_qkv if impl == "flash_short"
+                      else fa.flash_attention_qkv)
+
             def local_qkv(x):
                 lb, ls, _, lh, lhd = x.shape
-                return flash_attention_qkv(
+                return kernel(
                     x.reshape(lb, ls, 3 * lh * lhd), lh, causal=self.causal
                 )
 
@@ -367,7 +469,7 @@ class SelfAttention(nn.Module):
             out = dot_product_attention(
                 q, *self._widen_kv(k, v), causal=self.causal,
                 seq_axis=self.seq_axis,
-                sp_impl=self.sp_impl, impl=self.attn_impl,
+                sp_impl=self.sp_impl, impl=impl,
             )
         return self._out_proj(out)
 
@@ -547,7 +649,7 @@ class EncoderBlock(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
     seq_axis: Optional[str] = None
     sp_impl: str = "ring"
-    attn_impl: str = "xla"
+    attn_impl: str = "auto"         # see SelfAttention.attn_impl
     causal: bool = False
     rope: bool = False
     # pass-through to SelfAttention: None | jnp.dtype | "int8"
@@ -646,7 +748,7 @@ class EncoderBlock(nn.Module):
         return not (
             decode or self.rope or self.seq_axis is not None
             or self.use_moe or self.dropout_rate > 0.0
-            or self.attn_impl != "xla"
+            or self.attn_impl not in ("xla", "auto")
         )
 
     def _auto_fuse(self, x, decode) -> bool:
@@ -731,7 +833,7 @@ class ViT(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
     seq_axis: Optional[str] = None
     sp_impl: str = "ring"
-    attn_impl: str = "xla"
+    attn_impl: str = "auto"         # see SelfAttention.attn_impl
     dropout_rate: float = 0.0       # residual-branch dropout in every block
     # one-Pallas-kernel layers (small-d fix); "auto" picks them whenever
     # the EncoderBlock's constraints hold (see EncoderBlock.fused)

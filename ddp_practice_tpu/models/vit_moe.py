@@ -35,7 +35,7 @@ class ViTMoE(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
     seq_axis: Optional[str] = None
     sp_impl: str = "ring"
-    attn_impl: str = "xla"
+    attn_impl: str = "auto"
     axis_name: Optional[str] = None  # registry uniformity (no BN)
 
     @nn.compact

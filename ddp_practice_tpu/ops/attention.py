@@ -5,7 +5,13 @@ origin_main.py:9-31); this implements the transformer path of the model
 ladder. Two execution paths:
 
 - fused single-device/GSPMD path: plain jnp softmax attention, fp32
-  accumulation, fused by XLA onto the MXU.
+  accumulation, fused by XLA onto the MXU (`_attention`). It writes the
+  (b, h, s, s) scores to HBM, so the models do not take it everywhere:
+  `attn_impl="auto"` (models/vit.py SelfAttention.resolve_attn_impl)
+  hands short unsharded sequences on a TPU to the whole-sequence Pallas
+  kernels (ops/flash_attention.py flash_short_qkv) before they reach
+  this module, and resolves to "xla" (this path) everywhere else;
+  "flash" names the streaming kernels for long sequences.
 - sequence-parallel path: `parallel.ring.ring_attention` — blockwise
   attention with online softmax, K/V blocks rotated around the 'seq' mesh
   axis with `lax.ppermute` (ring attention; long-context first-class).

@@ -1041,6 +1041,357 @@ def flash_attention_qkv(
 
 
 # --------------------------------------------------------------------- #
+# Whole-sequence kernels for short sequences (ViT: 196 patches).
+#
+# The streaming kernels above exist so that a sequence need not fit VMEM.
+# Where it does (a few hundred tokens), a grid cell can hold ALL the keys
+# of a head, and attention is plain again: one score tile, one row max,
+# one exp, one row sum, one normalisation of the (s, d) output; no running
+# m / l, no rescale, no scratch carried between grid steps. The backward
+# is ONE kernel of five dots (s, dp, dv, dk, dq): nothing accumulates
+# across cells, so s and dp are not recomputed for a second kernel.
+#
+# A grid cell is G images x one 128-lane head pack, the whole sequence of
+# each, windowed out of the flat (b, s, 3*h*d) projection like the packed
+# kernels' fused_qkv input. The block's sequence dim IS the array's, so
+# nothing is padded in HBM; the tiles are the logical (s, s) and (s, d),
+# and what lies between 196 and the lane tile's 256 exists in vregs
+# only, where Mosaic masks it (padding the keys to 256 by hand, with a
+# penalty on the tail, read 3% slower on the chip: PERF.md section 6,
+# PR 29). The body is unrolled over the cell's images and the pack's
+# heads: one straight-line block (PERF.md section 6, PR 27: Mosaic
+# overlaps MXU and VPU work inside a basic block only). The backward
+# writes dq, dk, dv into ONE flat (b, s, 3*h*d) cotangent: a third grid
+# axis of 3 steps visits the q, k and v column blocks of the pack; step 0
+# computes all three and keeps dk, dv in VMEM, steps 1 and 2 copy them
+# out (no concatenate in HBM).
+# --------------------------------------------------------------------- #
+
+# The sequence lengths for which these kernels are chosen where nobody
+# names a kernel (models/vit.py SelfAttention, attn_impl="auto"). Upper
+# end: the longest sequence one cell holds; at 1280 the backward's score
+# tiles (four (s, s) float32 live at once) ask for 17.86 MB of the 16 MB
+# of scoped VMEM (tests/test_tpu_compile.py compiles the end). Lower end:
+# the shortest length measured; XLA's own attention lost at every length
+# from there up (PERF.md section 6, PR 29).
+SHORT_SEQ_MIN = 64
+SHORT_SEQ_MAX = 1024
+
+# Images a grid cell: enough that a cell's dots outweigh a grid step's
+# fixed cost (~0.35 us), few enough that the unrolled body and the cell's
+# double-buffered blocks stay small. On the chip 4 and 8 images read the
+# same at ViT-B/16's shape, 2 and 16 slower, and 6 (a ragged last cell of
+# 128 images) slower than either (PERF.md section 6, PR 29).
+_SHORT_CELL_FLOPS = 5e8
+_SHORT_CELL_BYTES = 10 * 2**20
+_SHORT_MAX_IMAGES = 16
+
+
+def short_seq_supported(seq: int, n_heads: int, head_dim: int) -> bool:
+    """Can the whole-sequence kernels run this self-attention shape?
+    (Heads that pack into 128 lanes, a sequence one cell holds.)"""
+    return (_heads_per_pack(n_heads, head_dim) is not None
+            and 1 <= seq <= SHORT_SEQ_MAX)
+
+
+def _images_per_cell(b: int, seq: int, w: int) -> int:
+    """G: images a grid cell of the short kernels holds (see above): the
+    fewest that make a cell's work, capped by VMEM, and the largest
+    divisor of the batch at or under that where it is at least half of it
+    (no ragged last cell)."""
+    rows = -(-seq // 16) * 16
+    # the backward's blocks: q, k, v, do in and one cotangent out, each
+    # two deep, and the two kept cotangents
+    cell_bytes = 12 * rows * w * 2
+    # forward + backward: seven dots of 2 * s * s * w
+    cell_flops = 7 * 2.0 * seq * seq * w
+    g = max(int(min(_SHORT_CELL_BYTES // cell_bytes,
+                    -(-_SHORT_CELL_FLOPS // cell_flops),
+                    _SHORT_MAX_IMAGES, b)), 1)
+    whole = max(n for n in range(1, g + 1) if b % n == 0)
+    return whole if 2 * whole >= g else g
+
+
+def _short_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, g,
+                      hpc, d, causal):
+    """Grid cell (image group, head pack): plain softmax attention of g
+    images x hpc heads, each over its whole sequence. lse_ref: (seq,
+    g * hpc), one column an (image, head)."""
+    seq = q_ref.shape[1]
+    pen = _tile_penalty(0, seq, seq) if causal else None
+    for i in range(g):
+        for lanes, hh in _packed_heads(hpc, d):
+            q = (q_ref[i, :, lanes] * sm_scale).astype(q_ref.dtype)
+            s = _mask_tail(_dot_tb(q, k_ref[i, :, lanes]), pen)
+            m = jnp.max(s, axis=1, keepdims=True)
+            e = jnp.exp(s - m)
+            l = jnp.sum(e, axis=1, keepdims=True)
+            v = v_ref[i, :, lanes]
+            o = lax.dot_general(
+                e.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            o_ref[i, :, lanes] = (o * (1.0 / l)).astype(o_ref.dtype)
+            col = i * hpc + hh
+            lse_ref[:, col:col + 1] = m + jnp.log(l)
+
+
+def _short_bwd_tiles(q_ref, k_ref, v_ref, do_ref, lse_ref, *, sm_scale, g,
+                     hpc, d, causal):
+    """The backward of one cell, as a generator over (image, head):
+    (i, lanes, dq, dk, dv) in float32. Five dots an (image, head).
+
+    delta is rowsum(p * dp) over the score tile that is here anyway, not
+    the streaming kernels' rowsum(do * out): with the float32 p on both
+    sides a row of ds sums to zero as it does under autodiff of
+    _attention, where out's bf16 rounding leaves every row a residue that
+    lands, times q, in the K bias's gradient (zero in exact arithmetic;
+    AdamW steps it at full size whatever its norm: PERF.md section 6,
+    PR 29). It also spares the backward the out block."""
+    seq = q_ref.shape[1]
+    pen = _tile_penalty(0, seq, seq) if causal else None
+    for i in range(g):
+        for lanes, hh in _packed_heads(hpc, d):
+            qs = (q_ref[i, :, lanes] * sm_scale).astype(q_ref.dtype)
+            k = k_ref[i, :, lanes]
+            do = do_ref[i, :, lanes]
+            s = _mask_tail(_dot_tb(qs, k), pen)
+            col = i * hpc + hh
+            p = jnp.exp(s - lse_ref[:, col:col + 1])
+            pdp = p * _dot_tb(do, v_ref[i, :, lanes])       # p * (do @ v^T)
+            delta = jnp.sum(pdp, axis=1, keepdims=True)
+            ds = (pdp - p * delta).astype(qs.dtype)
+            dv = _dot_ta(p.astype(do.dtype), do)            # p^T @ do
+            dk = _dot_ta(ds, qs)                            # ds^T @ qs
+            dq = lax.dot_general(                           # ds @ k
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            yield i, lanes, dq, dk, dv
+
+
+def _short_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dq_ref, dk_ref,
+                      dv_ref, **kw):
+    """Sliced inputs: grid cell (image group, head pack), three outputs."""
+    for i, lanes, dq, dk, dv in _short_bwd_tiles(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, **kw):
+        dq_ref[i, :, lanes] = dq.astype(dq_ref.dtype)
+        dk_ref[i, :, lanes] = dk.astype(dk_ref.dtype)
+        dv_ref[i, :, lanes] = dv.astype(dv_ref.dtype)
+
+
+def _short_bwd_kernel_qkv(q_ref, k_ref, v_ref, do_ref, lse_ref, dqkv_ref,
+                          dk_scr, dv_scr, **kw):
+    """Fused input: grid cell (image group, head pack, t); dqkv_ref is the
+    pack's q column block of the flat cotangent at t == 0, its k block at
+    1, its v block at 2. The inputs' block indices do not depend on t, so
+    they are fetched once a cell."""
+    t = pl.program_id(2)
+
+    @pl.when(t == 0)
+    def _compute():
+        for i, lanes, dq, dk, dv in _short_bwd_tiles(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, **kw):
+            dqkv_ref[i, :, lanes] = dq.astype(dqkv_ref.dtype)
+            dk_scr[i, :, lanes] = dk.astype(dk_scr.dtype)
+            dv_scr[i, :, lanes] = dv.astype(dv_scr.dtype)
+
+    @pl.when(t == 1)
+    def _dk():
+        dqkv_ref[:] = dk_scr[:]
+
+    @pl.when(t == 2)
+    def _dv():
+        dqkv_ref[:] = dv_scr[:]
+
+
+def _short_dims(qf, n_heads, fused_qkv):
+    """(b, seq, hd, d, hpc, w, n_packs, koff, voff) of a short-kernel call."""
+    b, seq, hd = qf.shape
+    if fused_qkv:
+        hd //= 3
+    d = hd // n_heads
+    hpc = _heads_per_pack(n_heads, d)
+    w = hpc * d
+    n_packs = n_heads // hpc
+    off = n_packs if fused_qkv else 0
+    return b, seq, hd, d, hpc, w, n_packs, off, 2 * off
+
+
+_SHORT_STATICS = ("n_heads", "causal", "g", "interpret", "fused_qkv")
+
+
+@functools.partial(jax.jit, static_argnames=_SHORT_STATICS)
+def _flash_short_fwd(qf, kf, vf, *, n_heads, causal, g, interpret,
+                     fused_qkv=False):
+    """qf/kf/vf: flat (b, s, h*d), or with fused_qkv all the SAME
+    (b, s, 3*h*d) projection output, windowed at column-block offsets as
+    in _flash_fwd_packed. Returns (out (b, s, h*d), lse (n_cells, n_packs,
+    s, g * hpc) float32: one column an (image of the cell, head of the
+    pack)). A batch that g does not divide leaves the last cell ragged:
+    its missing images are never fetched or written."""
+    b, seq, hd, d, hpc, w, n_packs, koff, voff = _short_dims(
+        qf, n_heads, fused_qkv)
+    n_cells = pl.cdiv(b, g)
+    kernel = functools.partial(
+        _short_fwd_kernel, sm_scale=1.0 / (d ** 0.5), g=g, hpc=hpc, d=d,
+        causal=causal)
+    block = (g, seq, w)
+    return pl.pallas_call(
+        kernel,
+        name="flash_short_fwd",
+        grid=(n_cells, n_packs),
+        in_specs=[
+            pl.BlockSpec(block, lambda c, p: (c, 0, p)),
+            pl.BlockSpec(block, lambda c, p: (c, 0, p + koff)),
+            pl.BlockSpec(block, lambda c, p: (c, 0, p + voff)),
+        ],
+        out_specs=[
+            pl.BlockSpec(block, lambda c, p: (c, 0, p)),
+            pl.BlockSpec((None, None, seq, g * hpc),
+                         lambda c, p: (c, p, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, seq, hd), qf.dtype),
+            jax.ShapeDtypeStruct((n_cells, n_packs, seq, g * hpc),
+                                 jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(qf, kf, vf)
+
+
+@functools.partial(jax.jit, static_argnames=_SHORT_STATICS)
+def _flash_short_bwd(qf, kf, vf, do, lse, *, n_heads, causal, g, interpret,
+                     fused_qkv=False):
+    """Gradients of _flash_short_fwd's out: (dq, dk, dv), each (b, s,
+    h*d), or with fused_qkv ONE (b, s, 3*h*d) cotangent in the
+    projection's own column order."""
+    b, seq, hd, d, hpc, w, n_packs, koff, voff = _short_dims(
+        qf, n_heads, fused_qkv)
+    n_cells = pl.cdiv(b, g)
+    kw = dict(sm_scale=1.0 / (d ** 0.5), g=g, hpc=hpc, d=d, causal=causal)
+    block = (g, seq, w)
+
+    def at(off):
+        return pl.BlockSpec(block, lambda c, p, *t: (c, 0, p + off))
+
+    in_specs = [
+        at(0), at(koff), at(voff), at(0),
+        pl.BlockSpec((None, None, seq, g * hpc),
+                     lambda c, p, *t: (c, p, 0, 0)),
+    ]
+    if not fused_qkv:
+        return pl.pallas_call(
+            functools.partial(_short_bwd_kernel, **kw),
+            name="flash_short_bwd",
+            grid=(n_cells, n_packs),
+            in_specs=in_specs,
+            out_specs=[at(0)] * 3,
+            out_shape=[jax.ShapeDtypeStruct((b, seq, hd), qf.dtype)] * 3,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
+            interpret=interpret,
+        )(qf, kf, vf, do, lse)
+    return pl.pallas_call(
+        functools.partial(_short_bwd_kernel_qkv, **kw),
+        name="flash_short_bwd",
+        grid=(n_cells, n_packs, 3),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec(
+            block, lambda c, p, t: (c, 0, p + t * n_packs)),
+        out_shape=jax.ShapeDtypeStruct(qf.shape, qf.dtype),
+        scratch_shapes=[pltpu.VMEM(block, qf.dtype)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(qf, kf, vf, do, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_short(qf, kf, vf, n_heads, causal, g):
+    return _flash_short_fwd(qf, kf, vf, n_heads=n_heads, causal=causal, g=g,
+                            interpret=_interpret())[0]
+
+
+def _flash_short_vjp_fwd(qf, kf, vf, n_heads, causal, g):
+    out, lse = _flash_short_fwd(qf, kf, vf, n_heads=n_heads, causal=causal,
+                                g=g, interpret=_interpret())
+    return out, (qf, kf, vf, lse)
+
+
+def _flash_short_vjp_bwd(n_heads, causal, g, res, g_out):
+    qf, kf, vf, lse = res
+    return tuple(_flash_short_bwd(
+        qf, kf, vf, g_out.astype(qf.dtype), lse, n_heads=n_heads,
+        causal=causal, g=g, interpret=_interpret()))
+
+
+_flash_short.defvjp(_flash_short_vjp_fwd, _flash_short_vjp_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _flash_short_qkv(qkvf, n_heads, causal, g):
+    return _flash_short_fwd(qkvf, qkvf, qkvf, n_heads=n_heads, causal=causal,
+                            g=g, interpret=_interpret(), fused_qkv=True)[0]
+
+
+def _flash_short_qkv_vjp_fwd(qkvf, n_heads, causal, g):
+    out, lse = _flash_short_fwd(
+        qkvf, qkvf, qkvf, n_heads=n_heads, causal=causal, g=g,
+        interpret=_interpret(), fused_qkv=True)
+    return out, (qkvf, lse)
+
+
+def _flash_short_qkv_vjp_bwd(n_heads, causal, g, res, g_out):
+    qkvf, lse = res
+    return (_flash_short_bwd(
+        qkvf, qkvf, qkvf, g_out.astype(qkvf.dtype), lse,
+        n_heads=n_heads, causal=causal, g=g, interpret=_interpret(),
+        fused_qkv=True),)
+
+
+_flash_short_qkv.defvjp(_flash_short_qkv_vjp_fwd, _flash_short_qkv_vjp_bwd)
+
+
+def _check_short(seq, n_heads, d):
+    if not short_seq_supported(seq, n_heads, d):
+        raise ValueError(
+            f"the whole-sequence kernels take heads that pack into 128 "
+            f"lanes and a sequence of at most {SHORT_SEQ_MAX}; got seq "
+            f"{seq}, {n_heads} heads of {d}")
+
+
+def flash_short_qkv(qkv, n_heads: int, *, causal: bool = False,
+                    images_per_cell=None) -> jnp.ndarray:
+    """Whole-sequence self-attention straight off the flat (b, s, 3*h*d)
+    QKV projection (column order [q heads | k heads | v heads], as
+    flash_attention_qkv takes it). Returns (b, s, h, d). Shapes must pass
+    short_seq_supported; `images_per_cell` overrides G (tests, timing)."""
+    b, s, three_hd = qkv.shape
+    d = three_hd // 3 // n_heads
+    _check_short(s, n_heads, d)
+    g = images_per_cell or _images_per_cell(
+        b, s, _heads_per_pack(n_heads, d) * d)
+    return _flash_short_qkv(qkv, n_heads, causal, g).reshape(
+        b, s, n_heads, d)
+
+
+def flash_short(q, k, v, *, causal: bool = False,
+                images_per_cell=None) -> jnp.ndarray:
+    """The same over sliced (b, s, h, d) q, k, v of one length."""
+    b, s, h, d = q.shape
+    _check_short(s, h, d)
+    if k.shape != q.shape or v.shape != q.shape:
+        raise ValueError(
+            f"flash_short is self-attention over one length: q {q.shape}, "
+            f"k {k.shape}, v {v.shape}")
+    g = images_per_cell or _images_per_cell(b, s, _heads_per_pack(h, d) * d)
+    flat = lambda x: x.reshape(b, s, h * d)
+    return _flash_short(flat(q), flat(k), flat(v), h, causal, g).reshape(
+        b, s, h, d)
+
+
+# --------------------------------------------------------------------- #
 # Differentiable entry points.
 # _flash_lse returns (out, lse), both differentiable; the lse cotangent
 # folds into delta (see module docstring). flash_attention drops lse.

@@ -139,9 +139,10 @@ class Trainer:
         if self.sp > 1:
             model_kwargs["seq_axis"] = MeshConfig.AXIS_SEQ
             model_kwargs["sp_impl"] = config.sp_impl
-        if config.attn_impl != "xla":
+        if config.attn_impl != "auto":
             # only attention models accept this; a conv model raises loudly
-            # rather than silently ignoring the requested kernel
+            # rather than silently ignoring the requested kernel ("auto"
+            # asks for none: the attention models resolve it themselves)
             model_kwargs["attn_impl"] = config.attn_impl
         fused_req = config.fused_encoder
         from ddp_practice_tpu.models import accepts_fused
@@ -296,7 +297,14 @@ class Trainer:
         def init_fn(r):
             return create_state(self.model, self.tx, rng=r, sample_input=sample)
 
-        abstract = jax.eval_shape(init_fn, rng)
+        from ddp_practice_tpu.models.vit import resolved_attn_impls
+
+        with resolved_attn_impls() as resolved:
+            abstract = jax.eval_shape(init_fn, rng)
+        # which attention core the step programs run, as SelfAttention
+        # resolved it for this shape and mesh (None: a model without one);
+        # an attribute of every train_epoch span
+        self.attn_impl = "+".join(sorted(resolved)) or None
         rules = param_sharding_rules(config.model)
         if config.fsdp:
             from ddp_practice_tpu.parallel.fsdp import fsdp_rules
@@ -1064,7 +1072,8 @@ class Trainer:
         watchdog, log readback — its `block` child is the readback),
         then the closing `block` fence: host time outside every child
         is the span's self time."""
-        with self._tspan("train_epoch", epoch=epoch):
+        attrs = {"attn_impl": self.attn_impl} if self.attn_impl else {}
+        with self._tspan("train_epoch", epoch=epoch, **attrs):
             if self.resident_train_step is not None:
                 return self._train_epoch_resident(epoch)
             return self._train_epoch_host(epoch)

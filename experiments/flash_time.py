@@ -1,7 +1,18 @@
-"""Time the restructured flash kernels (fwd, fwd+bwd) vs the bundled jax
-TPU kernel at lm_base shapes. Slope-fit over K in {16, 64} chained scans,
-min of 5 reps, scalar-readback fenced."""
+"""Time the flash kernels on the chip. Slope-fit over K in {16, 64} chained
+scans, min of 5 reps, scalar-readback fenced.
+
+    python experiments/flash_time.py            # lm_base shapes: the streaming
+                                                # kernels vs the bundled jax one
+    python experiments/flash_time.py vit [impl:BxS[:fwd][:causal] ...]
+                                                # ViT-B/16's attention, one
+        # layer, forward + backward off the flat qkv projection: XLA's
+        # _attention, the streaming kernels, the whole-sequence kernels over
+        # G, and the sweep over sequence length that set SHORT_SEQ_MIN
+        # (PERF.md section 6, PR 29)
+"""
 import functools
+import json
+import os
 import sys
 import time
 
@@ -35,6 +46,115 @@ def timed(fn, args, K1=16, K2=64):
             ts.append(time.perf_counter() - t0)
         best.append(min(ts))
     return (best[1] - best[0]) / (K2 - K1) * 1e3
+
+
+def timed1(fn, x):
+    """`timed` for a chain over ONE array (qkv -> d loss / d qkv)."""
+    return timed(lambda c, _k, _v: fn(c), (x, x[:1], x[:1]))
+
+
+def vit(only=(), out="chiprun_out/flash_time_vit.json"):
+    """ViT-B/16's attention core a layer (b 128, s 196, 12 heads of 64,
+    bf16), gradient with respect to the flat (b, s, 3*h*d) projection."""
+    from ddp_practice_tpu.ops import flash_attention as fa
+    from ddp_practice_tpu.ops.attention import _attention
+
+    h, d = 12, 64
+
+    def xla(qkv, causal):
+        b, s, _ = qkv.shape
+        x = qkv.reshape(b, s, 3, h, d)
+        return _attention(x[:, :, 0], x[:, :, 1], x[:, :, 2], causal=causal)
+
+    impls = {
+        "xla": xla,
+        "streaming": lambda qkv, causal: fa.flash_attention_qkv(
+            qkv, h, causal=causal),
+        "short": lambda qkv, causal: fa.flash_short_qkv(
+            qkv, h, causal=causal),
+    }
+    def sliced(qkv, causal):
+        b, s, _ = qkv.shape
+        x = qkv.reshape(b, s, 3, h, d)
+        return fa.flash_short(x[:, :, 0], x[:, :, 1], x[:, :, 2],
+                              causal=causal)
+
+    impls["short_sliced"] = sliced
+    for g in (2, 4, 8, 16):
+        impls[f"short_g{g}"] = functools.partial(
+            lambda qkv, causal, g: fa.flash_short_qkv(
+                qkv, h, causal=causal, images_per_cell=g), g=g)
+
+    def case(name, b, s, causal=False, grad=True):
+        qkv = jax.random.normal(jax.random.PRNGKey(0), (b, s, 3 * h * d),
+                                jnp.bfloat16)
+        fwd = lambda x: impls[name](x, causal)
+        if grad:
+            # cotangent of the out projection's shape and dtype
+            w = jax.random.normal(jax.random.PRNGKey(1), (b, s, h, d),
+                                  jnp.bfloat16)
+            fn = jax.grad(lambda x: (fwd(x) * w).astype(jnp.float32).sum())
+        else:
+            # the chain's carry stays the projection: one element of the
+            # output is written into it in place
+            fn = lambda x: lax.dynamic_update_slice(
+                x, fwd(x)[:1, :1, 0, :1].astype(x.dtype), (0, 0, 0))
+        ms = timed1(fn, qkv)
+        useful = (7 if grad else 2) * 2.0 * b * h * s * s * d * (
+            0.5 if causal else 1.0)
+        row = {"impl": name, "b": b, "s": s, "causal": causal,
+               "what": "fwd+bwd" if grad else "fwd", "ms": round(ms, 4),
+               "useful_tflops": round(useful / ms / 1e9, 2)}
+        print(json.dumps(row), flush=True)
+        return row
+
+    def errors(b=16, s=196):
+        """Output and gradient of each bf16 path against float32 XLA."""
+        k0, k1 = jax.random.split(jax.random.PRNGKey(2))
+        qkv = jax.random.normal(k0, (b, s, 3 * h * d), jnp.float32)
+        w = jax.random.normal(k1, (b, s, h, d), jnp.float32)
+        qkv = qkv.astype(jnp.bfloat16).astype(jnp.float32)
+        w = w.astype(jnp.bfloat16).astype(jnp.float32)
+
+        def both(name, dtype):
+            f = lambda x: impls[name](x, False).astype(jnp.float32)
+            out, vjp = jax.vjp(f, qkv.astype(dtype))
+            return out, vjp(w)[0].astype(jnp.float32)
+
+        o32, g32 = jax.jit(functools.partial(both, "xla", jnp.float32))()
+        rel = lambda a, r: float(jnp.linalg.norm(a - r) / jnp.linalg.norm(r))
+        for name in ("xla", "streaming", "short"):
+            o, g = jax.jit(functools.partial(both, name, jnp.bfloat16))()
+            row = {"impl": name, "what": "rel. l2 error vs float32 xla",
+                   "out": rel(o, o32), "dqkv": rel(g, g32)}
+            print(json.dumps(row), flush=True)
+            rows.append(row)
+
+    rows = []
+    if only:
+        # e.g. `vit short_g4:128x196 short:392x64:fwd`
+        for spec in only:
+            name, shape, *what = spec.split(":")
+            b, s = map(int, shape.split("x"))
+            rows.append(case(name, b, s, causal="causal" in what,
+                             grad="fwd" not in what))
+        return rows
+    errors()
+    # step 1 of ISSUE 29: the cell's shape
+    for name in ("xla", "streaming", "short_g2", "short_g4", "short_g8",
+                 "short_g16", "short", "short_sliced"):
+        rows.append(case(name, 128, 196))
+    for name in ("xla", "short_g8"):
+        rows.append(case(name, 128, 196, grad=False))
+    # the range: the same ~25k tokens a step at other lengths
+    for b, s in ((392, 64), (196, 128), (98, 256), (64, 384), (44, 576)):
+        for name in ("xla", "short"):
+            rows.append(case(name, b, s))
+    for name in ("xla", "streaming", "short"):
+        rows.append(case(name, 98, 256, causal=True))
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    with open(out, "w") as f:
+        json.dump(rows, f, indent=1)
 
 
 def main():
@@ -91,4 +211,4 @@ def main():
 
 
 if __name__ == "__main__":
-    main()
+    vit(sys.argv[2:]) if sys.argv[1:2] == ["vit"] else main()

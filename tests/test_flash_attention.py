@@ -439,3 +439,140 @@ def test_causal_subtiles_match_dense(path, shape):
     for a, b_ in zip(got, want):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b_), rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------
+# The whole-sequence kernels for short sequences (PR 29): a grid cell is
+# G images x one 128-lane head pack, plain softmax, ONE backward kernel.
+# ---------------------------------------------------------------------
+
+# (batch, seq, heads, head_dim, images a cell (None: from the shape),
+#  fused qkv input?)
+SHORT_CASES = [
+    (2, 64, 2, 64, 2, True),
+    (3, 196, 2, 64, 2, True),      # G does not divide the batch
+    (3, 196, 12, 64, 2, False),
+    (2, 197, 2, 64, 1, False),
+    (2, 256, 12, 64, None, True),
+    (4, 384, 2, 64, None, False),
+    (3, 196, 1, 128, 2, True),     # one head a pack
+]
+
+
+def _short_vs_dense(b, s, h, d, g, fused, causal, dtype, seed=0):
+    """((out, dq, dk, dv) of the short kernels, the same of _attention in
+    float32), from one set of inputs rounded to `dtype`."""
+    from ddp_practice_tpu.ops.flash_attention import (
+        flash_short,
+        flash_short_qkv,
+    )
+
+    rng = np.random.default_rng(seed)
+    qkv = jnp.asarray(rng.normal(size=(b, s, 3, h, d)), dtype)
+    w = jnp.asarray(rng.normal(size=(b, s, h, d)), dtype)
+
+    def split(x):
+        return x[:, :, 0], x[:, :, 1], x[:, :, 2]
+
+    def short(x):
+        if fused:
+            out = flash_short_qkv(x.reshape(b, s, 3 * h * d), h,
+                                  causal=causal, images_per_cell=g)
+        else:
+            out = flash_short(*split(x), causal=causal, images_per_cell=g)
+        assert out.dtype == dtype and out.shape == (b, s, h, d)
+        return out.astype(jnp.float32)
+
+    def dense(x):
+        return _attention(*split(x), causal=causal)
+
+    def with_grads(fn, x):
+        out, vjp = jax.vjp(fn, x)
+        return (out,) + split(vjp(w.astype(out.dtype))[0].astype(jnp.float32))
+
+    return (with_grads(short, qkv),
+            with_grads(dense, qkv.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", SHORT_CASES, ids=lambda c: "b{}s{}h{}d{}g{}{}".format(
+    *c[:5], "fused" if c[5] else "sliced"))
+def test_short_matches_dense(case, dtype):
+    """Forward and all three gradients against _attention in float32."""
+    got, want = _short_vs_dense(*case, causal=False, dtype=dtype)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    for name, a, r in zip(("out", "dq", "dk", "dv"), got, want):
+        scale = float(jnp.abs(r).max())
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(r), rtol=tol, atol=tol * scale,
+            err_msg=name)
+
+
+@pytest.mark.parametrize("case", [SHORT_CASES[1], SHORT_CASES[3],
+                                  SHORT_CASES[4]],
+                         ids=["s196fused", "s197sliced", "s256h12"])
+def test_short_causal_matches_dense(case):
+    """The static triangle: one penalty tile a cell."""
+    got, want = _short_vs_dense(*case, causal=True, dtype=jnp.float32)
+    for name, a, r in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(r), rtol=2e-5,
+            atol=2e-5 * float(jnp.abs(r).max()), err_msg=name)
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "sliced"])
+def test_short_ignores_what_it_does_not_own(monkeypatch, fused):
+    """Poison: with every byte the kernels do not write themselves set to
+    NaN (the TPU interpreter's uninitialised memory: the images past the
+    batch in a ragged last cell, the cotangents' VMEM scratch, the rows
+    past the sequence in a block), no output and no gradient changes by
+    a bit. The key tail up to the lane tile (196 -> 256) is Mosaic's to
+    mask: the kernels work on logical (s, s) tiles, and chip_smoke-style
+    numerics on the chip (experiments/flash_time.py vit) hold that."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    case = (3, 196, 2, 64, 2, fused)
+    want, _ = _short_vs_dense(*case, causal=False, dtype=jnp.float32)
+    pallas_call = pl.pallas_call
+
+    def poisoned(*args, interpret, **kw):
+        assert interpret is True
+        return pallas_call(*args, **kw, interpret=pltpu.InterpretParams(
+            uninitialized_memory="nan", out_of_bounds_reads="uninitialized"))
+
+    monkeypatch.setattr(pl, "pallas_call", poisoned)
+    jax.clear_caches()  # the wrappers are jitted: lower them again
+    got, _ = _short_vs_dense(*case, causal=False, dtype=jnp.float32)
+    jax.clear_caches()
+    for name, a, r in zip(("out", "dq", "dk", "dv"), got, want):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(r), name)
+
+
+def test_short_refuses_what_it_cannot_hold():
+    from ddp_practice_tpu.ops.flash_attention import (
+        SHORT_SEQ_MAX,
+        flash_short_qkv,
+        short_seq_supported,
+    )
+
+    assert short_seq_supported(196, 12, 64)
+    assert short_seq_supported(196, 2, 128)
+    assert not short_seq_supported(196, 3, 64)      # 3 heads: no pack
+    assert not short_seq_supported(196, 4, 48)
+    assert not short_seq_supported(SHORT_SEQ_MAX + 1, 12, 64)
+    with pytest.raises(ValueError, match="whole-sequence"):
+        flash_short_qkv(jnp.zeros((2, 16, 3 * 3 * 64)), 3)
+
+
+def test_short_cell_size_follows_the_shape():
+    """G: a cell of at least a few microseconds of dots, inside VMEM,
+    never past the batch."""
+    from ddp_practice_tpu.ops.flash_attention import _images_per_cell
+
+    at = lambda b, s: _images_per_cell(b, s, 128)
+    assert at(128, 196) >= 4
+    assert at(2, 196) == 2
+    assert at(128, 64) >= at(128, 196) >= at(128, 384) >= at(128, 640) >= 1

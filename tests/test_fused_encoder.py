@@ -218,3 +218,135 @@ def test_fused_lm_matches_unfused(devices):
             np.asarray(f), np.asarray(w), rtol=3e-4, atol=3e-4,
             err_msg=jax.tree_util.keystr(path),
         )
+
+
+# ---------------------------------------------------------------------
+# attn_impl="auto" (PR 29): SelfAttention picks its attention core from
+# the shape at trace time, as `fused="auto"` picks the layer kernels.
+# ---------------------------------------------------------------------
+
+def _resolve(on_tpu=True, seq=196, heads=12, head_dim=64, decode=False,
+             mesh=None, **attn_kw):
+    from ddp_practice_tpu.models.vit import SelfAttention
+    from ddp_practice_tpu.parallel.ring import set_current_mesh
+    from ddp_practice_tpu.utils import backend
+
+    was = backend.on_tpu
+    backend.on_tpu = lambda: on_tpu
+    set_current_mesh(mesh)
+    try:
+        return SelfAttention(num_heads=heads, **attn_kw).resolve_attn_impl(
+            seq, head_dim, decode=decode)
+    finally:
+        backend.on_tpu = was
+        set_current_mesh(None)
+
+
+@pytest.mark.parametrize("kw,want", [
+    # ViT-B/16's shape on the chip, nothing named: the short kernels
+    (dict(), "flash_short"),
+    (dict(causal=True), "flash_short"),
+    (dict(seq=576), "flash_short"),
+    (dict(heads=2, head_dim=128), "flash_short"),
+    # off the TPU: ALWAYS _attention (a CPU test never interprets a
+    # kernel it did not ask for)
+    (dict(on_tpu=False), "xla"),
+    # the range: from the shortest length the chip timed to the longest
+    # a cell holds (lm_base's 2048 stays with whoever names "flash")
+    (dict(seq=64), "flash_short"),
+    (dict(seq=63), "xla"),
+    (dict(seq=1024), "flash_short"),
+    (dict(seq=1025), "xla"),
+    (dict(seq=2048), "xla"),
+    # what the kernels cannot express
+    (dict(decode=True, causal=True), "xla"),
+    (dict(rope=True), "xla"),
+    (dict(seq_axis="seq"), "xla"),
+    (dict(heads=3), "xla"),                  # vit_tiny: 3 heads, no pack
+    (dict(heads=12, head_dim=48), "xla"),
+    (dict(heads=12, kv_heads=2), "xla"),     # no fused qkv projection
+    # a named kernel is taken at its word, wherever it runs
+    (dict(attn_impl="xla"), "xla"),
+    (dict(attn_impl="flash"), "flash"),
+    (dict(attn_impl="flash", on_tpu=False, seq=64), "flash"),
+    (dict(attn_impl="xla", on_tpu=False), "xla"),
+])
+def test_attn_impl_auto_resolves_from_the_shape(kw, want):
+    assert _resolve(**kw) == want
+
+
+def test_attn_impl_auto_counts_the_heads_a_device_holds(devices):
+    """Under a mesh the kernels run in a shard_map island on each device's
+    own heads: 12 heads over tensor=2 are 6 (three packs), 2 heads over
+    tensor=2 are 1 (no pack of 64-wide heads)."""
+    from ddp_practice_tpu.config import MeshConfig
+    from ddp_practice_tpu.parallel.mesh import build_mesh
+
+    mesh = build_mesh(MeshConfig(data=2, tensor=2), devices=devices[:4])
+    assert _resolve(mesh=mesh) == "flash_short"
+    assert _resolve(mesh=mesh, heads=2) == "xla"
+    assert _resolve(mesh=mesh, heads=3) == "xla"
+
+
+def test_auto_attention_keeps_the_fused_encoder_its_precedence():
+    """`_plain_block` treats "auto" as plain: the small-d models keep the
+    one-kernel layer under fused="auto"; a named kernel still opts out."""
+    assert _block()._plain_block(decode=False)
+    assert _block(attn_impl="xla")._plain_block(decode=False)
+    assert not _block(attn_impl="flash")._plain_block(decode=False)
+    assert EncoderBlock(HEADS, MLP).attn_impl == "auto"
+    assert create_model("vit_tiny").attn_impl == "auto"
+    assert create_model("lm_tiny", vocab_size=64).attn_impl == "auto"
+
+
+def test_conv_model_takes_auto_and_refuses_a_named_kernel(devices):
+    """train/loop.py forwards attn_impl only when one is named: "auto" on
+    a conv model is not an error, "flash" on one is."""
+    from ddp_practice_tpu.config import MeshConfig, TrainConfig
+    from ddp_practice_tpu.train.loop import Trainer
+
+    cfg = dict(dataset="synthetic", epochs=1, batch_size=4,
+               mesh=MeshConfig(data=1))
+    assert TrainConfig(**cfg).attn_impl == "auto"
+    assert Trainer(TrainConfig(**cfg)).attn_impl is None
+    with pytest.raises(TypeError, match="attn_impl"):
+        Trainer(TrainConfig(attn_impl="flash", **cfg))
+
+
+def test_auto_short_attention_matches_xla_through_the_model(monkeypatch):
+    """SelfAttention under "auto" on the (pretended) TPU: the flat
+    projection + short kernels give what the DenseGeneral projection +
+    _attention give, outputs and parameter gradients."""
+    import ddp_practice_tpu.ops.flash_attention as fa
+    from ddp_practice_tpu.models.vit import (
+        SelfAttention,
+        resolved_attn_impls,
+    )
+    from ddp_practice_tpu.utils import backend
+
+    x = jnp.asarray(np.random.default_rng(7).standard_normal((3, 36, 128)),
+                    jnp.float32)
+    ref = SelfAttention(num_heads=2, attn_impl="xla")
+    variables = ref.init(jax.random.PRNGKey(0), x)
+
+    def loss(mod):
+        return lambda v, x: jnp.sum(jnp.square(mod.apply(v, x)))
+
+    want = jax.value_and_grad(loss(ref), argnums=(0, 1))(variables, x)
+    auto = SelfAttention(num_heads=2)
+    monkeypatch.setattr(fa, "SHORT_SEQ_MIN", 16)
+    with resolved_attn_impls() as seen:
+        # on_tpu() also switches the kernels to compiled mode: pretend
+        # only while the choice is made
+        real = auto.resolve_attn_impl
+        monkeypatch.setattr(backend, "on_tpu", lambda: True)
+        picked = real(36, 64)
+        monkeypatch.undo()
+        monkeypatch.setattr(SelfAttention, "resolve_attn_impl",
+                            lambda self, *a, **k: picked)
+        got = jax.value_and_grad(loss(auto), argnums=(0, 1))(variables, x)
+    assert picked == "flash_short" and seen == {"flash_short"}
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4),
+        got, want)

@@ -414,6 +414,50 @@ def test_train_epoch_alone_yields_the_tree(devices, placement, annotations):
                                 | {"train_epoch"}}
 
 
+@pytest.mark.parametrize("asked,on_tpu,want", [
+    ("auto", False, "xla"),          # off the TPU "auto" interprets nothing
+    ("auto", True, "flash_short"),   # what the chip's programs would run
+    ("flash", False, "flash"),       # a named kernel is taken at its word
+])
+def test_train_epoch_span_says_which_attention_runs(devices, monkeypatch,
+                                                    asked, on_tpu, want):
+    """The `attn_impl` attribute is what SelfAttention RESOLVED for the
+    Trainer's shapes and mesh (traced once, in the abstract init), not the
+    config's string; a model without attention has none (the conv model
+    of test_train_epoch_alone_yields_the_tree: attrs == {"epoch": 0})."""
+    from ddp_practice_tpu import models
+    from ddp_practice_tpu.config import MeshConfig
+    from ddp_practice_tpu.train.loop import Trainer
+    from ddp_practice_tpu.utils import backend
+
+    name = "vit_span_test"
+    try:
+        models.create_model(name)
+    except ValueError:
+        # one block, two heads of 64, 7 x 7 patches of a 28 x 28 image
+        models.register(name)(lambda **kw: models.create_model(
+            "vit_tiny", **{**kw, "hidden_dim": 128, "depth": 1,
+                           "num_heads": 2, "mlp_dim": 128}))
+    monkeypatch.setattr(backend, "on_tpu", lambda: on_tpu)
+    if on_tpu:
+        # the range's lower end belongs to the chip's measurements; the
+        # rule here is only that the resolved value reaches the span
+        import ddp_practice_tpu.ops.flash_attention as fa
+
+        monkeypatch.setattr(fa, "SHORT_SEQ_MIN", 16)
+    rec = TraceRecorder()
+    trainer = Trainer(train_config(model=name, attn_impl=asked,
+                                   mesh=MeshConfig(data=1),
+                                   fused_encoder="off"), tracer=rec)
+    assert trainer.attn_impl == want
+    if on_tpu:
+        return  # the step itself would interpret kernels as compiled ones
+    trainer.train_epoch(0)
+    root, = [r for r in lane_spans(rec) if r.parent is None]
+    assert root.name == "train_epoch"
+    assert root.attrs == {"epoch": 0, "attn_impl": want}
+
+
 def test_save_trace_is_public_and_needs_no_fit(devices, tmp_path):
     from ddp_practice_tpu.train.loop import Trainer
 
@@ -530,4 +574,5 @@ def test_no_pallas_call_in_flash_attention_goes_unnamed():
 
     src = open(fa.__file__).read()
     calls = src.count("pl.pallas_call(")
-    assert calls == 6 == len(re.findall(r'name="flash_(fwd|bwd_)', src))
+    assert calls == 9 == len(
+        re.findall(r'name="flash_(short_)?(fwd|bwd)', src))

@@ -117,6 +117,22 @@ def _flash_qkv():
     return jax.grad(loss), (_sds((B, S, 3 * HD)),)
 
 
+def _flash_short(batch=128, seq=196):
+    """ViT-B/16's attention core a layer (perf/configs/vit_b16.json under
+    perf/traffic/vit_224_b128.json): 128 images of 196 patches, 12 heads
+    of 64, off the flat projection; 196 is no multiple of the sublane
+    tile, and is the block's whole sequence dim. And the longest sequence
+    "auto" hands these kernels (SHORT_SEQ_MAX): one image a cell, whose
+    four (s, s) float32 tiles still fit (at 1280 the backward asks for
+    17.86 MB of the 16 MB of scoped VMEM)."""
+    from ddp_practice_tpu.ops.flash_attention import flash_short_qkv
+
+    def loss(qkv):
+        return flash_short_qkv(qkv, H).astype(jnp.float32).sum()
+
+    return jax.grad(loss), (_sds((batch, seq, 3 * HD)),)
+
+
 def _decode_packed(L, int8=False):
     from ddp_practice_tpu.ops.decode_attention import decode_attention_packed
 
@@ -250,6 +266,13 @@ def _moe_gmm(rows_a_tile):
                  _sds((tiles,), jnp.int32), _sds((1,), jnp.int32))
 
 
+def _kernel_calls(text):
+    """Names of the compiled Pallas custom calls, in program order."""
+    return [ln.split("=")[0].strip().lstrip("%").split(".")[0]
+            for ln in text.splitlines()
+            if "custom-call(" in ln and "tpu_custom_call" in ln]
+
+
 KERNELS = {
     "hybrid_paged_grouped_32q_2kv": _paged_grouped,
     "hybrid_ssm_step": _ssm_step,
@@ -258,6 +281,10 @@ KERNELS = {
     "flash_fwd": functools.partial(_flash, grad=False),
     "flash_fwd_bwd": functools.partial(_flash, grad=True),
     "flash_qkv_fwd_bwd": _flash_qkv,
+    "flash_short_fwd_bwd": _flash_short,
+    "flash_short_fwd_bwd_longest": lambda: _flash_short(
+        8, __import__("ddp_practice_tpu.ops.flash_attention", fromlist=["x"]
+                      ).SHORT_SEQ_MAX),
     # h 5 x d 48 does not pack into 128 lanes: the folded (b*h, s, d) path
     "flash_folded_fwd_bwd": functools.partial(_flash, grad=True, h=5, d=48),
     "decode_single_block_L640": functools.partial(_decode_packed, 640),
@@ -292,18 +319,14 @@ def test_kernel_compiles_for_v5e(topo, name):
         # ONE device op a call, named by the kernel's `name=`:
         # perf/lib/readers.py sums every traced op whose name holds
         # "paged_decode" (a gather beside the compute would be a second)
-        calls = [ln.split("=")[0].strip().lstrip("%") for ln in
-                 text.splitlines() if "custom-call(" in ln
-                 and "tpu_custom_call" in ln]
+        calls = _kernel_calls(text)
         assert len(calls) == 1 and "paged_decode" in calls[0], calls
     if name.startswith("hybrid"):
         # each is ONE device op under the name the benchmark's readers
         # look for (perf/layer_metrics/flood_ssm_*, flood_moe_*)
         want = {"hybrid_paged": "paged_decode", "hybrid_ssm_s": "ssm_step",
                 "hybrid_moe_g": "moe_gmm"}[name[:12]]
-        calls = [ln.split("=")[0].strip().lstrip("%") for ln in
-                 text.splitlines() if "custom-call(" in ln
-                 and "tpu_custom_call" in ln]
+        calls = _kernel_calls(text)
         assert len(calls) == 1 and want in calls[0], calls
 
 
@@ -340,6 +363,102 @@ def test_flash_compiles_sharded_over_four_devices(topo):
     assert named == ["flash_bwd_dkv_packed", "flash_bwd_dq_packed",
                      "flash_fwd_packed"], named
     per_device = f"bf16[{B // 4},{S},{HD}]"
+    assert all(per_device in ln for ln in calls), calls[0][:300]
+    assert "all-gather" not in text
+
+
+def _attention_grad(device, *, batch, seq, causal=False, **attn_kw):
+    """HLO of d loss / d (params, x) of one bf16 SelfAttention of 12 heads
+    of 64 (ViT-B/16's and lm_base's), compiled for `device`."""
+    from ddp_practice_tpu.models.vit import SelfAttention
+
+    attn = SelfAttention(num_heads=H, dtype=BF16, causal=causal, **attn_kw)
+    variables = jax.eval_shape(
+        lambda: attn.init(jax.random.PRNGKey(0), jnp.zeros((1, seq, HD), BF16)))
+
+    def loss(variables, x):
+        return attn.apply(variables, x).astype(jnp.float32).sum()
+
+    return _compile(jax.grad(loss, argnums=(0, 1)), variables,
+                    _sds((batch, seq, HD)), device=device, min_kernels=2)
+
+
+def test_vit_attention_takes_the_short_kernels_unasked(topo):
+    """ViT-B/16's SelfAttention with NO attn_impl given, at the benchmark
+    cell's shape: both short kernels are in the program, no (b, h, s, s)
+    float32 tensor is, the projection reaches the kernels as the
+    convolution wrote it (no `pad`, no relayout `copy` of it), and the
+    cotangent leaves the backward kernel flat."""
+    text = _attention_grad(topo.devices[0], batch=128, seq=196)
+    assert _kernel_calls(text) == ["flash_short_fwd", "flash_short_bwd"]
+    assert "f32[128,12,196,196]" not in text
+    assert "bf16[128,12,196,196]" not in text
+    qkv = r"bf16\[128,196,(2304|3,12,64)\]"
+    moved = [ln.strip()[:160] for ln in text.splitlines()
+             if re.search(rf"= {qkv}\S* (pad|copy)\(", ln)]
+    # ONE relayout is XLA's own: it keeps (128, 196, .) activations
+    # sequence-major, and the weight gradient's convolution takes the
+    # flat cotangent that way ({2,0,1}); under _attention there were
+    # five such copies of the projection and three pads
+    assert len(moved) <= 1 and not any(" pad(" in m for m in moved), moved
+    assert all("{2,0,1" in m for m in moved), moved
+    assert re.search(r"flash_short_bwd\S* = bf16\[128,196,2304\]", text)
+    # the forward kernel reads what the projection's convolution wrote
+    fwd = next(ln for ln in text.splitlines()
+               if "custom-call(" in ln and "flash_short_fwd" in ln)
+    operands = set(re.findall(r"custom-call\((.*?)\), custom_call_target",
+                              fwd)[0].split(", "))
+    assert len(operands) == 1 and "fusion" in operands.pop(), fwd[:300]
+
+
+def test_lm_attention_keeps_the_streaming_kernels(topo):
+    """The LM cells' block (8 x 2048, causal, "flash" named): exactly the
+    three packed streaming kernels, as before PR 29; and with nothing
+    named, 2048 is past the short kernels' range: plain XLA, no kernel."""
+    text = _attention_grad(topo.devices[0], batch=B, seq=S, causal=True,
+                           attn_impl="flash")
+    assert sorted(_kernel_calls(text)) == [
+        "flash_bwd_dkv_packed", "flash_bwd_dq_packed", "flash_fwd_packed"]
+    from ddp_practice_tpu.models.vit import SelfAttention
+
+    assert SelfAttention(num_heads=H, causal=True).resolve_attn_impl(
+        S, D) == "xla"
+
+
+@pytest.mark.parametrize("layout", ["data4", "data2_tensor2"])
+def test_short_attention_compiles_on_the_mesh(topo, layout):
+    """Under a mesh "auto" opens the same shard_map island as "flash":
+    each device runs the short kernels on its own images (data) and its
+    own heads (tensor), nothing is gathered. With the heads whole the
+    flat projection feeds them; split over tensor=2 each device flattens
+    its own 6 heads of the (3, h, hd) projection."""
+    mesh_cfg = (MeshConfig(data=2, tensor=2) if layout == "data2_tensor2"
+                else MeshConfig())
+    mesh = build_mesh(mesh_cfg, devices=topo.devices)
+    set_current_mesh(mesh)
+    from ddp_practice_tpu.models.vit import SelfAttention
+
+    attn = SelfAttention(num_heads=H, dtype=BF16)
+    variables = jax.eval_shape(
+        lambda: attn.init(jax.random.PRNGKey(0), jnp.zeros((4, 196, HD), BF16)))
+    rep = NamedSharding(mesh, P())
+    variables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        variables)
+    x = jax.ShapeDtypeStruct(
+        (128, 196, HD), BF16,
+        sharding=NamedSharding(mesh, P(MeshConfig.AXIS_DATA)))
+
+    def loss(variables, x):
+        return attn.apply(variables, x).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        variables, x).compile().as_text()
+    assert _kernel_calls(text) == ["flash_short_fwd", "flash_short_bwd"]
+    dp, tp = mesh.shape["data"], mesh.shape["tensor"]
+    calls = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    per_device = f"bf16[{128 // dp},196,{3 * HD // tp}]"
     assert all(per_device in ln for ln in calls), calls[0][:300]
     assert "all-gather" not in text
 
