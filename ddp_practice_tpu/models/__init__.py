@@ -20,6 +20,7 @@ from ddp_practice_tpu.models.pipeline_vit import PipelinedViT
 from ddp_practice_tpu.models.vit_moe import ViTMoE
 from ddp_practice_tpu.models.lm import LMBase, LMTiny, TransformerLM
 from ddp_practice_tpu.models.hybrid_lm import HybridLM
+from ddp_practice_tpu.models.mla_lm import MLALM
 
 _REGISTRY = {}
 # registry names whose module exposes the tri-state `fused` field
@@ -213,6 +214,18 @@ def _nemotron_h(*, num_classes, policy, axis_name, **kw):
     )
 
 
+@register("deepseek_v3")
+def _deepseek_v3(*, num_classes, policy, axis_name, **kw):
+    # multi-head latent attention + gated experts after leading dense
+    # layers; test-sized defaults, the published widths come as options
+    # (perf/families/deepseek_v3.py model_options)
+    return MLALM(
+        dtype=policy.compute_dtype,
+        param_dtype=policy.param_dtype,
+        **kw,
+    )
+
+
 __all__ = [
     "create_model",
     "ConvNet",
@@ -227,6 +240,7 @@ __all__ = [
     "ViTMoE",
     "TransformerLM",
     "HybridLM",
+    "MLALM",
     "LMTiny",
     "LMBase",
 ]

@@ -374,12 +374,13 @@ _CHUNK_TOKENS = 128
 _CHUNK_VMEM_BYTES = 8 << 20
 
 
-def _pages_per_chunk(block_size: int, hd_total: int, dtype) -> int:
-    """Pages P the walk fetches and computes at a time: enough for 128
-    tokens (128 <= P * block_size < 256 whatever the page size, one page
+def _pages_per_chunk(block_size: int, hd_total: int, dtype,
+                     tokens: int = _CHUNK_TOKENS) -> int:
+    """Pages P the walk fetches and computes at a time: enough for
+    `tokens` (128 <= P * block_size < 256 whatever the page size, one page
     when a page is longer), halved while the four (P * block_size,
     h*hd) buffers would pass the VMEM budget (very wide models)."""
-    p = -(-_CHUNK_TOKENS // block_size)
+    p = -(-tokens // block_size)
     row = hd_total * jnp.dtype(dtype).itemsize
     while p > 1 and 4 * p * block_size * row > _CHUNK_VMEM_BYTES:
         p //= 2
@@ -390,7 +391,7 @@ def _paged_walk_kernel(
     len_ref, start_ref, pt_ref,          # scalar prefetch (SMEM)
     q_ref, k_hbm, v_hbm, o_ref,          # q/out blocks; the pools, in HBM
     k_buf, v_buf, sem, first_buf,        # scratch
-    *, sm_scale, block_size, pages, d, rows, kv_heads=None,
+    *, sm_scale, block_size, pages, d, rows, kv_heads=None, v_lanes=None,
 ):
     """Grid (slots,): one cell walks ONE slot's live pages, columns
     `attn_start // block_size` to `len // block_size` of its page-table
@@ -418,6 +419,13 @@ def _paged_walk_kernel(
     against that head's own d lanes of the chunk, so a chunk is two
     matmuls a KV head and K and V are read once for the whole group.
 
+    A LATENT pool (`v_hbm` and `v_buf` None, `v_lanes` set; kv_heads 1:
+    absorbed multi-head latent attention, `_latent_walk_kernel`) is the
+    grouped walk with ONE pool: a row is a token's key, `d` lanes wide,
+    and its first `v_lanes` lanes are the token's value, so a page comes
+    by one copy and the chunk's value tile is a lane slice of its key
+    tile.
+
     Pages of a chunk past the slot's last are not fetched; their rows
     keep what an earlier chunk left (zeros at first), which is finite,
     so the mask's -1e30 turns them into exact zeros."""
@@ -432,6 +440,9 @@ def _paged_walk_kernel(
         last = jnp.minimum(len_ref[slot] // bs, mb - 1)
         return jnp.clip(start_ref[slot] // bs, 0, last), last
 
+    pools = ((k_hbm, k_buf, 0),) if v_hbm is None \
+        else ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1))
+
     def copies(slot, first, last, c, buf, go):
         """Start (`go`) or wait for chunk c of `slot` in buffer `buf`."""
         for i in range(pages):
@@ -441,7 +452,7 @@ def _paged_walk_kernel(
             def _(i=i, col=col):
                 page = pt_ref[slot, col]
                 rows_i = pl.ds(i * bs, bs)
-                for hbm, dst, which in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                for hbm, dst, which in pools:
                     dma = pltpu.make_async_copy(
                         hbm.at[page], dst.at[buf, rows_i], sem.at[which, buf]
                     )
@@ -455,8 +466,8 @@ def _paged_walk_kernel(
 
     @pl.when(b == 0)
     def _open():
-        k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
-        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        for _, dst, _ in pools:
+            dst[...] = jnp.zeros(dst.shape, dst.dtype)
         first_buf[0] = 0
         copies(b, first, last, 0, 0, True)
 
@@ -471,7 +482,7 @@ def _paged_walk_kernel(
     if grouped:
         qs = (q_ref[...] * sm_scale).astype(q_ref.dtype)    # (h, d)
         lanes = [(j * rows, j * d) for j in range(kv_heads)]
-        width = d
+        width = v_lanes or d
     else:
         hd_total = q_ref.shape[-1]
         lane = jax.lax.broadcasted_iota(jnp.int32, (rows, hd_total), 1)
@@ -504,8 +515,9 @@ def _paged_walk_kernel(
         for (r0, l0), state in zip(lanes, states):
             s = _dot_tb(qs[r0:r0 + rows],
                         k_buf[buf, :, l0:l0 + d]) + penalty
-            out.append(_softmax_accumulate(
-                s, v_buf[buf, :, l0:l0 + d], *state))
+            v_tile = k_buf[buf, :, :v_lanes] if v_buf is None \
+                else v_buf[buf, :, l0:l0 + d]
+            out.append(_softmax_accumulate(s, v_tile, *state))
         return tuple(out)
 
     states = lax.fori_loop(0, n_chunks, chunk, tuple((
@@ -521,6 +533,13 @@ def _paged_walk_kernel(
         o_ref[...] = jnp.sum(
             jnp.where(own, states[0][2], 0.0), axis=0, keepdims=True
         ).astype(o_ref.dtype)
+
+
+def _latent_walk_kernel(len_ref, start_ref, pt_ref, q_ref, c_hbm, o_ref,
+                        c_buf, sem, first_buf, **kw):
+    """`_paged_walk_kernel` over ONE pool (see its docstring)."""
+    _paged_walk_kernel(len_ref, start_ref, pt_ref, q_ref, c_hbm, None, o_ref,
+                       c_buf, None, sem, first_buf, **kw)
 
 
 def gather_pages(pages: jnp.ndarray, page_table: jnp.ndarray,
@@ -811,3 +830,99 @@ def paged_decode_attention(
         name="paged_decode",
     )(lens, start, pt, q, k_pages, v_pages)
     return out.reshape(b, 1, hd_total)
+
+
+# ----------------------------------------------- absorbed latent attention
+# Multi-head latent attention keeps ONE row a token and layer: the
+# normalised latent c (its first `v_lanes` lanes) and the one rotated key
+# all heads share. With the key expansion absorbed into the query and the
+# value expansion applied after, a decode step is multi-query attention over
+# that row: score_h = q~_h . row, out_h = sum_s p_h(s) row(s)[:v_lanes]
+# (models/mla_lm.py). The walk reads each live row once, for all heads.
+
+# Tokens a chunk of the latent walk aims at: a row is read once for all
+# heads, so a chunk is two matmuls however long it is, and a longer one
+# spreads the loop's fixed cost over more bytes.
+_MLA_CHUNK_TOKENS = 1024
+
+
+def paged_mla_reference(q, latent_pages, page_table, lengths,
+                        attn_start=None, *, v_lanes: int, sm_scale: float):
+    """XLA gather path of `paged_decode_mla`: each slot's pages as one
+    span, masked softmax in float32."""
+    span = jnp.take(latent_pages, page_table, axis=0)    # (b, mb, bs, w)
+    b = q.shape[0]
+    span = span.reshape(b, -1, span.shape[-1])
+    pos = jnp.arange(span.shape[1], dtype=jnp.int32)[None, :]
+    valid = pos <= lengths[:, None]
+    if attn_start is not None:
+        valid &= pos >= attn_start[:, None]
+    scores = jnp.einsum("bhw,bsw->bhs", q.astype(span.dtype), span,
+                        preferred_element_type=jnp.float32) * sm_scale
+    scores = jnp.where(valid[:, None, :], scores, _NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhs,bsv->bhv", probs.astype(span.dtype),
+                     span[..., :v_lanes], preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def paged_decode_mla(q, latent_pages, page_table, lengths, attn_start=None,
+                     *, v_lanes: int, sm_scale: float, impl: str = "auto"):
+    """One absorbed latent-attention decode step over a paged latent pool.
+
+    q (b, heads, w): each head's absorbed query over the pool's row, zeros
+    in whatever lanes of the row are padding; latent_pages (num_blocks,
+    block_size, w); returns (b, heads, v_lanes), each head's sum of the
+    rows' first `v_lanes` lanes (the caller expands it to the head's
+    value). `lengths` is each slot's current position, inclusive, and
+    `attn_start` its first, as in `paged_decode_attention`.
+
+    The kernel is `_paged_walk_kernel` with one pool and one "KV head":
+    ONE device op named `paged_decode_mla`, one copy a page (a row is key
+    and value). impl as in `paged_decode_attention`."""
+    b, heads, w = q.shape
+    bs = latent_pages.shape[1]
+    packable = (w % _LANES == 0 and v_lanes % _LANES == 0
+                and heads % 8 == 0 and bs % 8 == 0)
+    if impl == "reference" or (impl == "auto" and (
+            not packable or not backend.on_tpu())):
+        return paged_mla_reference(
+            q, latent_pages, page_table, lengths, attn_start,
+            v_lanes=v_lanes, sm_scale=sm_scale)
+    if not packable:
+        raise ValueError(
+            f"impl='kernel' needs a row ({w}) and a value ({v_lanes}) of "
+            f"whole lane tiles, heads ({heads}) and block_size ({bs}) "
+            f"multiples of 8")
+    lens = jnp.asarray(lengths, jnp.int32)
+    start = (jnp.zeros((b,), jnp.int32) if attn_start is None
+             else jnp.asarray(attn_start, jnp.int32))
+    pages = _pages_per_chunk(bs, w, latent_pages.dtype, _MLA_CHUNK_TOKENS)
+    kernel = functools.partial(
+        _latent_walk_kernel, sm_scale=sm_scale, block_size=bs, pages=pages,
+        d=w, rows=heads, kv_heads=1, v_lanes=v_lanes)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((None, heads, w), lambda b_, *_: (b_, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((None, heads, v_lanes),
+                                   lambda b_, *_: (b_, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * bs, w), latent_pages.dtype),
+                pltpu.SemaphoreType.DMA((1, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, heads, v_lanes), q.dtype),
+        # cells run in order: each starts the next one's first chunk
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=not backend.on_tpu(),
+        name="paged_decode_mla",
+    )(lens, start, jnp.asarray(page_table, jnp.int32), q, latent_pages)

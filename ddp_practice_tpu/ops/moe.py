@@ -1079,24 +1079,76 @@ def held_tile_layout(choices, *, offset: int, held: int, tile: int):
     }
 
 
-def _expert_mlp_kernel(te_ref, used_ref, x_ref, w1_ref, w2_ref, o_ref):
-    """One row tile through its expert: relu(x W1)^2 W2. Tiles past the
-    used ones write zeros and fetch nothing new."""
+def _relu2_body(x_ref, w1_ref, w2_ref):
+    """relu(x W1)^2 W2 of one row tile, float32 sums."""
+    h = jnp.dot(x_ref[...], w1_ref[...], preferred_element_type=jnp.float32)
+    h = jnp.square(jnp.maximum(h, 0.0)).astype(x_ref.dtype)
+    return jnp.dot(h, w2_ref[...], preferred_element_type=jnp.float32)
+
+
+def _glu_body(x_ref, wg_ref, wu_ref, wd_ref):
+    """(silu(x W_gate) * x W_up) W_down of one row tile, float32 sums."""
+    x = x_ref[...]
+    g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
+    h = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+    return jnp.dot(h, wd_ref[...], preferred_element_type=jnp.float32)
+
+
+def _expert_tiles_kernel(te_ref, used_ref, x_ref, *refs, body):
+    """One row tile through its expert (`body` over the expert's
+    matrices). Tiles past the used ones write zeros and fetch nothing
+    new."""
     del te_ref
+    *w_refs, o_ref = refs
     t = pl.program_id(0)
 
     @pl.when(t < used_ref[0])
     def _():
-        h = jnp.dot(x_ref[...], w1_ref[...],
-                    preferred_element_type=jnp.float32)
-        h = jnp.square(jnp.maximum(h, 0.0)).astype(x_ref.dtype)
-        o_ref[...] = jnp.dot(
-            h, w2_ref[...], preferred_element_type=jnp.float32
-        ).astype(o_ref.dtype)
+        o_ref[...] = body(x_ref, *w_refs).astype(o_ref.dtype)
 
     @pl.when(t >= used_ref[0])
     def _():
         o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+def _expert_tiles_call(body, name, x_rows, mats, tile_expert, tiles_used,
+                       tile: int):
+    """ONE device op called `name` (interpret mode off the TPU, where only
+    the tests call it): grid over the tiles, the weights' block index is
+    the tile's expert, so a run of tiles of one expert fetches its
+    matrices once and every held expert that has a row is streamed exactly
+    once."""
+    rows, d = x_rows.shape
+    n_tiles = rows // tile
+    widest = max(max(m.shape[1:]) for m in mats)
+    itemsize = jnp.dtype(mats[0].dtype).itemsize
+    # an expert's matrices, two deep, and the row tiles
+    vmem = 2 * sum(m.shape[1] * m.shape[2] for m in mats) * itemsize \
+        + 8 * tile * widest * 4 + (4 << 20)
+    return pl.pallas_call(
+        functools.partial(_expert_tiles_kernel, body=body),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_tiles,),
+            in_specs=[pl.BlockSpec((tile, d), lambda t, te, used: (t, 0))] + [
+                pl.BlockSpec((None,) + m.shape[1:],
+                             lambda t, te, used: (te[t], 0, 0))
+                for m in mats],
+            out_specs=pl.BlockSpec((tile, d), lambda t, te, used: (t, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, d), x_rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(vmem)),
+        interpret=not backend.on_tpu(),
+        name=name,
+    )(tile_expert, tiles_used, x_rows, *mats)
+
+
+def _live_tiles(out, n_tiles: int, tiles_used, like):
+    live = jnp.arange(n_tiles)[:, None, None] < tiles_used[0]
+    return jnp.where(live, out, 0.0).astype(like.dtype).reshape(like.shape)
 
 
 def expert_mlp_tiles(x_rows, w1, w2, tile_expert, tiles_used, *, tile: int):
@@ -1121,44 +1173,51 @@ def expert_mlp_tiles_reference(x_rows, w1, w2, tile_expert, tiles_used, *,
     h = jnp.square(jnp.maximum(h, 0.0)).astype(x_rows.dtype)
     out = jnp.einsum("tmf,tfd->tmd", h, w2[tile_expert],
                      preferred_element_type=jnp.float32)
-    live = jnp.arange(n_tiles)[:, None, None] < tiles_used[0]
-    return jnp.where(live, out, 0.0).astype(x_rows.dtype).reshape(rows, d)
+    return _live_tiles(out, n_tiles, tiles_used, x_rows)
 
 
 def expert_mlp_tiles_kernel(x_rows, w1, w2, tile_expert, tiles_used, *,
                             tile: int):
-    """ONE device op named `moe_gmm` (interpret mode off the TPU, where
-    only the tests call it): grid over the tiles, the weights' block index
-    is the tile's expert, so a run of tiles of one expert fetches its two
-    matrices once and every held expert that has a row is streamed exactly
-    once."""
+    """The device op `moe_gmm`: both matrices of an expert in VMEM."""
+    return _expert_tiles_call(_relu2_body, "moe_gmm", x_rows, (w1, w2),
+                              tile_expert, tiles_used, tile)
+
+
+def expert_glu_tiles(x_rows, w_gate, w_up, w_down, tile_expert, tiles_used,
+                     *, tile: int):
+    """(silu(x Wg_e) * x Wu_e) Wd_e for every row tile, e the tile's
+    expert: gated experts. w_gate, w_up (held, d, f); w_down (held, f, d)."""
+    if not backend.on_tpu():
+        with jax.named_scope("moe_gmm_glu"):
+            return expert_glu_tiles_reference(
+                x_rows, w_gate, w_up, w_down, tile_expert, tiles_used,
+                tile=tile)
+    return expert_glu_tiles_kernel(
+        x_rows, w_gate, w_up, w_down, tile_expert, tiles_used, tile=tile)
+
+
+def expert_glu_tiles_reference(x_rows, w_gate, w_up, w_down, tile_expert,
+                               tiles_used, *, tile: int):
     rows, d = x_rows.shape
     n_tiles = rows // tile
-    f = w1.shape[2]
-    itemsize = jnp.dtype(w1.dtype).itemsize
-    # both matrices of an expert, two deep, and the row tiles
-    vmem = 4 * d * f * itemsize + 8 * tile * max(d, f) * 4 + (4 << 20)
-    return pl.pallas_call(
-        _expert_mlp_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(n_tiles,),
-            in_specs=[
-                pl.BlockSpec((tile, d), lambda t, te, used: (t, 0)),
-                pl.BlockSpec((None, d, f),
-                             lambda t, te, used: (te[t], 0, 0)),
-                pl.BlockSpec((None, f, d),
-                             lambda t, te, used: (te[t], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((tile, d), lambda t, te, used: (t, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((rows, d), x_rows.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=int(vmem)),
-        interpret=not backend.on_tpu(),
-        name="moe_gmm",
-    )(tile_expert, tiles_used, x_rows, w1, w2)
+    xt = x_rows.reshape(n_tiles, tile, d)
+    g = jnp.einsum("tmd,tdf->tmf", xt, w_gate[tile_expert],
+                   preferred_element_type=jnp.float32)
+    u = jnp.einsum("tmd,tdf->tmf", xt, w_up[tile_expert],
+                   preferred_element_type=jnp.float32)
+    h = (g * jax.nn.sigmoid(g) * u).astype(x_rows.dtype)
+    out = jnp.einsum("tmf,tfd->tmd", h, w_down[tile_expert],
+                     preferred_element_type=jnp.float32)
+    return _live_tiles(out, n_tiles, tiles_used, x_rows)
+
+
+def expert_glu_tiles_kernel(x_rows, w_gate, w_up, w_down, tile_expert,
+                            tiles_used, *, tile: int):
+    """The device op `moe_gmm_glu`: the three matrices of an expert in
+    VMEM, two deep (2 x 9.4 MB at 2048 -> 768 -> 2048 in bf16)."""
+    return _expert_tiles_call(
+        _glu_body, "moe_gmm_glu", x_rows, (w_gate, w_up, w_down),
+        tile_expert, tiles_used, tile)
 
 
 def _row_tile(rows_per_expert: float) -> int:
@@ -1168,6 +1227,59 @@ def _row_tile(rows_per_expert: float) -> int:
     while tile < 128 and tile < rows_per_expert:
         tile *= 2
     return tile
+
+
+def _held_rows(layer, xf, src):
+    """The router and the row layout of a layer that holds a range of the
+    experts (`LatentMoE`, `GatedMoE`; `layer` gives `num_experts`, `top_k`,
+    `experts_held`, `expert_offset`, `routed_scaling` and the parameters'
+    scope): scores xf (n, d) over ALL experts in float32, lays the held
+    picks out as whole row tiles and fills them from `src` (n, width).
+    Returns (rows, layout, weights (n, k) float32, tile)."""
+    n, k, held = xf.shape[0], layer.top_k, layer.experts_held
+    if not 0 <= layer.expert_offset <= layer.num_experts - held:
+        raise ValueError(
+            f"held experts [{layer.expert_offset}, "
+            f"{layer.expert_offset + held}) lie outside the "
+            f"{layer.num_experts} the router scores")
+    bias = layer.param("e_score_correction_bias", nn.initializers.zeros,
+                       (layer.num_experts,), layer.param_dtype)
+    with jax.named_scope("moe_route"):
+        logits = nn.Dense(
+            layer.num_experts, use_bias=False, dtype=jnp.float32,
+            param_dtype=layer.param_dtype, name="router",
+        )(xf.astype(jnp.float32))
+        choices, weights = route_sigmoid_topk(
+            logits, bias, k=k, scaling=layer.routed_scaling)
+        tile = _row_tile(n * k / layer.num_experts)
+        lay = held_tile_layout(choices, offset=layer.expert_offset,
+                               held=held, tile=tile)
+        rows = jnp.where(lay["row_valid"][:, None],
+                         src[lay["row_token"]], 0).astype(layer.dtype)
+    return rows, lay, weights, tile
+
+
+def _held_combine(out, lay, weights, dtype):
+    """Each token's weighted sum over its held picks' rows of `out`."""
+    with jax.named_scope("moe_combine"):
+        gate = jnp.where(lay["pick_held"], weights, 0.0)
+        picked = out[lay["pick_row"]].astype(jnp.float32)  # (n, k, width)
+        return jnp.einsum("nk,nkd->nd", gate, picked).astype(dtype)
+
+
+def _held_count(layer, lay, decode: bool) -> None:
+    """Decode mode: add this call's counts to the cache collection's
+    `moe_stats` (rows that landed on held experts, held experts touched,
+    most rows on one expert; summed over the calls since the engine last
+    zeroed it), what `PagedEngine` hands its tracer."""
+    if not decode:
+        return
+    stats = layer.variable("cache", "moe_stats", jnp.zeros, (3,), jnp.int32)
+    if not layer.is_initializing():
+        c, old = lay["counts"], stats.value
+        stats.value = jnp.stack([
+            old[0] + c.sum(), old[1] + (c > 0).sum(),
+            jnp.maximum(old[2], c.max())]).astype(jnp.int32)
 
 
 class LatentMoE(nn.Module):
@@ -1180,11 +1292,9 @@ class LatentMoE(nn.Module):
         out = routed + relu(x V1)^2 V2          the shared expert, whole
 
     `experts_held` experts from `expert_offset` are here; `W_down`, `W_up`,
-    the router and the shared expert are whole on every chip. In decode
-    mode the layer also counts, into the cache collection's `moe_stats`
-    (rows that landed on held experts, held experts touched, most rows on
-    one expert; summed over the calls since the engine last zeroed it),
-    what `PagedEngine` hands its tracer."""
+    the router and the shared expert are whole on every chip. Router, row
+    layout, combine and the decode-mode counters (`moe_stats`) are
+    `GatedMoE`'s too (`_held_rows`, `_held_combine`, `_held_count`)."""
 
     num_experts: int
     top_k: int
@@ -1204,27 +1314,9 @@ class LatentMoE(nn.Module):
         dense = functools.partial(
             nn.Dense, use_bias=False, dtype=cd, param_dtype=self.param_dtype)
         xf = x.reshape(-1, d).astype(cd)
-        n, k, held = xf.shape[0], self.top_k, self.experts_held
-        if not 0 <= self.expert_offset <= self.num_experts - held:
-            raise ValueError(
-                f"held experts [{self.expert_offset}, "
-                f"{self.expert_offset + held}) lie outside the "
-                f"{self.num_experts} the router scores")
-        bias = self.param("e_score_correction_bias", nn.initializers.zeros,
-                          (self.num_experts,), self.param_dtype)
+        held = self.experts_held
         u = dense(self.latent_dim, name="down")(xf)
-        with jax.named_scope("moe_route"):
-            logits = nn.Dense(
-                self.num_experts, use_bias=False, dtype=jnp.float32,
-                param_dtype=self.param_dtype, name="router",
-            )(xf.astype(jnp.float32))
-            choices, weights = route_sigmoid_topk(
-                logits, bias, k=k, scaling=self.routed_scaling)
-            tile = _row_tile(n * k / self.num_experts)
-            lay = held_tile_layout(choices, offset=self.expert_offset,
-                                   held=held, tile=tile)
-            rows = jnp.where(lay["row_valid"][:, None],
-                             u[lay["row_token"]], 0).astype(cd)
+        rows, lay, weights, tile = _held_rows(self, xf, u)
         w1 = self.param("expert_w1", nn.initializers.normal(0.02),
                         (held, self.latent_dim, self.expert_dim),
                         self.param_dtype)
@@ -1234,20 +1326,68 @@ class LatentMoE(nn.Module):
         out = expert_mlp_tiles(
             rows, w1.astype(cd), w2.astype(cd), lay["tile_expert"],
             lay["tiles_used"], tile=tile)
-        with jax.named_scope("moe_combine"):
-            gate = jnp.where(lay["pick_held"], weights, 0.0)
-            picked = out[lay["pick_row"]].astype(jnp.float32)  # (n, k, lat)
-            routed = jnp.einsum("nk,nkd->nd", gate, picked).astype(cd)
-        y = dense(d, name="up")(routed)
+        y = dense(d, name="up")(_held_combine(out, lay, weights, cd))
         shared = dense(self.shared_dim, name="shared_in")(xf)
         shared = jnp.square(nn.relu(shared))
         y = y + dense(d, name="shared_out")(shared)
-        if decode:
-            stats = self.variable("cache", "moe_stats", jnp.zeros, (3,),
-                                  jnp.int32)
-            if not self.is_initializing():
-                c, old = lay["counts"], stats.value
-                stats.value = jnp.stack([
-                    old[0] + c.sum(), old[1] + (c > 0).sum(),
-                    jnp.maximum(old[2], c.max())]).astype(jnp.int32)
+        _held_count(self, lay, decode)
+        return y.reshape(*lead, d).astype(x.dtype)
+
+
+class GatedMLP(nn.Module):
+    """SwiGLU: W_down(silu(W_gate x) * W_up x), no biases."""
+
+    width: int
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype)
+        h = nn.silu(dense(self.width, name="gate")(x)) \
+            * dense(self.width, name="up")(x)
+        return dense(x.shape[-1], name="down")(h)
+
+
+class GatedMoE(nn.Module):
+    """Gated (SwiGLU) experts in the model's own width (DeepSeek-V3 style),
+    a chip's share of them held; `LatentMoE`'s router, row layout, combine
+    and counters.
+
+        s = sigmoid(x W_r)                      float32, all `num_experts`
+        picks = top_k(s + selection bias);  w_e = s_e / sum_picks s * scaling
+        routed = sum_{picks held here} w_e Wd_e(silu(Wg_e x) * Wu_e x)
+        out = routed + the shared SwiGLU expert of width `shared_dim`
+
+    The expert matrices go through ONE kernel, `moe_gmm_glu`."""
+
+    num_experts: int
+    top_k: int
+    expert_dim: int
+    shared_dim: int
+    experts_held: int
+    expert_offset: int = 0
+    routed_scaling: float = 1.0
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, *, decode: bool = False):
+        lead, d = x.shape[:-1], x.shape[-1]
+        cd, held, f = self.dtype, self.experts_held, self.expert_dim
+        xf = x.reshape(-1, d).astype(cd)
+        rows, lay, weights, tile = _held_rows(self, xf, xf)
+        mats = [self.param(name, nn.initializers.normal(0.02), shape,
+                           self.param_dtype).astype(cd)
+                for name, shape in (("expert_gate", (held, d, f)),
+                                    ("expert_up", (held, d, f)),
+                                    ("expert_down", (held, f, d)))]
+        out = expert_glu_tiles(rows, *mats, lay["tile_expert"],
+                               lay["tiles_used"], tile=tile)
+        y = _held_combine(out, lay, weights, cd)
+        y = y + GatedMLP(self.shared_dim, cd, self.param_dtype,
+                         name="shared")(xf)
+        _held_count(self, lay, decode)
         return y.reshape(*lead, d).astype(x.dtype)

@@ -27,13 +27,17 @@ def apply_rope(
     positions: jnp.ndarray,
     *,
     theta: float = 10000.0,
+    interleaved: bool = False,
 ) -> jnp.ndarray:
     """Rotate (b, s, h, d) by per-position angles; positions is (s,) int,
     or (b, s) int when sequences sit at different absolute offsets (the
     paged KV cache decodes every slot at its OWN write position —
     serve/kv_pages.py — so the batch no longer shares one cursor).
 
-    GPT-NeoX rotate-half convention: channel pairs are (i, i + d/2).
+    GPT-NeoX rotate-half convention: channel pairs are (i, i + d/2);
+    `interleaved` pairs (2i, 2i + 1) instead (a `rope_interleave` config:
+    models/mla_lm.py). A model that rotates only part of a head passes
+    that part (its own `d`, so the frequencies are the part's).
     Under GSPMD jit the model sees the GLOBAL sequence, so callers pass
     `arange(s)` (+ the KV-cache cursor when decoding); inside a hand-built
     shard_map over the sequence the caller must add its shard offset.
@@ -54,6 +58,11 @@ def apply_rope(
         raise ValueError(
             f"positions must be (s,) or (b, s), got ndim {positions.ndim}"
         )
+    if interleaved:
+        pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)

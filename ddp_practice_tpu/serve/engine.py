@@ -58,10 +58,12 @@ from ddp_practice_tpu.inference import (
 )
 from ddp_practice_tpu.serve.kv_pages import (
     GARBAGE_BLOCK,
+    LATENT_LEAF,
     BlockAllocator,
     RadixPrefixCache,
     copy_block,
     leaf_kind,
+    leaf_name,
     make_paged_cache,
     rewind_block_tail,
     scatter_prompt_blocks,
@@ -259,6 +261,11 @@ def warm_engine(engine, widths=None) -> None:
             engine.prefill_step(slot)
         engine.step_burst()
         engine.release(slot)
+        if getattr(engine, "radix", None) is not None:
+            # the warm-up prompt's blocks must not stay cached: the next
+            # width's prompt would match them and compile a NARROWER
+            # suffix bucket than its own, and no request wants them
+            engine.radix.clear()
     if getattr(engine, "drafter", None) is not None:
         # speculation on: the verify program is a THIRD compile that
         # must also land outside the timed/traced window. An all-ones
@@ -909,6 +916,10 @@ class PagedEngine(_EngineBase):
         # layers (every row of the batch is computed, retired slots' too)
         self.ssm_state_bytes = int(sum(
             a.nbytes for path, a in flat if leaf_kind(path) == "state"))
+        # bytes of the latent pools (gauge `latent_cache_bytes`): a model
+        # with latent attention caches one row a token, not K and V
+        self.latent_cache_bytes = int(sum(
+            a.nbytes for path, a in flat if leaf_name(path) == LATENT_LEAF))
         self._moe_layers = sum(
             1 for path, _ in flat if leaf_kind(path) == "stats")
         self._picks_a_step = (
@@ -982,7 +993,9 @@ class PagedEngine(_EngineBase):
         # prefix-mode suffix prefill (one compile per suffix bucket) and
         # the copy-on-write block split (one compile, ever) — both in
         # compile_stats so the churn pins cover the new admission paths
-        self._prefix_jit = jax.jit(self._prefix_prefill)
+        # (the pool is donated: a chunk rewrites a few pages of gigabytes)
+        self._prefix_jit = jax.jit(
+            self._prefix_prefill, donate_argnums=_decode_donate())
         self._cow_jit = jax.jit(
             copy_block, donate_argnums=_decode_donate(pool_argnum=0)
         )
@@ -1468,6 +1481,7 @@ class PagedEngine(_EngineBase):
             self._attn[slot] = 0
             self._pending_prompt[slot] = {
                 "prompt": [int(t) for t in prompt], "done": matched,
+                "hit": matched,
             }
             tr = self.tracer
             if tr is not None and tr.enabled:
@@ -1594,7 +1608,7 @@ class PagedEngine(_EngineBase):
         st = self._pending_prompt[slot]
         prompt = st["prompt"]
         p = len(prompt)
-        done = st["done"]
+        done = st["done"] = self._adopt_published(slot, prompt, st["done"])
         take = min(p - done, self.config.prefill_chunk)
         w = self.bucket_for(take)
         need = self._blocks_for(done + take)
@@ -1614,7 +1628,9 @@ class PagedEngine(_EngineBase):
             span, host, disp = self._prefill_spans(
                 "prefill_chunk", slot,
                 self._slot_trace.get(slot, f"slot{slot}"),
-                bucket=w, pos0=done, take=take)
+                bucket=w, pos0=done, take=take,
+                chunk=done // self.config.prefill_chunk,
+                prefix_hit=st["hit"])
         else:
             span = host = disp = _NULL
         with span:
@@ -1635,6 +1651,13 @@ class PagedEngine(_EngineBase):
         st["done"] = done
         self._len[slot] = done
         if done < p:
+            # publish what this chunk completed, so that a request with
+            # the same context admitted meanwhile does not prefill it
+            # again (it adopts the blocks: `_adopt_published`)
+            n_full = done // self.config.block_size
+            self.radix.insert(
+                prompt[:n_full * self.config.block_size],
+                [int(b) for b in self._pt[slot, :n_full]])
             return False
         # final chunk: the slot now looks exactly like a whole-prompt
         # prefix admission — publish, seed the drafter, go active
@@ -1652,6 +1675,28 @@ class PagedEngine(_EngineBase):
             self.drafter.begin(slot, [int(t) for t in prompt])
         self._active[slot] = True
         return True
+
+    def _adopt_published(self, slot: int, prompt: list, done: int) -> int:
+        """Blocks of `prompt` past `done` that another request has
+        published since this slot's last chunk join the slot's table
+        refcounted instead of being prefilled again; returns the new
+        `done`. At least one token is always left to prefill (the
+        radix's own clamp), and a block this slot has begun to write is
+        never replaced (`done` on a block boundary)."""
+        bs = self.config.block_size
+        have = int(self._nblk[slot])
+        if done % bs or have != done // bs:
+            return done
+        chain = self.radix.ref_prefix(prompt)   # pins the matched chain
+        self.blocks.free(chain[:have])          # the slot holds its own
+        ahead = chain[have:]
+        if not ahead:
+            return done
+        self._pt[slot, have:have + len(ahead)] = ahead
+        self._nblk[slot] = have + len(ahead)
+        self.radix.hit_tokens += len(ahead) * bs
+        self.radix.miss_tokens -= len(ahead) * bs
+        return done + len(ahead) * bs
 
     def fork(self, slot: int, *, seed: Optional[int] = None,
              trace_id: Optional[str] = None) -> int:
@@ -1809,12 +1854,16 @@ class PagedEngine(_EngineBase):
             # burst attends pages attn // bs .. (len + j) // bs of a slot
             act, bs = self._active, self.config.block_size
             last_page = (self._len[act][:, None] + np.arange(k)) // bs
+            walked = int(
+                (last_page - self._attn[act][:, None] // bs + 1).sum())
             span, disp, read = self._burst_spans(
                 "decode_burst", burst=k, blocks_grown=grown,
                 cow_splits=splits, blocks_free=self.blocks.num_free,
-                pages_walked=int(
-                    (last_page - self._attn[act][:, None] // bs + 1).sum()),
-                pages_held=int(self._nblk[act].sum()) * k)
+                pages_walked=walked,
+                pages_held=int(self._nblk[act].sum()) * k,
+                # every attention layer of a latent model walks latent rows
+                **({"latent_pages_walked": walked}
+                   if self.latent_cache_bytes else {}))
         else:
             span = disp = read = _NULL
         with span:

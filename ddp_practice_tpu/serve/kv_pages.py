@@ -14,7 +14,12 @@ resource with vLLM-style paging:
   pool — in-place TPU updates, ops/decode_attention.py); an int8 cache
   model (kv_cache_dtype="int8", models/vit.py) additionally pools its
   per-(head, position) fp32 scales as `(num_blocks, h, block_size)`
-  leaves — the per-BLOCK scale pages that halve KV bytes/token;
+  leaves — the per-BLOCK scale pages that halve KV bytes/token; a
+  latent-attention model (models/mla_lm.py) declares ONE leaf a layer,
+  `cached_latent`, a token's normalised latent and rotated key in one
+  row, pooled `(num_blocks, block_size, row)` like a K leaf (scatter,
+  copy-on-write and the radix cache are by the leaf's rank, not its
+  name);
 - each slot owns a host-side list of blocks plus a device-side PAGE
   TABLE row (`[max_slots, max_blocks_per_slot]` int32): position `p` of
   a slot lives in pool block `page_table[slot, p // block_size]` at row
@@ -433,12 +438,19 @@ class RadixPrefixCache:
 # vector a layer.
 STATE_LEAVES = ("ssm_state", "conv_state")
 STATS_LEAF = "moe_stats"
+# a latent-attention layer's one page leaf (models/mla_lm.py): pages like
+# any K or V leaf; named so that the engine can size its gauge
+LATENT_LEAF = "cached_latent"
+
+
+def leaf_name(path) -> str:
+    return str(getattr(path[-1], "key", path[-1]))
 
 
 def leaf_kind(path) -> str:
     """"state" | "stats" | "pages": which pool a cache leaf belongs to
     (scalars, the flat layout's cursors, are told by their rank)."""
-    name = str(getattr(path[-1], "key", path[-1]))
+    name = leaf_name(path)
     if name in STATE_LEAVES:
         return "state"
     return "stats" if name == STATS_LEAF else "pages"

@@ -69,6 +69,7 @@ class ServeMetrics:
         self.moe_rows_held = r.counter("moe_rows_held_total")
         self.moe_rows_routed = r.counter("moe_rows_routed_total")
         self.ssm_state_bytes = r.gauge("ssm_state_bytes")
+        self.latent_cache_bytes = r.gauge("latent_cache_bytes")
         self._last_held = self._last_routed = 0
         self.tokens_total = r.counter("serve_tokens_total")
         self.submitted = r.counter("serve_requests_submitted")
@@ -115,6 +116,7 @@ class ServeMetrics:
         self.moe_rows_routed.inc(routed - self._last_routed)
         self._last_held, self._last_routed = held, routed
         self.ssm_state_bytes.set(getattr(eng, "ssm_state_bytes", 0))
+        self.latent_cache_bytes.set(getattr(eng, "latent_cache_bytes", 0))
         drafted = getattr(eng, "spec_drafted_tokens", 0)
         accepted = getattr(eng, "spec_accepted_tokens", 0)
         self.spec_drafted.inc(drafted - self._last_drafted)

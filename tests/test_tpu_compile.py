@@ -266,6 +266,43 @@ def _moe_gmm(rows_a_tile):
                  _sds((tiles,), jnp.int32), _sds((1,), jnp.int32))
 
 
+def _paged_mla(page=64, slots=128, context=8960, pool_tokens=580_000):
+    """The latent-attention cell's decode step
+    (perf/configs/kanana2_30b_pp8.json): 32 absorbed query heads over ONE
+    640-lane row a token (512 latent + 64 rope + 64 of padding), read once
+    as key and value."""
+    from ddp_practice_tpu.ops.decode_attention import paged_decode_mla
+
+    def step(q, pool, table, lengths, start):
+        return paged_decode_mla(q, pool, table, lengths, start, v_lanes=512,
+                                sm_scale=192 ** -0.5)
+
+    return step, (_sds((slots, 32, 640)),
+                  _sds((1 + pool_tokens // page, page, 640)),
+                  _sds((slots, context // page), jnp.int32),
+                  _sds((slots,), jnp.int32), _sds((slots,), jnp.int32))
+
+
+def _moe_glu(rows_a_tile):
+    """ops/moe.py's gated expert kernel at the same cell's widths: 128
+    experts of 2048 -> 768 -> 2048, the three matrices of an expert
+    (9.4 MB) in VMEM two deep; a decode step's tiles of 16 rows and a
+    1024-token chunk's of 64."""
+    from ddp_practice_tpu.ops.moe import expert_glu_tiles
+
+    picks = {16: 128 * 6, 64: 1024 * 6}[rows_a_tile]
+    tiles = -(-picks // rows_a_tile) + 128
+
+    def mlp(rows, wg, wu, wd, tile_expert, used):
+        return expert_glu_tiles(rows, wg, wu, wd, tile_expert, used,
+                                tile=rows_a_tile)
+
+    return mlp, (_sds((tiles * rows_a_tile, 2048)),
+                 _sds((128, 2048, 768)), _sds((128, 2048, 768)),
+                 _sds((128, 768, 2048)),
+                 _sds((tiles,), jnp.int32), _sds((1,), jnp.int32))
+
+
 def _kernel_calls(text):
     """Names of the compiled Pallas custom calls, in program order."""
     return [ln.split("=")[0].strip().lstrip("%").split(".")[0]
@@ -278,6 +315,9 @@ KERNELS = {
     "hybrid_ssm_step": _ssm_step,
     "hybrid_moe_gmm_decode_tiles": functools.partial(_moe_gmm, 16),
     "hybrid_moe_gmm_prompt_tiles": functools.partial(_moe_gmm, 64),
+    "latent_paged_mla_page64": _paged_mla,
+    "latent_moe_glu_decode_tiles": functools.partial(_moe_glu, 16),
+    "latent_moe_glu_chunk_tiles": functools.partial(_moe_glu, 64),
     "flash_fwd": functools.partial(_flash, grad=False),
     "flash_fwd_bwd": functools.partial(_flash, grad=True),
     "flash_qkv_fwd_bwd": _flash_qkv,
@@ -328,6 +368,11 @@ def test_kernel_compiles_for_v5e(topo, name):
                 "hybrid_moe_g": "moe_gmm"}[name[:12]]
         calls = _kernel_calls(text)
         assert len(calls) == 1 and want in calls[0], calls
+    if name.startswith("latent"):
+        # the names perf/layer_metrics/flood_mla_*, flood_moe_glu_* sum by
+        want = "paged_decode_mla" if "mla" in name else "moe_gmm_glu"
+        calls = _kernel_calls(text)
+        assert len(calls) == 1 and calls[0].endswith(want), calls
 
 
 def test_flash_compiles_sharded_over_four_devices(topo):
