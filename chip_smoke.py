@@ -191,9 +191,9 @@ def train_phase(workdir: str, clock: CompileClock, *, seed: int,
     n_kernels = step_kernels(trainer)
     depth = trainer.model.depth
     if require_kernels:
-        check(n_kernels >= 3,
+        check(n_kernels >= 2,
               f"train step lowered with {n_kernels} Mosaic kernels: flash "
-              "attention was interpreted or replaced (want fwd, dk/dv, dq)")
+              "attention was interpreted or replaced (want fwd and bwd)")
     summary, losses, at = fit_and_read_losses(trainer, metrics)
     check(len(losses) == summary["steps"] and len(losses) >= 6,
           f"logged {len(losses)} losses for {summary['steps']} steps")
@@ -584,7 +584,7 @@ def mesh_phase(workdir: str, clock: CompileClock, *, seed: int,
               f"{name}: fc_in kernel {leaf.shape} in shards {shard_shapes}")
         n_kernels = step_kernels(trainer)
         if require_kernels:
-            check((n_kernels >= 3) == ("flash" in name),
+            check((n_kernels >= 2) == ("flash" in name),
                   f"{name}: step lowered with {n_kernels} Mosaic kernels")
         summary, losses, at = fit_and_read_losses(trainer, metrics)
         check(len(losses) == steps and all(map(math.isfinite, losses)),

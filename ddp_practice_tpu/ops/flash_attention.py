@@ -3,7 +3,7 @@
 The reference consumes fused CUDA kernels through torch (cuDNN/cuBLAS —
 SURVEY §2.2 "CUDA/cuDNN kernels"); the TPU-native analogue for the one op
 XLA doesn't already fuse optimally at long sequence length is a hand-tiled
-attention kernel. All three kernels are STREAMING: a 3D grid
+attention kernel. The kernels are STREAMING: a 3D grid
 (batch*heads, outer-block, inner-block) whose innermost dimension sweeps
 the contracted sequence axis while per-block state lives in VMEM scratch
 — so only one q tile and one k/v tile are VMEM-resident at any moment and
@@ -61,7 +61,13 @@ p = exp(s - lse) blockwise from the saved logsumexp:
     dQ kernel (grid bh x q-blocks x k-blocks, k innermost):
         dq += (ds @ k) * scale                          # scratch accum
 
-so training memory is O(seq) end to end. `flash_attention_with_lse`
+Both kernels recompute p and ds of every tile: seven dots and two exps a
+score. On the packed layout (below) the dKdV kernel takes dq along where a
+float32 accumulator for the WHOLE query sequence of a (batch row, head
+pack) fits VMEM (_ONE_KERNEL_BWD_VMEM: up to seq 6144 at two heads of 64
+in bf16), and the backward is ONE kernel of five dots and one exp; longer
+sequences, and the folded layout, run the two. Either way training memory
+is O(seq) end to end. `flash_attention_with_lse`
 additionally exposes lse as a differentiable output — the lse cotangent
 folds into delta (d lse/d s = p, so ds gains p*g_lse, i.e. delta -= g_lse)
 — which is what lets ring attention use this kernel as its per-block local
@@ -459,21 +465,21 @@ def _bwd_tiles(q_ref, do_ref, lse_ref, delta, k_ref, v_ref, heads, sm_scale,
             yield (rows, keys, lanes), qs, do, k, p, ds.astype(qs.dtype)
 
 
-def _dkdv_cell(dk_scr, dv_scr, *args):
-    """dk/dv of one grid cell, as _cell_strips' body."""
-    for (_, keys, lanes), qs, do, _, p, ds in _bwd_tiles(*args):
-        dv_scr[keys, lanes] = dv_scr[keys, lanes] + _dot_ta(
-            p.astype(do.dtype), do)                         # p^T @ do
-        dk_scr[keys, lanes] = dk_scr[keys, lanes] + _dot_ta(ds, qs)  # ds^T @ qs
-
-
-def _dq_cell(dq_scr, *args):
-    """dq of one grid cell, as _cell_strips' body."""
-    for (rows, _, lanes), _, _, k, _, ds in _bwd_tiles(*args):
-        dq_scr[rows, lanes] = dq_scr[rows, lanes] + lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),                # ds @ k
-            preferred_element_type=jnp.float32,
-        )
+def _bwd_cell(dq_scr, dk_scr, dv_scr, *args):
+    """The backward of one grid cell, as _cell_strips' body: a (strip,
+    head)'s p and ds, recomputed ONCE by _bwd_tiles, feed every
+    accumulator the kernel holds (None: another kernel's). dk_scr / dv_scr
+    cover the cell's K/V block, dq_scr its q block."""
+    for (rows, keys, lanes), qs, do, k, p, ds in _bwd_tiles(*args):
+        if dv_scr is not None:
+            dv_scr[keys, lanes] = dv_scr[keys, lanes] + _dot_ta(
+                p.astype(do.dtype), do)                     # p^T @ do
+            dk_scr[keys, lanes] = dk_scr[keys, lanes] + _dot_ta(ds, qs)  # ds^T @ qs
+        if dq_scr is not None:
+            dq_scr[rows, lanes] = dq_scr[rows, lanes] + lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),            # ds @ k
+                preferred_element_type=jnp.float32,
+            )
 
 
 def _dkdv_kernel(
@@ -495,7 +501,7 @@ def _dkdv_kernel(
         dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
 
     _cell_strips(
-        functools.partial(_dkdv_cell, dk_scr, dv_scr, q_ref, do_ref,
+        functools.partial(_bwd_cell, None, dk_scr, dv_scr, q_ref, do_ref,
                           lse_ref, lambda rows, lanes, hh: delta_ref[rows],
                           k_ref, v_ref, [(slice(None), 0)], sm_scale),
         qi, ki, block_q=block_q, block_k=block_k, causal=causal,
@@ -522,8 +528,8 @@ def _dq_kernel(
         dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
 
     _cell_strips(
-        functools.partial(_dq_cell, dq_scr, q_ref, do_ref, lse_ref,
-                          lambda rows, lanes, hh: delta_ref[rows],
+        functools.partial(_bwd_cell, dq_scr, None, None, q_ref, do_ref,
+                          lse_ref, lambda rows, lanes, hh: delta_ref[rows],
                           k_ref, v_ref, [(slice(None), 0)], sm_scale),
         qi, kj, block_q=block_q, block_k=block_k, causal=causal,
         seq_q=seq_q, seq_k=seq_k)
@@ -755,19 +761,39 @@ def _flash_fwd_packed(qf, kf, vf, *, n_heads, causal, block_q, block_k,
     return out, lse
 
 
-def _dkdv_kernel_packed(
-    q_ref, do_ref, out_ref, lse_ref, k_ref, v_ref, dk_ref, dv_ref,
-    dk_scr, dv_scr,
-    *, sm_scale, block_q, block_k, causal, seq_q, seq_k, hpc, d,
+def _bwd_kernel_packed(
+    q_ref, do_ref, out_ref, lse_ref, k_ref, v_ref, *refs,
+    sm_scale, block_q, block_k, causal, seq_q, seq_k, hpc, d,
 ):
+    """Packed grid cell (b, head-pack, k-block, q-block), q innermost:
+    dk/dv accumulate in (block_k, w) scratch across the q sweep and leave
+    at its last step. refs = (dk_ref, dv_ref, dk_scr, dv_scr) is the dk/dv
+    half of the two-kernel backward. refs = (dq_ref, dk_ref, dv_ref,
+    dq_scr, dk_scr, dv_scr) is the WHOLE backward in one kernel: the same
+    p and ds feed dq too, which no single grid step owns (every k-block
+    adds to every q block), so its float32 accumulator holds the whole
+    sequence of the (b, pack), (q-blocks, block_q, w), zeroed at the
+    pack's first cell and written at its last into an output block that
+    stays put over both inner grid axes. Five dots a (strip, head) where
+    the two kernels run 4 + 3, one exp of every score where they run two
+    (PERF.md section 6, PR 31)."""
+    if len(refs) == 6:
+        dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = refs
+    else:
+        (dk_ref, dv_ref, dk_scr, dv_scr), dq_ref, dq_scr = refs, None, None
     ki = pl.program_id(2)
     qi = pl.program_id(3)
-    n_q = pl.num_programs(3)
+    first_q, last_q = qi == 0, qi == pl.num_programs(3) - 1
 
-    @pl.when(qi == 0)
+    @pl.when(first_q)
     def _init():
         dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    if dq_ref is not None:
+        @pl.when(first_q & (ki == 0))
+        def _init_dq():
+            dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
 
     def delta(rows, lanes, hh):
         # delta = rowsum(do * o) for this head, recomputed in-register
@@ -781,16 +807,24 @@ def _dkdv_kernel_packed(
         )
 
     _cell_strips(
-        functools.partial(_dkdv_cell, dk_scr, dv_scr, q_ref, do_ref,
-                          lse_ref, delta, k_ref, v_ref,
-                          _packed_heads(hpc, d), sm_scale),
+        functools.partial(_bwd_cell,
+                          None if dq_scr is None else dq_scr.at[qi],
+                          dk_scr, dv_scr, q_ref, do_ref, lse_ref, delta,
+                          k_ref, v_ref, _packed_heads(hpc, d), sm_scale),
         qi, ki, block_q=block_q, block_k=block_k, causal=causal,
         seq_q=seq_q, seq_k=seq_k)
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(last_q)
     def _finalize():
         dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
+
+    if dq_ref is not None:
+        @pl.when(last_q & (ki == pl.num_programs(2) - 1))
+        def _finalize_dq():
+            for i in range(seq_q // block_q):
+                dq_ref[i * block_q:(i + 1) * block_q] = (
+                    dq_scr[i] * sm_scale).astype(dq_ref.dtype)
 
 
 def _dq_kernel_packed(
@@ -818,7 +852,7 @@ def _dq_kernel_packed(
 
     _cell_strips(
         functools.partial(
-            _dq_cell, dq_scr, q_ref, do_ref, lse_ref,
+            _bwd_cell, dq_scr, None, None, q_ref, do_ref, lse_ref,
             lambda rows, lanes, hh: delta_scr[rows, hh:hh + 1],
             k_ref, v_ref, _packed_heads(hpc, d), sm_scale),
         qi, kj, block_q=block_q, block_k=block_k, causal=causal,
@@ -829,15 +863,35 @@ def _dq_kernel_packed(
         dq_ref[:] = (dq_scr[:] * sm_scale).astype(dq_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=_PACKED_STATICS)
-def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
-                      block_q, block_k, interpret, fused_qkv=False):
-    """Packed grads. lse_pk: (b, n_packs, seq_q, hpc) fp32; out is the
-    saved forward output — delta (rowsum(do*o) per head) is computed
-    inside the kernels from do/out tiles whose DMAs ride the existing
-    block schedule. fused_qkv: as in _flash_fwd_packed (dq/dk/dv still
-    come back as three (b, s, h*d) arrays; the caller concatenates once
-    for the projection backward)."""
+# VMEM the packed backward's blocks (two deep) and scratch may take when
+# dq, dk and dv come from ONE kernel, whose dq accumulator and dq output
+# block hold the whole query sequence of a (batch row, head pack). Mosaic
+# adds a strip's score tiles to that (3.5 MiB at bf16, 4.4 at float32),
+# and the sum has to fit the 16 MiB a kernel is scoped. Where it does, one
+# kernel runs; past it the dk/dv kernel and the dq kernel with block-sized
+# state, as before PR 31. The shape decides, nothing else: at two heads of
+# 64 in bf16 under blocks (512, 1024) the line falls after seq_q 6144
+# (10.25 MiB held, 13.7 in all; the LM cells' 2048 hold 6.25 and are given
+# 9.7: tests/test_tpu_compile.py compiles both, and float32 at 2048, 14.4).
+_ONE_KERNEL_BWD_VMEM = 10.5 * 2**20
+
+
+def _one_kernel_bwd_vmem(seq_q, block_q, block_k, w, dtype):
+    """Bytes of _bwd_kernel_packed's blocks and scratch with dq aboard."""
+    two_deep = 2 * jnp.dtype(dtype).itemsize * w * (
+        3 * block_q          # q, do, out in
+        + 4 * block_k        # k, v in; dk, dv out
+        + seq_q)             # dq out
+    lse = 2 * 4 * block_q * _LANES
+    return two_deep + lse + 4 * w * (2 * block_k + seq_q)    # float32 scratch
+
+
+def _packed_bwd_calls(qf, kf, vf, do, out, lse_pk, *, one_kernel, n_heads,
+                      causal, block_q, block_k, interpret, fused_qkv):
+    """The packed backward as ONE pallas_call (`flash_bwd_packed`) or as
+    two (`flash_bwd_dkv_packed`, `flash_bwd_dq_packed`): the same tiles in
+    the same order either way. _flash_bwd_packed chooses; the tests hold
+    the two against each other (one_kernel None: from the shape)."""
     b, seq_q, hd = qf.shape
     if fused_qkv:
         hd //= 3
@@ -849,9 +903,16 @@ def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
     koff = n_packs if fused_qkv else 0
     voff = 2 * n_packs if fused_qkv else 0
     block_q, block_k = _check_blocks(seq_q, seq_k, block_q, block_k, causal)
-    sm_scale = 1.0 / (d ** 0.5)
+    n_q, n_k = seq_q // block_q, seq_k // block_k
+    if one_kernel is None:
+        one_kernel = _one_kernel_bwd_vmem(
+            seq_q, block_q, block_k, w, qf.dtype) <= _ONE_KERNEL_BWD_VMEM
     offset = seq_k - seq_q if causal else 0
     vis = _block_visible(block_q, block_k, offset)
+    statics = dict(sm_scale=1.0 / (d ** 0.5), block_q=block_q,
+                   block_k=block_k, causal=causal, seq_q=seq_q, seq_k=seq_k,
+                   hpc=hpc, d=d)
+    dq_shape = jax.ShapeDtypeStruct((b, seq_q, hd), qf.dtype)
 
     def qo_map(b_, g, j, i):
         return (b_, _redirect(causal, vis, i, j, i), g)
@@ -859,15 +920,14 @@ def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
     def stat_map_dkdv(b_, g, j, i):
         return (b_, g, _redirect(causal, vis, i, j, i), 0)
 
-    dkdv = functools.partial(
-        _dkdv_kernel_packed, sm_scale=sm_scale, block_q=block_q,
-        block_k=block_k, causal=causal, seq_q=seq_q, seq_k=seq_k,
-        hpc=hpc, d=d,
-    )
-    dk, dv = pl.pallas_call(
-        dkdv,
-        name="flash_bwd_dkv_packed",
-        grid=(b, n_packs, seq_k // block_k, seq_q // block_q),
+    kv_out = pl.BlockSpec((None, block_k, w), lambda b_, g, j, i: (b_, j, g))
+    # the whole dq of a (b, pack): the block index ignores both inner axes,
+    # so it is written back once, after the pack's last cell
+    dq_out = [pl.BlockSpec((None, seq_q, w), lambda b_, g, j, i: (b_, 0, g))]
+    grads = pl.pallas_call(
+        functools.partial(_bwd_kernel_packed, **statics),
+        name="flash_bwd_packed" if one_kernel else "flash_bwd_dkv_packed",
+        grid=(b, n_packs, n_k, n_q),
         in_specs=[
             pl.BlockSpec((None, block_q, w), qo_map),
             pl.BlockSpec((None, block_q, w), qo_map),
@@ -878,24 +938,27 @@ def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
             pl.BlockSpec((None, block_k, w),
                          lambda b_, g, j, i: (b_, j, g + voff)),
         ],
-        out_specs=[
-            pl.BlockSpec((None, block_k, w), lambda b_, g, j, i: (b_, j, g)),
-            pl.BlockSpec((None, block_k, w), lambda b_, g, j, i: (b_, j, g)),
-        ],
-        out_shape=[
+        out_specs=dq_out * one_kernel + [kv_out, kv_out],
+        out_shape=[dq_shape] * one_kernel + [
             jax.ShapeDtypeStruct((b, seq_k, hd), kf.dtype),
             jax.ShapeDtypeStruct((b, seq_k, hd), vf.dtype),
         ],
         scratch_shapes=[
+            pltpu.VMEM((n_q, block_q, w), jnp.float32)] * one_kernel + [
             pltpu.VMEM((block_k, w), jnp.float32),
             pltpu.VMEM((block_k, w), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
+            # dq is carried over the k-blocks too when it rides along
+            dimension_semantics=("parallel", "parallel",
+                                 "arbitrary" if one_kernel else "parallel",
                                  "arbitrary")
         ),
         interpret=interpret,
     )(qf, do, out, lse_pk, kf, vf)
+    if one_kernel:
+        return tuple(grads)
+    dk, dv = grads
 
     def k_map(b_, g, i, j):
         return (b_, _redirect(causal, vis, i, j, j), g + koff)
@@ -903,15 +966,10 @@ def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
     def v_map(b_, g, i, j):
         return (b_, _redirect(causal, vis, i, j, j), g + voff)
 
-    dqk = functools.partial(
-        _dq_kernel_packed, sm_scale=sm_scale, block_q=block_q,
-        block_k=block_k, causal=causal, seq_q=seq_q, seq_k=seq_k,
-        hpc=hpc, d=d,
-    )
     dq = pl.pallas_call(
-        dqk,
+        functools.partial(_dq_kernel_packed, **statics),
         name="flash_bwd_dq_packed",
-        grid=(b, n_packs, seq_q // block_q, seq_k // block_k),
+        grid=(b, n_packs, n_q, n_k),
         in_specs=[
             pl.BlockSpec((None, block_q, w), lambda b_, g, i, j: (b_, i, g)),
             pl.BlockSpec((None, block_q, w), lambda b_, g, i, j: (b_, i, g)),
@@ -923,7 +981,7 @@ def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
         ],
         out_specs=pl.BlockSpec((None, block_q, w),
                                lambda b_, g, i, j: (b_, i, g)),
-        out_shape=jax.ShapeDtypeStruct((b, seq_q, hd), qf.dtype),
+        out_shape=dq_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, w), jnp.float32),
             pltpu.VMEM((block_q, hpc), jnp.float32),
@@ -935,6 +993,22 @@ def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
         interpret=interpret,
     )(qf, do, out, lse_pk, kf, vf)
     return dq, dk, dv
+
+
+@functools.partial(jax.jit, static_argnames=_PACKED_STATICS)
+def _flash_bwd_packed(qf, kf, vf, do, out, lse_pk, *, n_heads, causal,
+                      block_q, block_k, interpret, fused_qkv=False):
+    """Packed grads. lse_pk: (b, n_packs, seq_q, hpc) fp32; out is the
+    saved forward output — delta (rowsum(do*o) per head) is computed
+    inside the kernels from do/out tiles whose DMAs ride the existing
+    block schedule. fused_qkv: as in _flash_fwd_packed (dq/dk/dv still
+    come back as three (b, s, h*d) arrays; the caller concatenates once
+    for the projection backward). One kernel where its whole-sequence dq
+    fits VMEM (_ONE_KERNEL_BWD_VMEM), two past that."""
+    return _packed_bwd_calls(
+        qf, kf, vf, do, out, lse_pk, one_kernel=None, n_heads=n_heads,
+        causal=causal, block_q=block_q, block_k=block_k, interpret=interpret,
+        fused_qkv=fused_qkv)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

@@ -9,6 +9,9 @@ scans, min of 5 reps, scalar-readback fenced.
         # _attention, the streaming kernels, the whole-sequence kernels over
         # G, and the sweep over sequence length that set SHORT_SEQ_MIN
         # (PERF.md section 6, PR 29)
+    python experiments/flash_time.py bwd        # the packed backward alone at
+        # the LM cells' shape: the two kernels (dq: 3 dots a tile, dk/dv: 4)
+        # beside the one that makes all three from 5 (PERF.md section 5, PR 31)
 """
 import functools
 import json
@@ -157,6 +160,61 @@ def vit(only=(), out="chiprun_out/flash_time_vit.json"):
         json.dump(rows, f, indent=1)
 
 
+def bwd(out_path="chiprun_out/flash_time_bwd.json", b=8, s=2048, h=12, d=64):
+    """The packed streaming backward a layer at the LM cells' shape (b 8,
+    s 2048, 12 heads of 64, causal, bf16), chained over the cotangent:
+    dq alone and dk/dv alone (XLA drops the call whose results nobody
+    reads), the two together, and the one kernel that replaced them."""
+    from ddp_practice_tpu.ops import flash_attention as fa
+
+    hd = h * d
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v, do = (jax.random.normal(kk, (b, s, hd), jnp.bfloat16)
+                   for kk in keys)
+    kw = dict(n_heads=h, causal=True, block_q=512, block_k=1024,
+              interpret=fa._interpret())
+    out, lse = fa._flash_fwd_packed(q, k, v, **kw)
+
+    def grads(do, one_kernel):
+        return fa._packed_bwd_calls(q, k, v, do, out, lse, fused_qkv=False,
+                                    one_kernel=one_kernel, **kw)
+
+    def keep(first, *rest):
+        # the chain's carry is one gradient; an element of each other one
+        # is written into it in place, so its kernel is not dropped
+        for x in rest:
+            first = lax.dynamic_update_slice(first, x[:1, :1, :1], (0, 0, 0))
+        return first
+
+    cases = {
+        "dq_3dots": (3, lambda g: grads(g, False)[0]),
+        "dkdv_4dots": (4, lambda g: keep(*grads(g, False)[1:])),
+        "two_kernels_7dots": (7, lambda g: keep(*grads(g, False))),
+        "one_kernel_5dots": (5, lambda g: keep(*grads(g, True))),
+    }
+    executed, useful = fa.causal_tile_counts(s, s)
+    rows = []
+    # both sides' results, once, before any timing
+    one, two = jax.jit(lambda g: (grads(g, True), grads(g, False)))(do)
+    for name, x, y in zip(("dq", "dk", "dv"), one, two):
+        diff = jnp.abs(x.astype(jnp.float32) - y.astype(jnp.float32)).max()
+        rows.append({"grad": name, "max_abs_diff_one_vs_two": float(diff)})
+        print(json.dumps(rows[-1]), flush=True)
+    for name, (dots, fn) in cases.items():
+        ms = timed1(fn, do)
+        # dots that hold work: 2*s*s*d a head over the unmasked scores;
+        # executed: over the 256-wide sub-tiles the schedule runs
+        fl = dots * 2.0 * b * h * d * 256 * 256
+        rows.append({
+            "case": name, "ms": round(ms, 4),
+            "useful_tflops": round(fl * useful / ms / 1e9, 2),
+            "executed_tflops": round(fl * executed / ms / 1e9, 2)})
+        print(json.dumps(rows[-1]), flush=True)
+    os.makedirs(os.path.dirname(out_path), exist_ok=True)
+    with open(out_path, "w") as f:
+        json.dump(rows, f, indent=1)
+
+
 def main():
     from ddp_practice_tpu.ops.flash_attention import flash_attention_with_lse
     from jax.experimental.pallas.ops.tpu.flash_attention import (
@@ -211,4 +269,9 @@ def main():
 
 
 if __name__ == "__main__":
-    vit(sys.argv[2:]) if sys.argv[1:2] == ["vit"] else main()
+    if sys.argv[1:2] == ["vit"]:
+        vit(sys.argv[2:])
+    elif sys.argv[1:2] == ["bwd"]:
+        bwd()
+    else:
+        main()

@@ -442,6 +442,143 @@ def test_causal_subtiles_match_dense(path, shape):
 
 
 # ---------------------------------------------------------------------
+# The packed backward as ONE kernel (PR 31): dq, dk and dv from a single
+# recomputation of p and ds, where a whole-sequence dq accumulator fits
+# VMEM; the dk/dv kernel and the dq kernel past that.
+# ---------------------------------------------------------------------
+
+def _eqns(jaxpr):
+    """Every equation under `jaxpr`, those of nested jaxprs (jit, cond
+    branches, a pallas_call's kernel) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for inner in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(inner, "jaxpr", inner)
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def _pallas_calls(jaxpr) -> dict:
+    """{name: kernel jaxpr} of every pallas_call under `jaxpr`."""
+    return {e.params["name"]: e.params["jaxpr"] for e in _eqns(jaxpr)
+            if e.primitive.name == "pallas_call"}
+
+
+# (seq_q, seq_k, block_q, block_k, heads, causal, fused qkv input?)
+_ONE_KERNEL_CASES = {
+    # skipped, crossed and bare sub-tiles, masked trailing columns
+    "causal": (1024, 1024, 512, 1024, 2, True, False),
+    "noncausal": (512, 512, 256, 256, 2, False, False),
+    # seq_q != seq_k: the causal offset, a whole K/V sub-tile
+    "cross": (512, 1024, 256, 512, 2, True, False),
+    # offset 128 under 256-row strips: the penalty on PART of a strip's
+    # keys (_mask_tail's concatenate), two crossed sub-tiles a strip
+    "cross_odd_tail": (512, 640, 512, 1024, 2, True, False),
+    "cross_noncausal": (256, 512, 128, 256, 2, False, False),
+    "qkv_causal": (768, 768, 512, 512, 2, True, True),
+    "qkv_noncausal": (512, 512, 256, 512, 2, False, True),
+    # the LM cells' twelve heads: six packs, the qkv windows at 0 / 6 / 12
+    "12_heads": (512, 512, 256, 256, 12, True, False),
+    "12_heads_qkv": (256, 256, 128, 256, 12, True, True),
+    # 192 holds no lane-aligned sub-tile: the cell whole, one q block
+    "whole_192": (192, 192, 512, 1024, 2, True, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ONE_KERNEL_CASES))
+def test_one_backward_kernel_matches_the_two_and_dense(case):
+    """dq, dk, dv of the one kernel: EQUAL to the two kernels' (the same
+    tiles accumulated in the same order) and within float32 rounding of
+    XLA's gradient of dense attention."""
+    from ddp_practice_tpu.ops import flash_attention as fa
+
+    seq_q, seq_k, block_q, block_k, h, causal, fused = _ONE_KERNEL_CASES[case]
+    b, d = 1, 64
+    rng = np.random.default_rng(41)
+    mk = lambda s: jnp.asarray(rng.normal(size=(b, s, h * d)), jnp.float32)
+    q, k, v, do = mk(seq_q), mk(seq_k), mk(seq_k), mk(seq_q)
+    kw = dict(n_heads=h, causal=causal, block_q=block_q, block_k=block_k,
+              interpret=True, fused_qkv=fused)
+    flat = (jnp.concatenate([q, k, v], axis=-1),) * 3 if fused else (q, k, v)
+    out, lse = fa._flash_fwd_packed(*flat, **kw)
+    one = fa._packed_bwd_calls(*flat, do, out, lse, one_kernel=True, **kw)
+    two = fa._packed_bwd_calls(*flat, do, out, lse, one_kernel=False, **kw)
+    heads = lambda x: x.reshape(b, -1, h, d)
+    _, vjp = jax.vjp(lambda *a: _attention(*a, causal=causal),
+                     heads(q), heads(k), heads(v))
+    for got, same, want in zip(one, two, vjp(heads(do))):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(same))
+        np.testing.assert_allclose(np.asarray(heads(got)), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("seq,heads,d,dtype,kernels", [
+    # the LM cells' shape, and the longest bf16 sequence on the near side
+    (2048, 2, 64, jnp.bfloat16, 1),
+    (6144, 2, 64, jnp.bfloat16, 1),
+    # past the rule: a whole-sequence dq would not leave the tiles room
+    (7168, 2, 64, jnp.bfloat16, 2),
+    (16384, 2, 64, jnp.bfloat16, 2),
+    # wider elements and wider packs cross the line sooner
+    (2048, 2, 64, jnp.float32, 1),
+    (4096, 2, 64, jnp.float32, 2),
+    (1024, 1, 256, jnp.bfloat16, 1),
+    (2048, 1, 256, jnp.bfloat16, 2),
+])
+@pytest.mark.parametrize("entry", ["packed", "qkv"])
+def test_the_shape_alone_picks_one_backward_kernel_or_two(
+        entry, seq, heads, d, dtype, kernels):
+    """No option names the backward: `_ONE_KERNEL_BWD_VMEM` against the
+    shape does, the same for both packed entries (traced, not run;
+    tests/test_tpu_compile.py asks the TPU compiler about these shapes)."""
+    from ddp_practice_tpu.ops import flash_attention as fa
+
+    if entry == "qkv":
+        loss = lambda x: fa.flash_attention_qkv(x, heads, causal=True).astype(
+            jnp.float32).sum()
+        x = jnp.zeros((1, seq, 3 * heads * d), dtype)
+    else:
+        loss = lambda x: fa.flash_attention(x, x, x, causal=True).astype(
+            jnp.float32).sum()
+        x = jnp.zeros((1, seq, heads, d), dtype)
+    names = sorted(_pallas_calls(jax.make_jaxpr(jax.grad(loss))(x).jaxpr))
+    want = {1: ["flash_bwd_packed"],
+            2: ["flash_bwd_dkv_packed", "flash_bwd_dq_packed"]}[kernels]
+    assert names == want + ["flash_fwd_packed"]
+    w = fa._heads_per_pack(heads, d) * d
+    held = fa._one_kernel_bwd_vmem(seq, 512, 1024, w, dtype)
+    assert (held <= fa._ONE_KERNEL_BWD_VMEM) == (kernels == 1), held
+
+
+def test_one_backward_kernel_runs_five_dots_where_two_ran_seven():
+    """A (strip, head) of the one kernel: s, dp, dv, dk, dq, and one exp;
+    the dk/dv kernel runs four of them and the dq kernel three, each with
+    an exp of its own. Counted in the kernels' jaxprs, non-causal (one
+    strip a cell) over a pack of two heads."""
+    from ddp_practice_tpu.ops import flash_attention as fa
+
+    x = jnp.zeros((1, 256, 128), jnp.float32)
+    lse = jnp.zeros((1, 1, 256, 2), jnp.float32)
+
+    def calls(one_kernel):
+        return _pallas_calls(jax.make_jaxpr(lambda *a: fa._packed_bwd_calls(
+            *a, one_kernel=one_kernel, n_heads=2, causal=False, block_q=256,
+            block_k=256, interpret=True, fused_qkv=False))(
+                x, x, x, x, x, lse).jaxpr)
+
+    def count(kernel):
+        ops = [e.primitive.name for e in _eqns(kernel)]
+        return ops.count("dot_general") // 2, ops.count("exp") // 2
+
+    assert {n: count(j) for n, j in calls(True).items()} == {
+        "flash_bwd_packed": (5, 1)}
+    assert {n: count(j) for n, j in calls(False).items()} == {
+        "flash_bwd_dkv_packed": (4, 1), "flash_bwd_dq_packed": (3, 1)}
+
+
+# ---------------------------------------------------------------------
 # The whole-sequence kernels for short sequences (PR 29): a grid cell is
 # G images x one 128-lane head pack, plain softmax, ONE backward kernel.
 # ---------------------------------------------------------------------

@@ -546,12 +546,15 @@ def test_the_paged_decode_kernel_is_one_op_named_paged_decode(pool):
     assert len(names) == 1 and "paged_decode" in names[0], names
 
 
-@pytest.mark.parametrize("heads,variant", [(2, "_packed"), (3, "")])
-def test_every_flash_kernel_carries_a_flash_name(heads, variant):
+@pytest.mark.parametrize("heads,names", [
+    (2, ("flash_fwd_packed", "flash_bwd_packed")),
+    (3, ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"))])
+def test_every_flash_kernel_carries_a_flash_name(heads, names):
     """`name=` is what the compiled custom call is named by (and so the
     op on a device trace's "XLA Ops" line, under shard_map too:
-    tests/test_tpu_compile.py compiles it): forward and both backward
-    kernels, packed (heads pair up in 128 lanes) and folded."""
+    tests/test_tpu_compile.py compiles it): the forward and the backward,
+    packed (heads pair up in 128 lanes: ONE backward kernel since PR 31)
+    and folded (two)."""
     import jax
     import jax.numpy as jnp
 
@@ -563,10 +566,9 @@ def test_every_flash_kernel_carries_a_flash_name(heads, variant):
         return flash_attention(q, k, v, causal=True).astype(
             jnp.float32).sum()
 
-    names = pallas_names(
+    got = pallas_names(
         jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x).jaxpr)
-    assert sorted(names) == sorted(
-        n + variant for n in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"))
+    assert sorted(got) == sorted(names)
 
 
 def test_no_pallas_call_in_flash_attention_goes_unnamed():

@@ -117,6 +117,20 @@ def _flash_qkv():
     return jax.grad(loss), (_sds((B, S, 3 * HD)),)
 
 
+def _flash_bwd(batch, seq=S, dtype=BF16, one_kernel=None):
+    """The packed backward alone, as the LM cells' rope path calls it."""
+    from ddp_practice_tpu.ops.flash_attention import _packed_bwd_calls
+
+    def bwd(q, k, v, do, out, lse):
+        return _packed_bwd_calls(
+            q, k, v, do, out, lse, one_kernel=one_kernel, n_heads=H,
+            causal=True, block_q=512, block_k=1024, interpret=False,
+            fused_qkv=False)
+
+    x = _sds((batch, seq, HD), dtype)
+    return bwd, (x,) * 5 + (_sds((batch, H // 2, seq, 2), jnp.float32),)
+
+
 def _flash_short(batch=128, seq=196):
     """ViT-B/16's attention core a layer (perf/configs/vit_b16.json under
     perf/traffic/vit_224_b128.json): 128 images of 196 patches, 12 heads
@@ -310,6 +324,16 @@ def _kernel_calls(text):
             if "custom-call(" in ln and "tpu_custom_call" in ln]
 
 
+def _scoped_vmem(text):
+    """{kernel name: bytes of scoped VMEM the compiler gave it}."""
+    return {
+        re.search(r"%(\w+?)(\.\d+)? = ", ln).group(1): int(re.search(
+            r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+            r'"offset":"0","size":"(\d+)"', ln).group(1))
+        for ln in text.splitlines()
+        if "custom-call(" in ln and "tpu_custom_call" in ln}
+
+
 KERNELS = {
     "hybrid_paged_grouped_32q_2kv": _paged_grouped,
     "hybrid_ssm_step": _ssm_step,
@@ -321,6 +345,16 @@ KERNELS = {
     "flash_fwd": functools.partial(_flash, grad=False),
     "flash_fwd_bwd": functools.partial(_flash, grad=True),
     "flash_qkv_fwd_bwd": _flash_qkv,
+    # the one-kernel backward (PR 31) at the LM cells' shape, at what a
+    # device of the four-chip cell gets, and at the ends of its shape
+    # rule: the longest bf16 sequence it takes, and float32
+    "flash_bwd_one_kernel_cell": functools.partial(_flash_bwd, B),
+    "flash_bwd_one_kernel_dp4_share": functools.partial(_flash_bwd, B // 4),
+    "flash_bwd_one_kernel_longest": functools.partial(_flash_bwd, 1, 6144),
+    "flash_bwd_one_kernel_float32": functools.partial(
+        _flash_bwd, 1, dtype=jnp.float32),
+    # past the rule the two kernels run; ONE kernel there is refused
+    "flash_bwd_two_kernels_s16384": functools.partial(_flash_bwd, 1, 16384),
     "flash_short_fwd_bwd": _flash_short,
     "flash_short_fwd_bwd_longest": lambda: _flash_short(
         8, __import__("ddp_practice_tpu.ops.flash_attention", fromlist=["x"]
@@ -355,6 +389,14 @@ KERNELS = {
 def test_kernel_compiles_for_v5e(topo, name):
     fn, args = KERNELS[name]()
     text = _compile(fn, *args, device=topo.devices[0])
+    if name.startswith("flash_bwd"):
+        # a kernel is scoped 16 MiB of VMEM by default and no flag raises
+        # it: the whole-sequence dq and the strip's score tiles fit
+        vmem = _scoped_vmem(text)
+        want = (["flash_bwd_packed"] if "one_kernel" in name else
+                ["flash_bwd_dkv_packed", "flash_bwd_dq_packed"])
+        assert sorted(vmem) == want, vmem
+        assert max(vmem.values()) <= 16 * 2**20, vmem
     if name.startswith("paged"):
         # ONE device op a call, named by the kernel's `name=`:
         # perf/lib/readers.py sums every traced op whose name holds
@@ -373,6 +415,14 @@ def test_kernel_compiles_for_v5e(topo, name):
         want = "paged_decode_mla" if "mla" in name else "moe_gmm_glu"
         calls = _kernel_calls(text)
         assert len(calls) == 1 and calls[0].endswith(want), calls
+
+
+def test_one_backward_kernel_past_its_rule_is_refused(topo):
+    """What `_ONE_KERNEL_BWD_VMEM` keeps from the compiler: at 16,384
+    positions the whole-sequence dq alone is 16 MiB of VMEM."""
+    fn, args = _flash_bwd(1, 16384, one_kernel=True)
+    with pytest.raises(Exception, match="vmem"):
+        _compile(fn, *args, device=topo.devices[0])
 
 
 def test_flash_compiles_sharded_over_four_devices(topo):
@@ -398,15 +448,14 @@ def test_flash_compiles_sharded_over_four_devices(topo):
         x, x, x).compile().as_text()
     calls = [ln for ln in text.splitlines()
              if "custom-call(" in ln and "tpu_custom_call" in ln]
-    assert len(calls) == 3, len(calls)  # fwd, dk/dv, dq
+    assert len(calls) == 2, len(calls)  # fwd, and ONE backward
     # the compiled custom calls are named by the kernels' `name=`, not by
     # the island around them ("shard_map"): a device trace's "XLA Ops"
     # line shows these names, and perf/layer_metrics/train_flash_dev_pct
     # reads them
     named = sorted(ln.split("=")[0].strip().lstrip("%").split(".")[0]
                    for ln in calls)
-    assert named == ["flash_bwd_dkv_packed", "flash_bwd_dq_packed",
-                     "flash_fwd_packed"], named
+    assert named == ["flash_bwd_packed", "flash_fwd_packed"], named
     per_device = f"bf16[{B // 4},{S},{HD}]"
     assert all(per_device in ln for ln in calls), calls[0][:300]
     assert "all-gather" not in text
@@ -457,13 +506,14 @@ def test_vit_attention_takes_the_short_kernels_unasked(topo):
 
 
 def test_lm_attention_keeps_the_streaming_kernels(topo):
-    """The LM cells' block (8 x 2048, causal, "flash" named): exactly the
-    three packed streaming kernels, as before PR 29; and with nothing
-    named, 2048 is past the short kernels' range: plain XLA, no kernel."""
+    """The LM cells' block (8 x 2048, causal, "flash" named): the packed
+    streaming forward and, since PR 31, ONE backward kernel, not the
+    short kernels; and with nothing named, 2048 is past the short
+    kernels' range: plain XLA, no kernel."""
     text = _attention_grad(topo.devices[0], batch=B, seq=S, causal=True,
                            attn_impl="flash")
     assert sorted(_kernel_calls(text)) == [
-        "flash_bwd_dkv_packed", "flash_bwd_dq_packed", "flash_fwd_packed"]
+        "flash_bwd_packed", "flash_fwd_packed"]
     from ddp_practice_tpu.models.vit import SelfAttention
 
     assert SelfAttention(num_heads=H, causal=True).resolve_attn_impl(
@@ -578,7 +628,7 @@ def test_lm_base_flash_step_compiles_on_the_mesh(topo, layout, pos_emb):
     ).compile().as_text()
     calls = [ln for ln in text.splitlines()
              if "custom-call(" in ln and "tpu_custom_call" in ln]
-    assert len(calls) == 6, len(calls)  # (fwd, dk/dv, dq) x 2 layers
+    assert len(calls) == 4, len(calls)  # (fwd, ONE backward) x 2 layers
     dp, tp = mesh.shape["data"], mesh.shape["tensor"]
     width = (3 * HD if pos_emb == "learned" else HD) // tp
     per_device = f"bf16[{B // dp},{S},{width}]"
