@@ -214,6 +214,22 @@ def _nemotron_h(*, num_classes, policy, axis_name, **kw):
     )
 
 
+@register("jamba")
+def _jamba(*, num_classes, policy, axis_name, **kw):
+    # the same HybridLM in Jamba's layout: a layer is a mixer sub-layer
+    # (Mamba-1 'S' or attention '*') and a dense gated MLP 'D', the head
+    # tied to the embedding; test-sized defaults, the published widths come
+    # as options (perf/families/jamba.py model_options)
+    kw.setdefault("pattern", "SD*DSD")
+    kw.setdefault("tie_embeddings", True)
+    kw.setdefault("norm_eps", 1e-6)
+    return HybridLM(
+        dtype=policy.compute_dtype,
+        param_dtype=policy.param_dtype,
+        **kw,
+    )
+
+
 @register("deepseek_v3")
 def _deepseek_v3(*, num_classes, policy, axis_name, **kw):
     # multi-head latent attention + gated experts after leading dense

@@ -1,23 +1,34 @@
-"""A decoder of mixed layers: Mamba-2 mixers, latent expert layers and
-grouped-query attention, one mixer a layer, chosen by a pattern string.
+"""A decoder of mixed layers: state-space mixers, expert layers, dense
+gated MLPs and grouped-query attention, one residual sub-layer a letter of
+a pattern string.
 
-    x = x + mixer_i(RMSNorm(x))        i over `pattern`
+    x = x + f_i(RMSNorm(x))            i over `pattern`
     'M'  Mamba-2 mixer                 (ops/ssm.py)
+    'S'  Mamba-1 (selective scan) mixer: a decay for every channel and
+         state, a low-rank dt, RMSNorm on dt, B and C (Jamba's)
     'E'  LatentMoE, a chip's share of the experts held  (ops/moe.py)
+    'D'  dense SwiGLU MLP of width `mlp_dim`            (ops/moe.py GatedMLP)
     '*'  causal attention, `kv_heads` <= `num_heads`, no positional
          embedding: the recurrent layers carry position
-    logits = RMSNorm(x) W_head         untied, over `vocab_size` rows
+    logits = RMSNorm(x) W_head         over `vocab_size` rows; with
+                                       `tie_embeddings` W_head is the
+                                       embedding itself
 
-The Nemotron-H layout (`create_model("nemotron_h", ...)`); the widths are
-options, so the tests run it small and the benchmark at the published
-sizes (perf/configs/nemotron3_super_ep4.json).
+Two layouts in the registry. Nemotron-H (`create_model("nemotron_h", ...)`):
+one mixer a layer from 'M', 'E', '*', untied head. Jamba
+(`create_model("jamba", ...)`): a layer is two sub-layers, a mixer ('S' or
+'*') then 'D', so 28 layers are 56 letters, and the head is tied. The widths
+are options, so the tests run both small and the benchmark at the published
+sizes (perf/configs/nemotron3_super_ep4.json, perf/configs/jamba2_3b.json).
 
 Decode mode keeps TWO kinds of cache in the "cache" collection: attention
 layers the K/V leaves `SelfAttention` declares (flat, or pages under
-`PagedEngine`), Mamba layers a fixed-size state a sequence, `ssm_state`
-(b, heads, head_dim, state) in float32 and `conv_state`, the conv's last
+`PagedEngine`), state-space layers a fixed-size state a sequence under the
+SAME two leaf names whichever mixer: `ssm_state` in float32 (Mamba-2
+(b, heads, head_dim, state); Mamba-1 (b, state, channels / 128, 128), the
+layout `ops/ssm.py sel_step` reads) and `conv_state`, the conv's last
 `conv_kernel - 1` inputs. A call with one token a sequence advances the
-state by the recurrence; a call with more runs the chunked scan FROM the
+state by the recurrence; a call with more runs the scan FROM the
 stored state (zeros for a fresh sequence) and leaves the final state.
 Left padding (`attn_start`) moves neither: a padded position has dt = 0
 and a zero conv input. Pages cannot re-derive a state, so what needs a
@@ -36,7 +47,7 @@ import jax.numpy as jnp
 
 from ddp_practice_tpu.models.vit import SelfAttention
 from ddp_practice_tpu.ops import ssm
-from ddp_practice_tpu.ops.moe import LatentMoE
+from ddp_practice_tpu.ops.moe import GatedMLP, LatentMoE
 
 
 class RMSNorm(nn.Module):
@@ -56,6 +67,19 @@ class RMSNorm(nn.Module):
             jnp.mean(jnp.square(shaped), axis=-1, keepdims=True) + self.eps)
         return (shaped.reshape(x.shape) * scale.astype(jnp.float32)
                 ).astype(self.dtype)
+
+
+def _state_leaves(module, state0, tail0, several_paged: bool):
+    """The two cache leaves every state-space mixer declares, under the
+    names `serve/kv_pages.py STATE_LEAVES` pools a slot."""
+    if several_paged:
+        raise ValueError(
+            "a recurrent layer cannot take several tokens at slot-"
+            "local positions through pages: its state holds only "
+            "the sequence's end (prefix reuse, chunked prefill and "
+            "speculative verify need state snapshots)")
+    return (module.variable("cache", "ssm_state", lambda: state0),
+            module.variable("cache", "conv_state", lambda: tail0))
 
 
 class Mamba2Mixer(nn.Module):
@@ -98,16 +122,8 @@ class Mamba2Mixer(nn.Module):
         state0 = jnp.zeros((b, h, p, n), jnp.float32)
         tail0 = jnp.zeros((b, self.conv_kernel - 1, conv_dim), self.dtype)
         if decode:
-            if paged and s > 1:
-                raise ValueError(
-                    "a recurrent layer cannot take several tokens at slot-"
-                    "local positions through pages: its state holds only "
-                    "the sequence's end (prefix reuse, chunked prefill and "
-                    "speculative verify need state snapshots)")
-            ssm_state = self.variable(
-                "cache", "ssm_state", lambda: state0)
-            conv_state = self.variable(
-                "cache", "conv_state", lambda: tail0)
+            ssm_state, conv_state = _state_leaves(
+                self, state0, tail0, paged and s > 1)
             if not self.is_initializing():
                 state0, tail0 = ssm_state.value, conv_state.value
         xbc, tail = ssm.causal_conv(xbc, tail0, conv_w, conv_b)
@@ -132,6 +148,71 @@ class Mamba2Mixer(nn.Module):
                         param_dtype=self.param_dtype, name="out_proj")(y)
 
 
+class Mamba1Mixer(nn.Module):
+    """[u, z] = x W_in; u = silu(conv(u) + b); [r, B, C] = u W_x, each
+    RMSNormed; dt = softplus(r W_dt + b_dt); the selective scan over
+    (channel, state) with A = -exp(A_log); out = (y * silu(z)) W_out."""
+
+    inner: int
+    state_size: int
+    dt_rank: int
+    conv_kernel: int = 4
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, *, decode: bool = False, attn_start=None,
+                 paged: bool = False):
+        b, s, d = x.shape
+        c, n, r = self.inner, self.state_size, self.dt_rank
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        vec = lambda name, shape: self.param(
+            name, nn.initializers.normal(0.02), shape, self.param_dtype)
+        norm = lambda name: RMSNorm(self.norm_eps, name=name, **kw)
+        u, z = jnp.split(
+            nn.Dense(2 * c, use_bias=False, name="in_proj", **kw)(x), 2,
+            axis=-1)
+        conv_w = vec("conv_kernel", (self.conv_kernel, c))
+        conv_b = vec("conv_bias", (c,))
+        a = -jnp.exp(vec("A_log", (c, n)).astype(jnp.float32))
+        d_skip = vec("D", (c,))
+        real = None
+        if attn_start is not None and s > 1:
+            # a call of several tokens is a padded row from its position 0
+            # (the engines' prefill); a single token is always real
+            real = (jnp.arange(s)[None, :] >= attn_start[:, None])[..., None]
+            u = jnp.where(real, u, 0)
+        state0 = jnp.zeros(ssm.sel_state_shape(b, c, n), jnp.float32)
+        tail0 = jnp.zeros((b, self.conv_kernel - 1, c), self.dtype)
+        if decode:
+            ssm_state, conv_state = _state_leaves(
+                self, state0, tail0, paged and s > 1)
+            if not self.is_initializing():
+                state0, tail0 = ssm_state.value, conv_state.value
+        u, tail = ssm.causal_conv(u, tail0, conv_w, conv_b)
+        u = nn.silu(u)
+        low, bm, cm = jnp.split(
+            nn.Dense(r + 2 * n, use_bias=False, name="x_proj", **kw)(u),
+            [r, r + n], axis=-1)
+        bm, cm = norm("b_norm")(bm), norm("c_norm")(cm)
+        dt = jax.nn.softplus(nn.Dense(c, name="dt_proj", **kw)(
+            norm("dt_norm")(low)).astype(jnp.float32))
+        if real is not None:
+            dt = jnp.where(real, dt, 0.0)
+        if decode and s == 1 and not self.is_initializing():
+            y, state = ssm.sel_step(u[:, 0], dt[:, 0], a, bm[:, 0],
+                                    cm[:, 0], d_skip, state0)
+            y = y[:, None]
+        else:
+            y, state = ssm.sel_scan(u, dt, a, bm, cm, d_skip, state0)
+        if decode and not self.is_initializing():
+            ssm_state.value = state
+            conv_state.value = tail.astype(conv_state.value.dtype)
+        y = y.astype(self.dtype) * nn.silu(z)
+        return nn.Dense(d, use_bias=False, name="out_proj", **kw)(y)
+
+
 class HybridLM(nn.Module):
     pattern: str = "MEM*EME"
     vocab_size: int = 256
@@ -144,6 +225,11 @@ class HybridLM(nn.Module):
     ssm_groups: int = 2
     conv_kernel: int = 4
     chunk_size: int = 128
+    # 'S'
+    mamba_inner: int = 128
+    dt_rank: int = 8
+    # 'D'
+    mlp_dim: int = 128
     # '*'
     num_heads: int = 4
     kv_heads: int = 2
@@ -158,6 +244,7 @@ class HybridLM(nn.Module):
     expert_offset: int = 0
     routed_scaling: float = 1.0
     norm_eps: float = 1e-5
+    tie_embeddings: bool = False
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
     # what the serving engines ask a model: how positions enter (here the
@@ -174,9 +261,10 @@ class HybridLM(nn.Module):
         the compute dtype. `decode`, `attn_start`, `page_table` and
         `kv_lengths` as in models/lm.py TransformerLM."""
         del train
-        if set(self.pattern) - set("ME*") or not self.pattern:
+        if set(self.pattern) - set("MSED*") or not self.pattern:
             raise ValueError(
-                f"pattern {self.pattern!r}: want a string of 'M', 'E', '*'")
+                f"pattern {self.pattern!r}: want a string of 'M', 'S', 'E', "
+                "'D', '*'")
         if self.hidden_dim != self.num_heads * self.head_dim:
             raise ValueError(
                 "SelfAttention takes its head size from the width: "
@@ -188,8 +276,9 @@ class HybridLM(nn.Module):
             raise ValueError(
                 f"sequence {tokens.shape[1]} exceeds max_len {self.max_len}")
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
-        x = nn.Embed(self.vocab_size, self.hidden_dim, name="tok_embed",
-                     **kw)(tokens)
+        embed = nn.Embed(self.vocab_size, self.hidden_dim, name="tok_embed",
+                         **kw)
+        x = embed(tokens)
         for i, kind in enumerate(self.pattern):
             y = RMSNorm(self.norm_eps, name=f"norm{i}", **kw)(x)
             if kind == "M":
@@ -199,6 +288,14 @@ class HybridLM(nn.Module):
                     self.norm_eps, name=f"mamba{i}", **kw,
                 )(y, decode=decode, attn_start=attn_start,
                   paged=page_table is not None)
+            elif kind == "S":
+                y = Mamba1Mixer(
+                    self.mamba_inner, self.ssm_state, self.dt_rank,
+                    self.conv_kernel, self.norm_eps, name=f"mamba{i}", **kw,
+                )(y, decode=decode, attn_start=attn_start,
+                  paged=page_table is not None)
+            elif kind == "D":
+                y = GatedMLP(self.mlp_dim, name=f"mlp{i}", **kw)(y)
             elif kind == "E":
                 y = LatentMoE(
                     self.num_experts, self.top_k, self.latent_dim,
@@ -215,5 +312,7 @@ class HybridLM(nn.Module):
                   page_table=page_table, kv_lengths=kv_lengths)
             x = x + y
         x = RMSNorm(self.norm_eps, name="norm_f", **kw)(x)
+        if self.tie_embeddings:
+            return embed.attend(x)
         return nn.Dense(self.vocab_size, use_bias=False, name="lm_head",
                         **kw)(x)

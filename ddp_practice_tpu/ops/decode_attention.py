@@ -374,6 +374,11 @@ _CHUNK_TOKENS = 128
 _CHUNK_VMEM_BYTES = 8 << 20
 
 
+# Rows a group of query heads is padded to a multiple of: a float32 sublane
+# tile (the scores and the softmax state are float32; 20 heads -> 24 rows)
+_GROUP_ROWS = 8
+
+
 def _pages_per_chunk(block_size: int, hd_total: int, dtype,
                      tokens: int = _CHUNK_TOKENS) -> int:
     """Pages P the walk fetches and computes at a time: enough for
@@ -727,10 +732,10 @@ def paged_decode_attention(
     if group == 1:
         packable = _heads_per_pack(n_heads, d) is not None and bs % 8 == 0
     else:
-        # the group's query heads are the sublane rows of a matmul and a
-        # KV head's lanes a whole-tile slice of the chunk; no int8 pool
-        packable = (d % _LANES == 0 and group % 8 == 0 and bs % 8 == 0
-                    and not quant)
+        # the group's query heads are the sublane rows of a matmul (padded
+        # to whole sublane tiles where the group is no multiple of 8) and
+        # a KV head's lanes a whole-tile slice of the chunk; no int8 pool
+        packable = d % _LANES == 0 and bs % 8 == 0 and not quant
     if impl == "reference" or (impl == "auto" and (
             not packable or not backend.on_tpu())):
         return paged_attention_reference(
@@ -792,13 +797,21 @@ def paged_decode_attention(
     pages = _pages_per_chunk(bs, kv_total, k_pages.dtype)
     if group > 1:
         # q and out as (heads, d) blocks: a free reshape out here, and in
-        # the kernel a KV head's query heads are then whole rows
-        q = q.reshape(b, n_heads, d)
-        q_spec = pl.BlockSpec((None, n_heads, d), lambda b_, *_: (b_, 0, 0))
-        out_shape = jax.ShapeDtypeStruct((b, n_heads, d), q.dtype)
+        # the kernel a KV head's query heads are then whole rows. A group
+        # that is no multiple of 8 (20 query heads on one KV head) is
+        # padded with zero rows: they cost MXU rows the pass had spare,
+        # score nothing but zeros and are cut from the output below
+        rows = -(-group // _GROUP_ROWS) * _GROUP_ROWS
+        q = q.reshape(b, kvh, group, d)
+        if rows != group:
+            q = jnp.pad(q, ((0, 0), (0, 0), (0, rows - group), (0, 0)))
+        q = q.reshape(b, kvh * rows, d)
+        q_spec = pl.BlockSpec((None, kvh * rows, d),
+                              lambda b_, *_: (b_, 0, 0))
+        out_shape = jax.ShapeDtypeStruct((b, kvh * rows, d), q.dtype)
         kernel = functools.partial(
             _paged_walk_kernel, sm_scale=sm_scale, block_size=bs,
-            pages=pages, d=d, rows=group, kv_heads=kvh,
+            pages=pages, d=d, rows=rows, kv_heads=kvh,
         )
     else:
         kernel = functools.partial(
@@ -829,6 +842,8 @@ def paged_decode_attention(
         interpret=interpret,
         name="paged_decode",
     )(lens, start, pt, q, k_pages, v_pages)
+    if group > 1 and rows != group:
+        out = out.reshape(b, kvh, rows, d)[:, :, :group]
     return out.reshape(b, 1, hd_total)
 
 

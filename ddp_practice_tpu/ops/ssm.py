@@ -1,5 +1,8 @@
-"""Mamba-2 (state-space duality) mixer arithmetic: a prompt's chunked scan
-and the one-token recurrence of decode.
+"""State-space mixer arithmetic: a prompt's scan and the one-token recurrence
+of decode, for Mamba-2 (one decay a head) and Mamba-1 (one a channel and
+state: the second half of this file).
+
+Mamba-2 (state-space duality) first.
 
 One recurrence, two forms. Per head (head size p, state size n, the head's
 group supplying B and C):
@@ -20,7 +23,8 @@ term vanishes. That is how left padding and the tail of a partial chunk
 are made invisible (models/hybrid_lm.py masks `dt` and the conv input).
 
 Device op names (PERF.md section 3): the kernel is `ssm_step`; the scan is
-XLA ops under `jax.named_scope("ssm_scan")`.
+XLA ops under `jax.named_scope("ssm_scan")`; Mamba-1's two kernels are
+`sel_step` and `sel_scan`.
 """
 
 from __future__ import annotations
@@ -206,3 +210,256 @@ def ssm_step_kernel(x, dt, a, b_mat, c_mat, d_skip, state):
       state.reshape(bsz, g, hg, p, n))
     y = y.reshape(bsz, h, p) + x * d_skip.astype(f32)[None, :, None]
     return y, new.reshape(bsz, h, p, n)
+
+
+# ------------------------------------------------ Mamba-1: selective scan
+# The decay differs for every (channel, state) pair:
+#
+#     h_t[c, n] = exp(dt_t[c] A[c, n]) h_{t-1}[c, n] + dt_t[c] u_t[c] B_t[n]
+#     y_t[c]    = sum_n h_t[c, n] C_t[n] + D[c] u_t[c]
+#
+# so no matmul form carries it: both kernels are element-wise over the
+# state, the exponential computed inside (a decay tensor in HBM would
+# double the state's traffic). A sequence's state is laid out
+# (n, channels / 128, 128): for one n, a block of 1024 channels is ONE
+# dense (8, 128) register, B_t[n] and C_t[n] are scalars (SMEM), and the
+# sum over n is a tree of multiply-adds between whole registers: no
+# broadcast along lanes, no reduction across sublanes, no masked store.
+# dt, u and y take the same (channels / 128, 128) layout a position.
+
+_LANES = 128
+_SUBLANES = 8
+# time steps a grid cell of the scan carries the state through
+SEL_CHUNK = 128
+# steps of the time loop in one basic block: Mosaic overlaps the loads, the
+# exponentials and the multiply-add chain inside a block, not across the
+# steps of a loop (PERF.md section 6, PR 27)
+_SEL_UNROLL = 4
+# positions one call of the scan kernel takes
+_SEL_CALL_TOKENS = 2048
+# most slots a grid cell of the decode kernel takes (2 MB of state in, 2 MB
+# out, two deep: half of the 16 MiB a kernel gets)
+_SEL_SLOTS = 32
+
+
+def sel_state_shape(batch: int, channels: int, state: int) -> tuple:
+    """Shape of the float32 state `sel_step` / `sel_scan` carry."""
+    if channels % _LANES:
+        raise ValueError(
+            f"the selective scan lays {channels} channels out in rows of "
+            f"{_LANES}: want a multiple")
+    return (batch, state, channels // _LANES, _LANES)
+
+
+def sel_step_reference(u, dt, a, b_mat, c_mat, d_skip, state):
+    """One token, plain jax.numpy. u, dt (b, c); a (c, n) negative; b_mat,
+    c_mat (b, n); d_skip (c,); state as `sel_state_shape`. Returns
+    (y (b, c) float32, new state)."""
+    f32 = jnp.float32
+    bsz, ch = u.shape
+    u, dt = u.astype(f32), dt.astype(f32)
+    h = state.reshape(bsz, -1, ch)                       # (b, n, c)
+    decay = jnp.exp(dt[:, None, :] * a.astype(f32).T[None])
+    h = decay * h + (dt * u)[:, None, :] * b_mat.astype(f32)[:, :, None]
+    y = jnp.sum(h * c_mat.astype(f32)[:, :, None], axis=1)
+    return y + u * d_skip.astype(f32), h.reshape(state.shape)
+
+
+def sel_scan_reference(u, dt, a, b_mat, c_mat, d_skip, h0):
+    """The recurrence one position at a time (`lax.scan`). u, dt (b, l, c);
+    b_mat, c_mat (b, l, n); h0 as `sel_state_shape`. Returns (y (b, l, c)
+    float32, final state)."""
+    def one(state, inp):
+        y_t, state = sel_step_reference(
+            inp[0], inp[1], a, inp[2], inp[3], d_skip, state)
+        return state, y_t
+
+    xs = tuple(jnp.moveaxis(v.astype(jnp.float32), 1, 0)
+               for v in (u, dt, b_mat, c_mat))
+    final, ys = lax.scan(one, h0.astype(jnp.float32), xs)
+    return jnp.moveaxis(ys, 0, 1), final
+
+
+def _sel_update(h, dt, dtu, a, b_of, c_of):
+    """One position of one channel block. h, a: a tile a state index n;
+    dt, dtu: the block's tile of dt and dt * u; b_of(n), c_of(n): scalars.
+    Returns (new h tiles, y tile)."""
+    new = [jnp.exp(dt * a[i]) * h[i] + dtu * b_of(i) for i in range(len(h))]
+    terms = [new[i] * c_of(i) for i in range(len(h))]
+    while len(terms) > 1:   # a tree, not a chain of len(h) dependent adds
+        terms = [terms[i] + terms[i + 1] if i + 1 < len(terms) else terms[i]
+                 for i in range(0, len(terms), 2)]
+    return new, terms[0]
+
+
+def _sel_step_kernel(b_ref, c_ref, dt_ref, u_ref, a_ref, h_ref, y_ref,
+                     ho_ref, *, slots, n):
+    """Grid (channel blocks, slot blocks): `slots` sequences' state of one
+    channel block, each read once and written once, in place."""
+    base = pl.program_id(1) * slots
+    a = [a_ref[i] for i in range(n)]
+
+    def one(s, carry):
+        dt = dt_ref[s]
+        row = (base + s) * n
+        new, y = _sel_update(
+            [h_ref[s, i] for i in range(n)], dt, dt * u_ref[s], a,
+            lambda i: b_ref[row + i], lambda i: c_ref[row + i])
+        for i in range(n):
+            ho_ref[s, i] = new[i]
+        y_ref[s] = y
+        return carry
+
+    lax.fori_loop(0, slots, one, 0)
+
+
+def _sel_scan_kernel(b_ref, c_ref, dt_ref, u_ref, a_ref, h0_ref, y_ref,
+                     ho_ref, *, chunk, n):
+    """Grid (sequences, channel blocks, time chunks), time innermost and in
+    order: the block's state stays in VMEM from chunk to chunk (`ho_ref`,
+    whose block index ignores the time axis, so it goes to HBM once, after
+    the last chunk) and in registers inside one, seeded from `h0_ref`."""
+    k = pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _seed():
+        ho_ref[...] = h0_ref[...]
+
+    a = [a_ref[i] for i in range(n)]
+
+    def some(g, h):
+        for j in range(_SEL_UNROLL):
+            t = g * _SEL_UNROLL + j
+            dt = dt_ref[t]
+            h, y = _sel_update(
+                h, dt, dt * u_ref[t], a,
+                lambda i: b_ref[k, t * n + i], lambda i: c_ref[k, t * n + i])
+            y_ref[t] = y
+        return tuple(h)
+
+    h = lax.fori_loop(0, chunk // _SEL_UNROLL, some,
+                      tuple(ho_ref[i] for i in range(n)))
+    for i in range(n):
+        ho_ref[i] = h[i]
+
+
+def _sel_rows(channels: int) -> tuple:
+    """(rows of 128 channels, rows a channel block)."""
+    rows = channels // _LANES
+    return rows, (_SUBLANES if rows % _SUBLANES == 0 else rows)
+
+
+def _sel_a(a, rows: int):
+    """a (c, n) -> (n, rows, 128), the state's layout."""
+    return a.astype(jnp.float32).T.reshape(a.shape[1], rows, _LANES)
+
+
+def sel_step(u, dt, a, b_mat, c_mat, d_skip, state):
+    """One token of the recurrence for every sequence; arguments and results
+    as `sel_step_reference`: the Pallas kernel on the TPU, plain jax.numpy
+    elsewhere."""
+    if not backend.on_tpu():
+        with jax.named_scope("sel_step"):
+            return sel_step_reference(u, dt, a, b_mat, c_mat, d_skip, state)
+    return sel_step_kernel(u, dt, a, b_mat, c_mat, d_skip, state)
+
+
+@jax.jit
+def sel_step_kernel(u, dt, a, b_mat, c_mat, d_skip, state):
+    """`sel_step` as ONE device op of that name (interpret mode off the
+    TPU, where only the tests call it)."""
+    f32 = jnp.float32
+    bsz, ch = u.shape
+    n = a.shape[1]
+    rows, sub = _sel_rows(ch)
+    slots = next(s for s in range(min(_SEL_SLOTS, bsz), 0, -1)
+                 if bsz % s == 0)
+    u, dt = u.astype(f32), dt.astype(f32)
+    tile = lambda v: v.reshape(bsz, rows, _LANES)
+    vec = pl.BlockSpec((slots, sub, _LANES), lambda j, i, *_: (i, j, 0))
+    st = pl.BlockSpec((slots, n, sub, _LANES), lambda j, i, *_: (i, 0, j, 0))
+    y, new = pl.pallas_call(
+        functools.partial(_sel_step_kernel, slots=slots, n=n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,          # B and C: 2 x b x n scalars
+            grid=(rows // sub, bsz // slots),
+            in_specs=[vec, vec,
+                      pl.BlockSpec((n, sub, _LANES),
+                                   lambda j, i, *_: (0, j, 0)),
+                      st],
+            out_specs=[vec, st],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((bsz, rows, _LANES), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        input_output_aliases={5: 1},     # the state is rewritten in place
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=not backend.on_tpu(),
+        name="sel_step",
+    )(b_mat.astype(f32).reshape(-1), c_mat.astype(f32).reshape(-1),
+      tile(dt), tile(u), _sel_a(a, rows), state)
+    return y.reshape(bsz, ch) + u * d_skip.astype(f32), new
+
+
+def sel_scan(u, dt, a, b_mat, c_mat, d_skip, h0):
+    """The recurrence over a whole call of several tokens, FROM `h0`;
+    arguments and results as `sel_scan_reference`: the Pallas kernel on the
+    TPU, the sequential `lax.scan` elsewhere. A position with dt == 0
+    leaves the state as it was."""
+    if not backend.on_tpu():
+        with jax.named_scope("sel_scan"):
+            return sel_scan_reference(u, dt, a, b_mat, c_mat, d_skip, h0)
+    # a call's B and C stand whole in SMEM (128 B a position): a longer
+    # sequence goes through the kernel a stretch at a time
+    ys, state = [], h0
+    for s in range(0, u.shape[1], _SEL_CALL_TOKENS):
+        cut = slice(s, s + _SEL_CALL_TOKENS)
+        y, state = sel_scan_kernel(u[:, cut], dt[:, cut], a, b_mat[:, cut],
+                                   c_mat[:, cut], d_skip, state)
+        ys.append(y)
+    return (ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=1)), state
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def sel_scan_kernel(u, dt, a, b_mat, c_mat, d_skip, h0, *,
+                    chunk: int = SEL_CHUNK):
+    """`sel_scan` as ONE device op of that name (interpret mode off the
+    TPU, where only the tests call it)."""
+    f32 = jnp.float32
+    bsz, l, ch = u.shape
+    n = a.shape[1]
+    rows, sub = _sel_rows(ch)
+    q = min(chunk, -(-l // _SEL_UNROLL) * _SEL_UNROLL)
+    pad = -l % q
+    u, dt, b_mat, c_mat = (v.astype(f32) for v in (u, dt, b_mat, c_mat))
+    ins = (u, dt, b_mat, c_mat)
+    if pad:  # dt = 0 there: the state does not move
+        ins = tuple(jnp.pad(v, ((0, 0), (0, pad), (0, 0))) for v in ins)
+    nc = (l + pad) // q
+    up, dtp, bp, cp = ins
+    tile = lambda v: v.reshape(bsz, nc * q, rows, _LANES)
+    scal = lambda v: v.reshape(bsz, nc, q * n)
+    vec = pl.BlockSpec((None, q, sub, _LANES), lambda i, j, k: (i, k, j, 0))
+    # a sequence's B and C whole (a block in SMEM obeys the (8, 128) rule
+    # too, so a chunk's share cannot be cut out): 8 B x n a position
+    smem = pl.BlockSpec((None, nc, q * n), lambda i, j, k: (i, 0, 0),
+                        memory_space=pltpu.SMEM)
+    st = pl.BlockSpec((None, n, sub, _LANES), lambda i, j, k: (i, 0, j, 0))
+    y, final = pl.pallas_call(
+        functools.partial(_sel_scan_kernel, chunk=q, n=n),
+        grid=(bsz, rows // sub, nc),
+        in_specs=[smem, smem, vec, vec,
+                  pl.BlockSpec((n, sub, _LANES), lambda i, j, k: (0, j, 0)),
+                  st],
+        out_specs=[vec, st],
+        out_shape=[jax.ShapeDtypeStruct((bsz, nc * q, rows, _LANES), f32),
+                   jax.ShapeDtypeStruct(h0.shape, f32)],
+        input_output_aliases={5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=not backend.on_tpu(),
+        name="sel_scan",
+    )(scal(bp), scal(cp), tile(dtp), tile(up), _sel_a(a, rows),
+      h0.astype(f32))
+    y = y.reshape(bsz, nc * q, ch)[:, :l]
+    return y + u * d_skip.astype(f32), final

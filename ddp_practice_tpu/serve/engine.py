@@ -285,6 +285,8 @@ def warm_engine(engine, widths=None) -> None:
     if getattr(engine, "moe_rows_routed", 0):
         # warmup picks belong to no request either
         engine.moe_rows_held = engine.moe_rows_routed = 0
+    if getattr(engine, "ssm_scan_tokens", 0):
+        engine.ssm_scan_tokens = engine.ssm_scan_padded_tokens = 0
     engine.reset_epoch()
 
 
@@ -926,6 +928,10 @@ class PagedEngine(_EngineBase):
             s * int(getattr(model, "top_k", 0)) * self._moe_layers)
         self.moe_rows_held = 0       # cumulative (metrics export)
         self.moe_rows_routed = 0
+        # positions the recurrent layers' prefill scans ran over: the
+        # prompts' own, and their buckets' left padding beside them
+        self.ssm_scan_tokens = 0
+        self.ssm_scan_padded_tokens = 0
         self._last_logits = jnp.zeros((s, model.vocab_size), model.dtype)
         self._keys = jnp.zeros((s, 2), jnp.uint32)
         self._active = np.zeros((s,), bool)
@@ -1517,9 +1523,16 @@ class PagedEngine(_EngineBase):
             self._slot_trace[slot] = tid
             span, host, disp = self._prefill_spans(
                 "prefill", slot, tid, bucket=w, prompt_len=p,
-                blocks=n_table, prefix_hit=matched)
+                blocks=n_table, prefix_hit=matched,
+                # positions a recurrent model's scans advance the state
+                # over, and the bucket's padding they run over besides
+                **({"scan_tokens": p, "scan_padded": w - p}
+                   if self._recurrent else {}))
         else:
             span = host = disp = _NULL
+        if self._recurrent:
+            self.ssm_scan_tokens += p
+            self.ssm_scan_padded_tokens += w - p
         if self.radix is None:
             # plain path, unchanged since PR 3: LEFT-padded scratch
             # prefill + block scatter

@@ -71,6 +71,11 @@ class ServeMetrics:
         self.ssm_state_bytes = r.gauge("ssm_state_bytes")
         self.latent_cache_bytes = r.gauge("latent_cache_bytes")
         self._last_held = self._last_routed = 0
+        # positions the recurrent layers' prefill scans ran over: real
+        # prompt tokens, and the buckets' padding beside them
+        self.ssm_scan_tokens = r.counter("ssm_scan_tokens_total")
+        self.ssm_scan_padded = r.counter("ssm_scan_padded_tokens_total")
+        self._last_scan = self._last_scan_padded = 0
         self.tokens_total = r.counter("serve_tokens_total")
         self.submitted = r.counter("serve_requests_submitted")
 
@@ -115,6 +120,11 @@ class ServeMetrics:
         self.moe_rows_held.inc(held - self._last_held)
         self.moe_rows_routed.inc(routed - self._last_routed)
         self._last_held, self._last_routed = held, routed
+        scan = getattr(eng, "ssm_scan_tokens", 0)
+        padded = getattr(eng, "ssm_scan_padded_tokens", 0)
+        self.ssm_scan_tokens.inc(scan - self._last_scan)
+        self.ssm_scan_padded.inc(padded - self._last_scan_padded)
+        self._last_scan, self._last_scan_padded = scan, padded
         self.ssm_state_bytes.set(getattr(eng, "ssm_state_bytes", 0))
         self.latent_cache_bytes.set(getattr(eng, "latent_cache_bytes", 0))
         drafted = getattr(eng, "spec_drafted_tokens", 0)

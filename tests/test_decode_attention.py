@@ -431,3 +431,80 @@ def test_paged_walk_waits_for_what_it_reads(case, monkeypatch):
     monkeypatch.setattr(pl, "pallas_call", on_wait)
     test_paged_walk_matches_reference(case)
     assert not interpret_pallas_call.races.races_found
+
+
+# --------------------------------------------- groups of any size (PR 32)
+# Grouped queries whose group is no multiple of 8 (20 query heads on ONE KV
+# head) used to fall to the gather reference under "auto"; the walk pads the
+# group's rows to whole sublane tiles and cuts the pad rows from the output.
+ANY_GROUP = {
+    # name: (query heads, KV heads, rows a KV head inside the kernel)
+    "group_20_one_kv_head": (20, 1, 24),
+    "group_5_two_kv_heads": (10, 2, 8),
+    "group_3_four_kv_heads": (12, 4, 8),
+    "group_16_unchanged": (16, 1, 16),
+    "group_32_unchanged": (64, 2, 32),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", sorted(ANY_GROUP))
+def test_paged_walk_takes_a_group_of_any_size(case, dtype):
+    from ddp_practice_tpu.ops.decode_attention import (
+        paged_attention_reference,
+        paged_decode_attention,
+    )
+
+    heads, kvh, rows = ANY_GROUP[case]
+    lengths, start = [3, 17, 100, 191, 64], [0, 5, 33, 0, 64]
+    b, d, bs, mb = len(lengths), 128, 16, 12
+    nb = 1 + b * mb
+    rng = np.random.default_rng(heads)
+    q = jnp.asarray(rng.normal(size=(b, 1, heads * d)), dtype)
+    kp = jnp.asarray(rng.normal(size=(nb, bs, kvh * d)), dtype)
+    vp = jnp.asarray(rng.normal(size=(nb, bs, kvh * d)), dtype)
+    pt = jnp.asarray(rng.permutation(nb - 1)[:b * mb].reshape(b, mb) + 1,
+                     jnp.int32)
+    args = (q, kp, vp, pt, jnp.asarray(lengths, jnp.int32),
+            jnp.asarray(start, jnp.int32))
+    kw = dict(n_heads=heads, n_kv_heads=kvh)
+    want = paged_attention_reference(*args, **kw)
+    walk = lambda *a: paged_decode_attention(*a, **kw, impl="kernel")
+    # bf16: an ulp of outputs that reach 2 (chip_smoke.py's bound)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(walk(*args), np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+    # ONE kernel, on `rows` query rows a KV head; a group that was a
+    # multiple of 8 already is handed over as it was (no pad, no slice)
+    text = str(jax.make_jaxpr(walk)(*args))
+    assert text.count("pallas_call") == 1
+    assert "name=paged_decode" in text.replace(" ", "")
+    assert f"[{b},{kvh * rows},{d}]" in text.replace(" ", "")
+    assert ("pad" in text) == (rows * kvh != heads)
+
+
+def test_latent_walk_of_32_rows_is_unchanged():
+    """The absorbed latent walk (32 query rows on one 640-lane row a
+    token) shares the kernel body and takes no padding."""
+    from ddp_practice_tpu.ops.decode_attention import (
+        paged_decode_mla,
+        paged_mla_reference,
+    )
+
+    rng = np.random.default_rng(32)
+    lengths, start = [3, 100, 191], [0, 33, 0]
+    b, heads, w, v, bs, mb = 3, 32, 640, 512, 16, 12
+    nb = 1 + b * mb
+    q = jnp.asarray(rng.normal(size=(b, heads, w)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(nb, bs, w)), jnp.float32)
+    pt = jnp.asarray(rng.permutation(nb - 1)[:b * mb].reshape(b, mb) + 1,
+                     jnp.int32)
+    args = (q, pool, pt, jnp.asarray(lengths, jnp.int32),
+            jnp.asarray(start, jnp.int32))
+    kw = dict(v_lanes=v, sm_scale=192 ** -0.5)
+    walk = lambda *a: paged_decode_mla(*a, **kw, impl="kernel")
+    np.testing.assert_allclose(walk(*args), paged_mla_reference(*args, **kw),
+                               atol=2e-5, rtol=2e-5)
+    text = str(jax.make_jaxpr(walk)(*args))
+    assert text.count("pallas_call") == 1 and "pad" not in text

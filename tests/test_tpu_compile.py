@@ -317,6 +317,38 @@ def _moe_glu(rows_a_tile):
                  _sds((tiles,), jnp.int32), _sds((1,), jnp.int32))
 
 
+def _sel(what, slots=256, length=1024):
+    """ops/ssm.py's Mamba-1 kernels at the Jamba cell's widths
+    (perf/configs/jamba2_3b.json): 5120 channels over a state of 16, a
+    sequence's float32 state (16, 40, 128) rewritten in place; the decode
+    step over 256 slots, a prompt's scan over the widest bucket."""
+    from ddp_practice_tpu.ops import ssm
+
+    f32 = jnp.float32
+    c, n = 5120, 16
+    lead = (slots,) if what == "step" else (1, length)
+    b = lead[0]
+    return (ssm.sel_step if what == "step" else ssm.sel_scan), (
+        _sds(lead + (c,)), _sds(lead + (c,), f32), _sds((c, n), f32),
+        _sds(lead + (n,)), _sds(lead + (n,)), _sds((c,)),
+        _sds(ssm.sel_state_shape(b, c, n), f32))
+
+
+def _paged_group20(slots=256, blocks_per_slot=48, page=64):
+    """The Jamba cell's attention: 20 query heads on ONE KV head of 128,
+    pages of 64 tokens 128 lanes wide; the group's rows padded to 24."""
+    from ddp_practice_tpu.ops.decode_attention import paged_decode_attention
+
+    def step(q, k, v, table, lengths, start):
+        return paged_decode_attention(
+            q, k, v, table, lengths, start, n_heads=20, n_kv_heads=1)
+
+    pool = _sds((1 + slots * blocks_per_slot, page, 128))
+    return step, (_sds((slots, 1, 20 * 128)), pool, pool,
+                  _sds((slots, blocks_per_slot), jnp.int32),
+                  _sds((slots,), jnp.int32), _sds((slots,), jnp.int32))
+
+
 def _kernel_calls(text):
     """Names of the compiled Pallas custom calls, in program order."""
     return [ln.split("=")[0].strip().lstrip("%").split(".")[0]
@@ -339,6 +371,10 @@ KERNELS = {
     "hybrid_ssm_step": _ssm_step,
     "hybrid_moe_gmm_decode_tiles": functools.partial(_moe_gmm, 16),
     "hybrid_moe_gmm_prompt_tiles": functools.partial(_moe_gmm, 64),
+    "jamba_sel_step_256_slots": functools.partial(_sel, "step"),
+    "jamba_sel_scan_1024": functools.partial(_sel, "scan"),
+    "jamba_sel_scan_128": functools.partial(_sel, "scan", length=128),
+    "jamba_paged_group20_page64": _paged_group20,
     "latent_paged_mla_page64": _paged_mla,
     "latent_moe_glu_decode_tiles": functools.partial(_moe_glu, 16),
     "latent_moe_glu_chunk_tiles": functools.partial(_moe_glu, 64),
@@ -410,6 +446,13 @@ def test_kernel_compiles_for_v5e(topo, name):
                 "hybrid_moe_g": "moe_gmm"}[name[:12]]
         calls = _kernel_calls(text)
         assert len(calls) == 1 and want in calls[0], calls
+    if name.startswith("jamba"):
+        # the names perf/layer_metrics/flood_sel_* and
+        # flood_paged_decode_roofline sum by
+        want = {"jamba_sel_st": "sel_step", "jamba_sel_sc": "sel_scan",
+                "jamba_paged_": "paged_decode"}[name[:12]]
+        calls = _kernel_calls(text)
+        assert len(calls) == 1 and calls[0].endswith(want), calls
     if name.startswith("latent"):
         # the names perf/layer_metrics/flood_mla_*, flood_moe_glu_* sum by
         want = "paged_decode_mla" if "mla" in name else "moe_gmm_glu"
