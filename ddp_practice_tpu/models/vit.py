@@ -104,10 +104,11 @@ _RESOLVED: Optional[set] = None
 @contextlib.contextmanager
 def resolved_attn_impls():
     """Collect, into the set this yields, what every SelfAttention traced
-    inside resolved its `attn_impl` to outside decode ("xla", "flash" or
-    "flash_short"). The choice is static, made once a trace from the
-    shape, so whoever traces a model once (the Trainer's abstract init)
-    knows which attention its programs run."""
+    inside resolved its `attn_impl` to outside decode ("xla", "flash",
+    "flash_short", or "flash_flat": the streaming kernels on a block that
+    stays flat, `SelfAttention._flat_block`). The choice is static, made
+    once a trace from the shape, so whoever traces a model once (the
+    Trainer's abstract init) knows which attention its programs run."""
     global _RESOLVED
     was, _RESOLVED = _RESOLVED, set()
     try:
@@ -123,8 +124,10 @@ class SelfAttention(nn.Module):
     seq_axis: Optional[str] = None  # mesh axis for sequence parallelism
     sp_impl: str = "ring"           # "ring" | "ulysses"
     # the attention core outside decode: "xla" (ops/attention.py
-    # _attention), "flash" (the streaming Pallas kernels), or "auto":
-    # chosen at trace time from the shape (resolve_attn_impl below)
+    # _attention), "flash" (the streaming Pallas kernels; where a device
+    # holds whole heads the block around them stays flat, rope included:
+    # `_flat_block`), or "auto": chosen at trace time from the shape
+    # (resolve_attn_impl below)
     attn_impl: str = "auto"
     causal: bool = False            # decoder (LM) blocks mask the future
     rope: bool = False              # rotary Q/K (ops/rope.py) vs none here
@@ -165,23 +168,54 @@ class SelfAttention(nn.Module):
         kv = dense((2, kvh, head_dim), name="kv")(x)
         return q, kv[:, :, 0], kv[:, :, 1], None
 
-    def _project_flat(self, x):
-        """The fused `qkv` projection once more (same parameters, after
-        `_project` made them), as ONE matmul onto a flat (b, s, 3*h*hd)
-        output: the layout the short kernels window. Through DenseGeneral
-        the output is (b, s, 3, h, hd), which XLA lays out batch-minor
-        when s is not a multiple of 8 (ViT's 196) and then relays out
-        around the kernels, 115 MB a copy at ViT-B/16's shape; its result
-        is dead code here. No mesh axis may split the heads: merging a
-        sharded (3, h, hd) into one dim would gather them."""
+    def _dense_flat(self, name: str, x):
+        """The DenseGeneral `name` once more (same parameters, after the
+        module made them), as ONE matmul between flat feature dims: its
+        (in, ...) or (..., out) kernel merged to two dims."""
         from flax.linen.dtypes import promote_dtype
 
-        p = self.variables["params"]["qkv"]
+        p = self.variables["params"][name]
         kernel = p["kernel"].reshape(x.shape[-1], -1)
         bias = p["bias"].reshape(-1) if self.use_bias else None
         x, kernel, bias = promote_dtype(x, kernel, bias, dtype=self.dtype)
         y = x @ kernel
         return y if bias is None else y + bias
+
+    def _project_flat(self, x):
+        """The fused `qkv` projection once more (after `_project` made
+        its parameters), onto a flat (b, s, 3*h*hd) output: the layout
+        the packed kernels window. Through DenseGeneral the output is
+        (b, s, 3, h, hd), which XLA lays out batch-minor when s is not a
+        multiple of 8 (ViT's 196) or sequence-minor once sliced to
+        (b, s, h, 64) (the LM's 2048), and then relays out around the
+        kernels, 115 MB a copy at ViT-B/16's shape; its result is dead
+        code here. No mesh axis may split the heads: merging a sharded
+        (3, h, hd) into one dim would gather them."""
+        return self._dense_flat("qkv", x)
+
+    def _out_proj_flat(self, out):
+        """`_out_proj` of a flat (b, s, h*hd) attention output, as ONE
+        matmul from the same `out` parameters: the kernels' row-major
+        output is the dot's operand as it was written, where
+        DenseGeneral(axis=(-2, -1)) over (b, s, h, hd) has it turned
+        sequence-minor first."""
+        if self.is_initializing():  # DenseGeneral makes the parameters
+            return self._out_proj(
+                out.reshape(*out.shape[:2], self.num_heads, -1))
+        return self._dense_flat("out", out)
+
+    def _flat_block(self, head_dim: int, decode: bool) -> bool:
+        """Whether a "flash" call keeps the block flat (`__call__`): no
+        decode, no sequence parallelism, the fused `qkv` projection (no
+        grouped K/V heads), heads that pack into 128 lanes, and no mesh
+        axis over the heads (merging a sharded (3, h, hd) into one dim
+        would gather them: `_project_flat`). Observed, never asked for."""
+        from ddp_practice_tpu.ops.flash_attention import _heads_per_pack
+
+        return (not decode and self.seq_axis is None
+                and (self.kv_heads or self.num_heads) == self.num_heads
+                and _heads_per_pack(self.num_heads, head_dim) is not None
+                and self._tensor_parallel() == 1)
 
     def _widen_kv(self, k, v):
         """K and V repeated to one head a query head (the dense attention
@@ -241,8 +275,30 @@ class SelfAttention(nn.Module):
         head_dim = d // self.num_heads
         q, k, v, qkv = self._project(x, head_dim)
         impl = self.resolve_attn_impl(s, head_dim, decode=decode)
+        flat = impl == "flash" and self._flat_block(head_dim, decode)
         if _RESOLVED is not None and not decode:
-            _RESOLVED.add(impl)
+            _RESOLVED.add("flash_flat" if flat else impl)
+        if flat:
+            # training on whole heads: the block stays (b, s, features)
+            # row-major from the qkv matmul to the out matmul, rope
+            # included. Through the 4-D code below XLA lays every
+            # (b, s, h, 64) activation out sequence-minor and copies it
+            # back to row-major on each side of the kernels (eight
+            # relayouts a layer, the cotangents' through float32:
+            # ops/flash_attention.py flash_attention_flat).
+            from ddp_practice_tpu.ops.flash_attention import (
+                flash_attention_flat,
+            )
+            from ddp_practice_tpu.parallel.ring import kernel_island
+
+            batch_split = P(MeshConfig.AXIS_DATA)
+            out = kernel_island(
+                functools.partial(flash_attention_flat,
+                                  n_heads=self.num_heads,
+                                  causal=self.causal, rope=self.rope),
+                in_specs=(batch_split,), out_specs=batch_split,
+            )(self._project_flat(x))
+            return self._out_proj_flat(out)
         if (
             impl in ("flash", "flash_short")
             and not decode
@@ -254,10 +310,12 @@ class SelfAttention(nn.Module):
             # (3, h, hd) feature flatten IS the [q|k|v] column layout they
             # window at offsets, so q/k/v never materialize as slices
             # (~4 ms/step of layout traffic at lm_base — round-4 profile).
-            # rope rotates q/k in 4D before the kernel and keeps the
-            # sliced path; flash_attention_qkv itself falls back for
-            # unpackable head shapes. Falls through to the shared output
-            # projection below.
+            # What reaches this branch is "flash_short", or "flash" with
+            # the heads split over 'tensor' or unpackable (the flat
+            # branch above took the rest); flash_attention_qkv itself
+            # falls back for unpackable head shapes. rope never does: it
+            # rotates q/k in 4-D below, in sequence-minor fusions with a
+            # relayout copy each side of the kernel.
             # Under a mesh the kernel runs in a shard_map island
             # (parallel/ring.py kernel_island): the split is taken on
             # the (3, h, hd) dims, heads over 'tensor', and each device

@@ -89,6 +89,11 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ddp_practice_tpu.ops.rope import (
+    flat_rope_tables,
+    rope_flat_bwd,
+    rope_flat_qk,
+)
 from ddp_practice_tpu.utils import backend
 
 _NEG_INF = -1e30
@@ -658,6 +663,27 @@ def _packed_heads(hpc, d):
     return [(slice(hh * d, (hh + 1) * d), hh) for hh in range(hpc)]
 
 
+def _packed_dims(qf, kf, vf, n_heads, fused_qkv):
+    """(hd, d, hpc, n_packs, koff, voff) of a packed call. An operand
+    3*h*d wide IS the raw QKV-projection output, columns [q heads | k
+    heads | v heads], and is windowed at its own column blocks (k at
+    n_packs, v at 2*n_packs): all three under `fused_qkv`; v alone where
+    q and k were rotated out of it (the flat rope path), whose q and k
+    operands are (b, s, h*d) arrays of their own."""
+    hd = qf.shape[-1] // 3 if fused_qkv else qf.shape[-1]
+    d = hd // n_heads
+    hpc = _heads_per_pack(n_heads, d)
+    n_packs = n_heads // hpc
+    for name, x in (("k", kf), ("v", vf)):
+        if x.shape[-1] not in (hd, 3 * hd):
+            raise ValueError(
+                f"{name} is {x.shape[-1]} wide: neither h*d = {hd} nor the "
+                f"projection's {3 * hd}")
+    koff = n_packs if kf.shape[-1] == 3 * hd else 0
+    voff = 2 * n_packs if vf.shape[-1] == 3 * hd else 0
+    return hd, d, hpc, n_packs, koff, voff
+
+
 def _fwd_kernel_packed(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     *, sm_scale, block_q, block_k, causal, seq_q, seq_k, hpc, d,
@@ -702,17 +728,15 @@ def _flash_fwd_packed(qf, kf, vf, *, n_heads, causal, block_q, block_k,
     The three in_specs window it at column-block offsets (0, n_packs,
     2*n_packs), so no slice/relayout ever materializes q, k, v (the
     sliced path cost ~4 ms/step of pure data formatting at lm_base
-    shapes — round-4 profile)."""
-    b, seq_q, hd = qf.shape
-    if fused_qkv:
-        hd //= 3
+    shapes — round-4 profile). Without it kf or vf may still be that
+    array and is windowed at its own offset (_packed_dims): the flat
+    rope path hands in rotated q and k and reads v where the projection
+    wrote it."""
+    b, seq_q, _ = qf.shape
     seq_k = kf.shape[1]
-    d = hd // n_heads
-    hpc = _heads_per_pack(n_heads, d)
+    hd, d, hpc, n_packs, koff, voff = _packed_dims(
+        qf, kf, vf, n_heads, fused_qkv)
     w = hpc * d
-    n_packs = n_heads // hpc
-    koff = n_packs if fused_qkv else 0
-    voff = 2 * n_packs if fused_qkv else 0
     block_q, block_k = _check_blocks(seq_q, seq_k, block_q, block_k, causal)
     sm_scale = 1.0 / (d ** 0.5)
     offset = seq_k - seq_q if causal else 0
@@ -892,16 +916,11 @@ def _packed_bwd_calls(qf, kf, vf, do, out, lse_pk, *, one_kernel, n_heads,
     two (`flash_bwd_dkv_packed`, `flash_bwd_dq_packed`): the same tiles in
     the same order either way. _flash_bwd_packed chooses; the tests hold
     the two against each other (one_kernel None: from the shape)."""
-    b, seq_q, hd = qf.shape
-    if fused_qkv:
-        hd //= 3
+    b, seq_q, _ = qf.shape
     seq_k = kf.shape[1]
-    d = hd // n_heads
-    hpc = _heads_per_pack(n_heads, d)
+    hd, d, hpc, n_packs, koff, voff = _packed_dims(
+        qf, kf, vf, n_heads, fused_qkv)
     w = hpc * d
-    n_packs = n_heads // hpc
-    koff = n_packs if fused_qkv else 0
-    voff = 2 * n_packs if fused_qkv else 0
     block_q, block_k = _check_blocks(seq_q, seq_k, block_q, block_k, causal)
     n_q, n_k = seq_q // block_q, seq_k // block_k
     if one_kernel is None:
@@ -1110,8 +1129,81 @@ def flash_attention_qkv(
             rs(q), rs(k), rs(v), causal=causal, block_q=block_q,
             block_k=block_k,
         )
-    out = _flash_packed_qkv(qkv, n_heads, causal, block_q, block_k)
-    return out.reshape(b, s, n_heads, d)
+    return flash_attention_flat(
+        qkv, n_heads, causal=causal, block_q=block_q, block_k=block_k
+    ).reshape(b, s, n_heads, d)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_packed_rope_qkv(qkvf, cos, sin, n_heads, causal, block_q, block_k):
+    return _flash_packed_rope_qkv_vjp_fwd(
+        qkvf, cos, sin, n_heads, causal, block_q, block_k)[0]
+
+
+def _flash_packed_rope_qkv_vjp_fwd(qkvf, cos, sin, n_heads, causal, block_q,
+                                   block_k):
+    # q' and k' leave the rotary pass row-major; v stays where the
+    # projection wrote it, the third column window of qkvf
+    qf, kf = rope_flat_qk(qkvf, cos, sin, n_heads=n_heads)
+    out, lse_pk = _flash_fwd_packed(
+        qf, kf, qkvf, n_heads=n_heads, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=_interpret(),
+    )
+    return out, (qf, kf, qkvf, cos, sin, out, lse_pk)
+
+
+def _flash_packed_rope_qkv_vjp_bwd(n_heads, causal, block_q, block_k, res,
+                                   g_out):
+    qf, kf, qkvf, cos, sin, out, lse_pk = res
+    dq, dk, dv = _flash_bwd_packed(
+        qf, kf, qkvf, g_out.astype(qkvf.dtype), out, lse_pk,
+        n_heads=n_heads, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=_interpret(),
+    )
+    # the rotation's transpose and the concatenate in one pass: the
+    # projection's cotangent is one row-major bf16 array
+    return (rope_flat_bwd(dq, dk, dv, cos, sin, n_heads=n_heads),
+            jnp.zeros_like(cos), jnp.zeros_like(sin))
+
+
+_flash_packed_rope_qkv.defvjp(_flash_packed_rope_qkv_vjp_fwd,
+                              _flash_packed_rope_qkv_vjp_bwd)
+
+
+def flash_attention_flat(
+    qkv: jnp.ndarray,  # (batch, seq, 3 * heads * head_dim)
+    n_heads: int,
+    *,
+    causal: bool = False,
+    rope: bool = False,
+    block_q: int = 512,
+    block_k: int = 1024,
+) -> jnp.ndarray:
+    """Self-attention that never leaves the flat row-major layout: the
+    (b, s, 3*h*d) output of ONE qkv matmul in, (b, s, h*d) out for ONE
+    out-projection matmul, and one (b, s, 3*h*d) cotangent back. No
+    (b, s, h, d) array exists in between, so XLA has no 64-wide minor
+    dimension to lay out sequence-minor and relayout around the kernels
+    (eight copies a layer, two of them through float32, at lm_base's
+    shape: PERF.md section 6, PR 33).
+
+    `rope` rotates q and k at positions arange(s), as `apply_rope` does,
+    in an element-wise kernel over the flat rows (ops/rope.py
+    rope_flat_qk); the packed kernels then read q' and k' from its
+    outputs and v from the projection's third column window. Without it
+    this is `flash_attention_qkv` less its reshape. Heads must pack
+    (_heads_per_pack): the caller keeps the 4-D path otherwise."""
+    b, s, three_hd = qkv.shape
+    hd = three_hd // 3
+    if three_hd % 3 or _heads_per_pack(n_heads, hd // n_heads) is None:
+        raise ValueError(
+            f"{n_heads} heads over a {three_hd}-wide qkv do not pack into "
+            f"{_LANES}-lane tiles")
+    if not rope:
+        return _flash_packed_qkv(qkv, n_heads, causal, block_q, block_k)
+    cos, sin = flat_rope_tables(jnp.arange(s), hd, n_heads)
+    return _flash_packed_rope_qkv(
+        qkv, cos, sin, n_heads, causal, block_q, block_k)
 
 
 # --------------------------------------------------------------------- #

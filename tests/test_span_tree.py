@@ -417,7 +417,9 @@ def test_train_epoch_alone_yields_the_tree(devices, placement, annotations):
 @pytest.mark.parametrize("asked,on_tpu,want", [
     ("auto", False, "xla"),          # off the TPU "auto" interprets nothing
     ("auto", True, "flash_short"),   # what the chip's programs would run
-    ("flash", False, "flash"),       # a named kernel is taken at its word
+    # a named kernel is taken at its word; whole heads on the one device,
+    # so the block around the streaming kernels stays flat (PR 33)
+    ("flash", False, "flash_flat"),
 ])
 def test_train_epoch_span_says_which_attention_runs(devices, monkeypatch,
                                                     asked, on_tpu, want):

@@ -131,6 +131,25 @@ def _flash_bwd(batch, seq=S, dtype=BF16, one_kernel=None):
     return bwd, (x,) * 5 + (_sds((batch, H // 2, seq, 2), jnp.float32),)
 
 
+def _rope_flat(d=D):
+    """The flat block's rotary passes at the LM cells' rows (8 x 2048 x
+    768): forward off the projection, backward into its cotangent."""
+    from ddp_practice_tpu.ops.rope import (
+        flat_rope_tables,
+        rope_flat_bwd,
+        rope_flat_qk,
+    )
+
+    h = HD // d
+
+    def both(qkv, g):
+        cos, sin = flat_rope_tables(jnp.arange(S), HD, h)
+        q, k = rope_flat_qk(qkv, cos, sin, n_heads=h)
+        return rope_flat_bwd(q + g, k + g, g, cos, sin, n_heads=h)
+
+    return both, (_sds((B, S, 3 * HD)), _sds((B, S, HD)))
+
+
 def _flash_short(batch=128, seq=196):
     """ViT-B/16's attention core a layer (perf/configs/vit_b16.json under
     perf/traffic/vit_224_b128.json): 128 images of 196 patches, 12 heads
@@ -351,7 +370,7 @@ def _paged_group20(slots=256, blocks_per_slot=48, page=64):
 
 def _kernel_calls(text):
     """Names of the compiled Pallas custom calls, in program order."""
-    return [ln.split("=")[0].strip().lstrip("%").split(".")[0]
+    return [ln.split("=")[0].split("%")[-1].strip().split(".")[0]
             for ln in text.splitlines()
             if "custom-call(" in ln and "tpu_custom_call" in ln]
 
@@ -391,6 +410,11 @@ KERNELS = {
         _flash_bwd, 1, dtype=jnp.float32),
     # past the rule the two kernels run; ONE kernel there is refused
     "flash_bwd_two_kernels_s16384": functools.partial(_flash_bwd, 1, 16384),
+    # two heads a 128-lane tile (a roll each way and a select), one head
+    # a tile, one head over two tiles
+    "rope_flat_d64": _rope_flat,
+    "rope_flat_d128": functools.partial(_rope_flat, 128),
+    "rope_flat_d256": functools.partial(_rope_flat, 256),
     "flash_short_fwd_bwd": _flash_short,
     "flash_short_fwd_bwd_longest": lambda: _flash_short(
         8, __import__("ddp_practice_tpu.ops.flash_attention", fromlist=["x"]
@@ -433,6 +457,10 @@ def test_kernel_compiles_for_v5e(topo, name):
                 ["flash_bwd_dkv_packed", "flash_bwd_dq_packed"])
         assert sorted(vmem) == want, vmem
         assert max(vmem.values()) <= 16 * 2**20, vmem
+    if name.startswith("rope_flat"):
+        # named apart from the flash kernels: train_flash_dev_pct sums
+        # the ops named `flash_*`
+        assert _kernel_calls(text) == ["rope_flat_qk", "rope_flat_bwd"]
     if name.startswith("paged"):
         # ONE device op a call, named by the kernel's `name=`:
         # perf/lib/readers.py sums every traced op whose name holds
@@ -504,20 +532,32 @@ def test_flash_compiles_sharded_over_four_devices(topo):
     assert "all-gather" not in text
 
 
-def _attention_grad(device, *, batch, seq, causal=False, **attn_kw):
-    """HLO of d loss / d (params, x) of one bf16 SelfAttention of 12 heads
-    of 64 (ViT-B/16's and lm_base's), compiled for `device`."""
+def _attention_grad(device, *, batch, seq, causal=False, mesh=None,
+                    **attn_kw):
+    """HLO of the loss and d loss / d (params, x) of one bf16
+    SelfAttention of 12 heads of 64 (ViT-B/16's and lm_base's), compiled
+    for `device`, or for `mesh` with the batch split over 'data'."""
     from ddp_practice_tpu.models.vit import SelfAttention
 
     attn = SelfAttention(num_heads=H, dtype=BF16, causal=causal, **attn_kw)
-    variables = jax.eval_shape(
-        lambda: attn.init(jax.random.PRNGKey(0), jnp.zeros((1, seq, HD), BF16)))
+    variables = jax.eval_shape(      # 4 rows: one a device of a data=4 mesh
+        lambda: attn.init(jax.random.PRNGKey(0), jnp.zeros((4, seq, HD), BF16)))
 
     def loss(variables, x):
         return attn.apply(variables, x).astype(jnp.float32).sum()
 
-    return _compile(jax.grad(loss, argnums=(0, 1)), variables,
-                    _sds((batch, seq, HD)), device=device, min_kernels=2)
+    fn = jax.value_and_grad(loss, argnums=(0, 1))
+    if mesh is None:
+        return _compile(fn, variables, _sds((batch, seq, HD)),
+                        device=device, min_kernels=2)
+    rep = NamedSharding(mesh, P())
+    variables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        variables)
+    x = jax.ShapeDtypeStruct(
+        (batch, seq, HD), BF16,
+        sharding=NamedSharding(mesh, P(MeshConfig.AXIS_DATA)))
+    return jax.jit(fn).lower(variables, x).compile().as_text()
 
 
 def test_vit_attention_takes_the_short_kernels_unasked(topo):
@@ -561,6 +601,73 @@ def test_lm_attention_keeps_the_streaming_kernels(topo):
 
     assert SelfAttention(num_heads=H, causal=True).resolve_attn_impl(
         S, D) == "xla"
+
+
+def _defs(text):
+    """{name: (op, operand names)} of every instruction of the HLO."""
+    defs = {}
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*? ([\w\-]+)\((.*)", ln)
+        if m:
+            defs[m.group(1)] = (
+                m.group(2), re.findall(r"%([\w.\-]+)", m.group(3).split(
+                    "), ")[0]))
+    return defs
+
+
+def _through(defs, name):
+    """`name`, or the instruction it is a view of (bitcasts and tuple
+    elements are looked through)."""
+    while defs[name][0] in ("bitcast", "get-tuple-element"):
+        name = defs[name][1][0]
+    return name
+
+
+@pytest.mark.parametrize("layout", ["one_device", "data4"])
+def test_lm_attention_block_stays_flat(topo, layout):
+    """The LM cells' block with rope (8 rows of 2048 a device, 12 heads
+    of 64, "flash" named) with its gradient: every (8, 2048, .)
+    activation stays row-major from the qkv projection to the out
+    projection. The forward kernel's q and k are the rotary kernel's
+    outputs and its v the projection's own fusion, its output reaches
+    the out projection's dot as written, and the program holds at most
+    one relayout `copy` of such an activation (through the 4-D code:
+    eight, two of them float32: PERF.md section 6, PR 33)."""
+    mesh = None
+    if layout == "data4":
+        mesh = build_mesh(MeshConfig(), devices=topo.devices)
+        set_current_mesh(mesh)
+    text = _attention_grad(
+        topo.devices[0], batch=B * (4 if mesh is not None else 1), seq=S,
+        causal=True, rope=True, attn_impl="flash", mesh=mesh)
+    assert sorted(_kernel_calls(text)) == [
+        "flash_bwd_packed", "flash_fwd_packed", "rope_flat_bwd",
+        "rope_flat_qk"]
+    assert "all-gather" not in text
+    defs = _defs(text)
+    by_kernel = {n.split(".")[0]: n for n, (op, _) in defs.items()
+                 if op == "custom-call" and n.split(".")[0] in (
+                     "flash_fwd_packed", "rope_flat_qk")}
+    fwd = defs[by_kernel["flash_fwd_packed"]][1]
+    q, k, v = (_through(defs, o) for o in fwd[:3])
+    assert q == k == by_kernel["rope_flat_qk"], (q, k)
+    assert defs[v][0] == "fusion", defs[v]          # the qkv matmul
+    rope_in = {_through(defs, o)
+               for o in defs[by_kernel["rope_flat_qk"]][1][2:]}
+    assert rope_in == {v}, rope_in
+    # whoever reads the kernel's output (the out projection's fusion, the
+    # backward kernel) reads the custom call's own tuple element
+    out_views = {n for n, (op, ops) in defs.items()
+                 if op in ("get-tuple-element", "bitcast")
+                 and _through(defs, n) == by_kernel["flash_fwd_packed"]}
+    readers = {op for n, (op, ops) in defs.items()
+               if op not in ("get-tuple-element", "bitcast")
+               and set(ops) & out_views}
+    assert readers and "copy" not in readers, readers
+    assert "fusion" in readers, readers
+    moved = [ln.strip()[:140] for ln in text.splitlines()
+             if re.search(rf"= (bf16|f32)\[{B},{S},[^\]]*\]\S* copy\(", ln)]
+    assert len(moved) <= 1, moved
 
 
 @pytest.mark.parametrize("layout", ["data4", "data2_tensor2"])
@@ -650,8 +757,10 @@ def _collectives(text):
 def test_lm_base_flash_step_compiles_on_the_mesh(topo, layout, pos_emb):
     """chip_smoke.py --chips 4's program: lm_base (depth cut to 2 for
     compile time), flash, global batch 8 at s 2048 through the resident
-    train step Trainer uses, on the described 2x2. rope takes the sliced
-    flash path, learned positions the packed-QKV one."""
+    train step Trainer uses, on the described 2x2. With the heads whole
+    on a device (data4, fsdp4) the attention block stays flat, rotary
+    included; data2_tensor2 keeps the 4-D code: rope the sliced flash
+    path, learned positions the packed-QKV one."""
     from ddp_practice_tpu.train.steps import make_resident_lm_train_step
 
     mesh_cfg = (MeshConfig(data=2, tensor=2) if layout == "data2_tensor2"
@@ -671,7 +780,16 @@ def test_lm_base_flash_step_compiles_on_the_mesh(topo, layout, pos_emb):
     ).compile().as_text()
     calls = [ln for ln in text.splitlines()
              if "custom-call(" in ln and "tpu_custom_call" in ln]
-    assert len(calls) == 4, len(calls)  # (fwd, ONE backward) x 2 layers
+    names = _kernel_calls(text)
+    # (fwd, ONE backward) x 2 layers
+    assert sum(n.startswith("flash_") for n in names) == 4, names
+    # the heads whole on every device: the block stays flat, and under
+    # rope the two rotary kernels run beside the flash kernels; split
+    # over tensor=2, rope rotates in 4-D as before
+    rotary = sum(n.startswith("rope_flat_") for n in names)
+    flat_rope = pos_emb == "rope" and layout != "data2_tensor2"
+    assert rotary == (4 if flat_rope else 0), names
+    assert len(names) == 4 + rotary, names
     dp, tp = mesh.shape["data"], mesh.shape["tensor"]
     width = (3 * HD if pos_emb == "learned" else HD) // tp
     per_device = f"bf16[{B // dp},{S},{width}]"
