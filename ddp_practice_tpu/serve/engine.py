@@ -184,7 +184,11 @@ def _sample_step(cfg: EngineConfig, last_logits, active, keys,
     per_slot_sampling path, where every slot samples under its own
     params via sample_logits_batch and the key chains ALWAYS advance
     (greedy rows discard their draw), so a request's stream never
-    depends on its batchmates' params."""
+    depends on its batchmates' params.
+
+    Every caller runs it, and its own finite-logits and accept logic,
+    under `jax.named_scope("sample")`: the token by which a profiler
+    trace's device time is given to sampling (perf/lib/scopes.py)."""
     if sampling is not None:
         temp, tk, tp = sampling
         split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
@@ -538,9 +542,11 @@ class SlotEngine(_EngineBase):
             attn_start=attn_start[None], batch_stats=self.batch_stats,
         )
         pool = write_slot(pool, scratch, slot)
-        last_logits = lax.dynamic_update_slice(
-            last_logits, logits[:, -1].astype(last_logits.dtype), (slot, 0)
-        )
+        with jax.named_scope("sample"):
+            last_logits = lax.dynamic_update_slice(
+                last_logits, logits[:, -1].astype(last_logits.dtype),
+                (slot, 0),
+            )
         attn_starts = lax.dynamic_update_slice(
             attn_starts, attn_start[None], (slot,)
         )
@@ -554,9 +560,10 @@ class SlotEngine(_EngineBase):
         # own slot — attention is per-row, so the NaN cannot cross slots,
         # and this flag is what lets the scheduler finish ONE request
         # with status "error" instead of serving garbage batch-wide
-        finite = jnp.isfinite(last_logits).all(axis=-1)
-        toks, new_keys = _sample_step(cfg, last_logits, active, keys,
-                                      sampling)
+        with jax.named_scope("sample"):
+            finite = jnp.isfinite(last_logits).all(axis=-1)
+            toks, new_keys = _sample_step(cfg, last_logits, active, keys,
+                                          sampling)
         pool, logits = decode_apply(
             self.model, params, pool, toks[:, None],
             attn_start=attn_starts, batch_stats=self.batch_stats,
@@ -1025,9 +1032,11 @@ class PagedEngine(_EngineBase):
         pool = scatter_prompt_blocks(
             pool, scratch, block_ids, w, self.config.block_size, slot
         )
-        last_logits = lax.dynamic_update_slice(
-            last_logits, logits[:, -1].astype(last_logits.dtype), (slot, 0)
-        )
+        with jax.named_scope("sample"):
+            last_logits = lax.dynamic_update_slice(
+                last_logits, logits[:, -1].astype(last_logits.dtype),
+                (slot, 0),
+            )
         return pool, last_logits
 
     def _prefix_prefill(self, params, pool, last_logits, tokens,
@@ -1045,12 +1054,13 @@ class PagedEngine(_EngineBase):
             batch_stats=self.batch_stats,
             page_table=pt_row, kv_lengths=pos0[None],
         )
-        last = lax.dynamic_slice(
-            logits, (0, true_len - 1, 0), (1, 1, logits.shape[2])
-        )[:, 0]
-        last_logits = lax.dynamic_update_slice(
-            last_logits, last.astype(last_logits.dtype), (slot, 0)
-        )
+        with jax.named_scope("sample"):
+            last = lax.dynamic_slice(
+                logits, (0, true_len - 1, 0), (1, 1, logits.shape[2])
+            )[:, 0]
+            last_logits = lax.dynamic_update_slice(
+                last_logits, last.astype(last_logits.dtype), (slot, 0)
+            )
         return pool, last_logits
 
     @staticmethod
@@ -1074,9 +1084,10 @@ class PagedEngine(_EngineBase):
 
         def body(carry, _):
             pool, last_logits, keys, lengths = carry
-            finite = jnp.isfinite(last_logits).all(axis=-1)
-            toks, keys = _sample_step(self.config, last_logits, active,
-                                      keys, sampling)
+            with jax.named_scope("sample"):
+                finite = jnp.isfinite(last_logits).all(axis=-1)
+                toks, keys = _sample_step(self.config, last_logits, active,
+                                          keys, sampling)
             pool, logits = decode_apply(
                 self.model, params, pool, toks[:, None],
                 attn_start=attn_starts, batch_stats=self.batch_stats,
@@ -1148,36 +1159,39 @@ class PagedEngine(_EngineBase):
             attn_start=attn_starts, batch_stats=self.batch_stats,
             page_table=page_table, kv_lengths=lengths,
         )
-        all_logits = jnp.concatenate(
-            [last_logits[:, None], win_logits.astype(last_logits.dtype)],
-            axis=1,
-        )                                                   # (s, k+1, v)
-        g = sample_logits(all_logits, None, temperature=0.0)
-        g = g.astype(jnp.int32)                             # (s, k+1)
-        matches = (drafts == g[:, :k]) & (
-            jnp.arange(k, dtype=jnp.int32)[None, :] < draft_lens[:, None]
-        )
-        accepted = jnp.cumprod(
-            matches.astype(jnp.int32), axis=1
-        ).sum(axis=1)                                       # (s,) in [0, k]
-        accepted = jnp.where(active, accepted, 0)
-        finite = jnp.isfinite(all_logits).all(axis=-1)      # (s, k+1)
-        correction = jnp.take_along_axis(g, accepted[:, None], axis=1)
-        correction = jnp.where(
-            active[:, None], correction, jnp.int32(self.config.pad_id)
-        )
+        with jax.named_scope("sample"):
+            all_logits = jnp.concatenate(
+                [last_logits[:, None],
+                 win_logits.astype(last_logits.dtype)], axis=1,
+            )                                               # (s, k+1, v)
+            g = sample_logits(all_logits, None, temperature=0.0)
+            g = g.astype(jnp.int32)                         # (s, k+1)
+            matches = (drafts == g[:, :k]) & (
+                jnp.arange(k, dtype=jnp.int32)[None, :]
+                < draft_lens[:, None]
+            )
+            accepted = jnp.cumprod(
+                matches.astype(jnp.int32), axis=1
+            ).sum(axis=1)                                   # (s,) in [0, k]
+            accepted = jnp.where(active, accepted, 0)
+            finite = jnp.isfinite(all_logits).all(axis=-1)  # (s, k+1)
+            correction = jnp.take_along_axis(g, accepted[:, None], axis=1)
+            correction = jnp.where(
+                active[:, None], correction, jnp.int32(self.config.pad_id)
+            )
         pool, nxt_logits = decode_apply(
             self.model, params, pool, correction,
             attn_start=attn_starts, batch_stats=self.batch_stats,
             page_table=page_table, kv_lengths=lengths + accepted,
         )
-        last_logits = jnp.where(
-            active[:, None],
-            nxt_logits[:, -1].astype(last_logits.dtype), last_logits,
-        )
-        toks = jnp.where(
-            active[:, None], g, jnp.int32(self.config.pad_id)
-        )
+        with jax.named_scope("sample"):
+            last_logits = jnp.where(
+                active[:, None],
+                nxt_logits[:, -1].astype(last_logits.dtype), last_logits,
+            )
+            toks = jnp.where(
+                active[:, None], g, jnp.int32(self.config.pad_id)
+            )
         return pool, last_logits, toks, accepted, finite
 
     # ----------------------------------------------------------------- host

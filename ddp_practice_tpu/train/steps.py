@@ -88,6 +88,25 @@ def _step_rngs(step, seed: int = 0):
     return {"dropout": jax.random.fold_in(jax.random.PRNGKey(seed), step)}
 
 
+def _apply_gradients(tx, grads, state: TrainState):
+    """(new params, new optimizer state). What no module owns runs under a
+    scope of its own, here `optimizer` and in the loss functions `loss`: a
+    scope is a token in each device op's `op_name`, by which a profiler
+    trace's time is given to the model's parts (perf/lib/scopes.py reads
+    it, tests/test_scopes.py holds it). Metadata only: no op, shape or
+    fusion changes with it."""
+    with jax.named_scope("optimizer"):
+        updates, new_opt_state = tx.update(
+            grads, state.opt_state, state.params
+        )
+        return optax.apply_updates(state.params, updates), new_opt_state
+
+
+def _grad_norm(grads):
+    with jax.named_scope("optimizer"):
+        return optax.global_norm(grads)
+
+
 def _train_step_fn(model, tx, label_smoothing: float, seed: int = 0,
                    augment: bool = False):
     """The pure (state, batch) -> (state, metrics) function both the
@@ -119,23 +138,24 @@ def _train_step_fn(model, tx, label_smoothing: float, seed: int = 0,
                 mutable=mutable, rngs=_step_rngs(state.step, seed),
             )
             new_stats = updated["batch_stats"] if has_bn else None
-            loss = cross_entropy(
-                logits, batch["label"], label_smoothing=label_smoothing
-            )
             inter = updated.get("intermediates", {})
-            loss = loss + _sown_aux_loss(inter)
+            with jax.named_scope("loss"):
+                loss = cross_entropy(
+                    logits, batch["label"], label_smoothing=label_smoothing
+                )
+                loss = loss + _sown_aux_loss(inter)
             return loss, (logits, new_stats, inter)
 
         (loss, (logits, new_stats, inter)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(state.params)
-        updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        correct, total = accuracy_counts(logits, batch["label"])
+        new_params, new_opt_state = _apply_gradients(tx, grads, state)
+        with jax.named_scope("loss"):
+            correct, total = accuracy_counts(logits, batch["label"])
         metrics = {
             "loss": loss,
             "accuracy": correct / total,
-            "grad_norm": optax.global_norm(grads),
+            "grad_norm": _grad_norm(grads),
             **_moe_metrics(inter),
         }
         new_state = TrainState(
@@ -274,14 +294,15 @@ def _lm_train_step_fn(model, tx, label_smoothing: float = 0.0, seed: int = 0,
                 rngs=_step_rngs(state.step, seed),
             )
             new_stats = updated["batch_stats"] if has_stats else None
-            loss = cross_entropy(
-                logits, targets, weight=weight,
-                label_smoothing=label_smoothing,
-            )
             inter = updated.get("intermediates", {})
-            # MoE blocks (lm_moe) sow their load-balance loss + router
-            # health here, exactly like the image step
-            loss = loss + _sown_aux_loss(inter)
+            with jax.named_scope("loss"):
+                loss = cross_entropy(
+                    logits, targets, weight=weight,
+                    label_smoothing=label_smoothing,
+                )
+                # MoE blocks (lm_moe) sow their load-balance loss + router
+                # health here, exactly like the image step
+                loss = loss + _sown_aux_loss(inter)
             return loss, (logits, new_stats, inter)
 
         if getattr(model, "schedule", None) in ("1f1b", "interleaved"):
@@ -312,17 +333,17 @@ def _lm_train_step_fn(model, tx, label_smoothing: float = 0.0, seed: int = 0,
                 loss_fn, has_aux=True
             )(state.params)
             if with_accuracy:
-                correct, total = accuracy_counts(
-                    logits, targets, weight=weight
-                )
+                with jax.named_scope("loss"):
+                    correct, total = accuracy_counts(
+                        logits, targets, weight=weight
+                    )
             else:
                 correct, total = None, None
-        updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        new_params, new_opt_state = _apply_gradients(tx, grads, state)
         metrics = {
             "loss": loss,
             "perplexity": jnp.exp(loss),
-            "grad_norm": optax.global_norm(grads),
+            "grad_norm": _grad_norm(grads),
             **_moe_metrics(inter),
         }
         if correct is not None:

@@ -1,0 +1,17 @@
+"""Share of chip 0's busy time in the traced slice under the two ends of the
+model: the embeddings, the head (a tied head is `tok_embed.attend`) and the
+loss with its gradient. Read off each device op's `op_name` path
+(`perf/lib/scopes.py`). A share is read, not steered: `better` only says which
+way the existing `*_dev_pct` shares point.
+"""
+
+from perf.lib import scopes
+
+UNIT = "%"
+LAYER = "jitted step"
+SOURCE = "device_trace"
+MOVES = "train_mfu_pct"
+
+
+def read(obs: dict):
+    return scopes.class_pct(obs, "head", "loss", "embed")

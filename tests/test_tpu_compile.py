@@ -17,6 +17,7 @@ Kernel cases are tier-1 (about a second each). Whole-step programs take
 10-20 s each and are `slow`.
 """
 
+import contextlib
 import functools
 import os
 import re
@@ -717,6 +718,190 @@ def test_forced_fused_refuses_a_mesh_at_trace_time(topo):
     set_current_mesh(build_mesh(MeshConfig(), devices=topo.devices))
     with pytest.raises(ValueError, match="4-device mesh"):
         jax.eval_shape(block.apply, variables, x)
+
+
+# ------------------------------------------------------- scopes (PR 34)
+# The contract of perf/lib/scopes.py, held at the benchmark cells' own
+# shapes (widths, batch, sequence, engine) with the depth cut to one layer
+# of each kind: a layer more adds no kind of op, and a whole step of twelve
+# takes a tier-1 minute.
+CELL_DEPTH = {
+    "lm": lambda cfg: dict(cfg, n_layer=1),
+    "vit": lambda cfg: dict(cfg, num_hidden_layers=1),
+    "nemotron_h": lambda cfg: dict(cfg, hybrid_override_pattern="ME*"),
+    "deepseek_v3": lambda cfg: dict(cfg, layers_run=2),   # dense, experts
+    "jamba": lambda cfg: dict(cfg, num_hidden_layers=2, attn_layer_period=2,
+                              attn_layer_offset=1),       # Mamba, attention
+}
+
+
+def _cell_files(name, whole=False):
+    """(cell, config, traffic) of a benchmark cell, the depth cut unless
+    `whole`."""
+    from perf import run as harness
+
+    _, cell, cfg, traffic = harness.load_cell(name)
+    return cell, (cfg if whole else CELL_DEPTH[cfg["family"]](cfg)), traffic
+
+
+def _train_program(topo, name, whole=False):
+    """{"train_step": a thunk that lowers the cell's resident train step}:
+    what `Trainer` builds for the cell (perf/drivers/train.py
+    build_trainer), from shapes alone on the described chips."""
+    from perf.drivers import train as driver
+    from ddp_practice_tpu.train import steps
+
+    cell, cfg, traffic = _cell_files(name, whole)
+    family = driver.family_of(cfg)
+    family.prepare(cfg)
+    opts = dict(traffic["trainer"], **family.trainer_options(cfg, traffic))
+    b = traffic["batch_per_chip"] * cell["chips"]
+    depth = cfg.get("n_layer", cfg.get("num_hidden_layers"))
+    if cfg["family"] == "lm":
+        s = traffic["seq_len"]
+        kw = dict(vocab_size=cfg["vocab_size"], max_len=s, depth=depth,
+                  attn_impl=opts["attn_impl"], pos_emb=opts["pos_emb"],
+                  tied_embeddings=opts["tied_embeddings"])
+        sample = jnp.zeros((b, s), jnp.int32)
+    else:
+        side = cfg["image_size"]
+        kw = dict(num_classes=cfg["num_labels"], axis_name=None, depth=depth)
+        sample = jnp.zeros((b, side, side, cfg["num_channels"]), jnp.float32)
+    mesh, net, tx, abstract, shardings = _abstract_trainer(
+        topo, model=cfg["program_model"],
+        mesh_cfg=MeshConfig(data=cell["chips"]), model_kwargs=kw,
+        sample=sample)
+    if cfg["family"] == "lm":
+        step = steps.make_resident_lm_train_step(
+            net, tx, window=s + 1, seed=0, mesh=mesh,
+            state_shardings=shardings)
+        args = ({"tokens": _sds((b * 12 * (s + 1),), jnp.int32)},
+                _sds((1, b), jnp.int32))
+    else:
+        step = steps.make_resident_train_step(
+            net, tx, seed=0, mesh=mesh, state_shardings=shardings)
+        args = ({"image": _sds((b * 12,) + sample.shape[1:], jnp.uint8),
+                 "label": _sds((b * 12,), jnp.int32)},
+                _sds((1, b), jnp.int32))
+    return {"train_step": lambda: step.lower(abstract, *args)}
+
+
+def _serve_programs(topo, name, whole=False):
+    """{program: a thunk that lowers it} of the cell's `PagedEngine`, built
+    as perf/drivers/serve.py build_engine does: the first bucket's
+    admission prefill (the prefix-cache chunk where the cell has one) and
+    the decode burst. The engine allocates its pool where jax.devices() says
+    (the CPU); only its traced programs are lowered for the described
+    chip."""
+    import dataclasses
+
+    from ddp_practice_tpu.serve.engine import EngineConfig, PagedEngine
+    from perf.drivers import serve as driver
+
+    cell, cfg, traffic = _cell_files(name, whole)
+    family = driver.family_of(cfg)
+    opts = family.model_options(cfg)
+    if cfg["family"] == "lm":
+        opts["depth"] = cfg["n_layer"]
+    model = create_model(cfg["program_model"], policy=PrecisionPolicy.bf16(),
+                         **opts)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, BF16),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    eng = traffic["engine"]
+    if not whole:   # the pool is allocated for real, on this host
+        eng = dict(eng, num_blocks=2 * eng["max_blocks_per_slot"] + 1)
+    fields = {f.name for f in dataclasses.fields(EngineConfig)}
+    engine = PagedEngine(model, params, EngineConfig(
+        prompt_buckets=tuple(eng["buckets"]), block_size=eng["page"],
+        decode_burst=eng["burst"], temperature=0.0,
+        **{k: v for k, v in eng.items() if k in fields}))
+    slots, mb, i32 = eng["max_slots"], engine.max_blocks_per_slot, jnp.int32
+    on_chip = functools.partial(_on, topo.devices[0])
+    logits = _sds((slots, model.vocab_size), model.dtype)
+    w = engine.buckets[0]
+    if engine.radix is None:
+        prefill = lambda: engine._prefill_jit.lower(*on_chip((
+            params, engine._cache, logits, _sds((1, w), i32), _sds((), i32),
+            _sds((-(-w // eng["page"]),), i32), _sds((), i32))))
+    else:
+        prefill = lambda: engine._prefix_jit.lower(*on_chip((
+            params, engine._cache, logits, _sds((1, w), i32), _sds((), i32),
+            _sds((), i32), _sds((1, mb), i32), _sds((), i32))))
+    decode = lambda: engine._decode_jit.lower(*on_chip((
+        params, engine._cache, logits, _sds((slots,), i32),
+        _sds((slots,), jnp.bool_), _sds((slots, 2), jnp.uint32),
+        _sds((slots, mb), i32), _sds((slots,), i32))), None)
+    return {"prefill": prefill, "decode_burst": decode}
+
+
+def _cell_programs(topo, name, whole=False):
+    cell, cfg, traffic = _cell_files(name, whole)
+    build = _train_program if traffic["driver"] == "train" \
+        else _serve_programs
+    return build(topo, name, whole)
+
+
+CELLS = ["vitb16_train_224", "gpt2s_train_2k", "gpt2s_train_2k_dp4",
+         "gpt2s_serve_flood", "nemo3s_serve_flood", "kanana2_serve_docs",
+         "jamba2_serve_batch"]
+
+
+@contextlib.contextmanager
+def _no_frames_in_locations():
+    """A Pallas kernel's payload holds its ops' source locations with the
+    Python frames that led there (ten of them), the caller's own lines among
+    them. While open, a location carries no frames, so that two builds of
+    one program from two lines of a test are one text."""
+    key = "jax_traceback_in_locations_limit"
+    was = getattr(jax.config, key)
+    jax.config.update(key, 0)
+    try:
+        yield
+    finally:
+        jax.config.update(key, was)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_programs_carry_their_scopes(topo, cell, monkeypatch):
+    """Every step program of every benchmark cell, compiled for the
+    described v5e at the cell's own widths, under the contract of
+    tests/test_scopes.py: at least 95% of its own device ops (dot,
+    convolution, fusion, reduce, Pallas call, outside a fusion's body) carry
+    a path that perf/lib/scopes.py classifies; a train step holds `loss` and
+    `optimizer`, both serving programs `sample`; every kernel's name agrees
+    with the class and direction its path reads; and the three scopes the
+    contract added are METADATA: with them patched away the optimized HLO
+    is the same text but for `metadata={...}`."""
+    import test_scopes as contract
+
+    def build():
+        with _no_frames_in_locations():
+            return {k: lower().compile().as_text()
+                    for k, lower in _cell_programs(topo, cell).items()}
+
+    texts = build()
+    kernels = 0
+    for prog, text in texts.items():
+        want = {"loss", "optimizer"} if prog == "train_step" else {"sample"}
+        contract.hold(text, want | {"attn", "mlp", "norm"}, f"{cell} {prog}")
+        kernels += contract.kernels_agree(text)
+        for op, name, path, cls in contract.own_ops(text):
+            if op == "custom-call":   # a kernel's own instruction, by name
+                assert cls and cls[1] == (
+                    "bwd" if "_bwd" in name else "fwd"), (name, path)
+    assert kernels >= 1, kernels
+    real = jax.named_scope
+    monkeypatch.setattr(
+        jax, "named_scope",
+        lambda name: contextlib.nullcontext()
+        if name in contract.NEW_SCOPES else real(name))
+    for prog, bare in build().items():
+        assert 'op_name="' in bare and not re.search(
+            r"[/(](loss|optimizer|sample)[/)]", bare), prog
+        assert contract.without_metadata(bare) \
+            == contract.without_metadata(texts[prog]), prog
 
 
 # ------------------------------------------------------------ whole steps
