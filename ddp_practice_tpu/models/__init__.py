@@ -7,6 +7,21 @@ data-parallel axis name (which turns every BatchNorm into a SyncBatchNorm,
 replacing ddp_main.py:120).
 
 Ladder beyond parity (BASELINE.json configs): ResNet-18/50, ViT-Tiny.
+
+`HybridLM` (models/hybrid_lm.py) is in the registry three times, one layout
+of its pattern string each; the defaults are test-sized and the published
+widths come as options (perf/families/*.py `model_options`):
+
+    nemotron_h   'M' Mamba-2, 'E' LatentMoE, '*' grouped-query attention
+    jamba        'S' Mamba-1, 'D' dense SwiGLU MLP, '*'; tied head
+    qwen3_next   'G' Gated DeltaNet, 'A' gated attention (heads of
+                 `head_dim`, q/k norms, rotary on `rope_dim` lanes, an
+                 output gate), 'Q' GatedMoE with a softmax router and a
+                 gated shared expert; zero-centred norms, pos_emb="rope"
+
+All three hold recurrent state (`recurrent=True`): `PagedEngine` serves them
+and refuses `prefix_cache`, `prefill_chunk`, `spec_decode` and `fork()`
+(ROADMAP M6); `SlotEngine` refuses them.
 """
 
 from typing import Optional
@@ -223,6 +238,27 @@ def _jamba(*, num_classes, policy, axis_name, **kw):
     kw.setdefault("pattern", "SD*DSD")
     kw.setdefault("tie_embeddings", True)
     kw.setdefault("norm_eps", 1e-6)
+    return HybridLM(
+        dtype=policy.compute_dtype,
+        param_dtype=policy.param_dtype,
+        **kw,
+    )
+
+
+@register("qwen3_next")
+def _qwen3_next(*, num_classes, policy, axis_name, **kw):
+    # the same HybridLM in Qwen3-Next's layout: a layer is a mixer
+    # sub-layer (Gated DeltaNet 'G', every fourth gated attention 'A') and
+    # an expert sub-layer 'Q' (softmax router, gated shared expert), every
+    # norm zero-centred, rotary on part of a head, untied head; test-sized
+    # defaults, the published widths come as options
+    # (perf/families/qwen3_next.py model_options)
+    kw.setdefault("pattern", "GQGQGQAQ")
+    kw.setdefault("norm_plus_one", True)
+    kw.setdefault("norm_eps", 1e-6)
+    kw.setdefault("pos_emb", "rope")
+    kw.setdefault("hidden_dim", 64)
+    kw.setdefault("head_dim", 32)
     return HybridLM(
         dtype=policy.compute_dtype,
         param_dtype=policy.param_dtype,

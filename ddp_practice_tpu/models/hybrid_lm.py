@@ -6,27 +6,39 @@ a pattern string.
     'M'  Mamba-2 mixer                 (ops/ssm.py)
     'S'  Mamba-1 (selective scan) mixer: a decay for every channel and
          state, a low-rank dt, RMSNorm on dt, B and C (Jamba's)
+    'G'  Gated DeltaNet mixer: the gated delta rule on a (key_dim,
+         value_dim) matrix state a value head   (ops/gdn.py)
     'E'  LatentMoE, a chip's share of the experts held  (ops/moe.py)
+    'Q'  GatedMoE with a softmax router and a shared expert behind a
+         sigmoid gate of its own, a chip's share held   (ops/moe.py)
     'D'  dense SwiGLU MLP of width `mlp_dim`            (ops/moe.py GatedMLP)
     '*'  causal attention, `kv_heads` <= `num_heads`, no positional
          embedding: the recurrent layers carry position
+    'A'  gated attention: heads of `head_dim` whatever the width, a norm
+         on every q and k head, rotary on a head's first `rope_dim`
+         lanes, the output times sigmoid(gate), the gate the query
+         projection's second half (`pos_emb="rope"`)
     logits = RMSNorm(x) W_head         over `vocab_size` rows; with
                                        `tie_embeddings` W_head is the
                                        embedding itself
 
-Two layouts in the registry. Nemotron-H (`create_model("nemotron_h", ...)`):
-one mixer a layer from 'M', 'E', '*', untied head. Jamba
+Three layouts in the registry. Nemotron-H (`create_model("nemotron_h",
+...)`): one mixer a layer from 'M', 'E', '*', untied head. Jamba
 (`create_model("jamba", ...)`): a layer is two sub-layers, a mixer ('S' or
-'*') then 'D', so 28 layers are 56 letters, and the head is tied. The widths
-are options, so the tests run both small and the benchmark at the published
-sizes (perf/configs/nemotron3_super_ep4.json, perf/configs/jamba2_3b.json).
+'*') then 'D', so 28 layers are 56 letters, and the head is tied. Qwen3-Next
+(`create_model("qwen3_next", ...)`): a mixer ('G', every fourth 'A') then
+'Q', every norm the zero-centred `(1 + w)` one (`norm_plus_one`), untied
+head. The widths are options, so the tests run all three small and the
+benchmark at the published sizes (perf/configs/nemotron3_super_ep4.json,
+jamba2_3b.json, qwen3next_80b_ep4.json).
 
 Decode mode keeps TWO kinds of cache in the "cache" collection: attention
 layers the K/V leaves `SelfAttention` declares (flat, or pages under
 `PagedEngine`), state-space layers a fixed-size state a sequence under the
 SAME two leaf names whichever mixer: `ssm_state` in float32 (Mamba-2
 (b, heads, head_dim, state); Mamba-1 (b, state, channels / 128, 128), the
-layout `ops/ssm.py sel_step` reads) and `conv_state`, the conv's last
+layout `ops/ssm.py sel_step` reads; Gated DeltaNet (b, value heads,
+key_dim, value_dim)) and `conv_state`, the conv's last
 `conv_kernel - 1` inputs. A call with one token a sequence advances the
 state by the recurrence; a call with more runs the scan FROM the
 stored state (zeros for a fresh sequence) and leaves the final state.
@@ -39,6 +51,7 @@ engine construction.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import flax.linen as nn
@@ -46,8 +59,8 @@ import jax
 import jax.numpy as jnp
 
 from ddp_practice_tpu.models.vit import SelfAttention
-from ddp_practice_tpu.ops import ssm
-from ddp_practice_tpu.ops.moe import GatedMLP, LatentMoE
+from ddp_practice_tpu.ops import gdn, ssm
+from ddp_practice_tpu.ops.moe import GatedMLP, GatedMoE, LatentMoE
 
 
 class RMSNorm(nn.Module):
@@ -56,11 +69,18 @@ class RMSNorm(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
     # > 1: the last dim is normalised in that many equal groups
     groups: int = 1
+    # zero-centred: the learned `weight` starts at 0 and scales by 1 + w
+    plus_one: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           self.param_dtype)
+        if self.plus_one:
+            scale = 1.0 + self.param(
+                "weight", nn.initializers.zeros, (x.shape[-1],),
+                self.param_dtype).astype(jnp.float32)
+        else:
+            scale = self.param("scale", nn.initializers.ones,
+                               (x.shape[-1],), self.param_dtype)
         xf = x.astype(jnp.float32)
         shaped = xf.reshape(*x.shape[:-1], self.groups, -1)
         shaped = shaped * jax.lax.rsqrt(
@@ -213,6 +233,81 @@ class Mamba1Mixer(nn.Module):
         return nn.Dense(d, use_bias=False, name="out_proj", **kw)(y)
 
 
+class GatedDeltaMixer(nn.Module):
+    """[q|k|v|z] = x W_in, [b|a] = x W_ba; silu(conv([q|k|v])), no bias;
+    q, k L2-normalised a head, q / sqrt(key_dim); beta = sigmoid(b),
+    g = -exp(A_log) softplus(a + dt_bias); the gated delta rule (ops/gdn.py)
+    on a (key_dim, value_dim) state a value head, a key head serving
+    value_heads / key_heads of them; out = (RMSNorm_head(o) * silu(z)) W_out
+    with the norm and the gate in float32."""
+
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv_kernel: int = 4
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, *, decode: bool = False, attn_start=None,
+                 paged: bool = False):
+        b, s, d = x.shape
+        hk, hv, dk, dv = (self.key_heads, self.value_heads, self.key_dim,
+                          self.value_dim)
+        keys, values = hk * dk, hv * dv
+        conv_dim = 2 * keys + values
+        f32 = jnp.float32
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        vec = lambda name, shape: self.param(
+            name, nn.initializers.normal(0.02), shape, self.param_dtype)
+        qkv, z = jnp.split(
+            nn.Dense(conv_dim + values, use_bias=False, name="in_proj",
+                     **kw)(x), [conv_dim], axis=-1)
+        beta, a = jnp.split(
+            nn.Dense(2 * hv, use_bias=False, name="ba_proj", **kw)(x)
+            .astype(f32), 2, axis=-1)
+        conv_w = vec("conv_kernel", (self.conv_kernel, conv_dim))
+        beta = nn.sigmoid(beta)
+        g = -jnp.exp(vec("A_log", (hv,)).astype(f32)) * jax.nn.softplus(
+            a + vec("dt_bias", (hv,)).astype(f32))
+        if attn_start is not None and s > 1:
+            # a call of several tokens is a padded row from its position 0
+            # (the engines' prefill); a single token is always real
+            real = (jnp.arange(s)[None, :] >= attn_start[:, None])[..., None]
+            beta, g = jnp.where(real, beta, 0.0), jnp.where(real, g, 0.0)
+            qkv = jnp.where(real, qkv, 0)
+        state0 = jnp.zeros((b, hv, dk, dv), f32)
+        tail0 = jnp.zeros((b, self.conv_kernel - 1, conv_dim), self.dtype)
+        if decode:
+            ssm_state, conv_state = _state_leaves(
+                self, state0, tail0, paged and s > 1)
+            if not self.is_initializing():
+                state0, tail0 = ssm_state.value, conv_state.value
+        qkv, tail = ssm.causal_conv(qkv, tail0, conv_w, None)
+        q, k, v = jnp.split(nn.silu(qkv).astype(f32), [keys, 2 * keys],
+                            axis=-1)
+        unit = lambda t: t * jax.lax.rsqrt(
+            jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+        q = unit(q.reshape(b, s, hk, dk)) * dk ** -0.5
+        k = unit(k.reshape(b, s, hk, dk))
+        v = v.reshape(b, s, hv, dv)
+        if decode and s == 1 and not self.is_initializing():
+            o, state = gdn.gdn_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                    beta[:, 0], state0)
+            o = o[:, None]
+        else:
+            o, state = gdn.gdn_scan(q, k, v, g, beta, state0)
+        if decode and not self.is_initializing():
+            ssm_state.value = state
+            conv_state.value = tail.astype(conv_state.value.dtype)
+        o = RMSNorm(self.norm_eps, f32, self.param_dtype, name="norm")(o)
+        o = o * nn.silu(z.astype(f32).reshape(b, s, hv, dv))
+        return nn.Dense(d, use_bias=False, name="out_proj", **kw)(
+            o.reshape(b, s, values).astype(self.dtype))
+
+
 class HybridLM(nn.Module):
     pattern: str = "MEM*EME"
     vocab_size: int = 256
@@ -228,13 +323,21 @@ class HybridLM(nn.Module):
     # 'S'
     mamba_inner: int = 128
     dt_rank: int = 8
+    # 'G'
+    gdn_key_heads: int = 2
+    gdn_value_heads: int = 4
+    gdn_key_dim: int = 16
+    gdn_value_dim: int = 16
     # 'D'
     mlp_dim: int = 128
-    # '*'
+    # '*', 'A'
     num_heads: int = 4
     kv_heads: int = 2
     head_dim: int = 16
-    # 'E'
+    # 'A'
+    rope_dim: int = 8
+    rope_theta: float = 10000.0
+    # 'E', 'Q' ('Q' has no latent)
     num_experts: int = 16
     top_k: int = 3
     latent_dim: int = 32
@@ -244,6 +347,8 @@ class HybridLM(nn.Module):
     expert_offset: int = 0
     routed_scaling: float = 1.0
     norm_eps: float = 1e-5
+    # every RMSNorm of the residual stream and of 'A' scales by 1 + w
+    norm_plus_one: bool = False
     tie_embeddings: bool = False
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
@@ -261,15 +366,18 @@ class HybridLM(nn.Module):
         the compute dtype. `decode`, `attn_start`, `page_table` and
         `kv_lengths` as in models/lm.py TransformerLM."""
         del train
-        if set(self.pattern) - set("MSED*") or not self.pattern:
+        if set(self.pattern) - set("MSGEQD*A") or not self.pattern:
             raise ValueError(
-                f"pattern {self.pattern!r}: want a string of 'M', 'S', 'E', "
-                "'D', '*'")
-        if self.hidden_dim != self.num_heads * self.head_dim:
+                f"pattern {self.pattern!r}: want a string of 'M', 'S', 'G', "
+                "'E', 'Q', 'D', '*', 'A'")
+        if "*" in self.pattern \
+                and self.hidden_dim != self.num_heads * self.head_dim:
             raise ValueError(
-                "SelfAttention takes its head size from the width: "
+                "'*' takes its head size from the width: "
                 f"hidden_dim {self.hidden_dim} != num_heads "
                 f"{self.num_heads} x head_dim {self.head_dim}")
+        if "A" in self.pattern and self.pos_emb != "rope":
+            raise ValueError("'A' rotates q and k: want pos_emb='rope'")
         if (page_table is not None or attn_start is not None) and not decode:
             raise ValueError("page_table / attn_start are decode features")
         if tokens.shape[1] > self.max_len:
@@ -279,8 +387,10 @@ class HybridLM(nn.Module):
         embed = nn.Embed(self.vocab_size, self.hidden_dim, name="tok_embed",
                          **kw)
         x = embed(tokens)
+        norm = functools.partial(RMSNorm, self.norm_eps,
+                                 plus_one=self.norm_plus_one, **kw)
         for i, kind in enumerate(self.pattern):
-            y = RMSNorm(self.norm_eps, name=f"norm{i}", **kw)(x)
+            y = norm(name=f"norm{i}")(x)
             if kind == "M":
                 y = Mamba2Mixer(
                     self.mamba_heads, self.mamba_head_dim, self.ssm_state,
@@ -294,8 +404,22 @@ class HybridLM(nn.Module):
                     self.conv_kernel, self.norm_eps, name=f"mamba{i}", **kw,
                 )(y, decode=decode, attn_start=attn_start,
                   paged=page_table is not None)
+            elif kind == "G":
+                y = GatedDeltaMixer(
+                    self.gdn_key_heads, self.gdn_value_heads,
+                    self.gdn_key_dim, self.gdn_value_dim, self.conv_kernel,
+                    self.norm_eps, name=f"mamba{i}", **kw,
+                )(y, decode=decode, attn_start=attn_start,
+                  paged=page_table is not None)
             elif kind == "D":
                 y = GatedMLP(self.mlp_dim, name=f"mlp{i}", **kw)(y)
+            elif kind == "Q":
+                y = GatedMoE(
+                    self.num_experts, self.top_k, self.expert_dim,
+                    self.shared_dim, self.experts_held, self.expert_offset,
+                    self.routed_scaling, router="softmax", shared_gate=True,
+                    name=f"moe{i}", **kw,
+                )(y, decode=decode)
             elif kind == "E":
                 y = LatentMoE(
                     self.num_experts, self.top_k, self.latent_dim,
@@ -303,6 +427,15 @@ class HybridLM(nn.Module):
                     self.expert_offset, self.routed_scaling,
                     name=f"moe{i}", **kw,
                 )(y, decode=decode)
+            elif kind == "A":
+                y = SelfAttention(
+                    self.num_heads, causal=True, rope=True,
+                    kv_heads=self.kv_heads, use_bias=False,
+                    head_dim=self.head_dim, qk_norm=norm,
+                    rope_dim=self.rope_dim, rope_theta=self.rope_theta,
+                    out_gate=True, name=f"attn{i}", **kw,
+                )(y, decode=decode, attn_start=attn_start,
+                  page_table=page_table, kv_lengths=kv_lengths)
             else:
                 y = SelfAttention(
                     self.num_heads, causal=True, rope=False,
@@ -311,7 +444,7 @@ class HybridLM(nn.Module):
                 )(y, decode=decode, attn_start=attn_start,
                   page_table=page_table, kv_lengths=kv_lengths)
             x = x + y
-        x = RMSNorm(self.norm_eps, name="norm_f", **kw)(x)
+        x = norm(name="norm_f")(x)
         if self.tie_embeddings:
             return embed.attend(x)
         return nn.Dense(self.vocab_size, use_bias=False, name="lm_head",

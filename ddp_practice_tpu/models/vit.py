@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Optional
+from typing import Callable, Optional
 
 import flax.linen as nn
 import jax.numpy as jnp
@@ -149,6 +149,19 @@ class SelfAttention(nn.Module):
     # kv_heads * head_dim wide.
     kv_heads: Optional[int] = None
     use_bias: bool = True
+    # what a later decoder block adds, each off by default (the plain XLA
+    # attention and the decode paths take them; the flash paths refuse):
+    # a head size that is not width / heads;
+    head_dim: Optional[int] = None
+    # a norm over each head of q and of k before the rotation: a module
+    # constructor called as `qk_norm(name=...)`;
+    qk_norm: Optional[Callable] = None
+    # the rotation on a head's first `rope_dim` lanes only, at this base;
+    rope_dim: Optional[int] = None
+    rope_theta: float = 10000.0
+    # and a sigmoid gate on the attention output from the query
+    # projection's second half (a head projects to [query | gate])
+    out_gate: bool = False
 
     def _project(self, x, head_dim: int):
         """(q, k, v, fused): q (b, s, h, hd), k and v (b, s, kv_heads,
@@ -164,9 +177,22 @@ class SelfAttention(nn.Module):
         if self.num_heads % kvh:
             raise ValueError(
                 f"kv_heads {kvh} must divide num_heads {self.num_heads}")
-        q = dense((self.num_heads, head_dim), name="q")(x)
+        q = dense((self.num_heads, (1 + self.out_gate) * head_dim),
+                  name="q")(x)
         kv = dense((2, kvh, head_dim), name="kv")(x)
         return q, kv[:, :, 0], kv[:, :, 1], None
+
+    def _rotate(self, q, k, positions):
+        """Rotary q and k at `positions`: whole heads, or the first
+        `rope_dim` lanes of each (the frequencies are the part's)."""
+        r = self.rope_dim
+        if r is None:
+            return (apply_rope(q, positions, theta=self.rope_theta),
+                    apply_rope(k, positions, theta=self.rope_theta))
+        part = lambda x: jnp.concatenate(
+            [apply_rope(x[..., :r], positions, theta=self.rope_theta),
+             x[..., r:]], axis=-1)
+        return part(q), part(k)
 
     def _dense_flat(self, name: str, x):
         """The DenseGeneral `name` once more (same parameters, after the
@@ -271,10 +297,24 @@ class SelfAttention(nn.Module):
     def __call__(self, x, *, decode: bool = False, attn_start=None,
                  page_table=None, kv_lengths=None):
         b, s, d = x.shape
-        assert d % self.num_heads == 0, (d, self.num_heads)
-        head_dim = d // self.num_heads
+        if self.head_dim is None:
+            assert d % self.num_heads == 0, (d, self.num_heads)
+        head_dim = self.head_dim or d // self.num_heads
         q, k, v, qkv = self._project(x, head_dim)
         impl = self.resolve_attn_impl(s, head_dim, decode=decode)
+        later = (self.head_dim is not None or self.qk_norm is not None
+                 or self.rope_dim is not None or self.out_gate)
+        if later and (impl != "xla" or qkv is not None):
+            raise ValueError(
+                "head_dim, qk_norm, rope_dim and out_gate run on the plain "
+                "attention path with a split q / kv projection: attn_impl "
+                f"resolved to {impl!r}, kv_heads {self.kv_heads}")
+        gate = None
+        if self.out_gate:
+            q, gate = q[..., :head_dim], q[..., head_dim:]
+        if self.qk_norm is not None:
+            q = self.qk_norm(name="q_norm")(q)
+            k = self.qk_norm(name="k_norm")(k)
         flat = impl == "flash" and self._flat_block(head_dim, decode)
         if _RESOLVED is not None and not decode:
             _RESOLVED.add("flash_flat" if flat else impl)
@@ -361,8 +401,7 @@ class SelfAttention(nn.Module):
             # Rotations bake absolute position into Q/K, so attention
             # scores depend only on relative offsets downstream.
             positions = jnp.arange(s)
-            q = apply_rope(q, positions)
-            k = apply_rope(k, positions)
+            q, k = self._rotate(q, k, positions)
         if decode:
             # KV-cache incremental decoding: the cache collection holds
             # pre-allocated FLAT (b, max_len, h*hd) key/value buffers
@@ -392,7 +431,7 @@ class SelfAttention(nn.Module):
                 # branch before the flat-cache declarations below.
                 return self._out_proj(self._paged_decode(
                     q, k, v, page_table, kv_lengths, attn_start
-                ))
+                ), gate, d)
             # "int8": quantized cache — 1 byte/element plus per-(batch,
             # head, position) fp32 scales. Decode is HBM-bound and the
             # cache is ~40% of its traffic at batched sizes, so this is
@@ -446,8 +485,7 @@ class SelfAttention(nn.Module):
                     # cached keys are stored rotated, so only the incoming
                     # block needs rotation — at its absolute positions
                     positions = cur + jnp.arange(s)
-                    q = apply_rope(q, positions)
-                    k = apply_rope(k, positions)
+                    q, k = self._rotate(q, k, positions)
                 if quant:
                     def _quantize(x4):
                         # per-(batch, token, head) symmetric int8: the
@@ -529,7 +567,7 @@ class SelfAttention(nn.Module):
                 seq_axis=self.seq_axis,
                 sp_impl=self.sp_impl, impl=impl,
             )
-        return self._out_proj(out)
+        return self._out_proj(out, gate, d)
 
     def _paged_decode(self, q, k, v, page_table, kv_lengths, attn_start):
         """Paged KV-cache decode step / prefill (serve/kv_pages.py).
@@ -620,8 +658,7 @@ class SelfAttention(nn.Module):
             # no rotation at all is as slot-local as a rotary one (a model
             # whose other layers carry position); a learned absolute table
             # is refused where it lives (models/lm.py)
-            q = apply_rope(q, positions)
-            k = apply_rope(k, positions)
+            q, k = self._rotate(q, k, positions)
         if quant:
             def _quantize(x4):
                 # per-(batch, token, head) symmetric int8, same recipe
@@ -685,11 +722,16 @@ class SelfAttention(nn.Module):
         )
         return out.reshape(b_, s_, self.num_heads, hd_).astype(q.dtype)
 
-    def _out_proj(self, out):
+    def _out_proj(self, out, gate=None, width=None):
         """Shared output projection over (b, s, h, hd) attention output —
         one definition for the fused-QKV and sliced/decode paths (they
-        share the 'out' parameters)."""
-        d = out.shape[-2] * out.shape[-1]
+        share the 'out' parameters), onto `width` features (heads x
+        head size where none is given: the model's width unless
+        `head_dim` is set). `gate` (b, s, h, hd): out * sigmoid(gate)
+        first."""
+        if gate is not None:
+            out = out * nn.sigmoid(gate)
+        d = width or out.shape[-2] * out.shape[-1]
         return nn.DenseGeneral(
             d,
             axis=(-2, -1),

@@ -1,0 +1,334 @@
+"""The gated delta rule (Gated DeltaNet): a matrix state a head, a prompt's
+chunked scan and the one-token recurrence of decode.
+
+A value head h (its key head is h // (value heads / key heads)) keeps a
+state S (key_dim, value_dim) in float32:
+
+    S  <- exp(g_t) S                         g_t <= 0, one scalar a head
+    d  =  beta_t (v_t - S^T k_t)             the write reads the decayed state
+    S  <- S + k_t d^T
+    o_t = S^T q_t
+
+`ops/ssm.py ssm_step` (Mamba-2) has the decay, the outer product and the
+contraction; the READ of the decayed state before the write is what it
+lacks, and what makes the chunked form need a triangular solve a chunk.
+
+`gdn_scan`, over a chunk of C positions with G the running sum of g inside
+it and S0 the state entering it:
+
+    A[t,s] = beta_t exp(G_t - G_s) (k_t . k_s)   s < t, else 0
+    T      = (I + A)^-1
+    U      = T (beta V) - T (beta exp(G) K) S0   the rows d_t
+    O      = (exp(G) Q) S0 + tril(Q K^T exp(G_t - G_s)) U
+    S_C    = exp(G_C) S0 + (exp(G_C - G) K)^T U
+
+Everything that does not hold S0 is computed for all chunks at once as plain
+matmuls in XLA (`_chunk_terms`; A is strictly lower of C = 64 rows and
+inverted by blocks, `_unit_lower_inverse`: ten 64^3 matmuls and no solve);
+the three lines that do are the sequential part: the Pallas kernel
+`gdn_scan`, the state in VMEM from chunk to chunk, off the TPU a `lax.scan`
+over the chunks. A position with beta = 0, g = 0 and zero q, k, v moves
+nothing: left padding and the tail of a partial chunk.
+
+Device op names (PERF.md section 3): the kernels are `gdn_step` and
+`gdn_scan`; the chunk terms are XLA fusions under the scope `gdn_scan`.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ddp_practice_tpu.ops.ssm import _heads_of_groups
+from ddp_practice_tpu.utils import backend
+
+F32 = jnp.float32
+HIGHEST = lax.Precision.HIGHEST
+# positions a chunk of the scan holds
+CHUNK = 64
+_LANES = 128
+# value heads a grid cell of either kernel takes (64 KB of state each)
+_HEADS = 16
+# the scan's cell holds a chunk's six terms and its output for 16 heads two
+# deep (5.6 MB at 128 x 128 heads) beside 4 MB of state blocks
+_SCAN_VMEM = 40 << 20
+
+
+def _dot(x, y, dims=(((1,), (0,)), ((), ()))):
+    """float32 matmul at full precision (inside a kernel Mosaic's default
+    is its own; the state's error would feed back through every read)."""
+    return lax.dot_general(x, y, dims, precision=HIGHEST,
+                           preferred_element_type=F32)
+
+
+_TA = (((0,), (0,)), ((), ()))   # x^T y
+
+
+def gdn_step_reference(q, k, v, g, beta, state):
+    """One token, plain jax.numpy. q, k (b, hk, dk), normalised and scaled
+    by the caller; v (b, hv, dv); g, beta (b, hv); state (b, hv, dk, dv)
+    float32. Returns (o (b, hv, dv) float32, new state)."""
+    hv = v.shape[1]
+    q, k = (_heads_of_groups(x.astype(F32), hv) for x in (q, k))
+    s = state * jnp.exp(g.astype(F32))[..., None, None]
+    read = jnp.einsum("bhkv,bhk->bhv", s, k, precision=HIGHEST)
+    d = beta.astype(F32)[..., None] * (v.astype(F32) - read)
+    s = s + k[..., :, None] * d[..., None, :]
+    return jnp.einsum("bhkv,bhk->bhv", s, q, precision=HIGHEST), s
+
+
+def gdn_scan_reference(q, k, v, g, beta, h0):
+    """The recurrence one position at a time (`lax.scan`): what the chunked
+    form must equal. q, k (b, l, hk, dk); v (b, l, hv, dv); g, beta
+    (b, l, hv); h0 (b, hv, dk, dv). Returns (o (b, l, hv, dv) float32,
+    final state)."""
+    def one(state, inp):
+        o_t, state = gdn_step_reference(*inp, state)
+        return state, o_t
+
+    xs = tuple(jnp.moveaxis(x.astype(F32), 1, 0) for x in (q, k, v, g, beta))
+    final, os_ = lax.scan(one, h0.astype(F32), xs)
+    return jnp.moveaxis(os_, 0, 1), final
+
+
+# ------------------------------------------------------------- decode step
+def _step_kernel(q_ref, k_ref, v_ref, da_ref, beta_ref, h_ref, o_ref, ho_ref,
+                 *, heads, per_key):
+    """One grid cell: one sequence, `heads` value heads. A head's (dk, dv)
+    tile is decayed, read, written and read again on the VPU, whole
+    registers at a time: k and q stand as COLUMNS broadcast along the lanes
+    (`col[a, b] = x[a]`, made once a key head as a depth-8 matmul of the
+    vector against ones, row 0 real: a column vector is not a layout the
+    lanes hold), so `S^T k` is a product of two tiles summed down the
+    sublanes and `k (x) d` a product with d's row. (Through the MXU, the
+    vector as eight equal rows against the tile, each head paid two float32
+    passes of a (128, 128) operand and the kernel read a third of its
+    roofline: PERF.md section 6, PR 38.)"""
+    dk, dv = h_ref.shape[-2:]
+    row0 = lax.broadcasted_iota(jnp.int32, (8, 1), 0) == 0
+    ones = jnp.ones((8, dv), F32)
+    col = lambda ref, j: _dot(
+        jnp.where(row0, jnp.broadcast_to(ref[j:j + 1, :], (8, dk)), 0.0),
+        ones, _TA)                                             # (dk, dv)
+    for j in range(heads // per_key):
+        kcol, qcol = col(k_ref, j), col(q_ref, j)
+        for i in range(j * per_key, (j + 1) * per_key):
+            s = h_ref[i] * da_ref[i:i + 1, :]
+            read = jnp.sum(s * kcol, axis=0, keepdims=True)    # (1, dv)
+            d = beta_ref[i:i + 1, :] * (v_ref[i:i + 1, :] - read)
+            new = s + kcol * d
+            ho_ref[i] = new
+            o_ref[i:i + 1, :] = jnp.sum(new * qcol, axis=0, keepdims=True)
+
+
+def gdn_step(q, k, v, g, beta, state):
+    """One token of the recurrence for every sequence; arguments and results
+    as `gdn_step_reference`: the Pallas kernel on the TPU, plain jax.numpy
+    elsewhere."""
+    with jax.named_scope("gdn_step"):
+        if not backend.on_tpu():
+            return gdn_step_reference(q, k, v, g, beta, state)
+        return gdn_step_kernel(q, k, v, g, beta, state)
+
+
+def _head_block(hv: int, hk: int) -> tuple:
+    """(value heads, key heads) a grid cell takes: `_HEADS` value heads
+    where that is whole key heads in whole sublane tiles, else all."""
+    per_key = hv // hk
+    if hv % _HEADS == 0 and _HEADS % per_key == 0 \
+            and (_HEADS // per_key) % 8 == 0:
+        return _HEADS, _HEADS // per_key
+    return hv, hk
+
+
+def gdn_step_kernel(q, k, v, g, beta, state):
+    """`gdn_step` as ONE device op of that name (interpret mode off the
+    TPU, where only the tests call it)."""
+    bsz, hv, dv = v.shape
+    hk, dk = k.shape[1:]
+    bh, bk = _head_block(hv, hk)
+    # a head's decay and beta, laid along the lanes so the kernel broadcasts
+    # them down the sublanes (1 KB a head beside its 128 KB of state)
+    lanes = lambda x: jnp.broadcast_to(x.astype(F32)[..., None],
+                                       (bsz, hv, dv))
+    key = pl.BlockSpec((None, bk, dk), lambda i, j: (i, j, 0))
+    val = pl.BlockSpec((None, bh, dv), lambda i, j: (i, j, 0))
+    st = pl.BlockSpec((None, bh, dk, dv), lambda i, j: (i, j, 0, 0))
+    o, new = pl.pallas_call(
+        functools.partial(_step_kernel, heads=bh, per_key=hv // hk),
+        grid=(bsz, hv // bh),
+        in_specs=[key, key, val, val, val, st],
+        out_specs=[val, st],
+        out_shape=[jax.ShapeDtypeStruct((bsz, hv, dv), F32),
+                   jax.ShapeDtypeStruct(state.shape, F32)],
+        input_output_aliases={5: 1},     # the state is rewritten in place
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=not backend.on_tpu(),
+        name="gdn_step",
+    )(q.astype(F32), k.astype(F32), v.astype(F32),
+      lanes(jnp.exp(g.astype(F32))), lanes(beta), state)
+    return o, new
+
+
+# ------------------------------------------------------------ prefill scan
+def _chunk_terms(q, k, v, g, beta, chunk: int) -> dict:
+    """What the chunked form needs of every chunk that does not hold the
+    state, for all chunks at once; each (b, hv, nc, C, .) float32:
+    `w` = T (beta exp(G) K), `u0` = T (beta V), `qg` = exp(G) Q, `p` =
+    tril(Q K^T exp(G_t - G_s)) (C rows, its columns padded to 128 lanes),
+    `kend` = exp(G_C - G) K, `dend` = exp(G_C) along value_dim lanes
+    (b, hv, nc, 1, dv)."""
+    bsz, l, hv, dv = v.shape
+    nc, c = l // chunk, chunk
+    heads = lambda x: jnp.moveaxis(        # (b, l, h, d) -> (b, h, nc, C, d)
+        x.astype(F32).reshape(bsz, nc, c, *x.shape[2:]), 3, 1)
+    qh, kh = (heads(_heads_of_groups(x, hv)) for x in (q, k))
+    vh = heads(v)
+    gh, bh = (heads(x[..., None])[..., 0] for x in (g, beta))  # (b,h,nc,C)
+    cum = jnp.cumsum(gh, axis=-1)
+    seg = cum[..., :, None] - cum[..., None, :]                # G_t - G_s
+    low = jnp.tril(jnp.ones((c, c), bool))
+    decay = jnp.where(low, jnp.exp(jnp.where(low, seg, 0.0)), 0.0)
+    mm = functools.partial(jnp.einsum, precision=HIGHEST)
+    kk = mm("bhctd,bhcsd->bhcts", kh, kh)
+    strict = jnp.tril(jnp.ones((c, c), bool), -1)
+    t = _unit_lower_inverse(
+        jnp.where(strict, bh[..., :, None] * decay * kk, 0.0))
+    eg = jnp.exp(cum)[..., None]
+    return {
+        "w": mm("bhcts,bhcsd->bhctd", t, bh[..., None] * eg * kh),
+        "u0": mm("bhcts,bhcsd->bhctd", t, bh[..., None] * vh),
+        "qg": eg * qh,
+        # its C columns zero-filled to whole 128-lane tiles: the kernel's
+        # matmul against it contracts over lanes that hold something
+        "p": jnp.pad(decay * mm("bhctd,bhcsd->bhcts", qh, kh),
+                     ((0, 0),) * 4 + ((0, -c % _LANES),)),
+        "kend": jnp.exp(cum[..., -1:] - cum)[..., None] * kh,
+        "dend": jnp.broadcast_to(jnp.exp(cum[..., -1])[..., None, None],
+                                 (bsz, hv, nc, 1, dv)),
+    }
+
+
+def _unit_lower_inverse(a):
+    """(I + a)^-1 of a strictly lower (..., c, c), as matmuls of whole
+    (c, c) matrices and no solve, by blocks: the 8-wide diagonal blocks d
+    first, (I + d)^-1 = (I - d)(I + d^2)(I + d^4) (d^8 = 0; four matmuls),
+    then block pairs merged three times, 8 -> 16 -> 32 -> 64: with T the
+    inverse of the block diagonal and L what the next size adds below it,
+    (D + L)^-1 = T - T L T exactly (L T L = 0; two matmuls a level). Ten
+    matmuls at c = 64, as many as the closed product
+    prod_j (I + (-a)^(2^j)) takes, whose powers reach 1e14 where the keys of
+    a chunk are alike and the decay slow (a prompt of one repeated token)
+    and cancel to nothing in float32: PERF.md section 6, PR 38."""
+    c = a.shape[-1]
+    at = jnp.arange(c)
+    same = lambda size: (at[:, None] // size) == (at[None, :] // size)
+    mm = lambda x, y: jnp.einsum("...ts,...sr->...tr", x, y,
+                                 precision=HIGHEST)
+    d = jnp.where(same(8), a, 0.0)
+    d2 = mm(d, d)
+    t = jnp.eye(c, dtype=F32) - d
+    t = t + mm(t, d2)
+    t = t + mm(t, mm(d2, d2))
+    size = 8
+    while size < c:
+        below = jnp.where(same(2 * size) & ~same(size), a, 0.0)
+        t = t - mm(t, mm(below, t))
+        size *= 2
+    return t
+
+
+def _carry_chunk(s, w, u0, qg, p, kend, dend):
+    """The three lines of one chunk that hold the state. s (dk, dv)."""
+    u = u0 - _dot(w, s)
+    rows = jnp.concatenate(   # zero rows under p's zero columns
+        [u, jnp.zeros((p.shape[-1] - u.shape[0], u.shape[1]), F32)]) \
+        if p.shape[-1] > u.shape[0] else u
+    o = _dot(qg, s) + _dot(p, rows)
+    return o, dend * s + _dot(kend, u, _TA)
+
+
+def _carry_reference(terms: dict, h0):
+    """The state through the chunks, a `lax.scan`: (o (b, hv, nc, C, dv),
+    final state)."""
+    one = jax.vmap(jax.vmap(_carry_chunk))       # over batch and heads
+
+    def step(s, chunk):
+        o, s = one(s, *chunk)
+        return s, o
+
+    order = ("w", "u0", "qg", "p", "kend", "dend")
+    final, o = lax.scan(
+        step, h0, tuple(jnp.moveaxis(terms[n], 2, 0) for n in order))
+    return jnp.moveaxis(o, 0, 2), final
+
+
+def _scan_kernel(w_ref, u0_ref, qg_ref, p_ref, kend_ref, dend_ref, h0_ref,
+                 o_ref, ho_ref, *, heads):
+    """Grid (sequences, head blocks, chunks), chunks innermost and in order:
+    the block's state stays in VMEM from chunk to chunk (`ho_ref`, whose
+    block index ignores the chunk axis, so it goes to HBM once, after the
+    last chunk), seeded from `h0_ref`."""
+    @pl.when(pl.program_id(2) == 0)
+    def _seed():
+        ho_ref[...] = h0_ref[...]
+
+    for i in range(heads):
+        o, new = _carry_chunk(ho_ref[i], w_ref[i], u0_ref[i], qg_ref[i],
+                              p_ref[i], kend_ref[i], dend_ref[i])
+        o_ref[i] = o
+        ho_ref[i] = new
+
+
+def _carry_kernel(terms: dict, h0):
+    """`_carry_reference` as ONE device op, `gdn_scan`."""
+    bsz, hv, nc, c, dv = terms["u0"].shape
+    dk = terms["w"].shape[-1]
+    bh = _HEADS if hv % _HEADS == 0 else hv
+    per = lambda rows, width: pl.BlockSpec(
+        (None, bh, None, rows, width), lambda i, j, t: (i, j, t, 0, 0))
+    st = pl.BlockSpec((None, bh, dk, dv), lambda i, j, t: (i, j, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_scan_kernel, heads=bh),
+        grid=(bsz, hv // bh, nc),
+        in_specs=[per(c, dk), per(c, dv), per(c, dk),
+                  per(c, terms["p"].shape[-1]), per(c, dk), per(1, dv), st],
+        out_specs=[per(c, dv), st],
+        out_shape=[jax.ShapeDtypeStruct((bsz, hv, nc, c, dv), F32),
+                   jax.ShapeDtypeStruct(h0.shape, F32)],
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_SCAN_VMEM),
+        interpret=not backend.on_tpu(),
+        name="gdn_scan",
+    )(*(terms[n] for n in ("w", "u0", "qg", "p", "kend", "dend")), h0)
+
+
+def gdn_scan(q, k, v, g, beta, h0, *, chunk: int = CHUNK,
+             kernel: bool | None = None):
+    """The recurrence over a whole call of several tokens, FROM `h0`;
+    arguments and results as `gdn_scan_reference`, by the chunked form. The
+    carry through the chunks is the Pallas kernel on the TPU (`kernel`
+    None), a `lax.scan` elsewhere; the tests name either."""
+    with jax.named_scope("gdn_scan"):
+        l = v.shape[1]
+        c = min(chunk, l)
+        pad = -l % c
+        ins = (q, k, v, g, beta)
+        if pad:  # beta = 0, g = 0, zero q, k, v: nothing moves
+            ins = tuple(jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),)
+                                * (x.ndim - 2)) for x in ins)
+        terms = _chunk_terms(*ins, c)
+        use = backend.on_tpu() if kernel is None else kernel
+        o, final = (_carry_kernel if use else _carry_reference)(
+            terms, h0.astype(F32))
+        bsz, hv, nc, _, dv = o.shape
+        o = jnp.moveaxis(o, 1, 3).reshape(bsz, nc * c, hv, dv)
+        return o[:, :l], final

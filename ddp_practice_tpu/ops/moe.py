@@ -1033,6 +1033,16 @@ def route_sigmoid_topk(scores_logits, select_bias, *, k: int,
     return choices.astype(jnp.int32), w * scaling
 
 
+def route_softmax_topk(scores_logits, *, k: int, scaling: float):
+    """Softmax router: the k largest of softmax(logits) over ALL experts,
+    renormalised to sum 1 over the picks, times `scaling`. Returns
+    (choices (N, k) int32, weights (N, k) float32)."""
+    p = jax.nn.softmax(scores_logits.astype(jnp.float32), axis=-1)
+    w, choices = jax.lax.top_k(p, k)
+    w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-20)
+    return choices.astype(jnp.int32), w * scaling
+
+
 def held_tile_layout(choices, *, offset: int, held: int, tile: int):
     """Where each pick's row goes. choices (N, k) over ALL the experts the
     router scores; experts `offset` .. `offset + held` are here.
@@ -1232,8 +1242,9 @@ def _row_tile(rows_per_expert: float) -> int:
 def _held_rows(layer, xf, src):
     """The router and the row layout of a layer that holds a range of the
     experts (`LatentMoE`, `GatedMoE`; `layer` gives `num_experts`, `top_k`,
-    `experts_held`, `expert_offset`, `routed_scaling` and the parameters'
-    scope): scores xf (n, d) over ALL experts in float32, lays the held
+    `experts_held`, `expert_offset`, `routed_scaling`, the parameters'
+    scope and, where it has one, `router`: "sigmoid" with a selection bias,
+    or "softmax"): scores xf (n, d) over ALL experts in float32, lays the held
     picks out as whole row tiles and fills them from `src` (n, width).
     Returns (rows, layout, weights (n, k) float32, tile)."""
     n, k, held = xf.shape[0], layer.top_k, layer.experts_held
@@ -1242,15 +1253,21 @@ def _held_rows(layer, xf, src):
             f"held experts [{layer.expert_offset}, "
             f"{layer.expert_offset + held}) lie outside the "
             f"{layer.num_experts} the router scores")
-    bias = layer.param("e_score_correction_bias", nn.initializers.zeros,
-                       (layer.num_experts,), layer.param_dtype)
+    softmax = getattr(layer, "router", "sigmoid") == "softmax"
+    if not softmax:
+        bias = layer.param("e_score_correction_bias", nn.initializers.zeros,
+                           (layer.num_experts,), layer.param_dtype)
     with jax.named_scope("moe_route"):
         logits = nn.Dense(
             layer.num_experts, use_bias=False, dtype=jnp.float32,
             param_dtype=layer.param_dtype, name="router",
         )(xf.astype(jnp.float32))
-        choices, weights = route_sigmoid_topk(
-            logits, bias, k=k, scaling=layer.routed_scaling)
+        if softmax:
+            choices, weights = route_softmax_topk(
+                logits, k=k, scaling=layer.routed_scaling)
+        else:
+            choices, weights = route_sigmoid_topk(
+                logits, bias, k=k, scaling=layer.routed_scaling)
         tile = _row_tile(n * k / layer.num_experts)
         lay = held_tile_layout(choices, offset=layer.expert_offset,
                                held=held, tile=tile)
@@ -1361,7 +1378,10 @@ class GatedMoE(nn.Module):
         routed = sum_{picks held here} w_e Wd_e(silu(Wg_e x) * Wu_e x)
         out = routed + the shared SwiGLU expert of width `shared_dim`
 
-    The expert matrices go through ONE kernel, `moe_gmm_glu`."""
+    `router="softmax"`: s = softmax(x W_r), picks = top_k(s), no bias.
+    `shared_gate`: the shared expert times sigmoid(x w_s), a gate of its
+    own (Qwen's). The expert matrices go through ONE kernel,
+    `moe_gmm_glu`."""
 
     num_experts: int
     top_k: int
@@ -1372,6 +1392,8 @@ class GatedMoE(nn.Module):
     routed_scaling: float = 1.0
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
+    router: str = "sigmoid"
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x, *, decode: bool = False):
@@ -1387,7 +1409,12 @@ class GatedMoE(nn.Module):
         out = expert_glu_tiles(rows, *mats, lay["tile_expert"],
                                lay["tiles_used"], tile=tile)
         y = _held_combine(out, lay, weights, cd)
-        y = y + GatedMLP(self.shared_dim, cd, self.param_dtype,
-                         name="shared")(xf)
+        shared = GatedMLP(self.shared_dim, cd, self.param_dtype,
+                          name="shared")(xf)
+        if self.shared_gate:
+            shared = shared * nn.sigmoid(nn.Dense(
+                1, use_bias=False, dtype=cd, param_dtype=self.param_dtype,
+                name="shared_expert_gate")(xf))
+        y = y + shared
         _held_count(self, lay, decode)
         return y.reshape(*lead, d).astype(x.dtype)

@@ -44,13 +44,16 @@ from ddp_practice_tpu.utils import backend
 def causal_conv(xbc, tail, weight, bias):
     """Depthwise causal conv over time. xbc (b, l, c); `tail` (b, k-1, c)
     the k-1 inputs before position 0 (zeros for a fresh sequence); weight
-    (k, c), bias (c,). Returns (out (b, l, c), new tail (b, k-1, c))."""
+    (k, c), bias (c,) or None. Returns (out (b, l, c), new tail
+    (b, k-1, c))."""
     k = weight.shape[0]
     seq = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
     l = xbc.shape[1]
     out = sum(seq[:, i:i + l] * weight[i].astype(xbc.dtype)
               for i in range(k))
-    return out + bias.astype(xbc.dtype), seq[:, l:]
+    if bias is not None:
+        out = out + bias.astype(xbc.dtype)
+    return out, seq[:, l:]
 
 
 def _heads_of_groups(v, heads: int):

@@ -508,3 +508,42 @@ def test_latent_walk_of_32_rows_is_unchanged():
                                atol=2e-5, rtol=2e-5)
     text = str(jax.make_jaxpr(walk)(*args))
     assert text.count("pallas_call") == 1 and "pad" not in text
+
+
+# ------------------------------------------- heads of 256 lanes (PR 38)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", ["ragged_left_padded", "no_start"])
+def test_paged_walk_at_head_dim_256_group_8_page_64(case, dtype):
+    """The walk as `qwen3next_80b_ep4` runs it: 16 query heads of 256 lanes
+    on 2 KV heads (a group of 8, a pool row of 512 lanes), pages of 64,
+    contexts that end inside a page and past several; against the gather
+    reference, ONE kernel, no padding of the group."""
+    from ddp_practice_tpu.ops.decode_attention import (
+        paged_attention_reference,
+        paged_decode_attention,
+    )
+
+    lengths = [3, 64, 100, 700, 333]
+    start = None if case == "no_start" else [0, 5, 64, 130, 0]
+    b, heads, kvh, d, bs, mb = len(lengths), 16, 2, 256, 64, 12
+    nb = 1 + b * mb
+    rng = np.random.default_rng(256)
+    q = jnp.asarray(rng.normal(size=(b, 1, heads * d)), dtype)
+    kp = jnp.asarray(rng.normal(size=(nb, bs, kvh * d)), dtype)
+    vp = jnp.asarray(rng.normal(size=(nb, bs, kvh * d)), dtype)
+    pt = jnp.asarray(rng.permutation(nb - 1)[:b * mb].reshape(b, mb) + 1,
+                     jnp.int32)
+    args = (q, kp, vp, pt, jnp.asarray(lengths, jnp.int32),
+            None if start is None else jnp.asarray(start, jnp.int32))
+    kw = dict(n_heads=heads, n_kv_heads=kvh)
+    want = paged_attention_reference(*args, **kw)
+    walk = lambda *a: paged_decode_attention(*a, **kw, impl="kernel")
+    # bf16: an ulp of outputs that reach 2 (chip_smoke.py's bound)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(walk(*args), np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+    text = str(jax.make_jaxpr(walk)(*args))
+    assert text.count("pallas_call") == 1 and "pad" not in text
+    assert "name=paged_decode" in text.replace(" ", "")
+    assert f"[{b},{heads},{d}]" in text.replace(" ", "")

@@ -119,15 +119,15 @@ def test_absorbed_step_agrees_with_the_expanded_one(toy):
     pool = make_paged_cache(model, 12, 8)
     table = jnp.asarray([[3, 5, 7, 9, 11, 2]], jnp.int32)
     toks = jnp.asarray([PROMPT])
+    # jitted: one trace a width, not the model op by op
+    step = jax.jit(lambda pool, toks, at: decode_apply(
+        model, params, pool, toks, page_table=table,
+        kv_lengths=jnp.full((1,), at, jnp.int32)))
     with jax.default_matmul_precision("highest"):
-        pool, _ = decode_apply(model, params, pool, toks[:, :30],
-                               page_table=table, kv_lengths=jnp.zeros((1,), jnp.int32))
-        _, two = decode_apply(model, params, pool, toks[:, 30:32],
-                              page_table=table, kv_lengths=jnp.asarray([30]))
-        pool, _ = decode_apply(model, params, pool, toks[:, 30:31],
-                               page_table=table, kv_lengths=jnp.asarray([30]))
-        _, one = decode_apply(model, params, pool, toks[:, 31:32],
-                              page_table=table, kv_lengths=jnp.asarray([31]))
+        pool, _ = step(pool, toks[:, :30], 0)
+        _, two = step(pool, toks[:, 30:32], 30)
+        pool, _ = step(pool, toks[:, 30:31], 30)
+        _, one = step(pool, toks[:, 31:32], 31)
     np.testing.assert_allclose(np.asarray(one[0, 0]), np.asarray(two[0, 1]),
                                atol=TOL, rtol=TOL)
 
@@ -183,17 +183,15 @@ def test_paged_decode_mla_waits_for_what_it_reads(monkeypatch):
 
 def test_paged_decode_mla_reads_live_pages_alone():
     q, pool, table, lengths, start, kw = _mla_case()
-    want = np.asarray(da.paged_decode_mla(
+    # one trace of the interpreted kernel for the three pools
+    walk = jax.jit(lambda pool: da.paged_decode_mla(
         q, pool, table, lengths, start, impl="kernel", **kw))
+    want = np.asarray(walk(pool))
     t = np.asarray(table)
     dead = np.concatenate([t[0, 1:], t[1, 37:], t[2, :2], t[2, 10:], [0]])
-    got = np.asarray(da.paged_decode_mla(
-        q, pool.at[dead].set(jnp.nan), table, lengths, start, impl="kernel",
-        **kw))
+    got = np.asarray(walk(pool.at[dead].set(jnp.nan)))
     np.testing.assert_array_equal(got[:4], want[:4])
-    hit = np.asarray(da.paged_decode_mla(
-        q, pool.at[t[1, 36]].set(jnp.nan), table, lengths, start,
-        impl="kernel", **kw))
+    hit = np.asarray(walk(pool.at[t[1, 36]].set(jnp.nan)))
     assert np.isnan(hit[1]).all() and not np.isnan(hit[0]).any()
 
 

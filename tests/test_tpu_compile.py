@@ -369,6 +369,56 @@ def _paged_group20(slots=256, blocks_per_slot=48, page=64):
                   _sds((slots,), jnp.int32), _sds((slots,), jnp.int32))
 
 
+def _gdn(what, slots=128, length=4096):
+    """ops/gdn.py's Gated DeltaNet kernels at the Qwen3-Next cell's widths
+    (perf/configs/qwen3next_80b_ep4.json): 16 key heads serving 32 value
+    heads of 128 x 128 float32 state, rewritten in place; the decode step
+    over 128 slots, a prompt's chunked scan over the widest bucket (the
+    chunk terms are XLA's, the carry the kernel's)."""
+    from ddp_practice_tpu.ops import gdn
+
+    f32 = jnp.float32
+    lead = (slots,) if what == "step" else (1, length)
+    return (gdn.gdn_step if what == "step" else gdn.gdn_scan), (
+        _sds(lead + (16, 128), f32), _sds(lead + (16, 128), f32),
+        _sds(lead + (32, 128), f32), _sds(lead + (32,), f32),
+        _sds(lead + (32,), f32), _sds((lead[0], 32, 128, 128), f32))
+
+
+def _paged_hd256(slots=128, blocks_per_slot=76, page=64):
+    """The Qwen3-Next cell's attention: 16 query heads of 256 lanes on 2 KV
+    heads (a group of 8), pages of 64 tokens 512 lanes wide."""
+    from ddp_practice_tpu.ops.decode_attention import paged_decode_attention
+
+    def step(q, k, v, table, lengths, start):
+        return paged_decode_attention(
+            q, k, v, table, lengths, start, n_heads=16, n_kv_heads=2)
+
+    pool = _sds((1 + slots * blocks_per_slot, page, 512))
+    return step, (_sds((slots, 1, 16 * 256)), pool, pool,
+                  _sds((slots, blocks_per_slot), jnp.int32),
+                  _sds((slots,), jnp.int32), _sds((slots,), jnp.int32))
+
+
+def _moe_glu_qwen(rows_a_tile):
+    """The gated expert kernel at the Qwen3-Next cell's widths: 128 held
+    experts of 2048 -> 512 -> 2048 under a 512-wide router with 10 picks; a
+    decode step's tiles of 16 rows and a 4,096-token prompt's of 128."""
+    from ddp_practice_tpu.ops.moe import expert_glu_tiles
+
+    picks = {16: 128 * 10, 128: 4096 * 10}[rows_a_tile]
+    tiles = -(-picks // rows_a_tile) + 128
+
+    def mlp(rows, wg, wu, wd, tile_expert, used):
+        return expert_glu_tiles(rows, wg, wu, wd, tile_expert, used,
+                                tile=rows_a_tile)
+
+    return mlp, (_sds((tiles * rows_a_tile, 2048)),
+                 _sds((128, 2048, 512)), _sds((128, 2048, 512)),
+                 _sds((128, 512, 2048)),
+                 _sds((tiles,), jnp.int32), _sds((1,), jnp.int32))
+
+
 def _kernel_calls(text):
     """Names of the compiled Pallas custom calls, in program order."""
     return [ln.split("=")[0].split("%")[-1].strip().split(".")[0]
@@ -395,6 +445,12 @@ KERNELS = {
     "jamba_sel_scan_1024": functools.partial(_sel, "scan"),
     "jamba_sel_scan_128": functools.partial(_sel, "scan", length=128),
     "jamba_paged_group20_page64": _paged_group20,
+    "qwen_gdn_step_128_slots": functools.partial(_gdn, "step"),
+    "qwen_gdn_scan_4096": functools.partial(_gdn, "scan"),
+    "qwen_gdn_scan_256": functools.partial(_gdn, "scan", length=256),
+    "qwen_paged_hd256_group8_page64": _paged_hd256,
+    "qwen_moe_glu_decode_tiles": functools.partial(_moe_glu_qwen, 16),
+    "qwen_moe_glu_prompt_tiles": functools.partial(_moe_glu_qwen, 128),
     "latent_paged_mla_page64": _paged_mla,
     "latent_moe_glu_decode_tiles": functools.partial(_moe_glu, 16),
     "latent_moe_glu_chunk_tiles": functools.partial(_moe_glu, 64),
@@ -480,6 +536,14 @@ def test_kernel_compiles_for_v5e(topo, name):
         # flood_paged_decode_roofline sum by
         want = {"jamba_sel_st": "sel_step", "jamba_sel_sc": "sel_scan",
                 "jamba_paged_": "paged_decode"}[name[:12]]
+        calls = _kernel_calls(text)
+        assert len(calls) == 1 and calls[0].endswith(want), calls
+    if name.startswith("qwen"):
+        # the names perf/layer_metrics/flood_gdn_*, flood_moe_glu_* and
+        # flood_paged_decode_roofline sum by
+        want = {"qwen_gdn_st": "gdn_step", "qwen_gdn_sc": "gdn_scan",
+                "qwen_paged_": "paged_decode",
+                "qwen_moe_gl": "moe_gmm_glu"}[name[:11]]
         calls = _kernel_calls(text)
         assert len(calls) == 1 and calls[0].endswith(want), calls
     if name.startswith("latent"):
@@ -732,6 +796,8 @@ CELL_DEPTH = {
     "deepseek_v3": lambda cfg: dict(cfg, layers_run=2),   # dense, experts
     "jamba": lambda cfg: dict(cfg, num_hidden_layers=2, attn_layer_period=2,
                               attn_layer_offset=1),       # Mamba, attention
+    "qwen3_next": lambda cfg: dict(cfg, layers_run=2,     # DeltaNet,
+                                   full_attention_interval=2),  # attention
 }
 
 
@@ -863,45 +929,114 @@ def _no_frames_in_locations():
         jax.config.update(key, was)
 
 
+def _holds_the_contract(cell, prog, text) -> int:
+    """The scopes contract on one compiled program; its kernels, counted."""
+    import test_scopes as contract
+
+    want = {"loss", "optimizer"} if prog == "train_step" else {"sample"}
+    contract.hold(text, want | {"attn", "mlp", "norm"}, f"{cell} {prog}")
+    for op, name, path, cls in contract.own_ops(text):
+        if op == "custom-call":   # a kernel's own instruction, by name
+            assert cls and cls[1] == (
+                "bwd" if "_bwd" in name else "fwd"), (name, path)
+    return contract.kernels_agree(text)
+
+
+_CELL_TEXTS: dict = {}
+
+
+def _cell_texts(topo, cell) -> dict:
+    """The optimized HLO of every step program of `cell`, built once a
+    process: the two tests below share it, so that each compiles the cell
+    once (a build is 7-10 s of the compiler at the cells' widths, and one
+    test with both read 37-42 s beside five workers, at the tier-1 line)."""
+    if cell not in _CELL_TEXTS:
+        with _no_frames_in_locations():
+            _CELL_TEXTS[cell] = {
+                k: lower().compile().as_text()
+                for k, lower in _cell_programs(topo, cell).items()}
+    return _CELL_TEXTS[cell]
+
+
 @pytest.mark.parametrize("cell", CELLS)
-def test_cell_programs_carry_their_scopes(topo, cell, monkeypatch):
+def test_cell_programs_carry_their_scopes(topo, cell):
     """Every step program of every benchmark cell, compiled for the
     described v5e at the cell's own widths, under the contract of
     tests/test_scopes.py: at least 95% of its own device ops (dot,
     convolution, fusion, reduce, Pallas call, outside a fusion's body) carry
     a path that perf/lib/scopes.py classifies; a train step holds `loss` and
-    `optimizer`, both serving programs `sample`; every kernel's name agrees
-    with the class and direction its path reads; and the three scopes the
-    contract added are METADATA: with them patched away the optimized HLO
-    is the same text but for `metadata={...}`."""
+    `optimizer`, both serving programs `sample`; and every kernel's name
+    agrees with the class and direction its path reads."""
+    assert sum(_holds_the_contract(cell, prog, text)
+               for prog, text in _cell_texts(topo, cell).items()) >= 1
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_programs_scopes_are_metadata(topo, cell, monkeypatch):
+    """The three scopes the contract added are METADATA: with them patched
+    away the optimized HLO of every step program of every cell is the same
+    text but for `metadata={...}`."""
     import test_scopes as contract
 
-    def build():
-        with _no_frames_in_locations():
-            return {k: lower().compile().as_text()
-                    for k, lower in _cell_programs(topo, cell).items()}
-
-    texts = build()
-    kernels = 0
-    for prog, text in texts.items():
-        want = {"loss", "optimizer"} if prog == "train_step" else {"sample"}
-        contract.hold(text, want | {"attn", "mlp", "norm"}, f"{cell} {prog}")
-        kernels += contract.kernels_agree(text)
-        for op, name, path, cls in contract.own_ops(text):
-            if op == "custom-call":   # a kernel's own instruction, by name
-                assert cls and cls[1] == (
-                    "bwd" if "_bwd" in name else "fwd"), (name, path)
-    assert kernels >= 1, kernels
+    texts = _cell_texts(topo, cell)
     real = jax.named_scope
     monkeypatch.setattr(
         jax, "named_scope",
         lambda name: contextlib.nullcontext()
         if name in contract.NEW_SCOPES else real(name))
-    for prog, bare in build().items():
+    with _no_frames_in_locations():
+        bares = {k: lower().compile().as_text()
+                 for k, lower in _cell_programs(topo, cell).items()}
+    for prog, bare in bares.items():
         assert 'op_name="' in bare and not re.search(
             r"[/(](loss|optimizer|sample)[/)]", bare), prog
         assert contract.without_metadata(bare) \
             == contract.without_metadata(texts[prog]), prog
+
+
+def _kernel_counts(text):
+    from collections import Counter
+
+    return Counter(c.split("/")[-1] for c in _kernel_calls(text))
+
+
+@pytest.mark.parametrize("prog, kernels", [
+    ("decode_burst", {"gdn_step": 1, "paged_decode": 1, "moe_gmm_glu": 2}),
+    ("prefill", {"gdn_scan": 1, "moe_gmm_glu": 2})])
+def test_qwen3_next_programs_carry_their_scopes_and_kernels(topo, prog,
+                                                            kernels):
+    """The new cell's programs compiled for the described v5e at its widths
+    and engine, one layer of each mixer (G A), a program a case: the scopes
+    contract as above (the three scopes PR 34 added are shown to be metadata
+    there, in the engine this cell shares line for line), and the kernels by
+    name and count: a decode step 1 `gdn_step`, 1 `paged_decode` and 2
+    `moe_gmm_glu`; an admission prefill 1 `gdn_scan` and 2 `moe_gmm_glu`
+    (its attention is plain XLA)."""
+    cell = "qwen3next_serve_mixed"
+    with _no_frames_in_locations():
+        text = _cell_programs(topo, cell)[prog]().compile().as_text()
+    assert _holds_the_contract(cell, prog, text) >= 1
+    assert _kernel_counts(text) == kernels
+
+
+@pytest.mark.slow
+def test_qwen3_next_programs_hold_their_kernels_by_count(topo):
+    """The same programs one period deep (G G G A, half of `layers_run`): a
+    decode step holds 3 `gdn_step`, 1 `paged_decode` and 4 `moe_gmm_glu`, an
+    admission prefill 3 `gdn_scan` and 4 `moe_gmm_glu`: a layer twice over
+    is the cell's 6 / 2 / 8 and 6 / 8. (26 s of compiling at the published
+    widths: `slow`.)"""
+    CELL_DEPTH["qwen3_next"], was = (
+        lambda cfg: dict(cfg, layers_run=4)), CELL_DEPTH["qwen3_next"]
+    try:
+        progs = _cell_programs(topo, "qwen3next_serve_mixed")
+    finally:
+        CELL_DEPTH["qwen3_next"] = was
+    decode = _kernel_counts(progs["decode_burst"]().compile().as_text())
+    assert decode == {"gdn_step": 3, "paged_decode": 1, "moe_gmm_glu": 4}, \
+        decode
+    prefill = _kernel_counts(progs["prefill"]().compile().as_text())
+    assert prefill == {"gdn_scan": 3, "moe_gmm_glu": 4}, prefill
 
 
 # ------------------------------------------------------------ whole steps
