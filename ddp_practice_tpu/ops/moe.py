@@ -1017,7 +1017,10 @@ class MoEMlp(nn.Module):
 # runs without that exchange). The dropless sorted path above, with a held
 # range: the picks sort by held expert (`_assignment_permutation`, absent
 # picks last), each expert's rows are laid out as whole row tiles, and ONE
-# kernel, `moe_gmm`, walks the tiles with the tile's expert's weights.
+# kernel, `moe_gmm`, walks the tiles with the tile's expert's weights. Two
+# more move the rows: `moe_rows_fill` writes the used tiles from the tokens'
+# rows and `moe_rows_sum` adds each valid row, times its weight, to its
+# token's sum: one row move a HELD pick each way, whatever the router did.
 
 
 def route_sigmoid_topk(scores_logits, select_bias, *, k: int,
@@ -1043,18 +1046,34 @@ def route_softmax_topk(scores_logits, *, k: int, scaling: float):
     return choices.astype(jnp.int32), w * scaling
 
 
+@functools.partial(jax.jit, static_argnames=("offset", "held", "tile"))
 def held_tile_layout(choices, *, offset: int, held: int, tile: int):
     """Where each pick's row goes. choices (N, k) over ALL the experts the
     router scores; experts `offset` .. `offset + held` are here.
 
     The held picks' rows form a padded buffer of `n_tiles * tile` rows in
     which expert e's rows start at a tile boundary (so one row tile has
-    one expert); `n_tiles = ceil(N k / tile) + held` covers any routing.
-    Returns a dict: `row_token` (rows,) the token whose latent a row
-    holds, `row_valid` (rows,), `tile_expert` (n_tiles,) local expert of
-    each tile (idle tiles repeat the last used one: no new weights are
-    fetched for them), `tiles_used` (1,), `pick_row` (N, k) the row of a
-    held pick (0 otherwise), `pick_held` (N, k) bool, `counts` (held,)."""
+    one expert). `n_tiles = ceil(N k / tile) + held` is the buffer's SIZE,
+    enough for any routing; what is moved into it and out of it is what
+    THIS routing holds: the first `tiles_used` tiles, and in them the
+    valid rows alone (`held_rows_fill`, `held_rows_sum`). Returns a dict.
+    By tile: `tile_expert` (n_tiles,) local expert of each tile (idle tiles
+    repeat the last used one: no new weights are fetched for them),
+    `tiles_used` (1,), `tile_rows` (n_tiles,) valid rows of a tile (0 past
+    the used ones), `tile_first_pick` (n_tiles,) where a tile's rows start
+    in the expert-sorted order of the picks, of which `sorted_pick` (N k,)
+    is the flat pick and `sorted_token` its token (a tile's valid rows are
+    `tile_rows` consecutive sorted picks: all the kernels read). By row,
+    for the gathers off the TPU: `row_token` (rows,) the token whose latent
+    a row holds, `row_valid` (rows,). By pick: `pick_row` (N, k) the row of
+    a held pick (0 otherwise), `pick_held` (N, k) bool. `counts` (held,).
+    The by-row and by-pick keys cost more than the rows they index (an
+    index gather a row of the whole buffer, a one-hot cumsum a pick:
+    PERF.md section 6, PR 39); a program that does not read them does not
+    compute them. Jitted on its own, as the two kernels' calls are: a
+    model's expert layers ask for the same layout, and a function traced
+    and lowered once a program, not once a layer, is host time off every
+    program's set-up."""
     n, k = choices.shape
     local = choices - offset
     is_held = (local >= 0) & (local < held)
@@ -1079,8 +1098,17 @@ def held_tile_layout(choices, *, offset: int, held: int, tile: int):
     src = inv[jnp.where(row_valid, first[row_e] + rank, 0)]
     held_local = jnp.minimum(local, held - 1)
     pick_row = tile_first[held_local] * tile + dest - first[held_local]
+    tiles_all = jnp.arange(n_tiles, dtype=jnp.int32)
+    tile_rank = (tiles_all - tile_first[tile_expert]) * tile
     return {
         "row_token": src // k, "row_valid": row_valid,
+        "sorted_pick": inv, "sorted_token": inv // k,
+        "tile_first_pick": (first[tile_expert] + tile_rank
+                            ).astype(jnp.int32),
+        "tile_rows": jnp.where(
+            tiles_all < used,
+            jnp.clip(counts[tile_expert] - tile_rank, 0, tile), 0
+        ).astype(jnp.int32),
         "tile_expert": tile_expert,
         "tiles_used": used.astype(jnp.int32)[None],
         "pick_row": jnp.where(is_held.reshape(-1), pick_row, 0
@@ -1239,6 +1267,206 @@ def _row_tile(rows_per_expert: float) -> int:
     return tile
 
 
+_TOKEN_BLOCK = 4096   # tokens whose sums `moe_rows_sum` holds in VMEM
+_FILL_BYTES = 48 << 20  # the most of src that `moe_rows_fill` holds there
+
+
+def _row_of_words(x_ref, row, paired: bool):
+    """Row `row` of a ref as (1, d) float32. A 16-bit ref is read through
+    its view as (rows / 2, d) 32-bit words, two rows a word: a bf16's bits
+    are the upper half of its float32's, so the even row is the word
+    shifted up and the odd row the word's upper half."""
+    if not paired:
+        return x_ref[pl.ds(row, 1), :].astype(jnp.float32)
+    word = x_ref.bitcast(jnp.uint32)[pl.ds(row // 2, 1), :]
+    up = (16 * (1 - row % 2)).astype(jnp.uint32)
+    return pltpu.bitcast((word << up) & jnp.uint32(0xFFFF0000), jnp.float32)
+
+
+def _rows_fill_kernel(tok_ref, first_ref, n_ref, used_ref, x_ref, o_ref,
+                      tile_scr, *, paired: bool):
+    """Row tile t of the buffer from its picks' tokens' rows of x, which is
+    whole in VMEM. A tile's valid rows are `n_ref[t]` consecutive picks of
+    the expert-sorted order from `first_ref[t]` (`tok_ref`: a sorted pick's
+    token): one row move each, zeros after them. Tiles past the used ones
+    are not written."""
+    t = pl.program_id(0)
+
+    @pl.when(t < used_ref[0])
+    def _():
+        tile_scr[...] = jnp.zeros(tile_scr.shape, tile_scr.dtype)
+
+        def one(i, _):
+            tok = tok_ref[first_ref[t] + i]
+            tile_scr[pl.ds(i, 1), :] = _row_of_words(x_ref, tok, paired)
+        jax.lax.fori_loop(0, n_ref[t], one, None)
+        o_ref[...] = tile_scr[...].astype(o_ref.dtype)
+
+
+def _paired(dtype) -> bool:
+    """True for bfloat16 rows (`_row_of_words` reads them as halves of
+    words), False for 32-bit ones; no other width is moved."""
+    if jnp.dtype(dtype) == jnp.bfloat16:
+        return True
+    if jnp.dtype(dtype).itemsize != 4:
+        raise ValueError(f"rows of {dtype} cannot be moved as words")
+    return False
+
+
+def _last_used(t, used):
+    return jnp.minimum(t, jnp.maximum(used[0] - 1, 0))
+
+
+def _interpret(interpret) -> bool:
+    return (not backend.on_tpu()) if interpret is None else interpret
+
+
+def held_rows_fill_kernel(src, lay, *, tile: int, interpret=None):
+    """The device op `moe_rows_fill`: the row buffer of `held_tile_layout`
+    from src (n, d), one row move a held pick."""
+    return _rows_fill_call(
+        src, lay["sorted_token"], lay["tile_first_pick"], lay["tile_rows"],
+        lay["tiles_used"], tile=tile, interpret=_interpret(interpret))
+
+
+# jitted on their own, as `held_tile_layout` is
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _rows_fill_call(src, sorted_token, tile_first_pick, tile_rows,
+                    tiles_used, *, tile: int, interpret: bool):
+    n_tiles = tile_rows.shape[0]
+    n, d = src.shape
+    paired = _paired(src.dtype)
+    if paired and n % 16:         # whole sublane tiles of 16-bit rows
+        src = jnp.pad(src, ((0, -n % 16), (0, 0)))
+    return pl.pallas_call(
+        functools.partial(_rows_fill_kernel, paired=paired),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n_tiles,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec(
+                (tile, d), lambda t, tok, first, n, used: (
+                    _last_used(t, used), 0)),
+            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_tiles * tile, d), src.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(src.size * src.dtype.itemsize
+                                 + 8 * tile * d * 4 + (4 << 20))),
+        interpret=interpret,
+        name="moe_rows_fill",
+    )(sorted_token, tile_first_pick, tile_rows, tiles_used, src)
+
+
+def held_rows_fill(src, lay, *, tile: int):
+    """The row buffer of `held_tile_layout` from src (n, d): the kernel on
+    the TPU where src fits its VMEM (`_FILL_BYTES`: every call the engines
+    make; 16 MB at 4,096 tokens of width 2,048), gathers elsewhere."""
+    if backend.on_tpu() and src.size * src.dtype.itemsize <= _FILL_BYTES:
+        return held_rows_fill_kernel(src, lay, tile=tile)
+    return held_rows_fill_reference(src, lay)
+
+
+def held_rows_fill_reference(src, lay):
+    """Every row of the buffer gathered (idle rows gather token 0 and are
+    zeroed after): what the kernel stands in for, off the TPU."""
+    return jnp.where(lay["row_valid"][:, None], src[lay["row_token"]], 0)
+
+
+def _rows_sum_kernel(tok_ref, first_ref, n_ref, used_ref, w_ref, out_ref,
+                     y_ref, acc, tile_scr, *, block: int):
+    """Cell (b, t): the rows of tile t whose tokens lie in token block b,
+    each times its pick's float32 weight, added to their tokens' float32
+    sums; the block's sums are written as the last tile leaves. Only a
+    tile's valid rows are read, so what else the buffer holds reaches no
+    token, and a pick that is not held has no row."""
+    b, t = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(t == 0)
+    def _():
+        acc[...] = jnp.zeros(acc.shape, acc.dtype)
+
+    @pl.when(t < used_ref[0])
+    def _():
+        tile_scr[...] = out_ref[...].astype(jnp.float32)
+
+        def one(i, _):
+            at = first_ref[t] + i
+            tok = tok_ref[at] - b * block
+
+            @pl.when((tok >= 0) & (tok < block))
+            def _():
+                acc[pl.ds(tok, 1), :] += w_ref[at] * tile_scr[pl.ds(i, 1), :]
+        jax.lax.fori_loop(0, n_ref[t], one, None)
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _():
+        y_ref[...] = acc[...].astype(y_ref.dtype)
+
+
+def held_rows_sum_kernel(out, lay, weights, dtype, *, tile: int,
+                         interpret=None):
+    """The device op `moe_rows_sum`: each token's weighted sum over its
+    held picks' rows of `out`, one row move a held pick."""
+    return _rows_sum_call(
+        out, weights, lay["sorted_pick"], lay["tile_first_pick"],
+        lay["tile_rows"], lay["tiles_used"], dtype=jnp.dtype(dtype),
+        tile=tile, interpret=_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "tile", "interpret"))
+def _rows_sum_call(out, weights, sorted_pick, tile_first_pick, tile_rows,
+                   tiles_used, *, dtype, tile: int, interpret: bool):
+    n, k = weights.shape
+    n_tiles, d = tile_rows.shape[0], out.shape[1]
+    block = min(_TOKEN_BLOCK, -(-n // 16) * 16)
+    blocks = -(-n // block)
+    y = pl.pallas_call(
+        functools.partial(_rows_sum_kernel, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(blocks, n_tiles),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((tile, d), lambda b, t, tok, first, n, used: (
+                    _last_used(t, used), 0))],
+            out_specs=pl.BlockSpec(
+                (block, d), lambda b, t, tok, first, n, used: (b, 0)),
+            scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
+                            pltpu.VMEM((tile, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((blocks * block, d), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=int(
+                block * d * (4 + 2 * dtype.itemsize)
+                + 8 * tile * d * 4 + (4 << 20))),
+        interpret=interpret,
+        name="moe_rows_sum",
+    )(sorted_pick // k, tile_first_pick, tile_rows, tiles_used,
+      weights.astype(jnp.float32).reshape(-1)[sorted_pick], out)
+    return y[:n] if blocks * block != n else y
+
+
+def held_rows_sum(out, lay, weights, dtype, *, tile: int):
+    """Each token's sum over its held picks' rows of `out` (the buffer's
+    shape), times their weights (n, k) float32, in float32: the kernel on
+    the TPU, gathers elsewhere."""
+    if backend.on_tpu():
+        return held_rows_sum_kernel(out, lay, weights, dtype, tile=tile)
+    return held_rows_sum_reference(out, lay, weights, dtype)
+
+
+def held_rows_sum_reference(out, lay, weights, dtype):
+    """A gather of every pick's row (row 0 for a pick that is not held,
+    dropped by the select before it meets a weight)."""
+    held = lay["pick_held"]
+    picked = jnp.where(held[..., None], out[lay["pick_row"]], 0)
+    return jnp.einsum("nk,nkd->nd", jnp.where(held, weights, 0.0),
+                      picked.astype(jnp.float32)).astype(dtype)
+
+
 def _held_rows(layer, xf, src):
     """The router and the row layout of a layer that holds a range of the
     experts (`LatentMoE`, `GatedMoE`; `layer` gives `num_experts`, `top_k`,
@@ -1271,24 +1499,23 @@ def _held_rows(layer, xf, src):
         tile = _row_tile(n * k / layer.num_experts)
         lay = held_tile_layout(choices, offset=layer.expert_offset,
                                held=held, tile=tile)
-        rows = jnp.where(lay["row_valid"][:, None],
-                         src[lay["row_token"]], 0).astype(layer.dtype)
+        rows = held_rows_fill(src.astype(layer.dtype), lay, tile=tile)
     return rows, lay, weights, tile
 
 
-def _held_combine(out, lay, weights, dtype):
+def _held_combine(out, lay, weights, dtype, tile: int):
     """Each token's weighted sum over its held picks' rows of `out`."""
     with jax.named_scope("moe_combine"):
-        gate = jnp.where(lay["pick_held"], weights, 0.0)
-        picked = out[lay["pick_row"]].astype(jnp.float32)  # (n, k, width)
-        return jnp.einsum("nk,nkd->nd", gate, picked).astype(dtype)
+        return held_rows_sum(out, lay, weights, dtype, tile=tile)
 
 
-def _held_count(layer, lay, decode: bool) -> None:
+def _held_count(layer, lay, decode: bool, tile: int) -> None:
     """Decode mode: add this call's counts to the cache collection's
     `moe_stats` (rows that landed on held experts, held experts touched,
     most rows on one expert; summed over the calls since the engine last
-    zeroed it), what `PagedEngine` hands its tracer."""
+    zeroed it), what `PagedEngine` hands its tracer; and to `moe_rows` the
+    rows this call moved beside the rows of its whole layout
+    (`held_rows_moved`)."""
     if not decode:
         return
     stats = layer.variable("cache", "moe_stats", jnp.zeros, (3,), jnp.int32)
@@ -1297,6 +1524,23 @@ def _held_count(layer, lay, decode: bool) -> None:
         stats.value = jnp.stack([
             old[0] + c.sum(), old[1] + (c > 0).sum(),
             jnp.maximum(old[2], c.max())]).astype(jnp.int32)
+    rows = layer.variable("cache", "moe_rows", jnp.zeros, (2,), jnp.int32)
+    if not layer.is_initializing():
+        rows.value = rows.value + held_rows_moved(lay, tile)
+
+
+def held_rows_moved(lay, tile: int):
+    """(2,) int32: the rows a call moves into its tile buffer and out of
+    it, and the rows of the whole layout, which covers any routing and
+    which the gathers move (`held_rows_fill_reference`,
+    `held_rows_sum_reference`: they stand in for the kernels off the TPU,
+    where the count is still the kernels'). Into the buffer go the used
+    tiles' rows, the held picks rounded up to whole tiles an expert; out of
+    it one row a held pick."""
+    n, k = lay["pick_held"].shape
+    moved = lay["tiles_used"][0] * tile + lay["counts"].sum()
+    layout = lay["tile_expert"].shape[0] * tile + n * k
+    return jnp.stack([moved, layout]).astype(jnp.int32)
 
 
 class LatentMoE(nn.Module):
@@ -1343,11 +1587,11 @@ class LatentMoE(nn.Module):
         out = expert_mlp_tiles(
             rows, w1.astype(cd), w2.astype(cd), lay["tile_expert"],
             lay["tiles_used"], tile=tile)
-        y = dense(d, name="up")(_held_combine(out, lay, weights, cd))
+        y = dense(d, name="up")(_held_combine(out, lay, weights, cd, tile))
         shared = dense(self.shared_dim, name="shared_in")(xf)
         shared = jnp.square(nn.relu(shared))
         y = y + dense(d, name="shared_out")(shared)
-        _held_count(self, lay, decode)
+        _held_count(self, lay, decode, tile)
         return y.reshape(*lead, d).astype(x.dtype)
 
 
@@ -1408,7 +1652,7 @@ class GatedMoE(nn.Module):
                                     ("expert_down", (held, f, d)))]
         out = expert_glu_tiles(rows, *mats, lay["tile_expert"],
                                lay["tiles_used"], tile=tile)
-        y = _held_combine(out, lay, weights, cd)
+        y = _held_combine(out, lay, weights, cd, tile)
         shared = GatedMLP(self.shared_dim, cd, self.param_dtype,
                           name="shared")(xf)
         if self.shared_gate:
@@ -1416,5 +1660,5 @@ class GatedMoE(nn.Module):
                 1, use_bias=False, dtype=cd, param_dtype=self.param_dtype,
                 name="shared_expert_gate")(xf))
         y = y + shared
-        _held_count(self, lay, decode)
+        _held_count(self, lay, decode, tile)
         return y.reshape(*lead, d).astype(x.dtype)

@@ -289,6 +289,7 @@ def warm_engine(engine, widths=None) -> None:
     if getattr(engine, "moe_rows_routed", 0):
         # warmup picks belong to no request either
         engine.moe_rows_held = engine.moe_rows_routed = 0
+        engine.moe_rows_moved = engine.moe_rows_layout = 0
     if getattr(engine, "ssm_scan_tokens", 0):
         engine.ssm_scan_tokens = engine.ssm_scan_padded_tokens = 0
     engine.reset_epoch()
@@ -935,6 +936,8 @@ class PagedEngine(_EngineBase):
             s * int(getattr(model, "top_k", 0)) * self._moe_layers)
         self.moe_rows_held = 0       # cumulative (metrics export)
         self.moe_rows_routed = 0
+        self.moe_rows_moved = 0
+        self.moe_rows_layout = 0
         # positions the recurrent layers' prefill scans ran over: the
         # prompts' own, and their buckets' left padding beside them
         self.ssm_scan_tokens = 0
@@ -1096,6 +1099,15 @@ class PagedEngine(_EngineBase):
             lengths = lengths + active.astype(lengths.dtype)
             return (pool, logits[:, -1], keys, lengths), (toks, finite)
 
+        def rows_moved(pool):
+            """(rows moved, rows of the whole layouts) the expert layers
+            have counted into their `moe_rows` leaves."""
+            return sum(a for path, a
+                       in jax.tree_util.tree_flatten_with_path(pool)[0]
+                       if leaf_kind(path) == "rows")
+
+        # what the admissions since the last burst left in `moe_rows`
+        admitted = rows_moved(pool)
         # expert layers count into their `moe_stats` leaf: zeroed here so
         # that after the scan it holds this burst's own sums
         pool = jax.tree_util.tree_map_with_path(
@@ -1112,6 +1124,13 @@ class PagedEngine(_EngineBase):
             stats = jnp.stack(stats)
             stats = jnp.stack([stats[:, 0].sum(), stats[:, 1].sum(),
                                stats[:, 2].max()])
+            # ... and (rows moved, rows of the layouts): the burst's own,
+            # then the admissions' before it; zeroed for the next ones
+            stats = jnp.concatenate(
+                [stats, rows_moved(pool) - admitted, admitted])
+            pool = jax.tree_util.tree_map_with_path(
+                lambda path, a: jnp.zeros_like(a)
+                if leaf_kind(path) == "rows" else a, pool)
         return pool, last_logits, toks, keys, finite, stats
 
     def _verify(self, params, pool, last_logits, attn_starts, active,
@@ -1913,14 +1932,26 @@ class PagedEngine(_EngineBase):
                 # picks that landed on held experts, held experts with a
                 # row (summed over layers and steps) and the most rows
                 # one expert took in a step
-                rows, touched, most = (int(v) for v in stats)
+                # ... and the rows the layers' layouts moved in and out
+                # of their tile buffers beside the rows of the whole
+                # layouts: this burst's, then those of the admissions
+                # before it (a prefill is not read back: its count comes
+                # with the next burst's)
+                (rows, touched, most, moved, layout,
+                 pre_moved, pre_layout) = (int(v) for v in stats)
                 self.last_burst_experts = (rows, touched, most)
                 self.moe_rows_held += rows
                 self.moe_rows_routed += k * self._picks_a_step
+                self.moe_rows_moved += moved + pre_moved
+                self.moe_rows_layout += layout + pre_layout
                 if traced and getattr(span, "attrs", None) is not None:
                     span.attrs.update(expert_rows=rows,
                                       experts_touched=touched,
-                                      expert_rows_max=most)
+                                      expert_rows_max=most,
+                                      expert_rows_moved=moved,
+                                      expert_rows_layout=layout,
+                                      prefill_rows_moved=pre_moved,
+                                      prefill_rows_layout=pre_layout)
         self.burst_seq += 1
         self.last_burst_active = int(np.count_nonzero(self._active))
         self.last_finite = np.asarray(finite)

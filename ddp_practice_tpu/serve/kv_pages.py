@@ -434,10 +434,12 @@ class RadixPrefixCache:
 # Cache leaves that are NOT pages, by the variable's name in the model's
 # "cache" collection: a recurrent layer's fixed-size state a sequence
 # (models/hybrid_lm.py Mamba2Mixer) is pooled a SLOT, and an expert
-# layer's counters (ops/moe.py LatentMoE `moe_stats`) are one small
-# vector a layer.
+# layer's counters (ops/moe.py LatentMoE `moe_stats`, and `moe_rows`: the
+# rows its layout moved and those the whole layout holds) are one small
+# vector each a layer.
 STATE_LEAVES = ("ssm_state", "conv_state")
 STATS_LEAF = "moe_stats"
+ROWS_LEAF = "moe_rows"
 # a latent-attention layer's one page leaf (models/mla_lm.py): pages like
 # any K or V leaf; named so that the engine can size its gauge
 LATENT_LEAF = "cached_latent"
@@ -448,12 +450,13 @@ def leaf_name(path) -> str:
 
 
 def leaf_kind(path) -> str:
-    """"state" | "stats" | "pages": which pool a cache leaf belongs to
-    (scalars, the flat layout's cursors, are told by their rank)."""
+    """"state" | "stats" | "rows" | "pages": which pool a cache leaf
+    belongs to (scalars, the flat layout's cursors, are told by their
+    rank)."""
     name = leaf_name(path)
     if name in STATE_LEAVES:
         return "state"
-    return "stats" if name == STATS_LEAF else "pages"
+    return {STATS_LEAF: "stats", ROWS_LEAF: "rows"}.get(name, "pages")
 
 
 def make_paged_cache(model, num_blocks: int, block_size: int,
@@ -477,7 +480,7 @@ def make_paged_cache(model, num_blocks: int, block_size: int,
     shapes = jax.eval_shape(lambda: make_cache(model, 1, block_size))
 
     def per_leaf(path, a):
-        if a.ndim == 0 or leaf_kind(path) == "stats":
+        if a.ndim == 0 or leaf_kind(path) in ("stats", "rows"):
             return jnp.zeros(a.shape, a.dtype)
         lead = max_slots if leaf_kind(path) == "state" else num_blocks
         return jnp.zeros((lead,) + a.shape[1:], a.dtype)
@@ -509,9 +512,10 @@ def scatter_prompt_blocks(pool: Any, scratch: Any, block_ids,
     positions. int8 scale leaves ((1, h, width) -> (nb, h, block_size))
     chunk along their position axis (2) the same way. Scalar leaves
     keep the POOL's value (no global clock), as do an expert layer's
-    counters. A recurrent layer's state leaves are not pages: the
-    scratch's batch-1 final state overwrites row `slot` of the state
-    pool.
+    counters; the rows this prefill's expert layers moved join the
+    pool's (the next burst reads them back). A recurrent layer's state
+    leaves are not pages: the scratch's batch-1 final state overwrites
+    row `slot` of the state pool.
     """
     n_chunks = -(-width // block_size)
 
@@ -519,6 +523,8 @@ def scatter_prompt_blocks(pool: Any, scratch: Any, block_ids,
         kind = leaf_kind(path)
         if p.ndim == 0 or kind == "stats":
             return p
+        if kind == "rows":
+            return p + s
         if kind == "state":
             return lax.dynamic_update_slice(
                 p, s.astype(p.dtype), (slot,) + (0,) * (p.ndim - 1))

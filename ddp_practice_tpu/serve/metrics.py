@@ -68,9 +68,15 @@ class ServeMetrics:
         # engine's cumulative fields; bytes of the per-slot state pool
         self.moe_rows_held = r.counter("moe_rows_held_total")
         self.moe_rows_routed = r.counter("moe_rows_routed_total")
+        # rows the expert layers' layouts moved into their tile buffers
+        # and out of them (decode steps and prefills), beside the rows of
+        # the whole layouts, which cover any routing
+        self.moe_rows_moved = r.counter("moe_rows_moved_total")
+        self.moe_rows_layout = r.counter("moe_rows_layout_total")
         self.ssm_state_bytes = r.gauge("ssm_state_bytes")
         self.latent_cache_bytes = r.gauge("latent_cache_bytes")
         self._last_held = self._last_routed = 0
+        self._last_moved = self._last_layout = 0
         # positions the recurrent layers' prefill scans ran over: real
         # prompt tokens, and the buckets' padding beside them
         self.ssm_scan_tokens = r.counter("ssm_scan_tokens_total")
@@ -120,6 +126,11 @@ class ServeMetrics:
         self.moe_rows_held.inc(held - self._last_held)
         self.moe_rows_routed.inc(routed - self._last_routed)
         self._last_held, self._last_routed = held, routed
+        moved = getattr(eng, "moe_rows_moved", 0)
+        layout = getattr(eng, "moe_rows_layout", 0)
+        self.moe_rows_moved.inc(moved - self._last_moved)
+        self.moe_rows_layout.inc(layout - self._last_layout)
+        self._last_moved, self._last_layout = moved, layout
         scan = getattr(eng, "ssm_scan_tokens", 0)
         padded = getattr(eng, "ssm_scan_padded_tokens", 0)
         self.ssm_scan_tokens.inc(scan - self._last_scan)

@@ -252,6 +252,7 @@ def test_state_pool_is_a_slot_and_pages_are_the_kv_heads_wide(engine):
     assert shapes["mamba0/conv_state"] == (3, 3, 64 + 2 * 2 * 16)
     assert shapes["attn3/cached_key"] == (1 + 3 * 12, 8, 2 * 16)
     assert shapes["moe1/moe_stats"] == (3,)
+    assert shapes["moe1/moe_rows"] == (2,)
     state = 3 * (8 * 8 * 16 * 4 + 3 * 128 * 4) * 3   # three Mamba layers
     assert engine.ssm_state_bytes == state
 
@@ -289,6 +290,55 @@ def test_decode_burst_span_and_counters_say_what_the_experts_saw(toy):
     assert snap["moe_rows_held_total"] == sum(
         e["args"]["expert_rows"] for e in bursts)
     assert snap["ssm_state_bytes"] == engine.ssm_state_bytes
+
+
+def test_rows_moved_are_the_held_picks_rounded_to_tiles(toy):
+    """`expert_rows_moved` / `expert_rows_layout` on `decode_burst` and the
+    counters beside `moe_rows_held_total` (PR 39), on a routing known in
+    advance: a selection bias that sends every token to held experts 0, 1
+    and 2. A step's three slots then fill three tiles of 16 rows a layer and
+    sum 9 rows out of them, where the layout that covers any routing holds
+    (1 + 4) tiles and 9 picks; the admission's 8 positions fill the same
+    three tiles and sum 24 rows, and are counted with the burst after them."""
+    from ddp_practice_tpu.serve.metrics import ServeMetrics
+    from ddp_practice_tpu.serve.scheduler import Request, Scheduler
+    from ddp_practice_tpu.utils.trace import TraceRecorder
+
+    model, params = toy
+    bias = jnp.zeros((16,)).at[:3].set(10.0)
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, a: bias.astype(a.dtype)
+        if path[-1].key == "e_score_correction_bias" else a, params)
+    engine = make_engine(model, params, decode_burst=4, prompt_buckets=(8,))
+    tracer = TraceRecorder(max_events=1 << 12)
+    engine.set_tracer(tracer)
+    metrics = ServeMetrics()
+    sched = Scheduler(engine, max_queue=8, tracer=tracer, metrics=metrics)
+    sched.submit(Request(rid=0, prompt=[5, 6, 7], max_new_tokens=8, seed=0))
+    for _ in range(6):
+        sched.step()
+    bursts = [e["args"] for e in tracer.to_chrome_trace()["traceEvents"]
+              if e.get("name") == "decode_burst" and "args" in e]
+    assert len(bursts) >= 2
+    layers, steps, slots, k, tile, held = 3, 4, 3, 3, 16, 4
+    for a in bursts:
+        assert a["expert_rows"] == layers * steps * slots * k
+        assert a["expert_rows_moved"] == layers * steps * (
+            3 * tile + slots * k)
+        assert a["expert_rows_layout"] == layers * steps * (
+            (1 + held) * tile + slots * k)
+        assert a["expert_rows_moved"] <= a["expert_rows_layout"]
+    # the one admission (bucket 8) is read back with the first burst
+    assert bursts[0]["prefill_rows_moved"] == layers * (3 * tile + 8 * k)
+    assert bursts[0]["prefill_rows_layout"] == layers * (
+        (2 + held) * tile + 8 * k)
+    assert all(a["prefill_rows_moved"] == 0 for a in bursts[1:])
+    snap = metrics.registry.snapshot()
+    assert snap["moe_rows_moved_total"] == sum(
+        a["expert_rows_moved"] + a["prefill_rows_moved"] for a in bursts)
+    assert snap["moe_rows_layout_total"] == sum(
+        a["expert_rows_layout"] + a["prefill_rows_layout"] for a in bursts)
+    assert 0 < snap["moe_rows_moved_total"] < snap["moe_rows_layout_total"]
 
 
 GROUPED = {

@@ -262,7 +262,9 @@ def test_a_decode_step_is_one_mla_walk_and_one_glu_kernel_a_layer(
     names = _pallas_names(jax.make_jaxpr(step)(
         params, pool, jnp.zeros((4, 1), jnp.int32),
         jnp.zeros((4, 2), jnp.int32), jnp.zeros((4,), jnp.int32)).jaxpr)
-    assert sorted(names) == ["moe_gmm_glu"] * 2 + ["paged_decode_mla"] * 3
+    # an expert layer is three kernels: its rows in, its experts, its sums
+    assert sorted(names) == ["moe_gmm_glu"] * 2 + ["moe_rows_fill"] * 2 \
+        + ["moe_rows_sum"] * 2 + ["paged_decode_mla"] * 3
 
 
 # ------------------------------------------------------------- the engine

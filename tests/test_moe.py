@@ -546,3 +546,226 @@ def test_expert_choice_lm_generates():
     out = gen(params, prompt, jax.random.PRNGKey(1))
     assert out.shape == (2, 14)
     assert (np.asarray(out[:, :8]) == np.asarray(prompt)).all()
+
+
+# ------------------------------------------- a chip's share of the experts
+# `LatentMoE` / `GatedMoE` (ops/moe.py): the rows in and out of the expert
+# kernel are the held picks' (`moe_rows_fill`, `moe_rows_sum`, in interpret
+# mode here), whatever the router does.
+
+def _held_layer(kind, *, k, held, experts, router="sigmoid"):
+    from ddp_practice_tpu.ops import moe
+
+    if kind == "latent":
+        return moe.LatentMoE(
+            num_experts=experts, top_k=k, latent_dim=128, expert_dim=24,
+            shared_dim=40, experts_held=held, expert_offset=experts - held,
+            routed_scaling=1.5)
+    return moe.GatedMoE(
+        num_experts=experts, top_k=k, expert_dim=24, shared_dim=40,
+        experts_held=held, expert_offset=experts - held, routed_scaling=1.5,
+        router=router, shared_gate=router == "softmax")
+
+
+def _steer(params, routing, *, k, held, experts):
+    """The layer's parameters with its router made to do `routing`: the
+    first input feature is a constant 1 (`_held_case`) and the router's
+    first row says which experts every token prefers."""
+    prefer = np.full((experts,), -30.0, np.float32)
+    first = experts - held
+    if routing == "all_held":
+        prefer[first:first + k] = 30.0
+    elif routing == "none_held":
+        prefer[:k] = 30.0
+    elif routing == "one_expert":
+        prefer[first + held // 2] = 30.0
+    else:
+        assert routing == "random", routing
+        prefer[:] = 0.0
+    router = np.array(params["router"]["kernel"])
+    router[0] += prefer
+    params = dict(params, router={"kernel": jnp.asarray(router)})
+    if "e_score_correction_bias" in params:   # it selects; make it count
+        params["e_score_correction_bias"] = jnp.asarray(
+            np.random.default_rng(1).normal(size=experts) * 0.05,
+            jnp.float32)
+    return params
+
+
+def _held_loop(layer, params, x):
+    """The layer token by token in float32 numpy: the router's picks and
+    weights (the layer's own router functions over the same logits), then a
+    plain loop over each token's HELD picks."""
+    from ddp_practice_tpu.ops import moe
+
+    p = jax.tree.map(lambda a: np.asarray(a, np.float64), params)
+    x = np.asarray(x, np.float64)
+    logits = jnp.asarray(x, jnp.float32) @ params["router"]["kernel"]
+    if getattr(layer, "router", "sigmoid") == "softmax":
+        choices, weights = moe.route_softmax_topk(
+            logits, k=layer.top_k, scaling=layer.routed_scaling)
+    else:
+        choices, weights = moe.route_sigmoid_topk(
+            logits, params["e_score_correction_bias"], k=layer.top_k,
+            scaling=layer.routed_scaling)
+    choices, weights = np.asarray(choices), np.asarray(weights, np.float64)
+    silu = lambda v: v / (1.0 + np.exp(-v))
+    latent = isinstance(layer, moe.LatentMoE)
+    u = x @ p["down"]["kernel"] if latent else x
+    routed = np.zeros_like(u)
+    lo = layer.expert_offset
+    for t in range(x.shape[0]):
+        for e, w in zip(choices[t], weights[t]):
+            if not lo <= e < lo + layer.experts_held:
+                continue
+            if latent:
+                h = np.maximum(u[t] @ p["expert_w1"][e - lo], 0.0) ** 2
+                routed[t] += w * (h @ p["expert_w2"][e - lo])
+            else:
+                h = silu(u[t] @ p["expert_gate"][e - lo]) \
+                    * (u[t] @ p["expert_up"][e - lo])
+                routed[t] += w * (h @ p["expert_down"][e - lo])
+    if latent:
+        shared = np.maximum(x @ p["shared_in"]["kernel"], 0.0) ** 2
+        return routed @ p["up"]["kernel"] + shared @ p["shared_out"]["kernel"]
+    s = p["shared"]
+    shared = (silu(x @ s["gate"]["kernel"]) * (x @ s["up"]["kernel"])) \
+        @ s["down"]["kernel"]
+    if layer.shared_gate:
+        shared = shared / (1.0 + np.exp(
+            -(x @ p["shared_expert_gate"]["kernel"])))
+    return routed + shared
+
+
+def _held_case(kind, n, k, held, experts, routing, router="sigmoid"):
+    layer = _held_layer(kind, k=k, held=held, experts=experts, router=router)
+    x = jax.random.normal(jax.random.PRNGKey(n), (n, 128), jnp.float32)
+    x = x.at[:, 0].set(1.0)
+    params = layer.init(jax.random.PRNGKey(7), x)["params"]
+    params = jax.tree.map(   # weights large enough for every term to show
+        lambda a: a * 8.0 if a.ndim == 3 else a, params)
+    return layer, _steer(params, routing, k=k, held=held, experts=experts), x
+
+
+@pytest.fixture()
+def rows_by_kernel(monkeypatch):
+    """The layers' row movement through the kernels, interpreted (off the
+    TPU a layer takes the gathers: a kernel nobody asked for is not
+    interpreted)."""
+    import functools
+
+    from ddp_practice_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "held_rows_fill", functools.partial(
+        moe.held_rows_fill_kernel, interpret=True))
+    monkeypatch.setattr(moe, "held_rows_sum", functools.partial(
+        moe.held_rows_sum_kernel, interpret=True))
+
+
+HELD_CASES = [
+    # kind, n, k, held of experts, routing[, router]
+    ("gated", 24, 3, 4, 16, "random"),
+    ("gated", 24, 3, 4, 16, "all_held"),
+    ("gated", 24, 3, 4, 16, "none_held"),
+    ("gated", 24, 1, 4, 16, "one_expert"),
+    ("gated", 7, 3, 8, 8, "random"),          # all held; n k = 21, tile 16
+    ("gated", 24, 4, 4, 16, "random", "softmax"),
+    ("gated", 24, 4, 4, 16, "all_held", "softmax"),
+    ("latent", 24, 3, 4, 16, "random"),
+    ("latent", 24, 3, 4, 16, "all_held"),
+    ("latent", 24, 3, 4, 16, "none_held"),
+    ("latent", 24, 1, 4, 16, "one_expert"),
+    ("latent", 5, 2, 3, 6, "random"),         # n k = 10, under one tile
+]
+
+
+@pytest.mark.parametrize("rows", ["gathers", "kernels"])
+@pytest.mark.parametrize("case", HELD_CASES, ids=lambda c: "-".join(
+    str(v) for v in c))
+def test_held_layer_is_the_loop_over_its_held_picks(case, rows, request):
+    """Exact for any routing: the layer against a plain per-token float32
+    loop over the picks that are held, for picks drawn at random, all held,
+    none held, every token on one expert, and `n k` off the tile; with the
+    gathers (what the CPU runs) and with the kernels (what the chip runs)."""
+    if rows == "kernels":
+        request.getfixturevalue("rows_by_kernel")
+    layer, params, x = _held_case(*case)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(jax.jit(
+            lambda p, x: layer.apply({"params": p}, x))(params, x))
+    want = _held_loop(layer, params, x)
+    assert np.isfinite(got).all()
+    # the routing is the one asked for (the steering worked)
+    _, mut = layer.apply({"params": params}, x, decode=True,
+                         mutable=["cache"])
+    held_picks = int(mut["cache"]["moe_stats"][0])
+    n, k, routing = case[1], case[2], case[5]
+    assert held_picks == {"all_held": n * k, "none_held": 0,
+                          "one_expert": n}.get(routing, held_picks)
+    scale = max(1.0, float(np.abs(want).max()))
+    np.testing.assert_allclose(got, want, atol=2e-4 * scale, rtol=0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("rows", ["gathers", "kernels"])
+def test_no_row_without_a_valid_pick_reaches_a_token(rows, dtype):
+    """A non-finite value in every buffer row that holds no valid pick (the
+    tiles' tails, the idle tiles, row 0 where it is one) leaves every sum
+    finite and as it was: a pick that is not held adds an exact 0."""
+    from ddp_practice_tpu.ops import moe
+
+    n, k, experts, held, tile, d = 24, 3, 16, 5, 16, 128
+    ka, kb, kc = jax.random.split(jax.random.PRNGKey(3), 3)
+    _, choices = jax.lax.top_k(jax.random.normal(ka, (n, experts)), k)
+    weights = jax.random.uniform(kb, (n, k), jnp.float32)
+    lay = moe.held_tile_layout(choices.astype(jnp.int32), offset=2,
+                               held=held, tile=tile)
+    assert not bool(lay["pick_held"].all()) and bool(lay["pick_held"].any())
+    clean = jnp.where(lay["row_valid"][:, None], jax.random.normal(
+        kc, (lay["row_valid"].shape[0], d)), 0.0).astype(dtype)
+    poisoned = jnp.where(lay["row_valid"][:, None], clean, jnp.nan)
+    if rows == "kernels":
+        total = lambda out: moe.held_rows_sum_kernel(
+            out, lay, weights, jnp.float32, tile=tile, interpret=True)
+    else:
+        total = lambda out: moe.held_rows_sum_reference(
+            out, lay, weights, jnp.float32)
+    got = np.asarray(total(poisoned))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, np.asarray(total(clean)))
+    want = np.zeros((n, d))
+    for t in range(n):
+        for j in range(k):
+            if lay["pick_held"][t, j]:
+                want[t] += float(weights[t, j]) * np.asarray(
+                    clean[lay["pick_row"][t, j]], np.float64)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("n, k, experts, held, offset", [
+    (24, 3, 16, 5, 2), (7, 3, 4, 4, 0), (40, 1, 16, 4, 12), (33, 2, 8, 2, 6)])
+def test_rows_fill_kernel_is_the_gather_to_the_bit(n, k, experts, held,
+                                                   offset, dtype):
+    """`moe_rows_fill`: the used tiles row for row what the gather over the
+    whole layout gives (16-bit rows are moved as halves of 32-bit words:
+    odd and even tokens both)."""
+    from ddp_practice_tpu.ops import moe
+
+    ka, kb = jax.random.split(jax.random.PRNGKey(n), 2)
+    _, choices = jax.lax.top_k(jax.random.normal(ka, (n, experts)), k)
+    src = jax.random.normal(kb, (n, 256)).astype(dtype)
+    lay = moe.held_tile_layout(choices.astype(jnp.int32), offset=offset,
+                               held=held, tile=16)
+    used = int(lay["tiles_used"][0]) * 16
+    assert used
+    got = moe.held_rows_fill_kernel(src, lay, tile=16, interpret=True)
+    want = moe.held_rows_fill_reference(src, lay)
+    np.testing.assert_array_equal(
+        np.asarray(got[:used].astype(jnp.float32)),
+        np.asarray(want[:used].astype(jnp.float32)))
+    moved, layout = (int(v) for v in moe.held_rows_moved(lay, 16))
+    assert moved == used + int(lay["pick_held"].sum()) <= layout
+    assert layout == lay["row_valid"].shape[0] + n * k

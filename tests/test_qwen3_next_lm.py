@@ -495,7 +495,9 @@ def _pallas_names(jaxpr) -> list:
 def test_a_decode_step_and_a_prefill_hold_their_kernels_by_name(monkeypatch):
     """At the published depth and layout (toy widths): a decode step is 6
     `gdn_step`, 2 `paged_decode` and 8 `moe_gmm_glu`; an admission prefill 6
-    `gdn_scan` and 8 `moe_gmm_glu` (its attention is plain XLA)."""
+    `gdn_scan` and 8 `moe_gmm_glu` (its attention is plain XLA); and beside
+    each `moe_gmm_glu` the two kernels that move its rows, `moe_rows_fill`
+    in and `moe_rows_sum` out."""
     from ddp_practice_tpu.inference import make_cache
     from ddp_practice_tpu.utils import backend
 
@@ -514,8 +516,9 @@ def test_a_decode_step_and_a_prefill_hold_their_kernels_by_name(monkeypatch):
     names = _pallas_names(jax.make_jaxpr(step)(
         params, pool, jnp.zeros((4, 1), jnp.int32),
         jnp.zeros((4, 2), jnp.int32), jnp.zeros((4,), jnp.int32)).jaxpr)
-    assert sorted(names) == ["gdn_step"] * 6 + ["moe_gmm_glu"] * 8 \
-        + ["paged_decode"] * 2
+    experts = ["moe_gmm_glu"] * 8 + ["moe_rows_fill"] * 8 \
+        + ["moe_rows_sum"] * 8
+    assert sorted(names) == ["gdn_step"] * 6 + experts + ["paged_decode"] * 2
 
     def prefill(params, tokens, start):
         return decode_apply(model, params, make_cache(model, 1, 128), tokens,
@@ -524,7 +527,7 @@ def test_a_decode_step_and_a_prefill_hold_their_kernels_by_name(monkeypatch):
     names = _pallas_names(jax.make_jaxpr(prefill)(
         params, jnp.zeros((1, 128), jnp.int32),
         jnp.zeros((1,), jnp.int32)).jaxpr)
-    assert sorted(names) == ["gdn_scan"] * 6 + ["moe_gmm_glu"] * 8
+    assert sorted(names) == ["gdn_scan"] * 6 + experts
 
 
 def test_the_scopes_gdn_scan_and_gdn_step_are_in_the_op_paths(toy):

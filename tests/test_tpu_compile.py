@@ -419,6 +419,36 @@ def _moe_glu_qwen(rows_a_tile):
                  _sds((tiles,), jnp.int32), _sds((1,), jnp.int32))
 
 
+def _moe_rows(which, n, k, experts, width, held=128):
+    """The held experts' row movement (ops/moe.py, PR 39) at a cell's
+    widths: `moe_rows_fill` holds all n tokens' rows in VMEM (16 MB at a
+    4,096-token prompt of width 2,048) and `moe_rows_sum` all n float32 sums
+    (32 MB there); sorted tokens and weights of every pick in SMEM."""
+    from ddp_practice_tpu.ops import moe
+
+    tile = moe._row_tile(n * k / experts)
+    tiles = -(-n * k // tile) + held
+    i32 = lambda *shape: _sds(shape, jnp.int32)
+
+    def lay_of(sorted_pick, first, rows, tile_expert, used):
+        return {"sorted_pick": sorted_pick, "sorted_token": sorted_pick // k,
+                "tile_first_pick": first, "tile_rows": rows,
+                "tile_expert": tile_expert, "tiles_used": used}
+
+    def fill(src, *lay):
+        return moe.held_rows_fill_kernel(src, lay_of(*lay), tile=tile)
+
+    def total(out, weights, *lay):
+        return moe.held_rows_sum_kernel(out, lay_of(*lay), weights, BF16,
+                                        tile=tile)
+
+    lay = (i32(n * k), i32(tiles), i32(tiles), i32(tiles), i32(1))
+    if which == "fill":
+        return fill, (_sds((n, width)),) + lay
+    return total, (_sds((tiles * tile, width)),
+                   _sds((n, k), jnp.float32)) + lay
+
+
 def _kernel_calls(text):
     """Names of the compiled Pallas custom calls, in program order."""
     return [ln.split("=")[0].split("%")[-1].strip().split(".")[0]
@@ -449,6 +479,13 @@ KERNELS = {
     "qwen_gdn_scan_4096": functools.partial(_gdn, "scan"),
     "qwen_gdn_scan_256": functools.partial(_gdn, "scan", length=256),
     "qwen_paged_hd256_group8_page64": _paged_hd256,
+    **{f"rows_{which}_{cell}_{n}": functools.partial(
+        _moe_rows, which, n, k, experts, width)
+       for cell, k, experts, width, ns in (
+           ("qwen", 10, 512, 2048, (128, 4096)), ("nemo", 22, 512, 1024,
+                                                   (768,)),
+           ("kanana", 6, 128, 2048, (1024,)))
+       for n in ns for which in ("fill", "sum")},
     "qwen_moe_glu_decode_tiles": functools.partial(_moe_glu_qwen, 16),
     "qwen_moe_glu_prompt_tiles": functools.partial(_moe_glu_qwen, 128),
     "latent_paged_mla_page64": _paged_mla,
@@ -546,6 +583,13 @@ def test_kernel_compiles_for_v5e(topo, name):
                 "qwen_moe_gl": "moe_gmm_glu"}[name[:11]]
         calls = _kernel_calls(text)
         assert len(calls) == 1 and calls[0].endswith(want), calls
+    if name.startswith("rows"):
+        # ONE device op each, named `moe_` and not `moe_gmm*`: the rooflines
+        # of the expert kernels sum the ops named `moe_gmm*` and must not
+        # hold these
+        calls = _kernel_calls(text)
+        assert len(calls) == 1 and calls[0].endswith(
+            "moe_rows_" + name.split("_")[1]), calls
     if name.startswith("latent"):
         # the names perf/layer_metrics/flood_mla_*, flood_moe_glu_* sum by
         want = "paged_decode_mla" if "mla" in name else "moe_gmm_glu"
@@ -1000,9 +1044,14 @@ def _kernel_counts(text):
     return Counter(c.split("/")[-1] for c in _kernel_calls(text))
 
 
+def _expert_layers(n):
+    """`n` expert layers' kernels: the rows in, the experts, the sums."""
+    return {"moe_rows_fill": n, "moe_gmm_glu": n, "moe_rows_sum": n}
+
+
 @pytest.mark.parametrize("prog, kernels", [
-    ("decode_burst", {"gdn_step": 1, "paged_decode": 1, "moe_gmm_glu": 2}),
-    ("prefill", {"gdn_scan": 1, "moe_gmm_glu": 2})])
+    ("decode_burst", {"gdn_step": 1, "paged_decode": 1, **_expert_layers(2)}),
+    ("prefill", {"gdn_scan": 1, **_expert_layers(2)})])
 def test_qwen3_next_programs_carry_their_scopes_and_kernels(topo, prog,
                                                             kernels):
     """The new cell's programs compiled for the described v5e at its widths
@@ -1011,7 +1060,8 @@ def test_qwen3_next_programs_carry_their_scopes_and_kernels(topo, prog,
     there, in the engine this cell shares line for line), and the kernels by
     name and count: a decode step 1 `gdn_step`, 1 `paged_decode` and 2
     `moe_gmm_glu`; an admission prefill 1 `gdn_scan` and 2 `moe_gmm_glu`
-    (its attention is plain XLA)."""
+    (its attention is plain XLA); a `moe_rows_fill` and a `moe_rows_sum`
+    beside each `moe_gmm_glu` (PR 39)."""
     cell = "qwen3next_serve_mixed"
     with _no_frames_in_locations():
         text = _cell_programs(topo, cell)[prog]().compile().as_text()
@@ -1033,10 +1083,10 @@ def test_qwen3_next_programs_hold_their_kernels_by_count(topo):
     finally:
         CELL_DEPTH["qwen3_next"] = was
     decode = _kernel_counts(progs["decode_burst"]().compile().as_text())
-    assert decode == {"gdn_step": 3, "paged_decode": 1, "moe_gmm_glu": 4}, \
-        decode
+    assert decode == {"gdn_step": 3, "paged_decode": 1,
+                      **_expert_layers(4)}, decode
     prefill = _kernel_counts(progs["prefill"]().compile().as_text())
-    assert prefill == {"gdn_scan": 3, "moe_gmm_glu": 4}, prefill
+    assert prefill == {"gdn_scan": 3, **_expert_layers(4)}, prefill
 
 
 # ------------------------------------------------------------ whole steps
