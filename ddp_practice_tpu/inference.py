@@ -81,6 +81,7 @@ def decode_apply(
     batch_stats: Any = None,
     page_table=None,
     kv_lengths=None,
+    real_lengths=None,
 ) -> tuple:
     """One decode-mode model application: `(new_cache, logits)`.
 
@@ -101,7 +102,9 @@ def decode_apply(
     already-resident prefix through the table (the prefix-cache
     admission path, serve/engine.py PagedEngine._prefix_prefill). An
     int8-cache model pools per-block scale pages alongside
-    (models/vit.py).
+    (models/vit.py). `real_lengths` (b,), for a model with recurrent state
+    (models/hybrid_lm.py) alone: how many of a paged prefill's tokens are
+    real, the rest right padding its scans must not advance over.
     """
     variables = {"params": params, "cache": cache}
     if batch_stats is not None:
@@ -109,6 +112,8 @@ def decode_apply(
     kwargs = {}
     if page_table is not None:
         kwargs = {"page_table": page_table, "kv_lengths": kv_lengths}
+        if real_lengths is not None:
+            kwargs["real_lengths"] = real_lengths
     logits, mut = model.apply(
         variables,
         tokens,

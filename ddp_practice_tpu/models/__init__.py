@@ -8,7 +8,7 @@ replacing ddp_main.py:120).
 
 Ladder beyond parity (BASELINE.json configs): ResNet-18/50, ViT-Tiny.
 
-`HybridLM` (models/hybrid_lm.py) is in the registry three times, one layout
+`HybridLM` (models/hybrid_lm.py) is in the registry four times, one layout
 of its pattern string each; the defaults are test-sized and the published
 widths come as options (perf/families/*.py `model_options`):
 
@@ -18,9 +18,16 @@ widths come as options (perf/families/*.py `model_options`):
                  `head_dim`, q/k norms, rotary on `rope_dim` lanes, an
                  output gate), 'Q' GatedMoE with a softmax router and a
                  gated shared expert; zero-centred norms, pos_emb="rope"
+    minicpm_sala 'L' lightning (linear) attention on the Mamba-2 kernels,
+                 'B' block-sparse attention (compressed keys, top-k pages
+                 past `sparse.dense_len`), 'D'; muP scalars on the stream
+                 (`embed_scale`, `residual_scale`, `head_scale`),
+                 pos_emb="rope"
 
-All three hold recurrent state (`recurrent=True`): `PagedEngine` serves them
-and refuses `prefix_cache`, `prefill_chunk`, `spec_decode` and `fork()`
+All four hold recurrent state (`recurrent=True`): `PagedEngine` serves them,
+admits a long prompt in chunks over the slot's own state (`prefill_chunk`
+without `prefix_cache`) and refuses `prefix_cache`, `spec_decode` and
+`fork()`, which need the state at a position that is not the sequence's end
 (ROADMAP M6); `SlotEngine` refuses them.
 """
 
@@ -259,6 +266,34 @@ def _qwen3_next(*, num_classes, policy, axis_name, **kw):
     kw.setdefault("pos_emb", "rope")
     kw.setdefault("hidden_dim", 64)
     kw.setdefault("head_dim", 32)
+    return HybridLM(
+        dtype=policy.compute_dtype,
+        param_dtype=policy.param_dtype,
+        **kw,
+    )
+
+
+@register("minicpm_sala")
+def _minicpm_sala(*, num_classes, policy, axis_name, **kw):
+    # the same HybridLM in MiniCPM-SALA's layout: a layer is a mixer
+    # sub-layer (lightning attention 'L', every fourth block-sparse
+    # attention 'B') and a dense SwiGLU 'D', muP scalars on the stream,
+    # untied head; test-sized defaults, the published widths come as
+    # options (perf/families/minicpm_sala.py model_options)
+    from ddp_practice_tpu.ops.sparse_attention import SparseSpec
+
+    kw.setdefault("pattern", "BDLDLDLD")
+    kw.setdefault("pos_emb", "rope")
+    kw.setdefault("norm_eps", 1e-6)
+    kw.setdefault("hidden_dim", 64)
+    kw.setdefault("head_dim", 16)
+    kw.setdefault("sparse", SparseSpec(
+        block=8, kernel=4, stride=2, window=8, dense_len=32, topk=4))
+    if "lightning_layers" not in kw:   # numbered as they stand
+        mixers = kw["pattern"][::2]
+        kw["lightning_layers"] = tuple(
+            i for i, m in enumerate(mixers) if m == "L")
+        kw.setdefault("decay_layers", len(mixers))
     return HybridLM(
         dtype=policy.compute_dtype,
         param_dtype=policy.param_dtype,

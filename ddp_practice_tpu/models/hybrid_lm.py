@@ -8,6 +8,9 @@ a pattern string.
          state, a low-rank dt, RMSNorm on dt, B and C (Jamba's)
     'G'  Gated DeltaNet mixer: the gated delta rule on a (key_dim,
          value_dim) matrix state a value head   (ops/gdn.py)
+    'L'  lightning (linear) attention: the Mamba-2 recurrence with dt = 1
+         and one constant decay a head, on rotated, normed q and k
+         (ops/ssm.py `ssm_step` / `ssm_scan`; `LightningMixer`)
     'E'  LatentMoE, a chip's share of the experts held  (ops/moe.py)
     'Q'  GatedMoE with a softmax router and a shared expert behind a
          sigmoid gate of its own, a chip's share held   (ops/moe.py)
@@ -18,19 +21,27 @@ a pattern string.
          on every q and k head, rotary on a head's first `rope_dim`
          lanes, the output times sigmoid(gate), the gate the query
          projection's second half (`pos_emb="rope"`)
+    'B'  block-sparse attention: head norms, no rotary, an output gate;
+         past `sparse.dense_len` visible tokens a query attends `topk`
+         pages picked through compressed keys (ops/sparse_attention.py;
+         `SparseAttention`)
     logits = RMSNorm(x) W_head         over `vocab_size` rows; with
                                        `tie_embeddings` W_head is the
                                        embedding itself
 
-Three layouts in the registry. Nemotron-H (`create_model("nemotron_h",
+Four layouts in the registry. Nemotron-H (`create_model("nemotron_h",
 ...)`): one mixer a layer from 'M', 'E', '*', untied head. Jamba
 (`create_model("jamba", ...)`): a layer is two sub-layers, a mixer ('S' or
 '*') then 'D', so 28 layers are 56 letters, and the head is tied. Qwen3-Next
 (`create_model("qwen3_next", ...)`): a mixer ('G', every fourth 'A') then
 'Q', every norm the zero-centred `(1 + w)` one (`norm_plus_one`), untied
-head. The widths are options, so the tests run all three small and the
-benchmark at the published sizes (perf/configs/nemotron3_super_ep4.json,
-jamba2_3b.json, qwen3next_80b_ep4.json).
+head. MiniCPM-SALA (`create_model("minicpm_sala", ...)`): a mixer ('L',
+every fourth 'B') then 'D', with muP scalars on the stream: `embed_scale`
+times the embedding, `residual_scale` times every branch, `head_scale`
+times the head's input (each 1 in the other layouts). The widths are
+options, so the tests run all four small and the benchmark at the published
+sizes (perf/configs/nemotron3_super_ep4.json, jamba2_3b.json,
+qwen3next_80b_ep4.json, minicpm_sala_9b_pp4.json).
 
 Decode mode keeps TWO kinds of cache in the "cache" collection: attention
 layers the K/V leaves `SelfAttention` declares (flat, or pages under
@@ -39,28 +50,34 @@ SAME two leaf names whichever mixer: `ssm_state` in float32 (Mamba-2
 (b, heads, head_dim, state); Mamba-1 (b, state, channels / 128, 128), the
 layout `ops/ssm.py sel_step` reads; Gated DeltaNet (b, value heads,
 key_dim, value_dim)) and `conv_state`, the conv's last
-`conv_kernel - 1` inputs. A call with one token a sequence advances the
+`conv_kernel - 1` inputs ('L' has no conv and declares `ssm_state` alone,
+(b, heads, value, key)). A call with one token a sequence advances the
 state by the recurrence; a call with more runs the scan FROM the
 stored state (zeros for a fresh sequence) and leaves the final state.
-Left padding (`attn_start`) moves neither: a padded position has dt = 0
-and a zero conv input. Pages cannot re-derive a state, so what needs a
-sequence's past at an arbitrary position (a paged call of several tokens:
-prefix reuse, chunked prefill, speculative verify) is refused here and at
-engine construction.
+Padding moves neither: a padded position has dt = 0 and a zero conv input.
+A flat call of several tokens is LEFT-padded (`attn_start`); a paged one
+continues the slot's END (a prompt's next chunk, at slot-local positions
+`kv_lengths + [0, s)`) and is RIGHT-padded past `real_lengths` (0 for the
+one token of a slot that is not decoding: its state stays). Pages
+cannot re-derive a state, so what needs a sequence's state at a position
+that is NOT its end (prefix reuse, speculative verify, a fork) is refused
+at engine construction (serve/engine.py): the mixers cannot tell.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Callable, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ddp_practice_tpu.models.vit import SelfAttention
-from ddp_practice_tpu.ops import gdn, ssm
+from ddp_practice_tpu.ops import gdn, sparse_attention as sparse_ops, ssm
 from ddp_practice_tpu.ops.moe import GatedMLP, GatedMoE, LatentMoE
+from ddp_practice_tpu.ops.rope import apply_rope
 
 
 class RMSNorm(nn.Module):
@@ -89,17 +106,27 @@ class RMSNorm(nn.Module):
                 ).astype(self.dtype)
 
 
-def _state_leaves(module, state0, tail0, several_paged: bool):
+def _state_leaves(module, state0, tail0):
     """The two cache leaves every state-space mixer declares, under the
     names `serve/kv_pages.py STATE_LEAVES` pools a slot."""
-    if several_paged:
-        raise ValueError(
-            "a recurrent layer cannot take several tokens at slot-"
-            "local positions through pages: its state holds only "
-            "the sequence's end (prefix reuse, chunked prefill and "
-            "speculative verify need state snapshots)")
     return (module.variable("cache", "ssm_state", lambda: state0),
             module.variable("cache", "conv_state", lambda: tail0))
+
+
+def _real_rows(s: int, attn_start, real_lengths, paged: bool):
+    """(b, s, 1) bool, the positions of a call that are not padding, or
+    None where all are: a flat call of several tokens is a left-padded row
+    from its position 0 (the engines' prefill), a paged call right-padded
+    past `real_lengths`: a chunk's tail, or the one token of a slot that is
+    not decoding (`real_lengths` 0: a slot between two chunks of its prompt
+    keeps its state through the others' decode steps)."""
+    real = None
+    if s > 1 and attn_start is not None and not paged:
+        real = jnp.arange(s)[None, :] >= attn_start[:, None]
+    if real_lengths is not None:
+        inside = jnp.arange(s)[None, :] < real_lengths[:, None]
+        real = inside if real is None else real & inside
+    return None if real is None else real[..., None]
 
 
 class Mamba2Mixer(nn.Module):
@@ -115,7 +142,7 @@ class Mamba2Mixer(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, decode: bool = False, attn_start=None,
-                 paged: bool = False):
+                 paged: bool = False, real_lengths=None):
         b, s, d = x.shape
         h, p, n, g = (self.num_heads, self.head_dim, self.state_size,
                       self.groups)
@@ -133,20 +160,18 @@ class Mamba2Mixer(nn.Module):
         d_skip = vec("D", (h,))
         dt = jax.nn.softplus(
             dt.astype(jnp.float32) + vec("dt_bias", (h,)).astype(jnp.float32))
-        if attn_start is not None and s > 1:
-            # a call of several tokens is a padded row from its position 0
-            # (the engines' prefill); a single token is always real
-            real = jnp.arange(s)[None, :] >= attn_start[:, None]   # (b, s)
-            dt = jnp.where(real[..., None], dt, 0.0)
-            xbc = jnp.where(real[..., None], xbc, 0)
+        real = _real_rows(s, attn_start, real_lengths, paged)
+        if real is not None:
+            dt = jnp.where(real, dt, 0.0)
+            xbc = jnp.where(real, xbc, 0)
         state0 = jnp.zeros((b, h, p, n), jnp.float32)
         tail0 = jnp.zeros((b, self.conv_kernel - 1, conv_dim), self.dtype)
         if decode:
-            ssm_state, conv_state = _state_leaves(
-                self, state0, tail0, paged and s > 1)
+            ssm_state, conv_state = _state_leaves(self, state0, tail0)
             if not self.is_initializing():
                 state0, tail0 = ssm_state.value, conv_state.value
-        xbc, tail = ssm.causal_conv(xbc, tail0, conv_w, conv_b)
+        xbc, tail = ssm.causal_conv(xbc, tail0, conv_w, conv_b,
+                                    real_lengths)
         xbc = nn.silu(xbc)
         xs, bm, cm = jnp.split(xbc, [inner, inner + gn], axis=-1)
         xs = xs.reshape(b, s, h, p)
@@ -183,7 +208,7 @@ class Mamba1Mixer(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, decode: bool = False, attn_start=None,
-                 paged: bool = False):
+                 paged: bool = False, real_lengths=None):
         b, s, d = x.shape
         c, n, r = self.inner, self.state_size, self.dt_rank
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
@@ -197,20 +222,17 @@ class Mamba1Mixer(nn.Module):
         conv_b = vec("conv_bias", (c,))
         a = -jnp.exp(vec("A_log", (c, n)).astype(jnp.float32))
         d_skip = vec("D", (c,))
-        real = None
-        if attn_start is not None and s > 1:
-            # a call of several tokens is a padded row from its position 0
-            # (the engines' prefill); a single token is always real
-            real = (jnp.arange(s)[None, :] >= attn_start[:, None])[..., None]
+        real = _real_rows(s, attn_start, real_lengths, paged)
+        if real is not None:
             u = jnp.where(real, u, 0)
         state0 = jnp.zeros(ssm.sel_state_shape(b, c, n), jnp.float32)
         tail0 = jnp.zeros((b, self.conv_kernel - 1, c), self.dtype)
         if decode:
-            ssm_state, conv_state = _state_leaves(
-                self, state0, tail0, paged and s > 1)
+            ssm_state, conv_state = _state_leaves(self, state0, tail0)
             if not self.is_initializing():
                 state0, tail0 = ssm_state.value, conv_state.value
-        u, tail = ssm.causal_conv(u, tail0, conv_w, conv_b)
+        u, tail = ssm.causal_conv(u, tail0, conv_w, conv_b,
+                                  real_lengths)
         u = nn.silu(u)
         low, bm, cm = jnp.split(
             nn.Dense(r + 2 * n, use_bias=False, name="x_proj", **kw)(u),
@@ -252,7 +274,7 @@ class GatedDeltaMixer(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, decode: bool = False, attn_start=None,
-                 paged: bool = False):
+                 paged: bool = False, real_lengths=None):
         b, s, d = x.shape
         hk, hv, dk, dv = (self.key_heads, self.value_heads, self.key_dim,
                           self.value_dim)
@@ -272,20 +294,18 @@ class GatedDeltaMixer(nn.Module):
         beta = nn.sigmoid(beta)
         g = -jnp.exp(vec("A_log", (hv,)).astype(f32)) * jax.nn.softplus(
             a + vec("dt_bias", (hv,)).astype(f32))
-        if attn_start is not None and s > 1:
-            # a call of several tokens is a padded row from its position 0
-            # (the engines' prefill); a single token is always real
-            real = (jnp.arange(s)[None, :] >= attn_start[:, None])[..., None]
+        real = _real_rows(s, attn_start, real_lengths, paged)
+        if real is not None:
             beta, g = jnp.where(real, beta, 0.0), jnp.where(real, g, 0.0)
             qkv = jnp.where(real, qkv, 0)
         state0 = jnp.zeros((b, hv, dk, dv), f32)
         tail0 = jnp.zeros((b, self.conv_kernel - 1, conv_dim), self.dtype)
         if decode:
-            ssm_state, conv_state = _state_leaves(
-                self, state0, tail0, paged and s > 1)
+            ssm_state, conv_state = _state_leaves(self, state0, tail0)
             if not self.is_initializing():
                 state0, tail0 = ssm_state.value, conv_state.value
-        qkv, tail = ssm.causal_conv(qkv, tail0, conv_w, None)
+        qkv, tail = ssm.causal_conv(qkv, tail0, conv_w, None,
+                                    real_lengths)
         q, k, v = jnp.split(nn.silu(qkv).astype(f32), [keys, 2 * keys],
                             axis=-1)
         unit = lambda t: t * jax.lax.rsqrt(
@@ -308,6 +328,238 @@ class GatedDeltaMixer(nn.Module):
             o.reshape(b, s, values).astype(self.dtype))
 
 
+class LightningMixer(nn.Module):
+    """Lightning (linear) attention. [q|k|v|gate] = x W_in; q, k RMSNormed a
+    head, rotated whole (half-split pairs) at the token's position,
+    q / sqrt(head_dim); a head's (value, key) float32 state
+    S_t = lambda_h S_{t-1} + v_t k_t^T, o_t = S_t q_t, which is the Mamba-2
+    recurrence with dt = 1, x = v, B = k, C = q, no skip and a group a head
+    (ops/ssm.py); out = (RMSNorm_head(o) * sigmoid(gate)) W_out. The decay is
+    a constant: lambda_h = exp(-s_h), s_h = 2^(-8 (h + 1) / heads) *
+    (1 - layer / (layers - 1) + 1e-5), `layer` of `layers` the model's own
+    (published) numbering."""
+
+    num_heads: int
+    head_dim: int
+    layer: int = 0
+    layers: int = 2
+    rope_theta: float = 10000.0
+    chunk_size: int = 128
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, *, positions, decode: bool = False,
+                 attn_start=None, paged: bool = False, real_lengths=None):
+        b, s, d = x.shape
+        h, p = self.num_heads, self.head_dim
+        f32 = jnp.float32
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        norm = lambda name: RMSNorm(self.norm_eps, name=name, **kw)
+        q, k, v, gate = jnp.split(
+            nn.Dense(4 * h * p, use_bias=False, name="in_proj", **kw)(x), 4,
+            axis=-1)
+        heads = lambda t: t.reshape(b, s, h, p)
+        q = apply_rope(norm("q_norm")(heads(q)), positions,
+                       theta=self.rope_theta) * p ** -0.5
+        k = apply_rope(norm("k_norm")(heads(k)), positions,
+                       theta=self.rope_theta)
+        slope = 2.0 ** (-8.0 * (jnp.arange(h, dtype=f32) + 1.0) / h) \
+            * (1.0 - self.layer / max(self.layers - 1, 1) + 1e-5)
+        real = _real_rows(s, attn_start, real_lengths, paged)
+        dt = jnp.ones((b, s, h), f32) if real is None \
+            else jnp.broadcast_to(real.astype(f32), (b, s, h))
+        state0 = jnp.zeros((b, h, p, p), f32)
+        if decode:
+            ssm_state = self.variable("cache", "ssm_state", lambda: state0)
+            if not self.is_initializing():
+                state0 = ssm_state.value
+        no_skip = jnp.zeros((h,), f32)
+        if decode and s == 1 and not self.is_initializing():
+            o, state = ssm.ssm_step(heads(v)[:, 0], dt[:, 0], -slope,
+                                    k[:, 0], q[:, 0], no_skip, state0)
+            o = o[:, None]
+        else:
+            o, state = ssm.ssm_scan(heads(v), dt, -slope, k, q, no_skip,
+                                    state0, chunk=self.chunk_size)
+        if decode and not self.is_initializing():
+            ssm_state.value = state
+        o = RMSNorm(self.norm_eps, f32, self.param_dtype, name="norm")(o)
+        o = o.reshape(b, s, h * p) * nn.sigmoid(gate.astype(f32))
+        return nn.Dense(d, use_bias=False, name="out_proj", **kw)(
+            o.astype(self.dtype))
+
+
+class SparseAttention(nn.Module):
+    """Block-sparse grouped-query attention (ops/sparse_attention.py has the
+    equations): [query | gate] = x W_q a head, k, v = x W_kv, q and k
+    RMSNormed a head, no rotary; dense causal attention up to
+    `spec.dense_len` visible tokens, `spec.topk` picked blocks past it;
+    out = (o * sigmoid(gate)) W_o. A class beside `SelfAttention`, not a mode
+    of it: it shares the projections' shapes and nothing of the cache (a
+    third leaf, the compressed keys `cached_index`, `spec.rows` rows a
+    page; per-slot counts `sparse_stats`) or of the attention cores.
+
+    Decode mode: a flat cache (`inference.make_cache`, the engines' scratch
+    prefill) runs the plain jax.numpy form over the whole span; pages
+    (`page_table`) run `sparse_select` + `sparse_walk` for one token a slot
+    and `sparse_prefill` for a chunk, whose rows past `real_lengths` are
+    padding. Blocks are counted in slot-local POSITIONS, so they are the
+    sequence's own only where it starts at position 0 (a paged, right-padded
+    admission: `PagedEngine` with `prefill_chunk`); a left-padded row is
+    exact up to `dense_len` and picks among shifted blocks past it."""
+
+    num_heads: int
+    kv_heads: int
+    head_dim: int
+    spec: sparse_ops.SparseSpec
+    qk_norm: Callable
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    def _windows(self, span, rows):
+        """(b, L, lanes) keys from position 0 -> (b, rows, lanes): the
+        compressed rows 0..rows-1 (zeros where a window passes L)."""
+        sp = self.spec
+        at = sp.stride * jnp.arange(rows)[:, None] + jnp.arange(sp.kernel)
+        span = jnp.pad(span, ((0, 0), (0, max(
+            sp.stride * (rows - 1) + sp.kernel - span.shape[1], 0)), (0, 0)))
+        return sparse_ops.compress(span[:, at])
+
+    @nn.compact
+    def __call__(self, x, *, decode: bool = False, attn_start=None,
+                 page_table=None, kv_lengths=None, real_lengths=None):
+        b, s, d = x.shape
+        h, kvh, hd, sp = (self.num_heads, self.kv_heads, self.head_dim,
+                          self.spec.check())
+        dense = functools.partial(
+            nn.DenseGeneral, dtype=self.dtype, param_dtype=self.param_dtype,
+            use_bias=False)
+        qg = dense((h, 2 * hd), name="q")(x)
+        q, gate = qg[..., :hd], qg[..., hd:]
+        kv = dense((2, kvh, hd), name="kv")(x)
+        q = self.qk_norm(name="q_norm")(q)
+        k, v = self.qk_norm(name="k_norm")(kv[:, :, 0]), kv[:, :, 1]
+        start = jnp.zeros((b,), jnp.int32) if attn_start is None \
+            else attn_start.astype(jnp.int32)
+        flat = lambda t: t.reshape(b, s, kvh * hd)
+        if not decode or (page_table is None and self.is_initializing()):
+            if decode:   # the flat cache's leaves, shaped by this call
+                self._leaves(b, s, k.dtype)
+            blocks = -(-s // sp.block)
+            index = self._windows(flat(k), blocks * sp.rows)
+            out, _ = sparse_ops.sparse_attention_reference(
+                q, k, v, index.reshape(b, -1, kvh, hd),
+                jnp.broadcast_to(jnp.arange(s), (b, s)), start, sp)
+        elif page_table is None:
+            out = self._flat_decode(q, flat(k), flat(v), start)
+        else:
+            out = self._through_pages(q, flat(k), flat(v), start,
+                                      page_table, kv_lengths, real_lengths)
+        out = out * nn.sigmoid(gate)
+        return dense(d, axis=(-2, -1), name="out")(out)
+
+    def _leaves(self, b, s, dtype):
+        wide = self.kv_heads * self.head_dim
+        leaf = lambda name, rows: self.variable(
+            "cache", name, jnp.zeros, (b, rows, wide), dtype)
+        return (leaf("cached_key", s), leaf("cached_value", s),
+                leaf("cached_index", s // self.spec.stride),
+                self.variable("cache", "cache_index",
+                              lambda: jnp.zeros((), jnp.int32)),
+                self.variable("cache", "sparse_stats", jnp.zeros, (b, 2),
+                              jnp.int32))
+
+    def _flat_decode(self, q, k, v, start):
+        """The s tokens at the flat cache's cursor: written, the compressed
+        rows made again from the whole span, the plain form over it."""
+        b, s, h, hd = q.shape
+        sp, kvh = self.spec, self.kv_heads
+        ck, cv, ci, cursor, _ = self._leaves(b, s, k.dtype)
+        cur, span = cursor.value, ck.value.shape[1]
+        if span % sp.block:
+            raise ValueError(
+                f"a flat cache of {span} positions is not whole blocks of "
+                f"{sp.block}")
+        ck.value = lax.dynamic_update_slice(
+            ck.value, k.astype(ck.value.dtype), (0, cur, 0))
+        cv.value = lax.dynamic_update_slice(
+            cv.value, v.astype(cv.value.dtype), (0, cur, 0))
+        ci.value = self._windows(ck.value, ci.value.shape[1])
+        cursor.value = cur + s
+        out, _ = sparse_ops.sparse_attention_reference(
+            q, ck.value.reshape(b, span, kvh, hd),
+            cv.value.reshape(b, span, kvh, hd),
+            ci.value.reshape(b, -1, kvh, hd),
+            jnp.broadcast_to(cur + jnp.arange(s), (b, s)), start, sp)
+        return out
+
+    def _through_pages(self, q, k, v, start, page_table, kv_lengths,
+                       real_lengths):
+        """Pools of pages through `page_table`, the s tokens at slot-local
+        positions `kv_lengths + [0, s)`."""
+        b, s, h, hd = q.shape
+        sp, kvh = self.spec, self.kv_heads
+        ck, cv, ci, _, stats = self._leaves(b, s, k.dtype)
+        bs, mb = ck.value.shape[1], page_table.shape[1]
+        if bs != sp.block:
+            raise ValueError(
+                f"the engine's page ({bs}) is the selection's block "
+                f"({sp.block})")
+        pos0 = jnp.asarray(kv_lengths, jnp.int32)
+        positions = pos0[:, None] + jnp.arange(s, dtype=jnp.int32)
+
+        def page_of(col):
+            # the clamp keeps a retired slot (page row 0, length pinned)
+            # inside the table; a live slot never reaches it
+            return jnp.take_along_axis(
+                page_table, jnp.clip(col, 0, mb - 1), axis=1)
+
+        blk, off = page_of(positions // bs), positions % bs
+        keys = ck.value.at[blk, off].set(k.astype(ck.value.dtype))
+        values = cv.value.at[blk, off].set(v.astype(cv.value.dtype))
+        ck.value, cv.value = keys, values
+        # the compressed rows whose window ends among these tokens, their
+        # keys read back through the table (a window reaches behind a chunk)
+        real = jnp.full((b,), s, jnp.int32) if real_lengths is None \
+            else jnp.asarray(real_lengths, jnp.int32)
+        j, due = sparse_ops.due_rows(sp, pos0, pos0 + real,
+                                     -(-s // sp.stride) + 1)
+        at = sp.stride * j[..., None] + jnp.arange(sp.kernel)  # (b, r, ker)
+        flat_at = at.reshape(b, -1)
+        window = keys[page_of(flat_at // bs), flat_at % bs].reshape(
+            *at.shape, kvh * hd)
+        row_page = jnp.where(due, page_of(j // sp.rows), 0)
+        index = ci.value.at[row_page, jnp.where(due, j % sp.rows, 0)].set(
+            sparse_ops.compress(window))
+        ci.value = index
+        if s == 1:
+            with jax.named_scope("sparse_select"):
+                pages, tokens, held = sparse_ops.sparse_select(
+                    q[:, 0], index, page_table, pos0, start, spec=sp,
+                    kv_heads=kvh)
+            walked = jnp.sum(tokens // bs + 1, axis=1)
+            stats.value = stats.value + jnp.stack(
+                [walked, kvh * held], axis=1).astype(jnp.int32)
+            out = sparse_ops.sparse_walk(
+                q[:, 0], keys, values, pages, tokens,
+                jnp.broadcast_to((start % bs)[:, None], tokens.shape))
+            return out[:, None]
+        outs = []
+        for i in range(b):   # a chunk is one sequence's (the engine's b = 1)
+            rows = jnp.take(index, page_table[i], axis=0).reshape(
+                -1, kvh, hd)
+            qi = q[i].reshape(s, kvh, h // kvh, hd)
+            with jax.named_scope("sparse_select"):
+                picked = sparse_ops.prefill_selection(
+                    qi, rows, positions[i], start[i], sp)
+            outs.append(sparse_ops.sparse_prefill(
+                qi, keys, values, picked, page_table[i], pos0[i],
+                block=bs).reshape(s, h, hd))
+        return jnp.stack(outs)
+
+
 class HybridLM(nn.Module):
     pattern: str = "MEM*EME"
     vocab_size: int = 256
@@ -328,6 +580,12 @@ class HybridLM(nn.Module):
     gdn_value_heads: int = 4
     gdn_key_dim: int = 16
     gdn_value_dim: int = 16
+    # 'L' (its heads are `num_heads` of `head_dim`): the layer numbers its
+    # decays follow, one a letter 'L' in pattern order, of `decay_layers`
+    lightning_layers: tuple = ()
+    decay_layers: int = 2
+    # 'B' (heads as 'A'): the selection's sizes, ops/sparse_attention.py
+    sparse: sparse_ops.SparseSpec = sparse_ops.SparseSpec()
     # 'D'
     mlp_dim: int = 128
     # '*', 'A'
@@ -350,6 +608,11 @@ class HybridLM(nn.Module):
     # every RMSNorm of the residual stream and of 'A' scales by 1 + w
     norm_plus_one: bool = False
     tie_embeddings: bool = False
+    # muP scalars on the stream: the embedding's output, every residual
+    # branch and the head's input times these
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    head_scale: float = 1.0
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
     # what the serving engines ask a model: how positions enter (here the
@@ -361,23 +624,35 @@ class HybridLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False, decode: bool = False,
-                 attn_start=None, page_table=None, kv_lengths=None):
+                 attn_start=None, page_table=None, kv_lengths=None,
+                 real_lengths=None):
         """tokens (batch, seq) int32 -> logits (batch, seq, vocab_size) in
         the compute dtype. `decode`, `attn_start`, `page_table` and
-        `kv_lengths` as in models/lm.py TransformerLM."""
+        `kv_lengths` as in models/lm.py TransformerLM; `real_lengths`
+        (batch,): the tokens of a paged call that are real, the rest right
+        padding the recurrent layers do not advance over; a call of several
+        tokens then returns the logits of each sequence's LAST REAL position
+        alone, (batch, 1, vocab_size): what a prompt's chunk is asked for
+        (the head over a 2,048-token chunk's every row is 1.2 TFLOP at
+        73,448 rows)."""
         del train
-        if set(self.pattern) - set("MSGEQD*A") or not self.pattern:
+        if set(self.pattern) - set("MSGLEQD*AB") or not self.pattern:
             raise ValueError(
                 f"pattern {self.pattern!r}: want a string of 'M', 'S', 'G', "
-                "'E', 'Q', 'D', '*', 'A'")
+                "'L', 'E', 'Q', 'D', '*', 'A', 'B'")
         if "*" in self.pattern \
                 and self.hidden_dim != self.num_heads * self.head_dim:
             raise ValueError(
                 "'*' takes its head size from the width: "
                 f"hidden_dim {self.hidden_dim} != num_heads "
                 f"{self.num_heads} x head_dim {self.head_dim}")
-        if "A" in self.pattern and self.pos_emb != "rope":
-            raise ValueError("'A' rotates q and k: want pos_emb='rope'")
+        if set("AL") & set(self.pattern) and self.pos_emb != "rope":
+            raise ValueError(
+                "'A' and 'L' rotate q and k: want pos_emb='rope'")
+        if self.pattern.count("L") != len(self.lightning_layers):
+            raise ValueError(
+                f"lightning_layers {self.lightning_layers} numbers the 'L' "
+                f"layers of {self.pattern!r}, one each")
         if (page_table is not None or attn_start is not None) and not decode:
             raise ValueError("page_table / attn_start are decode features")
         if tokens.shape[1] > self.max_len:
@@ -387,30 +662,62 @@ class HybridLM(nn.Module):
         embed = nn.Embed(self.vocab_size, self.hidden_dim, name="tok_embed",
                          **kw)
         x = embed(tokens)
+        if self.embed_scale != 1.0:
+            x = x * self.embed_scale
         norm = functools.partial(RMSNorm, self.norm_eps,
                                  plus_one=self.norm_plus_one, **kw)
+        paged = page_table is not None
+        recur = dict(decode=decode, attn_start=attn_start, paged=paged,
+                     real_lengths=real_lengths)
+        if "L" in self.pattern:
+            # 'L' rotates at the token's position: slot-local under pages,
+            # else from a cursor of the model's own in the flat cache
+            s = tokens.shape[1]
+            if paged:
+                positions = jnp.asarray(kv_lengths, jnp.int32)[:, None] \
+                    + jnp.arange(s, dtype=jnp.int32)
+            else:
+                positions = jnp.arange(s, dtype=jnp.int32)
+                if decode:
+                    cursor = self.variable(
+                        "cache", "cache_index",
+                        lambda: jnp.zeros((), jnp.int32))
+                    if not self.is_initializing():
+                        positions = cursor.value + positions
+                        cursor.value = cursor.value + s
+        lightning = iter(self.lightning_layers)
         for i, kind in enumerate(self.pattern):
             y = norm(name=f"norm{i}")(x)
-            if kind == "M":
+            if kind == "L":
+                y = LightningMixer(
+                    self.num_heads, self.head_dim, next(lightning),
+                    self.decay_layers, self.rope_theta, self.chunk_size,
+                    self.norm_eps, name=f"mamba{i}", **kw,
+                )(y, positions=positions, **recur)
+            elif kind == "B":
+                y = SparseAttention(
+                    self.num_heads, self.kv_heads, self.head_dim,
+                    self.sparse, norm, name=f"attn{i}", **kw,
+                )(y, decode=decode, attn_start=attn_start,
+                  page_table=page_table, kv_lengths=kv_lengths,
+                  real_lengths=real_lengths)
+            elif kind == "M":
                 y = Mamba2Mixer(
                     self.mamba_heads, self.mamba_head_dim, self.ssm_state,
                     self.ssm_groups, self.conv_kernel, self.chunk_size,
                     self.norm_eps, name=f"mamba{i}", **kw,
-                )(y, decode=decode, attn_start=attn_start,
-                  paged=page_table is not None)
+                )(y, **recur)
             elif kind == "S":
                 y = Mamba1Mixer(
                     self.mamba_inner, self.ssm_state, self.dt_rank,
                     self.conv_kernel, self.norm_eps, name=f"mamba{i}", **kw,
-                )(y, decode=decode, attn_start=attn_start,
-                  paged=page_table is not None)
+                )(y, **recur)
             elif kind == "G":
                 y = GatedDeltaMixer(
                     self.gdn_key_heads, self.gdn_value_heads,
                     self.gdn_key_dim, self.gdn_value_dim, self.conv_kernel,
                     self.norm_eps, name=f"mamba{i}", **kw,
-                )(y, decode=decode, attn_start=attn_start,
-                  paged=page_table is not None)
+                )(y, **recur)
             elif kind == "D":
                 y = GatedMLP(self.mlp_dim, name=f"mlp{i}", **kw)(y)
             elif kind == "Q":
@@ -443,8 +750,15 @@ class HybridLM(nn.Module):
                     name=f"attn{i}", **kw,
                 )(y, decode=decode, attn_start=attn_start,
                   page_table=page_table, kv_lengths=kv_lengths)
+            if self.residual_scale != 1.0:
+                y = y * self.residual_scale
             x = x + y
+        if real_lengths is not None and paged and x.shape[1] > 1:
+            last = jnp.clip(real_lengths - 1, 0, x.shape[1] - 1)
+            x = jnp.take_along_axis(x, last[:, None, None], axis=1)
         x = norm(name="norm_f")(x)
+        if self.head_scale != 1.0:
+            x = x * self.head_scale
         if self.tie_embeddings:
             return embed.attend(x)
         return nn.Dense(self.vocab_size, use_bias=False, name="lm_head",

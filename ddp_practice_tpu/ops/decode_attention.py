@@ -397,6 +397,7 @@ def _paged_walk_kernel(
     q_ref, k_hbm, v_hbm, o_ref,          # q/out blocks; the pools, in HBM
     k_buf, v_buf, sem, first_buf,        # scratch
     *, sm_scale, block_size, pages, d, rows, kv_heads=None, v_lanes=None,
+    lane_heads=None,
 ):
     """Grid (slots,): one cell walks ONE slot's live pages, columns
     `attn_start // block_size` to `len // block_size` of its page-table
@@ -431,6 +432,11 @@ def _paged_walk_kernel(
     by one copy and the chunk's value tile is a lane slice of its key
     tile.
 
+    A LIST walk (`lane_heads` set, kv_heads 1: ops/sparse_attention.py
+    `sparse_walk`): the grid is (slots * lane_heads,), row c of `pt_ref` is
+    the pages ONE KV head of a slot attends, and a page comes as that head's
+    own d lanes of the pool's row (head c % lane_heads).
+
     Pages of a chunk past the slot's last are not fetched; their rows
     keep what an earlier chunk left (zeros at first), which is finite,
     so the mask's -1e30 turns them into exact zeros."""
@@ -458,8 +464,10 @@ def _paged_walk_kernel(
                 page = pt_ref[slot, col]
                 rows_i = pl.ds(i * bs, bs)
                 for hbm, dst, which in pools:
+                    src = hbm.at[page] if lane_heads is None else hbm.at[
+                        page, :, pl.ds((slot % lane_heads) * d, d)]
                     dma = pltpu.make_async_copy(
-                        hbm.at[page], dst.at[buf, rows_i], sem.at[which, buf]
+                        src, dst.at[buf, rows_i], sem.at[which, buf]
                     )
                     if go:
                         dma.start()

@@ -41,11 +41,13 @@ from ddp_practice_tpu.ops.flash_attention import _dot_ta, _dot_tb
 from ddp_practice_tpu.utils import backend
 
 
-def causal_conv(xbc, tail, weight, bias):
+def causal_conv(xbc, tail, weight, bias, real_lengths=None):
     """Depthwise causal conv over time. xbc (b, l, c); `tail` (b, k-1, c)
     the k-1 inputs before position 0 (zeros for a fresh sequence); weight
     (k, c), bias (c,) or None. Returns (out (b, l, c), new tail
-    (b, k-1, c))."""
+    (b, k-1, c)): the last k-1 inputs, or with `real_lengths` (b,) those
+    before position real_lengths[b] (the rest of the row is right
+    padding)."""
     k = weight.shape[0]
     seq = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
     l = xbc.shape[1]
@@ -53,7 +55,10 @@ def causal_conv(xbc, tail, weight, bias):
               for i in range(k))
     if bias is not None:
         out = out + bias.astype(xbc.dtype)
-    return out, seq[:, l:]
+    if real_lengths is None:
+        return out, seq[:, l:]
+    return out, jax.vmap(lambda row, at: lax.dynamic_slice_in_dim(
+        row, at, k - 1, axis=0))(seq, real_lengths.astype(jnp.int32))
 
 
 def _heads_of_groups(v, heads: int):
@@ -148,23 +153,31 @@ def ssm_step_reference(x, dt, a, b_mat, c_mat, d_skip, state):
     return y + x * d_skip.astype(f32)[None, :, None], state
 
 
+# heads a grid cell of `ssm_step` holds at most: a cell is one group's heads
+# where a group has that many (16 tiles of 64 x 128 floats in, as many out),
+# and several groups' where a group is a head or two (lightning attention: a
+# group a head, and a cell of ONE 64 KB tile is all fixed cost)
+_STEP_HEADS = 16
+
+
 def _step_kernel(da_ref, xd_ref, b_ref, c_ref, h_ref, y_ref, ho_ref, *,
-                 heads):
-    """One grid cell: one sequence, one group's `heads` heads. The state
-    tile of a head is (p, n): its update is `decay * h + xd (x) B` with the
-    outer product as a depth-8 matmul (row 0 real, seven rows of zeros:
+                 groups, heads):
+    """One grid cell: one sequence, `groups` groups of `heads` heads. The
+    state tile of a head is (p, n): its update is `decay * h + xd (x) B` with
+    the outer product as a depth-8 matmul (row 0 real, seven rows of zeros:
     a column vector is not a layout the lanes hold), its output `C . h`
     one matmul against the new tile."""
     n = h_ref.shape[-1]
     row0 = lax.broadcasted_iota(jnp.int32, (8, 1), 0) == 0
-    b8 = jnp.where(row0, jnp.broadcast_to(b_ref[...], (8, n)), 0.0)
-    c8 = jnp.broadcast_to(c_ref[...], (8, n))
-    for i in range(heads):
-        x8 = jnp.where(row0, jnp.broadcast_to(
-            xd_ref[i:i + 1, :], (8, xd_ref.shape[-1])), 0.0)
-        new = h_ref[i] * da_ref[i:i + 1, :] + _dot_ta(x8, b8)   # (p, n)
-        ho_ref[i] = new
-        y_ref[i:i + 1, :] = _dot_tb(c8, new)[:1]
+    for j in range(groups):
+        b8 = jnp.where(row0, jnp.broadcast_to(b_ref[j], (8, n)), 0.0)
+        c8 = jnp.broadcast_to(c_ref[j], (8, n))
+        for i in range(heads):
+            x8 = jnp.where(row0, jnp.broadcast_to(
+                xd_ref[j, i:i + 1, :], (8, xd_ref.shape[-1])), 0.0)
+            new = h_ref[j, i] * da_ref[j, i:i + 1, :] + _dot_ta(x8, b8)
+            ho_ref[j, i] = new                                  # (p, n)
+            y_ref[j, i:i + 1, :] = _dot_tb(c8, new)[:1]
 
 
 def ssm_step(x, dt, a, b_mat, c_mat, d_skip, state):
@@ -192,13 +205,15 @@ def ssm_step_kernel(x, dt, a, b_mat, c_mat, d_skip, state):
         jnp.exp(dt * a.astype(f32))[..., None], (bsz, h, n)
     ).reshape(bsz, g, hg, n)
     xd = (x * dt[..., None]).reshape(bsz, g, hg, p)
-    grp = lambda last: pl.BlockSpec((None, None, hg, last),
+    gb = max(k for k in range(1, g + 1)
+             if g % k == 0 and k * hg <= max(_STEP_HEADS, hg))
+    grp = lambda last: pl.BlockSpec((None, gb, hg, last),
                                     lambda i, j: (i, j, 0, 0))
-    vec = pl.BlockSpec((None, None, 1, n), lambda i, j: (i, j, 0, 0))
-    st = pl.BlockSpec((None, None, hg, p, n), lambda i, j: (i, j, 0, 0, 0))
+    vec = pl.BlockSpec((None, gb, 1, n), lambda i, j: (i, j, 0, 0))
+    st = pl.BlockSpec((None, gb, hg, p, n), lambda i, j: (i, j, 0, 0, 0))
     y, new = pl.pallas_call(
-        functools.partial(_step_kernel, heads=hg),
-        grid=(bsz, g),
+        functools.partial(_step_kernel, groups=gb, heads=hg),
+        grid=(bsz, g // gb),
         in_specs=[grp(n), grp(p), vec, vec, st],
         out_specs=[grp(p), st],
         out_shape=[jax.ShapeDtypeStruct((bsz, g, hg, p), f32),

@@ -58,6 +58,7 @@ from ddp_practice_tpu.inference import (
 )
 from ddp_practice_tpu.serve.kv_pages import (
     GARBAGE_BLOCK,
+    INDEX_LEAF,
     LATENT_LEAF,
     BlockAllocator,
     RadixPrefixCache,
@@ -65,6 +66,7 @@ from ddp_practice_tpu.serve.kv_pages import (
     leaf_kind,
     leaf_name,
     make_paged_cache,
+    per_slot,
     rewind_block_tail,
     scatter_prompt_blocks,
 )
@@ -158,7 +160,9 @@ class EngineConfig:
     # spec_decode: exact acceptance is greedy string matching, which
     # per-request temperatures would break.
     per_slot_sampling: bool = False
-    # ---- chunked prefill (PagedEngine + prefix_cache only) ----
+    # ---- chunked prefill (PagedEngine; with prefix_cache, or without it
+    # for a model with recurrent state: chunks over the slot's own state,
+    # every admission then a chunk admission at canonical positions) ----
     # split long COLD prompts into chunks of at most this many tokens,
     # prefilled one chunk per scheduler tick interleaved with decode
     # bursts (Sarathi-style): a long admit no longer stalls every
@@ -166,10 +170,17 @@ class EngineConfig:
     # by one chunk's forward instead of the longest prompt's. 0 = off
     # (whole-prompt admission, the pre-16 behavior). Chunks ride the
     # `_prefix_prefill` program at canonical right-padded slot-local
-    # positions — which is why prefix_cache is required — and a prompt
+    # positions — the prefix cache's layout — and a prompt
     # may now EXCEED the largest bucket: servability is bounded by the
     # per-slot block capacity, not the bucket table.
     prefill_chunk: int = 0
+    # chunk forwards a scheduler tick runs beside its decode burst, handed
+    # out oldest admission first (a slot may take several). 0 = one for
+    # EVERY mid-prefill slot, whatever their number: under a flood of long
+    # prompts every slot is mid-prefill at once and a tick is as many chunk
+    # forwards, seconds between two tokens of a running stream; a cap
+    # bounds that gap and finishes prompts in order
+    prefill_chunks_per_tick: int = 0
 
 
 def _sample_step(cfg: EngineConfig, last_logits, active, keys,
@@ -292,6 +303,8 @@ def warm_engine(engine, widths=None) -> None:
         engine.moe_rows_moved = engine.moe_rows_layout = 0
     if getattr(engine, "ssm_scan_tokens", 0):
         engine.ssm_scan_tokens = engine.ssm_scan_padded_tokens = 0
+    if getattr(engine, "sparse_pages_held", 0):
+        engine.sparse_pages_walked = engine.sparse_pages_held = 0
     engine.reset_epoch()
 
 
@@ -335,6 +348,7 @@ class _EngineBase:
     # over expert layers and steps, most rows one expert took) of the last
     # decode burst; None for a model without held experts (PagedEngine)
     last_burst_experts = None
+    last_burst_sparse = None
 
     def set_tracer(self, tracer, replica: int = 0) -> None:
         """Attach a utils/trace.py TraceRecorder; `replica` is this
@@ -836,12 +850,13 @@ class PagedEngine(_EngineBase):
         if self._recurrent:
             # pages cannot re-derive a state: each of these needs a
             # sequence's state at a position that is not its end (ROADMAP
-            # M6: snapshots at block boundaries)
+            # M6: snapshots at block boundaries). Chunks of ONE prompt run
+            # in order need only the state at the sequence's end, which is
+            # what the slot holds: `prefill_chunk` is admitted, through the
+            # slot's own table, with nothing published to a radix tree
             for option, why in (
                 ("prefix_cache", "a shared prefix's pages come without "
                  "the state at the prefix's end"),
-                ("prefill_chunk", "chunks append through the page table "
-                 "(needs prefix_cache)"),
                 ("spec_decode", "a rejected draft cannot be rolled back "
                  "out of the state"),
             ):
@@ -871,7 +886,7 @@ class PagedEngine(_EngineBase):
                     "slot sampling at its own temperature would break"
                 )
         if config.prefill_chunk:
-            if not config.prefix_cache:
+            if not config.prefix_cache and not self._recurrent:
                 raise ValueError(
                     "prefill_chunk needs prefix_cache=True — chunks "
                     "append at canonical right-padded positions through "
@@ -915,6 +930,14 @@ class PagedEngine(_EngineBase):
             RadixPrefixCache(self.blocks, bs) if config.prefix_cache
             else None
         )
+        # canonical slot-local positions: a prompt starts at position 0 and
+        # is RIGHT-padded (the prefix cache's layout, and every admission of
+        # a recurrent model under `prefill_chunk`: its chunks continue the
+        # slot's own state, and a layer that counts blocks from position 0
+        # needs the sequence to start there); else a bucket's LEFT padding
+        # counts as positions
+        self._canonical = config.prefix_cache or (
+            self._recurrent and bool(config.prefill_chunk))
         # matched tokens of the MOST RECENT admit (None = no prefix
         # cache): the scheduler reads this right after admit() to book
         # prefix_hit_tokens into the request's flight record
@@ -930,6 +953,16 @@ class PagedEngine(_EngineBase):
         # with latent attention caches one row a token, not K and V
         self.latent_cache_bytes = int(sum(
             a.nbytes for path, a in flat if leaf_name(path) == LATENT_LEAF))
+        # bytes of the compressed-key pools (gauge `index_cache_bytes`): a
+        # block-sparse attention layer scores them every decode step
+        self.index_cache_bytes = int(sum(
+            a.nbytes for path, a in flat if leaf_name(path) == INDEX_LEAF))
+        self._sparse_layers = sum(
+            1 for path, _ in flat if leaf_kind(path) == "slots")
+        self._dense_len = int(getattr(
+            getattr(model, "sparse", None), "dense_len", 0))
+        self.sparse_pages_walked = 0   # cumulative (metrics export)
+        self.sparse_pages_held = 0
         self._moe_layers = sum(
             1 for path, _ in flat if leaf_kind(path) == "stats")
         self._picks_a_step = (
@@ -1051,16 +1084,37 @@ class PagedEngine(_EngineBase):
         s>1 path). One compile per suffix bucket width w. The pad rows
         write garbage K/V at positions past the context, which the
         causal mask hides until decode overwrites them; the next-token
-        logits are the last REAL row's (dynamic true_len - 1)."""
+        logits are the last REAL row's (dynamic true_len - 1).
+
+        A recurrent model's chunk continues the slot's OWN state: the
+        per-slot leaves are cut to row `slot` for the batch-1 call (zeros
+        for a prompt's first chunk, pos0 == 0) and written back after, and
+        the model is told where the padding starts (`real_lengths`), which
+        its scans must not advance over."""
+        extra, whole = {}, pool
+        if self._recurrent:
+            row = lambda a: lax.dynamic_slice(
+                a, (slot,) + (0,) * (a.ndim - 1), (1,) + a.shape[1:])
+            pool = jax.tree_util.tree_map_with_path(
+                lambda path, a: jnp.where(pos0 == 0, 0, row(a)).astype(
+                    a.dtype) if per_slot(path) else a, whole)
+            extra = {"real_lengths": true_len[None]}
         pool, logits = decode_apply(
             self.model, params, pool, tokens,
             batch_stats=self.batch_stats,
-            page_table=pt_row, kv_lengths=pos0[None],
+            page_table=pt_row, kv_lengths=pos0[None], **extra,
         )
+        if self._recurrent:
+            pool = jax.tree_util.tree_map_with_path(
+                lambda path, a, w: lax.dynamic_update_slice(
+                    w, a, (slot,) + (0,) * (a.ndim - 1))
+                if per_slot(path) else a, pool, whole)
         with jax.named_scope("sample"):
-            last = lax.dynamic_slice(
-                logits, (0, true_len - 1, 0), (1, 1, logits.shape[2])
-            )[:, 0]
+            # (a model told `real_lengths` hands back that one row alone)
+            last = logits[:, 0] if logits.shape[1] == 1 \
+                else lax.dynamic_slice(
+                    logits, (0, true_len - 1, 0), (1, 1, logits.shape[2])
+                )[:, 0]
             last_logits = lax.dynamic_update_slice(
                 last_logits, last.astype(last_logits.dtype), (slot, 0)
             )
@@ -1094,10 +1148,16 @@ class PagedEngine(_EngineBase):
             pool, logits = decode_apply(
                 self.model, params, pool, toks[:, None],
                 attn_start=attn_starts, batch_stats=self.batch_stats,
-                page_table=page_table, kv_lengths=lengths,
+                page_table=page_table, kv_lengths=lengths, **idle,
             )
             lengths = lengths + active.astype(lengths.dtype)
             return (pool, logits[:, -1], keys, lengths), (toks, finite)
+
+        # a recurrent model's slot between two chunks of its prompt is not
+        # active and must keep its state through these steps: the model is
+        # told which rows are padding
+        idle = {"real_lengths": active.astype(jnp.int32)} \
+            if self._recurrent and self.config.prefill_chunk else {}
 
         def rows_moved(pool):
             """(rows moved, rows of the whole layouts) the expert layers
@@ -1110,9 +1170,10 @@ class PagedEngine(_EngineBase):
         admitted = rows_moved(pool)
         # expert layers count into their `moe_stats` leaf: zeroed here so
         # that after the scan it holds this burst's own sums
+        # ... and sparse attention layers into `sparse_stats`, a row a slot
         pool = jax.tree_util.tree_map_with_path(
             lambda path, a: jnp.zeros_like(a)
-            if leaf_kind(path) == "stats" else a, pool)
+            if leaf_kind(path) in ("stats", "slots") else a, pool)
         (pool, last_logits, keys, _), (toks, finite) = lax.scan(
             body, (pool, last_logits, keys, lengths), None,
             length=self.config.decode_burst,
@@ -1131,7 +1192,16 @@ class PagedEngine(_EngineBase):
             pool = jax.tree_util.tree_map_with_path(
                 lambda path, a: jnp.zeros_like(a)
                 if leaf_kind(path) == "rows" else a, pool)
-        return pool, last_logits, toks, keys, finite, stats
+        sparse = [a for path, a
+                  in jax.tree_util.tree_flatten_with_path(pool)[0]
+                  if leaf_kind(path) == "slots"]
+        if sparse:   # (pages walked, pages a dense walk reads, sparse slots)
+            # of the ACTIVE slots, over the layers and the burst's steps
+            by_slot = jnp.where(active[:, None], sum(sparse), 0)
+            sparse = jnp.concatenate([by_slot.sum(axis=0), jnp.sum(
+                active & (lengths + 1 - attn_starts > self._dense_len)
+            )[None]])
+        return pool, last_logits, toks, keys, finite, (stats, sparse)
 
     def _verify(self, params, pool, last_logits, attn_starts, active,
                 drafts, draft_lens, page_table, lengths):
@@ -1278,7 +1348,7 @@ class PagedEngine(_EngineBase):
         if plan is None:
             return "never"
         matched, w, need_now = plan
-        if self.radix is None:
+        if not self._canonical:
             end = w + needed_positions
         else:
             end = max(matched + w, prompt_len + needed_positions)
@@ -1456,7 +1526,10 @@ class PagedEngine(_EngineBase):
             shared, matched = self.radix.match(prompt)
             self.last_prefix_hit = matched
         chunk = self.config.prefill_chunk
-        chunked = bool(chunk) and (p - matched) > chunk
+        # a recurrent model's every admission is a chunk admission: one
+        # program (`_prefix_prefill`) at canonical positions, however short
+        chunked = bool(chunk) and (
+            (p - matched) > chunk or self._recurrent)
         try:
             w = self.bucket_for(min(p - matched, chunk) if chunked
                                 else p - matched)
@@ -1468,9 +1541,9 @@ class PagedEngine(_EngineBase):
         # true p — but its prefill pad rows touch up to matched + w
         if max_positions is None:
             max_positions = self.max_context - (
-                w if self.radix is None else max(matched + w, p)
+                w if not self._canonical else max(matched + w, p)
             )
-        if self.radix is None:
+        if not self._canonical:
             end = w + max_positions
         else:
             end = max(matched + w, p + max_positions)
@@ -1676,9 +1749,16 @@ class PagedEngine(_EngineBase):
                 self._slot_trace.get(slot, f"slot{slot}"),
                 bucket=w, pos0=done, take=take,
                 chunk=done // self.config.prefill_chunk,
-                prefix_hit=st["hit"])
+                prefix_hit=st["hit"],
+                # positions a recurrent model's scans advance the state
+                # over, and the chunk's padding they run over besides
+                **({"scan_tokens": take, "scan_padded": w - take}
+                   if self._recurrent else {}))
         else:
             span = host = disp = _NULL
+        if self._recurrent:
+            self.ssm_scan_tokens += take
+            self.ssm_scan_padded_tokens += w - take
         with span:
             with host:
                 padded = np.full((1, w), self.config.pad_id, np.int32)
@@ -1701,9 +1781,10 @@ class PagedEngine(_EngineBase):
             # the same context admitted meanwhile does not prefill it
             # again (it adopts the blocks: `_adopt_published`)
             n_full = done // self.config.block_size
-            self.radix.insert(
-                prompt[:n_full * self.config.block_size],
-                [int(b) for b in self._pt[slot, :n_full]])
+            if self.radix is not None:
+                self.radix.insert(
+                    prompt[:n_full * self.config.block_size],
+                    [int(b) for b in self._pt[slot, :n_full]])
             return False
         # final chunk: the slot now looks exactly like a whole-prompt
         # prefix admission — publish, seed the drafter, go active
@@ -1713,7 +1794,7 @@ class PagedEngine(_EngineBase):
             self.blocks, self._pt[slot], int(self._nblk[slot]), floor
         )
         n_full = p // self.config.block_size
-        if n_full:
+        if n_full and self.radix is not None:
             self.radix.insert(
                 prompt, [int(b) for b in self._pt[slot, :n_full]]
             )
@@ -1731,7 +1812,7 @@ class PagedEngine(_EngineBase):
         never replaced (`done` on a block boundary)."""
         bs = self.config.block_size
         have = int(self._nblk[slot])
-        if done % bs or have != done // bs:
+        if self.radix is None or done % bs or have != done // bs:
             return done
         chain = self.radix.ref_prefix(prompt)   # pins the matched chain
         self.blocks.free(chain[:have])          # the slot holds its own
@@ -1925,8 +2006,20 @@ class PagedEngine(_EngineBase):
                                 self._keys)
             self._len[self._active] += k
             with read:  # the host waits for the device here
-                toks, finite, stats = jax.device_get(
+                toks, finite, (stats, sparse) = jax.device_get(
                     (toks, finite, stats))
+            if self._sparse_layers:
+                # what the burst's sparse attention layers read, from the
+                # program: pages their walks read, pages dense walks would
+                # have, slots past `dense_len` when the burst began
+                walked, held, slots = (int(v) for v in sparse)
+                self.last_burst_sparse = (walked, held, slots)
+                self.sparse_pages_walked += walked
+                self.sparse_pages_held += held
+                if traced and getattr(span, "attrs", None) is not None:
+                    span.attrs.update(sparse_pages_walked=walked,
+                                      sparse_pages_held=held,
+                                      sparse_slots=slots)
             if self._moe_layers:
                 # what the burst's expert layers saw, from the program:
                 # picks that landed on held experts, held experts with a

@@ -438,6 +438,12 @@ class RadixPrefixCache:
 # rows its layout moved and those the whole layout holds) are one small
 # vector each a layer.
 STATE_LEAVES = ("ssm_state", "conv_state")
+# a block-sparse attention layer's per-slot counts (models/hybrid_lm.py
+# SparseAttention: pages its walk read, pages a dense walk would have), one
+# row a slot like a state; its compressed keys (`INDEX_LEAF`) are pages like
+# any K leaf, `block / stride` rows a block where a K leaf has `block`
+SLOT_STATS_LEAF = "sparse_stats"
+INDEX_LEAF = "cached_index"
 STATS_LEAF = "moe_stats"
 ROWS_LEAF = "moe_rows"
 # a latent-attention layer's one page leaf (models/mla_lm.py): pages like
@@ -450,13 +456,18 @@ def leaf_name(path) -> str:
 
 
 def leaf_kind(path) -> str:
-    """"state" | "stats" | "rows" | "pages": which pool a cache leaf
-    belongs to (scalars, the flat layout's cursors, are told by their
-    rank)."""
+    """"state" | "slots" | "stats" | "rows" | "pages": which pool a cache
+    leaf belongs to (scalars, the flat layout's cursors, are told by their
+    rank). "state" and "slots" hold one row a SLOT."""
     name = leaf_name(path)
     if name in STATE_LEAVES:
         return "state"
-    return {STATS_LEAF: "stats", ROWS_LEAF: "rows"}.get(name, "pages")
+    return {STATS_LEAF: "stats", ROWS_LEAF: "rows",
+            SLOT_STATS_LEAF: "slots"}.get(name, "pages")
+
+
+def per_slot(path) -> bool:
+    return leaf_kind(path) in ("state", "slots")
 
 
 def make_paged_cache(model, num_blocks: int, block_size: int,
@@ -474,15 +485,17 @@ def make_paged_cache(model, num_blocks: int, block_size: int,
     leaves (`STATE_LEAVES`) are a per-SLOT state pool, `(max_slots, ...)`:
     admission overwrites a slot's row from the prefill's final state,
     decode updates every row in place, and release leaves the row for
-    the next owner to overwrite. Scalar leaves (the flat layout's write
-    cursors) and an expert layer's counters stay as they are.
+    the next owner to overwrite; a sparse attention layer's per-slot counts
+    pool the same way and its compressed keys as pages of their own row
+    count. Scalar leaves (the flat layout's write cursors) and an expert
+    layer's counters stay as they are.
     """
     shapes = jax.eval_shape(lambda: make_cache(model, 1, block_size))
 
     def per_leaf(path, a):
         if a.ndim == 0 or leaf_kind(path) in ("stats", "rows"):
             return jnp.zeros(a.shape, a.dtype)
-        lead = max_slots if leaf_kind(path) == "state" else num_blocks
+        lead = max_slots if per_slot(path) else num_blocks
         return jnp.zeros((lead,) + a.shape[1:], a.dtype)
 
     return jax.tree_util.tree_map_with_path(per_leaf, shapes)
@@ -517,21 +530,23 @@ def scatter_prompt_blocks(pool: Any, scratch: Any, block_ids,
     leaves are not pages: the scratch's batch-1 final state overwrites
     row `slot` of the state pool.
     """
-    n_chunks = -(-width // block_size)
-
     def per_leaf(path, p, s):
         kind = leaf_kind(path)
-        if p.ndim == 0 or kind == "stats":
+        if p.ndim == 0 or kind in ("stats", "slots"):
             return p
         if kind == "rows":
             return p + s
         if kind == "state":
             return lax.dynamic_update_slice(
                 p, s.astype(p.dtype), (slot,) + (0,) * (p.ndim - 1))
+        # rows a block of THIS leaf holds (a compressed-key leaf has fewer
+        # a block than K and V) and those `width` positions fill of it
         pos_axis = 2 if _is_scale_leaf(path) else 1
-        for i in range(n_chunks):
-            lo = i * block_size
-            rows = min(block_size, width - lo)
+        per = p.shape[pos_axis]
+        filled = -(-width * per // block_size)
+        for i in range(-(-filled // per)):
+            lo = i * per
+            rows = min(per, filled - lo)
             if pos_axis == 1:
                 chunk = lax.dynamic_slice(
                     s, (0, lo, 0), (1, rows, s.shape[2])

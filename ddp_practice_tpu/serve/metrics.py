@@ -82,6 +82,14 @@ class ServeMetrics:
         self.ssm_scan_tokens = r.counter("ssm_scan_tokens_total")
         self.ssm_scan_padded = r.counter("ssm_scan_padded_tokens_total")
         self._last_scan = self._last_scan_padded = 0
+        # block-sparse attention (models/hybrid_lm.py SparseAttention): pages
+        # the decode steps' walks read beside those dense walks would have,
+        # as deltas of the engine's cumulative fields; bytes of the pools of
+        # compressed keys the selection scores
+        self.sparse_pages_walked = r.counter("sparse_pages_walked_total")
+        self.sparse_pages_held = r.counter("sparse_pages_held_total")
+        self.index_cache_bytes = r.gauge("index_cache_bytes")
+        self._last_walked = self._last_pages_held = 0
         self.tokens_total = r.counter("serve_tokens_total")
         self.submitted = r.counter("serve_requests_submitted")
 
@@ -138,6 +146,12 @@ class ServeMetrics:
         self._last_scan, self._last_scan_padded = scan, padded
         self.ssm_state_bytes.set(getattr(eng, "ssm_state_bytes", 0))
         self.latent_cache_bytes.set(getattr(eng, "latent_cache_bytes", 0))
+        walked = getattr(eng, "sparse_pages_walked", 0)
+        pages_held = getattr(eng, "sparse_pages_held", 0)
+        self.sparse_pages_walked.inc(walked - self._last_walked)
+        self.sparse_pages_held.inc(pages_held - self._last_pages_held)
+        self._last_walked, self._last_pages_held = walked, pages_held
+        self.index_cache_bytes.set(getattr(eng, "index_cache_bytes", 0))
         drafted = getattr(eng, "spec_drafted_tokens", 0)
         accepted = getattr(eng, "spec_accepted_tokens", 0)
         self.spec_drafted.inc(drafted - self._last_drafted)

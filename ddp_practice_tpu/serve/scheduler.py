@@ -802,7 +802,8 @@ class Scheduler:
             )
 
     def _prefill_pump(self) -> None:
-        """Drive ONE prefill chunk per mid-prefill slot per tick —
+        """Drive ONE prefill chunk per mid-prefill slot per tick (or the
+        engine's `prefill_chunks_per_tick`, oldest first) —
         Sarathi-style interleaving: a long cold prompt shares every
         tick with the running decode burst instead of monopolizing one,
         so running streams see at most one chunk's forward of added
@@ -812,9 +813,16 @@ class Scheduler:
         preemption releases the slot and requeues the request at the
         front, like any admission failure."""
         eng = self.engine
+        # `EngineConfig.prefill_chunks_per_tick`: that many chunk forwards
+        # a tick at most, the oldest admission first, a slot as many as are
+        # left; 0 = one for every mid-prefill slot
+        cap = getattr(eng.config, "prefill_chunks_per_tick", 0)
+        left = cap or len(self.running)
         for slot, st in list(self.running.items()):
             if not st.prefilling:
                 continue
+            if left <= 0:
+                break
             now = self.clock.now()
             if st.req.deadline is not None and now > st.req.deadline:
                 del self.running[slot]
@@ -822,20 +830,23 @@ class Scheduler:
                 self._finish(st.req, [], "timeout",
                              admitted=(st.admit_t0, now))
                 continue
-            try:
-                done = eng.prefill_step(slot)
-            except RuntimeError:
-                del self.running[slot]
-                eng.release(slot)
-                self.queue.appendleft(self._continuation(st))
-                continue
-            self.clock.tick()
-            if done:
-                # the slot just went active: prefill ends HERE for the
-                # flight record, and the next burst decodes it with
-                # everyone else
-                st.prefilling = False
-                st.admit_t1 = self.clock.now()
+            for _ in range(left if cap else 1):
+                try:
+                    done = eng.prefill_step(slot)
+                except RuntimeError:
+                    del self.running[slot]
+                    eng.release(slot)
+                    self.queue.appendleft(self._continuation(st))
+                    break
+                left -= 1
+                self.clock.tick()
+                if done:
+                    # the slot just went active: prefill ends HERE for the
+                    # flight record, and the next burst decodes it with
+                    # everyone else
+                    st.prefilling = False
+                    st.admit_t1 = self.clock.now()
+                    break
         # chunk growth may have preempted active runners
         # (_acquire_decode inside prefill_step) — requeue them before
         # the burst maps token rows

@@ -234,15 +234,27 @@ def test_fork_and_the_slot_engine_refuse_recurrent_state(toy, engine):
         SlotEngine(model, params, EngineConfig(prompt_buckets=(8,)))
 
 
-def test_pages_refuse_several_tokens_for_a_recurrent_layer(toy, engine):
-    from ddp_practice_tpu.inference import decode_apply
-
+def test_chunks_over_the_state_give_the_logits_of_one_whole_prefill(toy):
+    """`prefill_chunk` without `prefix_cache`: a prompt's chunks run in order
+    through the slot's own table, each from the state the one before left
+    (the last one right-padded, which the scans do not advance over), and
+    the slot then decodes as after one whole prefill."""
     model, params = toy
-    with pytest.raises(ValueError, match="recurrent layer"):
-        decode_apply(model, params, engine._cache,
-                     jnp.zeros((3, 4), jnp.int32),
-                     page_table=jnp.zeros((3, 12), jnp.int32),
-                     kv_lengths=jnp.zeros((3,), jnp.int32))
+    rng = np.random.default_rng(11)
+    seq = rng.integers(1, 96, 29).tolist()
+    chunked = make_engine(model, params, prefill_chunk=8)
+    assert chunked.radix is None
+    slot = chunked.admit(seq, max_positions=8)
+    chunks = 0
+    while chunked.is_prefilling(slot):
+        chunked.prefill_step(slot)
+        chunks += 1
+    assert chunks == 4 and chunked.context_len(slot) == 29
+    got, toks = decode(chunked, slot, 4)
+    whole = make_engine(model, params)
+    want, same = decode(whole, whole.admit(seq, max_positions=8), 4)
+    assert toks == same
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
 
 
 def test_state_pool_is_a_slot_and_pages_are_the_kv_heads_wide(engine):
@@ -464,3 +476,37 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
             total += out - shared
     assert np.abs(want - shared).max() > 0.1     # the experts do something
     np.testing.assert_allclose(total + shared, want, atol=TOL, rtol=TOL)
+
+
+@pytest.mark.parametrize("name", ["nemotron_h", "jamba", "qwen3_next"])
+def test_the_stream_scalars_default_to_the_layouts_as_they_were(name):
+    """`embed_scale`, `residual_scale` and `head_scale` (MiniCPM-SALA's muP
+    scalars) default to 1 and then add no op: each of the three older
+    layouts traces to the SAME program, forward and paged decode step,
+    whether they are left out or spelled out, so its logits are what they
+    were to the bit; a scalar that is not 1 is another program."""
+    from ddp_practice_tpu.models import create_model
+    from ddp_practice_tpu.serve.kv_pages import make_paged_cache
+
+    tokens = jnp.zeros((2, 16), jnp.int32)
+
+    def programs(**kw):
+        model = create_model(name, **kw)
+        params = jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), tokens[:, :8]))["params"]
+        pool = jax.eval_shape(lambda: make_paged_cache(model, 9, 8, 2))
+        step = lambda p, c: decode_apply(
+            model, p, c, tokens[:, :1],
+            page_table=jnp.zeros((2, 4), jnp.int32),
+            kv_lengths=jnp.zeros((2,), jnp.int32))
+        return (str(jax.make_jaxpr(
+            lambda p: model.apply({"params": p}, tokens))(params)),
+            str(jax.make_jaxpr(step)(params, pool)))
+
+    from ddp_practice_tpu.inference import decode_apply
+
+    plain = programs()
+    spelled = programs(embed_scale=1.0, residual_scale=1.0, head_scale=1.0)
+    assert plain == spelled
+    for option in ("embed_scale", "residual_scale", "head_scale"):
+        assert programs(**{option: 0.5})[0] != plain[0], option

@@ -385,21 +385,22 @@ def test_engine_refuses_what_needs_a_state_snapshot(toy, option, value):
         make_engine(*toy, **{option: value})
 
 
-def test_fork_and_several_paged_tokens_stay_refused(toy, engine):
-    """ROADMAP M6: a fork and a paged call of several tokens need the state
-    at a position that is not the sequence's end."""
+def test_fork_stays_refused_and_chunks_continue_the_state(toy, engine):
+    """ROADMAP M6: a fork needs the state at a position that is not the
+    sequence's end; a prompt's chunks run in order need only the end."""
     model, params = toy
     slot = engine.admit([3, 4, 5], max_positions=8)
     with pytest.raises(ValueError, match="fork is refused"):
         engine.fork(slot)
     engine.release(slot)
-    pool = jax.eval_shape(lambda: make_paged_cache(model, 5, 8))
-    with pytest.raises(ValueError, match="several tokens"):
-        jax.eval_shape(
-            lambda p, c: decode_apply(
-                model, p, c, jnp.zeros((1, 3), jnp.int32),
-                page_table=jnp.zeros((1, 4), jnp.int32),
-                kv_lengths=jnp.zeros((1,), jnp.int32)), params, pool)
+    seq = np.random.default_rng(3).integers(1, 96, 21).tolist()
+    chunked = make_engine(model, params, prefill_chunk=8)
+    slot = chunked.admit(seq, max_positions=8)
+    while chunked.is_prefilling(slot):
+        chunked.prefill_step(slot)
+    got = np.asarray(chunked._last_logits[slot])
+    want = ref_logits(params, seq)[-1]
+    assert np.abs(got - want).max() < TOL
 
 
 # ------------------------------------------------------------ the experts

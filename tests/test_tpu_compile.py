@@ -385,6 +385,47 @@ def _gdn(what, slots=128, length=4096):
         _sds(lead + (32,), f32), _sds((lead[0], 32, 128, 128), f32))
 
 
+def _sala(what, slots=32, blocks_per_slot=536, page=64):
+    """ops/sparse_attention.py's kernels and `ssm_step` at the MiniCPM-SALA
+    cell's widths (perf/configs/minicpm_sala_9b_pp4.json, perf/traffic/
+    long_docs_s32.json): 32 query heads of 128 on 2 KV heads, pages of 64
+    tokens 256 lanes wide, 32 slots of 536 pages; the list walk over 128
+    columns a KV head, a 2,048-token chunk against the whole table with its
+    selection, the selection of a decode step, and the step kernel at a
+    group a head (32 x 128 x 128 float32 a slot)."""
+    from ddp_practice_tpu.ops import sparse_attention as sa, ssm
+
+    i32, f32, spec = jnp.int32, jnp.float32, sa.SparseSpec()
+    blocks = 1 + slots * blocks_per_slot
+    pool = _sds((blocks, page, 256))
+    index = _sds((blocks, spec.rows, 256))
+    if what == "walk":
+        return sa.sparse_walk, (
+            _sds((slots, 32, 128)), pool, pool,
+            _sds((slots, 2, spec.list_pages), i32), _sds((slots, 2), i32),
+            _sds((slots, 2), i32))
+    if what == "select":
+        return functools.partial(sa.sparse_select, spec=spec, kv_heads=2), (
+            _sds((slots, 32, 128)), index,
+            _sds((slots, blocks_per_slot), i32), _sds((slots,), i32),
+            _sds((slots,), i32))
+    if what == "prefill":
+        def chunk(q, k, v, index, table, pos0):
+            rows = jnp.take(index, table, axis=0).reshape(-1, 2, 128)
+            picked = sa.prefill_selection(
+                q, rows, pos0 + jnp.arange(2048), jnp.int32(0), spec)
+            return sa.sparse_prefill(q, k, v, picked, table, pos0,
+                                     block=page)
+
+        return chunk, (_sds((2048, 2, 16, 128)), pool, pool, index,
+                       _sds((blocks_per_slot,), i32), _sds((), i32))
+    return ssm.ssm_step, (
+        _sds((slots, 32, 128), f32), _sds((slots, 32), f32),
+        _sds((32,), f32), _sds((slots, 32, 128), f32),
+        _sds((slots, 32, 128), f32), _sds((32,), f32),
+        _sds((slots, 32, 128, 128), f32))
+
+
 def _paged_hd256(slots=128, blocks_per_slot=76, page=64):
     """The Qwen3-Next cell's attention: 16 query heads of 256 lanes on 2 KV
     heads (a group of 8), pages of 64 tokens 512 lanes wide."""
@@ -479,6 +520,9 @@ KERNELS = {
     "qwen_gdn_scan_4096": functools.partial(_gdn, "scan"),
     "qwen_gdn_scan_256": functools.partial(_gdn, "scan", length=256),
     "qwen_paged_hd256_group8_page64": _paged_hd256,
+    "sala_sparse_walk_32_slots": functools.partial(_sala, "walk"),
+    "sala_sparse_prefill_2048": functools.partial(_sala, "prefill"),
+    "sala_ssm_step_group_a_head": functools.partial(_sala, "step"),
     **{f"rows_{which}_{cell}_{n}": functools.partial(
         _moe_rows, which, n, k, experts, width)
        for cell, k, experts, width, ns in (
@@ -583,6 +627,16 @@ def test_kernel_compiles_for_v5e(topo, name):
                 "qwen_moe_gl": "moe_gmm_glu"}[name[:11]]
         calls = _kernel_calls(text)
         assert len(calls) == 1 and calls[0].endswith(want), calls
+    if name.startswith("sala"):
+        # the names perf/layer_metrics/flood_sparse_* and
+        # flood_ssm_step_roofline sum by; none is named `paged_decode`
+        want = {"sala_sparse_w": "sparse_walk", "sala_sparse_p":
+                "sparse_prefill", "sala_ssm_step": "ssm_step"}[name[:13]]
+        calls = _kernel_calls(text)
+        # `sparse_prefill` stands once a list width (128-640 entries at 536
+        # pages a slot: a `lax.switch` by what the chunk's end can see)
+        assert len(calls) == (5 if "prefill" in name else 1) \
+            and all(c.endswith(want) for c in calls), calls
     if name.startswith("rows"):
         # ONE device op each, named `moe_` and not `moe_gmm*`: the rooflines
         # of the expert kernels sum the ops named `moe_gmm*` and must not
@@ -842,6 +896,8 @@ CELL_DEPTH = {
                               attn_layer_offset=1),       # Mamba, attention
     "qwen3_next": lambda cfg: dict(cfg, layers_run=2,     # DeltaNet,
                                    full_attention_interval=2),  # attention
+    "minicpm_sala": lambda cfg: dict(cfg, layers_run=2,   # sparse, lightning
+                                     layers_published=[9, 10]),
 }
 
 
@@ -931,7 +987,7 @@ def _serve_programs(topo, name, whole=False):
     on_chip = functools.partial(_on, topo.devices[0])
     logits = _sds((slots, model.vocab_size), model.dtype)
     w = engine.buckets[0]
-    if engine.radix is None:
+    if not engine._canonical:
         prefill = lambda: engine._prefill_jit.lower(*on_chip((
             params, engine._cache, logits, _sds((1, w), i32), _sds((), i32),
             _sds((-(-w // eng["page"]),), i32), _sds((), i32))))
@@ -973,11 +1029,15 @@ def _no_frames_in_locations():
         jax.config.update(key, was)
 
 
-def _holds_the_contract(cell, prog, text) -> int:
-    """The scopes contract on one compiled program; its kernels, counted."""
+def _holds_the_contract(cell, prog, text, sample=True) -> int:
+    """The scopes contract on one compiled program; its kernels, counted.
+    `sample` false: the program's `sample` scope holds no op the contract
+    counts (a chunk of a recurrent model writes ONE carried row, a bare
+    dynamic-update-slice: the model hands back its last real row alone)."""
     import test_scopes as contract
 
-    want = {"loss", "optimizer"} if prog == "train_step" else {"sample"}
+    want = {"loss", "optimizer"} if prog == "train_step" \
+        else {"sample"} if sample else set()
     contract.hold(text, want | {"attn", "mlp", "norm"}, f"{cell} {prog}")
     for op, name, path, cls in contract.own_ops(text):
         if op == "custom-call":   # a kernel's own instruction, by name
@@ -1087,6 +1147,28 @@ def test_qwen3_next_programs_hold_their_kernels_by_count(topo):
                       **_expert_layers(4)}, decode
     prefill = _kernel_counts(progs["prefill"]().compile().as_text())
     assert prefill == {"gdn_scan": 3, **_expert_layers(4)}, prefill
+
+
+@pytest.mark.parametrize("prog, kernels", [
+    ("decode_burst", {"ssm_step": 1, "sparse_walk": 1}),
+    ("prefill", {"sparse_prefill": 5})])
+def test_minicpm_sala_programs_carry_their_scopes_and_kernels(topo, prog,
+                                                              kernels):
+    """The MiniCPM-SALA cell's programs compiled for the described v5e at
+    its widths and engine (32 slots of 536 pages, chunks of the first
+    bucket), one layer of each mixer (B L), a program a case: the scopes
+    contract, and the kernels by name and count: a decode step 1 `ssm_step`
+    and 1 `sparse_walk` (the selection is XLA under `sparse_select`), a
+    chunk `sparse_prefill` once a list width, of which one runs (128 to 640
+    entries at 536 pages a slot; its scan is XLA under `ssm_scan`); no op
+    of either is named `paged_decode`."""
+    cell = "minicpm_sala_serve_long"
+    with _no_frames_in_locations():
+        text = _cell_programs(topo, cell)[prog]().compile().as_text()
+    assert _holds_the_contract(
+        cell, prog, text, sample=prog == "decode_burst") >= 1
+    assert prog != "prefill" or "/sample/dynamic_update_slice" in text
+    assert _kernel_counts(text) == kernels
 
 
 # ------------------------------------------------------------ whole steps
