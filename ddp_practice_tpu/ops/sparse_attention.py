@@ -41,9 +41,13 @@ Three device ops, named for the readers (PERF.md section 3):
     sparse_prefill  a chunk of queries at positions [pos0, pos0 + s)
                     against the slot's pages (the chunk's own keys are
                     written before): a flash kernel over (KV head, tile of
-                    128 query positions, page) cells that runs only the
-                    pages SOME row of the tile picked (their list comes by
-                    scalar prefetch) and masks by row inside, so the result
+                    128 query positions, `PREFILL_FOLD` entries of the
+                    tile's list) cells that runs only the pages SOME row of
+                    the tile picked (their list comes by scalar prefetch,
+                    a page an index map, joined in VMEM to one 256-key
+                    tile a step; ONE kernel whatever the context, its list
+                    axis bounded at run time by what the chunk's end can
+                    see) and masks by row inside, so the result
                     is the per-row selection above and no (chunk x context)
                     score matrix exists. Gathering a row's 64 pages instead
                     would move 2.1 MB a row and KV head, ~120 GB a 14k
@@ -72,7 +76,7 @@ from ddp_practice_tpu.ops.flash_attention import (
     _LANES,
     _NEG_INF,
     _dot_tb,
-    _softmax_accumulate,
+    _widen,
 )
 from ddp_practice_tpu.utils import backend
 
@@ -81,6 +85,14 @@ POOL = (5, 4, 1)
 # query positions a grid cell of `sparse_prefill` holds (x the group's heads
 # = the rows of its matmuls)
 PREFILL_TILE = 128
+# entries of a tile's pick list (pages) a grid step of `sparse_prefill` folds:
+# 4 pages of 64 are 256 keys under one update of the softmax state, whose 512
+# lane reductions and 2,048-row rescale cost a step the same whatever its
+# keys. Timed alone against 1 and 2 on the chip by
+# `experiments/sparse_prefill_time.py` (PERF.md section 6, PR 41)
+PREFILL_FOLD = 4
+# the "position" of a dead list entry's keys: past every query
+_DEAD_POS = 2 ** 30
 
 
 class SparseSpec(NamedTuple):
@@ -417,17 +429,27 @@ def prefill_selection(q, index, pos, start, spec: SparseSpec):
 
 
 def _prefill_kernel(logi_ref, cnt_ref, pt_ref, pos_ref,       # SMEM
-                    q_ref, bias_ref, k_ref, v_ref, o_ref,
-                    m_scr, l_scr, acc_scr, *, sm_scale, block_size, tile,
-                    group, tiles, width):
-    """Grid (kv heads, tiles, list entries): cell (n, t, u) folds block
-    `logi[n, t, u]` of the sequence (page `pt[logi]`) into the
-    online softmax of tile t's `group * tile` query rows, under the causal
-    mask and the rows' own picks (`bias`, a column a BLOCK: 0 where the row
-    picked it, -1e30 where not). Entries past the tile's count are not run and not
-    fetched (their index map repeats the last live page)."""
+                    q_ref, bias_ref, *refs, block_size, tile, group, tiles,
+                    width, fold):
+    """Grid (kv heads, tiles, list entries / `fold`, as far as the chunk's
+    last row sees: a run-time bound): cell (n, t, u) folds the `fold` blocks
+    `logi[n, t, fold * u + j]` of the sequence (pages `pt[logi]`, fetched
+    one a `BlockSpec` and joined in VMEM to ONE key tile and ONE value tile
+    of `fold * block_size` keys) into the online softmax of tile t's
+    `group * tile` query rows (q comes scaled), under the causal mask of
+    each entry's own block and the rows' own picks (`bias`, a column a
+    BLOCK in lane tiles of 128: 0 where the row picked it, -1e30 where not;
+    an entry's column is spread over its keys by a one-hot matmul, no
+    reduction over the list). An entry at or past the tile's count (a live
+    step's tail) is masked whole; a step whose first entry is past it is not
+    run, and neither fetches (the index maps repeat the last live page).
+    The state is this kernel's own: running max and denominator replicated
+    over the lanes, an accumulator that is NOT normalised until `_done`."""
+    k_refs, v_refs = refs[:fold], refs[fold:2 * fold]
+    o_ref, m_scr, l_scr, acc_scr = refs[2 * fold:]
     n, t, u = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     cell = n * tiles + t
+    count = cnt_ref[cell]
 
     @pl.when(u == 0)
     def _init():
@@ -435,28 +457,49 @@ def _prefill_kernel(logi_ref, cnt_ref, pt_ref, pos_ref,       # SMEM
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    @pl.when(u < cnt_ref[cell])
+    @pl.when(fold * u < count)
     def _fold():
-        bs = block_size
-        block = logi_ref[cell * width + u]
-        lane = lax.broadcasted_iota(jnp.int32, bias_ref.shape, 1)
-        own = jnp.sum(jnp.where(lane == block, bias_ref[...], 0.0), axis=1,
-                      keepdims=True)                          # (tile, 1)
+        bs, keys = block_size, fold * block_size
+        col = lax.broadcasted_iota(jnp.int32, (tile, keys), 1)
         q_pos = pos_ref[0] + t * tile + lax.broadcasted_iota(
-            jnp.int32, (tile, bs), 0)
-        k_pos = block * bs + lax.broadcasted_iota(
-            jnp.int32, (tile, bs), 1)
-        pen = jnp.where(k_pos <= q_pos, 0.0, _NEG_INF) + own   # (tile, bs)
-        qs = (q_ref[...] * sm_scale).astype(q_ref.dtype)
-        s = _dot_tb(qs, k_ref[...])                     # (group*tile, bs)
-        s = (s.reshape(group, tile, bs) + pen[None]).reshape(
-            group * tile, bs)
-        m_scr[...], l_scr[...], acc_scr[...] = _softmax_accumulate(
-            s, v_ref[...], m_scr[...], l_scr[...], acc_scr[...])
+            jnp.int32, (tile, keys), 0)
+        hot_row = lax.broadcasted_iota(jnp.int32, (_LANES, keys), 0)
+        hot_col = lax.broadcasted_iota(jnp.int32, (_LANES, keys), 1)
+        k_pos = jnp.full((tile, keys), _DEAD_POS, jnp.int32)
+        own = jnp.zeros((tile, keys), jnp.float32)
+        for j in range(fold):
+            entry = fold * u + j
+            block = logi_ref[cell * width + entry]
+            mine = lambda at: (at >= j * bs) & (at < (j + 1) * bs)
+            # a dead entry's keys stand past every query: the causal mask
+            first = jnp.where(entry < count, block * bs, _DEAD_POS)
+            k_pos = jnp.where(mine(col), first + col - j * bs, k_pos)
+            hot = (hot_row == lax.rem(block, _LANES)) & mine(hot_col)
+            own = own + jnp.dot(
+                bias_ref[lax.div(block, _LANES)],
+                jnp.where(hot, 1.0, 0.0).astype(bias_ref.dtype),
+                preferred_element_type=jnp.float32)
+        pen = jnp.where(k_pos <= q_pos, 0.0, _NEG_INF) + own   # (tile, keys)
+        join = lambda rs: jnp.concatenate([r[...] for r in rs], axis=0)
+        s = _dot_tb(q_ref[...], join(k_refs))         # (group*tile, keys)
+        s = (s.reshape(group, tile, keys) + pen[None]).reshape(
+            group * tile, keys)
+        m_prev = m_scr[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
+        p = jnp.exp(s - _widen(m_next, keys))
+        alpha = jnp.exp(m_prev - m_next)
+        v = join(v_refs)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1)[:, None]
+        acc_scr[...] = acc_scr[...] * _widen(alpha, v.shape[-1]) + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_scr[...] = m_next
 
     @pl.when(u == pl.num_programs(2) - 1)
     def _done():
-        o_ref[...] = acc_scr[...].astype(o_ref.dtype)
+        l = l_scr[...]
+        l_inv = jnp.where(l == 0.0, 1.0, 1.0 / l)
+        o_ref[...] = (acc_scr[...] * _widen(l_inv, acc_scr.shape[-1])
+                      ).astype(o_ref.dtype)
 
 
 def sparse_prefill(q, k_pages, v_pages, selected, pt_row, pos0, *,
@@ -468,6 +511,7 @@ def sparse_prefill(q, k_pages, v_pages, selected, pt_row, pos0, *,
     s, kvh, group, d = q.shape
     mb = pt_row.shape[0]
     tile = min(PREFILL_TILE, s)
+    fold = PREFILL_FOLD
     packable = d % _LANES == 0 and block % 8 == 0 and s % tile == 0 \
         and tile % 8 == 0
     if impl == "reference" or (impl == "auto" and (
@@ -492,57 +536,60 @@ def sparse_prefill(q, k_pages, v_pages, selected, pt_row, pos0, *,
         order, jnp.minimum(at, jnp.maximum(count[..., None] - 1, 0)), -1),
         mb - 1)
     bias = jnp.where(jnp.pad(sel, ((0, 0),) * 3 + ((0, pad),)), 0.0,
-                     _NEG_INF)
+                     _NEG_INF).astype(jnp.bfloat16)      # (T, tile, kvh, M)
+    # a lane tile of 128 blocks a leading index: the kernel takes the tile
+    # that holds an entry's block and never the whole width
+    bias = jnp.transpose(
+        bias.reshape(tiles, tile, kvh, full // _LANES, _LANES),
+        (2, 0, 3, 1, 4))                     # (kvh, T, M / 128, tile, 128)
     to_cells = lambda a: jnp.moveaxis(a, 1, 0)      # (T, kvh, ..) -> kvh first
-    bias = jnp.moveaxis(bias, 2, 0).astype(jnp.float32)  # (kvh, T, tile, M)
     logical, count = to_cells(logical), to_cells(count).reshape(-1)
-    qk = jnp.moveaxis(q.reshape(tiles, tile, kvh, group, d), (2, 3), (0, 2))
+    # scaled here, once a chunk, not in every grid step
+    qk = (q * d ** -0.5).astype(q.dtype)
+    qk = jnp.moveaxis(qk.reshape(tiles, tile, kvh, group, d), (2, 3), (0, 2))
     qk = qk.reshape(kvh, tiles, group * tile, d)
-    pos0 = jnp.asarray(pos0, jnp.int32)
+    kernel = functools.partial(
+        _prefill_kernel, block_size=block, tile=tile, group=group,
+        tiles=tiles, width=full, fold=fold)
 
-    def run(width: int):
-        """The kernel over lists of `width` entries: a grid step an entry,
-        live or not, so the lists are cut to the blocks the chunk can see
-        (a row picks among those at or before its own)."""
-        kernel = functools.partial(
-            _prefill_kernel, sm_scale=d ** -0.5, block_size=block,
-            tile=tile, group=group, tiles=tiles, width=width)
-
+    def page_spec(j):
         def page_map(n, t, u, logi, cnt, pt, pos):
-            return (pt[logi[(n * tiles + t) * width + u]], 0, n)
+            return pt[logi[(n * tiles + t) * full + fold * u + j]], 0, n
+        return pl.BlockSpec((None, block, d), page_map)
 
-        cell = lambda n, t, u, *_: (n, t, 0, 0)
-        page_spec = pl.BlockSpec((None, block, d), page_map)
-        return pl.pallas_call(
-            kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=4,
-                grid=(kvh, tiles, width),
-                in_specs=[
-                    pl.BlockSpec((None, None, group * tile, d), cell),
-                    pl.BlockSpec((None, None, tile, width), cell),
-                    page_spec, page_spec,
-                ],
-                out_specs=pl.BlockSpec((None, None, group * tile, d), cell),
-                scratch_shapes=[
-                    pltpu.VMEM((group * tile, _LANES), jnp.float32),
-                    pltpu.VMEM((group * tile, _LANES), jnp.float32),
-                    pltpu.VMEM((group * tile, d), jnp.float32),
-                ],
-            ),
-            out_shape=jax.ShapeDtypeStruct((kvh, tiles, group * tile, d),
-                                           q.dtype),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=not backend.on_tpu(),
-            name="sparse_prefill",
-        )(logical[..., :width].reshape(-1), count, pt_row.astype(jnp.int32),
-          pos0.reshape(1), qk, bias[..., :width], k_pages, v_pages)
-
-    widths = list(range(_LANES, full + 1, _LANES))
-    seen = (pos0 + s - 1) // block + 1          # blocks the chunk's end sees
-    out = lax.switch(jnp.clip((seen - 1) // _LANES, 0, len(widths) - 1),
-                     [functools.partial(run, w) for w in widths])
+    cell = lambda n, t, u, *_: (n, t, 0, 0)
+    pages = [page_spec(j) for j in range(fold)]
+    pos0 = jnp.asarray(pos0, jnp.int32)
+    # ONE kernel whatever the context: the list axis of the grid ends where
+    # the chunk's last row can see (a row picks among the blocks at or
+    # before its own), a bound the call reads at run time
+    steps = jnp.minimum((pos0 + s - 1) // block // fold + 1, full // fold)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(kvh, tiles, steps),
+            in_specs=[
+                pl.BlockSpec((None, None, group * tile, d), cell),
+                pl.BlockSpec((None, None, full // _LANES, tile, _LANES),
+                             lambda n, t, u, *_: (n, t, 0, 0, 0)),
+                *pages, *pages,
+            ],
+            out_specs=pl.BlockSpec((None, None, group * tile, d), cell),
+            scratch_shapes=[
+                pltpu.VMEM((group * tile, _LANES), jnp.float32),
+                pltpu.VMEM((group * tile, _LANES), jnp.float32),
+                pltpu.VMEM((group * tile, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((kvh, tiles, group * tile, d),
+                                       q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=not backend.on_tpu(),
+        name="sparse_prefill",
+    )(logical.reshape(-1), count, pt_row.astype(jnp.int32), pos0.reshape(1),
+      qk, bias, *[k_pages] * fold, *[v_pages] * fold)
     out = out.reshape(kvh, tiles, group, tile, d)
     return jnp.moveaxis(out, (0, 2), (2, 3)).reshape(s, kvh, group, d)
 

@@ -258,24 +258,79 @@ def test_the_list_walk_kernel_is_its_plain_form():
     assert np.abs(np.asarray(got - want)).max() < KERNEL_TOL
 
 
-def test_the_prefill_kernel_is_the_per_row_selection():
-    """`sparse_prefill` in interpret mode on a chunk of 32 queries at
-    positions 40-71 of a 12-page table: rows attend their OWN picks though a
-    tile runs the union of them."""
+def _hand_picks(s, blocks, rows):
+    """(s, 2, blocks) bool: every row its own `rows[i]` blocks, both KV
+    heads alike."""
+    picked = np.zeros((s, 2, blocks), bool)
+    for i, own in enumerate(rows):
+        picked[i, :, list(own)] = True
+    return jnp.asarray(picked)
+
+
+def _prefill_case(name):
+    """(s, pages, pos0, picks, check): a chunk of `s` queries at `pos0` of a
+    `pages`-page table; `picks` None for the real selection or (s, 2, pages)
+    bool; `check(picked, counts)` says the case is the one its name tells of
+    (counts (tiles, 2): the entries of each tile's list)."""
+    fold = sparse.PREFILL_FOLD
+    if name == "many_picks":        # rows attend their OWN picks though a
+        return 32, 12, 40, None, lambda p, c: (      # tile runs the union
+            (p.sum(-1) == 4).all()
+            and len({tuple(r) for r in p[:, 0].tolist()}) > 4)
+    if name == "odd_count":         # the last live step's tail entry is dead
+        rows = [{0, 2, 5, 6, 9}] * 16 + [{0, 2, 3, 6, 8, 9}] * 16
+        return 32, 12, 72, _hand_picks(32, 12, rows), \
+            lambda p, c: (c == 7).all() and 7 % fold
+    if name == "count_of_one":      # a chunk at position 0 of the first page
+        return 8, 12, 0, None, lambda p, c: (c == 1).all()
+    if name == "first_of_a_pair":   # entries 2 and 3 are blocks 4 and 7:
+        rows = [{0, 1, 4, 9}] * 16 + [{0, 1, 4, 7, 9}] * 16   # no row has 7
+        return 32, 12, 72, _hand_picks(32, 12, rows), \
+            lambda p, c: p[:16, :, 4].all() and not p[:16, :, 7].any()
+    if name == "second_of_a_pair":
+        rows = [{0, 1, 7, 9}] * 16 + [{0, 1, 4, 7, 9}] * 16   # nor has 4
+        return 32, 12, 72, _hand_picks(32, 12, rows), \
+            lambda p, c: p[:16, :, 7].all() and not p[:16, :, 4].any()
+    if name == "across_dense_len":  # rows 16-31 are dense, 32-47 pick 4
+        return 32, 12, 16, None, lambda p, c: (
+            (p[:16].sum(-1) == np.arange(16, 32)[:, None] // 8 + 1).all()
+            and (p[16:].sum(-1) == 4).all())
+    if name == "second_lane_tile":  # 160 blocks: picks past block 127, whose
+        return 32, 160, 1048, None, lambda p, c: (  # bias is the 2nd lane tile
+            (p.sum(-1) == 4).all() and p[:, :, 128:].any(-1).all())
+    if name == "two_tiles":         # tile 0 ends at block 15, tile 1 at 31
+        return 256, 40, 0, None, lambda p, c: (
+            c.shape[0] == 2 and (c[0] != c[1]).all())
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("name", [
+    "many_picks", "odd_count", "count_of_one", "first_of_a_pair",
+    "second_of_a_pair", "across_dense_len", "second_lane_tile", "two_tiles"])
+def test_the_prefill_kernel_is_the_per_row_selection(name):
+    """`sparse_prefill` in interpret mode against its plain form: a tile
+    runs the union of its rows' picks, `PREFILL_FOLD` list entries a grid
+    step, and every row attends its OWN picks under the causal mask."""
+    s, pages, pos0, picks, check = _prefill_case(name)
     rng = np.random.default_rng(0)
     f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
-    kp, vp = f(40, 8, 256), f(40, 8, 256)
-    table = jnp.asarray(rng.permutation(np.arange(1, 40))[:12], jnp.int32)
-    q, index = f(32, 2, 4, 128), f(48, 2, 128)
-    picked = sparse.prefill_selection(q, index, 40 + jnp.arange(32),
-                                      jnp.int32(0), SPEC)
-    assert (np.asarray(picked).sum(-1) == 4).all()
-    assert len({tuple(r) for r in np.asarray(picked)[:, 0].tolist()}) > 4
-    want = sparse.sparse_prefill(q, kp, vp, picked, table, 40, block=8,
+    pool = pages + 28
+    kp, vp = f(pool, 8, 256), f(pool, 8, 256)
+    table = jnp.asarray(rng.permutation(np.arange(1, pool))[:pages],
+                        jnp.int32)
+    q, index = f(s, 2, 4, 128), f(pages * SPEC.rows, 2, 128)
+    picked = picks if picks is not None else sparse.prefill_selection(
+        q, index, pos0 + jnp.arange(s), jnp.int32(0), SPEC)
+    tile = min(sparse.PREFILL_TILE, s)
+    counts = np.asarray(picked).reshape(s // tile, tile, 2, pages).any(
+        1).sum(-1)
+    assert check(np.asarray(picked), counts), counts
+    want = sparse.sparse_prefill(q, kp, vp, picked, table, pos0, block=8,
                                  impl="reference")
-    got = sparse.sparse_prefill(q, kp, vp, picked, table, 40, block=8,
+    got = sparse.sparse_prefill(q, kp, vp, picked, table, pos0, block=8,
                                 impl="kernel")
     assert np.abs(np.asarray(got - want)).max() < KERNEL_TOL
+    assert np.abs(np.asarray(want)).max() > 0.1
 
 
 # ------------------------------------------------------------------ the model
