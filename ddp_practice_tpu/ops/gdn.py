@@ -22,16 +22,23 @@ it and S0 the state entering it:
     O      = (exp(G) Q) S0 + tril(Q K^T exp(G_t - G_s)) U
     S_C    = exp(G_C) S0 + (exp(G_C - G) K)^T U
 
-Everything that does not hold S0 is computed for all chunks at once as plain
-matmuls in XLA (`_chunk_terms`; A is strictly lower of C = 64 rows and
-inverted by blocks, `_unit_lower_inverse`: ten 64^3 matmuls and no solve);
-the three lines that do are the sequential part: the Pallas kernel
-`gdn_scan`, the state in VMEM from chunk to chunk, off the TPU a `lax.scan`
-over the chunks. A position with beta = 0, g = 0 and zero q, k, v moves
-nothing: left padding and the tail of a partial chunk.
+Everything that does not hold S0 is a chunk's TERMS (`_chunk_terms`; A is
+strictly lower of C = 64 rows and inverted by blocks, `_unit_lower_inverse`:
+ten 64^3 matmuls and no solve). On the TPU they are one Pallas kernel,
+`gdn_terms` (PR 42): a grid cell reads a chunk's rows of q, k, v, g and beta
+once, in the layout the mixer has them, holds every (C, C) array in VMEM
+and writes the six terms; before, they were some thirty XLA ops a layer
+whose float32 operands and results crossed HBM. Off the TPU the terms are
+plain matmuls in XLA for all chunks at once, which is also the kernel's
+oracle. The three lines that hold S0 are the sequential part: the Pallas
+kernel `gdn_scan`, the state in VMEM from chunk to chunk, off the TPU a
+`lax.scan` over the chunks. A position with beta = 0, g = 0 and zero q, k,
+v moves nothing: left padding and the tail of a partial chunk.
 
-Device op names (PERF.md section 3): the kernels are `gdn_step` and
-`gdn_scan`; the chunk terms are XLA fusions under the scope `gdn_scan`.
+Device op names (PERF.md section 3): the kernels are `gdn_step`,
+`gdn_terms` and `gdn_scan`, one `gdn_terms` beside every `gdn_scan`, both
+under the scope `gdn_scan`; `flood_gdn_dev_pct` sums the three by their
+prefix, the two rooflines read `gdn_step` and `gdn_scan` alone.
 """
 
 from __future__ import annotations
@@ -40,6 +47,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -215,7 +223,7 @@ def _chunk_terms(q, k, v, g, beta, chunk: int) -> dict:
     }
 
 
-def _unit_lower_inverse(a):
+def _unit_lower_inverse(a, same=None, c=None):
     """(I + a)^-1 of a strictly lower (..., c, c), as matmuls of whole
     (c, c) matrices and no solve, by blocks: the 8-wide diagonal blocks d
     first, (I + d)^-1 = (I - d)(I + d^2)(I + d^4) (d^8 = 0; four matmuls),
@@ -225,15 +233,20 @@ def _unit_lower_inverse(a):
     matmuls at c = 64, as many as the closed product
     prod_j (I + (-a)^(2^j)) takes, whose powers reach 1e14 where the keys of
     a chunk are alike and the decay slow (a prompt of one repeated token)
-    and cancel to nothing in float32: PERF.md section 6, PR 38."""
-    c = a.shape[-1]
-    at = jnp.arange(c)
-    same = lambda size: (at[:, None] // size) == (at[None, :] // size)
+    and cancel to nothing in float32: PERF.md section 6, PR 38.
+
+    `same(size)` is the mask of the pairs in one diagonal block of `size`
+    (from `arange` where none is given; the kernel hands in its own, of
+    2-D iotas, for several matrices of `c` rows down one diagonal)."""
+    c = c or a.shape[-1]
+    if same is None:
+        at = jnp.arange(c)
+        same = lambda size: (at[:, None] // size) == (at[None, :] // size)
     mm = lambda x, y: jnp.einsum("...ts,...sr->...tr", x, y,
                                  precision=HIGHEST)
     d = jnp.where(same(8), a, 0.0)
     d2 = mm(d, d)
-    t = jnp.eye(c, dtype=F32) - d
+    t = jnp.where(same(1), 1.0, 0.0).astype(F32) - d
     t = t + mm(t, d2)
     t = t + mm(t, mm(d2, d2))
     size = 8
@@ -242,6 +255,151 @@ def _unit_lower_inverse(a):
         t = t - mm(t, mm(below, t))
         size *= 2
     return t
+
+
+_TERMS = ("w", "u0", "qg", "p", "kend", "dend")
+_FAR = 1 << 30    # `_pair_levels` of two positions in two matrices
+
+
+def _terms_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, tri_ref, w_ref,
+                  u0_ref, qg_ref, p_ref, kend_ref, dend_ref):
+    """One grid cell: one sequence, one chunk of c positions, a block of
+    value heads; `_chunk_terms` of that chunk with every (c, c) array in
+    VMEM. The refs hold the chunk's rows as the mixer has them: q, k
+    (c, key heads, dk), v (c, heads, dv), g and beta (c, ALL value heads);
+    `tri_ref` is `_pair_levels`.
+
+    The value heads of ONE key head are worked as one matrix: their A's
+    down the diagonal of an (n, n) tile, n = per_key c (two of 64 rows fill
+    the MXU's 128 x 128 and every lane of a register; the inverse of a
+    block diagonal is the blocks' inverses, and the merges stop at c), their
+    rows of K, Q, V one below the other. The cell's key heads are the BATCH
+    of every product: the inverse is a chain of ten matmuls each waiting
+    for the last, and the other heads' chains are what fills the wait (a
+    key head at a time read 3.33 ms a 4,096-token layer, all eight side by
+    side 2.15: PERF.md section 6, PR 42). Whole arrays, no loop over heads:
+    the body is traced and lowered on the host once a prompt bucket, and
+    an op a head made that 4 s a bucket on the chip's host."""
+    heads, c, dv = u0_ref.shape
+    keys, dk = k_ref.shape[1:]
+    per_key = heads // keys
+    n = per_key * c
+    wide = max(n, dk, dv)
+    tri = tri_ref[...]
+    near = jnp.abs(tri)
+    same = lambda size: near <= size
+    low, strict = (tri >= 0) & (near < _FAR), (tri > 0) & (near < _FAR)
+    g, beta = g_ref[...], beta_ref[...]
+    if g.shape[1] > heads:    # this block's columns, by a 0 / 1 matmul
+        at = lambda axis: lax.broadcasted_iota(
+            jnp.int32, (g.shape[1], heads), axis)
+        pick = (at(0) == pl.program_id(1) * heads + at(1)).astype(F32)
+        g, beta = _dot(g, pick), _dot(beta, pick)
+    # G, the running sum of g down the chunk: (c, heads)
+    cum = _dot(low[:c, :c].astype(F32), g)
+    mm = functools.partial(jnp.einsum, precision=HIGHEST,
+                           preferred_element_type=F32)
+    heads_first = lambda ref: jnp.swapaxes(ref[...], 0, 1)     # (h, c, d)
+    tile = lambda x: jnp.concatenate([x] * per_key, 1)
+    # a key head's value heads one below the other, and back
+    by_key = lambda x: x.reshape(keys, n, x.shape[-1])
+    by_head = lambda x: x.reshape(heads, c, x.shape[-1])
+    k1, q1 = heads_first(k_ref), heads_first(q_ref)            # (keys, c, dk)
+    k, q, v = tile(k1), tile(q1), by_key(heads_first(v_ref))
+    # a head's column of scalars, along the lanes: (keys, n, wide)
+    gc, b = (by_key(jnp.broadcast_to(x.T[:, :, None], (heads, c, wide)))
+             for x in (cum, beta))
+    seg = gc[..., :n] - jnp.swapaxes(gc[..., :n], 1, 2)        # G_t - G_s
+    decay = jnp.where(low, jnp.exp(jnp.where(low, seg, 0.0)), 0.0)
+    # q k^T over k k^T, their columns once a value head: ONE product
+    both = mm("jtd,jsd->jts", jnp.concatenate([q1, k1], 1), k)
+    qk, kk = tile(both[:, :c]), tile(both[:, c:])              # (keys, n, n)
+    t = _unit_lower_inverse(
+        jnp.where(strict, b[..., :n] * decay * kk, 0.0), same, c)
+    eg = jnp.exp(gc)
+    w_ref[...] = by_head(mm("jts,jsd->jtd", t, (b * eg)[..., :dk] * k))
+    u0_ref[...] = by_head(mm("jts,jsd->jtd", t, b[..., :dv] * v))
+    qg_ref[...] = by_head(eg[..., :dk] * q)
+    dend_ref[...] = jnp.exp(jnp.broadcast_to(                  # exp(G_C)
+        cum.T[:, c - 1:c][:, :, None], (heads, 1, dv)))
+    # G_t - G_C is a head's last column of seg
+    kend_ref[...] = by_head(jnp.exp(-jnp.broadcast_to(jnp.concatenate(
+        [seg[:, r * c:(r + 1) * c, (r + 1) * c - 1:(r + 1) * c]
+         for r in range(per_key)], 1), (keys, n, dk))) * k)
+    # a head's c columns of p first, zeros to the last lane
+    p = decay * qk
+    p = by_head(jnp.stack([
+        p[:, r * c:(r + 1) * c] if not r
+        else pltpu.roll(p[:, r * c:(r + 1) * c], n - r * c, 2)
+        for r in range(per_key)], 1).reshape(keys, n, n))
+    if n >= p_ref.shape[-1]:
+        p_ref[...] = p[..., :p_ref.shape[-1]]
+    else:
+        p_ref[:, :, :n] = p
+        p_ref[:, :, n:] = jnp.zeros((heads, c, p_ref.shape[-1] - n), F32)
+
+
+def _pair_levels(c: int, per_key: int):
+    """(n, n) int32, n = per_key c, for the pairs (t, s) of `per_key`
+    matrices of c rows down one diagonal: 0 on the diagonal, else the size
+    of the smallest block of `_unit_lower_inverse` (8, 16, 32, ... up to
+    the first that holds c) that holds both, `_FAR` where t and s lie in
+    two matrices; negative above the diagonal. What the kernel's masks are
+    compared from (as iotas, a `//` and a `%` a mask were half of the
+    kernel's lowering)."""
+    at = np.arange(per_key * c)
+    t, s = at[:, None], at[None, :]
+    level = np.full((per_key * c,) * 2, _FAR)
+    size = 8
+    while size < 2 * c:
+        size *= 2
+    while size >= 8:
+        level = np.where((t // c == s // c)
+                         & (t % c // size == s % c // size), size, level)
+        size //= 2
+    return (np.sign(t - s) * np.where(t == s, 0, level)).astype(np.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _terms_call(q, k, v, g, beta, *, chunk: int, interpret: bool) -> dict:
+    """`_chunk_terms` as ONE device op, `gdn_terms`. Jitted on its own: a
+    model's layers ask for the same shapes, and a kernel traced and lowered
+    once a program, not once a layer, is host time off its set-up."""
+    bsz, l, hv, dv = v.shape
+    hk, dk = k.shape[2:]
+    nc, c, n = l // chunk, chunk, hv // hk * chunk
+    bh, bk = _head_block(hv, hk)
+    rows = lambda h, width: pl.BlockSpec((None, c, h, width),
+                                         lambda i, j, t: (i, t, j, 0))
+    scalars = pl.BlockSpec((None, c, hv), lambda i, j, t: (i, t, 0))
+    per = lambda r, width: pl.BlockSpec(
+        (None, bh, None, r, width), lambda i, j, t: (i, j, t, 0, 0))
+    widths = {"w": dk, "u0": dv, "qg": dk, "p": c + -c % _LANES,
+              "kend": dk, "dend": dv}
+    out = pl.pallas_call(
+        _terms_kernel,
+        grid=(bsz, hv // bh, nc),
+        in_specs=[rows(bk, dk), rows(bk, dk), rows(bh, dv), scalars,
+                  scalars, pl.BlockSpec((n, n), lambda i, j, t: (0, 0))],
+        out_specs=[per(1 if name == "dend" else c, widths[name])
+                   for name in _TERMS],
+        out_shape=[jax.ShapeDtypeStruct(
+            (bsz, hv, nc, 1 if name == "dend" else c, widths[name]), F32)
+            for name in _TERMS],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3,
+            vmem_limit_bytes=_SCAN_VMEM),
+        interpret=interpret,
+        name="gdn_terms",
+    )(*(x.astype(F32) for x in (q, k, v, g, beta)), _pair_levels(c, hv // hk))
+    return dict(zip(_TERMS, out))
+
+
+def gdn_terms_kernel(q, k, v, g, beta, chunk: int = CHUNK) -> dict:
+    """`_chunk_terms` as ONE device op, `gdn_terms` (interpret mode off the
+    TPU, where only the tests call it)."""
+    return _terms_call(q, k, v, g, beta, chunk=chunk,
+                       interpret=not backend.on_tpu())
 
 
 def _carry_chunk(s, w, u0, qg, p, kend, dend):
@@ -263,9 +421,8 @@ def _carry_reference(terms: dict, h0):
         o, s = one(s, *chunk)
         return s, o
 
-    order = ("w", "u0", "qg", "p", "kend", "dend")
     final, o = lax.scan(
-        step, h0, tuple(jnp.moveaxis(terms[n], 2, 0) for n in order))
+        step, h0, tuple(jnp.moveaxis(terms[n], 2, 0) for n in _TERMS))
     return jnp.moveaxis(o, 0, 2), final
 
 
@@ -288,6 +445,13 @@ def _scan_kernel(w_ref, u0_ref, qg_ref, p_ref, kend_ref, dend_ref, h0_ref,
 
 def _carry_kernel(terms: dict, h0):
     """`_carry_reference` as ONE device op, `gdn_scan`."""
+    return _carry_call(terms, h0, interpret=not backend.on_tpu())
+
+
+# jitted on its own, as `_terms_call` is (six lowerings a prefill program
+# before: tests/test_tpu_compile.py counts them)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _carry_call(terms: dict, h0, *, interpret: bool):
     bsz, hv, nc, c, dv = terms["u0"].shape
     dk = terms["w"].shape[-1]
     bh = _HEADS if hv % _HEADS == 0 else hv
@@ -306,17 +470,18 @@ def _carry_kernel(terms: dict, h0):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_SCAN_VMEM),
-        interpret=not backend.on_tpu(),
+        interpret=interpret,
         name="gdn_scan",
-    )(*(terms[n] for n in ("w", "u0", "qg", "p", "kend", "dend")), h0)
+    )(*(terms[n] for n in _TERMS), h0)
 
 
 def gdn_scan(q, k, v, g, beta, h0, *, chunk: int = CHUNK,
              kernel: bool | None = None):
     """The recurrence over a whole call of several tokens, FROM `h0`;
     arguments and results as `gdn_scan_reference`, by the chunked form. The
-    carry through the chunks is the Pallas kernel on the TPU (`kernel`
-    None), a `lax.scan` elsewhere; the tests name either."""
+    chunks' terms and the carry through the chunks are the two Pallas
+    kernels on the TPU (`kernel` None), XLA matmuls and a `lax.scan`
+    elsewhere; the tests name either pair."""
     with jax.named_scope("gdn_scan"):
         l = v.shape[1]
         c = min(chunk, l)
@@ -325,8 +490,8 @@ def gdn_scan(q, k, v, g, beta, h0, *, chunk: int = CHUNK,
         if pad:  # beta = 0, g = 0, zero q, k, v: nothing moves
             ins = tuple(jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),)
                                 * (x.ndim - 2)) for x in ins)
-        terms = _chunk_terms(*ins, c)
         use = backend.on_tpu() if kernel is None else kernel
+        terms = (gdn_terms_kernel if use else _chunk_terms)(*ins, c)
         o, final = (_carry_kernel if use else _carry_reference)(
             terms, h0.astype(F32))
         bsz, hv, nc, _, dv = o.shape

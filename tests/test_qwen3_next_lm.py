@@ -125,8 +125,9 @@ SCANS = {
 @pytest.mark.parametrize("case", sorted(SCANS))
 def test_chunked_scan_is_the_sequential_recurrence(case, kernel):
     """From a NON-ZERO state: outputs and the final state of the chunked
-    form (the carry as a `lax.scan`, and as the kernel in interpret mode)
-    against one position at a time; left padding moves nothing."""
+    form (the terms in XLA and the carry as a `lax.scan`; both as their
+    kernels, `gdn_terms` and `gdn_scan`, in interpret mode) against one
+    position at a time; left padding moves nothing."""
     length, chunk, pad = SCANS[case]
     args = scan_inputs(2, length, 7, pad=pad)
     want_o, want_h = gdn.gdn_scan_reference(*args)
@@ -140,23 +141,74 @@ def test_chunked_scan_is_the_sequential_recurrence(case, kernel):
         np.testing.assert_array_equal(same, args[5])
 
 
-@pytest.mark.parametrize("beta, g", [(0.7, -0.34), (0.95, -0.05),
-                                     (0.99, -0.01)])
-def test_a_prompt_of_one_repeated_token_stays_the_recurrence(beta, g):
+REPEATED = [(0.7, -0.34), (0.95, -0.05), (0.99, -0.01)]   # (beta, g)
+
+
+def repeated_token(inputs, beta, g):
+    """`inputs` with one token all along the prompt under one (beta, g)."""
+    q, k, v = (jnp.broadcast_to(x[:, :1], x.shape) for x in inputs[:3])
+    return (q, k, v, jnp.full(v.shape[:3], g), jnp.full(v.shape[:3], beta))
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+@pytest.mark.parametrize("beta, g", REPEATED)
+def test_a_prompt_of_one_repeated_token_stays_the_recurrence(beta, g, kernel):
     """Every key of a chunk the same unit vector, a gate near 1 and a slow
     decay: what a prompt of one repeated token gives a layer (the engines'
     warm-up prompt is one). The chunk's triangular system is then dense and
     far from the identity; inverted by blocks it stays the recurrence to
     float32's last digits, where the closed product of its powers lost
     every digit (an error of 1e25 at beta 0.9, NaN past it: on the chip the
-    warm-up left a state of 6e17 and then NaN in every slot, PR 38)."""
-    q, k, v, _, _, h0 = scan_inputs(1, 256, 9, h0_zero=True)
-    q, k, v = (jnp.broadcast_to(x[:, :1], x.shape) for x in (q, k, v))
-    gs, betas = jnp.full(v.shape[:3], g), jnp.full(v.shape[:3], beta)
-    want_o, want_h = gdn.gdn_scan_reference(q, k, v, gs, betas, h0)
-    o, h = gdn.gdn_scan(q, k, v, gs, betas, h0, kernel=False)
+    warm-up left a state of 6e17 and then NaN in every slot, PR 38); so in
+    XLA and in the kernel `gdn_terms`, which inverts by the same blocks."""
+    ins = scan_inputs(1, 256, 9, h0_zero=True)
+    args = repeated_token(ins, beta, g) + ins[5:]
+    want_o, want_h = gdn.gdn_scan_reference(*args)
+    o, h = gdn.gdn_scan(*args, kernel=kernel)
     np.testing.assert_allclose(o, want_o, atol=KERNEL_TOL, rtol=KERNEL_TOL)
     np.testing.assert_allclose(h, want_h, atol=KERNEL_TOL, rtol=KERNEL_TOL)
+
+
+TERMS = {
+    # (inputs, chunk): toy widths (2 key heads serving 4 value heads of
+    # 32 x 32) and the published 128 x 128 with two value heads a key head
+    "whole_chunks": lambda: (scan_inputs(1, 64, 3)[:5], 16),
+    "partial_last_chunk": lambda: (tuple(
+        jnp.pad(x, ((0, 0), (0, 10)) + ((0, 0),) * (x.ndim - 2))
+        for x in scan_inputs(1, 38, 4)[:5]), 16),
+    "left_padding": lambda: (scan_inputs(1, 64, 5, pad=21)[:5], 16),
+    "batch_of_two": lambda: (scan_inputs(2, 48, 6)[:5], 16),
+    **{f"repeated_token_{beta}": lambda beta=beta, g=g: (repeated_token(
+        scan_inputs(1, 128, 9), beta, g), 64) for beta, g in REPEATED},
+    "published_head_128x128": lambda: (scan_inputs(
+        1, 128, 8, hk=1, hv=2, dk=128, dv=128)[:5], 64),
+    "two_head_blocks": lambda: (scan_inputs(
+        1, 32, 10, hk=16, hv=32, dk=8, dv=8)[:5], 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TERMS))
+def test_the_terms_kernel_is_the_chunk_terms(case):
+    """`gdn_terms` (interpret mode) against its oracle `_chunk_terms`, term
+    by term, to float32 rounding: the same products at the same precision,
+    a (c, c) product summed inside an (n, n) tile whose other blocks are
+    zeros, the running sum of g as a matmul against the lower
+    triangle. The partial last chunk is padded as `gdn_scan` pads it (beta
+    0, g 0, zero q, k, v). The repeated token makes the triangular system
+    dense and its inverse a sum of terms that cancel (a closed product lost
+    every digit there): another order of the same sums moves T's products
+    by ten roundings where a random chunk's move by one."""
+    ins, chunk = TERMS[case]()
+    want = gdn._chunk_terms(*ins, chunk)
+    got = gdn.gdn_terms_kernel(*ins, chunk)
+    assert sorted(got) == sorted(want) == sorted(gdn._TERMS)
+    tol = 4e-6 if case.startswith("repeated") else 4e-7
+    for name in gdn._TERMS:
+        assert got[name].shape == want[name].shape, name
+        scale = float(jnp.abs(want[name]).max())
+        assert scale > 0.01 or (name == "dend" and scale > 0), name
+        np.testing.assert_allclose(got[name], want[name], rtol=0,
+                                   atol=tol * max(scale, 1.0), err_msg=name)
 
 
 def test_the_block_inverse_is_the_inverse():
@@ -199,19 +251,22 @@ def test_scan_then_steps_is_one_long_scan(kernel):
 
 
 def test_the_kernels_are_one_named_op_each():
-    """`gdn_scan` and `gdn_step` are the `name=` of ONE `pallas_call` each:
-    the names perf/layer_metrics/flood_gdn_* sum device time by, and neither
-    starts with `ssm_` or `sel_`, which the older readers sum."""
+    """`gdn_terms`, `gdn_scan` and `gdn_step` are the `name=` of ONE
+    `pallas_call` each: the names perf/layer_metrics/flood_gdn_* sum device
+    time by (`flood_gdn_dev_pct` by the prefix `gdn_`, the two rooflines by
+    `gdn_scan` and `gdn_step` whole, so the terms' kernel is in the first
+    and in neither of the others), and none starts with `ssm_` or `sel_`,
+    which the older readers sum. A prompt's call holds one `gdn_terms`
+    beside its one `gdn_scan`; off the kernel path it holds neither."""
     q, k, v, g, beta, h0 = scan_inputs(2, 32, 3)
-    for fn, args, name in (
-            (lambda *a: gdn.gdn_scan(*a, chunk=16, kernel=True),
-             (q, k, v, g, beta, h0), "gdn_scan"),
-            (gdn.gdn_step_kernel,
-             (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], h0),
-             "gdn_step")):
-        text = str(jax.make_jaxpr(fn)(*args))
-        assert text.count("pallas_call") == 1, name
-        assert f"name={name}" in text.replace(" ", ""), name
+    scan = lambda kernel: _pallas_names(jax.make_jaxpr(
+        lambda *a: gdn.gdn_scan(*a, chunk=16, kernel=kernel))(
+            q, k, v, g, beta, h0).jaxpr)
+    assert scan(True) == ["gdn_terms", "gdn_scan"]
+    assert scan(False) == []
+    assert _pallas_names(jax.make_jaxpr(gdn.gdn_step_kernel)(
+        q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], h0).jaxpr) \
+        == ["gdn_step"]
 
 
 # ------------------------------------------------------------- the model
@@ -495,10 +550,11 @@ def _pallas_names(jaxpr) -> list:
 
 def test_a_decode_step_and_a_prefill_hold_their_kernels_by_name(monkeypatch):
     """At the published depth and layout (toy widths): a decode step is 6
-    `gdn_step`, 2 `paged_decode` and 8 `moe_gmm_glu`; an admission prefill 6
-    `gdn_scan` and 8 `moe_gmm_glu` (its attention is plain XLA); and beside
-    each `moe_gmm_glu` the two kernels that move its rows, `moe_rows_fill`
-    in and `moe_rows_sum` out."""
+    `gdn_step`, 2 `paged_decode` and 8 `moe_gmm_glu`, and no `gdn_terms`;
+    an admission prefill 6 `gdn_scan`, one `gdn_terms` beside each, and 8
+    `moe_gmm_glu` (its attention is plain XLA); and beside each
+    `moe_gmm_glu` the two kernels that move its rows, `moe_rows_fill` in
+    and `moe_rows_sum` out."""
     from ddp_practice_tpu.inference import make_cache
     from ddp_practice_tpu.utils import backend
 
@@ -528,7 +584,7 @@ def test_a_decode_step_and_a_prefill_hold_their_kernels_by_name(monkeypatch):
     names = _pallas_names(jax.make_jaxpr(prefill)(
         params, jnp.zeros((1, 128), jnp.int32),
         jnp.zeros((1,), jnp.int32)).jaxpr)
-    assert sorted(names) == ["gdn_scan"] * 6 + experts
+    assert sorted(names) == ["gdn_scan"] * 6 + ["gdn_terms"] * 6 + experts
 
 
 def test_the_scopes_gdn_scan_and_gdn_step_are_in_the_op_paths(toy):

@@ -373,16 +373,19 @@ def _gdn(what, slots=128, length=4096):
     """ops/gdn.py's Gated DeltaNet kernels at the Qwen3-Next cell's widths
     (perf/configs/qwen3next_80b_ep4.json): 16 key heads serving 32 value
     heads of 128 x 128 float32 state, rewritten in place; the decode step
-    over 128 slots, a prompt's chunked scan over the widest bucket (the
-    chunk terms are XLA's, the carry the kernel's)."""
+    over 128 slots, a prompt's chunked scan over the widest bucket and the
+    narrowest (the chunk terms are the kernel `gdn_terms`, the carry the
+    kernel `gdn_scan`), and the terms alone."""
     from ddp_practice_tpu.ops import gdn
 
     f32 = jnp.float32
     lead = (slots,) if what == "step" else (1, length)
-    return (gdn.gdn_step if what == "step" else gdn.gdn_scan), (
-        _sds(lead + (16, 128), f32), _sds(lead + (16, 128), f32),
-        _sds(lead + (32, 128), f32), _sds(lead + (32,), f32),
-        _sds(lead + (32,), f32), _sds((lead[0], 32, 128, 128), f32))
+    args = (_sds(lead + (16, 128), f32), _sds(lead + (16, 128), f32),
+            _sds(lead + (32, 128), f32), _sds(lead + (32,), f32),
+            _sds(lead + (32,), f32), _sds((lead[0], 32, 128, 128), f32))
+    if what == "terms":
+        return gdn.gdn_terms_kernel, args[:5]
+    return (gdn.gdn_step if what == "step" else gdn.gdn_scan), args
 
 
 def _sala(what, slots=32, blocks_per_slot=536, page=64):
@@ -519,6 +522,8 @@ KERNELS = {
     "qwen_gdn_step_128_slots": functools.partial(_gdn, "step"),
     "qwen_gdn_scan_4096": functools.partial(_gdn, "scan"),
     "qwen_gdn_scan_256": functools.partial(_gdn, "scan", length=256),
+    "qwen_gdn_terms_4096": functools.partial(_gdn, "terms"),
+    "qwen_gdn_terms_256": functools.partial(_gdn, "terms", length=256),
     "qwen_paged_hd256_group8_page64": _paged_hd256,
     "sala_sparse_walk_32_slots": functools.partial(_sala, "walk"),
     "sala_sparse_prefill_2048": functools.partial(_sala, "prefill"),
@@ -622,11 +627,14 @@ def test_kernel_compiles_for_v5e(topo, name):
     if name.startswith("qwen"):
         # the names perf/layer_metrics/flood_gdn_*, flood_moe_glu_* and
         # flood_paged_decode_roofline sum by
-        want = {"qwen_gdn_st": "gdn_step", "qwen_gdn_sc": "gdn_scan",
-                "qwen_paged_": "paged_decode",
-                "qwen_moe_gl": "moe_gmm_glu"}[name[:11]]
-        calls = _kernel_calls(text)
-        assert len(calls) == 1 and calls[0].endswith(want), calls
+        # (a prompt's call is two ops: the terms, then the carry)
+        want = {"qwen_gdn_st": ["gdn_step"],
+                "qwen_gdn_sc": ["gdn_terms", "gdn_scan"],
+                "qwen_gdn_te": ["gdn_terms"],
+                "qwen_paged_": ["paged_decode"],
+                "qwen_moe_gl": ["moe_gmm_glu"]}[name[:11]]
+        calls = [c.split("/")[-1] for c in _kernel_calls(text)]
+        assert calls == want, calls
     if name.startswith("sala"):
         # the names perf/layer_metrics/flood_sparse_* and
         # flood_ssm_step_roofline sum by; none is named `paged_decode`
@@ -1108,7 +1116,7 @@ def _expert_layers(n):
 
 @pytest.mark.parametrize("prog, kernels", [
     ("decode_burst", {"gdn_step": 1, "paged_decode": 1, **_expert_layers(2)}),
-    ("prefill", {"gdn_scan": 1, **_expert_layers(2)})])
+    ("prefill", {"gdn_terms": 1, "gdn_scan": 1, **_expert_layers(2)})])
 def test_qwen3_next_programs_carry_their_scopes_and_kernels(topo, prog,
                                                             kernels):
     """The new cell's programs compiled for the described v5e at its widths
@@ -1116,9 +1124,10 @@ def test_qwen3_next_programs_carry_their_scopes_and_kernels(topo, prog,
     contract as above (the three scopes PR 34 added are shown to be metadata
     there, in the engine this cell shares line for line), and the kernels by
     name and count: a decode step 1 `gdn_step`, 1 `paged_decode` and 2
-    `moe_gmm_glu`; an admission prefill 1 `gdn_scan` and 2 `moe_gmm_glu`
-    (its attention is plain XLA); a `moe_rows_fill` and a `moe_rows_sum`
-    beside each `moe_gmm_glu` (PR 39)."""
+    `moe_gmm_glu` and no `gdn_terms`; an admission prefill 1 `gdn_scan`, 1
+    `gdn_terms` beside it (PR 42) and 2 `moe_gmm_glu` (its attention is
+    plain XLA); a `moe_rows_fill` and a `moe_rows_sum` beside each
+    `moe_gmm_glu` (PR 39)."""
     cell = "qwen3next_serve_mixed"
     with _no_frames_in_locations():
         text = _cell_programs(topo, cell)[prog]().compile().as_text()
@@ -1130,9 +1139,9 @@ def test_qwen3_next_programs_carry_their_scopes_and_kernels(topo, prog,
 def test_qwen3_next_programs_hold_their_kernels_by_count(topo):
     """The same programs one period deep (G G G A, half of `layers_run`): a
     decode step holds 3 `gdn_step`, 1 `paged_decode` and 4 `moe_gmm_glu`, an
-    admission prefill 3 `gdn_scan` and 4 `moe_gmm_glu`: a layer twice over
-    is the cell's 6 / 2 / 8 and 6 / 8. (26 s of compiling at the published
-    widths: `slow`.)"""
+    admission prefill 3 `gdn_scan`, 3 `gdn_terms` and 4 `moe_gmm_glu`: a
+    layer twice over is the cell's 6 / 2 / 8 and 6 / 6 / 8. (26 s of
+    compiling at the published widths: `slow`.)"""
     CELL_DEPTH["qwen3_next"], was = (
         lambda cfg: dict(cfg, layers_run=4)), CELL_DEPTH["qwen3_next"]
     try:
@@ -1143,7 +1152,31 @@ def test_qwen3_next_programs_hold_their_kernels_by_count(topo):
     assert decode == {"gdn_step": 3, "paged_decode": 1,
                       **_expert_layers(4)}, decode
     prefill = _kernel_counts(progs["prefill"]().compile().as_text())
-    assert prefill == {"gdn_scan": 3, **_expert_layers(4)}, prefill
+    assert prefill == {"gdn_terms": 3, "gdn_scan": 3,
+                       **_expert_layers(4)}, prefill
+
+
+def test_a_six_layer_prefill_lowers_the_gdn_kernels_once(topo):
+    """The cell's prefill program at its published depth (G G G A twice: six
+    Gated DeltaNet layers), traced and lowered for the described v5e, not
+    compiled: the module holds ONE `gdn_terms` and ONE `gdn_scan` kernel,
+    each in a function of its own called six times, because their
+    `pallas_call`s are jitted on their own (`ops/gdn.py _terms_call`,
+    `_carry_call`). A kernel lowered once a layer is host time in every
+    bucket's warm-up, with every executable from the cache (PR 39 lost 13 s
+    of `setup_s` so: ROADMAP S5); `gdn_scan` was lowered six times before
+    PR 42."""
+    CELL_DEPTH["qwen3_next"], was = (
+        lambda cfg: dict(cfg, layers_run=8)), CELL_DEPTH["qwen3_next"]
+    try:
+        text = _cell_programs(
+            topo, "qwen3next_serve_mixed")["prefill"]().as_text()
+    finally:
+        CELL_DEPTH["qwen3_next"] = was
+    for kernel, fn in (("gdn_terms", "_terms_call"),
+                       ("gdn_scan", "_carry_call")):
+        assert text.count(f'kernel_name = "{kernel}"') == 1, kernel
+        assert len(re.findall(rf"call @{fn}\b", text)) == 6, fn
 
 
 @pytest.mark.parametrize("prog, kernels", [
