@@ -28,7 +28,7 @@ All four hold recurrent state (`recurrent=True`): `PagedEngine` serves them,
 admits a long prompt in chunks over the slot's own state (`prefill_chunk`
 without `prefix_cache`) and refuses `prefix_cache`, `spec_decode` and
 `fork()`, which need the state at a position that is not the sequence's end
-(ROADMAP M6); `SlotEngine` refuses them.
+(ROADMAP M6).
 """
 
 from typing import Optional

@@ -596,8 +596,8 @@ def paged_attention_reference(
     (b, max_blocks_per_slot * block_size) span and run masked attention.
 
     The span is the PER-SLOT capacity (sized to the request's own
-    context budget), not the pool — the slot engine's cost driver was
-    the pool-global [0, max_len) scan, which this path already removes.
+    context budget), not the pool — a flat cache's cost driver is the
+    batch-wide [0, max_len) scan, which this path already removes.
     It is also the correctness oracle for `_paged_walk_kernel` (and the
     int8 kernel) and the serving path on backends without the kernel (CPU
     tests; unpackable head shapes). An int8 pool dequantizes through

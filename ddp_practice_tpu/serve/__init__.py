@@ -2,20 +2,18 @@
 
 Layers (each its own module, composable and separately testable):
 
-- kv_slots.py  — slot-based KV-cache pool: fixed `(max_slots, max_len)`
-  cache, left-aligned admission at a shared write cursor, whole-row
-  scatter on admit, free-list slot reuse;
 - kv_pages.py  — PAGED KV-cache pool (vLLM-style): fixed-size blocks,
-  host block allocator + per-slot device page tables, slot-local
-  positions — no shared clock, per-page release, contexts past max_len;
-- engine.py    — SlotEngine/PagedEngine: bucketed jitted prefill-admit
-  + one jitted batched decode step; static shapes, so batch composition
-  churns with zero recompiles; per-slot finite-logits flag contains a
-  NaN to one request; one interface (admit_gate/admit/step_burst/
-  release) over both memory layouts;
+  host block and slot allocators + per-slot device page tables,
+  slot-local positions — no shared clock, per-page release, contexts
+  past max_len; the radix prefix cache over the blocks;
+- engine.py    — PagedEngine, the one engine: bucketed jitted
+  prefill-admit + one jitted batched decode step; static shapes, so
+  batch composition churns with zero recompiles; per-slot finite-logits
+  flag contains a NaN to one request; driven through
+  admit_gate/admit/step_burst/release;
 - spec.py      — speculative decoding drafts WITHOUT a draft model:
   DraftSource interface + the n-gram prompt-lookup drafter (host-side
-  suffix match over prompt+generated tokens); the paged engine verifies
+  suffix match over prompt+generated tokens); the engine verifies
   k drafted tokens in ONE jitted forward (the s>1 paged-prefill path)
   with exact greedy acceptance and block-aware KV rollback —
   token-identical to plain decoding, fewer sequential steps;
@@ -47,7 +45,7 @@ Layers (each its own module, composable and separately testable):
   mode (the worker pushes completion/heartbeat snapshots; the
   router select()s on the stream fds — no polling in steady state);
 - worker.py    — one replica as a real OS PROCESS: own single-process
-  jax runtime, Scheduler+Slot/PagedEngine built from a JSON
+  jax runtime, Scheduler+PagedEngine built from a JSON
   WorkerSpec, warmed before its WORKER_READY line, serving the RPC
   seam plus its own /metrics /healthz /flight endpoints;
 - supervisor.py— worker lifecycles: spawn/waitpid, restart with
@@ -60,7 +58,7 @@ Layers (each its own module, composable and separately testable):
   fleet counters (retries, failovers, sheds-by-reason, breaker state,
   brown-out), emitted through the process-0 gate (utils/metrics.py
   render_text() serves the same registry as Prometheus exposition);
-  request-lifecycle SPANS live in utils/trace.py: scheduler/engines/
+  request-lifecycle SPANS live in utils/trace.py: scheduler/engine/
   router all take an optional TraceRecorder (`--trace-out` exports
   Chrome trace JSON; tools/check_traces.py validates it), and every
   Completion carries a queue/prefill/decode/stall flight record;
@@ -99,11 +97,7 @@ from ddp_practice_tpu.serve.fairshare import (
     federate_tenant_reports,
     jains_index,
 )
-from ddp_practice_tpu.serve.engine import (
-    EngineConfig,
-    PagedEngine,
-    SlotEngine,
-)
+from ddp_practice_tpu.serve.engine import EngineConfig, PagedEngine
 from ddp_practice_tpu.serve.faults import (
     FaultInjector,
     FaultPlan,
@@ -119,6 +113,7 @@ from ddp_practice_tpu.serve.health import (
 from ddp_practice_tpu.serve.kv_pages import (
     BlockAllocator,
     RadixPrefixCache,
+    SlotAllocator,
 )
 from ddp_practice_tpu.serve.frontdoor import (
     Frontdoor,
@@ -126,7 +121,6 @@ from ddp_practice_tpu.serve.frontdoor import (
     RouterDriver,
     sse_request,
 )
-from ddp_practice_tpu.serve.kv_slots import SlotAllocator
 from ddp_practice_tpu.serve.metrics import (
     FrontdoorMetrics,
     RouterMetrics,
@@ -211,7 +205,6 @@ __all__ = [
     "Scheduler",
     "ServeMetrics",
     "SlotAllocator",
-    "SlotEngine",
     "Supervisor",
     "SupervisorConfig",
     "TenantLedger",

@@ -382,18 +382,17 @@ def kv_summary(engine, publisher: Optional[DigestPublisher] = None) -> dict:
     token counters, and — when a publisher is attached — the prefix
     digest frame cache-aware routing scores against. ONE builder for
     the worker and the in-process handle, so the Router sees identical
-    shapes on both sides of the RPC seam. Zeros for a slot engine (no
-    paged pool), matching ServeMetrics.on_tick's getattr guards."""
-    blocks = getattr(engine, "blocks", None)
-    radix = getattr(engine, "radix", None)
-    hit = getattr(radix, "hit_tokens", 0) if radix is not None else 0
-    miss = getattr(radix, "miss_tokens", 0) if radix is not None else 0
+    shapes on both sides of the RPC seam. The radix fields read zero
+    without a prefix cache (`engine.radix` is None)."""
+    blocks = engine.blocks
+    radix = engine.radix
+    hit = radix.hit_tokens if radix is not None else 0
+    miss = radix.miss_tokens if radix is not None else 0
     out = {
-        "blocks_used": blocks.num_used if blocks is not None else 0,
-        "blocks_shared": blocks.num_shared if blocks is not None else 0,
+        "blocks_used": blocks.num_used,
+        "blocks_shared": blocks.num_shared,
         # minus the garbage block, same accounting as the gauges
-        "blocks_total": (blocks.num_blocks - 1
-                         if blocks is not None else 0),
+        "blocks_total": blocks.num_blocks - 1,
         "evictable": radix.evictable() if radix is not None else 0,
         "hit_tokens": hit,
         "miss_tokens": miss,

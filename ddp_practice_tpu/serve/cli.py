@@ -1,8 +1,10 @@
 """`python -m ddp_practice_tpu.cli serve`: serve prompts from a checkpoint.
 
 Loads a trained LM checkpoint (generate.py load_lm), puts every --prompt
-through one SlotEngine behind a Scheduler (continuous batching: the
-prompts share the decode batch at slot granularity), prints each
+through one PagedEngine behind a Scheduler (continuous batching: the
+prompts share the decode batch at slot granularity; the block pool is
+sized so that every slot can hold its bucketed prompt and
+--max_new_tokens at once), prints each
 completion with its status and time to first token, then the run's
 ServeMetrics as one log line. Measurement is not done here: the
 benchmark is perf/run.py (BENCHMARK.json), its results are in PERF.md.
@@ -52,7 +54,7 @@ def main(argv=None) -> int:
 
     from ddp_practice_tpu.generate import load_lm
     from ddp_practice_tpu.inference import decode_bytes, encode_bytes
-    from ddp_practice_tpu.serve.engine import EngineConfig, SlotEngine
+    from ddp_practice_tpu.serve.engine import EngineConfig, PagedEngine
     from ddp_practice_tpu.serve.metrics import ServeMetrics
     from ddp_practice_tpu.serve.scheduler import Request, Scheduler
     from ddp_practice_tpu.utils.backend import enable_compile_cache
@@ -68,14 +70,19 @@ def main(argv=None) -> int:
     bucket = 8
     while bucket < max_prompt:
         bucket *= 2
-    engine = SlotEngine(
+    burst = args.decode_burst or 1
+    # a slot's span: the bucketed prompt, the tokens asked for, and the
+    # last burst's overshoot (the scheduler budgets whole bursts)
+    span = bucket + args.max_new_tokens + burst
+    engine = PagedEngine(
         model, params,
         EngineConfig(
             max_slots=args.max_slots,
             prompt_buckets=(bucket,),
             temperature=args.temperature, top_k=args.top_k,
             top_p=args.top_p, eos_id=args.eos_id,
-            decode_burst=args.decode_burst or 1,
+            decode_burst=burst,
+            max_blocks_per_slot=-(-span // EngineConfig.block_size),
         ),
         batch_stats=batch_stats,
     )

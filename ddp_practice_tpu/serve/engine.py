@@ -1,26 +1,31 @@
-"""Continuous-batching engine core: two jitted programs, zero recompiles.
+"""Continuous-batching engine core: jitted programs of static shape, zero
+recompiles.
 
 The one-shot path (inference.make_generate_fn) compiles prefill + a
 `lax.scan` of decode steps into ONE program per (batch, prompt_len,
 max_new_tokens) triple — a new request shape means a new XLA program,
 and nothing can join until the scan returns. This engine splits the
-same `decode_apply` primitive into two separately-jitted functions with
+same `decode_apply` primitive into separately-jitted functions with
 STATIC shapes, so batch composition can churn at token granularity:
 
 - `prefill+admit` (one compile per prompt bucket width): run the new
-  request's prompt through a batch-1 scratch cache positioned to end at
-  the pool cursor, then scatter the scratch rows + next-token logits
-  into the pool at the slot index (kv_slots.write_slot);
+  request's prompt through a batch-1 scratch cache at slot-local
+  positions [0, w), then scatter the scratch rows into the slot's
+  freshly allocated blocks of the paged pool (serve/kv_pages.py) and the
+  next-token logits into the slot's row; with the prefix cache or
+  chunked prefill the prompt is instead appended through the slot's page
+  table (`_prefix_prefill`, one compile per suffix bucket);
 - `decode step` (one compile, ever): sample one token per slot from the
-  carried last-logits, apply the model batch-wide at s=1, return new
-  logits/tokens. Free slots ride along emitting pad tokens — their rows
-  are garbage by construction and invisible by masking. A lax.scan runs
-  `decode_burst` such steps per dispatch (multi-step scheduling) so the
-  constant host/dispatch cost amortizes over K tokens; releases become
-  burst-granular, the tokens do not change (pinned in
-  tests/test_serve_engine.py).
+  carried last-logits, apply the model batch-wide at s=1 with every slot
+  writing at its OWN position through its page-table row, return new
+  logits/tokens. Free slots ride along emitting pad tokens — their
+  writes land in the garbage block and are invisible by masking. A
+  lax.scan runs `decode_burst` such steps per dispatch (multi-step
+  scheduling) so the constant host/dispatch cost amortizes over K
+  tokens; releases become burst-granular, the tokens do not change
+  (pinned in tests/test_serve_engine.py).
 
-Prompts are LEFT-padded into a small set of bucket widths
+Prompts are padded into a small set of bucket widths
 (EngineConfig.prompt_buckets), so the prefill jit cache is bounded by
 the bucket count however many distinct prompt lengths arrive — the
 "no recompilation churn" property the scheduler tests pin via
@@ -29,15 +34,14 @@ the bucket count however many distinct prompt lengths arrive — the
 Sampling is per-slot (each request carries its own fold_in'd PRNG
 chain), so a request's tokens do not depend on what else shares the
 batch — the property that makes continuous batching transparent to
-clients. Greedy decode is bit-identical to the one-shot generator
+clients. Greedy decode matches the one-shot generator
 (tests/test_serve_equivalence.py) because both paths run the same
 `decode_apply` and the same `sample_logits`.
 
-Two engines share this contract behind one interface (`admit_gate` /
-`admit` / `step_burst` / `release` / `compile_stats`): SlotEngine over
-the shared-cursor slot pool (kv_slots.py) and PagedEngine over the
-block-granular paged pool (kv_pages.py — per-slot page tables, no
-global clock, contexts past max_len). The scheduler drives either.
+There is ONE engine, `PagedEngine`; the scheduler drives it through
+`admit_gate` / `admit` / `step_burst` / `release`, and `serve`
+(serve/cli.py), the in-process router and the worker process all build
+it — the engine the benchmark measures.
 """
 
 from __future__ import annotations
@@ -62,6 +66,7 @@ from ddp_practice_tpu.serve.kv_pages import (
     LATENT_LEAF,
     BlockAllocator,
     RadixPrefixCache,
+    SlotAllocator,
     copy_block,
     leaf_kind,
     leaf_name,
@@ -71,11 +76,6 @@ from ddp_practice_tpu.serve.kv_pages import (
     scatter_prompt_blocks,
 )
 from ddp_practice_tpu.serve.spec import DraftSource, PromptLookupDraft
-from ddp_practice_tpu.serve.kv_slots import (
-    SlotAllocator,
-    set_cursor,
-    write_slot,
-)
 from ddp_practice_tpu.utils import backend
 from ddp_practice_tpu.utils.trace import (
     ENGINE_LANE,
@@ -89,15 +89,13 @@ class EngineConfig:
     """Compile-time serving knobs (all closed over by the jitted fns)."""
 
     max_slots: int = 4
-    # pool positions per slot; 0 = the model's max_len. For PagedEngine
-    # this sizes the DEFAULTS of the block pool (num_blocks /
-    # max_blocks_per_slot below), not a hard span — per-slot capacity is
+    # pool positions per slot; 0 = the model's max_len. This sizes the
+    # DEFAULTS of the block pool (num_blocks / max_blocks_per_slot
+    # below), not a hard span — per-slot capacity is
     # max_blocks_per_slot * block_size and may exceed the model's
     # max_len (RoPE positions are unbounded).
     max_len: int = 0
-    # LEFT-pad prompt widths for the bucketed prefill compile cache; the
-    # largest bucket is also the base cursor (admission always has room
-    # to place a full-width prompt behind the cursor)
+    # padded prompt widths for the bucketed prefill compile cache
     prompt_buckets: Tuple[int, ...] = (8, 16, 32, 64)
     temperature: float = 0.0
     top_k: int = 0
@@ -112,7 +110,7 @@ class EngineConfig:
     # request vs the static baseline's E[max - asked]. K=1 is exact
     # token-granularity scheduling (the deterministic-test setting).
     decode_burst: int = 1
-    # ---- PagedEngine knobs (ignored by SlotEngine) ----
+    # ---- the block pool ----
     # positions per pool block; the allocation granule. Multiples of 8
     # keep the TPU kernel's sublane tiling happy (ops/decode_attention).
     block_size: int = 16
@@ -133,7 +131,7 @@ class EngineConfig:
     # `_prefix_prefill`, not the scratch+scatter pair — greedy tokens
     # stay equivalent (RoPE; pinned in tests/test_serve_equivalence.py).
     prefix_cache: bool = False
-    # ---- speculative decoding (PagedEngine only, greedy only) ----
+    # ---- speculative decoding (greedy only) ----
     # draft-free speculation (serve/spec.py): a host-side prompt-lookup
     # drafter proposes up to spec_k tokens per slot and ONE jitted
     # verify dispatch (`step_verify`) scores the whole window — a short
@@ -148,7 +146,7 @@ class EngineConfig:
     # prompt-lookup n-gram match lengths, tried longest-first
     spec_ngram_max: int = 3
     spec_ngram_min: int = 1
-    # ---- per-slot sampling (both engines) ----
+    # ---- per-slot sampling ----
     # temperature / top_k / top_p stop being compile-time constants:
     # every slot carries its own (temp, k, p) in small device arrays
     # shipped per dispatch (like the page table), and the decode
@@ -160,9 +158,9 @@ class EngineConfig:
     # spec_decode: exact acceptance is greedy string matching, which
     # per-request temperatures would break.
     per_slot_sampling: bool = False
-    # ---- chunked prefill (PagedEngine; with prefix_cache, or without it
-    # for a model with recurrent state: chunks over the slot's own state,
-    # every admission then a chunk admission at canonical positions) ----
+    # ---- chunked prefill (with prefix_cache, or without it for a model
+    # with recurrent state: chunks over the slot's own state, every
+    # admission then a chunk admission at canonical positions) ----
     # split long COLD prompts into chunks of at most this many tokens,
     # prefilled one chunk per scheduler tick interleaved with decode
     # bursts (Sarathi-style): a long admit no longer stalls every
@@ -185,9 +183,8 @@ class EngineConfig:
 
 def _sample_step(cfg: EngineConfig, last_logits, active, keys,
                  sampling=None):
-    """One sampling step shared by both engines: per-slot PRNG chains,
-    greedy fast path, pad tokens for free slots. Returns
-    (tokens int32, new_keys).
+    """One sampling step: per-slot PRNG chains, greedy fast path, pad
+    tokens for free slots. Returns (tokens int32, new_keys).
 
     `sampling` is None (params baked from cfg — the legacy single-
     compile path, pytree-empty so it costs no trace arg) or a triple of
@@ -258,30 +255,30 @@ def _await_dispatch(*state) -> None:
 
 def warm_engine(engine, widths=None) -> None:
     """Compile an engine's programs outside any timed/traced window:
-    one admit per bucket width in play + one decode burst, then release
-    and rewind. THE one warmup recipe — the in-process router's
-    ReplicaHandle and the worker process (serve/worker.py) both call
-    it, so a restarted replica re-warms exactly like a fresh one.
-    The admit budgets only the one warmup burst: a paged engine's
-    default admit reserves its whole per-slot capacity, which an
-    oversubscribed block pool can't cover even though the gated
-    scheduler path serves it fine."""
+    one admit per bucket width in play + one decode burst, then release.
+    THE one warmup recipe — the in-process router's ReplicaHandle and
+    the worker process (serve/worker.py) both call it, so a restarted
+    replica re-warms exactly like a fresh one.
+    The admit budgets only the one warmup burst: the default admit
+    reserves the whole per-slot capacity, which an oversubscribed block
+    pool can't cover even though the gated scheduler path serves it
+    fine."""
     for w in widths or engine.buckets:
         slot = engine.admit([1] * w,
                             max_positions=engine.config.decode_burst)
         # chunk-admitted prompts (prefill_chunk) activate only once
         # every chunk has run — drive the chunk program to completion
         # so its compiles land in warmup too
-        while getattr(engine, "is_prefilling", lambda s: False)(slot):
+        while engine.is_prefilling(slot):
             engine.prefill_step(slot)
         engine.step_burst()
         engine.release(slot)
-        if getattr(engine, "radix", None) is not None:
+        if engine.radix is not None:
             # the warm-up prompt's blocks must not stay cached: the next
             # width's prompt would match them and compile a NARROWER
             # suffix bucket than its own, and no request wants them
             engine.radix.clear()
-    if getattr(engine, "drafter", None) is not None:
+    if engine.drafter is not None:
         # speculation on: the verify program is a THIRD compile that
         # must also land outside the timed/traced window. An all-ones
         # prompt makes the lookup drafter propose a full window (every
@@ -297,501 +294,27 @@ def warm_engine(engine, widths=None) -> None:
         engine.spec_drafted_tokens = 0
         engine.spec_accepted_tokens = 0
         engine.spec_dispatches = 0
-    if getattr(engine, "moe_rows_routed", 0):
-        # warmup picks belong to no request either
-        engine.moe_rows_held = engine.moe_rows_routed = 0
-        engine.moe_rows_moved = engine.moe_rows_layout = 0
-    if getattr(engine, "ssm_scan_tokens", 0):
-        engine.ssm_scan_tokens = engine.ssm_scan_padded_tokens = 0
-    if getattr(engine, "sparse_pages_held", 0):
-        engine.sparse_pages_walked = engine.sparse_pages_held = 0
-    engine.reset_epoch()
+    # warmup picks, scans and walks belong to no request either
+    engine.moe_rows_held = engine.moe_rows_routed = 0
+    engine.moe_rows_moved = engine.moe_rows_layout = 0
+    engine.ssm_scan_tokens = engine.ssm_scan_padded_tokens = 0
+    engine.sparse_pages_walked = engine.sparse_pages_held = 0
 
 
-class _EngineBase:
-    """What the two memory layouts share: the prompt-bucket map, slot
-    accounting over a SlotAllocator at `self.allocator`, the
-    token-granular `step()` veneer over `step_burst`, the
-    two-jitted-programs observable (`self._prefill_jit` /
-    `self._decode_jit` set by each subclass __init__), and the optional
-    tracer (`set_tracer`): per-dispatch `prefill` / `decode_burst` /
-    `verify` lane spans, each split into what the host prepares
-    (`prefill_host`, `burst_plan`), the jitted call (`prefill_dispatch`,
-    `burst_dispatch`) and the wait for the device (`burst_readback`).
-    `set_tracer` also hands the recorder `jax.profiler.TraceAnnotation`
-    (TraceRecorder.set_annotate), so while a profiler session is open
-    every one of these spans is mirrored on the profiler's host line
-    under a FIXED name (`serve:prefill`, `serve:burst_dispatch`, ...;
-    request ids are span attrs, never names). tracer=None (default)
-    keeps the dispatch path free of spans and annotations alike.
-
-    The jitted methods' names are a contract too: a device trace shows
-    each program as `jit_<method name>` ("XLA Modules" line), and the
-    benchmark's readers find the prefill and decode programs by the
-    substrings `prefill_admit`, `prefix_prefill`, `decode_burst` and
-    `verify` (PERF.md §3; pinned by tests/test_span_tree.py). Rename
-    `_prefill_admit` / `_prefix_prefill` / `_decode_burst` / `_verify`
-    only together with those readers."""
-
-    # set by each subclass __init__ via set_tracer defaults
-    tracer = None
-    replica = 0
-    # per-burst surfacing for the streaming plane: how many decode
-    # dispatches this engine ever ran and how many slots were live in
-    # the last one. The scheduler stamps `burst_seq` onto each
-    # TokenChunk's telemetry line, so per-chunk flight accounting can
-    # tell "no bursts ran" (a stalled engine) from "bursts ran without
-    # this request" (preempted / queued) when attributing a resume gap.
-    burst_seq = 0
-    last_burst_active = 0
-    # (picks that landed on held experts, held experts with a row summed
-    # over expert layers and steps, most rows one expert took) of the last
-    # decode burst; None for a model without held experts (PagedEngine)
-    last_burst_experts = None
-    last_burst_sparse = None
-
-    def set_tracer(self, tracer, replica: int = 0) -> None:
-        """Attach a utils/trace.py TraceRecorder; `replica` is this
-        engine's pid in the exported timeline (lane conventions:
-        trace.label_replica)."""
-        self.tracer = tracer
-        self.replica = replica
-        if tracer is not None:
-            tracer.set_annotate(jax.profiler.TraceAnnotation, "serve")
-
-    def _span(self, name: str, tid: int = ENGINE_LANE, **attrs):
-        """One of this engine's lane spans (callers have tested
-        `tracer.enabled`). Engine-lane spans name no request, so under
-        head sampling they ride only while a SAMPLED request is in
-        flight (`sampled_only`) — otherwise an idle 1%-sampled fleet
-        would still record every burst and the plane would never
-        shrink; a slot-lane span carries its request's trace_id and
-        follows that request's own verdict."""
-        return self.tracer.span(name, pid=self.replica, tid=tid,
-                                sampled_only="trace_id" not in attrs,
-                                **attrs)
-
-    def _prefill_spans(self, name: str, slot: int, trace_id: str,
-                       **attrs) -> tuple:
-        """(`name`, its `prefill_host` half, its `prefill_dispatch`
-        half) on the slot's lane, all under the request's trace_id."""
-        lane = SLOT_LANE_BASE + slot
-        return (self._span(name, lane, trace_id=trace_id, slot=slot,
-                           **attrs),
-                self._span("prefill_host", lane, trace_id=trace_id),
-                self._span("prefill_dispatch", lane, trace_id=trace_id))
-
-    def _burst_spans(self, name: str, **attrs) -> tuple:
-        """(`name`, its `burst_dispatch` half, its `burst_readback`
-        half) on the engine lane."""
-        return (self._span(name, active=int(np.count_nonzero(self._active)),
-                           **attrs),
-                self._span("burst_dispatch"), self._span("burst_readback"))
-
-    def bucket_for(self, prompt_len: int) -> int:
-        """Smallest bucket width holding `prompt_len` (raises if none)."""
-        for w in self.buckets:
-            if prompt_len <= w:
-                return w
-        raise ValueError(
-            f"prompt length {prompt_len} exceeds the largest bucket "
-            f"{self.buckets[-1]}"
-        )
-
-    def fits_prompt(self, prompt_len: int) -> bool:
-        """Can this engine EVER serve a prompt of this length? The
-        feasibility probe the router's salvage/failover path asks
-        before re-targeting a request — bucket-bounded here; the
-        chunk-capable PagedEngine overrides it with a capacity bound."""
-        try:
-            self.bucket_for(prompt_len)
-            return True
-        except ValueError:
-            return False
-
-    def _sampling_args(self):
-        """Per-slot sampling params for the next decode dispatch: a
-        triple of (s,) device arrays when per_slot_sampling, else None.
-        None is an EMPTY pytree, so the legacy path's decode program
-        keeps its single compile and the per-slot path adds exactly
-        one — the churn pins (compile_stats) cover both."""
-        if not self.config.per_slot_sampling:
-            return None
-        return (jnp.asarray(self._temp), jnp.asarray(self._topk),
-                jnp.asarray(self._topp))
-
-    def _set_sampling(self, slot: int, sampling) -> None:
-        """Record a slot's sampling params at admit. `sampling` is
-        (temperature, top_k, top_p) with None fields falling back to
-        the engine config — the scheduler passes a request's overrides
-        verbatim. Overrides without per_slot_sampling raise: silently
-        sampling at the WRONG params is the one outcome this must
-        never produce (the decode program bakes the config values in)."""
-        cfg = self.config
-        t, k, p = sampling if sampling is not None else (None, None, None)
-        t = cfg.temperature if t is None else float(t)
-        k = cfg.top_k if k is None else int(k)
-        p = cfg.top_p if p is None else float(p)
-        if not cfg.per_slot_sampling and (
-                t != cfg.temperature or k != cfg.top_k
-                or p != cfg.top_p):
-            raise ValueError(
-                "per-request sampling params need "
-                "EngineConfig.per_slot_sampling=True"
-            )
-        self._temp[slot] = t
-        self._topk[slot] = k
-        self._topp[slot] = p
-
-    @property
-    def num_active(self) -> int:
-        return self.allocator.num_used
-
-    @property
-    def num_free(self) -> int:
-        return self.allocator.num_free
-
-    def step(self) -> np.ndarray:
-        """One decode step for the whole pool; tokens (max_slots,).
-        Token-granular stepping — requires decode_burst=1 (use
-        step_burst for the amortized path)."""
-        if self.config.decode_burst != 1:
-            raise RuntimeError("step() needs decode_burst=1")
-        return self.step_burst()[0]
-
-    def compile_stats(self) -> dict:
-        """Jit cache sizes — the no-recompilation-churn observable.
-
-        After warmup (one admit per bucket width in play, one decode
-        dispatch), these counts must stay CONSTANT however many requests
-        churn through (pinned via the conftest `compile_guard` helper
-        and tests/test_serve_scheduler.py)."""
-        return {
-            "prefill_compiles": self._prefill_jit._cache_size(),
-            "decode_compiles": self._decode_jit._cache_size(),
-        }
-
-
-class SlotEngine(_EngineBase):
-    """Slot-granular admission + batched single-token decode.
-
-    Pure mechanism: WHAT to admit/release and WHEN is the scheduler's
-    job (serve/scheduler.py); this class owns the device state (cache
-    pool, last-logits, attention starts, per-slot PRNG keys) and the two
-    jitted programs. All host<->device traffic per step is one token
-    vector readback.
-    """
-
-    def __init__(self, model, params, config: EngineConfig = EngineConfig(),
-                 *, batch_stats: Any = None) -> None:
-        if getattr(model, "pos_emb", None) != "rope":
-            raise ValueError(
-                "SlotEngine needs pos_emb='rope' — slot admission "
-                "left-aligns prompts at arbitrary cache offsets, which "
-                "only relative positions survive (models/lm.py attn_start)"
-            )
-        if getattr(model, "recurrent", False):
-            raise ValueError(
-                "SlotEngine serves no model with recurrent state: its "
-                "pool is positions under one cursor — use PagedEngine, "
-                "which keeps a state a slot beside the pages")
-        if not config.prompt_buckets:
-            raise ValueError("prompt_buckets must be non-empty")
-        if config.spec_decode:
-            raise ValueError(
-                "spec_decode needs PagedEngine — the verify window is a "
-                "paged prefill through per-slot page tables, which the "
-                "shared-cursor slot pool cannot express"
-            )
-        if config.prefill_chunk:
-            raise ValueError(
-                "prefill_chunk needs PagedEngine with prefix_cache — "
-                "chunks append at canonical slot-local positions "
-                "through the page table, which the shared-cursor slot "
-                "pool cannot express"
-            )
-        self.model = model
-        self.params = params
-        self.batch_stats = batch_stats
-        self.config = config
-        self.max_len = config.max_len or model.max_len
-        self.buckets = tuple(sorted(set(config.prompt_buckets)))
-        self.base_cursor = self.buckets[-1]
-        if self.base_cursor >= self.max_len:
-            raise ValueError(
-                f"largest prompt bucket {self.base_cursor} leaves no decode "
-                f"headroom in max_len {self.max_len}"
-            )
-        s = config.max_slots
-        self.allocator = SlotAllocator(s)
-        self.cursor = self.base_cursor  # host mirror of the device cursor
-        self._cache = set_cursor(
-            make_cache(model, s, self.max_len), self.base_cursor
-        )
-        self._last_logits = jnp.zeros((s, model.vocab_size), model.dtype)
-        self._attn_starts = jnp.zeros((s,), jnp.int32)
-        self._keys = jnp.zeros((s, 2), jnp.uint32)
-        self._active = np.zeros((s,), bool)
-        # per-slot sampling mirrors (host side, shipped per dispatch
-        # like _active when per_slot_sampling is on); config-filled so
-        # a slot admitted without overrides samples exactly as before
-        self._temp = np.full((s,), config.temperature, np.float32)
-        self._topk = np.full((s,), config.top_k, np.int32)
-        self._topp = np.full((s,), config.top_p, np.float32)
-        self.last_finite = np.ones((1, s), bool)  # updated per step_burst
-        self._slot_trace: dict = {}  # slot -> trace_id (tracer attached)
-        if config.decode_burst < 1:
-            raise ValueError("decode_burst must be >= 1")
-        self._prefill_jit = jax.jit(self._prefill_admit)
-        self._decode_jit = jax.jit(
-            self._decode_burst, donate_argnums=_decode_donate()
-        )
-
-    # ---------------------------------------------------------------- jitted
-    def _prefill_admit(self, params, pool, last_logits, attn_starts,
-                       tokens, start, attn_start, slot):
-        """tokens (1, w) left-padded; start = cursor - w; one compile per w."""
-        scratch = set_cursor(make_cache(self.model, 1, self.max_len), start)
-        scratch, logits = decode_apply(
-            self.model, params, scratch, tokens,
-            attn_start=attn_start[None], batch_stats=self.batch_stats,
-        )
-        pool = write_slot(pool, scratch, slot)
-        with jax.named_scope("sample"):
-            last_logits = lax.dynamic_update_slice(
-                last_logits, logits[:, -1].astype(last_logits.dtype),
-                (slot, 0),
-            )
-        attn_starts = lax.dynamic_update_slice(
-            attn_starts, attn_start[None], (slot,)
-        )
-        return pool, last_logits, attn_starts
-
-    def _decode_body(self, params, pool, last_logits, attn_starts,
-                     active, keys, sampling):
-        cfg = self.config
-        # per-slot finite-logits flag, computed on the SAMPLING INPUT: a
-        # non-finite row (bf16 overflow, poisoned cache) marks only its
-        # own slot — attention is per-row, so the NaN cannot cross slots,
-        # and this flag is what lets the scheduler finish ONE request
-        # with status "error" instead of serving garbage batch-wide
-        with jax.named_scope("sample"):
-            finite = jnp.isfinite(last_logits).all(axis=-1)
-            toks, new_keys = _sample_step(cfg, last_logits, active, keys,
-                                          sampling)
-        pool, logits = decode_apply(
-            self.model, params, pool, toks[:, None],
-            attn_start=attn_starts, batch_stats=self.batch_stats,
-        )
-        return pool, logits[:, -1], toks, new_keys, finite
-
-    def _decode_burst(self, params, pool, last_logits, attn_starts,
-                      active, keys, sampling):
-        """lax.scan of `decode_burst` single-token steps per dispatch —
-        the host-overhead amortizer (multi-step scheduling). Returns
-        tokens (K, max_slots); K=1 is plain token-granular stepping."""
-
-        def body(carry, _):
-            pool, last_logits, keys = carry
-            pool, last_logits, toks, keys, finite = self._decode_body(
-                params, pool, last_logits, attn_starts, active, keys,
-                sampling,
-            )
-            return (pool, last_logits, keys), (toks, finite)
-
-        (pool, last_logits, keys), (toks, finite) = lax.scan(
-            body, (pool, last_logits, keys), None,
-            length=self.config.decode_burst,
-        )
-        return pool, last_logits, toks, keys, finite
-
-    # ----------------------------------------------------------------- host
-    @property
-    def headroom(self) -> int:
-        """Decode positions left before the pool cursor hits max_len."""
-        return self.max_len - self.cursor
-
-    def admit_gate(self, prompt_len: int, needed_positions: int,
-                   prompt: Optional[Sequence[int]] = None) -> str:
-        """Admission verdict for a request needing `needed_positions`
-        decode positions (burst-rounded by the scheduler):
-        "ok" = admit now; "later" = cannot yet (positions will free —
-        here, after a drain + `make_room` rewind); "never" = can never
-        run on this engine (prompt outgrows every bucket, or more
-        positions than a fresh pool holds). `prompt` is accepted for
-        interface parity with PagedEngine (whose prefix cache probes
-        the tokens themselves) and ignored here."""
-        try:
-            self.bucket_for(prompt_len)
-        except ValueError:
-            return "never"
-        if needed_positions > self.max_len - self.base_cursor:
-            return "never"
-        if self.headroom < needed_positions:
-            return "later"
-        return "ok"
-
-    def make_room(self, prompt_len: Optional[int] = None,
-                  needed_positions: Optional[int] = None,
-                  prompt: Optional[Sequence[int]] = None) -> bool:
-        """Try to create admission headroom; True if anything changed.
-        Positions are a global resource under the shared cursor — the
-        only lever is rewinding the pool clock once every slot is free
-        (the scheduler drains, then calls this), so the blocked
-        request's shape (used by PagedEngine for targeted cache aging)
-        is accepted for interface parity and ignored. The paged engine
-        has no drain equivalent: its blocks free individually at
-        release."""
-        if self.allocator.num_used == 0 and self.cursor != self.base_cursor:
-            self.reset_epoch()
-            return True
-        return False
-
-    def admit(self, prompt: Sequence[int], *, seed: int = 0,
-              max_positions: Optional[int] = None,
-              trace_id: Optional[str] = None,
-              sampling: Optional[Tuple] = None) -> int:
-        """Prefill `prompt` into a free slot; returns the slot index.
-
-        The prompt joins exactly where the running batch is: its last
-        token's K/V lands at `cursor - 1`, so the NEXT decode step
-        produces its first generated token together with everyone
-        else's. Raises if no slot is free or the prompt outgrows the
-        buckets — admission POLICY (queueing, shedding) lives in the
-        scheduler. `max_positions` is accepted for engine-interface
-        parity with PagedEngine (which reserves blocks per request) and
-        ignored here: slot-pool positions are a global resource.
-        `trace_id` names the prefill span / profiler annotation when a
-        tracer is attached. `sampling` = per-request (temperature,
-        top_k, top_p) overrides, None fields defaulting to the config
-        (needs EngineConfig.per_slot_sampling).
-        """
-        p = len(prompt)
-        if p == 0:
-            raise ValueError("prompt must contain at least one token")
-        w = self.bucket_for(p)
-        slot = self.allocator.alloc()
-        if slot is None:
-            raise RuntimeError("no free slot — scheduler must gate admits")
-        try:
-            self._set_sampling(slot, sampling)
-        except ValueError:
-            self.allocator.free(slot)
-            raise
-        start = self.cursor - w
-        assert start >= 0, (self.cursor, w)  # cursor >= base >= every bucket
-        tr = self.tracer
-        if tr is not None and tr.enabled:
-            tid = trace_id or f"slot{slot}"
-            self._slot_trace[slot] = tid
-            span, host, disp = self._prefill_spans(
-                "prefill", slot, tid, bucket=w, prompt_len=p)
-        else:
-            span = host = disp = _NULL
-        with span:
-            with host:
-                padded = np.full((1, w), self.config.pad_id, np.int32)
-                padded[0, w - p:] = np.asarray(prompt, np.int32)
-                args = (jnp.asarray(padded), jnp.int32(start),
-                        jnp.int32(self.cursor - p), jnp.int32(slot))
-            with disp:
-                (self._cache, self._last_logits,
-                 self._attn_starts) = self._prefill_jit(
-                    self.params, self._cache, self._last_logits,
-                    self._attn_starts, *args,
-                )
-                _await_dispatch(self._cache, self._last_logits,
-                                self._attn_starts)
-        # keyed by the REQUEST's seed alone (not the slot), so a
-        # request's sampled tokens are independent of where admission
-        # happened to place it — batch composition stays invisible
-        self._keys = self._keys.at[slot].set(jax.random.PRNGKey(seed))
-        self._active[slot] = True
-        return slot
-
-    def step_burst(self) -> np.ndarray:
-        """One dispatch of `decode_burst` steps; tokens (K, max_slots).
-
-        Advances the shared cursor by K positions. Entries for free
-        slots are pad_id; the scheduler maps active slots' token rows
-        back to their requests, decides EOS/length/deadline release,
-        and discards rows past a request's release point.
-        """
-        k = self.config.decode_burst
-        if self.headroom < k:
-            raise RuntimeError(
-                "pool positions exhausted — drain and reset_epoch()"
-            )
-        tr = self.tracer
-        if tr is not None and tr.enabled:
-            span, disp, read = self._burst_spans(
-                "decode_burst", burst=k, cursor=self.cursor)
-        else:
-            span = disp = read = _NULL
-        with span:
-            with disp:
-                (self._cache, self._last_logits, toks,
-                 self._keys, finite) = self._decode_jit(
-                    self.params, self._cache, self._last_logits,
-                    self._attn_starts,
-                    jnp.asarray(self._active), self._keys,
-                    self._sampling_args(),
-                )
-                _await_dispatch(self._cache, self._last_logits,
-                                self._keys)
-            self.cursor += k
-            with read:  # the host waits for the device here
-                toks, finite = jax.device_get((toks, finite))
-        self.burst_seq += 1
-        self.last_burst_active = int(np.count_nonzero(self._active))
-        # (K, max_slots) bool: False rows mark slots whose token this
-        # burst was sampled from non-finite logits — the scheduler
-        # finishes those requests with status "error"
-        self.last_finite = np.asarray(finite)
-        return np.asarray(toks)
-
-    def poison_slot(self, slot: int) -> None:
-        """Overwrite one slot's pending sampling input with NaN — the
-        deterministic stand-in for a numerical blow-up (serve/faults.py
-        `nan_logits`). Host-side, between dispatches; the next decode
-        burst's finite flag turns False for exactly this slot."""
-        self._last_logits = self._last_logits.at[slot].set(jnp.nan)
-
-    def release(self, slot: int) -> None:
-        """Free a slot. Pure bookkeeping: the next admission overwrites
-        the slot's entire cache row (kv_slots.write_slot), so no device
-        work happens at release time."""
-        self.allocator.free(slot)
-        self._active[slot] = False
-        self._slot_trace.pop(slot, None)
-
-    def reset_epoch(self) -> None:
-        """Rewind the shared cursor to the base (all slots must be free).
-
-        Positions are a global resource under the shared-cursor design;
-        when the scheduler has drained all active requests it rewinds
-        the clock instead of reallocating the pool. Stale K/V stays in
-        the buffers — every future admission wipes its whole slot row.
-        """
-        if self.allocator.num_used:
-            raise RuntimeError("reset_epoch with active slots")
-        self._cache = set_cursor(self._cache, self.base_cursor)
-        self._attn_starts = jnp.zeros_like(self._attn_starts)
-        self.cursor = self.base_cursor
-
-
-class PagedEngine(_EngineBase):
+class PagedEngine:
     """Paged-KV continuous batching: per-slot page tables, no shared clock.
 
-    Same two-jitted-programs contract and public surface as SlotEngine
-    (the scheduler drives either through `admit_gate` / `admit` /
-    `step_burst` / `release`), but the cache is a pool of fixed-size
-    blocks (serve/kv_pages.py) and every slot decodes at its OWN
-    slot-local write position:
+    Pure mechanism: WHAT to admit/release and WHEN is the scheduler's
+    job (serve/scheduler.py), which drives this class through
+    `admit_gate` / `admit` / `step_burst` / `release`; the class owns the
+    device state (the block pool, last-logits, per-slot PRNG keys) and
+    the jitted programs. The cache is a pool of fixed-size blocks
+    (serve/kv_pages.py) and every slot decodes at its OWN slot-local
+    write position:
 
     - `admit` prefills the bucketed prompt into a batch-1 contiguous
       scratch cache at positions [0, w) and scatters it into freshly
-      allocated blocks (one compile per bucket width, as before);
+      allocated blocks (one compile per bucket width);
     - `step_burst` appends each active slot's token at `lengths[slot]`
       through the page table and attends only that slot's occupied
       pages (ops/decode_attention.paged_decode_attention) — a step's
@@ -800,10 +323,10 @@ class PagedEngine(_EngineBase):
     - `release` DEREFS the slot's blocks (serve/kv_pages.py refcounts):
       a block shared with the prefix cache or a fork sibling survives,
       a sole-owned one returns to the free list. Nothing ever drains
-      and nothing rewinds (no reset_epoch here);
-    - a request may decode past the model's / slot engine's max_len:
-      per-slot capacity is `max_blocks_per_slot * block_size` and RoPE
-      positions are unbounded.
+      and nothing rewinds;
+    - a request may decode past the model's max_len: per-slot capacity
+      is `max_blocks_per_slot * block_size` and RoPE positions are
+      unbounded.
 
     PR 6 turned the pool into a MULTIPLIER instead of a partition:
 
@@ -830,7 +353,42 @@ class PagedEngine(_EngineBase):
       up because nobody holds blocks they may never use; the solo-fit
       admission gate ("never" when a request outgrows the whole pool)
       keeps the preemption cascade terminating.
-    """
+
+    The optional tracer (`set_tracer`) records per-dispatch `prefill` /
+    `decode_burst` / `verify` lane spans, each split into what the host
+    prepares (`prefill_host`, `burst_plan`), the jitted call
+    (`prefill_dispatch`, `burst_dispatch`) and the wait for the device
+    (`burst_readback`). `set_tracer` also hands the recorder
+    `jax.profiler.TraceAnnotation` (TraceRecorder.set_annotate), so
+    while a profiler session is open every one of these spans is
+    mirrored on the profiler's host line under a FIXED name
+    (`serve:prefill`, `serve:burst_dispatch`, ...; request ids are span
+    attrs, never names). tracer=None (default) keeps the dispatch path
+    free of spans and annotations alike.
+
+    The jitted methods' names are a contract too: a device trace shows
+    each program as `jit_<method name>` ("XLA Modules" line), and the
+    benchmark's readers find the prefill and decode programs by the
+    substrings `prefill_admit`, `prefix_prefill`, `decode_burst` and
+    `verify` (PERF.md §3; pinned by tests/test_span_tree.py). Rename
+    `_prefill_admit` / `_prefix_prefill` / `_decode_burst` / `_verify`
+    only together with those readers."""
+
+    tracer = None
+    replica = 0
+    # per-burst surfacing for the streaming plane: how many decode
+    # dispatches this engine ever ran and how many slots were live in
+    # the last one. The scheduler stamps `burst_seq` onto each
+    # TokenChunk's telemetry line, so per-chunk flight accounting can
+    # tell "no bursts ran" (a stalled engine) from "bursts ran without
+    # this request" (preempted / queued) when attributing a resume gap.
+    burst_seq = 0
+    last_burst_active = 0
+    # (picks that landed on held experts, held experts with a row summed
+    # over expert layers and steps, most rows one expert took) of the last
+    # decode burst; None for a model without held experts
+    last_burst_experts = None
+    last_burst_sparse = None
 
     def __init__(self, model, params, config: EngineConfig = EngineConfig(),
                  *, batch_stats: Any = None,
@@ -978,7 +536,10 @@ class PagedEngine(_EngineBase):
         self._last_logits = jnp.zeros((s, model.vocab_size), model.dtype)
         self._keys = jnp.zeros((s, 2), jnp.uint32)
         self._active = np.zeros((s,), bool)
-        # per-slot sampling mirrors — same contract as SlotEngine's
+        # per-slot sampling mirrors (host side, shipped per dispatch
+        # like _active when per_slot_sampling is on); config-filled, so
+        # a slot admitted without overrides samples under the config's
+        # own values
         self._temp = np.full((s,), config.temperature, np.float32)
         self._topk = np.full((s,), config.top_k, np.int32)
         self._topp = np.full((s,), config.top_p, np.float32)
@@ -1284,6 +845,117 @@ class PagedEngine(_EngineBase):
         return pool, last_logits, toks, accepted, finite
 
     # ----------------------------------------------------------------- host
+    def set_tracer(self, tracer, replica: int = 0) -> None:
+        """Attach a utils/trace.py TraceRecorder; `replica` is this
+        engine's pid in the exported timeline (lane conventions:
+        trace.label_replica)."""
+        self.tracer = tracer
+        self.replica = replica
+        if tracer is not None:
+            tracer.set_annotate(jax.profiler.TraceAnnotation, "serve")
+
+    def _span(self, name: str, tid: int = ENGINE_LANE, **attrs):
+        """One of this engine's lane spans (callers have tested
+        `tracer.enabled`). Engine-lane spans name no request, so under
+        head sampling they ride only while a SAMPLED request is in
+        flight (`sampled_only`) — otherwise an idle 1%-sampled fleet
+        would still record every burst and the plane would never
+        shrink; a slot-lane span carries its request's trace_id and
+        follows that request's own verdict."""
+        return self.tracer.span(name, pid=self.replica, tid=tid,
+                                sampled_only="trace_id" not in attrs,
+                                **attrs)
+
+    def _prefill_spans(self, name: str, slot: int, trace_id: str,
+                       **attrs) -> tuple:
+        """(`name`, its `prefill_host` half, its `prefill_dispatch`
+        half) on the slot's lane, all under the request's trace_id."""
+        lane = SLOT_LANE_BASE + slot
+        return (self._span(name, lane, trace_id=trace_id, slot=slot,
+                           **attrs),
+                self._span("prefill_host", lane, trace_id=trace_id),
+                self._span("prefill_dispatch", lane, trace_id=trace_id))
+
+    def _burst_spans(self, name: str, **attrs) -> tuple:
+        """(`name`, its `burst_dispatch` half, its `burst_readback`
+        half) on the engine lane."""
+        return (self._span(name, active=int(np.count_nonzero(self._active)),
+                           **attrs),
+                self._span("burst_dispatch"), self._span("burst_readback"))
+
+    def bucket_for(self, prompt_len: int) -> int:
+        """Smallest bucket width holding `prompt_len` (raises if none)."""
+        for w in self.buckets:
+            if prompt_len <= w:
+                return w
+        raise ValueError(
+            f"prompt length {prompt_len} exceeds the largest bucket "
+            f"{self.buckets[-1]}"
+        )
+
+    def fits_prompt(self, prompt_len: int) -> bool:
+        """Can this engine EVER serve a prompt of this length? The
+        feasibility probe the router's salvage/failover path asks
+        before re-targeting a request — bounded by the buckets, or, with
+        `prefill_chunk`, by capacity: any prompt whose tokens + one
+        decode position fit the per-slot capacity and the pool can be
+        chunk-prefilled."""
+        if self.config.prefill_chunk:
+            return (prompt_len + 1 <= self.max_context
+                    and self._blocks_for(prompt_len + 1)
+                    <= self.blocks.num_blocks - 1)
+        return prompt_len <= self.buckets[-1]
+
+    def _sampling_args(self):
+        """Per-slot sampling params for the next decode dispatch: a
+        triple of (s,) device arrays when per_slot_sampling, else None.
+        None is an EMPTY pytree, so the config-baked decode program
+        keeps its single compile and the per-slot path adds exactly
+        one — the churn pins (compile_stats) cover both."""
+        if not self.config.per_slot_sampling:
+            return None
+        return (jnp.asarray(self._temp), jnp.asarray(self._topk),
+                jnp.asarray(self._topp))
+
+    def _set_sampling(self, slot: int, sampling) -> None:
+        """Record a slot's sampling params at admit. `sampling` is
+        (temperature, top_k, top_p) with None fields falling back to
+        the engine config — the scheduler passes a request's overrides
+        verbatim. Overrides without per_slot_sampling raise: silently
+        sampling at the WRONG params is the one outcome this must
+        never produce (the decode program bakes the config values in)."""
+        cfg = self.config
+        t, k, p = sampling if sampling is not None else (None, None, None)
+        t = cfg.temperature if t is None else float(t)
+        k = cfg.top_k if k is None else int(k)
+        p = cfg.top_p if p is None else float(p)
+        if not cfg.per_slot_sampling and (
+                t != cfg.temperature or k != cfg.top_k
+                or p != cfg.top_p):
+            raise ValueError(
+                "per-request sampling params need "
+                "EngineConfig.per_slot_sampling=True"
+            )
+        self._temp[slot] = t
+        self._topk[slot] = k
+        self._topp[slot] = p
+
+    @property
+    def num_active(self) -> int:
+        return self.allocator.num_used
+
+    @property
+    def num_free(self) -> int:
+        return self.allocator.num_free
+
+    def step(self) -> np.ndarray:
+        """One decode step for the whole pool; tokens (max_slots,).
+        Token-granular stepping — requires decode_burst=1 (use
+        step_burst for the amortized path)."""
+        if self.config.decode_burst != 1:
+            raise RuntimeError("step() needs decode_burst=1")
+        return self.step_burst()[0]
+
     def _blocks_for(self, positions: int) -> int:
         return -(-positions // self.config.block_size)
 
@@ -1684,8 +1356,9 @@ class PagedEngine(_EngineBase):
                 self.radix.insert(
                     prompt, [int(b) for b in self._pt[slot, :n_full]]
                 )
-        # keyed by the REQUEST's seed alone, as in SlotEngine: placement
-        # must stay invisible to the sample stream
+        # keyed by the REQUEST's seed alone (not the slot), so a
+        # request's sampled tokens are independent of where admission
+        # happened to place it — batch composition stays invisible
         self._keys = self._keys.at[slot].set(jax.random.PRNGKey(seed))
         self._slot_seed[slot] = (seed,)
         self._fork_n.pop(slot, None)
@@ -1900,9 +1573,8 @@ class PagedEngine(_EngineBase):
         active slot oldest-first (growth may preempt — LIFO victims must
         still be ungrown, not half-grown). Stepping a slot past its
         admit-time `max_positions` budget raises BEFORE touching the
-        allocator (the analogue of SlotEngine's positions-exhausted
-        guard; the scheduler's burst-rounded max_positions never trips
-        it). Returns the number of blocks grown (the decode-burst
+        allocator (the scheduler's burst-rounded max_positions never
+        trips it). Returns the number of blocks grown (the decode-burst
         span's `blocks_grown` attribute)."""
         total_grown = 0
         order = sorted(np.flatnonzero(self._active),
@@ -2152,26 +1824,21 @@ class PagedEngine(_EngineBase):
         tokens) — can exceed the model's max_len, the paged headline."""
         return int(self._len[slot])
 
-    def fits_prompt(self, prompt_len: int) -> bool:
-        """Chunked mode unbinds servability from the bucket table: any
-        prompt whose tokens + one decode position fit the per-slot
-        capacity and the pool can be chunk-prefilled."""
-        if self.config.prefill_chunk:
-            return (prompt_len + 1 <= self.max_context
-                    and self._blocks_for(prompt_len + 1)
-                    <= self.blocks.num_blocks - 1)
-        return super().fits_prompt(prompt_len)
-
     def poison_slot(self, slot: int) -> None:
-        """NaN one slot's pending sampling input (serve/faults.py) —
-        identical contract to SlotEngine.poison_slot."""
+        """Overwrite one slot's pending sampling input with NaN — the
+        deterministic stand-in for a numerical blow-up (serve/faults.py
+        `nan_logits`). Host-side, between dispatches; the next decode
+        burst's finite flag turns False for exactly this slot."""
         self._last_logits = self._last_logits.at[slot].set(jnp.nan)
 
     def compile_stats(self) -> dict:
-        """The two PR-3 programs plus the PR-6 admission paths plus the
-        speculative verify program — all five counters must stay flat
-        under churn (prefix hits, CoW splits, preempt/readmit, verify
-        dispatches included; conftest `compile_guard`)."""
+        """Jit cache sizes — the no-recompilation-churn observable: the
+        prefill and decode programs, the prefix-cache admission paths
+        and the speculative verify program. After warmup (one admit per
+        bucket width in play, one decode dispatch) all five counters
+        must stay flat however many requests churn through (prefix
+        hits, CoW splits, preempt/readmit, verify dispatches included;
+        conftest `compile_guard`, tests/test_serve_scheduler.py)."""
         return {
             "prefill_compiles": self._prefill_jit._cache_size(),
             "decode_compiles": self._decode_jit._cache_size(),
@@ -2207,13 +1874,3 @@ class PagedEngine(_EngineBase):
         self._slot_trace.pop(slot, None)
         self._slot_seed.pop(slot, None)
         self._fork_n.pop(slot, None)
-
-    def reset_epoch(self) -> None:
-        """Interface parity with SlotEngine (the router calls this in
-        warmup() and replica restart()): there is no pool clock to
-        rewind — every release already returned its pages — so with all
-        slots free this is a no-op (the prefix cache deliberately
-        SURVIVES: warm prefixes are the point); with active slots it
-        raises, same contract as the slot pool."""
-        if self.allocator.num_used:
-            raise RuntimeError("reset_epoch with active slots")

@@ -1,17 +1,16 @@
 """Paged KV-cache pool: block-granular memory for continuous batching.
 
-The slot pool (kv_slots.py) shares ONE write cursor: every decode step
-consumes a position for all slots, the pool drains in
-`max_len - max_bucket` steps between epoch rewinds, and decode attention
-scans the whole `[0, max_len)` span every step, whatever the slots
-hold: the flat cache's cost follows its allocated span, not the live
-context. This module replaces positions-as-a-global-
-resource with vLLM-style paging:
+A flat decode cache (inference.make_cache, the one-shot generator's)
+keeps one write cursor for the whole batch, and decode attention scans
+the whole `[0, max_len)` span every step, whatever the rows hold: its
+cost follows its allocated span, not the live context. A server's
+requests come and go one at a time, so this module gives every slot
+positions of its own, with vLLM-style paging:
 
 - the flax "cache" collection of a decode-mode model is allocated as a
   POOL of fixed-size blocks: every `cached_key`/`cached_value` leaf is
-  `(num_blocks, block_size, h*hd)` (same flat minor layout as the slot
-  pool — in-place TPU updates, ops/decode_attention.py); an int8 cache
+  `(num_blocks, block_size, h*hd)` (the flat cache's minor layout —
+  in-place TPU updates, ops/decode_attention.py); an int8 cache
   model (kv_cache_dtype="int8", models/vit.py) additionally pools its
   per-(head, position) fp32 scales as `(num_blocks, h, block_size)`
   leaves — the per-BLOCK scale pages that halve KV bytes/token; a
@@ -28,7 +27,7 @@ resource with vLLM-style paging:
 - admission scatters the bucketed scratch prefill into freshly allocated
   blocks (`scatter_prompt_blocks`), decode appends at each slot's own
   write position, release returns the slot's blocks to the free list
-  individually, and a request's context can outgrow the slot engine's
+  individually, and a request's context can outgrow the model's
   `max_len` as long as blocks exist.
 
 Blocks are REFCOUNTED (PR 6): a block may be referenced by several
@@ -83,7 +82,7 @@ GARBAGE_BLOCK = 0
 class BlockAllocator:
     """Host-side refcounted free-list over the pool's block indices.
 
-    Pure bookkeeping, same idiom as kv_slots.SlotAllocator: freed blocks
+    Pure bookkeeping, same idiom as SlotAllocator below: freed blocks
     go to the BACK of the free list, so allocation order is deterministic
     and reuse is observable in tests. `alloc(n)` is all-or-nothing —
     a request either gets its blocks or None (the scheduler's admission
@@ -179,6 +178,47 @@ class BlockAllocator:
         """Blocks held by more than one holder — the sharing observable
         behind the `kv_blocks_shared` gauge."""
         return sum(1 for c in self._refs.values() if c > 1)
+
+
+class SlotAllocator:
+    """Host-side free-list over the engine's slot (batch row) indices.
+
+    Pure bookkeeping — no device state. Freed slots go to the BACK of the
+    free list so reuse is observable in tests (a released slot is handed
+    out again once the older free slots are consumed) and allocation
+    order is deterministic.
+    """
+
+    def __init__(self, max_slots: int) -> None:
+        if max_slots <= 0:
+            raise ValueError("max_slots must be positive")
+        self.max_slots = max_slots
+        self._free: List[int] = list(range(max_slots))
+        self._used: set = set()
+
+    def alloc(self) -> Optional[int]:
+        if not self._free:
+            return None
+        slot = self._free.pop(0)
+        self._used.add(slot)
+        return slot
+
+    def free(self, slot: int) -> None:
+        if slot not in self._used:
+            raise ValueError(f"slot {slot} is not allocated")
+        self._used.remove(slot)
+        self._free.append(slot)
+
+    @property
+    def num_used(self) -> int:
+        return len(self._used)
+
+    @property
+    def num_free(self) -> int:
+        return len(self._free)
+
+    def used_slots(self) -> List[int]:
+        return sorted(self._used)
 
 
 class _RadixNode:

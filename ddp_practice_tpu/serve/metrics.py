@@ -36,10 +36,10 @@ class ServeMetrics:
         self.tpot = r.histogram("serve_tpot_s")
         self.queue_depth = r.gauge("serve_queue_depth")
         self.slot_occupancy = r.gauge("serve_slot_occupancy")
-        # paged-engine pool gauges (serve/kv_pages.py): block occupancy
-        # is the paged saturation signal — slots can be free while
-        # blocks are the binding constraint (long contexts) and vice
-        # versa (many short requests). Stay 0 for the slot engine.
+        # block-pool gauges (serve/kv_pages.py): block occupancy is the
+        # saturation signal — slots can be free while blocks are the
+        # binding constraint (long contexts) and vice versa (many short
+        # requests).
         self.block_occupancy = r.gauge("serve_block_occupancy")
         self.blocks_free = r.gauge("serve_blocks_free")
         # prefix-sharing / preemption observables (PR 6): in-use and
@@ -102,58 +102,52 @@ class ServeMetrics:
         self.queue_depth.set(len(scheduler.queue))
         eng = scheduler.engine
         self.slot_occupancy.set(eng.num_active / eng.allocator.max_slots)
-        blocks = getattr(eng, "blocks", None)  # PagedEngine only
-        if blocks is not None:
-            # blocks_available counts free + prefix-cache-evictable —
-            # what admission actually gates on; a gauge built from the
-            # raw free list would show a "full" pool whose cached
-            # prefixes are one make_room away from being promisable
-            allocatable = blocks.num_blocks - 1  # minus the garbage block
-            available = eng.blocks_available
-            self.block_occupancy.set(
-                (allocatable - available) / allocatable
-            )
-            self.blocks_free.set(available)
-            self.kv_blocks_in_use.set(blocks.num_used)
-            self.kv_blocks_shared.set(blocks.num_shared)
-            preempt = getattr(eng, "preemptions", 0)
-            self.preemptions.inc(preempt - self._last_preempt)
-            self._last_preempt = preempt
-            radix = getattr(eng, "radix", None)
-            if radix is not None:
-                self.prefix_hit_tokens.inc(
-                    radix.hit_tokens - self._last_hit
-                )
-                self.prefix_miss_tokens.inc(
-                    radix.miss_tokens - self._last_miss
-                )
-                self._last_hit = radix.hit_tokens
-                self._last_miss = radix.miss_tokens
-        held = getattr(eng, "moe_rows_held", 0)
-        routed = getattr(eng, "moe_rows_routed", 0)
+        blocks = eng.blocks
+        # blocks_available counts free + prefix-cache-evictable —
+        # what admission actually gates on; a gauge built from the
+        # raw free list would show a "full" pool whose cached
+        # prefixes are one make_room away from being promisable
+        allocatable = blocks.num_blocks - 1  # minus the garbage block
+        available = eng.blocks_available
+        self.block_occupancy.set((allocatable - available) / allocatable)
+        self.blocks_free.set(available)
+        self.kv_blocks_in_use.set(blocks.num_used)
+        self.kv_blocks_shared.set(blocks.num_shared)
+        preempt = eng.preemptions
+        self.preemptions.inc(preempt - self._last_preempt)
+        self._last_preempt = preempt
+        radix = eng.radix
+        if radix is not None:
+            self.prefix_hit_tokens.inc(radix.hit_tokens - self._last_hit)
+            self.prefix_miss_tokens.inc(
+                radix.miss_tokens - self._last_miss)
+            self._last_hit = radix.hit_tokens
+            self._last_miss = radix.miss_tokens
+        held = eng.moe_rows_held
+        routed = eng.moe_rows_routed
         self.moe_rows_held.inc(held - self._last_held)
         self.moe_rows_routed.inc(routed - self._last_routed)
         self._last_held, self._last_routed = held, routed
-        moved = getattr(eng, "moe_rows_moved", 0)
-        layout = getattr(eng, "moe_rows_layout", 0)
+        moved = eng.moe_rows_moved
+        layout = eng.moe_rows_layout
         self.moe_rows_moved.inc(moved - self._last_moved)
         self.moe_rows_layout.inc(layout - self._last_layout)
         self._last_moved, self._last_layout = moved, layout
-        scan = getattr(eng, "ssm_scan_tokens", 0)
-        padded = getattr(eng, "ssm_scan_padded_tokens", 0)
+        scan = eng.ssm_scan_tokens
+        padded = eng.ssm_scan_padded_tokens
         self.ssm_scan_tokens.inc(scan - self._last_scan)
         self.ssm_scan_padded.inc(padded - self._last_scan_padded)
         self._last_scan, self._last_scan_padded = scan, padded
-        self.ssm_state_bytes.set(getattr(eng, "ssm_state_bytes", 0))
-        self.latent_cache_bytes.set(getattr(eng, "latent_cache_bytes", 0))
-        walked = getattr(eng, "sparse_pages_walked", 0)
-        pages_held = getattr(eng, "sparse_pages_held", 0)
+        self.ssm_state_bytes.set(eng.ssm_state_bytes)
+        self.latent_cache_bytes.set(eng.latent_cache_bytes)
+        walked = eng.sparse_pages_walked
+        pages_held = eng.sparse_pages_held
         self.sparse_pages_walked.inc(walked - self._last_walked)
         self.sparse_pages_held.inc(pages_held - self._last_pages_held)
         self._last_walked, self._last_pages_held = walked, pages_held
-        self.index_cache_bytes.set(getattr(eng, "index_cache_bytes", 0))
-        drafted = getattr(eng, "spec_drafted_tokens", 0)
-        accepted = getattr(eng, "spec_accepted_tokens", 0)
+        self.index_cache_bytes.set(eng.index_cache_bytes)
+        drafted = eng.spec_drafted_tokens
+        accepted = eng.spec_accepted_tokens
         self.spec_drafted.inc(drafted - self._last_drafted)
         self.spec_accepted.inc(accepted - self._last_accepted)
         self._last_drafted = drafted

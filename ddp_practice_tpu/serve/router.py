@@ -1,6 +1,6 @@
-"""Fault-tolerant router over N SlotEngine replicas.
+"""Fault-tolerant router over N PagedEngine replicas.
 
-One SlotEngine is one chip's batch; heavy traffic needs a fleet. This
+One PagedEngine is one chip's batch; heavy traffic needs a fleet. This
 router is the serving mirror of train/elastic.py: the training side
 fails fast (watchdog) and recovers by checkpoint; the serving side
 fails fast (circuit breaker, serve/health.py) and recovers by REQUEST
@@ -53,7 +53,7 @@ import dataclasses
 import heapq
 from typing import Dict, List, Optional, Sequence
 
-from ddp_practice_tpu.serve.engine import EngineConfig, SlotEngine
+from ddp_practice_tpu.serve.engine import EngineConfig, PagedEngine
 from ddp_practice_tpu.serve.faults import FaultPlan, ReplicaCrashed
 from ddp_practice_tpu.serve.health import (
     BreakerConfig,
@@ -114,8 +114,8 @@ class RouterConfig:
     # ---- cache-aware dispatch: score HEALTHY replicas by expected
     # prefix-hit tokens from their published radix digests (affinity.py)
     # and dispatch by affinity minus a load penalty. Degrades to the
-    # least-loaded sort wherever digests are absent/cold, so fleets of
-    # non-paged engines behave byte-identically to cache_aware=False.
+    # least-loaded sort wherever digests are absent/cold, so fleets
+    # without a prefix cache behave byte-identically to cache_aware=False.
     cache_aware: bool = True
     # ---- weighted-fair service (serve/fairshare.py): when on,
     # make_router threads one VirtualTokenCounter through every
@@ -243,7 +243,7 @@ class ReplicaHandle:
                  breaker: BreakerConfig = BreakerConfig()) -> None:
         self.id = rid
         self.scheduler = scheduler
-        self.engine: SlotEngine = scheduler.engine
+        self.engine: PagedEngine = scheduler.engine
         self.health = ReplicaHealth(breaker)
         self.consumed = 0  # completions watermark (survives restarts)
         self.chunks_consumed = 0  # TokenChunk watermark (same contract)
@@ -329,17 +329,10 @@ class ReplicaHandle:
         return self.engine.num_active
 
     def fits_prompt(self, n_tokens: int) -> bool:
-        """Can a prompt of n_tokens prefill here? Delegates to the
-        engine's own feasibility probe (bucket-bounded, except the
-        chunk-capable paged engine, which is capacity-bounded)."""
-        probe = getattr(self.engine, "fits_prompt", None)
-        if probe is not None:
-            return probe(n_tokens)
-        try:
-            self.engine.bucket_for(n_tokens)
-            return True
-        except ValueError:
-            return False
+        """Can a prompt of n_tokens prefill here? The engine's own
+        feasibility probe (bucket-bounded, or capacity-bounded with
+        chunked prefill)."""
+        return self.engine.fits_prompt(n_tokens)
 
     @property
     def kv_summary(self) -> Optional[dict]:
@@ -347,8 +340,8 @@ class ReplicaHandle:
         the engine — the in-process twin of the worker's `_kv_summary`
         heartbeat payload (same builder, affinity.kv_summary), so the
         router's affinity scorer works identically with and without
-        the RPC seam. None for non-paged engines."""
-        if getattr(self.engine, "radix", None) is None:
+        the RPC seam. None without a prefix cache."""
+        if self.engine.radix is None:
             return None
         if not hasattr(self, "_digest_pub"):
             from ddp_practice_tpu.serve.affinity import DigestPublisher
@@ -366,14 +359,14 @@ class ReplicaHandle:
         return inj is None or inj.alive(now)
 
     def restart(self) -> None:
-        """Bring a probed-alive replica back: free every slot, rewind
-        the pool clock. The scheduler's queue/running were already
-        evacuated at death; its completions list (and our watermark)
-        survive so no completion is double-consumed."""
+        """Bring a probed-alive replica back: free every slot (their
+        blocks return with them; the prefix cache deliberately SURVIVES
+        — warm prefixes are the point). The scheduler's queue/running
+        were already evacuated at death; its completions list (and our
+        watermark) survive so no completion is double-consumed."""
         eng = self.engine
         for slot in list(eng.allocator.used_slots()):
             eng.release(slot)
-        eng.reset_epoch()
         inj = self.scheduler.fault_hook
         if inj is not None:
             inj.revive()
@@ -1262,7 +1255,7 @@ def make_router(
         )
     schedulers = []
     for i in range(n_replicas):
-        engine = SlotEngine(
+        engine = PagedEngine(
             model, params, engine_config, batch_stats=batch_stats
         )
         if tracer is not None:

@@ -1,7 +1,7 @@
 """Serving scheduler: FIFO admission, deadlines, shedding, slot churn.
 
-Policy layer over the SlotEngine mechanism. One `step()` is one
-scheduler tick:
+Policy layer over the PagedEngine mechanism (serve/engine.py). One
+`step()` is one scheduler tick:
 
 1. expire queued requests whose deadline already passed (they would
    burn prefill FLOPs to produce tokens nobody is waiting for);
@@ -19,15 +19,12 @@ and the admit loop asks the ENGINE's `admit_gate` for everything
 memory-shaped: "never" (prompt outgrows every bucket — after any
 prefix-cache match — or the request can never fit even an empty pool)
 is a fast reject, "later" waits for memory. Memory policy lives behind
-that gate — the slot engine answers from its shared-cursor headroom
-and frees positions only via `make_room` (drain + epoch rewind,
-kv_slots.py); the paged engine answers from free + prefix-cache-
-evictable blocks (kv_pages.py), which release per-request, age out of
-the radix cache (its make_room), or are taken back by BLOCK-AWARE
-PREEMPTION. This file carries no epoch logic at all — but it does own
-the preemption POLICY: when the engine evicts a slot (mid-decode
-growth exhaustion, `take_preempted`) or the admit loop evicts one for
-a blocked older request (`_preempt_victim_for` — only ever a
+that gate — the engine answers from free + prefix-cache-evictable
+blocks (kv_pages.py), which release per-request, age out of the radix
+cache (its `make_room`), or are taken back by BLOCK-AWARE PREEMPTION.
+This file owns the preemption POLICY: when the engine evicts a slot
+(mid-decode growth exhaustion, `take_preempted`) or the admit loop
+evicts one for a blocked older request (`_preempt_victim_for` — only ever a
 strictly-younger arrival, so readmission cascades terminate), the
 victim's request re-queues at the front and re-prefills
 prompt+tokens-so-far; `_resume` folds the pre-eviction tokens back
@@ -64,7 +61,7 @@ import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Sequence
 
-from ddp_practice_tpu.serve.engine import SlotEngine
+from ddp_practice_tpu.serve.engine import PagedEngine
 from ddp_practice_tpu.utils.trace import ENGINE_LANE, NULL_SPAN
 
 # slow_tick: a tick this many times the rolling median of the last
@@ -269,9 +266,9 @@ class _Running:
 
 
 class Scheduler:
-    """FIFO continuous-batching scheduler over one SlotEngine."""
+    """FIFO continuous-batching scheduler over one PagedEngine."""
 
-    def __init__(self, engine: SlotEngine, *, clock=None, max_queue: int = 64,
+    def __init__(self, engine: PagedEngine, *, clock=None, max_queue: int = 64,
                  metrics=None, fault_hook=None, tracer=None,
                  replica: int = 0, telemetry=None,
                  stream: bool = True, vtc=None) -> None:
@@ -312,8 +309,7 @@ class Scheduler:
         # position budget: verify grows a slot for the worst case
         # (spec_k + 1 positions) before acceptance is known.
         self._spec_k = (engine.config.spec_k
-                        if getattr(engine, "drafter", None) is not None
-                        else 0)
+                        if engine.drafter is not None else 0)
         # rid -> [drafted, accepted] cumulative across this request's
         # verify dispatches (rid-keyed, so preemption/readmission keeps
         # accumulating); popped into the completion's flight record
@@ -321,7 +317,7 @@ class Scheduler:
         # rid -> prefix-cache matched tokens, cumulative across this
         # request's admits (a preempted continuation re-matches its own
         # earlier blocks); popped into the flight record the same way.
-        # Only tracked for engines with a radix (last_prefix_hit set).
+        # Only tracked with the prefix cache on (last_prefix_hit set).
         self._prefix_hits: Dict[int, int] = {}
         # preempted-request resume state (PagedEngine block-aware
         # preemption): rid -> {"orig": the ORIGINAL request, "prefix":
@@ -407,7 +403,7 @@ class Scheduler:
                  # flight-accounting hook that tells a stalled engine
                  # (burst stands still) from a starved request (bursts
                  # advance without it) inside a resume gap
-                 burst=getattr(self.engine, "burst_seq", None))
+                 burst=self.engine.burst_seq)
 
     def _finish(self, req: Request, tokens: List[int], status: str,
                 first_token_time: Optional[float] = None,
@@ -567,14 +563,10 @@ class Scheduler:
         return creq
 
     def _drain_preempted(self) -> None:
-        """Requeue requests the ENGINE evicted during step_burst (paged
-        growth/CoW exhaustion): they re-enter at the FRONT and
-        re-prefill as room returns. No-op for engines without
-        preemption (SlotEngine)."""
-        take = getattr(self.engine, "take_preempted", None)
-        if take is None:
-            return
-        for slot in take():
+        """Requeue requests the ENGINE evicted during step_burst
+        (growth/CoW exhaustion): they re-enter at the FRONT and
+        re-prefill as room returns."""
+        for slot in self.engine.take_preempted():
             st = self.running.pop(slot, None)
             if st is not None:
                 self.queue.appendleft(self._continuation(st))
@@ -593,7 +585,7 @@ class Scheduler:
         and it must not shield the genuinely-younger runners behind
         it."""
         eng = self.engine
-        if not hasattr(eng, "preempt") or not self.running:
+        if not self.running:
             return None
         key = ((req.arrival or 0.0), req.rid)
         # mid-prefill slots are not preemptable (the engine raises on
@@ -618,15 +610,12 @@ class Scheduler:
         head, evicting anyone is pure churn (victims lose their decode
         progress to re-prefill, the head stays blocked), so nobody is
         touched and the head waits for releases instead."""
-        eng = self.engine
-        if not hasattr(eng, "preempt_headroom"):
-            return True
         key = ((req.arrival or 0.0), req.rid)
         fair = [s for s, st in self.running.items()
                 if not st.prefilling
                 and key < ((st.req.arrival or 0.0), st.req.rid)]
-        return eng.preempt_headroom(fair, len(req.prompt),
-                                    prompt=req.prompt)
+        return self.engine.preempt_headroom(fair, len(req.prompt),
+                                            prompt=req.prompt)
 
     def _needed_positions(self, max_new: int) -> int:
         """A request's decode-position budget: burst-granular (a request
@@ -674,12 +663,11 @@ class Scheduler:
             self._rotate_fair_head()
             req = self.queue[0]
             needed = self._needed_positions(req.max_new_tokens)
-            # memory policy is the ENGINE's: the slot engine gates on
-            # global cursor headroom (make_room = drain + epoch rewind),
-            # the paged engine on free + prefix-cache-evictable blocks
-            # (pages free per-request at release; make_room ages out
-            # cached prefixes; block-aware preemption evicts young
-            # runners for older blocked work). The scheduler only
+            # memory policy is the ENGINE's: it gates on free +
+            # prefix-cache-evictable blocks (pages free per-request at
+            # release; make_room ages out cached prefixes; block-aware
+            # preemption evicts young runners for older blocked work).
+            # The scheduler only
             # distinguishes can't-yet from can't-ever — and enforces
             # the arrival-order fairness preemption needs.
             gate = eng.admit_gate(len(req.prompt), needed,
@@ -754,17 +742,12 @@ class Scheduler:
                 self._finish(req, [], "error")
                 continue
             t_admit0 = self.clock.now()
-            admit_kw = {}
-            if (req.temperature is not None or req.top_k is not None
-                    or req.top_p is not None):
-                # only when the request actually overrides — engines
-                # (and test fakes) without the kwarg stay untouched
-                admit_kw["sampling"] = (req.temperature, req.top_k,
-                                        req.top_p)
             try:
                 slot = eng.admit(req.prompt, seed=req.seed,
                                  max_positions=needed,
-                                 trace_id=req.trace_id, **admit_kw)
+                                 trace_id=req.trace_id,
+                                 sampling=(req.temperature, req.top_k,
+                                           req.top_p))
             except ValueError:
                 # sampling overrides on an engine without
                 # per_slot_sampling (or a shape the gate missed): a
@@ -772,7 +755,7 @@ class Scheduler:
                 self._finish(req, [], "rejected")
                 continue
             t_admit1 = self.clock.now()
-            hit = getattr(eng, "last_prefix_hit", None)
+            hit = eng.last_prefix_hit
             if hit is not None:
                 self._prefix_hits[req.rid] = (
                     self._prefix_hits.get(req.rid, 0) + hit
@@ -797,8 +780,7 @@ class Scheduler:
                 # a preempted continuation's chunks continue the rid's
                 # global token offsets after the already-streamed prefix
                 chunk_base=len(prior["prefix"]) if prior else 0,
-                prefilling=bool(getattr(
-                    eng, "is_prefilling", lambda s: False)(slot)),
+                prefilling=eng.is_prefilling(slot),
             )
 
     def _prefill_pump(self) -> None:
@@ -816,7 +798,7 @@ class Scheduler:
         # `EngineConfig.prefill_chunks_per_tick`: that many chunk forwards
         # a tick at most, the oldest admission first, a slot as many as are
         # left; 0 = one for every mid-prefill slot
-        cap = getattr(eng.config, "prefill_chunks_per_tick", 0)
+        cap = eng.config.prefill_chunks_per_tick
         left = cap or len(self.running)
         for slot, st in list(self.running.items()):
             if not st.prefilling:

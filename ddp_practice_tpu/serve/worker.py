@@ -3,7 +3,7 @@
 `python -m ddp_practice_tpu.serve.worker --spec <json|@path>` boots a
 complete single-replica serving stack — its own single-process JAX
 runtime and devices, its own model/params (deterministic init from the
-spec, or a checkpoint), its own Scheduler + SlotEngine/PagedEngine —
+spec, or a checkpoint), its own Scheduler + PagedEngine —
 and serves two planes:
 
 - the serve/rpc.py seam (``submit`` / ``poll`` / ``ping`` / ``shed`` /
@@ -57,7 +57,7 @@ class WorkerSpec:
     # model architecture kwargs (deterministic PRNGKey(0) init — every
     # worker with the same spec holds byte-identical params)
     model: dict = dataclasses.field(default_factory=dict)
-    # EngineConfig kwargs, plus "paged": true to build a PagedEngine
+    # EngineConfig kwargs
     engine: dict = dataclasses.field(default_factory=dict)
     replica: int = 0            # id in fleet telemetry / lane labels
     max_queue: int = 64
@@ -97,8 +97,7 @@ class WorkerSpec:
     stream: bool = True
     # speculative decoding (serve/spec.py): first-class spec fields so
     # fleet launchers can flip the feature without knowing EngineConfig
-    # internals; folded into the engine kwargs at build time. Only
-    # meaningful for paged workers (the SlotEngine refuses it).
+    # internals; folded into the engine kwargs at build time.
     spec_decode: bool = False
     spec_k: int = 4
     # weighted-fair scheduling (serve/fairshare.py): the worker builds
@@ -107,6 +106,12 @@ class WorkerSpec:
     # per-tenant cost rollup. Off = byte-identical FIFO (no VTC
     # exists) — the same contract as RouterConfig.fair in-process.
     fair: bool = False
+
+    def __post_init__(self) -> None:
+        if "paged" in self.engine:
+            raise ValueError(
+                'WorkerSpec.engine carries "paged": every worker builds '
+                'the one engine, PagedEngine — drop the key')
 
     def to_json(self) -> str:
         return json.dumps(dataclasses.asdict(self))
@@ -191,11 +196,7 @@ class WorkerServer:
     state mutation against the serve loop."""
 
     def __init__(self, spec: WorkerSpec) -> None:
-        from ddp_practice_tpu.serve.engine import (
-            EngineConfig,
-            PagedEngine,
-            SlotEngine,
-        )
+        from ddp_practice_tpu.serve.engine import EngineConfig, PagedEngine
         from ddp_practice_tpu.serve.metrics import ServeMetrics
         from ddp_practice_tpu.serve.rpc import RpcServer
         from ddp_practice_tpu.serve.scheduler import Scheduler
@@ -208,25 +209,21 @@ class WorkerServer:
         self.spec = spec
         model, params = build_model(spec.model)
         eng_kw = dict(spec.engine)
-        paged = bool(eng_kw.pop("paged", False))
         if "prompt_buckets" in eng_kw:
             eng_kw["prompt_buckets"] = tuple(eng_kw["prompt_buckets"])
         if spec.spec_decode:
             eng_kw.setdefault("spec_decode", True)
             eng_kw.setdefault("spec_k", spec.spec_k)
-        cfg = EngineConfig(**eng_kw)
-        engine_cls = PagedEngine if paged else SlotEngine
-        self.engine = engine_cls(model, params, cfg)
+        self.engine = PagedEngine(model, params, EngineConfig(**eng_kw))
         # prefix-digest publisher (serve/affinity.py): fingerprints the
         # warm radix tree into every heartbeat so the router can route
         # by expected prefix hit. None without a prefix cache — the
         # kv summary simply carries no digest and the router falls back
         # to least-loaded.
-        radix = getattr(self.engine, "radix", None)
-        if radix is not None:
+        if self.engine.radix is not None:
             from ddp_practice_tpu.serve.affinity import DigestPublisher
 
-            self._digest = DigestPublisher(radix)
+            self._digest = DigestPublisher(self.engine.radix)
         else:
             self._digest = None
         self.registry = MetricsRegistry()
@@ -353,7 +350,7 @@ class WorkerServer:
         """KV/radix-cache occupancy riding every heartbeat frame: blocks
         in use / shared, prefix-cache hit rate, evictable count — plus
         the prefix digest (serve/affinity.py) cache-aware routing scores
-        against. Zeros (and no digest) for the slot engine. Federated
+        against (no digest without a prefix cache). Federated
         into per-worker gauges by the fleet view; the router's affinity
         index feeds straight off this payload."""
         from ddp_practice_tpu.serve.affinity import kv_summary

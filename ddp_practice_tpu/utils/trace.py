@@ -1,6 +1,6 @@
 """Request-lifecycle tracing: Dapper-style spans over an injectable clock.
 
-The serving stack (Scheduler -> SlotEngine/PagedEngine -> Router) and the
+The serving stack (Scheduler -> PagedEngine -> Router) and the
 training loop both answer "where did the time go?" with aggregate gauges
 only (utils/metrics.py) — a bad TTFT or a failover hop leaves no record
 of queue wait vs bucketed prefill vs decode-burst stalls vs retry hops.

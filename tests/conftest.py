@@ -99,10 +99,33 @@ def devices():
 
 
 @pytest.fixture
+def engine_at_rest():
+    """Factory of host-pure stand-ins for a PagedEngine that has served
+    nothing: every field `ServeMetrics.on_tick` reads, a dense model's
+    zeroes unless a test says otherwise."""
+    import types
+
+    def make(**fields):
+        blocks = types.SimpleNamespace(
+            num_blocks=9, num_used=0, num_shared=0, num_free=8)
+        at_rest = dict(
+            allocator=types.SimpleNamespace(max_slots=2), num_active=0,
+            blocks=blocks, blocks_available=8, radix=None, preemptions=0,
+            moe_rows_held=0, moe_rows_routed=0, moe_rows_moved=0,
+            moe_rows_layout=0, ssm_scan_tokens=0, ssm_scan_padded_tokens=0,
+            ssm_state_bytes=0, latent_cache_bytes=0, index_cache_bytes=0,
+            sparse_pages_walked=0, sparse_pages_held=0,
+            spec_drafted_tokens=0, spec_accepted_tokens=0)
+        return types.SimpleNamespace(**{**at_rest, **fields})
+
+    return make
+
+
+@pytest.fixture
 def compile_guard():
     """Assert-no-new-compiles context manager over serving engines.
 
-    Wraps the engines' jit-cache-size counters (SlotEngine/PagedEngine
+    Wraps the engines' jit-cache-size counters (PagedEngine
     `compile_stats()`): any XLA compile inside the `with` block — a new
     prompt bucket, a leaked dynamic shape, a paged-table shape change —
     fails loudly with the before/after counter diff. The

@@ -329,11 +329,16 @@ def test_policy_decayed_digest_falls_back():
 
 
 # ------------------------------------------------- kv summary one-shape
-def test_kv_summary_zeroes_for_slot_engines():
-    """A slot engine (no paged pool, no radix) publishes honest zeroes
-    and NO digest — the shape the router's fallback expects."""
-    out = kv_summary(types.SimpleNamespace(blocks=None, radix=None))
-    assert out["blocks_used"] == 0 and out["blocks_total"] == 0
+def test_kv_summary_without_a_prefix_cache_carries_no_digest():
+    """An engine without a prefix cache (`radix` None) publishes its
+    blocks, honest zeroes for the cache and NO digest — the shape the
+    router's fallback expects."""
+    _, alloc = _warm_radix()
+    held = alloc.alloc(3)
+    out = kv_summary(types.SimpleNamespace(blocks=alloc, radix=None))
+    assert out["blocks_used"] == len(held)
+    assert out["blocks_total"] == alloc.num_blocks - 1
+    assert out["evictable"] == out["hit_tokens"] == 0
     assert out["prefix_hit_rate"] == 0.0
     assert "digest" not in out and "block_size" not in out
 
@@ -475,7 +480,7 @@ def test_sigkill_affinity_preferred_worker_failover_and_invalidate():
     model_kw = {"vocab_size": 64, "max_len": 96, "hidden_dim": 64,
                 "depth": 2, "num_heads": 4, "mlp_dim": 128,
                 "pos_emb": "rope"}
-    engine_kw = {"paged": True, "prefix_cache": True, "num_blocks": 48,
+    engine_kw = {"prefix_cache": True, "num_blocks": 48,
                  "block_size": 16, "max_slots": 2, "max_len": 96,
                  "prompt_buckets": [16, 32, 48, 64],
                  "temperature": 0.0, "decode_burst": 4, "eos_id": None}
@@ -487,7 +492,6 @@ def test_sigkill_affinity_preferred_worker_failover_and_invalidate():
     # fault-free greedy oracle: one in-process paged replica
     model, params = build_model(model_kw)
     eng_kw = dict(engine_kw)
-    eng_kw.pop("paged")
     eng_kw["prompt_buckets"] = tuple(eng_kw["prompt_buckets"])
     oracle = Scheduler(PagedEngine(model, params, EngineConfig(**eng_kw)),
                        max_queue=64)

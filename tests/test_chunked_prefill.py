@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from ddp_practice_tpu.models import create_model
-from ddp_practice_tpu.serve import EngineConfig, PagedEngine, SlotEngine
+from ddp_practice_tpu.serve import EngineConfig, PagedEngine
 from ddp_practice_tpu.serve.engine import warm_engine
 
 VOCAB = 32
@@ -60,11 +60,6 @@ def test_chunk_config_gates(lm, devices):
     with pytest.raises(ValueError, match="exceeds"):
         PagedEngine(model, params, EngineConfig(
             **PKW, prompt_buckets=(8,), prefill_chunk=16))
-    # chunking is a paged-prefix mechanism; the slot engine refuses it
-    with pytest.raises(ValueError, match="prefill_chunk"):
-        SlotEngine(model, params, EngineConfig(
-            max_slots=2, prompt_buckets=(8,), max_len=64,
-            prefill_chunk=8))
 
 
 # ----------------------------------------------------------- equivalence

@@ -147,6 +147,7 @@ class _IdleEngine:
         decode_burst = 1
 
     num_free = 0
+    drafter = None
 
 
 def _queued_sched(vtc):
@@ -608,7 +609,7 @@ def test_fair_head_reorders_who_runs_never_what_they_decode(devices):
     import jax.numpy as jnp
 
     from ddp_practice_tpu.models import create_model
-    from ddp_practice_tpu.serve import EngineConfig, SlotEngine
+    from ddp_practice_tpu.serve import EngineConfig, PagedEngine
 
     model = create_model(
         "lm_tiny", vocab_size=32, max_len=96, hidden_dim=64, depth=2,
@@ -617,8 +618,8 @@ def test_fair_head_reorders_who_runs_never_what_they_decode(devices):
     params = model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
     )["params"]
-    engine = SlotEngine(model, params, EngineConfig(
-        max_slots=1, max_len=96, prompt_buckets=(8,), temperature=0.0))
+    engine = PagedEngine(model, params, EngineConfig(
+        max_slots=1, max_len=32, prompt_buckets=(8,), temperature=0.0))
     flood = [("bulk", [1 + i, 2, 3]) for i in range(6)] \
         + [("acme", [9, 8 + i]) for i in range(2)]
 

@@ -19,7 +19,6 @@ from ddp_practice_tpu.ops import moe, ssm  # noqa: E402
 from ddp_practice_tpu.serve.engine import (  # noqa: E402
     EngineConfig,
     PagedEngine,
-    SlotEngine,
 )
 from perf.reference import nemotron_h as reference  # noqa: E402
 
@@ -224,14 +223,11 @@ def test_engine_refuses_what_needs_a_state_snapshot(toy, option, value):
         make_engine(model, params, **{option: value}, **extra)
 
 
-def test_fork_and_the_slot_engine_refuse_recurrent_state(toy, engine):
-    model, params = toy
+def test_fork_refuses_recurrent_state(engine):
     slot = engine.admit([1, 2, 3], max_positions=4)
     with pytest.raises(ValueError, match="fork is refused"):
         engine.fork(slot)
     engine.release(slot)
-    with pytest.raises(ValueError, match="SlotEngine"):
-        SlotEngine(model, params, EngineConfig(prompt_buckets=(8,)))
 
 
 def test_chunks_over_the_state_give_the_logits_of_one_whole_prefill(toy):

@@ -141,3 +141,48 @@ def test_every_debt_names_a_roadmap_item():
 ], ids=["from-tools", "lazy-perf", "relative-up", "relative-here"])
 def test_the_walk_sees_every_form_of_import(line, package, want):
     assert want in imported_modules(line, package)
+
+
+# ------------------------------------------------------------ one engine
+ENGINE_METHODS = {"admit", "step_burst", "release"}
+
+
+def _serve_tree(name: str) -> ast.Module:
+    with open(os.path.join(ROOT, PKG, "serve", name)) as f:
+        return ast.parse(f.read())
+
+
+def _calls(node: ast.AST) -> set:
+    return {n.func.id for n in ast.walk(node)
+            if isinstance(n, ast.Call) and isinstance(n.func, ast.Name)}
+
+
+def test_serve_has_one_engine_and_its_three_callers_build_it():
+    """PR 43 closed the fork between the engine users started and the one
+    the benchmark measured. It stays closed: one class of `serve/` has the
+    scheduler's `admit` / `step_burst` / `release`, the package exports
+    it, and `serve` (cli.py), `make_router` and the worker construct it."""
+    engines = set()
+    for name in sorted(os.listdir(os.path.join(ROOT, PKG, "serve"))):
+        if name.endswith(".py"):
+            engines |= {
+                (name, node.name) for node in ast.walk(_serve_tree(name))
+                if isinstance(node, ast.ClassDef) and ENGINE_METHODS <= {
+                    f.name for f in node.body
+                    if isinstance(f, ast.FunctionDef)}}
+    assert engines == {("engine.py", "PagedEngine")}
+    exported = {
+        a.name for node in _serve_tree("__init__.py").body
+        if isinstance(node, ast.ImportFrom) and node.module.endswith(
+            ".serve.engine") for a in node.names}
+    assert "PagedEngine" in exported
+    assert not {n for n in exported if n.endswith("Engine")} - {"PagedEngine"}
+    (make_router,) = [n for n in _serve_tree("router.py").body
+                      if isinstance(n, ast.FunctionDef)
+                      and n.name == "make_router"]
+    (worker,) = [n for n in _serve_tree("worker.py").body
+                 if isinstance(n, ast.ClassDef) and n.name == "WorkerServer"]
+    for where, node in (("cli.py", _serve_tree("cli.py")),
+                        ("make_router", make_router),
+                        ("WorkerServer", worker)):
+        assert "PagedEngine" in _calls(node), where

@@ -15,7 +15,7 @@ import pytest
 
 from ddp_practice_tpu.inference import sample_logits, sample_logits_batch
 from ddp_practice_tpu.models import create_model
-from ddp_practice_tpu.serve import EngineConfig, SlotEngine
+from ddp_practice_tpu.serve import EngineConfig, PagedEngine
 from ddp_practice_tpu.serve.engine import warm_engine
 
 VOCAB = 32
@@ -128,13 +128,13 @@ def test_per_slot_stream_identical_to_config_baked_engine(lm, devices,
     rng = np.random.default_rng(3)
     prompt = rng.integers(1, VOCAB, 7).tolist()
 
-    legacy = SlotEngine(model, params, EngineConfig(
+    legacy = PagedEngine(model, params, EngineConfig(
         **SKW, temperature=0.8, top_k=5, top_p=0.9))
     warm_engine(legacy)
-    ps = SlotEngine(model, params, EngineConfig(
+    ps = PagedEngine(model, params, EngineConfig(
         **SKW, per_slot_sampling=True))
     warm_engine(ps)
-    greedy = SlotEngine(model, params, EngineConfig(**SKW))
+    greedy = PagedEngine(model, params, EngineConfig(**SKW))
     warm_engine(greedy)
 
     assert _run_slot(legacy, prompt) == _run_slot(
@@ -160,7 +160,7 @@ def test_sampling_override_without_flag_raises(lm, devices):
     decode program, so a per-request override it cannot honor raises
     at admit — and leaves no slot half-admitted."""
     model, params = lm
-    eng = SlotEngine(model, params, EngineConfig(**SKW))
+    eng = PagedEngine(model, params, EngineConfig(**SKW))
     warm_engine(eng)
     prompt = [1, 2, 3, 4]
     with pytest.raises(ValueError, match="per_slot_sampling"):
@@ -174,8 +174,6 @@ def test_sampling_override_without_flag_raises(lm, devices):
 def test_spec_decode_excludes_per_slot_sampling(lm, devices):
     """Exact speculative acceptance is greedy string matching; the
     combination is rejected at construction, before any compile."""
-    from ddp_practice_tpu.serve import PagedEngine
-
     model, params = lm
     with pytest.raises(ValueError, match="per_slot_sampling"):
         PagedEngine(model, params, EngineConfig(

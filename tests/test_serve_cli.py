@@ -1,7 +1,7 @@
 """`cli.py serve`: a checkpoint server and nothing else (serve/cli.py).
 
 The one user-facing serving command loads a checkpoint, puts the prompts
-through SlotEngine + Scheduler and prints what `generate.py` prints for
+through PagedEngine + Scheduler and prints what `generate.py` prints for
 the same checkpoint under greedy; every flag of the retired bench is
 argparse's exit 2.
 """

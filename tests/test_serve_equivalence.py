@@ -5,7 +5,8 @@ Greedy decode through the serving path (bucketed prefill-admit + batched
 single-token steps, serve/engine.py) must produce TOKEN-IDENTICAL output
 to the one-shot `make_generate_fn` scan for the same (params, prompt) —
 both paths are thin clients of `inference.decode_apply`, and the
-left-alignment shift is invisible to RoPE. Pinned for single requests,
+slot-local positions of the paged pool are invisible to RoPE. Pinned
+for single requests,
 a mid-decode join, and a left-padded variable-length batch driven
 through `pad_left_prompts` (the layout serve admission generalizes).
 """
@@ -17,7 +18,7 @@ import pytest
 
 from ddp_practice_tpu.inference import make_generate_fn, pad_left_prompts
 from ddp_practice_tpu.models import create_model
-from ddp_practice_tpu.serve import EngineConfig, PagedEngine, SlotEngine
+from ddp_practice_tpu.serve import EngineConfig, PagedEngine
 from ddp_practice_tpu.serve.scheduler import FakeClock, Request, Scheduler
 
 VOCAB = 32
@@ -38,8 +39,9 @@ def lm():
 def _serve_greedy(lm, prompts, n_new, max_slots=4):
     """Run prompts through the engine concurrently; per-request tokens."""
     model, params = lm
-    eng = SlotEngine(model, params, EngineConfig(
-        max_slots=max_slots, max_len=128, prompt_buckets=(8,),
+    eng = PagedEngine(model, params, EngineConfig(
+        max_slots=max_slots, prompt_buckets=(8,),
+        block_size=8, max_blocks_per_slot=3,   # bucket 8 + 16 tokens
     ))
     slots = [eng.admit(p) for p in prompts]
     out = [[] for _ in prompts]
@@ -77,8 +79,9 @@ def test_mid_decode_join_matches_one_shot(devices, lm):
     """A request admitted while another is mid-generation gets exactly
     its solo tokens — continuous batching is transparent to clients."""
     model, params = lm
-    eng = SlotEngine(model, params, EngineConfig(
-        max_slots=2, max_len=128, prompt_buckets=(8,),
+    eng = PagedEngine(model, params, EngineConfig(
+        max_slots=2, prompt_buckets=(8,),
+        block_size=8, max_blocks_per_slot=3,
     ))
     s1 = eng.admit([3, 1, 4, 1, 5])
     for _ in range(4):
@@ -112,14 +115,15 @@ def test_sampled_serve_is_deterministic_per_request(devices, lm):
     its own seed, not on batch composition — the same request sampled
     alone and next to a neighbor yields identical tokens."""
     model, params = lm
-    cfg = dict(max_len=128, prompt_buckets=(8,), temperature=1.3, top_k=8)
+    cfg = dict(prompt_buckets=(8,), temperature=1.3, top_k=8,
+               block_size=8, max_blocks_per_slot=2)
     prompt = [7, 7, 7]
 
-    eng_solo = SlotEngine(model, params, EngineConfig(max_slots=2, **cfg))
+    eng_solo = PagedEngine(model, params, EngineConfig(max_slots=2, **cfg))
     s = eng_solo.admit(prompt, seed=42)
     solo = [int(eng_solo.step()[s]) for _ in range(8)]
 
-    eng_pair = SlotEngine(model, params, EngineConfig(max_slots=2, **cfg))
+    eng_pair = PagedEngine(model, params, EngineConfig(max_slots=2, **cfg))
     eng_pair.admit([1, 2, 3, 4], seed=7)   # different slot, different seed
     s2 = eng_pair.admit(prompt, seed=42)
     paired = [int(eng_pair.step()[s2]) for _ in range(8)]
@@ -130,11 +134,11 @@ def test_sampled_serve_is_deterministic_per_request(devices, lm):
     assert all(0 <= t < VOCAB for t in solo)
 
 
-# ----------------------------------------------------------------- paged
-# The paged engine (serve/kv_pages.py, PagedEngine) must be just as
-# invisible an optimization as the slot pool: same decode_apply, same
-# sample_logits, per-slot positions instead of a shared cursor — greedy
-# tokens identical per request, whatever the memory layout underneath.
+# ------------------------------------------------- through the scheduler
+# The paged pool (serve/kv_pages.py) must stay an invisible optimization
+# under everything the scheduler does to it — churn, queueing, block
+# growth, slot reuse, prefix sharing, preemption: same decode_apply, same
+# sample_logits, greedy tokens identical per request.
 
 
 def _tolerate_load_flake(attempt, args_per_try):
@@ -173,35 +177,42 @@ def _shared_trace(rng, n=10):
     ]
 
 
-def test_paged_engine_matches_slot_engine_on_shared_trace(
+def test_shared_trace_matches_each_requests_one_shot_run(
         devices, lm, compile_guard):
-    """Greedy token-identity paged-vs-slot on one trace driven through
-    both schedulers — churn, queueing, block growth, slot reuse and all.
-    Both engines stay at two compiled programs throughout (pinned via
-    the conftest compile_guard)."""
+    """Greedy token-identity of one trace driven through the scheduler —
+    churn, queueing, block growth, slot reuse and all — against the
+    one-shot generate of each request alone, cut at its EOS. The engine
+    stays at its warmed programs throughout (conftest compile_guard)."""
     model, params = lm
+    n_ref = 16                              # the trace asks for 2..15
+    gen = jax.jit(make_generate_fn(model, max_new_tokens=n_ref,
+                                   temperature=0.0))
+
+    def one_shot(t):
+        """(status, tokens) of the request served alone, all at once."""
+        p = t["prompt"]
+        ref = np.asarray(
+            gen(params, jnp.asarray([p], jnp.int32)))[0, len(p):].tolist()
+        ref = ref[:t["max_new_tokens"]]
+        if 5 in ref:
+            return "eos", ref[:ref.index(5) + 1]
+        return "length", ref
 
     def attempt(trace_seed):
         trace = _shared_trace(np.random.default_rng(trace_seed))
-        slot_eng = SlotEngine(model, params, EngineConfig(
-            max_slots=3, max_len=128, prompt_buckets=(8,), eos_id=5,
-        ))
-        paged_eng = PagedEngine(model, params, EngineConfig(
+        eng = PagedEngine(model, params, EngineConfig(
             max_slots=3, prompt_buckets=(8,), eos_id=5,
-            block_size=8, max_blocks_per_slot=3,  # span 24 << slot's 128
+            block_size=8, max_blocks_per_slot=3,  # span 24 << model's 128
         ))
-        # warmup: one admit per bucket + one step each, then the trace
-        # runs compile-free on both layouts
-        for eng in (slot_eng, paged_eng):
-            s = eng.admit([1, 2, 3], max_positions=8)
-            eng.step()
-            eng.release(s)
-        slot_eng.reset_epoch()
-        with compile_guard(slot_eng, paged_eng):
-            got_slot = _run_trace(slot_eng, trace)
-            got_paged = _run_trace(paged_eng, trace)
-        assert got_paged == got_slot
-        assert any(status == "eos" for status, _ in got_slot.values())
+        # warmup: one admit per bucket + one step, then the trace runs
+        # compile-free
+        s = eng.admit([1, 2, 3], max_positions=8)
+        eng.step()
+        eng.release(s)
+        with compile_guard(eng):
+            got = _run_trace(eng, trace)
+        assert got == {t["rid"]: one_shot(t) for t in trace}
+        assert any(status == "eos" for status, _ in got.values())
 
     # retry the SAME trace: a deterministic divergence must fail both
     # attempts; only a load transient passes the replay
@@ -306,18 +317,17 @@ def test_prefix_hit_serves_prompt_longer_than_every_bucket(devices, lm):
     assert eng.radix.hit_tokens >= 16
 
 
-def test_paged_request_outgrows_slot_engine_max_len(devices, lm):
-    """A context the slot engine can NEVER serve (prompt + new tokens
-    past its max_len ceiling) completes on the paged engine, and its
-    prefix is greedy-identical to the one-shot run over the window the
-    one-shot can reach."""
+def test_paged_request_outgrows_the_models_max_len(devices, lm):
+    """A context the one-shot generator can NEVER hold (prompt + new
+    tokens past the model's max_len, the flat cache's ceiling) completes
+    on the engine, and its prefix is greedy-identical to the one-shot
+    run over the window the one-shot can reach."""
     model, params = lm   # model.max_len = 128
     prompt = [3, 1, 4, 1, 5]
     n_new = 150          # 8 + 150 > 128: beyond the model's own window
-    slot_eng = SlotEngine(model, params, EngineConfig(
-        max_slots=1, max_len=128, prompt_buckets=(8,),
-    ))
-    assert slot_eng.admit_gate(len(prompt), n_new) == "never"
+    with pytest.raises(ValueError, match="exceeds model max_len"):
+        make_generate_fn(model, max_new_tokens=n_new, temperature=0.0)(
+            params, jnp.asarray([prompt], jnp.int32))
 
     def attempt():
         paged_eng = PagedEngine(model, params, EngineConfig(

@@ -117,7 +117,7 @@ def test_serve_metrics_report(devices):
 
 
 @pytest.mark.fast
-def test_paged_pool_metrics_export(devices):
+def test_paged_pool_metrics_export(devices, engine_at_rest):
     """The PR-6 pool observables (kv_blocks_in_use / kv_blocks_shared
     gauges, prefix-cache hit/miss token counters, preemptions_total)
     flow from the engine's cumulative fields into the registry as
@@ -135,19 +135,11 @@ def test_paged_pool_metrics_export(devices):
         def evictable(self):
             return 1
 
-    class _Alloc:
-        max_slots = 4
-
-    class _Eng:
-        allocator = _Alloc()
-        blocks = _Blocks()
-        radix = _Radix()
-        num_active = 2
-        blocks_available = 4   # free + evictable
-        preemptions = 3
-
     class _Sched:
-        engine = _Eng()
+        engine = engine_at_rest(
+            blocks=_Blocks(), radix=_Radix(), num_active=2,
+            blocks_available=4,   # free + evictable
+            preemptions=3)
         queue = ()
 
     m = ServeMetrics()

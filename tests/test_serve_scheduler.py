@@ -5,8 +5,8 @@ early-EOS sequence, replayed on virtual time. Pinned: slot REUSE (a
 later request occupies a slot an earlier one freed), zero
 recompilation churn (jit cache sizes constant after warmup), bounded-
 queue shedding, deadline timeouts (queued and running), impossible-
-request rejection, and the epoch reset that rewinds the shared cursor
-when the position budget drains.
+request rejection, and a block pool too small for two requests at once,
+which queues with a slot free and still serves everyone.
 """
 
 import jax
@@ -18,13 +18,24 @@ from ddp_practice_tpu.models import create_model
 from ddp_practice_tpu.serve import (
     EngineConfig,
     FakeClock,
+    PagedEngine,
     Request,
     Scheduler,
     ServeMetrics,
-    SlotEngine,
 )
 
 VOCAB = 32
+# one prefill bucket and the decode program, nothing else ever compiled
+TWO_PROGRAMS = {"prefill_compiles": 1, "decode_compiles": 1,
+                "prefix_prefill_compiles": 0, "verify_compiles": 0}
+
+
+def own_programs(engine) -> dict:
+    """The engine's compile counters without `cow_compiles`, which counts
+    one jitted function shared by every engine of the process."""
+    stats = engine.compile_stats()
+    del stats["cow_compiles"]
+    return stats
 
 
 @pytest.fixture(scope="module")
@@ -57,8 +68,8 @@ def test_fake_clock_trace_20_requests(devices, lm):
     model, params = lm
     prompt0 = [3, 1, 4, 1, 5]
     eos = _greedy_eos(lm, prompt0)
-    engine = SlotEngine(model, params, EngineConfig(
-        max_slots=3, max_len=96, prompt_buckets=(8,), eos_id=eos,
+    engine = PagedEngine(model, params, EngineConfig(
+        max_slots=3, max_len=32, prompt_buckets=(8,), eos_id=eos,
     ))
     metrics = ServeMetrics()
     clock = FakeClock(step_s=0.01)
@@ -112,12 +123,10 @@ def test_fake_clock_trace_20_requests(devices, lm):
     assert sum(len(v) for v in admitted_slots.values()) == n_req
     # no recompilation churn: cache sizes after warmup == at the end
     assert warm_stats == engine.compile_stats()
-    assert engine.compile_stats() == {
-        "prefill_compiles": 1, "decode_compiles": 1,
-    }
+    assert own_programs(engine) == TWO_PROGRAMS
     # replaying the same trace on a fresh engine is bit-identical
-    engine2 = SlotEngine(model, params, EngineConfig(
-        max_slots=3, max_len=96, prompt_buckets=(8,), eos_id=eos,
+    engine2 = PagedEngine(model, params, EngineConfig(
+        max_slots=3, max_len=32, prompt_buckets=(8,), eos_id=eos,
     ))
     sched2 = Scheduler(engine2, clock=FakeClock(step_s=0.01), max_queue=64)
     i = 0
@@ -138,8 +147,8 @@ def test_fake_clock_trace_20_requests(devices, lm):
 
 def test_queue_bound_sheds(devices, lm):
     model, params = lm
-    engine = SlotEngine(model, params, EngineConfig(
-        max_slots=1, max_len=96, prompt_buckets=(8,),
+    engine = PagedEngine(model, params, EngineConfig(
+        max_slots=1, max_len=32, prompt_buckets=(8,),
     ))
     sched = Scheduler(engine, clock=FakeClock(), max_queue=2)
     results = [
@@ -156,8 +165,8 @@ def test_queue_bound_sheds(devices, lm):
 
 def test_deadlines_queued_and_running(devices, lm):
     model, params = lm
-    engine = SlotEngine(model, params, EngineConfig(
-        max_slots=1, max_len=96, prompt_buckets=(8,),
+    engine = PagedEngine(model, params, EngineConfig(
+        max_slots=1, max_len=64, prompt_buckets=(8,),
     ))
     clock = FakeClock(step_s=0.01)
     sched = Scheduler(engine, clock=clock, max_queue=8)
@@ -177,14 +186,14 @@ def test_deadlines_queued_and_running(devices, lm):
 
 def test_impossible_requests_rejected(devices, lm):
     model, params = lm
-    engine = SlotEngine(model, params, EngineConfig(
+    engine = PagedEngine(model, params, EngineConfig(
         max_slots=1, max_len=24, prompt_buckets=(8,),
     ))
     sched = Scheduler(engine, clock=FakeClock(), max_queue=8)
     sched.submit(Request(rid=0, prompt=list(range(1, 10)),  # > bucket 8
                          max_new_tokens=4))
     sched.submit(Request(rid=1, prompt=[1],
-                         max_new_tokens=99))  # > fresh-pool headroom 16
+                         max_new_tokens=99))  # > a slot's capacity 32
     sched.submit(Request(rid=2, prompt=[1], max_new_tokens=4))
     # zero/negative token budgets reject at the door (needed=0 would
     # bypass every headroom guard downstream)
@@ -197,20 +206,28 @@ def test_impossible_requests_rejected(devices, lm):
     assert by_rid[3].status == "rejected"
 
 
-def test_epoch_reset_keeps_serving(devices, lm):
-    """A tiny position budget forces cursor rewinds mid-trace; requests
-    keep completing correctly across resets."""
+def test_exhausted_block_pool_queues_then_serves_every_request(devices, lm):
+    """A pool of two blocks holds ONE request's 18 positions: the next
+    one waits in the queue although a slot is free (the gate says
+    "later": a prompt block and a decode block are not there), nobody is
+    preempted, and every request completes correctly as blocks return."""
     from ddp_practice_tpu.inference import make_generate_fn
 
     model, params = lm
-    engine = SlotEngine(model, params, EngineConfig(
-        max_slots=2, max_len=24, prompt_buckets=(8,),  # 16 decode positions
+    engine = PagedEngine(model, params, EngineConfig(
+        max_slots=2, prompt_buckets=(8,),
+        block_size=16, max_blocks_per_slot=2, num_blocks=1 + 2,
     ))
     sched = Scheduler(engine, clock=FakeClock(), max_queue=16)
     prompts = [[1 + i, 2, 3] for i in range(6)]
     for i, p in enumerate(prompts):
         sched.submit(Request(rid=i, prompt=p, max_new_tokens=10))
-    sched.run_until_idle()
+    waited_with_a_free_slot = 0
+    while not sched.idle:
+        sched.step()
+        waited_with_a_free_slot += bool(sched.queue and engine.num_free)
+    assert waited_with_a_free_slot and engine.preemptions == 0
+    assert engine.blocks.num_free == 2 and engine.num_active == 0
     assert len(sched.completions) == 6
     gen = jax.jit(make_generate_fn(model, max_new_tokens=10, temperature=0.0))
     for c in sched.completions:
@@ -219,10 +236,8 @@ def test_epoch_reset_keeps_serving(devices, lm):
             params, jnp.asarray([prompts[c.rid]], jnp.int32)
         ))
         assert c.tokens == want[0, len(prompts[c.rid]):].tolist()
-    # churn through 6 requests across resets: still just two programs
-    assert engine.compile_stats() == {
-        "prefill_compiles": 1, "decode_compiles": 1,
-    }
+    # churn through 6 requests across the waits: still just two programs
+    assert own_programs(engine) == TWO_PROGRAMS
 
 
 # --------------------------------------------------- preemption policy
@@ -240,6 +255,7 @@ class _BlockedEngine:
         decode_burst = 1
 
     num_free = 1
+    drafter = None
 
     def __init__(self, feasible=True):
         self.feasible = feasible
@@ -385,8 +401,8 @@ def test_stream_chunks_match_completions(devices, lm):
     terminal status — chunk delivery is complete exactly when the
     completion exists. stream=False (the control arm) builds none."""
     model, params = lm
-    engine = SlotEngine(model, params, EngineConfig(
-        max_slots=2, max_len=96, prompt_buckets=(8,),
+    engine = PagedEngine(model, params, EngineConfig(
+        max_slots=2, max_len=32, prompt_buckets=(8,),
     ))
     sched = Scheduler(engine, clock=FakeClock(step_s=0.01), max_queue=8)
     reqs = [Request(rid=i, prompt=[1 + i, 2, 3], max_new_tokens=4 + i)

@@ -23,6 +23,8 @@ _tolerate_load_flake) — identity pins retry the same trace: a real
 verify/rollback bug diverges on every attempt.
 """
 
+import importlib
+import json
 import types
 
 import numpy as np
@@ -122,12 +124,22 @@ def _stub_model():
     return types.SimpleNamespace(pos_emb="rope", max_len=128)
 
 
-def test_slot_engine_refuses_spec_decode():
-    from ddp_practice_tpu.serve.engine import EngineConfig, SlotEngine
+@pytest.mark.parametrize("what", ["worker_spec_paged", "import_kv_slots"])
+def test_the_removed_engine_is_refused_by_name(what):
+    """There is one engine: the switch that chose between two is refused
+    where it can still arrive from outside (a fleet launcher's JSON), and
+    the removed pool's module is gone, not left behind as a shim."""
+    if what == "worker_spec_paged":
+        from ddp_practice_tpu.serve.worker import WorkerSpec
 
-    with pytest.raises(ValueError, match="PagedEngine"):
-        SlotEngine(_stub_model(), None,
-                   EngineConfig(spec_decode=True))
+        text = json.dumps({"engine": {"paged": True, "spec_decode": True}})
+        with pytest.raises(ValueError, match='"paged"'):
+            WorkerSpec.from_json(text)
+        assert WorkerSpec.from_json(
+            json.dumps({"engine": {"spec_decode": True}})).engine
+    else:
+        with pytest.raises(ImportError, match="kv_slots"):
+            importlib.import_module("ddp_practice_tpu.serve.kv_slots")
 
 
 def test_paged_engine_validates_spec_config():
@@ -142,14 +154,10 @@ def test_paged_engine_validates_spec_config():
 
 
 # ----------------------------------------------- metrics/telemetry surface
-def test_serve_metrics_export_spec_counters_as_deltas():
+def test_serve_metrics_export_spec_counters_as_deltas(engine_at_rest):
     from ddp_practice_tpu.serve.metrics import ServeMetrics
 
-    eng = types.SimpleNamespace(
-        num_active=0,
-        allocator=types.SimpleNamespace(max_slots=2),
-        spec_drafted_tokens=10, spec_accepted_tokens=6,
-    )
+    eng = engine_at_rest(spec_drafted_tokens=10, spec_accepted_tokens=6)
     sched = types.SimpleNamespace(queue=[], engine=eng)
     m = ServeMetrics()
     m.on_tick(sched)
@@ -159,8 +167,7 @@ def test_serve_metrics_export_spec_counters_as_deltas():
     assert snap["spec_drafted_tokens_total"] == 25
     assert snap["spec_accepted_tokens_total"] == 14
     # engines without speculation keep the counters at zero, not absent
-    plain = types.SimpleNamespace(
-        num_active=0, allocator=types.SimpleNamespace(max_slots=2))
+    plain = engine_at_rest()
     m2 = ServeMetrics()
     m2.on_tick(types.SimpleNamespace(queue=[], engine=plain))
     assert m2.report()["spec_drafted_tokens_total"] == 0
@@ -433,7 +440,7 @@ def test_spec_respects_eos_inside_verified_run(devices, lm):
 WORKER_MODEL_KW = {"vocab_size": 64, "max_len": 64, "hidden_dim": 64,
                    "depth": 2, "num_heads": 4, "mlp_dim": 128,
                    "pos_emb": "rope"}
-WORKER_ENGINE_KW = {"paged": True, "max_slots": 2,
+WORKER_ENGINE_KW = {"max_slots": 2,
                     "prompt_buckets": [8, 16], "temperature": 0.0,
                     "eos_id": None, "block_size": 8,
                     "max_blocks_per_slot": 8, "decode_burst": 4}
@@ -462,7 +469,6 @@ def _plain_oracle(trace):
 
     model, params = build_model(WORKER_MODEL_KW)
     kw = dict(WORKER_ENGINE_KW)
-    kw.pop("paged")
     kw["prompt_buckets"] = tuple(kw["prompt_buckets"])
     engine = PagedEngine(model, params, EngineConfig(**kw))
     sched = Scheduler(engine, max_queue=64)

@@ -47,19 +47,15 @@ def lm():
 
 
 def make_engine(lm, kind: str):
-    from ddp_practice_tpu.serve.engine import (
-        EngineConfig,
-        PagedEngine,
-        SlotEngine,
-    )
+    """The engine under one of its two admission layouts: "paged" left-pads
+    a prompt into a scratch cache (`_prefill_admit`), "prefix" appends it
+    through the page table at canonical positions (`_prefix_prefill`)."""
+    from ddp_practice_tpu.serve.engine import EngineConfig, PagedEngine
 
     model, params = lm
-    if kind == "paged":
-        return PagedEngine(model, params, EngineConfig(
-            max_slots=2, prompt_buckets=(4, 8), eos_id=None, block_size=4,
-            decode_burst=2))
-    return SlotEngine(model, params, EngineConfig(
-        max_slots=2, prompt_buckets=(4, 8), eos_id=None, decode_burst=2))
+    return PagedEngine(model, params, EngineConfig(
+        max_slots=2, prompt_buckets=(4, 8), eos_id=None, block_size=4,
+        decode_burst=2, prefix_cache=kind == "prefix"))
 
 
 def serve(lm, kind: str, traced: bool = True, *, requests: int = 4,
@@ -96,7 +92,7 @@ def lane_spans(rec: TraceRecorder) -> list:
 
 
 # ------------------------------------------------------------ serve ticks
-@pytest.mark.parametrize("kind", ["paged", "slot"])
+@pytest.mark.parametrize("kind", ["paged", "prefix"])
 def test_every_tick_is_one_span_with_its_phases_as_children(lm, kind):
     sched, rec, n_ticks = serve(lm, kind)
     spans = lane_spans(rec)
@@ -137,8 +133,7 @@ def test_every_tick_is_one_span_with_its_phases_as_children(lm, kind):
     for r in spans:
         if r.name in ("burst_dispatch", "burst_readback"):
             assert by_link[r.parent].name == "decode_burst"
-    if kind == "paged":
-        assert any(r.name == "burst_plan" for r in spans)
+    assert any(r.name == "burst_plan" for r in spans)
     # the export carries the linkage and stays validator-clean
     trace = rec.to_chrome_trace()
     assert validate(trace) == []
@@ -283,7 +278,7 @@ def annotations(monkeypatch):
     return CountingAnnotation.names
 
 
-@pytest.mark.parametrize("kind", ["paged", "slot"])
+@pytest.mark.parametrize("kind", ["paged", "prefix"])
 def test_no_tracer_no_record_and_no_annotation(lm, kind, annotations,
                                                monkeypatch):
     from ddp_practice_tpu.serve import engine, scheduler
@@ -295,7 +290,7 @@ def test_no_tracer_no_record_and_no_annotation(lm, kind, annotations,
     # builds no span, so no attr is computed for one either
     monkeypatch.setattr(scheduler.Scheduler, "_traced_tick", built)
     for maker in ("_span", "_prefill_spans", "_burst_spans"):
-        monkeypatch.setattr(engine._EngineBase, maker, built)
+        monkeypatch.setattr(engine.PagedEngine, maker, built)
     sched, _, _ = serve(lm, kind, traced=False)
     assert annotations == []
     assert sched.engine.tracer is None and not sched.engine._slot_trace
@@ -307,7 +302,7 @@ def test_a_disabled_tracer_costs_what_none_costs(lm, annotations):
     assert annotations == [] and len(rec) == 0
 
 
-@pytest.mark.parametrize("kind", ["paged", "slot"])
+@pytest.mark.parametrize("kind", ["paged", "prefix"])
 def test_spans_are_mirrored_under_a_closed_set_of_names(lm, kind,
                                                         annotations):
     _, rec, _ = serve(lm, kind)
@@ -497,12 +492,19 @@ def test_paged_programs_keep_the_names_the_readers_match(lm, attr, needle):
     assert needle in fn.__name__
 
 
-@pytest.mark.parametrize("attr,needle", [
-    ("_prefill_jit", "prefill_admit"),
-    ("_decode_jit", "decode_burst"),
+@pytest.mark.parametrize("kind,ran,idle", [
+    ("paged", "prefill_compiles", "prefix_prefill_compiles"),
+    ("prefix", "prefix_prefill_compiles", "prefill_compiles"),
 ])
-def test_slot_programs_keep_the_names_the_readers_match(lm, attr, needle):
-    assert needle in getattr(make_engine(lm, "slot"), attr).__name__
+def test_an_admission_layout_runs_the_program_its_readers_match(
+        lm, kind, ran, idle):
+    """A cell's prefill time is read off ONE of the two program names: a
+    left-padded admission must dispatch `_prefill_admit` alone and a
+    prefix-cache admission `_prefix_prefill` alone."""
+    sched, _, _ = serve(lm, kind, traced=False)
+    stats = sched.engine.compile_stats()
+    assert stats[ran] == 1 and stats[idle] == 0
+    assert stats["decode_compiles"] == 1
 
 
 def pallas_names(jaxpr) -> list:

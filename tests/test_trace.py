@@ -299,7 +299,7 @@ def test_scheduler_engine_spans_and_flight_records(lm):
     """One FakeClock replica: queued/request lifecycle spans, per-slot
     prefill lanes, decode-burst spans on the engine lane, flight records
     on every completion — and the export is validator-clean."""
-    from ddp_practice_tpu.serve.engine import EngineConfig, SlotEngine
+    from ddp_practice_tpu.serve.engine import EngineConfig, PagedEngine
     from ddp_practice_tpu.serve.scheduler import (
         FakeClock,
         Request,
@@ -309,7 +309,7 @@ def test_scheduler_engine_spans_and_flight_records(lm):
     model, params = lm
     clock = FakeClock(step_s=0.01)
     rec = TraceRecorder(clock=clock)
-    engine = SlotEngine(model, params, EngineConfig(
+    engine = PagedEngine(model, params, EngineConfig(
         max_slots=2, prompt_buckets=(4, 8), eos_id=None,
     ))
     engine.set_tracer(rec, 0)
@@ -359,7 +359,7 @@ def test_scheduler_engine_spans_and_flight_records(lm):
 def test_tracer_off_records_nothing(lm):
     """tracer=None (the production default) leaves zero records and the
     engines' hot path un-annotated; flight records still attach."""
-    from ddp_practice_tpu.serve.engine import EngineConfig, SlotEngine
+    from ddp_practice_tpu.serve.engine import EngineConfig, PagedEngine
     from ddp_practice_tpu.serve.scheduler import (
         FakeClock,
         Request,
@@ -367,7 +367,7 @@ def test_tracer_off_records_nothing(lm):
     )
 
     model, params = lm
-    engine = SlotEngine(model, params, EngineConfig(
+    engine = PagedEngine(model, params, EngineConfig(
         max_slots=2, prompt_buckets=(4,), eos_id=None,
     ))
     sched = Scheduler(engine, clock=FakeClock(step_s=0.01))
@@ -383,7 +383,7 @@ def test_evacuate_reports_attempt_phases(lm):
     the ONLY record of its pre-crash queue/prefill/decode time (the
     router folds them in; without them the work would misreport as
     stall_s)."""
-    from ddp_practice_tpu.serve.engine import EngineConfig, SlotEngine
+    from ddp_practice_tpu.serve.engine import EngineConfig, PagedEngine
     from ddp_practice_tpu.serve.scheduler import (
         FakeClock,
         Request,
@@ -392,7 +392,7 @@ def test_evacuate_reports_attempt_phases(lm):
 
     model, params = lm
     clock = FakeClock(step_s=0.01)
-    engine = SlotEngine(model, params, EngineConfig(
+    engine = PagedEngine(model, params, EngineConfig(
         max_slots=1, prompt_buckets=(4,), eos_id=None,
     ))
     sched = Scheduler(engine, clock=clock)

@@ -9,7 +9,7 @@ preempt, failover, retry, resumed), exemplar gating (histograms and
 that streamed a span has decided KEEP — the router honors it), and the
 OTLP-JSON export against tools/check_otlp.py.
 
-Then the integration tiers: a real SlotEngine + Scheduler run at a 10%
+Then the integration tiers: a real PagedEngine + Scheduler run at a 10%
 head rate (the in-process half of the coherence contract), and THE
 acceptance e2e (slow+chaos): a 2-worker fleet at a 1% head rate,
 worker 0 SIGKILLed mid-decode — every failover-affected request must
@@ -495,22 +495,22 @@ def lm():
 
 
 def test_scheduler_head_samples_end_to_end(devices, lm):
-    """30 requests through a REAL SlotEngine at a 10% head rate: the
+    """30 requests through a REAL PagedEngine at a 10% head rate: the
     completions' trace_sampled bits match head_keep exactly, no
     unsampled trace_id leaks into the timeline, and the OTLP export
     carries exactly the sampled population."""
     from ddp_practice_tpu.serve import (
         EngineConfig,
         FakeClock,
+        PagedEngine,
         Request,
         Scheduler,
         ServeMetrics,
-        SlotEngine,
     )
 
     model, params = lm
-    engine = SlotEngine(model, params, EngineConfig(
-        max_slots=3, max_len=96, prompt_buckets=(8,), eos_id=-1,
+    engine = PagedEngine(model, params, EngineConfig(
+        max_slots=3, max_len=16, prompt_buckets=(8,), eos_id=-1,
     ))
     tracer = TraceRecorder()
     tracer.set_sampler(TraceSampler(0.10))

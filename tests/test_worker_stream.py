@@ -65,9 +65,9 @@ def _expected_tokens(trace):
     model, params = build_model(MODEL_KW)
     eng_kw = dict(ENGINE_KW)
     eng_kw["prompt_buckets"] = tuple(eng_kw["prompt_buckets"])
-    from ddp_practice_tpu.serve.engine import SlotEngine
+    from ddp_practice_tpu.serve.engine import PagedEngine
 
-    engine = SlotEngine(model, params, EngineConfig(**eng_kw))
+    engine = PagedEngine(model, params, EngineConfig(**eng_kw))
     sched = Scheduler(engine, max_queue=64)
     for t in trace:
         sched.submit(Request(**t))
