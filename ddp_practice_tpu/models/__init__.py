@@ -8,7 +8,7 @@ replacing ddp_main.py:120).
 
 Ladder beyond parity (BASELINE.json configs): ResNet-18/50, ViT-Tiny.
 
-`HybridLM` (models/hybrid_lm.py) is in the registry four times, one layout
+`HybridLM` (models/hybrid_lm.py) is in the registry five times, one layout
 of its pattern string each; the defaults are test-sized and the published
 widths come as options (perf/families/*.py `model_options`):
 
@@ -23,12 +23,21 @@ widths come as options (perf/families/*.py `model_options`):
                  past `sparse.dense_len`), 'D'; muP scalars on the stream
                  (`embed_scale`, `residual_scale`, `head_scale`),
                  pos_emb="rope"
+    smallthinker 'W' window attention with rotary, every fourth '*' with
+                 no positional embedding, heads of `head_dim`; 'R' ReGLU
+                 experts under a softmax router that reads the mixer's
+                 normed input, no shared expert; pos_emb="rope",
+                 recurrent=False, a chunk's attention in `window_prefill`
 
-All four hold recurrent state (`recurrent=True`): `PagedEngine` serves them,
-admits a long prompt in chunks over the slot's own state (`prefill_chunk`
-without `prefix_cache`) and refuses `prefix_cache`, `spec_decode` and
-`fork()`, which need the state at a position that is not the sequence's end
-(ROADMAP M6).
+The first four hold recurrent state (`recurrent=True`): `PagedEngine` serves
+them, admits a long prompt in chunks over the slot's own state
+(`prefill_chunk` without `prefix_cache`) and refuses `prefix_cache`,
+`spec_decode` and `fork()`, which need the state at a position that is not
+the sequence's end (ROADMAP M6). The fifth holds none, but its window
+layers' pages are a group of their own that gives back what lies behind a
+slot's window (serve/kv_pages.py CacheSpec): it is admitted in chunks the
+same way, and the same three are refused, each for the pages a window gave
+back (ROADMAP M3).
 """
 
 from typing import Optional
@@ -294,6 +303,33 @@ def _minicpm_sala(*, num_classes, policy, axis_name, **kw):
         kw["lightning_layers"] = tuple(
             i for i, m in enumerate(mixers) if m == "L")
         kw.setdefault("decay_layers", len(mixers))
+    return HybridLM(
+        dtype=policy.compute_dtype,
+        param_dtype=policy.param_dtype,
+        **kw,
+    )
+
+
+@register("smallthinker")
+def _smallthinker(*, num_classes, policy, axis_name, **kw):
+    # the same HybridLM in SmallThinker's layout: a layer is a mixer
+    # sub-layer (window attention with rotary 'W', every fourth '*': no
+    # positional embedding, every key) and ReGLU experts 'R' routed on the
+    # mixer's normed input; no recurrent state, a prompt's chunks through
+    # `window_prefill`; test-sized defaults, the published widths come as
+    # options (perf/families/smallthinker.py model_options)
+    kw.setdefault("pattern", "*RWRWRWR")
+    kw.setdefault("pos_emb", "rope")
+    kw.setdefault("recurrent", False)
+    kw.setdefault("norm_eps", 1e-6)
+    kw.setdefault("window", 8)
+    kw.setdefault("attn_prefill", "kernel")
+    kw.setdefault("hidden_dim", 48)
+    kw.setdefault("head_dim", 16)
+    kw.setdefault("num_experts", 8)
+    kw.setdefault("experts_held", 8)
+    kw.setdefault("top_k", 2)
+    kw.setdefault("expert_dim", 3)
     return HybridLM(
         dtype=policy.compute_dtype,
         param_dtype=policy.param_dtype,

@@ -14,9 +14,16 @@ a pattern string.
     'E'  LatentMoE, a chip's share of the experts held  (ops/moe.py)
     'Q'  GatedMoE with a softmax router and a shared expert behind a
          sigmoid gate of its own, a chip's share held   (ops/moe.py)
+    'R'  GatedMoE with ReGLU experts, a softmax router that reads the
+         normed input of the MIXER before it (not its own), no shared
+         expert                                         (ops/moe.py)
     'D'  dense SwiGLU MLP of width `mlp_dim`            (ops/moe.py GatedMLP)
     '*'  causal attention, `kv_heads` <= `num_heads`, no positional
-         embedding: the recurrent layers carry position
+         embedding: the recurrent layers carry position, or nothing does
+         (heads of `head_dim` where that is not width / heads)
+    'W'  window attention: '*' with rotary on the whole head and a
+         sliding window of `window` keys, the query's own among them; its
+         pages are the window GROUP's (`cache_spec`)
     'A'  gated attention: heads of `head_dim` whatever the width, a norm
          on every q and k head, rotary on a head's first `rope_dim`
          lanes, the output times sigmoid(gate), the gate the query
@@ -29,7 +36,7 @@ a pattern string.
                                        `tie_embeddings` W_head is the
                                        embedding itself
 
-Four layouts in the registry. Nemotron-H (`create_model("nemotron_h",
+Five layouts in the registry. Nemotron-H (`create_model("nemotron_h",
 ...)`): one mixer a layer from 'M', 'E', '*', untied head. Jamba
 (`create_model("jamba", ...)`): a layer is two sub-layers, a mixer ('S' or
 '*') then 'D', so 28 layers are 56 letters, and the head is tied. Qwen3-Next
@@ -38,10 +45,14 @@ Four layouts in the registry. Nemotron-H (`create_model("nemotron_h",
 head. MiniCPM-SALA (`create_model("minicpm_sala", ...)`): a mixer ('L',
 every fourth 'B') then 'D', with muP scalars on the stream: `embed_scale`
 times the embedding, `residual_scale` times every branch, `head_scale`
-times the head's input (each 1 in the other layouts). The widths are
-options, so the tests run all four small and the benchmark at the published
+times the head's input (each 1 in the other layouts). SmallThinker
+(`create_model("smallthinker", ...)`): a mixer ('W', every fourth '*' with
+no positional embedding at all) then 'R', no recurrent state
+(`recurrent=False`), a prompt's chunks through the kernel `window_prefill`
+(`attn_prefill="kernel"`), untied head. The widths are
+options, so the tests run all five small and the benchmark at the published
 sizes (perf/configs/nemotron3_super_ep4.json, jamba2_3b.json,
-qwen3next_80b_ep4.json, minicpm_sala_9b_pp4.json).
+qwen3next_80b_ep4.json, minicpm_sala_9b_pp4.json, smallthinker_21b_pp7.json).
 
 Decode mode keeps TWO kinds of cache in the "cache" collection: attention
 layers the K/V leaves `SelfAttention` declares (flat, or pages under
@@ -588,14 +599,19 @@ class HybridLM(nn.Module):
     sparse: sparse_ops.SparseSpec = sparse_ops.SparseSpec()
     # 'D'
     mlp_dim: int = 128
-    # '*', 'A'
+    # '*', 'A', 'W'
     num_heads: int = 4
     kv_heads: int = 2
     head_dim: int = 16
+    # 'W': keys a query attends, its own among them
+    window: int = 0
+    # '*', 'W': a paged chunk's attention, "xla" or "kernel"
+    # (models/vit.py SelfAttention.paged_prefill)
+    attn_prefill: str = "xla"
     # 'A'
     rope_dim: int = 8
     rope_theta: float = 10000.0
-    # 'E', 'Q' ('Q' has no latent)
+    # 'E', 'Q', 'R' ('Q' and 'R' have no latent, 'R' no shared expert)
     num_experts: int = 16
     top_k: int = 3
     latent_dim: int = 32
@@ -622,13 +638,22 @@ class HybridLM(nn.Module):
     recurrent: bool = True
     axis_name: Optional[str] = None  # registry uniformity
 
+    def cache_spec(self) -> dict:
+        """The page group of each layer, as the fields of serve/kv_pages.py
+        `CacheSpec`: the 'W' layers' pages are the window group's."""
+        return {"window": self.window, "window_layers": tuple(
+            f"attn{i}" for i, kind in enumerate(self.pattern)
+            if kind == "W")}
+
     @nn.compact
     def __call__(self, tokens, *, train: bool = False, decode: bool = False,
                  attn_start=None, page_table=None, kv_lengths=None,
                  real_lengths=None):
         """tokens (batch, seq) int32 -> logits (batch, seq, vocab_size) in
         the compute dtype. `decode`, `attn_start`, `page_table` and
-        `kv_lengths` as in models/lm.py TransformerLM; `real_lengths`
+        `kv_lengths` as in models/lm.py TransformerLM (`page_table` a dict
+        of tables by page group where `cache_spec` names more than the
+        global one: {"global": ..., "window": ...}); `real_lengths`
         (batch,): the tokens of a paged call that are real, the rest right
         padding the recurrent layers do not advance over; a call of several
         tokens then returns the logits of each sequence's LAST REAL position
@@ -636,19 +661,17 @@ class HybridLM(nn.Module):
         (the head over a 2,048-token chunk's every row is 1.2 TFLOP at
         73,448 rows)."""
         del train
-        if set(self.pattern) - set("MSGLEQD*AB") or not self.pattern:
+        if set(self.pattern) - set("MSGLEQRD*AWB") or not self.pattern:
             raise ValueError(
                 f"pattern {self.pattern!r}: want a string of 'M', 'S', 'G', "
-                "'L', 'E', 'Q', 'D', '*', 'A', 'B'")
-        if "*" in self.pattern \
-                and self.hidden_dim != self.num_heads * self.head_dim:
+                "'L', 'E', 'Q', 'R', 'D', '*', 'A', 'W', 'B'")
+        if set("ALW") & set(self.pattern) and self.pos_emb != "rope":
             raise ValueError(
-                "'*' takes its head size from the width: "
-                f"hidden_dim {self.hidden_dim} != num_heads "
-                f"{self.num_heads} x head_dim {self.head_dim}")
-        if set("AL") & set(self.pattern) and self.pos_emb != "rope":
-            raise ValueError(
-                "'A' and 'L' rotate q and k: want pos_emb='rope'")
+                "'A', 'L' and 'W' rotate q and k: want pos_emb='rope'")
+        if "W" in self.pattern and self.window < 1:
+            raise ValueError(f"'W' attends a window of keys: {self.window}")
+        if self.pattern[0] == "R":
+            raise ValueError("'R' routes on the input of the mixer before it")
         if self.pattern.count("L") != len(self.lightning_layers):
             raise ValueError(
                 f"lightning_layers {self.lightning_layers} numbers the 'L' "
@@ -667,6 +690,11 @@ class HybridLM(nn.Module):
         norm = functools.partial(RMSNorm, self.norm_eps,
                                  plus_one=self.norm_plus_one, **kw)
         paged = page_table is not None
+        tables = page_table if isinstance(page_table, dict) \
+            else {"global": page_table, "window": page_table}
+        # '*' and 'W': heads of `head_dim` where the width does not give it
+        own_head = {} if self.hidden_dim == self.num_heads * self.head_dim \
+            else {"head_dim": self.head_dim}
         recur = dict(decode=decode, attn_start=attn_start, paged=paged,
                      real_lengths=real_lengths)
         if "L" in self.pattern:
@@ -688,6 +716,8 @@ class HybridLM(nn.Module):
         lightning = iter(self.lightning_layers)
         for i, kind in enumerate(self.pattern):
             y = norm(name=f"norm{i}")(x)
+            if kind not in "EQRD":
+                mixer_in = y   # what a router placed before the mixer reads
             if kind == "L":
                 y = LightningMixer(
                     self.num_heads, self.head_dim, next(lightning),
@@ -699,7 +729,7 @@ class HybridLM(nn.Module):
                     self.num_heads, self.kv_heads, self.head_dim,
                     self.sparse, norm, name=f"attn{i}", **kw,
                 )(y, decode=decode, attn_start=attn_start,
-                  page_table=page_table, kv_lengths=kv_lengths,
+                  page_table=tables["global"], kv_lengths=kv_lengths,
                   real_lengths=real_lengths)
             elif kind == "M":
                 y = Mamba2Mixer(
@@ -727,6 +757,13 @@ class HybridLM(nn.Module):
                     self.routed_scaling, router="softmax", shared_gate=True,
                     name=f"moe{i}", **kw,
                 )(y, decode=decode)
+            elif kind == "R":
+                y = GatedMoE(
+                    self.num_experts, self.top_k, self.expert_dim, 0,
+                    self.experts_held, self.expert_offset,
+                    self.routed_scaling, router="softmax",
+                    activation="relu", name=f"moe{i}", **kw,
+                )(y, decode=decode, router_input=mixer_in)
             elif kind == "E":
                 y = LatentMoE(
                     self.num_experts, self.top_k, self.latent_dim,
@@ -742,14 +779,20 @@ class HybridLM(nn.Module):
                     rope_dim=self.rope_dim, rope_theta=self.rope_theta,
                     out_gate=True, name=f"attn{i}", **kw,
                 )(y, decode=decode, attn_start=attn_start,
-                  page_table=page_table, kv_lengths=kv_lengths)
-            else:
+                  page_table=tables["global"], kv_lengths=kv_lengths)
+            else:   # '*', or 'W': rotary, a window, the window group's pages
+                win = kind == "W"
                 y = SelfAttention(
-                    self.num_heads, causal=True, rope=False,
+                    self.num_heads, causal=True, rope=win,
                     kv_heads=self.kv_heads, use_bias=False,
-                    name=f"attn{i}", **kw,
+                    rope_theta=self.rope_theta,
+                    window=self.window if win else None,
+                    paged_prefill=self.attn_prefill, name=f"attn{i}",
+                    **own_head, **kw,
                 )(y, decode=decode, attn_start=attn_start,
-                  page_table=page_table, kv_lengths=kv_lengths)
+                  page_table=tables["window" if win else "global"],
+                  kv_lengths=kv_lengths,
+                  real_lengths=real_lengths if paged else None)
             if self.residual_scale != 1.0:
                 y = y * self.residual_scale
             x = x + y

@@ -162,6 +162,19 @@ class SelfAttention(nn.Module):
     # and a sigmoid gate on the attention output from the query
     # projection's second half (a head projects to [query | gate])
     out_gate: bool = False
+    # a sliding window: a query attends the `window` latest keys, its own
+    # among them (None: every key before it). Under pages the layer's
+    # leaves are the window GROUP's (serve/kv_pages.py CacheSpec): the pages
+    # behind the window go back to the pool, a decode step walks from
+    # `window_start` under the name `window_walk`, and a per-slot leaf
+    # `window_stats` counts the pages it read beside a whole walk's
+    window: Optional[int] = None
+    # a paged call of several tokens (a prompt's chunk): "xla" gathers the
+    # slot's whole span, widens K and V to a head a query head and masks;
+    # "kernel" is ops/window_attention.py `window_prefill` (grouped KV
+    # heads read as they are, pages behind a window neither fetched nor
+    # computed, nothing the size of the span)
+    paged_prefill: str = "xla"
 
     def _project(self, x, head_dim: int):
         """(q, k, v, fused): q (b, s, h, hd), k and v (b, s, kv_heads,
@@ -295,7 +308,7 @@ class SelfAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, decode: bool = False, attn_start=None,
-                 page_table=None, kv_lengths=None):
+                 page_table=None, kv_lengths=None, real_lengths=None):
         b, s, d = x.shape
         if self.head_dim is None:
             assert d % self.num_heads == 0, (d, self.num_heads)
@@ -303,12 +316,13 @@ class SelfAttention(nn.Module):
         q, k, v, qkv = self._project(x, head_dim)
         impl = self.resolve_attn_impl(s, head_dim, decode=decode)
         later = (self.head_dim is not None or self.qk_norm is not None
-                 or self.rope_dim is not None or self.out_gate)
+                 or self.rope_dim is not None or self.out_gate
+                 or self.window is not None)
         if later and (impl != "xla" or qkv is not None):
             raise ValueError(
-                "head_dim, qk_norm, rope_dim and out_gate run on the plain "
-                "attention path with a split q / kv projection: attn_impl "
-                f"resolved to {impl!r}, kv_heads {self.kv_heads}")
+                "head_dim, qk_norm, rope_dim, out_gate and window run on the "
+                "plain attention path with a split q / kv projection: "
+                f"attn_impl resolved to {impl!r}, kv_heads {self.kv_heads}")
         gate = None
         if self.out_gate:
             q, gate = q[..., :head_dim], q[..., head_dim:]
@@ -430,7 +444,8 @@ class SelfAttention(nn.Module):
                 # cursor. Declares its own cache variables, so it must
                 # branch before the flat-cache declarations below.
                 return self._out_proj(self._paged_decode(
-                    q, k, v, page_table, kv_lengths, attn_start
+                    q, k, v, page_table, kv_lengths, attn_start,
+                    real_lengths
                 ), gate, d)
             # "int8": quantized cache — 1 byte/element plus per-(batch,
             # head, position) fp32 scales. Decode is HBM-bound and the
@@ -465,6 +480,9 @@ class SelfAttention(nn.Module):
                     "cache", "cached_value_scale", jnp.zeros,
                     (b_, h_, s_), jnp.float32,
                 )
+            if self.window is not None:   # tree parity with the pages'
+                self.variable("cache", "window_stats", jnp.zeros, (b_, 2),
+                              jnp.int32)
             if self.is_initializing():
                 out = dot_product_attention(
                     q, *self._widen_kv(k, v), causal=True, impl="xla")
@@ -550,6 +568,9 @@ class SelfAttention(nn.Module):
                               * vs_t[..., None]).astype(q.dtype)
                     pos_q = cur + jnp.arange(s)
                     mask = jnp.arange(max_len)[None, :] <= pos_q[:, None]
+                    if self.window is not None:
+                        mask &= jnp.arange(max_len)[None, :] \
+                            > pos_q[:, None] - self.window
                     if attn_start is not None:
                         # left-padded prompts (inference.py variable-
                         # length batching): key positions before each
@@ -561,6 +582,15 @@ class SelfAttention(nn.Module):
                         mask = mask[:, None]  # (b, 1, sq, sk)
                     out = attention_with_mask(
                         q, *self._widen_kv(k4, v4), mask)
+        elif self.window is not None:
+            from ddp_practice_tpu.ops.attention import attention_with_mask
+
+            if not self.causal:
+                raise ValueError("a window is a causal layer's")
+            at = jnp.arange(s)
+            out = attention_with_mask(
+                q, *self._widen_kv(k, v), (at[None, :] <= at[:, None])
+                & (at[None, :] > at[:, None] - self.window))
         else:
             out = dot_product_attention(
                 q, *self._widen_kv(k, v), causal=self.causal,
@@ -569,7 +599,8 @@ class SelfAttention(nn.Module):
             )
         return self._out_proj(out, gate, d)
 
-    def _paged_decode(self, q, k, v, page_table, kv_lengths, attn_start):
+    def _paged_decode(self, q, k, v, page_table, kv_lengths, attn_start,
+                      real_lengths=None):
         """Paged KV-cache decode step / prefill (serve/kv_pages.py).
 
         The "cache" collection leaves are a POOL of fixed-size blocks
@@ -698,13 +729,47 @@ class SelfAttention(nn.Module):
             vs_pool = value_scale.value.at[blk, :, off].set(vs_new)
             key_scale.value = ks_pool
             value_scale.value = vs_pool
+        if self.window is not None:
+            from ddp_practice_tpu.ops.window_attention import window_start
+
+            stats = self.variable("cache", "window_stats", jnp.zeros,
+                                  (b_, 2), jnp.int32)
         if s_ == 1:
+            first, walk = attn_start, {}
+            if self.window is not None:
+                # the same walk from a later start, through the window
+                # group's table; what it read beside a whole walk's pages
+                first = window_start(pos0, attn_start, self.window)
+                whole = jnp.zeros_like(pos0) if attn_start is None \
+                    else attn_start
+                last = pos0 // block_size
+                stats.value = stats.value + jnp.stack(
+                    [last - first // block_size + 1,
+                     last - whole // block_size + 1], axis=1)
+                walk = {"name": "window_walk"}
             out = paged_decode_attention(
-                q.reshape(b_, 1, -1), kc, vc, page_table, pos0, attn_start,
+                q.reshape(b_, 1, -1), kc, vc, page_table, pos0, first,
                 n_heads=self.num_heads, n_kv_heads=h_,
-                k_scale=ks_pool, v_scale=vs_pool,
+                k_scale=ks_pool, v_scale=vs_pool, **walk,
             )
             return out.reshape(b_, 1, self.num_heads, hd_)
+        if self.paged_prefill == "kernel":
+            from ddp_practice_tpu.ops.window_attention import (
+                NO_WINDOW,
+                window_prefill,
+            )
+
+            if quant:
+                raise ValueError(
+                    "paged_prefill='kernel' reads bf16 or float32 pages")
+            group = self.num_heads // h_
+            outs = [window_prefill(   # a chunk is one sequence's (b = 1)
+                q[i].reshape(s_, h_, group, hd_), kc, vc, page_table[i],
+                pos0[i], start=0 if attn_start is None else attn_start[i],
+                window=self.window or NO_WINDOW,
+                real=None if real_lengths is None else real_lengths[i],
+            ).reshape(s_, self.num_heads, hd_) for i in range(b_)]
+            return jnp.stack(outs).astype(q.dtype)
         # paged prefill: gather the slot's span once (dequantizing int8
         # pools through their scale pages) and mask causally per query
         # row in slot-local coordinates
@@ -715,6 +780,8 @@ class SelfAttention(nn.Module):
         valid = kpos[None, None, :] <= positions[:, :, None]  # (b, s, span)
         if attn_start is not None:
             valid &= kpos[None, None, :] >= attn_start[:, None, None]
+        if self.window is not None:
+            valid &= kpos[None, None, :] > positions[:, :, None] - self.window
         cd = pool_dtype if not quant else q.dtype
         k4, v4 = self._widen_kv(k4, v4)
         out = attention_with_mask(

@@ -698,6 +698,7 @@ def paged_decode_attention(
     k_scale=None,
     v_scale=None,
     impl: str = "auto",
+    name: str = "paged_decode",
 ) -> jnp.ndarray:
     """One paged decode step; returns (b, 1, h*hd). See the module-level
     paged section for the layout.
@@ -707,7 +708,9 @@ def paged_decode_attention(
     double-buffered async copies, whatever the table's width. The
     traced device op is ONE, named `paged_decode` (`paged_decode_int8`
     for the int8 pool): perf/lib/readers.py sums the ops whose name
-    holds "paged_decode".
+    holds "paged_decode". A layer whose walk readers should tell apart
+    gives it a `name` of its own (a window layer's `window_walk`: the same
+    kernel from a later `attn_start`, through its own group's table).
 
     impl: "auto" runs the Pallas kernel on TPU when the heads pack into
     128-lane tiles and the gather reference otherwise (on CPU the
@@ -848,7 +851,7 @@ def paged_decode_attention(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
-        name="paged_decode",
+        name=name,
     )(lens, start, pt, q, k_pages, v_pages)
     if group > 1 and rows != group:
         out = out.reshape(b, kvh, rows, d)[:, :, :group]

@@ -1124,12 +1124,23 @@ def _relu2_body(x_ref, w1_ref, w2_ref):
     return jnp.dot(h, w2_ref[...], preferred_element_type=jnp.float32)
 
 
-def _glu_body(x_ref, wg_ref, wu_ref, wd_ref):
-    """(silu(x W_gate) * x W_up) W_down of one row tile, float32 sums."""
+def _glu_act(g, activation: str):
+    """The gate's activation on float32 `g`: "silu" (SwiGLU) or "relu"
+    (ReGLU). A static choice of the layer: the kernel's body holds the one
+    it was built with and no branch."""
+    if activation == "silu":
+        return g * jax.nn.sigmoid(g)
+    if activation == "relu":
+        return jnp.maximum(g, 0.0)
+    raise ValueError(f"activation {activation!r}: want 'silu' or 'relu'")
+
+
+def _glu_body(x_ref, wg_ref, wu_ref, wd_ref, *, activation: str = "silu"):
+    """(act(x W_gate) * x W_up) W_down of one row tile, float32 sums."""
     x = x_ref[...]
     g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
     u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-    h = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+    h = (_glu_act(g, activation) * u).astype(x.dtype)
     return jnp.dot(h, wd_ref[...], preferred_element_type=jnp.float32)
 
 
@@ -1222,20 +1233,23 @@ def expert_mlp_tiles_kernel(x_rows, w1, w2, tile_expert, tiles_used, *,
 
 
 def expert_glu_tiles(x_rows, w_gate, w_up, w_down, tile_expert, tiles_used,
-                     *, tile: int):
-    """(silu(x Wg_e) * x Wu_e) Wd_e for every row tile, e the tile's
-    expert: gated experts. w_gate, w_up (held, d, f); w_down (held, f, d)."""
+                     *, tile: int, activation: str = "silu"):
+    """(act(x Wg_e) * x Wu_e) Wd_e for every row tile, e the tile's
+    expert: gated experts, `activation` "silu" or "relu". w_gate, w_up
+    (held, d, f); w_down (held, f, d)."""
     if not backend.on_tpu():
         with jax.named_scope("moe_gmm_glu"):
             return expert_glu_tiles_reference(
                 x_rows, w_gate, w_up, w_down, tile_expert, tiles_used,
-                tile=tile)
+                tile=tile, activation=activation)
     return expert_glu_tiles_kernel(
-        x_rows, w_gate, w_up, w_down, tile_expert, tiles_used, tile=tile)
+        x_rows, w_gate, w_up, w_down, tile_expert, tiles_used, tile=tile,
+        activation=activation)
 
 
 def expert_glu_tiles_reference(x_rows, w_gate, w_up, w_down, tile_expert,
-                               tiles_used, *, tile: int):
+                               tiles_used, *, tile: int,
+                               activation: str = "silu"):
     rows, d = x_rows.shape
     n_tiles = rows // tile
     xt = x_rows.reshape(n_tiles, tile, d)
@@ -1243,18 +1257,23 @@ def expert_glu_tiles_reference(x_rows, w_gate, w_up, w_down, tile_expert,
                    preferred_element_type=jnp.float32)
     u = jnp.einsum("tmd,tdf->tmf", xt, w_up[tile_expert],
                    preferred_element_type=jnp.float32)
-    h = (g * jax.nn.sigmoid(g) * u).astype(x_rows.dtype)
+    h = (_glu_act(g, activation) * u).astype(x_rows.dtype)
     out = jnp.einsum("tmf,tfd->tmd", h, w_down[tile_expert],
                      preferred_element_type=jnp.float32)
     return _live_tiles(out, n_tiles, tiles_used, x_rows)
 
 
 def expert_glu_tiles_kernel(x_rows, w_gate, w_up, w_down, tile_expert,
-                            tiles_used, *, tile: int):
+                            tiles_used, *, tile: int,
+                            activation: str = "silu"):
     """The device op `moe_gmm_glu`: the three matrices of an expert in
-    VMEM, two deep (2 x 9.4 MB at 2048 -> 768 -> 2048 in bf16)."""
+    VMEM, two deep (2 x 9.4 MB at 2048 -> 768 -> 2048 in bf16, 2 x 11.8 MB
+    at 2560 -> 768 -> 2560)."""
+    # "silu" hands over `_glu_body` itself, as before the option came
+    body = _glu_body if activation == "silu" else functools.partial(
+        _glu_body, activation=activation)
     return _expert_tiles_call(
-        _glu_body, "moe_gmm_glu", x_rows, (w_gate, w_up, w_down),
+        body, "moe_gmm_glu", x_rows, (w_gate, w_up, w_down),
         tile_expert, tiles_used, tile)
 
 
@@ -1624,8 +1643,11 @@ class GatedMoE(nn.Module):
 
     `router="softmax"`: s = softmax(x W_r), picks = top_k(s), no bias.
     `shared_gate`: the shared expert times sigmoid(x w_s), a gate of its
-    own (Qwen's). The expert matrices go through ONE kernel,
-    `moe_gmm_glu`."""
+    own (Qwen's). `shared_dim` 0: no shared expert. `activation`: the
+    experts' gate, "silu" or "relu" (ReGLU). `router_input`: what the
+    router scores where that is not the rows the experts read (a router
+    placed before the layer's mixer). The expert matrices go through ONE
+    kernel, `moe_gmm_glu`."""
 
     num_experts: int
     top_k: int
@@ -1638,27 +1660,33 @@ class GatedMoE(nn.Module):
     param_dtype: jnp.dtype = jnp.float32
     router: str = "sigmoid"
     shared_gate: bool = False
+    activation: str = "silu"
 
     @nn.compact
-    def __call__(self, x, *, decode: bool = False):
+    def __call__(self, x, *, decode: bool = False, router_input=None):
         lead, d = x.shape[:-1], x.shape[-1]
         cd, held, f = self.dtype, self.experts_held, self.expert_dim
         xf = x.reshape(-1, d).astype(cd)
-        rows, lay, weights, tile = _held_rows(self, xf, xf)
+        scored = xf if router_input is None \
+            else router_input.reshape(-1, d).astype(cd)
+        rows, lay, weights, tile = _held_rows(self, scored, xf)
         mats = [self.param(name, nn.initializers.normal(0.02), shape,
                            self.param_dtype).astype(cd)
                 for name, shape in (("expert_gate", (held, d, f)),
                                     ("expert_up", (held, d, f)),
                                     ("expert_down", (held, f, d)))]
         out = expert_glu_tiles(rows, *mats, lay["tile_expert"],
-                               lay["tiles_used"], tile=tile)
+                               lay["tiles_used"], tile=tile,
+                               activation=self.activation)
         y = _held_combine(out, lay, weights, cd, tile)
-        shared = GatedMLP(self.shared_dim, cd, self.param_dtype,
-                          name="shared")(xf)
-        if self.shared_gate:
-            shared = shared * nn.sigmoid(nn.Dense(
-                1, use_bias=False, dtype=cd, param_dtype=self.param_dtype,
-                name="shared_expert_gate")(xf))
-        y = y + shared
+        if self.shared_dim:
+            shared = GatedMLP(self.shared_dim, cd, self.param_dtype,
+                              name="shared")(xf)
+            if self.shared_gate:
+                shared = shared * nn.sigmoid(nn.Dense(
+                    1, use_bias=False, dtype=cd,
+                    param_dtype=self.param_dtype,
+                    name="shared_expert_gate")(xf))
+            y = y + shared
         _held_count(self, lay, decode, tile)
         return y.reshape(*lead, d).astype(x.dtype)

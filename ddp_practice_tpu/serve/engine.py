@@ -64,9 +64,13 @@ from ddp_practice_tpu.serve.kv_pages import (
     GARBAGE_BLOCK,
     INDEX_LEAF,
     LATENT_LEAF,
+    SLOT_STATS_LEAF,
+    WINDOW_STATS_LEAF,
     BlockAllocator,
+    PageGroup,
     RadixPrefixCache,
     SlotAllocator,
+    cache_spec,
     copy_block,
     leaf_kind,
     leaf_name,
@@ -122,6 +126,12 @@ class EngineConfig:
     # ceil(max_len / block_size). THIS is a slot's attention span — size
     # it to the workload's real contexts, not the pool.
     max_blocks_per_slot: int = 0
+    # pool blocks of the WINDOW page group (a model whose `cache_spec`
+    # names window layers); 0 = 1 garbage block + max_slots * the most a
+    # slot ever holds there, ceil((window + prefill_chunk) / block_size) + 1
+    # (full backing). Smaller oversubscribes: admission and growth then gate
+    # on this group's pages as on the global one's.
+    window_blocks: int = 0
     # radix prefix cache over the block pool (serve/kv_pages.py
     # RadixPrefixCache): admissions whose prompt prefix is already
     # resident share those blocks refcounted and prefill only the
@@ -299,6 +309,9 @@ def warm_engine(engine, widths=None) -> None:
     engine.moe_rows_moved = engine.moe_rows_layout = 0
     engine.ssm_scan_tokens = engine.ssm_scan_padded_tokens = 0
     engine.sparse_pages_walked = engine.sparse_pages_held = 0
+    engine.window_pages_walked = engine.window_pages_whole = 0
+    if engine.wgroup is not None:
+        engine.wgroup.freed = 0
 
 
 class PagedEngine:
@@ -389,6 +402,10 @@ class PagedEngine:
     # decode burst; None for a model without held experts
     last_burst_experts = None
     last_burst_sparse = None
+    # (pages the window layers' walks read, pages whole walks would have) of
+    # the last decode burst, as the program counted them; None for a model
+    # without a window group
+    last_burst_window = None
 
     def __init__(self, model, params, config: EngineConfig = EngineConfig(),
                  *, batch_stats: Any = None,
@@ -422,6 +439,33 @@ class PagedEngine:
                     raise ValueError(
                         f"{option} is refused for a model with recurrent "
                         f"state: {why}")
+        # a model whose window layers' pages are a group of their own
+        # (serve/kv_pages.py CacheSpec): the pages behind a slot's window go
+        # back to that group's allocator, so whatever needs them again is
+        # refused (ROADMAP M3), and every admission is a chunk admission,
+        # whose `window_prefill` walks the window's pages and no others
+        spec = cache_spec(model)
+        self._window = int(spec.window) if spec.window_layers else 0
+        if self._window:
+            for option, why in (
+                ("prefix_cache", "a window layer's pages behind the window "
+                 "are given back, so a published prefix has none to share"),
+                ("spec_decode", "a rejected draft's positions cannot be "
+                 "rewound over pages the window already gave back"),
+            ):
+                if getattr(config, option):
+                    raise ValueError(
+                        f"{option} is refused for a model with a window "
+                        f"page group: {why}")
+            if not config.prefill_chunk:
+                raise ValueError(
+                    "a model with a window page group is admitted in "
+                    "chunks: set prefill_chunk (a whole prompt scattered "
+                    "from a scratch prefill would need every page of its "
+                    "window layers at once)")
+        # every admission a chunk admission at canonical positions, told
+        # where its padding starts: the recurrent models' and this one's
+        self._chunk_only = self._recurrent or bool(self._window)
         if not config.prompt_buckets:
             raise ValueError("prompt_buckets must be non-empty")
         if config.decode_burst < 1:
@@ -444,7 +488,7 @@ class PagedEngine:
                     "slot sampling at its own temperature would break"
                 )
         if config.prefill_chunk:
-            if not config.prefix_cache and not self._recurrent:
+            if not config.prefix_cache and not self._chunk_only:
                 raise ValueError(
                     "prefill_chunk needs prefix_cache=True — chunks "
                     "append at canonical right-padded positions through "
@@ -495,12 +539,28 @@ class PagedEngine:
         # needs the sequence to start there); else a bucket's LEFT padding
         # counts as positions
         self._canonical = config.prefix_cache or (
-            self._recurrent and bool(config.prefill_chunk))
+            self._chunk_only and bool(config.prefill_chunk))
         # matched tokens of the MOST RECENT admit (None = no prefix
         # cache): the scheduler reads this right after admit() to book
         # prefix_hit_tokens into the request's flight record
         self.last_prefix_hit: Optional[int] = None
-        self._cache = make_paged_cache(model, num_blocks, bs, max_slots=s)
+        # the window group's host side: its own allocator and table, a slot
+        # never past `window_pages` of it whatever its context
+        self.wgroup = None
+        if self._window:
+            self.window_pages_a_slot = spec.window_pages(
+                bs, config.prefill_chunk)
+            self.wgroup = PageGroup(
+                config.window_blocks or 1 + s * self.window_pages_a_slot,
+                s, self.max_blocks_per_slot)
+            if self.window_pages_a_slot > self.wgroup.blocks.num_blocks - 1:
+                raise ValueError(
+                    f"window_blocks {self.wgroup.blocks.num_blocks} cannot "
+                    f"hold one slot's {self.window_pages_a_slot} pages")
+        self._cache = make_paged_cache(
+            model, num_blocks, bs, max_slots=s,
+            window_blocks=self.wgroup.blocks.num_blocks if self._window
+            else 0)
         flat = jax.tree_util.tree_flatten_with_path(self._cache)[0]
         # bytes of the per-slot state pool (gauge `ssm_state_bytes`), and
         # the expert layers' picks a decode step routes: slots x top-k x
@@ -516,7 +576,17 @@ class PagedEngine:
         self.index_cache_bytes = int(sum(
             a.nbytes for path, a in flat if leaf_name(path) == INDEX_LEAF))
         self._sparse_layers = sum(
-            1 for path, _ in flat if leaf_kind(path) == "slots")
+            1 for path, _ in flat if leaf_name(path) == SLOT_STATS_LEAF)
+        # attention layers by page group (a window layer declares
+        # `window_stats`; every layer with K pages a `cached_key`)
+        self._window_layers = sum(
+            1 for path, _ in flat if leaf_name(path) == WINDOW_STATS_LEAF)
+        self._global_layers = sum(
+            1 for path, _ in flat if leaf_name(path) == "cached_key"
+        ) - self._window_layers
+        self.window_pages_walked = 0   # cumulative (metrics export)
+        self.window_pages_whole = 0
+        self._burst_freed = 0          # window pages the last burst gave back
         self._dense_len = int(getattr(
             getattr(model, "sparse", None), "dense_len", 0))
         self.sparse_pages_walked = 0   # cumulative (metrics export)
@@ -659,6 +729,7 @@ class PagedEngine:
             pool = jax.tree_util.tree_map_with_path(
                 lambda path, a: jnp.where(pos0 == 0, 0, row(a)).astype(
                     a.dtype) if per_slot(path) else a, whole)
+        if self._chunk_only:
             extra = {"real_lengths": true_len[None]}
         pool, logits = decode_apply(
             self.model, params, pool, tokens,
@@ -753,16 +824,20 @@ class PagedEngine:
             pool = jax.tree_util.tree_map_with_path(
                 lambda path, a: jnp.zeros_like(a)
                 if leaf_kind(path) == "rows" else a, pool)
-        sparse = [a for path, a
-                  in jax.tree_util.tree_flatten_with_path(pool)[0]
-                  if leaf_kind(path) == "slots"]
+        by_name = lambda name: [
+            a for path, a in jax.tree_util.tree_flatten_with_path(pool)[0]
+            if leaf_name(path) == name]
+        sparse = by_name(SLOT_STATS_LEAF)
         if sparse:   # (pages walked, pages a dense walk reads, sparse slots)
             # of the ACTIVE slots, over the layers and the burst's steps
             by_slot = jnp.where(active[:, None], sum(sparse), 0)
             sparse = jnp.concatenate([by_slot.sum(axis=0), jnp.sum(
                 active & (lengths + 1 - attn_starts > self._dense_len)
             )[None]])
-        return pool, last_logits, toks, keys, finite, (stats, sparse)
+        window = by_name(WINDOW_STATS_LEAF)
+        if window:   # (pages the window walks read, pages whole walks read)
+            window = jnp.where(active[:, None], sum(window), 0).sum(axis=0)
+        return pool, last_logits, toks, keys, finite, (stats, sparse, window)
 
     def _verify(self, params, pool, last_logits, attn_starts, active,
                 drafts, draft_lens, page_table, lengths):
@@ -986,6 +1061,29 @@ class PagedEngine:
             return 0
         return self.radix.peek(prompt)
 
+    def _tables(self, rows=slice(None)):
+        """The page tables a dispatch ships: the global group's array, as
+        ever, or a dict by group where the model has a window group."""
+        if self.wgroup is None:
+            return jnp.asarray(self._pt[rows])
+        return {"global": jnp.asarray(self._pt[rows]),
+                "window": jnp.asarray(self.wgroup.table[rows])}
+
+    def pages_held(self, a_slot: bool = False) -> dict:
+        """Pages the slots hold now, by page group (gauge
+        `kv_pages_held{group=}`); `a_slot`: the most any one slot holds."""
+        of = np.max if a_slot else np.sum
+        held = {"global": int(of(self._nblk))}
+        if self.wgroup is not None:
+            held["window"] = int(of(self.wgroup.end - self.wgroup.first))
+        return held
+
+    @property
+    def window_pages_freed(self) -> int:
+        """Pages given back behind a window, cumulative (counter
+        `kv_window_pages_freed_total`)."""
+        return 0 if self.wgroup is None else self.wgroup.freed
+
     def _admit_plan(self, prompt_len: int,
                     prompt: Optional[Sequence[int]] = None):
         """(matched, bucket_w, need_now) for an admission, or None when
@@ -1032,6 +1130,12 @@ class PagedEngine:
         # releases / preemption, not a reservation
         if need_now > self.blocks_available:
             return "later"
+        # ... and the same of the window group, counted on its own: its
+        # first chunk's pages and one more (a slot's bound there was
+        # checked against the pool at construction)
+        if self.wgroup is not None \
+                and self._blocks_for(w) + 1 > self.wgroup.blocks.num_free:
+            return "later"
         return "ok"
 
     def preempt_headroom(self, slots: Sequence[int], prompt_len: int,
@@ -1046,6 +1150,10 @@ class PagedEngine:
             return False
         bound = self.blocks_available \
             + int(sum(self._nblk[s] for s in slots))
+        if self.wgroup is not None and self._blocks_for(plan[1]) + 1 \
+                > self.wgroup.blocks.num_free \
+                + sum(self.wgroup.held(s) for s in slots):
+            return False
         return plan[2] <= bound
 
     def make_room(self, prompt_len: Optional[int] = None,
@@ -1101,7 +1209,7 @@ class PagedEngine:
             )
         return ids
 
-    def _acquire_decode(self, n: int, protect: int):
+    def _acquire_decode(self, n: int, protect: int, blocks=None):
         """n blocks for mid-decode growth / a CoW split: free list, then
         prefix-cache eviction, then BLOCK-AWARE PREEMPTION — evict the
         youngest-admitted active slot's non-shared blocks (LIFO victims,
@@ -1110,9 +1218,12 @@ class PagedEngine:
         could yield). Raises only when even preempting everyone else
         cannot cover — impossible for scheduler-gated traffic (the
         "never" gate bounds one request's whole-pool need), reachable by
-        direct users who oversubscribe fork budgets."""
+        direct users who oversubscribe fork budgets. `blocks`: the
+        allocator to take from, the global group's unless the window
+        group's is named (a victim gives back both groups' pages)."""
+        blocks = self.blocks if blocks is None else blocks
         while True:
-            ids = self.blocks.alloc(n)
+            ids = blocks.alloc(n)
             if ids is not None:
                 return ids
             if self.radix is not None \
@@ -1201,7 +1312,7 @@ class PagedEngine:
         # a recurrent model's every admission is a chunk admission: one
         # program (`_prefix_prefill`) at canonical positions, however short
         chunked = bool(chunk) and (
-            (p - matched) > chunk or self._recurrent)
+            (p - matched) > chunk or self._chunk_only)
         try:
             w = self.bucket_for(min(p - matched, chunk) if chunked
                                 else p - matched)
@@ -1415,14 +1526,21 @@ class PagedEngine:
             ids = self._acquire_decode(grow, protect=slot)
             self._pt[slot, self._nblk[slot]:need] = ids
             self._nblk[slot] = need
+        if self.wgroup is not None:
+            freed = self._window_pages(slot, done, done + take)
         tr = self.tracer
         if tr is not None and tr.enabled:
+            pages = {}
+            if self.wgroup is not None:   # what the chunk's kernels walk
+                pages = dict(zip(("global_pages", "window_pages"),
+                                 self._chunk_pages(done, take)),
+                             window_pages_freed=freed)
             span, host, disp = self._prefill_spans(
                 "prefill_chunk", slot,
                 self._slot_trace.get(slot, f"slot{slot}"),
                 bucket=w, pos0=done, take=take,
                 chunk=done // self.config.prefill_chunk,
-                prefix_hit=st["hit"],
+                prefix_hit=st["hit"], **pages,
                 # positions a recurrent model's scans advance the state
                 # over, and the chunk's padding they run over besides
                 **({"scan_tokens": take, "scan_padded": w - take}
@@ -1439,7 +1557,7 @@ class PagedEngine:
                                               np.int32)
                 args = (jnp.asarray(padded), jnp.int32(done),
                         jnp.int32(take),
-                        jnp.asarray(self._pt[slot:slot + 1]),
+                        self._tables(slice(slot, slot + 1)),
                         jnp.int32(slot))
             with disp:
                 self._cache, self._last_logits = self._prefix_jit(
@@ -1522,6 +1640,11 @@ class PagedEngine:
                 "fork is refused for a model with recurrent state: the "
                 "child would share the parent's pages but needs a state "
                 "of its own (ROADMAP M6: state snapshots)")
+        if self._window:
+            raise ValueError(
+                "fork is refused for a model with a window page group: the "
+                "child would share the parent's window pages, which the "
+                "parent gives back as it decodes on (ROADMAP M3)")
         if not self._active[slot]:
             raise ValueError(f"slot {slot} is not active")
         child = self.allocator.alloc()
@@ -1568,6 +1691,36 @@ class PagedEngine:
         return child
 
     # ------------------------------------------------------------- decode
+    def _window_pages(self, slot: int, first: int, end: int) -> int:
+        """The window group's pages of `slot` for queries at positions
+        [first, end): the pages wholly behind the FIRST query's window go
+        back to the group's allocator, the pages up to `end` are taken from
+        it (growth may preempt, as the global group's). Returns how many
+        went back."""
+        g, bs = self.wgroup, self.config.block_size
+        freed = g.trim(slot, max(0, first - self._window + 1) // bs)
+        grow = self._blocks_for(end) - int(g.end[slot])
+        if grow > 0:
+            g.extend(slot, self._acquire_decode(
+                grow, protect=slot, blocks=g.blocks))
+        assert g.held(slot) <= self.window_pages_a_slot, (
+            slot, g.held(slot), self.window_pages_a_slot)
+        return freed
+
+    def _chunk_pages(self, pos0: int, take: int) -> tuple:
+        """(global, window) pages a chunk's `window_prefill` calls walk,
+        over the layers of each group: a tile of rows from its first key's
+        page to its last row's own (ops/window_attention.py tile_walks)."""
+        from ddp_practice_tpu.ops.window_attention import WINDOW_TILE
+
+        bs = self.config.block_size
+        first = pos0 + WINDOW_TILE * np.arange(-(-take // WINDOW_TILE))
+        last = (first + WINDOW_TILE - 1) // bs
+        whole = int((last + 1).sum())
+        near = int((last - np.maximum(
+            first - self._window + 1, 0) // bs + 1).sum())
+        return whole * self._global_layers, near * self._window_layers
+
     def _grow_tables(self, k: int) -> int:
         """Allocate the blocks the next k decode positions need, per
         active slot oldest-first (growth may preempt — LIFO victims must
@@ -1598,6 +1751,13 @@ class PagedEngine:
             self._pt[slot, self._nblk[slot]:need] = ids
             self._nblk[slot] = need
             total_grown += grow
+        if self.wgroup is not None:
+            self._burst_freed = 0
+            for slot in order:
+                if self._active[slot]:
+                    length = int(self._len[slot])
+                    self._burst_freed += self._window_pages(
+                        int(slot), length, length + k)
         return total_grown
 
     def _cow_split(self, k: int) -> int:
@@ -1671,15 +1831,27 @@ class PagedEngine:
                  self._keys, finite, stats) = self._decode_jit(
                     self.params, self._cache, self._last_logits,
                     jnp.asarray(self._attn), jnp.asarray(self._active),
-                    self._keys, jnp.asarray(self._pt),
+                    self._keys, self._tables(),
                     jnp.asarray(self._len), self._sampling_args(),
                 )
                 _await_dispatch(self._cache, self._last_logits,
                                 self._keys)
             self._len[self._active] += k
             with read:  # the host waits for the device here
-                toks, finite, (stats, sparse) = jax.device_get(
+                toks, finite, (stats, sparse, window) = jax.device_get(
                     (toks, finite, stats))
+            if self._window_layers:
+                # what the burst's window layers read, from the program:
+                # pages their walks read, pages whole walks would have
+                near, whole = (int(v) for v in window)
+                self.last_burst_window = (near, whole)
+                self.window_pages_walked += near
+                self.window_pages_whole += whole
+                if traced and getattr(span, "attrs", None) is not None:
+                    span.attrs.update(
+                        window_pages=near,
+                        global_pages=walked * self._global_layers,
+                        window_pages_freed=self._burst_freed)
             if self._sparse_layers:
                 # what the burst's sparse attention layers read, from the
                 # program: pages their walks read, pages dense walks would
@@ -1852,6 +2024,8 @@ class PagedEngine:
         n = int(self._nblk[slot])
         if n:
             self.blocks.free([int(b) for b in self._pt[slot, :n]])
+        if self.wgroup is not None:
+            self.wgroup.clear(slot)
         if self.drafter is not None:
             self.drafter.end(slot)
         self.allocator.free(slot)

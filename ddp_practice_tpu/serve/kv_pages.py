@@ -66,10 +66,11 @@ hit (tests/test_kv_pages.py pins both).
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ddp_practice_tpu.inference import make_cache
@@ -484,6 +485,9 @@ STATE_LEAVES = ("ssm_state", "conv_state")
 # any K leaf, `block / stride` rows a block where a K leaf has `block`
 SLOT_STATS_LEAF = "sparse_stats"
 INDEX_LEAF = "cached_index"
+# a window attention layer's per-slot counts (models/vit.py SelfAttention
+# with `window`: pages its decode walks read, pages whole walks would have)
+WINDOW_STATS_LEAF = "window_stats"
 STATS_LEAF = "moe_stats"
 ROWS_LEAF = "moe_rows"
 # a latent-attention layer's one page leaf (models/mla_lm.py): pages like
@@ -502,18 +506,55 @@ def leaf_kind(path) -> str:
     name = leaf_name(path)
     if name in STATE_LEAVES:
         return "state"
-    return {STATS_LEAF: "stats", ROWS_LEAF: "rows",
-            SLOT_STATS_LEAF: "slots"}.get(name, "pages")
+    return {STATS_LEAF: "stats", ROWS_LEAF: "rows", SLOT_STATS_LEAF: "slots",
+            WINDOW_STATS_LEAF: "slots"}.get(name, "pages")
 
 
 def per_slot(path) -> bool:
     return leaf_kind(path) in ("state", "slots")
 
 
+class CacheSpec(NamedTuple):
+    """What a model says of its paged leaves beyond their names: the page
+    GROUP of each layer. Every paged leaf is in the group "global" (a slot
+    keeps a page for every position it has written) but those of the
+    modules named in `window_layers`, which are in "window": a query there
+    attends the `window` latest keys alone, so the pages behind a slot's
+    window are dead and go back to the group's own allocator
+    (`PageGroup.trim`). A group has its own pool lead dimension
+    (`make_paged_cache`), its own allocator and its own table row a slot;
+    positions address both tables alike, column `p // block_size`. A model
+    states the fields as `cache_spec()` (models/hybrid_lm.py); one without
+    the method has the global group alone. The state, latent and compressed-key
+    kinds are still told by leaf NAME (`leaf_kind`)."""
+
+    window: int = 0
+    window_layers: Tuple[str, ...] = ()
+
+    def group(self, path) -> str:
+        keys = {str(getattr(k, "key", k)) for k in path}
+        return "window" if keys & set(self.window_layers) else "global"
+
+    def window_pages(self, block_size: int, chunk: int) -> int:
+        """The most pages of the window group one slot ever holds: the keys
+        a chunk of `chunk` tokens attends (the window behind its first row,
+        and the chunk), wherever in a page they begin."""
+        return -(-(self.window + chunk) // block_size) + 1
+
+
+def cache_spec(model) -> CacheSpec:
+    """`model.cache_spec()` (the spec's fields, as a dict: models/ imports
+    nothing of serve/) as a `CacheSpec`; the global group alone without."""
+    return CacheSpec(**model.cache_spec()) if hasattr(model, "cache_spec") \
+        else CacheSpec()
+
+
 def make_paged_cache(model, num_blocks: int, block_size: int,
-                     max_slots: int = 1) -> Any:
+                     max_slots: int = 1, window_blocks: int = 0) -> Any:
     """Block-pool cache collection for `model` (decode mode): a cache
-    spec a layer, by what the layer declares.
+    spec a layer, by what the layer declares (`leaf_kind`, by name) and by
+    what the model says of it (`cache_spec`: a window layer's pages are a
+    pool of `window_blocks` of their own).
 
     Mirrors the tree structure of `inference.make_cache` — same variable
     names per attention block, so `decode_apply` threads it unchanged —
@@ -531,14 +572,67 @@ def make_paged_cache(model, num_blocks: int, block_size: int,
     layer's counters stay as they are.
     """
     shapes = jax.eval_shape(lambda: make_cache(model, 1, block_size))
+    spec = cache_spec(model)
 
     def per_leaf(path, a):
         if a.ndim == 0 or leaf_kind(path) in ("stats", "rows"):
             return jnp.zeros(a.shape, a.dtype)
-        lead = max_slots if per_slot(path) else num_blocks
+        if per_slot(path):
+            lead = max_slots
+        elif spec.group(path) == "window":
+            lead = window_blocks
+        else:
+            lead = num_blocks
         return jnp.zeros((lead,) + a.shape[1:], a.dtype)
 
     return jax.tree_util.tree_map_with_path(per_leaf, shapes)
+
+
+class PageGroup:
+    """Host side of one page group beside the engine's first (serve/
+    engine.py keeps the global group's table and allocator as it always
+    did): an allocator over the group's own pool and a page-table row a
+    slot whose columns are POSITIONS (`p // block_size`, as the global
+    table's), of which a slot holds the run `[first, end)`: every column
+    before `first` was given back (`trim`) and points at the garbage block
+    again, so a read behind the window finds block 0 and never another
+    slot's page."""
+
+    def __init__(self, num_blocks: int, max_slots: int, columns: int):
+        self.blocks = BlockAllocator(num_blocks)
+        self.table = np.zeros((max_slots, columns), np.int32)
+        self.first = np.zeros((max_slots,), np.int64)
+        self.end = np.zeros((max_slots,), np.int64)
+        self.freed = 0       # pages given back behind a window, cumulative
+
+    def held(self, slot: int) -> int:
+        return int(self.end[slot] - self.first[slot])
+
+    def trim(self, slot: int, live_from: int) -> int:
+        """Give back the slot's pages in the columns before `live_from`
+        (never past what it holds: a window is at least the query's own
+        position); how many went."""
+        lo, hi = int(self.first[slot]), min(int(self.end[slot]), live_from)
+        if hi <= lo:
+            return 0
+        self.blocks.free([int(b) for b in self.table[slot, lo:hi]])
+        self.table[slot, lo:hi] = GARBAGE_BLOCK
+        self.first[slot] = hi
+        self.freed += hi - lo
+        return hi - lo
+
+    def extend(self, slot: int, ids) -> None:
+        """The pages `ids` in the columns from the slot's end on."""
+        end = int(self.end[slot])
+        self.table[slot, end:end + len(ids)] = ids
+        self.end[slot] = end + len(ids)
+
+    def clear(self, slot: int) -> None:
+        lo, hi = int(self.first[slot]), int(self.end[slot])
+        if hi > lo:
+            self.blocks.free([int(b) for b in self.table[slot, lo:hi]])
+        self.table[slot, :] = GARBAGE_BLOCK
+        self.first[slot] = self.end[slot] = 0
 
 
 def _is_scale_leaf(path) -> bool:

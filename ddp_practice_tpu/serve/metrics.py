@@ -90,6 +90,14 @@ class ServeMetrics:
         self.sparse_pages_held = r.counter("sparse_pages_held_total")
         self.index_cache_bytes = r.gauge("index_cache_bytes")
         self._last_walked = self._last_pages_held = 0
+        # page groups (serve/kv_pages.py CacheSpec): pages the slots hold in
+        # each group, `kv_pages_held{group=}`, set a tick; pages the window
+        # layers gave back behind their slots' windows, and the pages their
+        # decode walks read beside whole walks', as deltas
+        self.window_pages_freed = r.counter("kv_window_pages_freed_total")
+        self.window_pages_walked = r.counter("window_pages_walked_total")
+        self.window_pages_whole = r.counter("window_pages_whole_total")
+        self._last_freed = self._last_near = self._last_whole = 0
         self.tokens_total = r.counter("serve_tokens_total")
         self.submitted = r.counter("serve_requests_submitted")
 
@@ -146,6 +154,17 @@ class ServeMetrics:
         self.sparse_pages_held.inc(pages_held - self._last_pages_held)
         self._last_walked, self._last_pages_held = walked, pages_held
         self.index_cache_bytes.set(eng.index_cache_bytes)
+        for group, pages in eng.pages_held().items():
+            self.registry.gauge(
+                labelled("kv_pages_held", group=group)).set(pages)
+        freed, near, whole = (eng.window_pages_freed,
+                              eng.window_pages_walked,
+                              eng.window_pages_whole)
+        self.window_pages_freed.inc(freed - self._last_freed)
+        self.window_pages_walked.inc(near - self._last_near)
+        self.window_pages_whole.inc(whole - self._last_whole)
+        self._last_freed, self._last_near, self._last_whole = \
+            freed, near, whole
         drafted = eng.spec_drafted_tokens
         accepted = eng.spec_accepted_tokens
         self.spec_drafted.inc(drafted - self._last_drafted)

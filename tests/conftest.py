@@ -115,6 +115,8 @@ def engine_at_rest():
             moe_rows_layout=0, ssm_scan_tokens=0, ssm_scan_padded_tokens=0,
             ssm_state_bytes=0, latent_cache_bytes=0, index_cache_bytes=0,
             sparse_pages_walked=0, sparse_pages_held=0,
+            pages_held=lambda: {"global": 0}, window_pages_freed=0,
+            window_pages_walked=0, window_pages_whole=0,
             spec_drafted_tokens=0, spec_accepted_tokens=0)
         return types.SimpleNamespace(**{**at_rest, **fields})
 

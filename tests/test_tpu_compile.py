@@ -429,6 +429,53 @@ def _sala(what, slots=32, blocks_per_slot=536, page=64):
         _sds((slots, 32, 128, 128), f32))
 
 
+def _thinker(what, slots=32, blocks_per_slot=240, page=64, window=4096):
+    """The SmallThinker cell's kernels at its widths (perf/configs/
+    smallthinker_21b_pp7.json, perf/traffic/short_long_s32.json): 28 query
+    heads of 128 on 4 KV heads, pages of 64 tokens 512 lanes wide, 32 slots
+    of 240 table columns in both page groups; a 2,048-token chunk through
+    `window_prefill` under a run-time window (a window layer's pool backs 97
+    pages a slot), a decode step's walk from the window's first page under
+    the name `window_walk`, and `moe_gmm_glu` with the ReGLU body at 64
+    experts of 2560 -> 768 -> 2560 (11.8 MB an expert, two deep in VMEM)."""
+    from ddp_practice_tpu.ops import window_attention as wa
+    from ddp_practice_tpu.ops.decode_attention import paged_decode_attention
+    from ddp_practice_tpu.ops.moe import expert_glu_tiles
+
+    i32 = jnp.int32
+    pool = _sds((1 + slots * 97, page, 512))
+    if what == "prefill":
+        def chunk(q, k, v, table, pos0, win, real):
+            return wa.window_prefill(q, k, v, table, pos0, window=win,
+                                     real=real)
+
+        return chunk, (_sds((2048, 4, 7, 128)), pool, pool,
+                       _sds((blocks_per_slot,), i32), _sds((), i32),
+                       _sds((), i32), _sds((), i32))
+    if what == "walk":
+        def step(q, k, v, table, lengths):
+            return paged_decode_attention(
+                q, k, v, table, lengths,
+                wa.window_start(lengths, None, window), n_heads=28,
+                n_kv_heads=4, name="window_walk")
+
+        return step, (_sds((slots, 1, 28 * 128)), pool, pool,
+                      _sds((slots, blocks_per_slot), i32),
+                      _sds((slots,), i32))
+    rows_a_tile = {"glu_decode": 16, "glu_chunk": 128}[what]
+    picks = {16: slots * 6, 128: 2048 * 6}[rows_a_tile]
+    tiles = -(-picks // rows_a_tile) + 64
+
+    def mlp(rows, wg, wu, wd, tile_expert, used):
+        return expert_glu_tiles(rows, wg, wu, wd, tile_expert, used,
+                                tile=rows_a_tile, activation="relu")
+
+    return mlp, (_sds((tiles * rows_a_tile, 2560)),
+                 _sds((64, 2560, 768)), _sds((64, 2560, 768)),
+                 _sds((64, 768, 2560)),
+                 _sds((tiles,), i32), _sds((1,), i32))
+
+
 def _paged_hd256(slots=128, blocks_per_slot=76, page=64):
     """The Qwen3-Next cell's attention: 16 query heads of 256 lanes on 2 KV
     heads (a group of 8), pages of 64 tokens 512 lanes wide."""
@@ -528,6 +575,10 @@ KERNELS = {
     "sala_sparse_walk_32_slots": functools.partial(_sala, "walk"),
     "sala_sparse_prefill_2048": functools.partial(_sala, "prefill"),
     "sala_ssm_step_group_a_head": functools.partial(_sala, "step"),
+    "thinker_window_prefill_2048": functools.partial(_thinker, "prefill"),
+    "thinker_window_walk_32_slots": functools.partial(_thinker, "walk"),
+    "thinker_reglu_decode_tiles": functools.partial(_thinker, "glu_decode"),
+    "thinker_reglu_chunk_tiles": functools.partial(_thinker, "glu_chunk"),
     **{f"rows_{which}_{cell}_{n}": functools.partial(
         _moe_rows, which, n, k, experts, width)
        for cell, k, experts, width, ns in (
@@ -640,6 +691,14 @@ def test_kernel_compiles_for_v5e(topo, name):
         # flood_ssm_step_roofline sum by; none is named `paged_decode`
         want = {"sala_sparse_w": "sparse_walk", "sala_sparse_p":
                 "sparse_prefill", "sala_ssm_step": "ssm_step"}[name[:13]]
+        calls = _kernel_calls(text)
+        assert len(calls) == 1 and calls[0].endswith(want), calls
+    if name.startswith("thinker"):
+        # the names perf/layer_metrics/flood_window_* and flood_moe_glu_*
+        # sum by; a window layer's walk is not named `paged_decode`
+        want = {"thinker_window_p": "window_prefill", "thinker_window_w":
+                "window_walk", "thinker_reglu_de": "moe_gmm_glu",
+                "thinker_reglu_ch": "moe_gmm_glu"}[name[:16]]
         calls = _kernel_calls(text)
         assert len(calls) == 1 and calls[0].endswith(want), calls
     if name.startswith("rows"):
@@ -903,6 +962,8 @@ CELL_DEPTH = {
                                    full_attention_interval=2),  # attention
     "minicpm_sala": lambda cfg: dict(cfg, layers_run=2,   # sparse, lightning
                                      layers_published=[9, 10]),
+    "smallthinker": lambda cfg: dict(cfg, layers_run=2,   # global, window
+                                     layers_published=[0, 1]),
 }
 
 
@@ -983,6 +1044,8 @@ def _serve_programs(topo, name, whole=False):
     eng = traffic["engine"]
     if not whole:   # the pool is allocated for real, on this host
         eng = dict(eng, num_blocks=2 * eng["max_blocks_per_slot"] + 1)
+        if "window_blocks" in eng:
+            eng["window_blocks"] = eng["num_blocks"]
     fields = {f.name for f in dataclasses.fields(EngineConfig)}
     engine = PagedEngine(model, params, EngineConfig(
         prompt_buckets=tuple(eng["buckets"]), block_size=eng["page"],
@@ -992,6 +1055,10 @@ def _serve_programs(topo, name, whole=False):
     on_chip = functools.partial(_on, topo.devices[0])
     logits = _sds((slots, model.vocab_size), model.dtype)
     w = engine.buckets[0]
+    # a table a page group where the model has a window group
+    table = lambda rows: _sds((rows, mb), i32) if engine.wgroup is None \
+        else {"global": _sds((rows, mb), i32),
+              "window": _sds((rows, mb), i32)}
     if not engine._canonical:
         prefill = lambda: engine._prefill_jit.lower(*on_chip((
             params, engine._cache, logits, _sds((1, w), i32), _sds((), i32),
@@ -999,11 +1066,11 @@ def _serve_programs(topo, name, whole=False):
     else:
         prefill = lambda: engine._prefix_jit.lower(*on_chip((
             params, engine._cache, logits, _sds((1, w), i32), _sds((), i32),
-            _sds((), i32), _sds((1, mb), i32), _sds((), i32))))
+            _sds((), i32), table(1), _sds((), i32))))
     decode = lambda: engine._decode_jit.lower(*on_chip((
         params, engine._cache, logits, _sds((slots,), i32),
         _sds((slots,), jnp.bool_), _sds((slots, 2), jnp.uint32),
-        _sds((slots, mb), i32), _sds((slots,), i32))), None)
+        table(slots), _sds((slots,), i32))), None)
     return {"prefill": prefill, "decode_burst": decode}
 
 
@@ -1203,6 +1270,30 @@ def test_minicpm_sala_programs_carry_their_scopes_and_kernels(topo, prog,
 
 
 # ------------------------------------------------------------ whole steps
+@pytest.mark.parametrize("prog, kernels", [
+    ("decode_burst", {"paged_decode": 1, "window_walk": 1, "moe_gmm_glu": 2,
+                      "moe_rows_fill": 2, "moe_rows_sum": 2}),
+    ("prefill", {"window_prefill": 2, "moe_gmm_glu": 2, "moe_rows_fill": 2,
+                 "moe_rows_sum": 2})])
+def test_smallthinker_programs_carry_their_scopes_and_kernels(topo, prog,
+                                                              kernels):
+    """The SmallThinker cell's programs compiled for the described v5e at
+    its widths and engine (32 slots of 240 table columns in two page groups,
+    chunks of the first bucket), one layer of each kind (G W), a program a
+    case: the scopes contract, and the kernels by name and count: a decode
+    step walks the global layer's pages as `paged_decode` and the window
+    layer's as `window_walk` (ONE kernel body, two names), a chunk runs
+    `window_prefill` in both (one kernel under a run-time window), and the
+    ReGLU experts are `moe_gmm_glu` between the two row kernels."""
+    cell = "smallthinker_serve_shortlong"
+    with _no_frames_in_locations():
+        text = _cell_programs(topo, cell)[prog]().compile().as_text()
+    assert _holds_the_contract(
+        cell, prog, text, sample=prog == "decode_burst") >= 1
+    assert prog != "prefill" or "/sample/dynamic_update_slice" in text
+    assert _kernel_counts(text) == kernels
+
+
 def _abstract_trainer(topo, *, model, mesh_cfg, model_kwargs, sample,
                       fsdp=False):
     """What Trainer.__init__ builds, from shapes alone on described
