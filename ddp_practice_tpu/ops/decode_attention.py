@@ -366,9 +366,30 @@ def decode_attention_packed(
 # tokens): neither the pool's size nor the table's width is paid for.
 
 
-# Tokens a chunk of the page walk aims at: the contraction depth of the
-# p @ V product on a 128 x 128 MXU, and eight 16-token pages.
+# Tokens a chunk of the MHA walk (block-diagonal query, every head's lanes in
+# one 1,536 B row) aims at: the contraction depth of the p @ V product on a
+# 128 x 128 MXU, and eight 16-token pages.
 _CHUNK_TOKENS = 128
+# Bytes of ONE pool a chunk of the GROUPED walk aims at, and the most pages
+# it copies. A chunk costs ~0.3 us whatever it moves (loop step, copy starts
+# and waits, one-tile matmuls and a softmax chain that cannot pipeline): at
+# 128 tokens of a 1,024 B row that is as long as the copy itself, and the
+# walk read 47-48% of what HBM allows whatever the context. But every
+# column of the tile is scored, live or not (~1.4 ns a column: a 100-token
+# slot under a 1,024-token tile takes 2.0 us where a 128-token tile takes
+# 0.76), and every page of the chunk is a `pl.when` at three sites, two
+# pools each, that costs 50-80 ns taken or not. The kernel alone at the four
+# grouped cells' shapes (experiments/paged_walk_time.py, PERF.md section 6,
+# PR 45; us a call, 24 of 32 slots live, chunk of 128 / 256 / 512 / 1,024
+# tokens): 4 KV heads of 128 on 64-token pages, 4,096 tokens from mid-table
+# 524 / 443 / 316 / 318, 500 tokens 72 / 64 / 49 / 66, 100 tokens 24 / 38 /
+# 44 / 65; one KV head of 128, 1,000 tokens 725 / 463 / 343 / 317; on
+# 16-token pages (8 / 16 / 32 / 64 pages) 500 tokens 303 / 297 / 272 / 466.
+# Eight pages of 64 KB hold the gain; past eight the page sites and the dead
+# columns take back what the longer copy gives, and on 16-token pages there
+# is nothing to give (its walk is bound by its 8 KB copies).
+_GROUPED_CHUNK_BYTES = 512 << 10
+_GROUPED_CHUNK_PAGES = 8
 # VMEM the walk's four chunk buffers (K and V, two deep) may take: half of
 # the 16 MiB a kernel gets by default.
 _CHUNK_VMEM_BYTES = 8 << 20
@@ -380,13 +401,22 @@ _GROUP_ROWS = 8
 
 
 def _pages_per_chunk(block_size: int, hd_total: int, dtype,
-                     tokens: int = _CHUNK_TOKENS) -> int:
-    """Pages P the walk fetches and computes at a time: enough for
-    `tokens` (128 <= P * block_size < 256 whatever the page size, one page
-    when a page is longer), halved while the four (P * block_size,
-    h*hd) buffers would pass the VMEM budget (very wide models)."""
-    p = -(-tokens // block_size)
+                     tokens: int = _CHUNK_TOKENS, *,
+                     table_pages: int | None = None) -> int:
+    """Pages P the walk fetches and computes at a time. `tokens` is what a
+    caller's chunk aims at (128 <= P * block_size < 256 whatever the page
+    size, one page when a page is longer). A GROUPED walk hands in the width
+    of its page table instead (`table_pages`), and its chunk aims at bytes:
+    `_GROUPED_CHUNK_BYTES` of one pool, at most `_GROUPED_CHUNK_PAGES`
+    pages, never more than the table holds (a slot's longest context).
+    Either is halved while the four (P * block_size, h*hd) buffers would
+    pass the VMEM budget (very wide models)."""
     row = hd_total * jnp.dtype(dtype).itemsize
+    if table_pages is None:
+        p = -(-tokens // block_size)
+    else:
+        p = max(1, min(_GROUPED_CHUNK_BYTES // (block_size * row),
+                       _GROUPED_CHUNK_PAGES, table_pages))
     while p > 1 and 4 * p * block_size * row > _CHUNK_VMEM_BYTES:
         p //= 2
     return p
@@ -805,7 +835,10 @@ def paged_decode_attention(
             name="paged_decode_int8",
         )(lens, start, pt, q, k_pages, v_pages, k_scale, v_scale)
     kv_total = kvh * d
-    pages = _pages_per_chunk(bs, kv_total, k_pages.dtype)
+    # fewer KV heads than query heads: the chunk follows the pool's shape
+    pages = _pages_per_chunk(
+        bs, kv_total, k_pages.dtype,
+        table_pages=page_table.shape[1] if group > 1 else None)
     if group > 1:
         # q and out as (heads, d) blocks: a free reshape out here, and in
         # the kernel a KV head's query heads are then whole rows. A group

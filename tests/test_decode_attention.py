@@ -325,26 +325,82 @@ WALK_CASES = {
         jnp.bfloat16),
 }
 
+# PR 45: a GROUPED walk's chunk follows the pool's shape, not 128 tokens.
+# 8 query heads of 128 on 2 KV heads; the cases name the chunk their lengths
+# are laid around (the test checks that the rule still picks it): on pages
+# of 64 eight pages = 512 tokens; on float32 pages of 128 (128 KB a page)
+# four; on pages of 16 eight pages = 128 tokens, as ever.
+GH, GKV, GD = 8, 2, 128
+GROUPED_WALK_CASES = {
+    # name: (block_size, table columns, lengths, attn_start, table, dtype,
+    #        pages a chunk)
+    # lengths end one short of, on and one past the chunk's edges; one
+    # ends on the first page of its THIRD chunk (1,024 + 5), one mid-chunk
+    "grouped_lengths_at_the_new_chunks_edges": (
+        64, 40, [0, 63, 511, 512, 513, 700, 1029, 40 * 64 - 1], None,
+        _random_table, jnp.bfloat16, 8),
+    # starts mid-page in what the table's first chunk does not hold (column
+    # 9, 17 and 39); slot 1 ends on the first page of its walk's second
+    # chunk (columns 9 .. 17), slot 2 inside its first, slot 3 on its
+    # start's own page at the table's end
+    "grouped_start_midpage_later_chunk_end_on_a_chunks_first_page": (
+        64, 40, [100, 17 * 64 + 3, 1500, 40 * 64 - 1, 2000],
+        [0, 9 * 64 + 21, 17 * 64 + 63, 39 * 64 + 5, 513],
+        _random_table, jnp.bfloat16, 8),
+    # bytes, not pages: float32 pages of 128 tokens are 128 KB, four a chunk
+    "grouped_float32_chunks_of_4_pages": (
+        128, 20, [511, 512, 513, 700, 20 * 128 - 1, 300],
+        [0, 0, 128 + 7, 4 * 128 + 9, 2000, 299],
+        _random_table, jnp.float32, 4),
+    # a table of 3 columns holds no whole chunk: P is the table's width
+    "grouped_table_narrower_than_a_chunk": (
+        64, 3, [0, 70, 191, 130], [0, 3, 65, 129], _random_table,
+        jnp.bfloat16, 3),
+    # slots 1 and 3 retired (row 0; one length pinned past the table)
+    # between slots that walk two and three chunks
+    "grouped_retired_slot_beside_active": (
+        64, 40, [600, 5, 1300, 40 * 64 + 5, 1029], [0, 0, 140, 0, 64],
+        _retired_table, jnp.bfloat16, 8),
+    # 16-token pages: eight a chunk, 128 tokens, what the walk always ran
+    "grouped_page_16_chunks_of_8_pages": (
+        16, 66, [40, 200, 700, LAST, 130, 128], [0, 21, 300, 1040, 129, 0],
+        _random_table, jnp.bfloat16, 8),
+}
 
-@pytest.mark.parametrize("case", sorted(WALK_CASES))
+
+def _walk_case(case):
+    """(case tuple, query heads, KV heads, head_dim)."""
+    if case in WALK_CASES:
+        return WALK_CASES[case] + (None,), H, H, HD
+    return GROUPED_WALK_CASES[case], GH, GKV, GD
+
+
+@pytest.mark.parametrize("case",
+                         sorted(WALK_CASES) + sorted(GROUPED_WALK_CASES))
 def test_paged_walk_matches_reference(case):
     from ddp_practice_tpu.ops.decode_attention import (
+        _pages_per_chunk,
         paged_attention_reference,
         paged_decode_attention,
     )
 
-    bs, mb, lengths, start, table, dtype = WALK_CASES[case]
+    (bs, mb, lengths, start, table, dtype, pages), heads, kvh, d = \
+        _walk_case(case)
+    if pages is not None:   # the chunk the case's lengths are laid around
+        assert _pages_per_chunk(bs, kvh * d, dtype, table_pages=mb) == pages
     b = len(lengths)
     nb = 1 + b * mb
-    rng = np.random.default_rng(sorted(WALK_CASES).index(case))
-    q = jnp.asarray(rng.normal(size=(b, 1, H * HD)), dtype)
-    kp = jnp.asarray(rng.normal(size=(nb, bs, H * HD)), dtype)
-    vp = jnp.asarray(rng.normal(size=(nb, bs, H * HD)), dtype)
+    rng = np.random.default_rng(
+        sorted([*WALK_CASES, *GROUPED_WALK_CASES]).index(case))
+    q = jnp.asarray(rng.normal(size=(b, 1, heads * d)), dtype)
+    kp = jnp.asarray(rng.normal(size=(nb, bs, kvh * d)), dtype)
+    vp = jnp.asarray(rng.normal(size=(nb, bs, kvh * d)), dtype)
     pt = jnp.asarray(table(rng, b, mb, nb), jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     start = None if start is None else jnp.asarray(start, jnp.int32)
-    ref = paged_attention_reference(q, kp, vp, pt, lengths, start, n_heads=H)
-    got = paged_decode_attention(q, kp, vp, pt, lengths, start, n_heads=H,
+    kw = dict(n_heads=heads, n_kv_heads=kvh)
+    ref = paged_attention_reference(q, kp, vp, pt, lengths, start, **kw)
+    got = paged_decode_attention(q, kp, vp, pt, lengths, start, **kw,
                                  impl="kernel")
     # bf16: an ulp of outputs that reach 2 (chip_smoke.py's bound)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
@@ -388,29 +444,63 @@ def test_paged_walk_skips_what_it_may_and_nothing_else():
         assert np.isnan(run(kp, vp.at[page].set(jnp.nan))[slot]).all(), page
 
 
-@pytest.mark.parametrize("block_size,width,dtype,pages", [
-    (16, 768, jnp.bfloat16, 8),     # the flood cell: 128 tokens, 4 x 196 KB
-    (8, 768, jnp.bfloat16, 16),
-    (32, 768, jnp.bfloat16, 4),
-    (24, 256, jnp.float32, 6),      # 144 tokens: never under 128
-    (128, 1024, jnp.bfloat16, 1),
-    (256, 1024, jnp.bfloat16, 1),   # a page longer than a chunk: one a time
-    (16, 16384, jnp.bfloat16, 4),   # too wide for 4 x 128 rows in 8 MiB
+@pytest.mark.parametrize("block_size,width,dtype,table,pages", [
+    # the MHA walk (no table handed in) aims at 128 tokens, as ever
+    (16, 768, jnp.bfloat16, None, 8),   # the flood cell: 4 x 196 KB
+    (8, 768, jnp.bfloat16, None, 16),
+    (32, 768, jnp.bfloat16, None, 4),
+    (24, 256, jnp.float32, None, 6),    # 144 tokens: never under 128
+    (128, 1024, jnp.bfloat16, None, 1),
+    (256, 1024, jnp.bfloat16, None, 1),   # a page longer than a chunk
+    (16, 16384, jnp.bfloat16, None, 4),   # too wide for 4 x 128 rows in 8 MiB
+    # the GROUPED walk at its four cells' shapes (page, KV heads x head_dim,
+    # table columns): 512 KB of a pool a chunk, at most eight pages
+    pytest.param(64, 4 * 128, jnp.bfloat16, 240, 8,
+                 id="smallthinker_512_tokens"),
+    pytest.param(64, 2 * 256, jnp.bfloat16, 76, 8,
+                 id="qwen3next_512_tokens"),
+    pytest.param(64, 1 * 128, jnp.bfloat16, 48, 8,
+                 id="jamba2_512_tokens_at_the_page_cap"),
+    pytest.param(16, 2 * 128, jnp.bfloat16, 114, 8,
+                 id="nemo3s_128_tokens_at_the_page_cap"),
+    # the cap on P: 8-token pages would ask 128 for their 512 KB
+    pytest.param(8, 256, jnp.bfloat16, 512, 8, id="grouped_page_cap"),
+    # bytes, not tokens: a 2,048 B row (float32) fills 512 KB in four pages
+    pytest.param(64, 4 * 128, jnp.float32, 240, 4, id="grouped_bytes"),
+    # never more than the table holds
+    pytest.param(64, 4 * 128, jnp.bfloat16, 3, 3, id="grouped_narrow_table"),
+    pytest.param(256, 8 * 128, jnp.bfloat16, 60, 1,
+                 id="grouped_page_of_512_KB"),
+    # the VMEM halving is the token targets' (the 16384-wide case above):
+    # 512 KB a pool keeps a grouped walk's four buffers at 2 MiB however
+    # wide the row (16 KB rows on 16-token pages: two pages)
+    pytest.param(16, 8192, jnp.bfloat16, 64, 2, id="grouped_inside_vmem"),
 ])
-def test_pages_per_chunk_follows_the_shapes(block_size, width, dtype, pages):
+def test_pages_per_chunk_follows_the_shapes(block_size, width, dtype, table,
+                                            pages):
     from ddp_practice_tpu.ops.decode_attention import (
         _CHUNK_VMEM_BYTES,
+        _MLA_CHUNK_TOKENS,
         _pages_per_chunk,
     )
 
-    got = _pages_per_chunk(block_size, width, dtype)
+    got = _pages_per_chunk(block_size, width, dtype, table_pages=table)
     assert got == pages
     buffers = 4 * got * block_size * width * jnp.dtype(dtype).itemsize
     assert got == 1 or buffers <= _CHUNK_VMEM_BYTES
+    # the latent walk's own target is what it was: 16 pages of 64, and
+    # halved like any token target where the row is very wide
+    assert _pages_per_chunk(64, 640, jnp.bfloat16, _MLA_CHUNK_TOKENS) == 16
+    assert _pages_per_chunk(64, 8192, jnp.bfloat16, _MLA_CHUNK_TOKENS) == 2
 
 
-@pytest.mark.parametrize("case", ["attn_start_zero_midpage_later_chunk",
-                                  "retired_slot_beside_active"])
+@pytest.mark.parametrize("case", [
+    "attn_start_zero_midpage_later_chunk",
+    "retired_slot_beside_active",
+    "grouped_start_midpage_later_chunk_end_on_a_chunks_first_page",
+    "grouped_table_narrower_than_a_chunk",
+    "grouped_retired_slot_beside_active",
+])
 def test_paged_walk_waits_for_what_it_reads(case, monkeypatch):
     """The same cases under the TPU interpreter, which lands a DMA's
     bytes only when it is WAITED for and watches for races: a chunk

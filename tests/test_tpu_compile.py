@@ -436,8 +436,10 @@ def _thinker(what, slots=32, blocks_per_slot=240, page=64, window=4096):
     of 240 table columns in both page groups; a 2,048-token chunk through
     `window_prefill` under a run-time window (a window layer's pool backs 97
     pages a slot), a decode step's walk from the window's first page under
-    the name `window_walk`, and `moe_gmm_glu` with the ReGLU body at 64
-    experts of 2560 -> 768 -> 2560 (11.8 MB an expert, two deep in VMEM)."""
+    the name `window_walk`, a global layer's walk of the whole context as
+    `paged_decode` (its pool backs all 240), and `moe_gmm_glu` with the ReGLU
+    body at 64 experts of 2560 -> 768 -> 2560 (11.8 MB an expert, two deep
+    in VMEM)."""
     from ddp_practice_tpu.ops import window_attention as wa
     from ddp_practice_tpu.ops.decode_attention import paged_decode_attention
     from ddp_practice_tpu.ops.moe import expert_glu_tiles
@@ -462,6 +464,15 @@ def _thinker(what, slots=32, blocks_per_slot=240, page=64, window=4096):
         return step, (_sds((slots, 1, 28 * 128)), pool, pool,
                       _sds((slots, blocks_per_slot), i32),
                       _sds((slots,), i32))
+    if what == "global_walk":
+        def step(q, k, v, table, lengths, start):
+            return paged_decode_attention(
+                q, k, v, table, lengths, start, n_heads=28, n_kv_heads=4)
+
+        pool = _sds((1 + slots * blocks_per_slot, page, 512))
+        return step, (_sds((slots, 1, 28 * 128)), pool, pool,
+                      _sds((slots, blocks_per_slot), i32),
+                      _sds((slots,), i32), _sds((slots,), i32))
     rows_a_tile = {"glu_decode": 16, "glu_chunk": 128}[what]
     picks = {16: slots * 6, 128: 2048 * 6}[rows_a_tile]
     tiles = -(-picks // rows_a_tile) + 64
@@ -577,6 +588,8 @@ KERNELS = {
     "sala_ssm_step_group_a_head": functools.partial(_sala, "step"),
     "thinker_window_prefill_2048": functools.partial(_thinker, "prefill"),
     "thinker_window_walk_32_slots": functools.partial(_thinker, "walk"),
+    "thinker_global_walk_32_slots": functools.partial(_thinker,
+                                                      "global_walk"),
     "thinker_reglu_decode_tiles": functools.partial(_thinker, "glu_decode"),
     "thinker_reglu_chunk_tiles": functools.partial(_thinker, "glu_chunk"),
     **{f"rows_{which}_{cell}_{n}": functools.partial(
@@ -632,6 +645,15 @@ KERNELS = {
     "fused_encoder_lm_tiny_causal": functools.partial(_fused_encoder, True),
     "moe_sorted_gmm_tgmm": _moe_sorted,
 }
+
+
+# the grouped page walks at their cells' shapes: eight pages a chunk since
+# PR 45 (tests/test_decode_attention.py holds the rule at these shapes),
+# 512 tokens but for the hybrid's 16-token pages
+GROUPED_WALKS = {
+    "hybrid_paged_grouped_32q_2kv", "jamba_paged_group20_page64",
+    "qwen_paged_hd256_group8_page64", "thinker_window_walk_32_slots",
+    "thinker_global_walk_32_slots"}
 
 
 # the MoE case spends 12 s in the sort around its kernels: `slow` by
@@ -697,10 +719,16 @@ def test_kernel_compiles_for_v5e(topo, name):
         # the names perf/layer_metrics/flood_window_* and flood_moe_glu_*
         # sum by; a window layer's walk is not named `paged_decode`
         want = {"thinker_window_p": "window_prefill", "thinker_window_w":
-                "window_walk", "thinker_reglu_de": "moe_gmm_glu",
+                "window_walk", "thinker_global_w": "paged_decode",
+                "thinker_reglu_de": "moe_gmm_glu",
                 "thinker_reglu_ch": "moe_gmm_glu"}[name[:16]]
         calls = _kernel_calls(text)
         assert len(calls) == 1 and calls[0].endswith(want), calls
+    if name in GROUPED_WALKS:
+        # the chunk's four buffers and the tile's scores stay inside what a
+        # kernel is scoped, 16 MiB
+        (vmem,) = _scoped_vmem(text).values()
+        assert vmem <= 16 * 2**20, vmem
     if name.startswith("rows"):
         # ONE device op each, named `moe_` and not `moe_gmm*`: the rooflines
         # of the expert kernels sum the ops named `moe_gmm*` and must not
