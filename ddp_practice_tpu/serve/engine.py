@@ -581,6 +581,9 @@ class PagedEngine:
         # `window_stats`; every layer with K pages a `cached_key`)
         self._window_layers = sum(
             1 for path, _ in flat if leaf_name(path) == WINDOW_STATS_LEAF)
+        self._kv_itemsize = next((
+            a.dtype.itemsize for path, a in flat
+            if leaf_name(path) == "cached_key"), 2)
         self._global_layers = sum(
             1 for path, _ in flat if leaf_name(path) == "cached_key"
         ) - self._window_layers
@@ -1532,8 +1535,7 @@ class PagedEngine:
         if tr is not None and tr.enabled:
             pages = {}
             if self.wgroup is not None:   # what the chunk's kernels walk
-                pages = dict(zip(("global_pages", "window_pages"),
-                                 self._chunk_pages(done, take)),
+                pages = dict(self._chunk_pages(done, take, w),
                              window_pages_freed=freed)
             span, host, disp = self._prefill_spans(
                 "prefill_chunk", slot,
@@ -1707,19 +1709,39 @@ class PagedEngine:
             slot, g.held(slot), self.window_pages_a_slot)
         return freed
 
-    def _chunk_pages(self, pos0: int, take: int) -> tuple:
-        """(global, window) pages a chunk's `window_prefill` calls walk,
-        over the layers of each group: a tile of rows from its first key's
-        page to its last row's own (ops/window_attention.py tile_walks)."""
-        from ddp_practice_tpu.ops.window_attention import WINDOW_TILE
+    def _chunk_pages(self, pos0: int, take: int, width: int) -> dict:
+        """What the `window_prefill` calls of a chunk of `take` tokens in a
+        bucket of `width` do, over the layers of each group and for one KV
+        head, counted on the host by the kernel's own rule
+        (ops/window_attention.py walk_counts): the pages their tiles WALK
+        (a tile of rows from its first key's page to its last row's own),
+        by group; the pages their grid steps EXECUTE (whole steps, and what
+        a walk's last step computes) and the share of the steps that ran
+        without a mask."""
+        from ddp_practice_tpu.ops.window_attention import (
+            NO_WINDOW,
+            WINDOW_TILE,
+            pages_per_step,
+            walk_counts,
+        )
 
-        bs = self.config.block_size
-        first = pos0 + WINDOW_TILE * np.arange(-(-take // WINDOW_TILE))
-        last = (first + WINDOW_TILE - 1) // bs
-        whole = int((last + 1).sum())
-        near = int((last - np.maximum(
-            first - self._window + 1, 0) // bs + 1).sum())
-        return whole * self._global_layers, near * self._window_layers
+        m, bs = self.model, self.config.block_size
+        columns = self._pt.shape[1]
+        pages = pages_per_step(
+            bs, m.kv_heads * m.head_dim,
+            m.num_heads // m.kv_heads * min(WINDOW_TILE, width), columns,
+            self._kv_itemsize)
+        far, near = (walk_counts(pos0, 0, window, take, s=width, block=bs,
+                                 columns=columns, pages=pages)
+                     for window in (NO_WINDOW, self._window))
+        g, n = self._global_layers, self._window_layers
+        steps = g * far["steps"] + n * near["steps"]
+        return {"global_pages": g * far["walked"],
+                "window_pages": n * near["walked"],
+                "pages_executed": g * far["executed"] + n * near["executed"],
+                "steps_unmasked": round(
+                    (g * far["clear"] + n * near["clear"]) / max(steps, 1),
+                    4)}
 
     def _grow_tables(self, k: int) -> int:
         """Allocate the blocks the next k decode positions need, per

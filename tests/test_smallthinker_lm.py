@@ -107,14 +107,29 @@ def _pools(kvh=2, d=128, bs=8, blocks=40, mb=24, seed=0):
     ("window_begins_mid_page", 16, 44, 3, 20, None),
     ("window_two_tiles", 256, 8, 0, 50, 200),
     ("window_of_one_page_behind", 32, 100, 0, 9, 30),
+    # PR 46: a step folds `pages_per_step` pages (8 of 8 tokens at tables
+    # of 12 to 15 columns), so a walk of 11 or 14 pages is one whole step
+    # and a part of one; 16 pages of 64 tokens at the 240 columns, two whole
+    # steps and two parts for 37 pages
+    ("walk_of_a_step_and_three_pages", 16, 72, 0, wa.NO_WINDOW, None),
+    ("window_edge_mid_page_in_a_whole_step", 16, 100, 0, 90, None),
+    ("start_inside_a_page_of_a_whole_step", 16, 100, 13, wa.NO_WINDOW, None),
+    ("a_tile_wholly_past_the_real_rows", 384, 64, 0, 90, 130),
+    ("global_at_a_table_of_240_columns", 16, 12300, 10005, wa.NO_WINDOW, 12),
 ])
 def test_the_prefill_kernel_is_the_mask_over_the_whole_span(
         name, s, pos0, start, window, real):
     """`window_prefill` in interpret mode against the gathered span under
     the mask: a global layer (first key `start`), a window whose first key
     lies inside a page (44 - 20 + 1 = 25 = page 3, row 1), two tiles of 128
-    rows each with its own first column, and padding past `real`."""
-    k, v, table = _pools(mb=(pos0 + s) // 8 + 1)
+    rows each with its own first column, and padding past `real`; walks
+    that are no whole number of steps, edges inside whole steps, a tile that
+    walks nothing, and the cell's 240 table columns."""
+    if "240" in name:    # the cell's table: pages of 64 tokens, 16 a step
+        k, v, table = _pools(bs=64, blocks=248, mb=240)
+    else:
+        mb = (pos0 + s) // 8 + 1
+        k, v, table = _pools(blocks=max(40, mb + 8), mb=mb)
     q = jnp.asarray(np.random.default_rng(1).normal(size=(s, 2, 3, 128)),
                     jnp.float32)
     kw = dict(start=start, window=window, real=real)
@@ -141,6 +156,152 @@ def test_a_tile_walks_its_window_and_no_page_behind_it():
         jnp.int32(2048), tiles=16, tile=128, block=64, columns=240)
     assert not lo.any() and cnt.tolist() == [
         (12288 + 128 * t + 127) // 64 + 1 for t in range(16)]
+
+
+    # the host's count (serve/engine.py _chunk_pages) by the same rule
+    args = (12300, 0, 4096, 300)
+    for a, b in zip(wa.tile_walks(*args, tiles=16, tile=128, block=64,
+                                  columns=240, xp=np),
+                    wa.tile_walks(*map(jnp.int32, args), tiles=16, tile=128,
+                                  block=64, columns=240)):
+        assert a.tolist() == b.tolist()
+
+
+def test_a_step_folds_what_the_shapes_allow():
+    """`pages_per_step`, the one place the key tile comes from: 8 pages =
+    512 keys at the window cell's shapes (896 rows, pages of 64 tokens of
+    4 x 128 lanes, 240 columns), a power of two whatever the shapes, never
+    more than the table holds, fewer where a tile has more rows, a page
+    more tokens or a row more lanes; the walk's last step computes a
+    quarter of a step at a time, of at least a lane tile of keys."""
+    assert wa.pages_per_step(64, 512, 896, 240) == 8
+    assert wa.pages_per_step(64, 512, 896, 5) == 4
+    assert wa.pages_per_step(64, 512, 2048, 240) == 4
+    assert wa.pages_per_step(16, 512, 896, 240) == 32
+    assert wa.pages_per_step(64, 512, 256, 76) == 16
+    assert wa.pages_per_step(64, 4096, 256, 76) == 2
+    assert wa.pages_per_step(8, 256, 48, 7, 4) == 4
+    assert wa.pages_per_step(8, 256, 48, 1, 4) == 1
+    for shape in [(64, 512, 896, 240), (8, 256, 384, 33, 4),
+                  (32, 512, 4096, 100), (128, 128, 128, 1000)]:
+        p = wa.pages_per_step(*shape)
+        assert p & (p - 1) == 0 and 1 <= p <= shape[3], (shape, p)
+    assert wa._tail_pages(16, 64) == 4 and wa._tail_pages(8, 64) == 2
+    assert wa._tail_pages(2, 64) == 2 and wa._tail_pages(16, 8) == 16
+
+
+def _call(pages, s, pos0, start, window, real, mb, **kw):
+    k, v, table = _pools(blocks=mb + 8, mb=mb)
+    q = jnp.asarray(np.random.default_rng(1).normal(size=(s, 2, 3, 128)),
+                    jnp.float32)
+    i32 = jnp.int32
+    got = wa._prefill_call(q, k, v, table, i32(pos0), i32(start), i32(window),
+                           i32(real), block=8, pages=pages, **kw)
+    return got, (q, k, v, table)
+
+
+@pytest.mark.parametrize("pages", [1, 2, 4, 8, 16, 32])
+def test_the_prefill_kernel_at_every_count_of_pages_a_step(pages):
+    """Every power of two the rule can return, on pages of 8 tokens (score
+    lane blocks of 8 to 128 keys; from 16 pages up the last step computes
+    its pages a part at a time): a window whose edge and whose diagonal lie
+    inside steps, a walk of 29 pages that no count but 1 divides."""
+    s, pos0, start, window, real, mb = 128, 200, 3, 100, 117, 48
+    got, (q, k, v, table) = _call(pages, s, pos0, start, window, real, mb)
+    want = wa._prefill_reference(q, k, v, table, pos0, start, window)
+    assert np.abs(np.asarray(got - want))[:real].max() < 2e-5
+    assert np.isfinite(np.asarray(got)).all()
+    counts = wa.walk_counts(pos0, start, window, real, s=s, block=8,
+                            columns=mb, pages=pages)
+    part = wa._tail_pages(pages, 8)
+    assert counts["walked"] == 29 and counts["steps"] == -(-29 // pages)
+    assert counts["executed"] == 29 // pages * pages \
+        + -(-(29 % pages) // part) * part
+    assert counts == _brute_counts(pos0, start, window, real, s=s, block=8,
+                                   columns=mb, pages=pages)
+
+
+@pytest.mark.parametrize("name, pages, s, pos0, start, window, real, clear", [
+    ("window_deep", 4, 128, 900, 0, 403, 128, 7),
+    ("window_two_tiles_ragged", 2, 256, 520, 0, 300, 200, 20),
+    ("global_from_a_start", 8, 128, 1000, 70, wa.NO_WINDOW, 128, 13),
+    ("global_short", 16, 128, 128, 0, wa.NO_WINDOW, 128, 1),
+])
+def test_a_step_without_an_edge_adds_no_mask(name, pages, s, pos0, start,
+                                             window, real, clear):
+    """The maskless body is taken exactly where no row's mask can cut a key
+    of the step: the same call with the mask added in EVERY step gives the
+    same output to the bit (a maskless step over an edge would keep a cut
+    score, a masked step adds zeros), and the host's count of such steps is
+    the brute-force one, over the rows and keys of every step."""
+    mb = (pos0 + s) // 8 + 1
+    got, _ = _call(pages, s, pos0, start, window, real, mb)
+    all_masked, _ = _call(pages, s, pos0, start, window, real, mb,
+                          mask_all=True)
+    assert np.array_equal(np.asarray(got), np.asarray(all_masked))
+    counts = wa.walk_counts(pos0, start, window, real, s=s, block=8,
+                            columns=mb, pages=pages)
+    assert counts == _brute_counts(pos0, start, window, real, s=s, block=8,
+                                   columns=mb, pages=pages)
+    assert counts["clear"] == clear
+
+
+@pytest.mark.parametrize("pages, s, pos0, window, real", [
+    (4, 256, 520, 300, 200),        # whole steps, a part, a tile past real
+    (16, 128, 200, wa.NO_WINDOW, 128),     # one step a tile, split in parts
+    (2, 384, 64, 90, 130)])         # two live tiles, the third walks none
+def test_the_prefill_kernel_waits_for_what_it_reads(pages, s, pos0, window,
+                                                    real, monkeypatch):
+    """The kernel under the TPU interpreter, which lands a copy's bytes only
+    when it is WAITED for and watches for races: a step's pages read before
+    their wait, a buffer refilled while a head still reads it, or a tile
+    that takes the copies its predecessor did not start, shows here and
+    nowhere else on the CPU (plain interpret mode copies at `start`)."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    pallas_call = pl.pallas_call
+
+    def on_wait(*args, interpret, **kw):
+        assert interpret is True
+        return pallas_call(*args, **kw, interpret=pltpu.InterpretParams(
+            detect_races=True, dma_execution_mode="on_wait"))
+
+    monkeypatch.setattr(pl, "pallas_call", on_wait)
+    jax.clear_caches()
+    mb = (pos0 + s) // 8 + 1
+    got, (q, k, v, table) = _call(pages, s, pos0, 0, window, real, mb)
+    want = wa._prefill_reference(q, k, v, table, pos0, 0, window)
+    assert np.abs(np.asarray(got - want))[:real].max() < 2e-5
+    assert not interpret_pallas_call.races.races_found
+    jax.clear_caches()
+
+
+def _brute_counts(pos0, start, window, real, *, s, block, columns, pages):
+    """`walk_counts` from the mask itself: a tile walks the pages that hold
+    a key some row of it attends, `pages` a step; a whole step is clear
+    where every row attends every key of it; the last step computes what is
+    left in parts."""
+    tile, part = min(wa.WINDOW_TILE, s), wa._tail_pages(pages, block)
+    keys = np.arange(columns * block)
+    out = dict(steps=0, clear=0, walked=0, executed=0)
+    for first in range(pos0, pos0 + min(s, real), tile):
+        rows = first + np.arange(tile)[:, None]
+        seen = (keys <= rows) & (keys >= start) & (keys > rows - window)
+        held = np.unique(keys[seen.any(0)] // block)
+        assert held.tolist() == list(range(held[0], held[-1] + 1))
+        out["walked"] += len(held)
+        for col in range(held[0], held[-1] + 1, pages):
+            left = held[-1] + 1 - col
+            out["steps"] += 1
+            if left >= pages:
+                out["executed"] += pages
+                out["clear"] += bool(
+                    seen[:, col * block:(col + pages) * block].all())
+            else:
+                out["executed"] += -(-left // part) * part
+    return out
 
 
 @pytest.mark.parametrize("window", [5, 16, 1000])
@@ -499,6 +660,9 @@ def test_scheduler_serves_it_and_the_spans_and_counters_say_what_ran(toy):
     assert len(chunks) == sum(-(-n // CHUNK) for n in lens)
     for a in chunks:
         assert 0 < a["window_pages"] <= 3 * a["global_pages"]
+        # what the steps compute beside what the tiles walk (PR 46)
+        assert a["pages_executed"] >= a["window_pages"] + a["global_pages"]
+        assert 0 <= a["steps_unmasked"] < 1
         assert a["window_pages_freed"] == max(
             0, a["pos0"] - WINDOW + 1) // PAGE - max(
             0, a["pos0"] - CHUNK - WINDOW + 1) // PAGE
@@ -516,6 +680,56 @@ def test_scheduler_serves_it_and_the_spans_and_counters_say_what_ran(toy):
     assert snap["kv_pages_held{group=global}"] == 0 \
         == snap["kv_pages_held{group=window}"]
     assert snap["moe_rows_held_total"] == snap["moe_rows_routed_total"] > 0
+
+
+@pytest.mark.parametrize("pos0, take, width", [
+    (0, 8, 8), (8, 8, 8), (16, 3, 4), (24, 5, 8), (40, 8, 8)])
+def test_a_chunks_span_counts_what_the_kernels_grid_runs(engine, pos0, take,
+                                                         width):
+    """`_chunk_pages` (the `prefill_chunk` span's attrs) against the count
+    made from the mask itself: the pages a chunk's `window_prefill` calls
+    walk, by group and over the layers of each, the pages their grid steps
+    execute at the step the rule gives this engine's shapes, and the share
+    of steps without a mask."""
+    m = engine.model
+    columns = engine._pt.shape[1]
+    pages = wa.pages_per_step(
+        PAGE, m.kv_heads * m.head_dim,
+        m.num_heads // m.kv_heads * min(width, 128), columns,
+        engine._kv_itemsize)
+    assert pages == 16 == columns
+    far, near = (_brute_counts(pos0, 0, w, take, s=width, block=PAGE,
+                               columns=columns, pages=pages)
+                 for w in (wa.NO_WINDOW, WINDOW))
+    g, n = engine._global_layers, engine._window_layers
+    assert (g, n) == (2, 6)
+    got = engine._chunk_pages(pos0, take, width)
+    assert got == {
+        "global_pages": g * far["walked"], "window_pages": n * near["walked"],
+        "pages_executed": g * far["executed"] + n * near["executed"],
+        "steps_unmasked": round((g * far["clear"] + n * near["clear"])
+                                / (g * far["steps"] + n * near["steps"]), 4)}
+
+
+def test_the_cells_chunk_executes_what_the_longer_step_costs():
+    """The counter at the cell's shapes (a 2,048-token chunk at position
+    4,096, 8 pages a step, pages of 64, a window of 4,096, 240 columns), one
+    KV head and layer: a window tile walks 66 pages in eight whole steps and
+    a part of one, seven of them without a mask; a global tile all its
+    pages; executed over walked says what the longer tile costs at the
+    edges (nothing here: every walk is a whole number of 2-page parts)."""
+    kw = dict(s=2048, block=64, columns=240, pages=8)
+    near = wa.walk_counts(4096, 0, 4096, 2048, **kw)
+    far = wa.walk_counts(4096, 0, wa.NO_WINDOW, 2048, **kw)
+    assert near == _brute_counts(4096, 0, 4096, 2048, **kw)
+    assert far == _brute_counts(4096, 0, wa.NO_WINDOW, 2048, **kw)
+    assert near == {"steps": 144, "clear": 112, "walked": 1056,
+                    "executed": 1056}
+    assert far["walked"] == sum(66 + 2 * t for t in range(16)) == 1296
+    assert far["steps"] == sum(-(-(66 + 2 * t) // 8) for t in range(16))
+    assert far["clear"] == 168 - 16 and far["executed"] == 1296
+    wide = wa.walk_counts(4096, 0, 4096, 2048, **dict(kw, pages=32))
+    assert wide["executed"] == 1152 and wide["clear"] == 16
 
 
 def test_published_widths_hold_3_966_937_600_parameters():

@@ -724,6 +724,15 @@ def test_kernel_compiles_for_v5e(topo, name):
                 "thinker_reglu_ch": "moe_gmm_glu"}[name[:16]]
         calls = _kernel_calls(text)
         assert len(calls) == 1 and calls[0].endswith(want), calls
+    if name == "thinker_window_prefill_2048":
+        # 8 pages = 512 keys a step by the rule (PR 46): every head's q,
+        # output and state, the pages' buffers two deep and a head's score
+        # tile stay inside what the call asks for (`vmem_limit_bytes`)
+        from ddp_practice_tpu.ops import window_attention as wa
+
+        assert wa.pages_per_step(64, 512, 7 * wa.WINDOW_TILE, 240) == 8
+        (vmem,) = _scoped_vmem(text).values()
+        assert vmem <= 24 * 2**20, vmem
     if name in GROUPED_WALKS:
         # the chunk's four buffers and the tile's scores stay inside what a
         # kernel is scoped, 16 MiB
@@ -1320,6 +1329,26 @@ def test_smallthinker_programs_carry_their_scopes_and_kernels(topo, prog,
         cell, prog, text, sample=prog == "decode_burst") >= 1
     assert prog != "prefill" or "/sample/dynamic_update_slice" in text
     assert _kernel_counts(text) == kernels
+
+
+def test_an_eight_layer_prefill_lowers_window_prefill_once(topo):
+    """The cell's chunk program at its published depth (G W W W twice: two
+    global and six window layers), traced and lowered for the described
+    v5e, not compiled: the module holds ONE `window_prefill` kernel in a
+    function of its own called eight times, because `window` is a run-time
+    scalar, the pages a step folds follow from shapes the layers share
+    (`ops/window_attention.py pages_per_step`) and the `pallas_call` is
+    jitted on its own (`_prefill_call`). Its three bodies are lowered once
+    a program, not once a layer (ROADMAP S5)."""
+    CELL_DEPTH["smallthinker"], was = (
+        lambda cfg: dict(cfg, layers_run=8)), CELL_DEPTH["smallthinker"]
+    try:
+        text = _cell_programs(
+            topo, "smallthinker_serve_shortlong")["prefill"]().as_text()
+    finally:
+        CELL_DEPTH["smallthinker"] = was
+    assert text.count('kernel_name = "window_prefill"') == 1
+    assert len(re.findall(r"call @_prefill_call\b", text)) == 8
 
 
 def _abstract_trainer(topo, *, model, mesh_cfg, model_kwargs, sample,
