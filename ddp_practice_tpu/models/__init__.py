@@ -337,6 +337,34 @@ def _smallthinker(*, num_classes, policy, axis_name, **kw):
     )
 
 
+@register("ling3")
+def _ling3(*, num_classes, policy, axis_name, layers: int = 3,
+           layer_group_size: int = 3, first_dense: int = 1, **kw):
+    # the same HybridLM in Ling-3.0-flash's layout, the pattern built from
+    # the config's keys: layer i of `layers` is a mixer sub-layer (latent
+    # attention 'T' where (i + 1) % layer_group_size == 0, Kimi Delta
+    # Attention 'K' otherwise, a gate a head on either's output) and a
+    # feed-forward sub-layer (a dense SwiGLU 'D' for the first
+    # `first_dense` layers, then experts 'U' under a sigmoid router that
+    # keeps `topk_group` of `n_group` groups); a recurrent state AND latent
+    # pages in one cache, untied head; test-sized defaults, the published
+    # widths come as options (perf/families/ling3.py model_options)
+    kw.setdefault("pattern", "".join(
+        ("T" if (i + 1) % layer_group_size == 0 else "K")
+        + ("D" if i < first_dense else "U") for i in range(layers)))
+    kw.setdefault("pos_emb", "rope")
+    kw.setdefault("norm_eps", 1e-6)
+    kw.setdefault("hidden_dim", 64)
+    kw.setdefault("experts_held", 16)
+    kw.setdefault("n_group", 4)
+    kw.setdefault("topk_group", 2)
+    return HybridLM(
+        dtype=policy.compute_dtype,
+        param_dtype=policy.param_dtype,
+        **kw,
+    )
+
+
 @register("deepseek_v3")
 def _deepseek_v3(*, num_classes, policy, axis_name, **kw):
     # multi-head latent attention + gated experts after leading dense

@@ -8,6 +8,9 @@ a pattern string.
          state, a low-rank dt, RMSNorm on dt, B and C (Jamba's)
     'G'  Gated DeltaNet mixer: the gated delta rule on a (key_dim,
          value_dim) matrix state a value head   (ops/gdn.py)
+    'K'  Kimi Delta Attention mixer: the delta rule with a decay a KEY
+         LANE under a bounded (safe) gate, one key head a value head, one
+         output gate scalar a head   (ops/kda.py; `KimiDeltaMixer`)
     'L'  lightning (linear) attention: the Mamba-2 recurrence with dt = 1
          and one constant decay a head, on rotated, normed q and k
          (ops/ssm.py `ssm_step` / `ssm_scan`; `LightningMixer`)
@@ -17,6 +20,9 @@ a pattern string.
     'R'  GatedMoE with ReGLU experts, a softmax router that reads the
          normed input of the MIXER before it (not its own), no shared
          expert                                         (ops/moe.py)
+    'U'  GatedMoE with a sigmoid router that keeps `topk_group` of
+         `n_group` groups of experts before it picks, a selection bias, an
+         ungated shared expert                          (ops/moe.py)
     'D'  dense SwiGLU MLP of width `mlp_dim`            (ops/moe.py GatedMLP)
     '*'  causal attention, `kv_heads` <= `num_heads`, no positional
          embedding: the recurrent layers carry position, or nothing does
@@ -32,11 +38,15 @@ a pattern string.
          past `sparse.dense_len` visible tokens a query attends `topk`
          pages picked through compressed keys (ops/sparse_attention.py;
          `SparseAttention`)
+    'T'  multi-head latent attention: ONE cached row `[c | k_rope]` a
+         token, rotary on the `rope_dim` rope lanes, absorbed for a decode
+         step (`paged_decode_mla`), one output gate scalar a head
+         (`LatentAttention`, the class `MLALM` runs: models/mla_lm.py)
     logits = RMSNorm(x) W_head         over `vocab_size` rows; with
                                        `tie_embeddings` W_head is the
                                        embedding itself
 
-Five layouts in the registry. Nemotron-H (`create_model("nemotron_h",
+Six layouts in the registry. Nemotron-H (`create_model("nemotron_h",
 ...)`): one mixer a layer from 'M', 'E', '*', untied head. Jamba
 (`create_model("jamba", ...)`): a layer is two sub-layers, a mixer ('S' or
 '*') then 'D', so 28 layers are 56 letters, and the head is tied. Qwen3-Next
@@ -49,18 +59,25 @@ times the head's input (each 1 in the other layouts). SmallThinker
 (`create_model("smallthinker", ...)`): a mixer ('W', every fourth '*' with
 no positional embedding at all) then 'R', no recurrent state
 (`recurrent=False`), a prompt's chunks through the kernel `window_prefill`
-(`attn_prefill="kernel"`), untied head. The widths are
-options, so the tests run all five small and the benchmark at the published
+(`attn_prefill="kernel"`), untied head. Ling-3.0-flash
+(`create_model("ling3", ...)`, the pattern built from `layers`,
+`layer_group_size` and `first_dense`): a mixer ('K', every
+`layer_group_size`-th 'T') then a feed-forward ('D' for the first
+`first_dense` layers, 'U' after), a recurrent state AND latent pages in one
+cache, untied head. The widths are
+options, so the tests run all six small and the benchmark at the published
 sizes (perf/configs/nemotron3_super_ep4.json, jamba2_3b.json,
-qwen3next_80b_ep4.json, minicpm_sala_9b_pp4.json, smallthinker_21b_pp7.json).
+qwen3next_80b_ep4.json, minicpm_sala_9b_pp4.json, smallthinker_21b_pp7.json,
+ling3_flash_ep4.json).
 
 Decode mode keeps TWO kinds of cache in the "cache" collection: attention
-layers the K/V leaves `SelfAttention` declares (flat, or pages under
+layers the K/V leaves `SelfAttention` declares or the one latent leaf of
+`LatentAttention` (flat, or pages under
 `PagedEngine`), state-space layers a fixed-size state a sequence under the
 SAME two leaf names whichever mixer: `ssm_state` in float32 (Mamba-2
 (b, heads, head_dim, state); Mamba-1 (b, state, channels / 128, 128), the
-layout `ops/ssm.py sel_step` reads; Gated DeltaNet (b, value heads,
-key_dim, value_dim)) and `conv_state`, the conv's last
+layout `ops/ssm.py sel_step` reads; Gated DeltaNet and Kimi Delta Attention
+(b, value heads, key_dim, value_dim)) and `conv_state`, the conv's last
 `conv_kernel - 1` inputs ('L' has no conv and declares `ssm_state` alone,
 (b, heads, value, key)). A call with one token a sequence advances the
 state by the recurrence; a call with more runs the scan FROM the
@@ -86,7 +103,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from ddp_practice_tpu.models.vit import SelfAttention
-from ddp_practice_tpu.ops import gdn, sparse_attention as sparse_ops, ssm
+from ddp_practice_tpu.ops import gdn, kda, sparse_attention as sparse_ops, ssm
+from ddp_practice_tpu.ops.attention import _attention, attention_with_mask
+from ddp_practice_tpu.ops.decode_attention import paged_decode_mla
 from ddp_practice_tpu.ops.moe import GatedMLP, GatedMoE, LatentMoE
 from ddp_practice_tpu.ops.rope import apply_rope
 
@@ -339,6 +358,80 @@ class GatedDeltaMixer(nn.Module):
             o.reshape(b, s, values).astype(self.dtype))
 
 
+class KimiDeltaMixer(nn.Module):
+    """Kimi Delta Attention. [q|k|v] = silu(conv(x W_in)), no bias; q, k
+    L2-normalised a head, q / sqrt(key_dim); beta = sigmoid(x W_b) a head;
+    the decay a KEY LANE, g = lower_bound * sigmoid(exp(A_log[h]) *
+    (x W_f + dt_bias)) in float32, so lower_bound < g < 0 (the safe gate;
+    ops/kda.py's chunked form relies on |lower_bound| <= 5); the delta rule
+    with that decay on a (key_dim, value_dim) state a head (ops/kda.py);
+    out = (RMSNorm_head(o) * sigmoid(x W_z)[h]) W_out, one gate scalar a
+    head, the norm and the gate in float32."""
+
+    num_heads: int
+    key_dim: int
+    value_dim: int
+    conv_kernel: int = 4
+    lower_bound: float = -5.0
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, *, decode: bool = False, attn_start=None,
+                 paged: bool = False, real_lengths=None):
+        b, s, d = x.shape
+        h, dk, dv = self.num_heads, self.key_dim, self.value_dim
+        keys, values = h * dk, h * dv
+        conv_dim = 2 * keys + values
+        f32 = jnp.float32
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        dense = functools.partial(nn.Dense, use_bias=False, **kw)
+        vec = lambda name, shape: self.param(
+            name, nn.initializers.normal(0.02), shape, self.param_dtype)
+        qkv = dense(conv_dim, name="in_proj")(x)
+        a = dense(keys, name="f_proj")(x).astype(f32) \
+            + vec("dt_bias", (keys,)).astype(f32)
+        rate = jnp.exp(vec("A_log", (h,)).astype(f32))
+        g = self.lower_bound * nn.sigmoid(
+            rate[:, None] * a.reshape(b, s, h, dk))
+        beta = nn.sigmoid(dense(h, name="b_proj")(x).astype(f32))
+        gate = nn.sigmoid(dense(h, name="z_proj")(x).astype(f32))
+        conv_w = vec("conv_kernel", (self.conv_kernel, conv_dim))
+        real = _real_rows(s, attn_start, real_lengths, paged)
+        if real is not None:
+            beta = jnp.where(real, beta, 0.0)
+            g = jnp.where(real[..., None], g, 0.0)
+            qkv = jnp.where(real, qkv, 0)
+        state0 = jnp.zeros((b, h, dk, dv), f32)
+        tail0 = jnp.zeros((b, self.conv_kernel - 1, conv_dim), self.dtype)
+        if decode:
+            ssm_state, conv_state = _state_leaves(self, state0, tail0)
+            if not self.is_initializing():
+                state0, tail0 = ssm_state.value, conv_state.value
+        qkv, tail = ssm.causal_conv(qkv, tail0, conv_w, None, real_lengths)
+        q, k, v = jnp.split(nn.silu(qkv).astype(f32), [keys, 2 * keys],
+                            axis=-1)
+        unit = lambda t: t * jax.lax.rsqrt(
+            jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+        q = unit(q.reshape(b, s, h, dk)) * dk ** -0.5
+        k = unit(k.reshape(b, s, h, dk))
+        v = v.reshape(b, s, h, dv)
+        if decode and s == 1 and not self.is_initializing():
+            o, state = kda.kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                    beta[:, 0], state0)
+            o = o[:, None]
+        else:
+            o, state = kda.kda_scan(q, k, v, g, beta, state0)
+        if decode and not self.is_initializing():
+            ssm_state.value = state
+            conv_state.value = tail.astype(conv_state.value.dtype)
+        o = RMSNorm(self.norm_eps, f32, self.param_dtype, name="norm")(o)
+        o = o * gate[..., None]
+        return dense(d, name="out_proj")(
+            o.reshape(b, s, values).astype(self.dtype))
+
+
 class LightningMixer(nn.Module):
     """Lightning (linear) attention. [q|k|v|gate] = x W_in; q, k RMSNormed a
     head, rotated whole (half-split pairs) at the token's position,
@@ -571,6 +664,196 @@ class SparseAttention(nn.Module):
         return jnp.stack(outs)
 
 
+_LANES = 128
+# Cached positions a block of the several-token paged path expands and scores
+# at a time (`LatentAttention._span_attention`).
+_SPAN_TOKENS = 1024
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention: one cached row `[RMSNorm(c) | k_rope]` a
+    token and layer, un-absorbed over several tokens, absorbed (the kernel
+    `paged_decode_mla`) for one token a slot through pages; the equations
+    and the two paths are in models/mla_lm.py's docstring, whose `MLALM`
+    runs this class in every layer as `HybridLM` runs it for a 'T'."""
+
+    num_heads: int
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    latent_dim: int = 512
+    rope_theta: float = 10000.0
+    rope_interleave: bool = True
+    norm_eps: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    # "head": every head's output times sigmoid(x W_z)[h], one scalar a head
+    out_gate: Optional[str] = None
+
+    @property
+    def row_width(self) -> int:
+        """Lanes of a cached row: latent + rope, in whole lane tiles."""
+        return -(-(self.latent_dim + self.rope_dim) // _LANES) * _LANES
+
+    def _expand(self, rows, kv_b):
+        """Cached rows (b, s, row) -> K (b, s, h, nope + rope) and V
+        (b, s, h, v): every position's keys and values from its latent."""
+        lat, r = self.latent_dim, self.rope_dim
+        kv = jnp.einsum("bsl,lhe->bshe", rows[..., :lat], kv_b,
+                        preferred_element_type=jnp.float32
+                        ).astype(rows.dtype)
+        k_rope = jnp.broadcast_to(
+            rows[:, :, None, lat:lat + r],
+            rows.shape[:2] + (self.num_heads, r))
+        return (jnp.concatenate([kv[..., :self.nope_dim], k_rope], axis=-1),
+                kv[..., self.nope_dim:])
+
+    @nn.compact
+    def __call__(self, x, *, decode: bool = False, attn_start=None,
+                 page_table=None, kv_lengths=None):
+        b, s, d = x.shape
+        h, lat, r = self.num_heads, self.latent_dim, self.rope_dim
+        cd = self.dtype
+        kw = dict(use_bias=False, dtype=cd, param_dtype=self.param_dtype)
+        q = nn.DenseGeneral((h, self.nope_dim + r), name="q", **kw)(x)
+        kv_a = nn.Dense(lat + r, name="kv_a", **kw)(x)
+        c = RMSNorm(self.norm_eps, cd, self.param_dtype,
+                    name="kv_norm")(kv_a[..., :lat])
+        kv_b = self.param(
+            "kv_b", nn.initializers.normal(0.02),
+            (lat, h, self.nope_dim + self.v_dim), self.param_dtype
+        ).astype(cd)
+        paged = page_table is not None
+        cached = index = None
+        if decode:
+            if paged and (kv_lengths is None or self.is_initializing()):
+                raise ValueError(
+                    "a paged call needs kv_lengths, and its pools come "
+                    "from serve/kv_pages.py make_paged_cache")
+            cached = self.variable("cache", "cached_latent", jnp.zeros,
+                                   (b, s, self.row_width), cd)
+            # tree parity with the other models' caches: the flat layout's
+            # cursor; a block pool has no clock and leaves it alone
+            index = self.variable("cache", "cache_index",
+                                  lambda: jnp.zeros((), jnp.int32))
+        live = decode and not self.is_initializing()
+        if paged:
+            pos0 = jnp.asarray(kv_lengths, jnp.int32)
+            positions = pos0[:, None] + jnp.arange(s, dtype=jnp.int32)
+        else:
+            positions = (index.value if live else 0) + jnp.arange(s)
+        rope = dict(theta=self.rope_theta, interleaved=self.rope_interleave)
+        q_rope = apply_rope(q[..., self.nope_dim:], positions, **rope)
+        k_rope = apply_rope(kv_a[:, :, None, lat:], positions, **rope)[:, :, 0]
+        q = jnp.concatenate([q[..., :self.nope_dim], q_rope], axis=-1)
+        rows = jnp.concatenate(
+            [c, k_rope, jnp.zeros((b, s, self.row_width - lat - r), cd)],
+            axis=-1)
+        if not live:
+            out = _attention(q, *self._expand(rows, kv_b), causal=True)
+        elif not paged:
+            cur, span = index.value, cached.value.shape[1]
+            cached.value = lax.dynamic_update_slice(
+                cached.value, rows.astype(cached.value.dtype), (0, cur, 0))
+            index.value = cur + s
+            kpos = jnp.arange(span)
+            mask = kpos[None, :] <= positions[:, None]          # (s, span)
+            if attn_start is not None:
+                mask = mask[None] & (kpos[None, None, :]
+                                     >= attn_start[:, None, None])
+                mask = mask[:, None]                    # (b, 1, s, span)
+            out = attention_with_mask(
+                q, *self._expand(cached.value, kv_b), mask)
+        else:
+            pool = cached.value
+            bs = pool.shape[1]
+            # the clamp keeps a retired slot (page row 0, length pinned)
+            # writing inside the table, as in models/vit.py _paged_decode
+            col = jnp.minimum(positions // bs, page_table.shape[1] - 1)
+            blk = jnp.take_along_axis(page_table, col, axis=1)
+            pool = pool.at[blk, positions % bs].set(rows.astype(pool.dtype))
+            cached.value = pool
+            if s == 1:
+                out = self._absorbed_step(q[:, 0], kv_b, pool, page_table,
+                                          pos0, attn_start)[:, None]
+            else:
+                out = self._span_attention(q, kv_b, pool, page_table,
+                                           positions, attn_start)
+        if self.out_gate == "head":
+            out = out * nn.sigmoid(
+                nn.Dense(h, name="gate", **kw)(x))[..., None]
+        elif self.out_gate is not None:
+            raise ValueError(f"out_gate {self.out_gate!r}: want 'head'")
+        return nn.DenseGeneral(d, axis=(-2, -1), name="out", **kw)(out)
+
+    def _span_attention(self, q, kv_b, pool, page_table, positions,
+                        attn_start):
+        """Several tokens a slot against the slot's pages, un-absorbed:
+        q (b, s, h, nope + rope) at slot-local `positions` (b, s) ->
+        (b, s, h, v). The span is taken `_SPAN_TOKENS` at a time, K and V
+        expanded from each block of rows and folded into a running
+        softmax, from the block of the first position any row may see to
+        the block of the last query and NO further: a chunk at position
+        2,048 of an 8,960-position table scores 3 blocks, not 9, and the
+        (h, s, span) scores are never whole in memory."""
+        b, s, h, _ = q.shape
+        bs, mb = pool.shape[1], page_table.shape[1]
+        pages = max(1, min(_SPAN_TOKENS // bs, mb))
+        tile = pages * bs
+        n_blocks = -(-mb // pages)
+        table = jnp.pad(page_table, ((0, 0), (0, n_blocks * pages - mb)))
+        start = jnp.zeros((b,), jnp.int32) if attn_start is None \
+            else jnp.asarray(attn_start, jnp.int32)
+        first = jnp.min(start) // tile
+        last = jnp.minimum(jnp.max(positions) // tile, n_blocks - 1)
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+
+        def block(j, carry):
+            m, l, acc = carry
+            cols = lax.dynamic_slice(table, (0, j * pages), (b, pages))
+            rows = jnp.take(pool, cols, axis=0).reshape(b, tile, -1)
+            k, v = self._expand(rows.astype(q.dtype), kv_b)
+            kpos = j * tile + jnp.arange(tile, dtype=jnp.int32)
+            seen = (kpos[None, None, :] <= positions[:, :, None]) \
+                & (kpos[None, None, :] >= start[:, None, None])
+            seen = seen[:, None]                          # (b, 1, s, tile)
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                                preferred_element_type=jnp.float32) * scale
+            m_new = jnp.maximum(m, jnp.max(
+                jnp.where(seen, scores, -1e30), axis=-1, keepdims=True))
+            p = jnp.where(seen, jnp.exp(scores - m_new), 0.0)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * alpha + jnp.einsum(
+                "bhqk,bkhd->bhqd", p.astype(q.dtype), v,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        m0 = jnp.full((b, h, s, 1), -1e30, jnp.float32)
+        _, l, acc = lax.fori_loop(
+            first, last + 1, block,
+            (m0, jnp.zeros_like(m0),
+             jnp.zeros((b, h, s, self.v_dim), jnp.float32)))
+        out = acc / jnp.maximum(l, 1e-30)
+        return jnp.swapaxes(out, 1, 2).astype(q.dtype)
+
+    def _absorbed_step(self, q, kv_b, pool, page_table, pos0, attn_start):
+        """q (b, h, nope + rope) of one token a slot -> (b, h, v)."""
+        n, lat = self.nope_dim, self.latent_dim
+        q_abs = jnp.einsum("bhn,lhn->bhl", q[..., :n], kv_b[..., :n],
+                           preferred_element_type=jnp.float32
+                           ).astype(q.dtype)
+        pad = self.row_width - lat - self.rope_dim
+        q_row = jnp.concatenate(
+            [q_abs, q[..., n:], jnp.zeros(q.shape[:2] + (pad,), q.dtype)],
+            axis=-1).astype(pool.dtype)
+        ctx = paged_decode_mla(
+            q_row, pool, page_table, pos0, attn_start, v_lanes=lat,
+            sm_scale=1.0 / (n + self.rope_dim) ** 0.5)
+        return jnp.einsum("bhl,lhv->bhv", ctx, kv_b[..., n:],
+                          preferred_element_type=jnp.float32).astype(q.dtype)
+
+
 class HybridLM(nn.Module):
     pattern: str = "MEM*EME"
     vocab_size: int = 256
@@ -591,6 +874,13 @@ class HybridLM(nn.Module):
     gdn_value_heads: int = 4
     gdn_key_dim: int = 16
     gdn_value_dim: int = 16
+    # 'K' (its heads are `gdn_value_heads` of `gdn_key_dim` /
+    # `gdn_value_dim`): the safe gate's lower bound
+    kda_lower_bound: float = -5.0
+    # 'T' (its heads are `num_heads`, its rotary lanes `rope_dim`)
+    nope_dim: int = 16
+    v_dim: int = 16
+    attn_latent_dim: int = 32
     # 'L' (its heads are `num_heads` of `head_dim`): the layer numbers its
     # decays follow, one a letter 'L' in pattern order, of `decay_layers`
     lightning_layers: tuple = ()
@@ -611,7 +901,7 @@ class HybridLM(nn.Module):
     # 'A'
     rope_dim: int = 8
     rope_theta: float = 10000.0
-    # 'E', 'Q', 'R' ('Q' and 'R' have no latent, 'R' no shared expert)
+    # 'E', 'Q', 'U', 'R' ('E' alone has a latent, 'R' no shared expert)
     num_experts: int = 16
     top_k: int = 3
     latent_dim: int = 32
@@ -620,6 +910,9 @@ class HybridLM(nn.Module):
     experts_held: int = 4
     expert_offset: int = 0
     routed_scaling: float = 1.0
+    # 'U': the router keeps `topk_group` of `n_group` groups of experts
+    n_group: int = 1
+    topk_group: int = 1
     norm_eps: float = 1e-5
     # every RMSNorm of the residual stream and of 'A' scales by 1 + w
     norm_plus_one: bool = False
@@ -661,13 +954,13 @@ class HybridLM(nn.Module):
         (the head over a 2,048-token chunk's every row is 1.2 TFLOP at
         73,448 rows)."""
         del train
-        if set(self.pattern) - set("MSGLEQRD*AWB") or not self.pattern:
+        if set(self.pattern) - set("MSGKLEQURD*AWBT") or not self.pattern:
             raise ValueError(
                 f"pattern {self.pattern!r}: want a string of 'M', 'S', 'G', "
-                "'L', 'E', 'Q', 'R', 'D', '*', 'A', 'W', 'B'")
-        if set("ALW") & set(self.pattern) and self.pos_emb != "rope":
+                "'K', 'L', 'E', 'Q', 'U', 'R', 'D', '*', 'A', 'W', 'B', 'T'")
+        if set("ALWT") & set(self.pattern) and self.pos_emb != "rope":
             raise ValueError(
-                "'A', 'L' and 'W' rotate q and k: want pos_emb='rope'")
+                "'A', 'L', 'W' and 'T' rotate q and k: want pos_emb='rope'")
         if "W" in self.pattern and self.window < 1:
             raise ValueError(f"'W' attends a window of keys: {self.window}")
         if self.pattern[0] == "R":
@@ -716,7 +1009,7 @@ class HybridLM(nn.Module):
         lightning = iter(self.lightning_layers)
         for i, kind in enumerate(self.pattern):
             y = norm(name=f"norm{i}")(x)
-            if kind not in "EQRD":
+            if kind not in "EQURD":
                 mixer_in = y   # what a router placed before the mixer reads
             if kind == "L":
                 y = LightningMixer(
@@ -748,8 +1041,30 @@ class HybridLM(nn.Module):
                     self.gdn_key_dim, self.gdn_value_dim, self.conv_kernel,
                     self.norm_eps, name=f"mamba{i}", **kw,
                 )(y, **recur)
+            elif kind == "K":
+                y = KimiDeltaMixer(
+                    self.gdn_value_heads, self.gdn_key_dim,
+                    self.gdn_value_dim, self.conv_kernel,
+                    self.kda_lower_bound, self.norm_eps, name=f"mamba{i}",
+                    **kw,
+                )(y, **recur)
+            elif kind == "T":
+                y = LatentAttention(
+                    self.num_heads, self.nope_dim, self.rope_dim, self.v_dim,
+                    self.attn_latent_dim, self.rope_theta,
+                    norm_eps=self.norm_eps, out_gate="head",
+                    name=f"attn{i}", **kw,
+                )(y, decode=decode, attn_start=attn_start,
+                  page_table=tables["global"], kv_lengths=kv_lengths)
             elif kind == "D":
                 y = GatedMLP(self.mlp_dim, name=f"mlp{i}", **kw)(y)
+            elif kind == "U":
+                y = GatedMoE(
+                    self.num_experts, self.top_k, self.expert_dim,
+                    self.shared_dim, self.experts_held, self.expert_offset,
+                    self.routed_scaling, n_group=self.n_group,
+                    topk_group=self.topk_group, name=f"moe{i}", **kw,
+                )(y, decode=decode)
             elif kind == "Q":
                 y = GatedMoE(
                     self.num_experts, self.top_k, self.expert_dim,

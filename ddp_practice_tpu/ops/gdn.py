@@ -35,6 +35,19 @@ kernel `gdn_scan`, the state in VMEM from chunk to chunk, off the TPU a
 `lax.scan` over the chunks. A position with beta = 0, g = 0 and zero q, k,
 v moves nothing: left padding and the tail of a partial chunk.
 
+A decay a KEY CHANNEL (Kimi Delta Attention, ops/kda.py, which imports this
+file's inverse, masks, head blocks and carry): `exp(g_t)` is then a vector
+over the key lanes, `exp(G_t - G_s)` stays INSIDE the dot product `k_t . k_s`
+and `q_t . k_s` and can no longer be factored out as the one scalar a pair
+that `decay` is here, so the two (C, C) sums are matmuls of rows scaled
+about a reference row, `(k_t exp(G_t - G_r)) . (k_s exp(G_r - G_s))`. Over a
+whole chunk of 64 positions G runs to 64 |g|max and `exp(G_r - G_s)` leaves
+float32 (|g| up to 5: exp(320)); about the start of a SUB-chunk of 16 it is
+at most exp(80) < exp(88), which is what that model's bounded gate buys.
+The step's decay becomes a third column beside k and q (`_lane_columns`) and
+the carry's end-of-chunk decay a row over the key lanes (`_scan_kernel`
+`key_decay`); T, U, the terms' names and the three carry lines are as here.
+
 Device op names (PERF.md section 3): the kernels are `gdn_step`,
 `gdn_terms` and `gdn_scan`, one `gdn_terms` beside every `gdn_scan`, both
 under the scope `gdn_scan`; `flood_gdn_dev_pct` sums the three by their
@@ -75,6 +88,30 @@ def _dot(x, y, dims=(((1,), (0,)), ((), ()))):
 
 
 _TA = (((0,), (0,)), ((), ()))   # x^T y
+
+
+def _column_selector(n: int, width: int):
+    """(8, n width) float32 0 / 1: block r of `width` lanes has ones in row
+    r. What `_lane_columns` multiplies by; the same for every head of a
+    kernel's cell, so made once a cell."""
+    at = lax.broadcasted_iota(jnp.int32, (8, 1), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (8, n * width), 1)
+    return ((lane >= at * width) & (lane < (at + 1) * width)).astype(F32)
+
+
+def _lane_columns(rows, pick):
+    """Up to eight (1, d) rows -> as many (d, width) tiles, tile r holding
+    row r's values as COLUMNS broadcast along the lanes (`col[a, b] =
+    x[a]`): ONE depth-8 matmul of the rows, one a sublane, against `pick`
+    (`_column_selector`). A column vector is not a layout the lanes hold
+    (`_step_kernel`)."""
+    d, width = rows[0].shape[-1], pick.shape[1] // len(rows)
+    at = lax.broadcasted_iota(jnp.int32, (8, 1), 0)
+    x = jnp.zeros((8, d), F32)
+    for r, row in enumerate(rows):
+        x = jnp.where(at == r, jnp.broadcast_to(row, (8, d)), x)
+    cols = _dot(x, pick, _TA)                     # (d, rows x width)
+    return [cols[:, r * width:(r + 1) * width] for r in range(len(rows))]
 
 
 def gdn_step_reference(q, k, v, g, beta, state):
@@ -427,18 +464,24 @@ def _carry_reference(terms: dict, h0):
 
 
 def _scan_kernel(w_ref, u0_ref, qg_ref, p_ref, kend_ref, dend_ref, h0_ref,
-                 o_ref, ho_ref, *, heads):
+                 o_ref, ho_ref, *, heads, key_decay=False):
     """Grid (sequences, head blocks, chunks), chunks innermost and in order:
     the block's state stays in VMEM from chunk to chunk (`ho_ref`, whose
     block index ignores the chunk axis, so it goes to HBM once, after the
-    last chunk), seeded from `h0_ref`."""
+    last chunk), seeded from `h0_ref`. `key_decay` (ops/kda.py): a head's
+    `dend` is a decay a KEY lane, (1, dk), and stands as a column over the
+    state's rows (`_lane_columns`)."""
     @pl.when(pl.program_id(2) == 0)
     def _seed():
         ho_ref[...] = h0_ref[...]
 
+    pick = _column_selector(1, ho_ref.shape[-1]) if key_decay else None
     for i in range(heads):
-        o, new = _carry_chunk(ho_ref[i], w_ref[i], u0_ref[i], qg_ref[i],
-                              p_ref[i], kend_ref[i], dend_ref[i])
+        args = (ho_ref[i], w_ref[i], u0_ref[i], qg_ref[i], p_ref[i],
+                kend_ref[i], dend_ref[i])
+        if key_decay:
+            args = args[:-1] + tuple(_lane_columns(args[-1:], pick))
+        o, new = _carry_chunk(*args)
         o_ref[i] = o
         ho_ref[i] = new
 
@@ -450,8 +493,10 @@ def _carry_kernel(terms: dict, h0):
 
 # jitted on its own, as `_terms_call` is (six lowerings a prefill program
 # before: tests/test_tpu_compile.py counts them)
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _carry_call(terms: dict, h0, *, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("interpret", "key_decay"))
+def _carry_call(terms: dict, h0, *, interpret: bool, key_decay: bool = False):
+    """`key_decay` (ops/kda.py): the same carry with `dend` a decay a key
+    lane, as the device op `kda_scan`."""
     bsz, hv, nc, c, dv = terms["u0"].shape
     dk = terms["w"].shape[-1]
     bh = _HEADS if hv % _HEADS == 0 else hv
@@ -459,10 +504,11 @@ def _carry_call(terms: dict, h0, *, interpret: bool):
         (None, bh, None, rows, width), lambda i, j, t: (i, j, t, 0, 0))
     st = pl.BlockSpec((None, bh, dk, dv), lambda i, j, t: (i, j, 0, 0))
     return pl.pallas_call(
-        functools.partial(_scan_kernel, heads=bh),
+        functools.partial(_scan_kernel, heads=bh, key_decay=key_decay),
         grid=(bsz, hv // bh, nc),
         in_specs=[per(c, dk), per(c, dv), per(c, dk),
-                  per(c, terms["p"].shape[-1]), per(c, dk), per(1, dv), st],
+                  per(c, terms["p"].shape[-1]), per(c, dk),
+                  per(1, terms["dend"].shape[-1]), st],
         out_specs=[per(c, dv), st],
         out_shape=[jax.ShapeDtypeStruct((bsz, hv, nc, c, dv), F32),
                    jax.ShapeDtypeStruct(h0.shape, F32)],
@@ -471,7 +517,7 @@ def _carry_call(terms: dict, h0, *, interpret: bool):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_SCAN_VMEM),
         interpret=interpret,
-        name="gdn_scan",
+        name="kda_scan" if key_decay else "gdn_scan",
     )(*(terms[n] for n in _TERMS), h0)
 
 

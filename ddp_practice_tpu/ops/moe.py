@@ -1024,13 +1024,32 @@ class MoEMlp(nn.Module):
 
 
 def route_sigmoid_topk(scores_logits, select_bias, *, k: int,
-                       scaling: float):
+                       scaling: float, n_group: int = 1,
+                       topk_group: int = 1):
     """Sigmoid router with a selection-only bias. scores_logits (N, E)
     float32. The k picks are the top of `sigmoid + bias`; the weights are
     the picks' own sigmoids (no bias), normalised over the picks, times
-    `scaling`. Returns (choices (N, k) int32, weights (N, k) float32)."""
+    `scaling`. With `n_group` > 1 the pick is group-limited: the experts
+    are `n_group` groups of E / n_group consecutive ones, a group's score
+    the sum of its two largest `sigmoid + bias`, the best `topk_group`
+    groups kept and the k picks taken among their experts alone. Returns
+    (choices (N, k) int32, weights (N, k) float32)."""
     s = jax.nn.sigmoid(scores_logits.astype(jnp.float32))
-    _, choices = jax.lax.top_k(s + select_bias.astype(jnp.float32), k)
+    biased = s + select_bias.astype(jnp.float32)
+    if n_group > 1:
+        n, e = biased.shape
+        if e % n_group or not 0 < topk_group <= n_group \
+                or k > topk_group * (e // n_group):
+            raise ValueError(
+                f"{e} experts in {n_group} groups, {topk_group} kept, "
+                f"{k} picked")
+        grouped = biased.reshape(n, n_group, e // n_group)
+        score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(score, topk_group)
+        keep = jnp.zeros((n, n_group), bool).at[
+            jnp.arange(n)[:, None], kept].set(True)
+        biased = jnp.where(keep[..., None], grouped, -jnp.inf).reshape(n, e)
+    _, choices = jax.lax.top_k(biased, k)
     w = jnp.take_along_axis(s, choices, axis=-1)
     w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-20)
     return choices.astype(jnp.int32), w * scaling
@@ -1490,8 +1509,8 @@ def _held_rows(layer, xf, src):
     """The router and the row layout of a layer that holds a range of the
     experts (`LatentMoE`, `GatedMoE`; `layer` gives `num_experts`, `top_k`,
     `experts_held`, `expert_offset`, `routed_scaling`, the parameters'
-    scope and, where it has one, `router`: "sigmoid" with a selection bias,
-    or "softmax"): scores xf (n, d) over ALL experts in float32, lays the held
+    scope and, where it has them, `router`: "sigmoid" with a selection bias,
+    or "softmax", and the sigmoid router's `n_group`, `topk_group`): scores xf (n, d) over ALL experts in float32, lays the held
     picks out as whole row tiles and fills them from `src` (n, width).
     Returns (rows, layout, weights (n, k) float32, tile)."""
     n, k, held = xf.shape[0], layer.top_k, layer.experts_held
@@ -1514,7 +1533,9 @@ def _held_rows(layer, xf, src):
                 logits, k=k, scaling=layer.routed_scaling)
         else:
             choices, weights = route_sigmoid_topk(
-                logits, bias, k=k, scaling=layer.routed_scaling)
+                logits, bias, k=k, scaling=layer.routed_scaling,
+                n_group=getattr(layer, "n_group", 1),
+                topk_group=getattr(layer, "topk_group", 1))
         tile = _row_tile(n * k / layer.num_experts)
         lay = held_tile_layout(choices, offset=layer.expert_offset,
                                held=held, tile=tile)
@@ -1642,6 +1663,8 @@ class GatedMoE(nn.Module):
         out = routed + the shared SwiGLU expert of width `shared_dim`
 
     `router="softmax"`: s = softmax(x W_r), picks = top_k(s), no bias.
+    `n_group`, `topk_group`: the sigmoid router's group-limited pick
+    (`route_sigmoid_topk`; 1 and 1: every expert a candidate).
     `shared_gate`: the shared expert times sigmoid(x w_s), a gate of its
     own (Qwen's). `shared_dim` 0: no shared expert. `activation`: the
     experts' gate, "silu" or "relu" (ReGLU). `router_input`: what the
@@ -1661,6 +1684,8 @@ class GatedMoE(nn.Module):
     router: str = "sigmoid"
     shared_gate: bool = False
     activation: str = "silu"
+    n_group: int = 1
+    topk_group: int = 1
 
     @nn.compact
     def __call__(self, x, *, decode: bool = False, router_input=None):

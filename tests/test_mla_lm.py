@@ -87,10 +87,10 @@ def test_prefill_then_decode_through_pages_gives_the_references_logits(
     `_span16`: the several-token path folds the span 16 positions at a
     time (its running softmax over 3 to 5 blocks, as 1,024 at a time
     over an 8,960-position table)."""
-    from ddp_practice_tpu.models import mla_lm
+    from ddp_practice_tpu.models import hybrid_lm
 
     if path.endswith("_span16"):
-        monkeypatch.setattr(mla_lm, "_SPAN_TOKENS", 16)
+        monkeypatch.setattr(hybrid_lm, "_SPAN_TOKENS", 16)
         path = path[:-len("_span16")]
     kw = {"scratch_scatter": {}, "prefix_cold": dict(prefix_cache=True),
           "prefix_hit": dict(prefix_cache=True),

@@ -388,6 +388,25 @@ def _gdn(what, slots=128, length=4096):
     return (gdn.gdn_step if what == "step" else gdn.gdn_scan), args
 
 
+def _kda(what, slots=128, length=2048):
+    """ops/kda.py's Kimi Delta Attention kernels at the Ling-3.0-flash
+    cell's widths (perf/configs/ling3_flash_ep4.json): 32 heads of a
+    128 x 128 float32 state, a decay a key lane; the decode step over 128
+    slots, a prompt chunk's scan over the widest bucket and the narrowest
+    (the terms are the kernel `kda_terms`, the carry `kda_scan`: ops/gdn.py's
+    carry with the end-of-chunk decay a column), and the terms alone."""
+    from ddp_practice_tpu.ops import kda
+
+    f32 = jnp.float32
+    lead = (slots,) if what == "step" else (1, length)
+    wide = _sds(lead + (32, 128), f32)
+    args = (wide, wide, wide, wide, _sds(lead + (32,), f32),
+            _sds((lead[0], 32, 128, 128), f32))
+    if what == "terms":
+        return kda.kda_terms_kernel, args[:5]
+    return (kda.kda_step if what == "step" else kda.kda_scan), args
+
+
 def _sala(what, slots=32, blocks_per_slot=536, page=64):
     """ops/sparse_attention.py's kernels and `ssm_step` at the MiniCPM-SALA
     cell's widths (perf/configs/minicpm_sala_9b_pp4.json, perf/traffic/
@@ -583,6 +602,10 @@ KERNELS = {
     "qwen_gdn_terms_4096": functools.partial(_gdn, "terms"),
     "qwen_gdn_terms_256": functools.partial(_gdn, "terms", length=256),
     "qwen_paged_hd256_group8_page64": _paged_hd256,
+    "ling_kda_step_128_slots": functools.partial(_kda, "step"),
+    "ling_kda_scan_2048": functools.partial(_kda, "scan"),
+    "ling_kda_scan_256": functools.partial(_kda, "scan", length=256),
+    "ling_kda_terms_2048": functools.partial(_kda, "terms"),
     "sala_sparse_walk_32_slots": functools.partial(_sala, "walk"),
     "sala_sparse_prefill_2048": functools.partial(_sala, "prefill"),
     "sala_ssm_step_group_a_head": functools.partial(_sala, "step"),
@@ -706,6 +729,14 @@ def test_kernel_compiles_for_v5e(topo, name):
                 "qwen_gdn_te": ["gdn_terms"],
                 "qwen_paged_": ["paged_decode"],
                 "qwen_moe_gl": ["moe_gmm_glu"]}[name[:11]]
+        calls = [c.split("/")[-1] for c in _kernel_calls(text)]
+        assert calls == want, calls
+    if name.startswith("ling"):
+        # the names perf/layer_metrics/flood_kda_* sum by (a prompt's call
+        # is two ops: the terms, then the carry)
+        want = {"ling_kda_st": ["kda_step"],
+                "ling_kda_sc": ["kda_terms", "kda_scan"],
+                "ling_kda_te": ["kda_terms"]}[name[:11]]
         calls = [c.split("/")[-1] for c in _kernel_calls(text)]
         assert calls == want, calls
     if name.startswith("sala"):
@@ -1001,6 +1032,9 @@ CELL_DEPTH = {
                                      layers_published=[9, 10]),
     "smallthinker": lambda cfg: dict(cfg, layers_run=2,   # global, window
                                      layers_published=[0, 1]),
+    "ling3": lambda cfg: dict(cfg, layers_run=2,          # KDA + dense,
+                              layer_group_size=2,         # latent + experts
+                              first_k_dense_replace=1),
 }
 
 
@@ -1323,6 +1357,28 @@ def test_smallthinker_programs_carry_their_scopes_and_kernels(topo, prog,
     `window_prefill` in both (one kernel under a run-time window), and the
     ReGLU experts are `moe_gmm_glu` between the two row kernels."""
     cell = "smallthinker_serve_shortlong"
+    with _no_frames_in_locations():
+        text = _cell_programs(topo, cell)[prog]().compile().as_text()
+    assert _holds_the_contract(
+        cell, prog, text, sample=prog == "decode_burst") >= 1
+    assert prog != "prefill" or "/sample/dynamic_update_slice" in text
+    assert _kernel_counts(text) == kernels
+
+
+@pytest.mark.parametrize("prog, kernels", [
+    ("decode_burst", {"kda_step": 1, "paged_decode_mla": 1,
+                      **_expert_layers(1)}),
+    ("prefill", {"kda_terms": 1, "kda_scan": 1, **_expert_layers(1)})])
+def test_ling3_programs_carry_their_scopes_and_kernels(topo, prog, kernels):
+    """The Ling-3.0-flash cell's programs compiled for the described v5e at
+    its widths and engine (128 slots of 176 table columns, a per-slot state
+    pool AND a latent page pool in one donated cache, chunks of the first
+    bucket), one layer of each kind (K D, T X), a program a case: the
+    scopes contract, and the kernels by name and count: a decode step holds
+    1 `kda_step`, 1 `paged_decode_mla` and the expert layer's three; a chunk
+    1 `kda_terms`, 1 `kda_scan` and the expert layer's three (its latent
+    attention is un-absorbed XLA)."""
+    cell = "ling3_serve_reason"
     with _no_frames_in_locations():
         text = _cell_programs(topo, cell)[prog]().compile().as_text()
     assert _holds_the_contract(
