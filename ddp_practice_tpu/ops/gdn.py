@@ -44,7 +44,7 @@ about a reference row, `(k_t exp(G_t - G_r)) . (k_s exp(G_r - G_s))`. Over a
 whole chunk of 64 positions G runs to 64 |g|max and `exp(G_r - G_s)` leaves
 float32 (|g| up to 5: exp(320)); about the start of a SUB-chunk of 16 it is
 at most exp(80) < exp(88), which is what that model's bounded gate buys.
-The step's decay becomes a third column beside k and q (`_lane_columns`) and
+The step's decay is a third column beside k and q (one transpose a cell) and
 the carry's end-of-chunk decay a row over the key lanes (`_scan_kernel`
 `key_decay`); T, U, the terms' names and the three carry lines are as here.
 

@@ -28,7 +28,15 @@ shorter sub-chunk. One matmul a sub-chunk (its rows against every key row
 scaled about its r), four a chunk; everything after is ops/gdn.py's: the
 inverse by blocks (`_unit_lower_inverse`), the terms' names, the carry
 through the chunks (`_carry_chunk`, `_carry_call`), whose end-of-chunk decay
-`dend` is here a row over the key lanes.
+`dend` is here a row over the key lanes (a column over the state's rows by
+ops/gdn.py `_lane_columns`, once a head and chunk).
+
+The decode step is ops/gdn.py `_step_kernel`'s arithmetic on the VPU with
+THREE column tiles a head (k, q and exp(g) as `col[a, b] = x[a]`, one
+(key_dim, value_dim) tile each). They come from the vectors' layout: a grid
+cell's 3 x 16 vectors are transposed once, key lanes along the sublanes as
+the state has them, and a tile is one lane of that, broadcast. The MXU is
+not in the kernel, whose time is the state's way through HBM.
 
 Device op names (PERF.md section 3): `kda_step` (decode), `kda_terms` and
 `kda_scan` (a prompt's chunks), each under the scope of its name; off the
@@ -48,8 +56,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ddp_practice_tpu.ops.gdn import (
     _FAR, _LANES, _SCAN_VMEM, _TERMS, CHUNK, F32, HIGHEST, _carry_call,
-    _carry_reference, _column_selector, _head_block, _lane_columns,
-    _pair_levels, _unit_lower_inverse)
+    _carry_reference, _head_block, _pair_levels, _unit_lower_inverse)
 from ddp_practice_tpu.utils import backend
 
 # positions about one reference row (module docstring)
@@ -84,19 +91,28 @@ def kda_scan_reference(q, k, v, g, beta, h0):
 # ------------------------------------------------------------- decode step
 def _step_kernel(q_ref, k_ref, v_ref, da_ref, beta_ref, h_ref, o_ref, ho_ref,
                  *, heads):
-    """One grid cell: one sequence, `heads` heads; ops/gdn.py `_step_kernel`
-    with the decay a third column beside k and q (one matmul makes the
-    three), broadcast over the value lanes."""
-    pick = _column_selector(3, h_ref.shape[-1])
+    """One grid cell: one sequence, `heads` heads; ops/gdn.py `_step_kernel`'s
+    arithmetic on the VPU with the decay a third column beside k and q. A
+    column tile holds no arithmetic (`col[a, b] = x[a]`), so none is made by
+    one: the cell's 3 x heads vectors are transposed ONCE (the XLU), key
+    lanes along the sublanes as the state has them, and a head's tile is one
+    lane of that broadcast along the lanes, made where it is consumed (a
+    head's state and one tile are half the core's registers). Through the
+    MXU (`_lane_columns`, a float32 "highest" matmul a head) the VPU waited
+    for three 128-column tiles a head and the kernel read 39% of its
+    roofline: PERF.md section 6, PR 48."""
+    dk, dv = h_ref.shape[-2:]
+    lanes = jnp.concatenate([da_ref[...], k_ref[...], q_ref[...]], 0).T
+    col = lambda r, i: jnp.broadcast_to(
+        lanes[:, r * heads + i:r * heads + i + 1], (dk, dv))
     for i in range(heads):
-        kcol, qcol, dcol = _lane_columns(
-            [k_ref[i:i + 1, :], q_ref[i:i + 1, :], da_ref[i:i + 1, :]], pick)
-        s = h_ref[i] * dcol
+        s = h_ref[i] * col(0, i)
+        kcol = col(1, i)
         read = jnp.sum(s * kcol, axis=0, keepdims=True)        # (1, dv)
         d = beta_ref[i:i + 1, :] * (v_ref[i:i + 1, :] - read)
         new = s + kcol * d
         ho_ref[i] = new
-        o_ref[i:i + 1, :] = jnp.sum(new * qcol, axis=0, keepdims=True)
+        o_ref[i:i + 1, :] = jnp.sum(new * col(2, i), axis=0, keepdims=True)
 
 
 def kda_step(q, k, v, g, beta, state):
@@ -117,7 +133,7 @@ def kda_step_kernel(q, k, v, g, beta, state):
 
 
 # jitted on its own, as `_terms_call` is: a decode program's six layers ask
-# for the same shapes, and the body's 768 ops are lowered once, not six times
+# for the same shapes, and the body's 357 ops are lowered once, not six times
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _step_call(q, k, v, g, beta, state, *, interpret: bool):
     bsz, h, dv = v.shape

@@ -33,6 +33,7 @@ from ddp_practice_tpu.serve.scheduler import Request, Scheduler  # noqa: E402
 from ddp_practice_tpu.utils.trace import TraceRecorder  # noqa: E402
 from perf.families import ling3 as family  # noqa: E402
 from perf.reference import ling3 as reference  # noqa: E402
+from test_flash_attention import _eqns  # noqa: E402
 
 CFG = ling3_toy.config()
 PUBLISHED = perf_toy.load("perf/configs/ling3_flash_ep4.json")
@@ -147,8 +148,13 @@ def test_the_terms_kernel_is_its_oracle_term_by_term():
     assert want["dend"].shape == (1, 4, 2, 1, 16)    # a decay a KEY lane
 
 
-def test_the_step_kernel_is_one_position_of_the_recurrence():
-    q, k, v, g, beta, h0 = _kda_inputs(b=3, l=1, h=4, dv=32)
+@pytest.mark.parametrize("shape", [
+    dict(b=3, h=4, dv=32), dict(b=3, h=16, dk=128, dv=128),
+    dict(b=2, h=32, dk=128, dv=128)], ids=["4x32", "16x128", "32x128"])
+def test_the_step_kernel_is_one_position_of_the_recurrence(shape):
+    """Every head in one grid cell (4 heads: `_head_block`'s fallback), one
+    whole cell of 16 at the served widths, and two cells a slot."""
+    q, k, v, g, beta, h0 = _kda_inputs(l=1, **shape)
     one = tuple(x[:, 0] for x in (q, k, v, g, beta))
     want_o, want_s = kda.kda_step_reference(*one, h0)
     got_o, got_s = kda.kda_step_kernel(*one, h0)
@@ -160,6 +166,23 @@ def test_the_step_kernel_is_one_position_of_the_recurrence():
                                   one[4], h0)[1]
     assert np.abs(kept[:, :, 5] - h0[:, :, 5]).max() == 0
     assert np.abs(kept[:, :, 4] - h0[:, :, 4]).max() > 0
+
+
+@pytest.mark.parametrize("heads", [4, 32])
+def test_the_step_kernel_makes_no_column_by_a_matmul(heads):
+    """A head's three column tiles are lanes of ONE transpose a grid cell,
+    broadcast: the body holds no `dot_general` (through PR 47 one float32
+    "highest" matmul a head made them, and the VPU waited for it: PERF.md
+    section 6, PR 48), whether a cell holds 16 heads or all of them."""
+    q, k, v, g, beta, h0 = _kda_inputs(b=2, l=1, h=heads, dk=128, dv=128)
+    one = tuple(x[:, 0] for x in (q, k, v, g, beta))
+    names = [e.primitive.name for e in _eqns(jax.make_jaxpr(
+        kda.kda_step_kernel)(*one, h0).jaxpr)]
+    assert names.count("pallas_call") == 1, names
+    assert "dot_general" not in names
+    cell = min(heads, 16)
+    assert names.count("transpose") == 1
+    assert names.count("swap") == 2 * cell      # a head's state and output
 
 
 def test_gdn_carries_a_scalar_decay_through_the_shared_carry():
