@@ -581,9 +581,6 @@ class PagedEngine:
         # `window_stats`; every layer with K pages a `cached_key`)
         self._window_layers = sum(
             1 for path, _ in flat if leaf_name(path) == WINDOW_STATS_LEAF)
-        self._kv_itemsize = next((
-            a.dtype.itemsize for path, a in flat
-            if leaf_name(path) == "cached_key"), 2)
         self._global_layers = sum(
             1 for path, _ in flat if leaf_name(path) == "cached_key"
         ) - self._window_layers
@@ -1385,9 +1382,11 @@ class PagedEngine:
             if tr is not None and tr.enabled:
                 tid = trace_id or f"slot{slot}"
                 self._slot_trace[slot] = tid
+                # mirrored: the ONE event of a chunk admission, so its
+                # `prefix_hit` and `prompt_len` are counted once
                 tr.instant("chunk_admit", trace_id=tid,
                            pid=self.replica, tid=SLOT_LANE_BASE + slot,
-                           prompt_len=p, prefix_hit=matched,
+                           mirror=True, prompt_len=p, prefix_hit=matched,
                            chunk=chunk, slot=slot)
             self._keys = self._keys.at[slot].set(jax.random.PRNGKey(seed))
             self._slot_seed[slot] = (seed,)
@@ -1413,13 +1412,12 @@ class PagedEngine:
         if tr is not None and tr.enabled:
             tid = trace_id or f"slot{slot}"
             self._slot_trace[slot] = tid
+            # the call runs `bucket` positions of which `prompt_len -
+            # prefix_hit` hold a token (what a recurrent model's scans
+            # advance the state over; the rest is the bucket's padding)
             span, host, disp = self._prefill_spans(
                 "prefill", slot, tid, bucket=w, prompt_len=p,
-                blocks=n_table, prefix_hit=matched,
-                # positions a recurrent model's scans advance the state
-                # over, and the bucket's padding they run over besides
-                **({"scan_tokens": p, "scan_padded": w - p}
-                   if self._recurrent else {}))
+                blocks=n_table, prefix_hit=matched)
         else:
             span = host = disp = _NULL
         if self._recurrent:
@@ -1540,13 +1538,10 @@ class PagedEngine:
             span, host, disp = self._prefill_spans(
                 "prefill_chunk", slot,
                 self._slot_trace.get(slot, f"slot{slot}"),
+                # `take` of the `bucket` positions hold a token
                 bucket=w, pos0=done, take=take,
                 chunk=done // self.config.prefill_chunk,
-                prefix_hit=st["hit"], **pages,
-                # positions a recurrent model's scans advance the state
-                # over, and the chunk's padding they run over besides
-                **({"scan_tokens": take, "scan_padded": w - take}
-                   if self._recurrent else {}))
+                prefix_hit=st["hit"], **pages)
         else:
             span = host = disp = _NULL
         if self._recurrent:
@@ -1710,38 +1705,24 @@ class PagedEngine:
         return freed
 
     def _chunk_pages(self, pos0: int, take: int, width: int) -> dict:
-        """What the `window_prefill` calls of a chunk of `take` tokens in a
-        bucket of `width` do, over the layers of each group and for one KV
-        head, counted on the host by the kernel's own rule
-        (ops/window_attention.py walk_counts): the pages their tiles WALK
-        (a tile of rows from its first key's page to its last row's own),
-        by group; the pages their grid steps EXECUTE (whole steps, and what
-        a walk's last step computes) and the share of the steps that ran
-        without a mask."""
+        """The pages the `window_prefill` tiles of a chunk of `take` tokens
+        in a bucket of `width` WALK (a tile of rows from its first key's
+        page to its last row's own), by page group, over the layers of each
+        and for one KV head, counted on the host by the kernel's own rule
+        (ops/window_attention.py tile_walks)."""
         from ddp_practice_tpu.ops.window_attention import (
             NO_WINDOW,
             WINDOW_TILE,
-            pages_per_step,
-            walk_counts,
+            tile_walks,
         )
 
-        m, bs = self.model, self.config.block_size
-        columns = self._pt.shape[1]
-        pages = pages_per_step(
-            bs, m.kv_heads * m.head_dim,
-            m.num_heads // m.kv_heads * min(WINDOW_TILE, width), columns,
-            self._kv_itemsize)
-        far, near = (walk_counts(pos0, 0, window, take, s=width, block=bs,
-                                 columns=columns, pages=pages)
-                     for window in (NO_WINDOW, self._window))
-        g, n = self._global_layers, self._window_layers
-        steps = g * far["steps"] + n * near["steps"]
-        return {"global_pages": g * far["walked"],
-                "window_pages": n * near["walked"],
-                "pages_executed": g * far["executed"] + n * near["executed"],
-                "steps_unmasked": round(
-                    (g * far["clear"] + n * near["clear"]) / max(steps, 1),
-                    4)}
+        tile = min(WINDOW_TILE, width)
+        far, near = (int(tile_walks(
+            pos0, 0, window, take, tiles=width // tile, tile=tile,
+            block=self.config.block_size, columns=self._pt.shape[1],
+            xp=np)[1].sum()) for window in (NO_WINDOW, self._window))
+        return {"global_pages": self._global_layers * far,
+                "window_pages": self._window_layers * near}
 
     def _grow_tables(self, k: int) -> int:
         """Allocate the blocks the next k decode positions need, per
@@ -1840,11 +1821,9 @@ class PagedEngine:
             span, disp, read = self._burst_spans(
                 "decode_burst", burst=k, blocks_grown=grown,
                 cow_splits=splits, blocks_free=self.blocks.num_free,
+                # (a latent model's attention layers walk latent rows)
                 pages_walked=walked,
-                pages_held=int(self._nblk[act].sum()) * k,
-                # every attention layer of a latent model walks latent rows
-                **({"latent_pages_walked": walked}
-                   if self.latent_cache_bytes else {}))
+                pages_held=int(self._nblk[act].sum()) * k)
         else:
             span = disp = read = _NULL
         with span:

@@ -50,7 +50,12 @@ the tick), the engine's `burst_plan` and `decode_burst` / `verify`
 (preemption drain, the row loop, `_finish`, chunk emission, metrics) —
 and a tick longer than 8x the rolling median of the last 64 records a
 `slow_tick` instant with the phase durations, the serving twin of the
-Trainer's `step_anomaly`.
+Trainer's `step_anomaly` (mirrored into an open profiler session like the
+spans, so a stall inside a traced slice names its phase in the xplane).
+A `tick` also says how its slots were spent: `slots` (the engine's),
+`decoding` (those in its burst) and `prefilling` (running ones still
+mid-prompt as it ends): `perf/lib/annots.py` weighs them by the tick's
+time.
 """
 
 from __future__ import annotations
@@ -866,8 +871,8 @@ class Scheduler:
                            sampled_only=True, **attrs)
 
         admits = self._admit_counter
-        with span("tick", queue=len(self.queue),
-                  running=len(self.running)) as tick:
+        with span("tick", queue=len(self.queue), running=len(self.running),
+                  slots=self.engine.config.max_slots) as tick:
             with span("expire"):
                 self._expire_queue()
             with span("admit"):
@@ -882,8 +887,14 @@ class Scheduler:
                     self._deliver(*dispatched)
                 if self.metrics:
                     self.metrics.on_tick(self)
-            tick.attrs["admitted"] = self._admit_counter - admits
-            tick.attrs["delivered"] = len(self.completions) - before
+            # how the tick's slots were spent: in its burst (the burst
+            # span's `active`), or still taking in their prompt as it ends
+            tick.attrs.update(
+                admitted=self._admit_counter - admits,
+                delivered=len(self.completions) - before,
+                decoding=self.engine.last_burst_active if decoding else 0,
+                prefilling=sum(st.prefilling
+                               for st in self.running.values()))
         self._judge_tick(tr, tick, decoding)
 
     def _dispatch(self, plan_span) -> tuple:
@@ -1013,7 +1024,8 @@ class Scheduler:
                 phases = {name + "_s": round(secs, 6)
                           for name, secs in (tick.caused or {}).items()}
                 tr.instant("slow_tick", pid=self.replica,
-                           tid=ENGINE_LANE, median_s=round(median, 6),
+                           tid=ENGINE_LANE, mirror=True,
+                           median_s=round(median, 6),
                            tick_s=round(dur, 6), **phases)
         if decoding:
             hist.append(dur)

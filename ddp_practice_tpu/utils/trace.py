@@ -66,7 +66,12 @@ is mirrored, for its lifetime, as a profiler annotation named
 `serve:decode_burst`, `train:dispatch`, ...; request ids live in span
 attrs, never in names). While a profiler session is open each program
 span is therefore also an event on the profiler's host line, on the
-device trace's own clock.
+device trace's own clock, and carries the span's scalar attributes as
+the event's own stats (handed over as the span ENDS, so what a readback
+filled in is there): the program's counts beside the device's lines, in
+the profiler's viewer and for `perf/lib/annots.py`. An instant recorded
+with `mirror=True` (`slow_tick`, `chunk_admit`) is a zero-length event of
+the same kind.
 """
 
 from __future__ import annotations
@@ -237,6 +242,15 @@ class _Rec:
         self.parent = parent
 
 
+def _hand_attrs(ann, attrs: dict) -> None:
+    """The scalar attributes (int, float, bool, str; a list or None is
+    left out) of a span that is ending, onto its mirror annotation."""
+    scalars = {k: v for k, v in attrs.items()
+               if isinstance(v, (int, float, str))}
+    if scalars:
+        ann.set_metadata(**scalars)
+
+
 class _Span:
     """Context manager for one lane span; created only when enabled.
 
@@ -278,8 +292,11 @@ class _Span:
     def __exit__(self, exc_type, exc, tb):
         rec = self.rec
         self.t1 = rec._now()
-        if self._ann is not None:
-            self._ann.__exit__(exc_type, exc, tb)
+        ann = self._ann
+        if ann is not None:
+            if self.attrs and rec._session_open():
+                _hand_attrs(ann, self.attrs)
+            ann.__exit__(exc_type, exc, tb)
         rec._open_spans().pop()
         parent = self.parent
         if parent is not None:
@@ -359,6 +376,7 @@ class TraceRecorder:
         self._link = itertools.count()
         # profiler mirror (set_annotate): None = spans stay host-only
         self._annotate = None
+        self._ann_live = None
         self._ann_prefix = ""
         self._ann_names: Dict[str, str] = {}
         if sink is not None:
@@ -371,9 +389,14 @@ class TraceRecorder:
         lifetime under the name `<prefix>:<span name>`. With a profiler
         session open, each program span is then also an event on the
         profiler's host line, on the device trace's clock; with none,
-        the annotation is a no-op of the profiler's. `fn=None` turns
-        the mirror off."""
+        the annotation is a no-op of the profiler's. Where `fn` has an
+        `is_enabled()` and it says a session is open, what `fn(name)`
+        returned is handed the span's scalar attributes (int, float,
+        bool, str) by `set_metadata(**kw)` just before it is left; a
+        `fn` without `is_enabled` is never asked. `fn=None` turns the
+        mirror off."""
         self._annotate = fn
+        self._ann_live = getattr(fn, "is_enabled", None)
         self._ann_prefix = prefix
         self._ann_names = {}
 
@@ -387,6 +410,13 @@ class TraceRecorder:
         ann = fn(full)
         ann.__enter__()
         return ann
+
+    def _session_open(self) -> bool:
+        """Whether the mirror's profiler says a session is open: asked
+        once a span that has attributes, as it ends, so a recorder
+        without a profiler pays this test and builds nothing."""
+        live = self._ann_live
+        return live is not None and live()
 
     def _open_spans(self) -> list:
         try:
@@ -708,9 +738,17 @@ class TraceRecorder:
                                 attrs, t0=t0, t1=t1)
 
     def instant(self, name: str, *, trace_id: Optional[str] = None,
-                pid: int = 0, tid: int = 0, **attrs) -> None:
+                pid: int = 0, tid: int = 0, mirror: bool = False,
+                **attrs) -> None:
+        """A point event, now. `mirror=True` also leaves it in an open
+        profiler session as a zero-length annotation `<prefix>:<name>`
+        with its scalar attributes (set_annotate)."""
         if not self.enabled:
             return
+        if mirror and self._session_open():
+            ann = self._annotation(name)
+            _hand_attrs(ann, attrs)
+            ann.__exit__(None, None, None)
         self.record_instant(name, self._now(), trace_id=trace_id,
                             pid=pid, tid=tid, attrs=attrs or None)
 

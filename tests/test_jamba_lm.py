@@ -330,8 +330,9 @@ def test_scheduler_serves_it_and_the_spans_and_counters_say_what_was_scanned(
         toy):
     """Through `Scheduler` on the normal path, with the recorder and the
     metrics plane attached: every `prefill` span carries the prompt's real
-    positions (`scan_tokens`) and its bucket's padding (`scan_padded`), the
-    counters add them up, and the gauge reads the state pool."""
+    positions (`prompt_len`) in its `bucket` (the rest is the padding the
+    scans run over besides), the counters add them up, and the gauge reads
+    the state pool."""
     model, params = toy
     tracer = TraceRecorder(max_events=1 << 14)
     engine = make_engine(model, params, decode_burst=2)
@@ -350,15 +351,14 @@ def test_scheduler_serves_it_and_the_spans_and_counters_say_what_was_scanned(
     assert all(c.status == "length" and len(c.tokens) == 6 for c in done)
     spans = [e for e in tracer.to_chrome_trace()["traceEvents"]
              if e.get("name") == "prefill" and e.get("ph") in ("X", "B")]
-    assert sorted(e["args"]["scan_tokens"] for e in spans) == sorted(lens)
+    assert sorted(e["args"]["prompt_len"] for e in spans) == sorted(lens)
     for e in spans:
         a = e["args"]
-        assert a["scan_tokens"] == a["prompt_len"]
-        assert a["scan_padded"] == a["bucket"] - a["prompt_len"] >= 0
+        assert a["bucket"] >= a["prompt_len"] and a["prefix_hit"] == 0
     snap = metrics.registry.snapshot()
     assert snap["ssm_scan_tokens_total"] == sum(lens)
     assert snap["ssm_scan_padded_tokens_total"] == sum(
-        e["args"]["scan_padded"] for e in spans)
+        e["args"]["bucket"] - e["args"]["prompt_len"] for e in spans)
     # 3 slots x 3 Mamba layers x (16 x 256 x 4 B state + 3 x 256 x 4 B tail)
     assert snap["ssm_state_bytes"] == engine.ssm_state_bytes \
         == 3 * 3 * (16 * 256 * 4 + 3 * 256 * 4)

@@ -463,12 +463,12 @@ def test_scheduler_serves_it_and_the_spans_and_gauges_say_what_ran(toy):
     events = tracer.to_chrome_trace()["traceEvents"]
     spans = [e["args"] for e in events if e.get("name") == "prefill_chunk"
              and e.get("ph") in ("X", "B")]
-    assert sum(a["scan_tokens"] for a in spans) == sum(lens)
+    assert sum(a["take"] for a in spans) == sum(lens)
     assert len(spans) == sum(-(-n // 16) for n in lens)
     bursts = [e["args"] for e in events
               if e.get("name") == "decode_burst" and "args" in e]
     assert bursts and all(
-        a["latent_pages_walked"] > 0 and a["expert_rows"] > 0
+        a["pages_walked"] > 0 and a["expert_rows"] > 0
         and 0 < a["experts_touched"] for a in bursts)
     snap = metrics.registry.snapshot()
     assert snap["ssm_scan_tokens_total"] == sum(lens)

@@ -645,7 +645,7 @@ def test_bf16_meets_a_tolerance_the_e4m3_control_fails(toy):
 def test_scheduler_serves_it_and_the_spans_and_counters_say_what_ran(toy):
     """Through `Scheduler` on the normal path, with the recorder and the
     metrics plane attached: every `prefill_chunk` span carries the chunk's
-    real positions (`scan_tokens`) and its padding (`scan_padded`), every
+    real positions (`take`) in its `bucket` (the rest is padding), every
     `decode_burst` what the sparse layers read, the counters add both up
     and the gauges read the pools."""
     model, params = toy
@@ -667,11 +667,10 @@ def test_scheduler_serves_it_and_the_spans_and_counters_say_what_ran(toy):
     events = tracer.to_chrome_trace()["traceEvents"]
     spans = [e["args"] for e in events if e.get("name") == "prefill_chunk"
              and e.get("ph") in ("X", "B")]
-    assert sum(a["scan_tokens"] for a in spans) == sum(lens)
+    assert sum(a["take"] for a in spans) == sum(lens)
     assert len(spans) == sum(-(-n // 16) for n in lens)
     for a in spans:
-        assert a["scan_tokens"] == a["take"] <= 16
-        assert a["scan_padded"] == a["bucket"] - a["take"] >= 0
+        assert 0 < a["take"] <= 16 and a["bucket"] >= a["take"]
     bursts = [e["args"] for e in events
               if e.get("name") == "decode_burst" and "args" in e]
     assert bursts and all(

@@ -650,7 +650,7 @@ def test_bf16_meets_a_tolerance_the_e4m3_control_fails(toy):
 def test_scheduler_serves_it_and_the_spans_and_counters_say_what_ran(toy):
     """Through `Scheduler` on the normal path, with the recorder and the
     metrics plane attached: every `prefill` span carries the prompt's real
-    positions (`scan_tokens`) and its bucket's padding (`scan_padded`),
+    positions (`prompt_len`) in its `bucket` (the rest is padding),
     every `decode_burst` what the softmax router's picks landed on, the
     counters add both up, and the gauge reads the state pool."""
     model, params = toy
@@ -672,11 +672,10 @@ def test_scheduler_serves_it_and_the_spans_and_counters_say_what_ran(toy):
     events = tracer.to_chrome_trace()["traceEvents"]
     spans = [e for e in events
              if e.get("name") == "prefill" and e.get("ph") in ("X", "B")]
-    assert sorted(e["args"]["scan_tokens"] for e in spans) == sorted(lens)
+    assert sorted(e["args"]["prompt_len"] for e in spans) == sorted(lens)
     for e in spans:
         a = e["args"]
-        assert a["scan_tokens"] == a["prompt_len"]
-        assert a["scan_padded"] == a["bucket"] - a["prompt_len"] >= 0
+        assert a["bucket"] >= a["prompt_len"] and a["prefix_hit"] == 0
     bursts = [e["args"] for e in events
               if e.get("name") == "decode_burst" and "args" in e]
     picks = 3 * 4 * 4 * 2        # slots x top-k x expert layers x steps
@@ -687,7 +686,7 @@ def test_scheduler_serves_it_and_the_spans_and_counters_say_what_ran(toy):
     snap = metrics.registry.snapshot()
     assert snap["ssm_scan_tokens_total"] == sum(lens)
     assert snap["ssm_scan_padded_tokens_total"] == sum(
-        e["args"]["scan_padded"] for e in spans)
+        e["args"]["bucket"] - e["args"]["prompt_len"] for e in spans)
     assert snap["moe_rows_routed_total"] == picks * len(bursts)
     assert snap["moe_rows_held_total"] == sum(
         a["expert_rows"] for a in bursts)

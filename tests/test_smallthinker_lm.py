@@ -660,9 +660,8 @@ def test_scheduler_serves_it_and_the_spans_and_counters_say_what_ran(toy):
     assert len(chunks) == sum(-(-n // CHUNK) for n in lens)
     for a in chunks:
         assert 0 < a["window_pages"] <= 3 * a["global_pages"]
-        # what the steps compute beside what the tiles walk (PR 46)
-        assert a["pages_executed"] >= a["window_pages"] + a["global_pages"]
-        assert 0 <= a["steps_unmasked"] < 1
+        # what the steps execute is the kernel's tests' to count, no span's
+        assert "pages_executed" not in a and "steps_unmasked" not in a
         assert a["window_pages_freed"] == max(
             0, a["pos0"] - WINDOW + 1) // PAGE - max(
             0, a["pos0"] - CHUNK - WINDOW + 1) // PAGE
@@ -688,27 +687,26 @@ def test_a_chunks_span_counts_what_the_kernels_grid_runs(engine, pos0, take,
                                                          width):
     """`_chunk_pages` (the `prefill_chunk` span's attrs) against the count
     made from the mask itself: the pages a chunk's `window_prefill` calls
-    walk, by group and over the layers of each, the pages their grid steps
-    execute at the step the rule gives this engine's shapes, and the share
-    of steps without a mask."""
+    walk, by group and over the layers of each (what their grid steps
+    execute at the step the rule gives this engine's shapes is
+    `walk_counts`', held to the same count here)."""
     m = engine.model
     columns = engine._pt.shape[1]
     pages = wa.pages_per_step(
         PAGE, m.kv_heads * m.head_dim,
         m.num_heads // m.kv_heads * min(width, 128), columns,
-        engine._kv_itemsize)
+        4)                              # the toy's pools are float32
     assert pages == 16 == columns
     far, near = (_brute_counts(pos0, 0, w, take, s=width, block=PAGE,
                                columns=columns, pages=pages)
                  for w in (wa.NO_WINDOW, WINDOW))
     g, n = engine._global_layers, engine._window_layers
     assert (g, n) == (2, 6)
-    got = engine._chunk_pages(pos0, take, width)
-    assert got == {
-        "global_pages": g * far["walked"], "window_pages": n * near["walked"],
-        "pages_executed": g * far["executed"] + n * near["executed"],
-        "steps_unmasked": round((g * far["clear"] + n * near["clear"])
-                                / (g * far["steps"] + n * near["steps"]), 4)}
+    assert engine._chunk_pages(pos0, take, width) == {
+        "global_pages": g * far["walked"], "window_pages": n * near["walked"]}
+    for brute, w in ((far, wa.NO_WINDOW), (near, WINDOW)):
+        assert wa.walk_counts(pos0, 0, w, take, s=width, block=PAGE,
+                              columns=columns, pages=pages) == brute
 
 
 def test_the_cells_chunk_executes_what_the_longer_step_costs():

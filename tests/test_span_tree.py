@@ -5,6 +5,7 @@ a tracer. Plus the names a device trace is read by: the jitted serving
 programs and the flash kernels.
 """
 
+import inspect
 import json
 import os
 import re
@@ -286,6 +287,8 @@ def test_no_tracer_no_record_and_no_annotation(lm, kind, annotations,
     def built(*args, **kw):
         raise AssertionError("a span or its attrs built without a tracer")
 
+    plain = inspect.getsource(scheduler.Scheduler.step)
+    traced_tick = inspect.getsource(scheduler.Scheduler._traced_tick)
     # without a tracer the tick takes the plain path and the engine
     # builds no span, so no attr is computed for one either
     monkeypatch.setattr(scheduler.Scheduler, "_traced_tick", built)
@@ -295,6 +298,10 @@ def test_no_tracer_no_record_and_no_annotation(lm, kind, annotations,
     assert annotations == []
     assert sched.engine.tracer is None and not sched.engine._slot_trace
     assert not sched._tick_history
+    # how a tick's slots were spent (ISSUE 49) is counted for the `tick`
+    # span alone: the plain path's source names none of the three
+    for attr in ("slots=", "decoding=", "prefilling="):
+        assert attr not in plain and traced_tick.count(attr) == 1
 
 
 def test_a_disabled_tracer_costs_what_none_costs(lm, annotations):
@@ -361,6 +368,221 @@ def test_slow_tick_stays_quiet_on_a_flat_history(lm):
     sched, rec, n = serve(lm, "paged", requests=2, max_new=40)
     assert n >= 20 and len(sched._tick_history) >= 20
     assert slow_ticks(rec) == []
+
+
+# ------------------------------------------- attributes ride the mirror
+class RecordingAnnotation:
+    """Stands where `jax.profiler.TraceAnnotation` stands and says, like
+    it, whether a profiler session is open."""
+
+    live = True
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+    def set_metadata(self, **kw):
+        self.log.append(("meta", self.name, kw))
+
+    @staticmethod
+    def is_enabled():
+        return RecordingAnnotation.live
+
+
+@pytest.fixture
+def recording(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(RecordingAnnotation, "live", True)
+    monkeypatch.setattr(RecordingAnnotation, "log", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", RecordingAnnotation)
+    return RecordingAnnotation
+
+
+def test_a_spans_scalar_attributes_reach_its_annotation_once_at_its_end(
+        recording):
+    rec = TraceRecorder()
+    rec.set_annotate(recording, "serve")
+    with rec.span("decode_burst", active=3, burst=2, rows=[1, 2],
+                  nothing=None, impl="flash", share=0.5, ok=True) as span:
+        with rec.span("burst_dispatch"):       # no attributes: no call
+            pass
+        span.attrs["expert_rows"] = 17         # arrives with the readback
+        span.attrs["late_list"] = (1, 2)
+    assert recording.log == [
+        ("enter", "serve:decode_burst"), ("enter", "serve:burst_dispatch"),
+        ("exit", "serve:burst_dispatch"),
+        ("meta", "serve:decode_burst",
+         {"active": 3, "burst": 2, "impl": "flash", "share": 0.5,
+          "ok": True, "expert_rows": 17}),
+        ("exit", "serve:decode_burst")]
+    # the record keeps everything, the trace id stays where it was
+    (burst,) = [r for r in rec._records if r.name == "decode_burst"]
+    assert burst.attrs["rows"] == [1, 2] and burst.attrs["nothing"] is None
+    with rec.span("prefill", trace_id="r7", slot=1):
+        pass
+    assert recording.log[-2] == ("meta", "serve:prefill", {"slot": 1})
+
+
+def test_no_session_no_metadata_and_a_double_that_cannot_say_is_not_asked(
+        recording, annotations):
+    rec = TraceRecorder()
+    rec.set_annotate(recording, "serve")
+    recording.live = False
+    with rec.span("tick", slots=4) as span:
+        span.attrs["decoding"] = 2
+    rec.instant("slow_tick", mirror=True, tick_s=1.0)
+    assert recording.log == [("enter", "serve:tick"), ("exit", "serve:tick")]
+    # `CountingAnnotation` has neither `is_enabled` nor `set_metadata`
+    rec.set_annotate(CountingAnnotation, "serve")
+    with rec.span("tick", slots=4):
+        pass
+    rec.instant("slow_tick", mirror=True, tick_s=1.0)
+    assert annotations == ["serve:tick"]
+
+
+def test_the_scheduler_says_how_each_ticks_slots_were_spent(lm, recording):
+    sched, rec, n_ticks = serve(lm, "paged", requests=3)
+    metas = [e for e in recording.log if e[0] == "meta"]
+    ticks = [kw for _, name, kw in metas if name == "serve:tick"]
+    bursts = [kw for _, name, kw in metas if name == "serve:decode_burst"]
+    assert len(ticks) == n_ticks
+    for kw in ticks:
+        assert {"queue", "running", "slots", "admitted", "delivered",
+                "decoding", "prefilling"} <= set(kw)
+        assert kw["slots"] == 2 and 0 <= kw["decoding"] <= 2
+        assert kw["prefilling"] == 0       # no chunked admission here
+        assert all(type(v) is int for v in kw.values())
+    # a burst's `active` is its tick's `decoding`; 0 where none ran
+    assert [kw["decoding"] for kw in ticks if kw["decoding"]] == [
+        kw["active"] for kw in bursts]
+    assert sum(kw["decoding"] for kw in ticks) == sum(
+        r.attrs["active"] for r in lane_spans(rec)
+        if r.name == "decode_burst")
+    assert all({"active", "burst", "pages_walked", "pages_held"} <= set(kw)
+               for kw in bursts)
+    prefills = [kw for _, name, kw in metas if name == "serve:prefill"]
+    assert len(prefills) == 3 and all(
+        (kw["bucket"], kw["prompt_len"], kw["prefix_hit"]) == (4, 3, 0)
+        for kw in prefills)
+
+
+def test_slow_tick_is_mirrored_with_the_phase_that_held_it(lm, monkeypatch,
+                                                           recording):
+    from ddp_practice_tpu.serve.scheduler import Scheduler
+
+    real_expire, stalled = Scheduler._expire_queue, []
+
+    def expire(self):
+        if len(self._tick_history) == 12 and not stalled:
+            stalled.append(True)
+            self.clock.advance(0.2)
+        real_expire(self)
+
+    monkeypatch.setattr(Scheduler, "_expire_queue", expire)
+    _, rec, _ = serve(lm, "paged", requests=2, max_new=40)
+    assert len(slow_ticks(rec)) == 1
+    at = [i for i, e in enumerate(recording.log)
+          if e[1] == "serve:slow_tick"]
+    assert [recording.log[i][0] for i in at] == ["enter", "meta", "exit"]
+    assert at[2] - at[0] == 2                  # zero-length: nothing inside
+    phases = recording.log[at[1]][2]
+    assert phases["expire_s"] == pytest.approx(0.2)
+    assert phases["tick_s"] == pytest.approx(0.22) and "admit_s" in phases
+
+
+def test_the_programs_counts_come_back_from_a_cpu_xplane(lm, tmp_path):
+    """The REAL round trip: the profiler open on the CPU around a toy
+    `PagedEngine` + `Scheduler`, then `perf/lib/annots.py` alone on the
+    `.xplane.pb` it wrote: every span's attributes, every child inside its
+    tick, on the trace's own clock."""
+    import glob
+
+    import jax
+
+    from ddp_practice_tpu.serve.engine import EngineConfig, PagedEngine
+    from ddp_practice_tpu.serve.scheduler import Request, Scheduler
+    from perf.lib import annots
+
+    model, params = lm
+    engine = PagedEngine(model, params, EngineConfig(
+        max_slots=2, prompt_buckets=(4, 8), eos_id=None, block_size=4,
+        decode_burst=2, prefix_cache=True, prefill_chunk=4))
+    rec = TraceRecorder()
+    engine.set_tracer(rec, 0)
+    sched = Scheduler(engine, tracer=rec, replica=0)
+    # two prompts of one chunk, one of three chunks that shares a block
+    for rid, prompt in enumerate(([1, 2, 3], [1, 2, 3, 4, 5, 6, 7, 8, 9, 10],
+                                  [1, 2, 3, 4, 9, 9, 9, 9, 9, 9, 9])):
+        sched.submit(Request(rid=rid, prompt=prompt, max_new_tokens=4))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("perf:traced"):
+            n_ticks = 0
+            while not sched.idle:
+                sched.step()
+                n_ticks += 1
+    finally:
+        jax.profiler.stop_trace()
+    assert all(c.status == "length" for c in sched.completions)
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    annots.load.cache_clear()
+    rows = annots.events(path)
+    ticks = annots.named(rows, "serve:tick", "slots", "decoding",
+                         "prefilling", "admitted", "delivered")
+    assert len(ticks) == n_ticks == len(annots.named(rows, "serve:tick"))
+    assert all(t[3]["slots"] == 2 for t in ticks)
+    assert any(t[3]["prefilling"] for t in ticks)     # the chunked prompts
+    bursts = annots.named(rows, "serve:decode_burst", "active", "burst",
+                          "pages_walked", "pages_held")
+    recorded = [r for r in lane_spans(rec) if r.name == "decode_burst"]
+    assert len(bursts) == len(recorded) > 0
+    assert [b[3]["pages_walked"] for b in bursts] == [
+        r.attrs["pages_walked"] for r in recorded]
+
+    def tick_of(row):
+        (owner,) = [t for t in ticks
+                    if t[1] <= row[1] and row[1] + row[2] <= t[1] + t[2]]
+        return owner
+
+    for b in bursts:                 # inside its tick, and counted as its
+        assert tick_of(b)[3]["decoding"] == b[3]["active"]
+    prefills = annots.named(rows, "serve:prefill", "bucket", "prompt_len",
+                            "prefix_hit")
+    chunks = annots.named(rows, "serve:prefill_chunk", "bucket", "take",
+                          "pos0", "prefix_hit")
+    admits = annots.named(rows, "serve:chunk_admit", "prompt_len",
+                          "prefix_hit")
+    assert len(prefills) == 1 and len(admits) == 2
+    assert len(chunks) == len(
+        [r for r in lane_spans(rec) if r.name == "prefill_chunk"]) >= 4
+    for row in prefills + chunks + admits:
+        tick_of(row)
+    for name in ("serve:admit", "serve:deliver", "serve:burst_readback"):
+        assert annots.named(rows, name) and all(
+            tick_of(r) for r in annots.named(rows, name))
+    # the five readers, from the file alone
+    obs = {"trace": {"planes": []}, "xplane": path}
+    spent = [annots.slot_seconds_pct(obs, k)
+             for k in ("decoding", "prefilling")]
+    assert 0 < spent[0] <= 100 and 0 < spent[1] <= 100
+    real = sum(c[3]["take"] for c in chunks) + 3
+    ran = sum(r[3]["bucket"] for r in prefills + chunks)
+    assert annots.prefill_pad_pct(obs) == pytest.approx(
+        100.0 * (ran - real) / ran)
+    hit = sum(r[3]["prefix_hit"] for r in prefills + admits)
+    assert annots.prefix_hit_pct(obs) == pytest.approx(
+        100.0 * hit / (3 + 10 + 11))
+    annots.load.cache_clear()
 
 
 # ---------------------------------------------------------------- trainer
