@@ -153,11 +153,17 @@ def ssm_step_reference(x, dt, a, b_mat, c_mat, d_skip, state):
     return y + x * d_skip.astype(f32)[None, :, None], state
 
 
-# heads a grid cell of `ssm_step` holds at most: a cell is one group's heads
-# where a group has that many (16 tiles of 64 x 128 floats in, as many out),
-# and several groups' where a group is a head or two (lightning attention: a
-# group a head, and a cell of ONE 64 KB tile is all fixed cost)
-_STEP_HEADS = 16
+# bytes of state a grid cell of `ssm_step` moves each way at most: as many
+# whole groups as fit and divide `g`, one where a group alone is larger. What
+# `kda_step` and `gdn_step` move a cell. Mamba-2 at 128 slots x 8 groups of 16
+# heads of (64, 128), 512 KB a group, alone on a v5e, ms a call and share of
+# the state's bytes at 819 GB/s (PERF.md section 6, PR 51): 1 group a cell
+# 1.887 (69.5%), 2 groups 1.666 (78.7%), 4 groups 1.637 (80.1%), all 8 (4 MiB)
+# 1.640 (79.9%). Lightning attention (a group ONE 64 KB head, all fixed cost
+# alone) fills the budget with 16 of its 32: 0.2085 ms, and 32 a cell are no
+# faster (0.2087) while their 32 unrolled heads take the decode program 0.9 s
+# longer to build at every start.
+_STEP_BYTES = 2**20
 
 
 def _step_kernel(da_ref, xd_ref, b_ref, c_ref, h_ref, y_ref, ho_ref, *,
@@ -205,8 +211,9 @@ def ssm_step_kernel(x, dt, a, b_mat, c_mat, d_skip, state):
         jnp.exp(dt * a.astype(f32))[..., None], (bsz, h, n)
     ).reshape(bsz, g, hg, n)
     xd = (x * dt[..., None]).reshape(bsz, g, hg, p)
+    group = hg * 4 * p * n              # bytes of one group's state
     gb = max(k for k in range(1, g + 1)
-             if g % k == 0 and k * hg <= max(_STEP_HEADS, hg))
+             if g % k == 0 and k * group <= max(_STEP_BYTES, group))
     grp = lambda last: pl.BlockSpec((None, gb, hg, last),
                                     lambda i, j: (i, j, 0, 0))
     vec = pl.BlockSpec((None, gb, 1, n), lambda i, j: (i, j, 0, 0))

@@ -116,24 +116,92 @@ def test_the_scan_at_a_group_a_head_is_the_sequential_recurrence():
     assert np.abs(np.asarray(final - state)).max() < KERNEL_TOL * 10
 
 
+def _step_args(h, g, p=128, n=128, slots=2, seed=1):
+    """`ssm_step`'s arguments for `h` heads of (p, n) in `g` groups."""
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    return (f(slots, h, p), jnp.abs(f(slots, h)), -jnp.abs(f(h)),
+            f(slots, g, n), f(slots, g, n), f(h), f(slots, h, p, n))
+
+
+def _step_call(*args):
+    """What `ssm_step_kernel` traces to: (grid, a cell's block of the state
+    past the squeezed slot, the kernel body's jaxpr as text)."""
+    fresh = lambda *a: ssm.ssm_step_kernel(*a)   # no trace of another budget
+    call, = (e for e in jax.make_jaxpr(fresh)(*args).eqns
+             if e.primitive.name == "pallas_call")
+    mapping = call.params["grid_mapping"]
+    block = tuple(getattr(d, "block_size", d)
+                  for d in mapping.block_mappings[4].block_shape[1:])
+    return tuple(mapping.grid), block, str(call.params["jaxpr"])
+
+
 def test_the_step_kernel_takes_several_groups_a_cell():
     """32 groups of one head (lightning attention at the published widths'
-    count) are 2 grid cells of 16 a sequence; 8 groups of 16 heads (Mamba-2)
-    stay one group a cell; both equal the plain step."""
-    rng = np.random.default_rng(1)
-    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    count) are 2 grid cells of 16 a sequence, 1 MiB of state each way: the
+    program the long-document cell has run since PR 40, an unrolled body
+    with no loop in it. Mamba-2's 8 groups of 16 heads of (64, 128), 512 KB
+    each, go two a cell (PR 51); a group that is 1 MiB alone stays a cell.
+    Both equal the plain step."""
     for h, g in ((32, 32), (16, 2)):
-        args = (f(2, h, 128), jnp.abs(f(2, h)), -jnp.abs(f(h)),
-                f(2, g, 128), f(2, g, 128), f(h), f(2, h, 128, 128))
+        args = _step_args(h, g)
         want, state = ssm.ssm_step_reference(*args)
         got, new = ssm.ssm_step_kernel(*args)
         assert np.abs(np.asarray(got - want)).max() < 1e-4
         assert np.abs(np.asarray(new - state)).max() < KERNEL_TOL
-    grid = lambda h, g: re.search(r"grid=\((\d+), (\d+)\)", str(
-        jax.make_jaxpr(ssm.ssm_step_kernel)(
-            f(2, h, 128), f(2, h), f(h), f(2, g, 128), f(2, g, 128), f(h),
-            f(2, h, 128, 128)))).groups()
-    assert grid(32, 32) == ("2", "2") and grid(128, 8) == ("2", "8")
+    grid = lambda *shape: _step_call(*_step_args(*shape))[0]
+    assert grid(128, 8) == (2, 8) and grid(128, 8, 64) == (2, 4)
+    lightning, block, body = _step_call(*_step_args(32, 32))
+    assert lightning == (2, 2) and block == (16, 1, 128, 128)
+    assert not re.search(r"\b(while|scan)\b", body)
+
+
+# (heads, groups, head size, state size), MiB a cell -> groups a grid cell
+STEP_CELLS = {
+    "two_mamba2_groups_a_cell": ((32, 2, 64, 128), 1, 2),
+    "four_mamba2_groups_in_2_mib": ((64, 4, 64, 128), 2, 4),
+    "four_half_groups_a_cell": ((32, 4, 64, 128), 1, 4),
+    "three_groups_none_divides": ((48, 3, 64, 128), 1, 1),
+    "one_group": ((16, 1, 64, 128), 1, 1),
+    "a_group_over_the_budget": ((32, 1, 128, 128), 1, 1),
+    "small_groups_all_in_one_cell": ((8, 4, 16, 32), 1, 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STEP_CELLS))
+def test_the_step_kernel_fills_its_cell_by_bytes(case, monkeypatch):
+    """`_STEP_BYTES` of state a cell (1 MiB in the tree; 2 MiB is the form
+    PR 51 measured beside it): whole groups, a divisor of their count, one
+    where nothing else fits; the interpreted kernel equals the plain step
+    whatever the cell holds."""
+    (h, g, p, n), mib, groups = STEP_CELLS[case]
+    assert ssm._STEP_BYTES == 2**20
+    monkeypatch.setattr(ssm, "_STEP_BYTES", mib * 2**20)
+    args = _step_args(h, g, p, n, seed=len(case))
+    grid, block, _ = _step_call(*args)
+    assert grid == (2, g // groups) and block == (groups, h // g, p, n)
+    cell = groups * (h // g) * 4 * p * n
+    assert cell <= ssm._STEP_BYTES or groups == 1
+    want, state = ssm.ssm_step_reference(*args)
+    got, new = jax.jit(lambda *a: ssm.ssm_step_kernel(*a))(*args)
+    assert np.abs(np.asarray(got - want)).max() < 1e-4
+    assert np.abs(np.asarray(new - state)).max() < KERNEL_TOL
+
+
+def test_the_step_kernel_rewrites_a_donated_state_in_place():
+    """Two calls, each on the state the one before wrote and donated (the
+    engine's decode burst): the aliased output is the second step of the
+    plain recurrence, with two groups a cell."""
+    *vectors, state = _step_args(32, 2, 64, 128, seed=7)
+    step = jax.jit(ssm.ssm_step_kernel, donate_argnums=(6,))
+    want = state
+    for _ in range(2):
+        want_y, want = ssm.ssm_step_reference(*vectors, want)
+    got = state + 0.0       # the donated copy
+    for _ in range(2):
+        got_y, got = step(*vectors, got)
+    assert np.abs(np.asarray(got_y - want_y)).max() < 1e-4
+    assert np.abs(np.asarray(got - want)).max() < KERNEL_TOL
 
 
 def test_lightning_mixer_is_the_references_layer(toy):

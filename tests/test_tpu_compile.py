@@ -590,6 +590,7 @@ def _scoped_vmem(text):
 KERNELS = {
     "hybrid_paged_grouped_32q_2kv": _paged_grouped,
     "hybrid_ssm_step": _ssm_step,
+    "hybrid_ssm_step_32_slots": functools.partial(_ssm_step, 32),
     "hybrid_moe_gmm_decode_tiles": functools.partial(_moe_gmm, 16),
     "hybrid_moe_gmm_prompt_tiles": functools.partial(_moe_gmm, 64),
     "jamba_sel_step_256_slots": functools.partial(_sel, "step"),
@@ -713,6 +714,10 @@ def test_kernel_compiles_for_v5e(topo, name):
                 "hybrid_moe_g": "moe_gmm"}[name[:12]]
         calls = _kernel_calls(text)
         assert len(calls) == 1 and want in calls[0], calls
+    if name.startswith("hybrid_ssm"):
+        # two groups of 16 heads a cell since PR 51 (1 MiB each way, two
+        # deep): inside the 16 MiB a kernel is scoped by default
+        assert max(_scoped_vmem(text).values()) <= 16 * 2**20
     if name.startswith("jamba"):
         # the names perf/layer_metrics/flood_sel_* and
         # flood_paged_decode_roofline sum by
